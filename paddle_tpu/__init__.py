@@ -27,6 +27,12 @@ from .memory import apply_memory_fraction as _amf
 
 _amf()
 
+# the persistent compilation cache directory is part of the cache key:
+# placed once, here (see compile_cache)
+from .compile_cache import apply_compile_cache as _acc
+
+_acc()
+
 from . import ops  # registers all op lowerings first
 from . import analysis  # static verifier + infer rules (ops registered them)
 from . import (

@@ -396,9 +396,26 @@ class CompiledBlock:
     def __init__(self, traced, jitted):
         self.traced = traced
         self.jitted = jitted
+        # abstract signature of the first call, so Executor.compiled_hlo
+        # can AOT-lower the same executable later
+        self.avals = None
 
     def __call__(self, feeds, ro_state, rw_state, rng_key):
+        if self.avals is None:
+            self.avals = call_avals((feeds, ro_state, rw_state, rng_key))
         return self.jitted(feeds, ro_state, rw_state, rng_key)
+
+
+def call_avals(args):
+    """Abstract twin of a call's arguments.  An uncommitted array (the
+    per-step rng key) keeps no sharding: pinning it would change the
+    lowered module, and with it the compilation-cache key, so the AOT
+    compile would miss the entry the jit call just wrote."""
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype,
+            sharding=x.sharding if getattr(x, "committed", True) else None),
+        args)
 
 
 class ExecutionCache:
@@ -439,6 +456,11 @@ class ExecutionCache:
         compiled = CompiledBlock(traced, jitted)
         self._cache[key] = compiled
         return compiled
+
+    def blocks_for(self, program):
+        """Every CompiledBlock cached for `program`."""
+        return [cb for key, cb in self._cache.items()
+                if key[0] == id(program)]
 
     def clear(self):
         self._cache.clear()

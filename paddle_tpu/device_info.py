@@ -33,7 +33,8 @@ def device_kind(place=None):
 
 
 def peak_flops(place=None):
-    """Peak bf16 FLOPs/sec of the attached chip (None when unknown) —
+    """Peak bf16 FLOPs/sec of the attached chip (None on a CPU device;
+    an unknown accelerator kind raises) —
     the gpu_info flops-estimate analog, used for MFU accounting."""
     from .memory import _device
     from .utils.flops import chip_peak_flops

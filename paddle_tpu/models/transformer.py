@@ -62,13 +62,21 @@ def _pos_encoding_table(max_len, d_model):
     return table
 
 
+def _word_emb_attr(d_model):
+    """Named so the family's vocab-sharding rule (`emb.w`) covers the
+    table: under the default embedding_N.w_0 name both word tables, a
+    sixth of the parameters, replicated on every mp shard."""
+    return ParamAttr(name=unique_name.generate("word_emb.w"),
+                     initializer=Normal(0.0, d_model ** -0.5))
+
+
 def prepare_embedding(ids, vocab_size, d_model, max_len, dropout_rate, pos_name, is_test=False):
     """Word + sinusoid position embedding (the reference's
     prepare_encoder/decoder), position table as a frozen parameter."""
     word_emb = layers.embedding(
         ids,
         size=[vocab_size, d_model],
-        param_attr=ParamAttr(initializer=Normal(0.0, d_model ** -0.5)),
+        param_attr=_word_emb_attr(d_model),
     )
     word_emb = layers.scale(word_emb, scale=d_model ** 0.5)
     pos_table = layers.create_parameter(
@@ -754,7 +762,7 @@ def transformer_decode_programs(hp=ModelHyperParams, batch=1, src_len=64,
                                       append_batch_size=False)
             word = layers.embedding(
                 tok, size=[hp.trg_vocab_size, hp.d_model],
-                param_attr=ParamAttr(initializer=Normal(0.0, hp.d_model ** -0.5)),
+                param_attr=_word_emb_attr(hp.d_model),
             )  # [B, W, D] (W == 1 squeezes in the lookup)
             word = layers.scale(
                 layers.reshape(word, shape=[batch, width, hp.d_model]),

@@ -364,10 +364,10 @@ def _cross_entropy(ctx, ins, attrs):
 @register("softmax_with_cross_entropy", no_grad_inputs=("Label",))
 def _softmax_xent(ctx, ins, attrs):
     logits, label = ins["Logits"][0], ins["Label"][0]
-    from .pallas_kernels import fused_softmax_xent, use_pallas
+    from .pallas_kernels import fused_softmax_xent, use_pallas_unwrapped
 
     if (
-        use_pallas()
+        use_pallas_unwrapped()
         and not attrs.get("soft_label", False)
         and attrs.get("ignore_index", -100) < 0
         and logits.ndim == 2
@@ -458,6 +458,7 @@ def _fused_linear_xent_op(ctx, ins, attrs):
         _linear_xent_dense,
         fused_linear_xent,
         use_pallas,
+        use_pallas_unwrapped,
     )
 
     x = ins["X"][0]
@@ -469,14 +470,15 @@ def _fused_linear_xent_op(ctx, ins, attrs):
     h = x.shape[-1]
     x2 = x.reshape(-1, h)
     lbl = label.reshape(-1).astype(jnp.int32)
+    loss2 = None
     if use_pallas():
         from .spmd_epilogue import spmd_linear_xent
 
         loss2 = spmd_linear_xent(ctx, x2, w, lbl, eps,
                                  bool(attrs.get("transpose_w", False)))
-        if loss2 is None:
+        if loss2 is None and use_pallas_unwrapped():
             loss2 = fused_linear_xent(x2, w, lbl, eps)
-    else:
+    if loss2 is None:
         loss2 = _linear_xent_dense(x2, w, lbl, eps)
     loss = loss2.reshape(tuple(x.shape[:-1]) + (1,)).astype(x.dtype)
     return {"Loss": [loss]}

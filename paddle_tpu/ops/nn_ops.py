@@ -259,10 +259,10 @@ def _layer_norm(ctx, ins, attrs):
     eps = attrs.get("epsilon", 1e-5)
     # pallas kernel override when the norm is over the last axis only
     # (the transformer case) — FLAGS_use_pallas, library-override analog
-    from .pallas_kernels import fused_layer_norm, use_pallas
+    from .pallas_kernels import fused_layer_norm, use_pallas_unwrapped
 
     if (
-        use_pallas()
+        use_pallas_unwrapped()
         and begin == x.ndim - 1
         and ins.get("Scale")
         and ins.get("Bias")
@@ -428,6 +428,7 @@ def _fc(ctx, ins, attrs):
         matmul_bias_act,
         mm_epilogue_ok,
         use_pallas,
+        use_pallas_unwrapped,
     )
 
     x, w = ins["Input"][0], ins["W"][0]
@@ -445,9 +446,11 @@ def _fc(ctx, ins, attrs):
         from .spmd_epilogue import spmd_matmul_bias_act
 
         out = spmd_matmul_bias_act(ctx, x2, w, bias, act)
-        if out is None:
+        if out is None and use_pallas_unwrapped():
             out = matmul_bias_act(x2, w, bias, act)
-        return {"Out": [out.reshape(tuple(x.shape[:k]) + (w.shape[-1],))]}
+        if out is not None:
+            return {"Out": [out.reshape(
+                tuple(x.shape[:k]) + (w.shape[-1],))]}
     out = x2 @ w
     out = out.reshape(tuple(x.shape[:k]) + (w.shape[-1],))
     if bias is not None:
@@ -468,6 +471,7 @@ def _fused_swiglu(ctx, ins, attrs):
         matmul_swiglu,
         mm_epilogue_ok,
         use_pallas,
+        use_pallas_unwrapped,
     )
 
     x, wg, wu = ins["X"][0], ins["GateW"][0], ins["UpW"][0]
@@ -475,13 +479,14 @@ def _fused_swiglu(ctx, ins, attrs):
     x2 = x.reshape((int(np.prod(x.shape[:k])), -1))
     M, K = x2.shape
     N = wg.shape[-1]
+    out = None
     if use_pallas() and mm_epilogue_ok(M, K, N, extra_w=2):
         from .spmd_epilogue import spmd_matmul_swiglu
 
         out = spmd_matmul_swiglu(ctx, x2, wg, wu)
-        if out is None:
+        if out is None and use_pallas_unwrapped():
             out = matmul_swiglu(x2, wg, wu)
-    else:
+    if out is None:
         out = _swiglu_dense(x2, wg, wu)
     return {"Out": [out.reshape(tuple(x.shape[:k]) + (N,))]}
 
@@ -497,6 +502,7 @@ def _fused_residual_ln(ctx, ins, attrs):
         _add_ln_dense,
         fused_add_layer_norm,
         use_pallas,
+        use_pallas_unwrapped,
     )
 
     x, y = ins["X"][0], ins["Y"][0]
@@ -506,14 +512,15 @@ def _fused_residual_ln(ctx, ins, attrs):
     y2 = y.reshape(-1, h)
     gamma = ins["Scale"][0].reshape(h)
     beta = ins["Bias"][0].reshape(h)
+    res = None
     if use_pallas():
         from .spmd_epilogue import spmd_add_layer_norm
 
         res = spmd_add_layer_norm(ctx, x2, y2, gamma, beta, eps)
-        s2, o2 = res if res is not None else fused_add_layer_norm(
-            x2, y2, gamma, beta, eps)
-    else:
-        s2, o2 = _add_ln_dense(x2, y2, gamma, beta, eps)
+        if res is None and use_pallas_unwrapped():
+            res = fused_add_layer_norm(x2, y2, gamma, beta, eps)
+    s2, o2 = res if res is not None else _add_ln_dense(
+        x2, y2, gamma, beta, eps)
     s = s2.reshape(x.shape)
     sf = s.astype(jnp.float32)
     mean = jnp.mean(sf, axis=-1)
@@ -617,11 +624,10 @@ def _padded_lstm(ctx, ins, attrs):
     # hidden, working set within VMEM.  Bias folds into the projected
     # gates either way.
     from .pallas_kernels import (
-        _interpret,
         _lstm_seq_dense,
-        _row_block,
         fused_lstm,
-        use_pallas,
+        recurrent_ok,
+        use_pallas_unwrapped,
     )
 
     if not is_reverse:
@@ -631,10 +637,7 @@ def _padded_lstm(ctx, ins, attrs):
             else jnp.full((bsz,), t, jnp.int32)
         )
         xg = xproj if b is None else xproj + b.reshape(1, 1, -1)
-        lane_ok = hid % (8 if _interpret() else 128) == 0
-        blk = _row_block(bsz, 8)
-        vmem_bytes = blk * t * (4 + 2) * hid * 4 + hid * 4 * hid * 4
-        if use_pallas() and lane_ok and vmem_bytes < 10 * 2 ** 20:
+        if use_pallas_unwrapped() and recurrent_ok(bsz, t, hid, 4):
             hs, cs = fused_lstm(xg, w, h0, c0, lens)
         else:
             hs, cs = _lstm_seq_dense(xg, w, h0, c0, lens)
@@ -686,10 +689,9 @@ def _padded_gru(ctx, ins, attrs):
     h0 = ins["H0"][0] if ins.get("H0") else jnp.zeros((bsz, hid), xproj.dtype)
     from .pallas_kernels import (
         _gru_seq_dense,
-        _interpret,
-        _row_block,
         fused_gru,
-        use_pallas,
+        recurrent_ok,
+        use_pallas_unwrapped,
     )
 
     if not attrs.get("is_reverse", False):
@@ -698,11 +700,7 @@ def _padded_gru(ctx, ins, attrs):
             if seq_len is not None
             else jnp.full((bsz,), t, jnp.int32)
         )
-        lane_ok = hid % (8 if _interpret() else 128) == 0
-        # the whole [block_b, T, 4H] working set must fit in VMEM
-        blk = _row_block(bsz, 8)
-        vmem_bytes = blk * t * 4 * hid * 4 + hid * 3 * hid * 4
-        if use_pallas() and lane_ok and vmem_bytes < 10 * 2 ** 20:
+        if use_pallas_unwrapped() and recurrent_ok(bsz, t, hid, 3):
             hs = fused_gru(xproj, w, h0, lens)
         else:
             # one shared cell implementation (also the fused path's
@@ -849,7 +847,7 @@ def _qvec_attention_mesh(q, k, v, qstart, scale, mesh, axis, bq_flag,
 
             return shard_map(
                 body, mesh=mesh, in_specs=(p4, p4, p4, P()),
-                out_specs=p4, check_rep=False)(q, k, v, qstart)
+                out_specs=p4, check_vma=False)(q, k, v, qstart)
     sh = NamedSharding(mesh, p4)
     qc = jax.lax.with_sharding_constraint(q, sh)
     kc = jax.lax.with_sharding_constraint(k, sh)
@@ -875,6 +873,7 @@ def _fused_attention(ctx, ins, attrs):
         _dense_attention,
         flash_attention,
         use_pallas,
+        use_pallas_unwrapped,
     )
 
     q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
@@ -1013,7 +1012,7 @@ def _fused_attention(ctx, ins, attrs):
                 return {"Out": [_qvec_attention_mesh(
                     q, k, v, qstart, float(scale), mesh, axis,
                     bq_flag, bk_flag, _mosaic_legal)]}
-        if use_pallas():
+        if use_pallas_unwrapped():
             bq = 128 if t % 128 == 0 else t
             bk = 128 if tk % 128 == 0 else tk
             if bq_flag or bk_flag:
@@ -1062,14 +1061,15 @@ def _fused_attention(ctx, ins, attrs):
         p = jax.nn.softmax(s, axis=-1)
         out = jnp.einsum("bqk,bkd->bqd", p.astype(qf.dtype), vf)
         return {"Out": [out.reshape(b, h, t, d)]}
-    kbias = None
+    kbias = kbias_b = seg_b = None
     if ins.get("Bias"):
         # additive key-padding bias, rank-1 in the key axis: [B, Tk] (or any
         # shape squeezing to it, e.g. the reference-style [B, 1, 1, Tk]);
         # broadcast over heads and query rows without ever materializing
         # the [Tq, Tk] score matrix
-        kbias = ins["Bias"][0].reshape(b, tk).astype(jnp.float32)
-        kbias = jnp.broadcast_to(kbias[:, None, :], (b, h, tk)).reshape(b * h, tk)
+        kbias_b = ins["Bias"][0].reshape(b, tk).astype(jnp.float32)
+        kbias = jnp.broadcast_to(
+            kbias_b[:, None, :], (b, h, tk)).reshape(b * h, tk)
     seg = None
     if ins.get("SegmentIds"):
         # sequence packing (reader.packing): [B, T] int ids; query i sees
@@ -1080,12 +1080,13 @@ def _fused_attention(ctx, ins, attrs):
             raise ValueError(
                 "fused_attention: SegmentIds requires Tq == Tk "
                 "(self-attention over one packed row)")
-        seg = ins["SegmentIds"][0].reshape(b, t).astype(jnp.int32)
-        seg = jnp.broadcast_to(seg[:, None, :], (b, h, t)).reshape(b * h, t)
+        seg_b = ins["SegmentIds"][0].reshape(b, t).astype(jnp.int32)
+        seg = jnp.broadcast_to(
+            seg_b[:, None, :], (b, h, t)).reshape(b * h, t)
     if qstart is not None:
         from .pallas_kernels import flash_attention_piece
 
-        if use_pallas() and (bq_flag or bk_flag):
+        if use_pallas_unwrapped() and (bq_flag or bk_flag):
             # sweep knobs apply here too: validate loudly and USE them —
             # silently benchmarking auto blocks (or the dense fallback)
             # under the requested label is the misattribution the
@@ -1102,7 +1103,7 @@ def _fused_attention(ctx, ins, attrs):
             return {"Out": [out.reshape(b, h, t, d)]}
         bq = 128 if t % 128 == 0 else t
         bk = 128 if tk % 128 == 0 else tk
-        if use_pallas() and bq <= 512 and bk <= 1024:
+        if use_pallas_unwrapped() and bq <= 512 and bk <= 1024:
             bq, bk = _auto_blocks(
                 "flash_attention_piece",
                 lambda p: (lambda q_, k_, v_: flash_attention_piece(
@@ -1119,6 +1120,19 @@ def _fused_attention(ctx, ins, attrs):
             out = _dense_attention(qf, kf, vf, True, float(scale),
                                    window=window, qoff=qstart)
         return {"Out": [out.reshape(b, h, t, d)]}
+    from .spmd_epilogue import mesh_ctx, spmd_flash_attention
+
+    mc = mesh_ctx()
+
+    def run_flash(bq, bk):
+        if mc is None:
+            return flash_attention(
+                qf, kf, vf, kbias, causal, float(scale), block_q=bq,
+                block_k=bk, window=window, seg=seg)
+        return spmd_flash_attention(
+            mc, q, k, v, kbias_b, seg_b, causal, float(scale), bq, bk,
+            window).reshape(b * h, t, d)
+
     if use_pallas() and (bq_flag or bk_flag):
         # explicit sweep knobs: validate loudly — a silently-ignored
         # flag would attribute fallback timings to the requested size
@@ -1131,29 +1145,28 @@ def _fused_attention(ctx, ins, attrs):
                 "and be a multiple of 128 (or equal the full length) — "
                 "the lse/delta/kbias BlockSpecs place the block in the "
                 "minor dim" % (bq, bk, t, tk))
-        out = flash_attention(qf, kf, vf, kbias, causal, float(scale),
-                              block_q=bq, block_k=bk, window=window,
-                              seg=seg)
+        out = run_flash(bq, bk)
     elif use_pallas():
         # auto path: 128-blocks when the lengths tile; otherwise a
         # single full-dim block is still Mosaic-legal, so short or odd
         # lengths ride flash too as long as the [bq, bk] score tile
         # stays VMEM-friendly.  Anything else goes dense.  The choice
         # among legal candidates goes through the tuning cache (searched
-        # at first real-device dispatch, seeded in interpret mode).
+        # at first real-device dispatch, seeded in interpret mode) —
+        # except under a live mesh, which keeps the deterministic
+        # defaults (the tuning search times STANDALONE kernels).
         bq = 128 if t % 128 == 0 else t
         bk = 128 if tk % 128 == 0 else tk
         # this derivation is Mosaic-legal by construction (each block is
         # 128-tiling or full-dim); only the VMEM score-tile budget gates
         if bq <= 512 and bk <= 1024:
-            bq, bk = _auto_blocks(
-                "flash_attention",
-                lambda p: (lambda q_, k_, v_: flash_attention(
-                    q_, k_, v_, None, causal, float(scale),
-                    p["block_q"], p["block_k"], window)))
-            out = flash_attention(qf, kf, vf, kbias, causal, float(scale),
-                                  block_q=bq, block_k=bk, window=window,
-                                  seg=seg)
+            if mc is None:
+                bq, bk = _auto_blocks(
+                    "flash_attention",
+                    lambda p: (lambda q_, k_, v_: flash_attention(
+                        q_, k_, v_, None, causal, float(scale),
+                        p["block_q"], p["block_k"], window)))
+            out = run_flash(bq, bk)
         else:
             out = _dense_attention(qf, kf, vf, causal, float(scale), kbias,
                                    window=window, seg=seg)

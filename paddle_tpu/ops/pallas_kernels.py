@@ -43,16 +43,29 @@ def _interpret():
     return jax.default_backend() != "tpu"
 
 
+# Mosaic's default scoped-VMEM limit on a v5e is 16 MiB, and Pallas
+# double-buffers every blocked operand: first contact with the chip
+# refused tile sets the dispatch gates admitted (fused_lstm B32/T63/H512:
+# "Scoped allocation with size 16.10M and limit 16.00M exceeded scoped
+# vmem limit").  Every kernel therefore asks for the limit below (the
+# v5e has 128 MiB of VMEM), and every gate admits a tile set only when
+# TWICE its resident bytes fit the budget — the limit less room for the
+# compiler's own temporaries.
+_VMEM_LIMIT_BYTES = 32 * 2 ** 20
+_TILE_BUDGET_BYTES = 24 * 2 ** 20
+
+
+def _mosaic_params():
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT_BYTES)
+
+
 def _sds(shape, dtype, *xs):
     """ShapeDtypeStruct whose vma (varying-mesh-axes) is the union of the
-    inputs' — so pallas_call out_shapes type-check inside shard_map on
-    jax builds with vma tracking; builds without it (this sandbox's
-    0.4.x) take neither the kwarg nor the tracking, so plain structs."""
+    inputs' — so pallas_call out_shapes type-check inside shard_map."""
     from ..parallel.mesh import vma_of
 
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None:
-        return jax.ShapeDtypeStruct(shape, dtype)
     return jax.ShapeDtypeStruct(shape, dtype, vma=vma_of(*xs))
 
 
@@ -60,9 +73,15 @@ def _cdiv(a, b):
     return (a + b - 1) // b
 
 
-def _row_block(n, default):
+def _row_block(n, default, row_bytes=0):
     """Shared row/batch tiling heuristic: the default block when it
-    divides n, else the largest of (8, 1) that does."""
+    divides n, else the largest of (8, 1) that does.  row_bytes: what
+    one row of the block costs in VMEM over the call's row-blocked
+    operands and tile-sized temporaries; the default halves until the
+    double-buffered block fits the tile budget (a [512, 50257] f32
+    logits block would ask for 100 MB)."""
+    while default > 8 and 2 * default * row_bytes > _TILE_BUDGET_BYTES:
+        default //= 2
     blk = min(default, n)
     if n % blk != 0:
         blk = 1 if n % 8 else 8
@@ -110,16 +129,20 @@ def _row_block_candidates(n, sizes=(128, 256, 512, 1024)):
 # ---------------------------------------------------------------------------
 def _unpack_flash_refs(refs, has_qoff, has_seg):
     """Shared operand unpack for the three flash kernels (fwd/dq/dkv):
-    the optional leading q base — SMEM scalar ([1] whole-array), or
-    per-row [BH, 1] blocked (1, 1) when has_qoff == "vec" (each grid-b
-    cell reads ITS row's base — the vector-qstart ragged serving
-    step) — then q/k/v/kbias and the optional segment-id pair.
+    the optional leading q base — a whole-array SMEM operand, [1] for
+    the scalar offset or [BH] when has_qoff == "vec" (each grid-b cell
+    reads ITS row's base by program_id(0) — the vector-qstart ragged
+    serving step; Mosaic refuses a (1, 1)-blocked SMEM spec, whose last
+    two block dims must be (8, 128)-divisible or full) — then
+    q/k/v/kbias and the optional segment-id pair.
     Returns (qo, q, k, v, kbias, seg_q, seg_k, remaining_refs); ONE
     copy so a new qstart encoding cannot silently miss a backward
     kernel's causal base."""
+    from jax.experimental import pallas as pl
+
     refs = list(refs)
     if has_qoff == "vec":
-        qo = refs.pop(0)[0, 0]
+        qo = refs.pop(0)[pl.program_id(0)]
     elif has_qoff:
         qo = refs.pop(0)[0]
     else:
@@ -223,8 +246,7 @@ def _flash_fwd(q, k, v, kbias, causal, scale, block_q, block_k, window=0,
     passes its chunk offset so causal/window masks apply in global
     positions.  qvec: optional [BH] int32 PER-ROW q-position bases (the
     continuous-batching ragged step: every serving slot carries its own
-    causal cutoff) riding as [BH, 1] SMEM blocks — mutually exclusive
-    with qoff.  seg: optional [BH, T] int32 segment ids (sequence
+    causal cutoff) riding whole in SMEM — mutually exclusive with qoff.  seg: optional [BH, T] int32 segment ids (sequence
     packing; requires Tq == Tk) — rides as two more [BH, 1, X] rank-1
     operands, compared per score tile.  Returns (o, lse)."""
     from jax.experimental import pallas as pl
@@ -269,13 +291,10 @@ def _flash_fwd(q, k, v, kbias, causal, scale, block_q, block_k, window=0,
                          memory_space=pltpu.VMEM),
         ]
         args += [seg3, seg3]
-    if qoff is not None:
+    if qoff is not None or qvec is not None:
         in_specs.insert(0, pl.BlockSpec(memory_space=pltpu.SMEM))
-        args.insert(0, qoff.astype(jnp.int32).reshape(1))
-    elif qvec is not None:
-        in_specs.insert(0, pl.BlockSpec((1, 1), lambda b, i, j: (b, 0),
-                                        memory_space=pltpu.SMEM))
-        args.insert(0, qvec.astype(jnp.int32).reshape(BH, 1))
+        args.insert(0, (qoff.astype(jnp.int32).reshape(1) if qoff is not None
+                        else qvec.astype(jnp.int32).reshape(BH)))
     o, lse = pl.pallas_call(
         kernel,
         grid=(BH, nq, nk),
@@ -295,6 +314,7 @@ def _flash_fwd(q, k, v, kbias, causal, scale, block_q, block_k, window=0,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
+        compiler_params=_mosaic_params(),
         interpret=_interpret(),
     )(*args)
     return o, lse.reshape(BH, T)
@@ -419,7 +439,7 @@ def _flash_bwd(q, k, v, kbias, o, lse, do, causal, scale, block_q, block_k,
         delta = delta - dlse.astype(jnp.float32)
     qoff_arg = (
         [qoff.astype(jnp.int32).reshape(1)] if qoff is not None
-        else [qvec.astype(jnp.int32).reshape(BH, 1)]
+        else [qvec.astype(jnp.int32).reshape(BH)]
         if qvec is not None else [])
     has_qoff = "vec" if qvec is not None else qoff is not None
     # 2D [BH, X] operands ride as [BH, 1, X] (Mosaic-legal blocks; see
@@ -438,10 +458,8 @@ def _flash_bwd(q, k, v, kbias, o, lse, do, causal, scale, block_q, block_k,
                              memory_space=pltpu.VMEM)
     row_spec_q = pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i),
                               memory_space=pltpu.VMEM)
-    smem = ([pl.BlockSpec(memory_space=pltpu.SMEM)] if qoff is not None
-            else [pl.BlockSpec((1, 1), lambda b, i, j: (b, 0),
-                               memory_space=pltpu.SMEM)]
-            if qvec is not None else [])
+    smem = ([pl.BlockSpec(memory_space=pltpu.SMEM)]
+            if qoff is not None or qvec is not None else [])
     seg_specs_q = ([row_spec_q, kb_spec_q] if seg is not None else [])
     seg_args = ([seg3, seg3] if seg is not None else [])
     dq = pl.pallas_call(
@@ -455,6 +473,7 @@ def _flash_bwd(q, k, v, kbias, o, lse, do, causal, scale, block_q, block_k,
         out_specs=q_spec_q,
         out_shape=_sds((BH, T, d), q.dtype, q, k, v, do),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        compiler_params=_mosaic_params(),
         interpret=_interpret(),
     )(*(qoff_arg + [q, k, v, kb3] + seg_args + [do, lse3, delta3]))
 
@@ -487,6 +506,7 @@ def _flash_bwd(q, k, v, kbias, o, lse, do, causal, scale, block_q, block_k,
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((1, block_k), jnp.float32),
         ],
+        compiler_params=_mosaic_params(),
         interpret=_interpret(),
     )(*(qoff_arg + [q, k, v, kb3] + seg_args + [do, lse3, delta3]))
     return dq, dk, dv, dkb.reshape(BH, Tk)
@@ -623,8 +643,8 @@ def flash_attention_qvec(q, k, v, qstart, scale=None, block_q=128,
     """PER-ROW-qstart causal flash attention: q [BH, Tq, d] against
     k/v [BH, Tk, d] where row b's query i sits at global position
     qstart[b] + i and keys at their cache indices (Tq may differ from
-    Tk).  qstart: [BH] int — rides as [BH, 1] SMEM blocks, so each grid
-    cell reads ITS row's causal cutoff; out-of-band K blocks are still
+    Tk).  qstart: [BH] int — rides whole in SMEM, and each grid cell
+    reads ITS row's causal cutoff; out-of-band K blocks are still
     skipped per row.  This is the ragged continuous-batching serving
     step's attention (PR 9's documented single biggest serving-perf
     lever): one dispatch serves a pool of requests at heterogeneous
@@ -694,7 +714,7 @@ def _ln_fwd(x2d, gamma, beta, eps, block_rows=None):
                        (beta.shape, beta.dtype)],
         )["block_rows"]
     _note("layernorm")
-    block_rows = _row_block(R, block_rows)
+    block_rows = _row_block(R, block_rows, 3 * H * 4)
     grid = (_cdiv(R, block_rows),)
     return pl.pallas_call(
         functools.partial(_ln_kernel, eps=eps),
@@ -708,6 +728,7 @@ def _ln_fwd(x2d, gamma, beta, eps, block_rows=None):
         out_specs=pl.BlockSpec((block_rows, H), lambda i: (i, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((R, H), x2d.dtype),
+        compiler_params=_mosaic_params(),
         interpret=_interpret(),
     )(x2d, gamma, beta)
 
@@ -746,11 +767,38 @@ def use_pallas():
     return get_flag("use_pallas")
 
 
+def use_pallas_unwrapped():
+    """May a dispatch site call a kernel with NO shard_map around it?
+    The flag, and not while tracing under a live GSPMD mesh: XLA cannot
+    partition a Mosaic custom call ("Mosaic kernels cannot be
+    automatically partitioned. Please wrap the call in a shard_map" —
+    the first four-chip run; interpret mode lowers to plain HLO and
+    never said so).  Under a mesh a kernel runs through its shard_map
+    form (ops/spmd_epilogue, nn_ops._qvec_attention_mesh) or the op
+    lowers densely and GSPMD partitions that."""
+    from .spmd_epilogue import mesh_ctx
+
+    return use_pallas() and mesh_ctx() is None
+
+
 # ---------------------------------------------------------------------------
 # fused GRU sequence kernel (math/jit_kernel.h gru kernels + fused/fusion_gru
 # analog): the hidden state lives in VMEM across ALL timesteps, so the
 # recurrence reads/writes HBM once per sequence instead of once per step
 # ---------------------------------------------------------------------------
+def recurrent_ok(bsz, t, hid, n_gates):
+    """THE dispatch gate for fused_gru (n_gates 3) and fused_lstm (4):
+    a lane-aligned hidden size (Mosaic's 128 once compiled), and the
+    whole-sequence working set of one batch block — projected gates in,
+    hidden (and cell) sequences out, the recurrent weight — fitting the
+    tile budget double-buffered."""
+    blk = _row_block(bsz, 8)
+    n_out = 2 if n_gates == 4 else 1
+    resident = 4 * (blk * t * (n_gates + n_out) * hid + hid * n_gates * hid)
+    return (hid % (8 if _interpret() else 128) == 0
+            and 2 * resident < _TILE_BUDGET_BYTES)
+
+
 def _gru_seq_kernel(x_ref, w_ref, h0_ref, len_ref, o_ref, *, hid, seq_len):
     w = w_ref[:].astype(jnp.float32)  # [H, 3H]
     w_uz = w[:, : 2 * hid]
@@ -804,6 +852,7 @@ def _gru_seq_fwd(xproj, w, h0, lens, block_b=8):
         out_specs=pl.BlockSpec((block_b, T, hid), lambda i: (i, 0, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((B, T, hid), xproj.dtype),
+        compiler_params=_mosaic_params(),
         interpret=_interpret(),
     )(xproj, w, h0, lens.reshape(B, 1))
 
@@ -918,6 +967,7 @@ def _lstm_seq_fwd(xproj, w, h0, c0, lens, block_b=8):
             jax.ShapeDtypeStruct((B, T, hid), xproj.dtype),
             jax.ShapeDtypeStruct((B, T, hid), xproj.dtype),
         ],
+        compiler_params=_mosaic_params(),
         interpret=_interpret(),
     )(xproj, w, h0, c0, lens.reshape(B, 1))
 
@@ -999,7 +1049,7 @@ def _sxent_fwd_call(logits, labels, block_rows=None):
                        ((R,), "int32")],
         )["block_rows"]
     _note("xent")
-    block_rows = _row_block(R, block_rows)
+    block_rows = _row_block(R, block_rows, 3 * C * 4)
     grid = (_cdiv(R, block_rows),)
     return pl.pallas_call(
         _sxent_kernel,
@@ -1014,6 +1064,7 @@ def _sxent_fwd_call(logits, labels, block_rows=None):
         out_specs=pl.BlockSpec((block_rows, 1), lambda i: (i, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((R, 1), jnp.float32),
+        compiler_params=_mosaic_params(),
         interpret=_interpret(),
     )(logits, labels.reshape(R, 1))
 
@@ -1072,7 +1123,7 @@ def _sxent_bwd_call(logits, labels, dy, block_rows=None):
             arg_specs=[(logits.shape, logits.dtype), ((R,), "int32"),
                        ((R, 1), "float32")],
         )["block_rows"]
-    block_rows = _row_block(R, block_rows)
+    block_rows = _row_block(R, block_rows, 5 * C * 4)
     row_spec = pl.BlockSpec((block_rows, 1), lambda i: (i, 0),
                             memory_space=pltpu.VMEM)
     return pl.pallas_call(
@@ -1087,6 +1138,7 @@ def _sxent_bwd_call(logits, labels, dy, block_rows=None):
         out_specs=pl.BlockSpec((block_rows, C), lambda i: (i, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((R, C), logits.dtype),
+        compiler_params=_mosaic_params(),
         interpret=_interpret(),
     )(logits, labels.reshape(R, 1), dy.reshape(R, 1).astype(jnp.float32))
 
@@ -1127,9 +1179,25 @@ fused_softmax_xent.defvjp(_sxent_vjp_fwd, _sxent_vjp_bwd)
 _MM_ACTS = ("", "identity", "relu", "tanh", "sigmoid", "gelu", "swish")
 
 
-def _mm_act(z, act):
+def _erf_mosaic(x):
+    """erf for kernel bodies.  The Pallas TPU lowering has neither erf
+    nor erfc (jax 0.9.0: "Unimplemented primitive in Pallas TPU lowering
+    for KernelType.TC: erfc"), so the exact-gelu epilogue could not
+    compile.  Abramowitz & Stegun 7.1.26: |error| <= 1.5e-7, f32
+    resolution, from an exp and a divide, which Mosaic has."""
+    a = jnp.abs(x)
+    t = 1.0 / (1.0 + 0.3275911 * a)
+    poly = t * (0.254829592 + t * (-0.284496736 + t * (
+        1.421413741 + t * (-1.453152027 + t * 1.061405429))))
+    y = 1.0 - poly * jnp.exp(-a * a)
+    return jnp.where(x < 0, -y, y)
+
+
+def _mm_act(z, act, in_kernel=False):
     """f32 epilogue activation (exact erf gelu / beta-1 swish: the same
-    defaults as the op lowerings in math_ops.ACTIVATIONS)."""
+    defaults as the op lowerings in math_ops.ACTIVATIONS).  in_kernel:
+    the caller is a Pallas kernel body, where gelu's erf must be
+    _erf_mosaic; the dense twin keeps XLA's own."""
     if act in ("", "identity"):
         return z
     if act == "relu":
@@ -1139,6 +1207,8 @@ def _mm_act(z, act):
     if act == "sigmoid":
         return jax.nn.sigmoid(z)
     if act == "gelu":
+        if in_kernel:
+            return 0.5 * z * (1.0 + _erf_mosaic(z * 0.7071067811865476))
         return jax.nn.gelu(z, approximate=False)
     if act == "swish":
         return z * jax.nn.sigmoid(z)
@@ -1152,7 +1222,7 @@ def _mm_kernel(*refs, act, has_bias):
     z = jnp.dot(x_ref[:], w_ref[:], preferred_element_type=jnp.float32)
     if has_bias:
         z = z + b_ref[:].astype(jnp.float32)  # [1, bn] broadcast
-    o_ref[:] = _mm_act(z, act).astype(o_ref.dtype)
+    o_ref[:] = _mm_act(z, act, in_kernel=True).astype(o_ref.dtype)
 
 
 def _mm_col_block(n, default):
@@ -1198,9 +1268,10 @@ def _mm_blocks(M, K, N, dtype, kernel, extra_w=1):
 
 
 def _mm_vmem_ok(M, K, N, bm, bn, extra_w=1):
-    """x/w/out tiles (f32 upper bound) must sit well inside VMEM."""
+    """x/w/out tiles plus the accumulator (f32 upper bound), double-
+    buffered, must fit the tile budget."""
     tile = (bm * K + extra_w * K * bn + 2 * bm * bn + bn) * 4
-    return tile < 12 * 2 ** 20
+    return 2 * tile < _TILE_BUDGET_BYTES
 
 
 def mm_epilogue_ok(M, K, N, act="", extra_w=1):
@@ -1241,6 +1312,7 @@ def _mm_call(x2d, w, bias, act, block_m, block_n):
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j: (i, j),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((M, N), x2d.dtype),
+        compiler_params=_mosaic_params(),
         interpret=_interpret(),
     )(*args)
 
@@ -1311,6 +1383,7 @@ def _swiglu_call(x2d, wg, wu, block_m, block_n):
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j: (i, j),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((M, N), x2d.dtype),
+        compiler_params=_mosaic_params(),
         interpret=_interpret(),
     )(x2d, wg, wu)
 
@@ -1370,7 +1443,7 @@ def _add_ln_call(x2d, y2d, gamma, beta, eps, block_rows):
 
     R, H = x2d.shape
     _note("layernorm")
-    block_rows = _row_block(R, block_rows)
+    block_rows = _row_block(R, block_rows, 5 * H * 4)
     row_spec = pl.BlockSpec((block_rows, H), lambda i: (i, 0),
                             memory_space=pltpu.VMEM)
     vec_spec = pl.BlockSpec((H,), lambda i: (0,), memory_space=pltpu.VMEM)
@@ -1383,6 +1456,7 @@ def _add_ln_call(x2d, y2d, gamma, beta, eps, block_rows):
             jax.ShapeDtypeStruct((R, H), x2d.dtype),
             jax.ShapeDtypeStruct((R, H), x2d.dtype),
         ],
+        compiler_params=_mosaic_params(),
         interpret=_interpret(),
     )(x2d, y2d, gamma, beta)
 
@@ -1571,11 +1645,11 @@ def _lxent_dw_kernel(x_ref, w_ref, lbl_ref, lse_ref, dy_ref, dw_ref,
 def _lx_vmem_ok(H, br, bv):
     """Worst-pass (dw) resident f32 upper bound: the x row tile
     [br, H], the w input + dw output + dw_acc scratch tiles [H, bv]
-    each, and the recomputed logits/softmax tile [br, bv] must sit
-    well inside VMEM — the linear-xent twin of _mm_vmem_ok (same
-    12 MB line)."""
+    each, and the recomputed logits/softmax tile [br, bv], double-
+    buffered, must fit the tile budget — the linear-xent twin of
+    _mm_vmem_ok."""
     tile = (br * H + 3 * H * bv + 2 * br * bv) * 4
-    return tile < 12 * 2 ** 20
+    return 2 * tile < _TILE_BUDGET_BYTES
 
 
 def _lxent_default_blocks(R, H, V):
@@ -1660,6 +1734,7 @@ def _lxent_fwd(x2d, w, labels, eps, block_r, block_v):
             pltpu.VMEM((block_r, 1), jnp.float32),
             pltpu.VMEM((block_r, 1), jnp.float32),
         ],
+        compiler_params=_mosaic_params(),
         interpret=_interpret(),
     )(x2d, w, labels.astype(jnp.int32).reshape(R, 1))
     return loss, lse
@@ -1685,6 +1760,7 @@ def _lxent_bwd(x2d, w, labels, lse, dy, eps, block_r, block_v):
         out_specs=x_spec,
         out_shape=jax.ShapeDtypeStruct((R, H), x2d.dtype),
         scratch_shapes=[pltpu.VMEM((block_r, H), jnp.float32)],
+        compiler_params=_mosaic_params(),
         interpret=_interpret(),
     )(x2d, w, lbl, lse2, dy2)
 
@@ -1698,6 +1774,7 @@ def _lxent_bwd(x2d, w, labels, lse, dy, eps, block_r, block_v):
         out_specs=w_spec,
         out_shape=jax.ShapeDtypeStruct((H, V), w.dtype),
         scratch_shapes=[pltpu.VMEM((H, block_v), jnp.float32)],
+        compiler_params=_mosaic_params(),
         interpret=_interpret(),
     )(x2d, w, lbl, lse2, dy2)
     return dx, dw
@@ -1823,6 +1900,7 @@ def _lxent_parts(x2d, w, lbl_local, block_r, block_v):
         out_specs=[row_spec, row_spec, row_spec],
         out_shape=[jax.ShapeDtypeStruct((R, 1), jnp.float32)] * 3,
         scratch_shapes=[pltpu.VMEM((block_r, 1), jnp.float32)] * 4,
+        compiler_params=_mosaic_params(),
         interpret=_interpret(),
     )(x2d, w, lbl_local.astype(jnp.int32).reshape(R, 1))
 
@@ -1906,6 +1984,7 @@ def _lxent_bwd_sharded(x2d, w, lbl_local, vld, lse, dy, eps, vocab_total,
         out_specs=x_spec,
         out_shape=jax.ShapeDtypeStruct((R, H), x2d.dtype),
         scratch_shapes=[pltpu.VMEM((block_r, H), jnp.float32)],
+        compiler_params=_mosaic_params(),
         interpret=_interpret(),
     )(x2d, w, lbl, vld2, lse2, dy2)
 
@@ -1920,6 +1999,7 @@ def _lxent_bwd_sharded(x2d, w, lbl_local, vld, lse, dy, eps, vocab_total,
         out_specs=w_spec,
         out_shape=jax.ShapeDtypeStruct((H, V), w.dtype),
         scratch_shapes=[pltpu.VMEM((H, block_v), jnp.float32)],
+        compiler_params=_mosaic_params(),
         interpret=_interpret(),
     )(x2d, w, lbl, vld2, lse2, dy2)
     return dx, dw
@@ -1978,7 +2058,7 @@ def _sharded_lxent_vjp_bwd(eps, axis, vocab_total, block_r, block_v,
                                   dy, eps, vocab_total, block_r, block_v)
     # dx stays the PARTIAL sum of this shard's columns: x enters the
     # enclosing shard_map with the vocab axis unmentioned, and under
-    # check_rep=False the shard_map transpose itself psums such inputs'
+    # check_vma=False the shard_map transpose itself psums such inputs'
     # cotangents — an explicit psum here would double-count
     dlbl = np.zeros(lbl_local.shape, dtype=jax.dtypes.float0)
     return dx_p, dw, dlbl

@@ -17,7 +17,7 @@ fused_linear_xent lowerings:
    classify the layout — column-parallel, row-parallel, vocab-sharded,
    or replicated-weights-with-dp-sharded-rows,
 3. run the SAME custom_vjp kernel per shard inside ``shard_map`` with
-   matching in/out specs.  ``check_rep=False`` autodiff supplies the
+   matching in/out specs.  ``check_vma=False`` autodiff supplies the
    transpose-side psums for replicated operands; the only hand-written
    collectives are the mathematical ones (the row-parallel epilogue's
    partial-sum psum, the vocab-sharded xent's lse/gold/sum combine).
@@ -27,9 +27,11 @@ from the LOCAL shard shapes — a per-shard tuning search would attribute
 collective time to block sizes (the qvec precedent).
 
 Every wrapper returns None when it declines (no mesh, mp=1 and dp=1,
-weight name unresolvable, layout not divisible) and the caller falls
-back to the unwrapped kernel — at mp=1 that keeps the single-device
-trace BIT-IDENTICAL.
+weight name unresolvable, layout not divisible).  With no live mesh the
+caller then runs the unwrapped kernel — at mp=1 that keeps the
+single-device trace BIT-IDENTICAL; under a live mesh it lowers densely
+(pallas_kernels.use_pallas_unwrapped: XLA cannot partition a Mosaic
+custom call).
 """
 
 import jax
@@ -38,6 +40,7 @@ import jax.numpy as jnp
 __all__ = [
     "mesh_ctx", "op_weight_name", "spmd_matmul_bias_act",
     "spmd_matmul_swiglu", "spmd_add_layer_norm", "spmd_linear_xent",
+    "spmd_flash_attention",
 ]
 
 
@@ -99,7 +102,7 @@ def _shard_map(mesh, body, in_specs, out_specs):
     from ..parallel.mesh import shard_map
 
     return shard_map(body, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+                     out_specs=out_specs, check_vma=False)
 
 
 def spmd_matmul_bias_act(ctx, x2, w, bias, act):
@@ -296,3 +299,44 @@ def spmd_linear_xent(ctx, x2, w, labels, eps, transpose_w):
     return _shard_map(
         mesh, body, (P(row, None), P(None, None), P(row)),
         P(row, None))(x2, w, labels.reshape(R))
+
+
+def spmd_flash_attention(mc, q, k, v, kbias_b, seg_b, causal, scale, bq, bk,
+                         window):
+    """Mesh-aware flash_attention (the training path): attention is
+    independent across batch rows and heads, so the kernel runs per
+    device with rows over dp and heads over mp, each wherever it divides
+    (an axis that does not divide replicates — this form never
+    declines).  mc: a live mesh_ctx().  q/k/v: rank-4 [B, H, Tq|Tk, D];
+    kbias_b [B, Tk] and seg_b [B, T] are the PER-BATCH operands (or
+    None), spread over the local heads inside the body."""
+    from jax.sharding import PartitionSpec as P
+
+    from .pallas_kernels import flash_attention
+
+    mesh, _rules, mp, nsh, dp_axis, ndp = mc
+    b, h = q.shape[:2]
+    rows = _row_axis(dp_axis, ndp, b)
+    heads = mp if (nsh > 1 and h % nsh == 0) else None
+    p4, p2 = P(rows, heads, None, None), P(rows, None)
+    extras = [a for a in (kbias_b, seg_b) if a is not None]
+
+    def body(q4, k4, v4, *extra):
+        lb, lh, lt, ld = q4.shape
+        ltk = k4.shape[2]
+
+        def per_head(a):
+            return jnp.broadcast_to(
+                a[:, None, :], (lb, lh, a.shape[-1])).reshape(lb * lh, -1)
+
+        extra = list(extra)
+        kb = per_head(extra.pop(0)) if kbias_b is not None else None
+        sg = per_head(extra.pop(0)) if seg_b is not None else None
+        o = flash_attention(
+            q4.reshape(lb * lh, lt, ld), k4.reshape(lb * lh, ltk, ld),
+            v4.reshape(lb * lh, ltk, ld), kb, causal, scale, block_q=bq,
+            block_k=bk, window=window, seg=sg)
+        return o.reshape(lb, lh, lt, ld)
+
+    return _shard_map(mesh, body, (p4, p4, p4) + (p2,) * len(extras),
+                      p4)(q, k, v, *extras)

@@ -40,11 +40,8 @@ def _enable_cpu_cross_process_collectives():
     cross-process collectives implementation (gloo over TCP) — without it
     XLA rejects the computation outright ("Multiprocess computations
     aren't implemented on the CPU backend").  Must run BEFORE the backend
-    initializes; harmless on jax builds without the knob or on TPU."""
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except (AttributeError, ValueError):  # pragma: no cover - jax version
-        pass
+    initializes; the option only affects the CPU client."""
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
 
 def init_distributed_env(coordinator_address=None, num_processes=None, process_id=None):

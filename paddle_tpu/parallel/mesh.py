@@ -3,55 +3,24 @@ except the 'communicators' are implicit in XLA collectives over the mesh)."""
 
 import numpy as np
 import jax
+from jax import shard_map
 from jax.sharding import Mesh
-
-import functools as _functools
-
-try:  # jax >= 0.5 promoted shard_map to the top level
-    from jax import shard_map as _shard_map
-
-    @_functools.wraps(_shard_map)
-    def shard_map(*args, **kwargs):
-        # the promoted API renamed check_rep -> check_vma; translate so
-        # callers written against either name work on both branches
-        if "check_rep" in kwargs:
-            kwargs["check_vma"] = kwargs.pop("check_rep")
-        return _shard_map(*args, **kwargs)
-except ImportError:  # pre-promotion home (this sandbox's jax 0.4.x)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    @_functools.wraps(_shard_map)
-    def shard_map(*args, **kwargs):
-        # the old replication checker predates vma tracking: it has no
-        # rule for pallas_call and rejects cond branches the new checker
-        # accepts, so bodies written against the promoted API need it off
-        kwargs.setdefault("check_rep", False)
-        kwargs.pop("check_vma", None)
-        return _shard_map(*args, **kwargs)
 
 __all__ = ["make_mesh", "default_mesh", "mesh_axis_sizes", "dp_mesh",
            "shard_map", "vma_of", "pcast_varying"]
 
 
 def vma_of(*xs):
-    """Union of the inputs' varying-mesh-axes.  ``jax.typeof``/vma
-    tracking is a newer-jax API; on builds without it (this sandbox's
-    0.4.x) nothing is tracked and the set is empty."""
-    typeof = getattr(jax, "typeof", None)
+    """Union of the inputs' varying-mesh-axes (``jax.typeof(x).vma``)."""
     out = frozenset()
-    if typeof is None:
-        return out
     for x in xs:
-        out = out | getattr(typeof(x), "vma", frozenset())
+        out = out | jax.typeof(x).vma
     return out
 
 
 def pcast_varying(v, axes):
-    """``jax.lax.pcast(v, axes, to="varying")`` where available; identity
-    on jax builds without vma tracking (old shard_map's check_rep model
-    needs no explicit cast for a value to be device-varying)."""
-    pcast = getattr(jax.lax, "pcast", None)
-    return pcast(v, axes, to="varying") if pcast is not None else v
+    """Mark `v` device-varying over `axes` inside a shard_map body."""
+    return jax.lax.pcast(v, axes, to="varying")
 
 
 def make_mesh(axes, devices=None):

@@ -1000,7 +1000,7 @@ def build_pipeline_runtime(program, plan, mesh, scope, feed_arrays,
             device_step, mesh=mesh,
             in_specs=(feed_specs, ro_specs, dict(rw_specs), P()),
             out_specs=out_specs,
-            check_rep=False,
+            check_vma=False,
         )(feeds, ro_state, rw_state, rng_key)
         return [fetch_out[n] for n in fetch_names], new_state
 

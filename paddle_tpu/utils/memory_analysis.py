@@ -55,7 +55,7 @@ def _aval_bytes(aval):
 
 def _sub_jaxprs(val):
     """Yield any Jaxpr / ClosedJaxpr reachable from an eqn param value."""
-    import jax.core as jcore
+    import jax.extend.core as jcore
 
     vals = val if isinstance(val, (list, tuple)) else [val]
     for v in vals:
@@ -79,7 +79,7 @@ def jaxpr_peak_bytes(jaxpr, stream_outvars=True):
     Sub-jaxprs recurse with stream_outvars=False (a call's results must
     exist when it returns).  A call-like eqn adds its sub-jaxpr's own
     peak as a transient on top of the bytes live across it."""
-    import jax.core as jcore
+    import jax.extend.core as jcore
 
     jaxpr = jaxpr.jaxpr if isinstance(jaxpr, jcore.ClosedJaxpr) else jaxpr
     eqns = jaxpr.eqns

@@ -608,3 +608,78 @@ def test_prng_impl_flag_rbg():
         np.testing.assert_allclose(nz, nz[0], rtol=1e-6)  # 1/(1-p) scale
     np.testing.assert_allclose(rbg, rbg_loop)
     assert (rbg == 0).sum() != 0 and not np.array_equal(rbg == 0, fry == 0)
+
+
+def test_tpu_place_raises_on_a_host_without_tpu():
+    """TPUPlace never falls back to whatever backend exists: a program
+    that asked for the chip and silently ran on the host is how CPU
+    timings came to be recorded under TPU metric names."""
+    import pytest
+
+    import paddle_tpu as fluid
+
+    with pytest.raises(RuntimeError, match="no TPU"):
+        fluid.TPUPlace(0).jax_device()
+    assert isinstance(fluid.default_place(), fluid.CPUPlace)
+
+
+def test_compile_cache_placed_from_outside_or_at_the_fixed_path():
+    """One site places JAX's persistent compilation cache: a directory
+    named by the environment is left to JAX; otherwise the fixed
+    in-checkout path (part of the cache key, so it must not move); a
+    CPU-pinned process gets none."""
+    import os
+
+    import jax
+
+    from paddle_tpu import compile_cache as cc
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert cc.resolve_cache_dir({}) == (
+        os.path.join(root, ".jax_cache"), False)
+    assert cc.resolve_cache_dir({"JAX_PLATFORMS": "cpu"}) == (None, False)
+    assert cc.resolve_cache_dir(
+        {"JAX_COMPILATION_CACHE_DIR": "/x", "JAX_PLATFORMS": "cpu"}) == (
+            "/x", True)
+    before = jax.config.jax_compilation_cache_dir
+    assert cc.apply_compile_cache({"JAX_COMPILATION_CACHE_DIR": "/x"}) == "/x"
+    assert jax.config.jax_compilation_cache_dir == before  # untouched
+    # the tree has exactly one site that sets the option
+    import subprocess
+
+    found = subprocess.run(
+        ["grep", "-rl", "--include=*.py", "jax_compilation_cache_dir",
+         "paddle_tpu", "tools", "scripts", "examples", "bench.py",
+         "__graft_entry__.py"],
+        cwd=root, capture_output=True, text=True).stdout.split()
+    assert found == ["paddle_tpu/compile_cache.py"], found
+
+
+def _run_chip_smoke(*args):
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)
+    return subprocess.run(
+        [sys.executable, os.path.join(root, "chip_smoke.py")] + list(args),
+        env=env, cwd=root, capture_output=True, text=True, timeout=600)
+
+
+def test_chip_smoke_refuses_to_run_without_a_chip():
+    proc = _run_chip_smoke()
+    assert proc.returncode != 0
+    assert "needs a tpu" in proc.stdout
+    assert '"ok"' not in proc.stdout
+
+
+@pytest.mark.slow  # full rehearsal (~40 s); rides the ci.sh pallas lane
+def test_chip_smoke_rehearsal_runs_every_phase_and_never_passes():
+    proc = _run_chip_smoke("--rehearse")
+    assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-3000:]
+    assert "REHEARSAL ok" in proc.stdout
+    for phase in ("train", "numerics", "kernels"):
+        assert "-- %s ok" % phase in proc.stdout
+    assert '"ok"' not in proc.stdout

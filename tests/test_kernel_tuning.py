@@ -85,7 +85,9 @@ def test_autotune_off_is_consult_only(tmp_path):
 
 def test_candidate_errors_are_skipped():
     """A candidate whose measurement raises (illegal block shapes
-    surface as compile errors) is skipped, not fatal."""
+    surface as compile errors) is skipped and counted, not fatal —
+    unless EVERY candidate raises: then the search raises with the last
+    message instead of seeding the default silently."""
     flags.set_flags({"kernel_tune_cache": "", "kernel_autotune": True})
 
     def measure(p):
@@ -97,6 +99,17 @@ def test_candidate_errors_are_skipped():
                           [{"b": 1}, {"b": 3}, {"b": 2}], {"b": 9},
                           measure=measure)
     assert got == {"b": 2}
+    stats = kt.attribution()["tuning"]
+    assert stats["failed_candidates"] == 1
+    assert "mosaic says no" in stats["last_failure"]
+
+    with pytest.raises(RuntimeError, match="all 1 candidates.*mosaic says no"):
+        kt.tuned_params("k", [(16, 8)], "float32", [{"b": 1}], {"b": 9},
+                        measure=measure)
+    # the refusal is not cached as a seeded default: asking again raises
+    with pytest.raises(RuntimeError, match="mosaic says no"):
+        kt.tuned_params("k", [(16, 8)], "float32", [{"b": 1}], {"b": 9},
+                        measure=measure)
 
 
 def test_shape_bucket_rounds_leading_dims_only():
@@ -179,17 +192,15 @@ def test_seeded_entries_never_persist_alongside_searched(tmp_path):
     heuristic forever (the next process hits instead of re-searching)."""
     path = str(tmp_path / "tune.json")
     flags.set_flags({"kernel_tune_cache": path, "kernel_autotune": True})
-    # a search whose candidates ALL fail -> seeded fallback entry
-    kt.tuned_params("broken", [(8, 8)], "float32", [{"b": 1}], {"b": 7},
-                    measure=lambda p: (_ for _ in ()).throw(
-                        RuntimeError("transient")))
+    # a consult with nothing to search -> seeded default entry
+    kt.tuned_params("broken", [(8, 8)], "float32", [], {"b": 7})
     # a successful search elsewhere triggers the save
     kt.tuned_params("fine", [(8, 8)], "float32", [{"b": 2}], {"b": 9},
                     measure=lambda p: 1.0)
     raw = json.load(open(path))
     assert all(v.get("searched") for v in raw["entries"].values())
     assert not any("broken" in k for k in raw["entries"])
-    # a fresh process re-searches the failed kernel (now healthy)
+    # a fresh process searches the seeded kernel instead of hitting
     kt.clear_cache(forget_path=True)
     got = kt.tuned_params("broken", [(8, 8)], "float32", [{"b": 1}],
                           {"b": 7}, measure=lambda p: 1.0)

@@ -340,6 +340,8 @@ def test_program_flops_counts_fused_attention():
 
 
 def test_chip_peak_flops_lookup():
+    import pytest
+
     from paddle_tpu.utils import flops as fu
 
     class FakeDev:
@@ -353,6 +355,15 @@ def test_chip_peak_flops_lookup():
         device_kind = "cpu"
 
     assert fu.chip_peak_flops(CpuDev()) is None
+
+    class UnknownChip:
+        platform = "tpu"
+        device_kind = "TPU v99 imaginary"
+
+    # an unknown accelerator is an error, not a None that lets a run
+    # report throughput under a device metric name with no peak behind it
+    with pytest.raises(ValueError, match="v99 imaginary"):
+        fu.chip_peak_flops(UnknownChip())
 
 
 def test_py_reader_pipeline_error_surfaces():

@@ -224,7 +224,9 @@ def test_bf16_amp_composes_with_mp():
     close to the unsharded bf16 run."""
     base, _, _, _ = _train(None, use_bf16=True)
     got, sc, _, _ = _train(make_mesh({"dp": 2, "mp": 2}), use_bf16=True)
-    np.testing.assert_allclose(got, base, rtol=1e-4)
+    # bf16 rounds at 4e-3; a sharded reduction order moves the last
+    # step by one rounding (1.3e-4 under jax 0.9.0's XLA)
+    np.testing.assert_allclose(got, base, rtol=1e-3)
     rules = train_partition_rules_for("gpt2")
     casts = [n for n in sc.all_var_names() if "@RAW_BF16" in n
              and "ffn_in.w" in n]
@@ -232,3 +234,41 @@ def test_bf16_amp_composes_with_mp():
         assert _spec_of(sc, n) == _spec_of(sc, rules.base_name(n)), n
 
 
+@pytest.mark.slow  # an interpret-mode compile at lane-legal widths (~25 s)
+@needs_four_devices
+def test_sharded_train_step_lowers_for_tpu_without_chips(monkeypatch):
+    """XLA cannot partition a Mosaic custom call: under a live mesh every
+    kernel must sit inside a shard_map (or its op must lower densely).
+    Interpret mode lowers kernels to plain HLO and never notices — the
+    first four-chip run refused the unwrapped train flash_attention with
+    "Mosaic kernels cannot be automatically partitioned".  Cross-lowering
+    the sharded step for the TPU platform runs that check on the CPU
+    host, for every dispatch site the transformer step reaches."""
+    import chip_smoke
+    from paddle_tpu.models import transformer as tfm
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    class HP(tfm.ModelHyperParams):  # small, but Mosaic-legal blocks
+        d_model, d_inner_hid, n_head, n_layer = 256, 512, 2, 1
+        src_vocab_size = trg_vocab_size = 1000
+        max_length = 128
+        fused_attn = True
+
+    _fresh()
+    mesh = make_mesh({"dp": 2, "mp": 2}, jax.devices()[:4])
+    autotune = flags.get_flag("kernel_autotune")  # train() pins it off
+    try:
+        r = chip_smoke.train(
+            HP, tfm.make_fake_batch(8, 128, 128, HP, seed=0), 128,
+            fluid.CPUPlace(), True, steps=1, mesh=mesh)
+    finally:
+        flags.set_flags({"kernel_autotune": autotune})
+    hits = r["attribution"]["pallas_hits"]
+    for fam in ("attention", "layernorm", "matmul_epilogue", "xent"):
+        assert hits.get(fam, 0) > 0, hits  # dispatched, not dense
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    jax.clear_caches()  # the interpreted trace must not be reused
+    (_traced, jitted, _sh, avals), = r["exe"]._spmd_cache.values()
+    text = jitted.trace(*avals[0]).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") > 0

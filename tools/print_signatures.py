@@ -76,6 +76,12 @@ def iter_signatures():
             obj = getattr(mod, name, None)
             if obj is None or inspect.ismodule(obj):
                 continue
+            # a re-exported third-party symbol (jax's PartitionSpec as
+            # partition_rules.P) is not this package's API to pin: its
+            # signature moves with the installed library
+            if not (getattr(obj, "__module__", None) or "").startswith(
+                    "paddle_tpu"):
+                continue
             if inspect.isclass(obj):
                 yield "%s.%s %s" % (modname, name, _sig(obj.__init__))
                 for mname, meth in sorted(vars(obj).items()):
