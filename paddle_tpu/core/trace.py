@@ -19,6 +19,7 @@ reads (``ro_state``) are not donated and stay valid across steps.
 import jax
 import jax.numpy as jnp
 
+from ..profiler import RecordEvent
 from .registry import OPS, LowerCtx, get_op, lower_grad_op
 from .selected_rows import SelectedRows, densify_maybe
 
@@ -217,7 +218,7 @@ def build_traced_function(program, block_idx, feed_names, fetch_names, scope,
     ro_names = [n for n in state_names if n not in set(updated)]
     is_test = getattr(program, "_is_test", False)
 
-    def fn(feeds, ro_state, rw_state, rng_key):
+    def program_step(feeds, ro_state, rw_state, rng_key):
         if collective_axis is not None:
             from ..parallel.collective import collective_lowering
 
@@ -294,86 +295,95 @@ def build_traced_function(program, block_idx, feed_names, fetch_names, scope,
         snapshots = {}
 
         def trace_ops(bidx, env):
+            """Lower a block's ops in order, each under the named scope
+            `<op_role>/<op type>/<index in its block>`: the scope reaches
+            the optimized HLO's `op_name` and the device trace, so a
+            fused instruction can be traced back to the Fluid ops in it.
+            Sub-block ops nest under their parent op's scope.  Scopes act
+            at trace time only: a compiled step pays nothing for them."""
             blk = program.block(bidx)
             for idx, op in enumerate(blk.ops):
                 if op.type in ("feed", "fetch", "read", "create_py_reader"):
                     continue  # satisfied as implicit feeds / host state
                 if bidx == block_idx and not keep[idx]:
                     continue
-                ctx.op_idx = (bidx << 20) | idx
-                ctx.block = blk
-                if op.type == "while":
-                    env = trace_while(op, env)
-                    continue
-                if op.type == "cond":
-                    env = trace_cond(op, env)
-                    continue
-                is_grad = op.type.endswith("_grad") and "__fwd_type__" in op.attrs
-                snap = None
-                if is_grad:
-                    snap = snapshots.get((bidx, op.attrs.get("__fwd_op_idx__")))
-                elif set(op.output_arg_names()) & set(op.input_arg_names()):
-                    snapshots[(bidx, idx)] = {
-                        n: env[n] for n in op.input_arg_names() if n in env
-                    }
-                ins = {}
-                for slot, names in op.inputs.items():
-                    vals = []
-                    use_snap = snap if not slot.endswith("@GRAD") else None
-                    for n in names:
-                        if use_snap is not None and n in use_snap:
-                            vals.append(use_snap[n])
-                            continue
-                        if n not in env:
-                            raise RuntimeError(
-                                "op %s reads undefined var %s" % (op.type, n)
-                            )
-                        vals.append(env[n])
-                    ins[slot] = vals
-                try:
-                    opdef = OPS.get(op.type)
-                    # SelectedRows inputs densify automatically for ops that
-                    # don't declare native support (reference: kernels not
-                    # specialized on SELECTED_ROWS see a dense tensor)
-                    if any(
-                        isinstance(v, SelectedRows)
-                        for vals in ins.values() for v in vals
-                    ) and not (opdef is not None
-                               and opdef.handles_selected_rows):
-                        ins = {
-                            s: [densify_maybe(v) for v in vals]
-                            for s, vals in ins.items()
-                        }
-                    if opdef is not None:
-                        outs = opdef.lower(ctx, ins, op.attrs)
-                    elif (op.type.endswith("_grad")
-                          and "__fwd_type__" in op.attrs):
-                        outs = lower_grad_op(ctx, op, ins, op.attrs)
-                    else:
-                        outs = get_op(op.type).lower(ctx, ins, op.attrs)
-                except Exception as e:
-                    # PADDLE_ENFORCE-style error context (enforce.h): name
-                    # the op and its inputs so a shape/dtype error inside a
-                    # compiled block is attributable without reading XLA
-                    # internals.  Tracer-context errors pass through.
-                    if isinstance(e, _TraceContextError):
-                        raise
-                    shapes = {
-                        slot: [getattr(v, "shape", "?") for v in vals]
-                        for slot, vals in ins.items()
-                    }
-                    raise _TraceContextError(
-                        "while lowering op '%s' (block %d, op %d) with input "
-                        "shapes %s: %s: %s"
-                        % (op.type, bidx, idx, shapes, type(e).__name__, e)
-                    ) from e
-                for slot, names in op.outputs.items():
-                    vals = outs.get(slot)
-                    if vals is None:
+                with jax.named_scope("%s/%s/%d" % (
+                        op.attrs.get("op_role", "forward"), op.type, idx)):
+                    env = trace_op(blk, bidx, idx, op, env)
+            return env
+
+        def trace_op(blk, bidx, idx, op, env):
+            ctx.op_idx = (bidx << 20) | idx
+            ctx.block = blk
+            if op.type == "while":
+                return trace_while(op, env)
+            if op.type == "cond":
+                return trace_cond(op, env)
+            is_grad = op.type.endswith("_grad") and "__fwd_type__" in op.attrs
+            snap = None
+            if is_grad:
+                snap = snapshots.get((bidx, op.attrs.get("__fwd_op_idx__")))
+            elif set(op.output_arg_names()) & set(op.input_arg_names()):
+                snapshots[(bidx, idx)] = {
+                    n: env[n] for n in op.input_arg_names() if n in env
+                }
+            ins = {}
+            for slot, names in op.inputs.items():
+                vals = []
+                use_snap = snap if not slot.endswith("@GRAD") else None
+                for n in names:
+                    if use_snap is not None and n in use_snap:
+                        vals.append(use_snap[n])
                         continue
-                    for n, v in zip(names, vals):
-                        if n and v is not None:
-                            env[n] = v
+                    if n not in env:
+                        raise RuntimeError(
+                            "op %s reads undefined var %s" % (op.type, n)
+                        )
+                    vals.append(env[n])
+                ins[slot] = vals
+            try:
+                opdef = OPS.get(op.type)
+                # SelectedRows inputs densify automatically for ops that
+                # don't declare native support (reference: kernels not
+                # specialized on SELECTED_ROWS see a dense tensor)
+                if any(
+                    isinstance(v, SelectedRows)
+                    for vals in ins.values() for v in vals
+                ) and not (opdef is not None
+                           and opdef.handles_selected_rows):
+                    ins = {
+                        s: [densify_maybe(v) for v in vals]
+                        for s, vals in ins.items()
+                    }
+                if opdef is not None:
+                    outs = opdef.lower(ctx, ins, op.attrs)
+                elif is_grad:
+                    outs = lower_grad_op(ctx, op, ins, op.attrs)
+                else:
+                    outs = get_op(op.type).lower(ctx, ins, op.attrs)
+            except Exception as e:
+                # PADDLE_ENFORCE-style error context (enforce.h): name
+                # the op and its inputs so a shape/dtype error inside a
+                # compiled block is attributable without reading XLA
+                # internals.  Tracer-context errors pass through.
+                if isinstance(e, _TraceContextError):
+                    raise
+                shapes = {
+                    slot: [getattr(v, "shape", "?") for v in vals]
+                    for slot, vals in ins.items()
+                }
+                raise _TraceContextError(
+                    "while lowering op '%s' (block %d, op %d) with input "
+                    "shapes %s: %s: %s"
+                    % (op.type, bidx, idx, shapes, type(e).__name__, e)
+                ) from e
+            for slot, names in op.outputs.items():
+                vals = outs.get(slot)
+                if vals is None:
+                    continue
+                for n, v in zip(names, vals):
+                    if n and v is not None:
+                        env[n] = v
             return env
 
         ctx.trace_block = trace_ops
@@ -387,15 +397,16 @@ def build_traced_function(program, block_idx, feed_names, fetch_names, scope,
         new_state = {n: densify_maybe(env[n]) for n in updated if n in env}
         return fetches, new_state
 
-    return TracedFunction(fn, list(feed_names), ro_names, rw_names, fetch_names, updated)
+    return TracedFunction(program_step, list(feed_names), ro_names, rw_names, fetch_names, updated)
 
 
 class CompiledBlock:
     """One XLA executable for (program version, block, signature)."""
 
-    def __init__(self, traced, jitted):
+    def __init__(self, traced, jitted, feed_sig):
         self.traced = traced
         self.jitted = jitted
+        self.feed_sig = feed_sig
         # abstract signature of the first call, so Executor.compiled_hlo
         # can AOT-lower the same executable later
         self.avals = None
@@ -404,6 +415,14 @@ class CompiledBlock:
         if self.avals is None:
             self.avals = call_avals((feeds, ro_state, rw_state, rng_key))
         return self.jitted(feeds, ro_state, rw_state, rng_key)
+
+
+def sig_text(feed_sig):
+    """A feed signature ((name, shape, dtype), ...) as one short string:
+    the argument of a trace_compile span, so that a recompile names its
+    cause in the trace."""
+    return " ".join("%s:%s%s" % (n, dt, list(shape))
+                    for n, shape, dt in feed_sig)
 
 
 def call_avals(args):
@@ -448,12 +467,17 @@ class ExecutionCache:
         if hit is not None:
             return hit
         self.compile_count += 1
-        feed_names = tuple(n for n, _, _ in feed_sig)
-        traced = build_traced_function(
-            program, block_idx, feed_names, fetch_names, scope
-        )
-        jitted = jax.jit(traced.fn, donate_argnums=(2,) if donate else ())
-        compiled = CompiledBlock(traced, jitted)
+        # the miss analyses the block; tracing and compiling wait for the
+        # executable's first call, which the Executor spans under the
+        # same name
+        with RecordEvent("trace_compile", feed_sig=sig_text(feed_sig)):
+            feed_names = tuple(n for n, _, _ in feed_sig)
+            traced = build_traced_function(
+                program, block_idx, feed_names, fetch_names, scope
+            )
+            jitted = jax.jit(traced.fn,
+                             donate_argnums=(2,) if donate else ())
+            compiled = CompiledBlock(traced, jitted, feed_sig)
         self._cache[key] = compiled
         return compiled
 

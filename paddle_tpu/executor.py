@@ -7,13 +7,15 @@ Feed dict entries become function arguments; fetch vars become outputs; no
 feed/fetch ops or feed-variable side channel are needed.
 """
 
+import time
+
 import numpy as np
 import jax
 import jax.numpy as jnp
 
 from . import framework
 from .core import scope as scope_mod
-from .core.trace import ExecutionCache, call_avals
+from .core.trace import ExecutionCache, call_avals, sig_text
 from .places import CPUPlace, default_place
 from .profiler import RecordEvent
 
@@ -21,8 +23,6 @@ __all__ = ["Executor", "global_scope", "scope_guard"]
 
 global_scope = scope_mod.global_scope
 scope_guard = scope_mod.scope_guard
-
-_FAST_MISS = object()  # sentinel: fast-path preconditions broke, go slow
 
 def as_numpy(value):
     """Fetch result -> numpy (executor.py:66 analog)."""
@@ -41,6 +41,24 @@ def _dtype_kind(dt):
         return "f"
     k = np.dtype(str(dt)).kind
     return "f" if k == "V" else k
+
+
+class CompiledStep:
+    """One executable an Executor has run for a program
+    (Executor.compiled_steps): the run path that owns it ("flat" for
+    _run_fast / _run_slow, "spmd"), the feeds of its first call as
+    {name: (shape, dtype)}, its fetch names, and `hlo()`, its optimized
+    HLO text, AOT-lowered again at that first call's signature."""
+
+    def __init__(self, path, traced, jitted, avals):
+        self.path = path
+        self.feeds = {n: (tuple(a.shape), str(a.dtype))
+                      for n, a in avals[0].items()}
+        self.fetches = list(traced.fetch_names)
+        self._jitted, self._avals = jitted, avals
+
+    def hlo(self):
+        return self._jitted.lower(*self._avals).compile().as_text()
 
 
 class Executor:
@@ -88,6 +106,52 @@ class Executor:
         arr = jax.device_put(v, device)
         scope.set(n, arr)
         return arr
+
+    # ---- the spans of one run() call ------------------------------------
+    # All five run paths open the same set through these helpers, nested
+    # under run()'s outer `executor.run` span: feed_upload, state_gather,
+    # executor_run (trace_compile inside it on a new executable's first
+    # call), state_commit, fetch_to_host.  PERF.md section 3 names the
+    # metric that reads each.
+    def _upload(self, stage):
+        """feed_upload: `stage()` puts the feeds on the device; its wall
+        time also accumulates in host_feed_ms."""
+        t0 = time.perf_counter()
+        with RecordEvent("feed_upload", cat="feed"):
+            feed_arrays = stage()
+        self._host_feed_ms += (time.perf_counter() - t0) * 1e3
+        return feed_arrays
+
+    def _gather(self, commit, ro_names, rw_names):
+        """state_gather: (ro_state, rw_state), every state variable the
+        step reads as `commit(name)` leaves it on the device."""
+        with RecordEvent("state_gather"):
+            return ({n: commit(n) for n in ro_names},
+                    {n: commit(n) for n in rw_names})
+
+    def _dispatch(self, jitted, args, new_sig=None):
+        """executor_run: the jitted call, i.e. dispatch.  `new_sig` is the
+        feed signature of an executable that has not run yet: its first
+        call traces and compiles, under trace_compile."""
+        with RecordEvent("executor_run"):
+            if new_sig is None:
+                return jitted(*args)
+            with RecordEvent("trace_compile", feed_sig=sig_text(new_sig)):
+                return jitted(*args)
+
+    def _commit(self, scope, new_state):
+        """state_commit: the step's updated state back into the scope."""
+        with RecordEvent("state_commit"):
+            for n, v in new_state.items():
+                scope.set(n, v)
+
+    def _fetched(self, fetches, return_numpy, to_numpy=as_numpy):
+        """The run's result; fetch_to_host only where the caller asked
+        for numpy (the device-to-host wait of the whole step)."""
+        if not return_numpy:
+            return list(fetches)
+        with RecordEvent("fetch_to_host"):
+            return [to_numpy(f) for f in fetches]
 
     def _rng_base(self, program):
         # base key derives from the program's seed (per-program, so
@@ -186,84 +250,78 @@ class Executor:
         # stamps program._collective): the step runs under shard_map over
         # a dp mesh so its c_allreduce_* ops lower to real collectives
         coll = getattr(program, "_collective", None)
-        if coll is not None:
-            return self._run_collective(program, feed, fetch_names, scope,
-                                        return_numpy, coll)
         # pipeline-stamped program (transpiler.pipeline.pipeline_program):
         # the stage-sliced schedule runs as one jitted shard_map step over
         # the dp×mp×pp mesh, params/optimizer state packed per-stage
         pp = getattr(program, "_pipeline", None)
-        if pp is not None:
-            return self._run_pipeline(program, feed, fetch_names, scope,
-                                      return_numpy, pp)
         # GSPMD-stamped program (parallel.partition_rules.annotate_spmd):
         # persistables place per the partition-rule table and the traced
         # step jits with those shardings — the tensor-parallel serving
         # pool's execution path
         spmd = getattr(program, "_spmd", None)
-        if spmd is not None:
-            return self._run_spmd(program, feed, fetch_names, scope,
-                                  return_numpy, spmd)
-        # steady-state fast path: everything the slow path re-derives per
-        # step — the listen_and_serv/reader op scans, per-feed var lookup
-        # + dtype-kind guard, the sorted feed-signature tuple, and the
-        # compile-cache hash — is memoized per (program version,
-        # feed-keys, fetches, scope).  The memo only validates that each
-        # feed still matches the recorded (shape, dtype); any surprise
-        # falls back to the full path, which refreshes the memo.
-        fast_key = (id(program), program._version, id(scope),
-                    tuple(fetch_names), tuple(sorted(feed)))
-        entry = self._run_cache.get(fast_key)
-        if entry is not None:
-            out = self._run_fast(entry, program, feed, fetch_names, scope,
-                                 return_numpy)
-            if out is not _FAST_MISS:
-                return out
-        return self._run_slow(program, feed, fetch_names, scope,
-                              return_numpy, fast_key)
+        if coll is not None:
+            path, runner, how = "collective", self._run_collective, coll
+        elif pp is not None:
+            path, runner, how = "pipeline", self._run_pipeline, pp
+        elif spmd is not None:
+            path, runner, how = "spmd", self._run_spmd, spmd
+        else:
+            # steady-state fast path: everything the slow path re-derives
+            # per step — the listen_and_serv/reader op scans, per-feed var
+            # lookup + dtype-kind guard, the sorted feed-signature tuple,
+            # and the compile-cache hash — is memoized per (program
+            # version, feed-keys, fetches, scope).  The memo only
+            # validates that each feed still matches the recorded (shape,
+            # dtype); any surprise takes the full path, which refreshes
+            # the memo.
+            fast_key = (id(program), program._version, id(scope),
+                        tuple(fetch_names), tuple(sorted(feed)))
+            entry = self._run_cache.get(fast_key)
+            if entry is not None and self._fast_entry_holds(entry, feed):
+                path, runner, how = "fast", self._run_fast, entry
+            else:
+                path, runner, how = "slow", self._run_slow, fast_key
+        with RecordEvent("executor.run", path=path):
+            return runner(program, feed, fetch_names, scope, return_numpy,
+                          how)
 
-    def _run_fast(self, entry, program, feed, fetch_names, scope,
-                  return_numpy):
+    @staticmethod
+    def _fast_entry_holds(entry, feed):
+        """The fast path's preconditions: lowering flags as recorded, and
+        every feed a plain array of the recorded (shape, dtype) — a
+        LoDTensor or list feed takes the slow path."""
         from .flags import get_flag
 
         if (bool(get_flag("use_pallas")),
                 get_flag("prng_impl")) != entry["flags"]:
-            return _FAST_MISS  # lowering flags flipped: recompile path
-        device = entry["device"]
+            return False  # lowering flags flipped: recompile path
         spec = entry["feed_spec"]
-        import time as _time
+        return all(
+            isinstance(value, (np.ndarray, jax.Array))
+            and (tuple(value.shape), str(value.dtype)) == spec.get(name)
+            for name, value in feed.items())
 
-        t0 = _time.perf_counter()
-        feed_arrays = {}
-        with RecordEvent("feed_upload", cat="feed"):
+    def _run_fast(self, program, feed, fetch_names, scope, return_numpy,
+                  entry):
+        device = entry["device"]
+
+        def stage():
+            feed_arrays = {}
             for name, value in feed.items():
-                want = spec.get(name)
-                shape = getattr(value, "shape", None)
-                dtype = getattr(value, "dtype", None)
-                if (want is None or shape is None or dtype is None
-                        or (tuple(shape), str(dtype)) != want):
-                    return _FAST_MISS
-                if isinstance(value, jax.Array):
-                    if (getattr(value, "committed", True)
-                            and device in value.devices()):
-                        feed_arrays[name] = value  # pre-staged (prefetch)
-                    else:
-                        feed_arrays[name] = jax.device_put(value, device)
-                elif isinstance(value, np.ndarray):
-                    feed_arrays[name] = jax.device_put(value, device)
+                if (isinstance(value, jax.Array)
+                        and getattr(value, "committed", True)
+                        and device in value.devices()):
+                    feed_arrays[name] = value  # pre-staged (prefetch)
                 else:
-                    return _FAST_MISS  # LoDTensor / list feeds: slow path
-        self._host_feed_ms += (_time.perf_counter() - t0) * 1e3
+                    feed_arrays[name] = jax.device_put(value, device)
+            return feed_arrays
+
+        feed_arrays = self._upload(stage)
         compiled = entry["compiled"]
         traced = compiled.traced
-        ro_state = {}
-        for n in traced.ro_names:
-            ro_state[n] = self._commit_state(n, scope.find_var(n), device,
-                                             scope)
-        rw_state = {}
-        for n in traced.rw_names:
-            rw_state[n] = self._commit_state(n, scope.find_var(n), device,
-                                             scope)
+        ro_state, rw_state = self._gather(
+            lambda n: self._commit_state(n, scope.find_var(n), device, scope),
+            traced.ro_names, traced.rw_names)
         return self._finish_run(compiled, feed_arrays, ro_state, rw_state,
                                 program, fetch_names, scope, return_numpy)
 
@@ -310,12 +368,8 @@ class Executor:
         self._maybe_verify_program(program, feed, fetch_names, scope)
 
         device = self.place.jax_device()
-        import time as _time
-
-        t0 = _time.perf_counter()
-        with RecordEvent("feed_upload", cat="feed"):
-            feed_arrays = self._prepare_feed(program, feed, device)
-        self._host_feed_ms += (_time.perf_counter() - t0) * 1e3
+        feed_arrays = self._upload(
+            lambda: self._prepare_feed(program, feed, device))
 
         # in-program readers: satisfy `read` op outputs from the staged
         # device queue (create_py_reader/double_buffer analog — host IO
@@ -344,15 +398,9 @@ class Executor:
         )
         compiled = self._cache.get(program, 0, feed_sig, fetch_names, scope)
         traced = compiled.traced
-
-        ro_state = {}
-        for n in traced.ro_names:
-            ro_state[n] = self._commit_state(n, scope.find_var(n), device,
-                                             scope)
-        rw_state = {}
-        for n in traced.rw_names:
-            rw_state[n] = self._commit_state(n, scope.find_var(n), device,
-                                             scope)
+        ro_state, rw_state = self._gather(
+            lambda n: self._commit_state(n, scope.find_var(n), device, scope),
+            traced.ro_names, traced.rw_names)
 
         # memoize for the steady-state fast path — only shapes the fast
         # path can fully re-validate (plain array feeds, no reader ops)
@@ -384,19 +432,18 @@ class Executor:
         from .flags import get_flag
 
         key = self._rng_key(program)
-        import time as _time
-
-        t0 = _time.time()
-        with RecordEvent("executor_run"):
-            fetches, new_state = compiled(feed_arrays, ro_state, rw_state, key)
-        if get_flag("benchmark"):
+        timed = get_flag("benchmark")
+        t0 = time.time() if timed else None
+        fetches, new_state = self._dispatch(
+            compiled, (feed_arrays, ro_state, rw_state, key),
+            new_sig=compiled.feed_sig if compiled.avals is None else None)
+        if timed:
             # FLAGS_benchmark contract: per-run timing log with a device
             # barrier so the number is real
             jax.block_until_ready(fetches if fetches else list(new_state.values()))
-            print("[benchmark] run %.3f ms" % ((_time.time() - t0) * 1e3))
+            print("[benchmark] run %.3f ms" % ((time.time() - t0) * 1e3))
 
-        for n, v in new_state.items():
-            scope.set(n, v)
+        self._commit(scope, new_state)
 
         if get_flag("check_nan_inf"):
             # FLAGS_check_nan_inf contract (operator.cc:688): raise on any
@@ -418,9 +465,7 @@ class Executor:
             if return_numpy:
                 return np_fetches
 
-        if return_numpy:
-            return [as_numpy(f) for f in fetches]
-        return list(fetches)
+        return self._fetched(fetches, return_numpy)
 
     # ---- GSPMD (tensor-parallel mesh) run path --------------------------
     def _spmd_state_sharding(self, program, mesh, rules, name, scope):
@@ -451,8 +496,6 @@ class Executor:
         signature, counted in compile_count like every other path), and
         row math stays row-independent under sharding (heads-axis splits
         never mix slots), so pooled == solo bit-for-bit."""
-        import time as _time
-
         from jax.sharding import NamedSharding, PartitionSpec
 
         from .flags import get_flag
@@ -480,13 +523,12 @@ class Executor:
                                           + (None,) * (a.ndim - 1))))
             return repl
 
-        t0 = _time.perf_counter()
-        feed_np = {n: np.asarray(v) for n, v in feed.items()}
-        with RecordEvent("feed_upload", cat="feed"):
-            feed_arrays = {n: jax.device_put(a, feed_sharding(a))
-                           for n, a in feed_np.items()}
-        self._host_feed_ms += (_time.perf_counter() - t0) * 1e3
+        def stage():  # through host numpy: the round trip is in the span
+            feed_np = {n: np.asarray(v) for n, v in feed.items()}
+            return {n: jax.device_put(a, feed_sharding(a))
+                    for n, a in feed_np.items()}
 
+        feed_arrays = self._upload(stage)
         feed_sig = tuple(sorted(
             (n, tuple(a.shape), str(a.dtype))
             for n, a in feed_arrays.items()))
@@ -503,24 +545,26 @@ class Executor:
             # a fresh trace+compile: count it where the engine's
             # no-retrace contract looks (Executor.compile_count)
             self._cache.compile_count += 1
-            traced = build_traced_function(
-                program, 0, tuple(n for n, _, _ in feed_sig), fetch_names,
-                scope, spmd=(mesh, rules))
-            sh = {n: self._spmd_state_sharding(program, mesh, rules, n,
-                                              scope)
-                  for n in set(traced.ro_names) | set(traced.rw_names)
-                  | set(traced.updated)}
-            jitted = jax.jit(
-                traced.fn,
-                in_shardings=(
-                    {n: feed_arrays[n].sharding for n in feed_arrays},
-                    {n: sh[n] for n in traced.ro_names},
-                    {n: sh[n] for n in traced.rw_names},
-                    repl,
-                ),
-                out_shardings=(None, {n: sh[n] for n in traced.updated}),
-                donate_argnums=(2,),
-            )
+            with RecordEvent("trace_compile", feed_sig=sig_text(feed_sig)):
+                traced = build_traced_function(
+                    program, 0, tuple(n for n, _, _ in feed_sig),
+                    fetch_names, scope, spmd=(mesh, rules))
+                sh = {n: self._spmd_state_sharding(program, mesh, rules, n,
+                                                  scope)
+                      for n in set(traced.ro_names) | set(traced.rw_names)
+                      | set(traced.updated)}
+                jitted = jax.jit(
+                    traced.fn,
+                    in_shardings=(
+                        {n: feed_arrays[n].sharding for n in feed_arrays},
+                        {n: sh[n] for n in traced.ro_names},
+                        {n: sh[n] for n in traced.rw_names},
+                        repl,
+                    ),
+                    out_shardings=(None,
+                                   {n: sh[n] for n in traced.updated}),
+                    donate_argnums=(2,),
+                )
             # avals[0] records the first call's abstract args so
             # compiled_hlo can AOT-lower the same signature later
             entry = cache[key_id] = (traced, jitted, sh, [None])
@@ -535,19 +579,17 @@ class Executor:
             scope.set(n, arr)
             return arr
 
-        ro_state = {n: commit(n) for n in traced.ro_names}
-        rw_state = {n: commit(n) for n in traced.rw_names}
+        ro_state, rw_state = self._gather(commit, traced.ro_names,
+                                          traced.rw_names)
         key = jax.device_put(self._rng_key(program), repl)
-        if avals[0] is None:
-            avals[0] = call_avals((feed_arrays, ro_state, rw_state, key))
-        with RecordEvent("executor_run"):
-            fetches, new_state = jitted(feed_arrays, ro_state, rw_state,
-                                        key)
-        for n, v in new_state.items():
-            scope.set(n, v)
-        if return_numpy:
-            return [as_numpy(f) for f in fetches]
-        return list(fetches)
+        args = (feed_arrays, ro_state, rw_state, key)
+        first = avals[0] is None
+        if first:
+            avals[0] = call_avals(args)
+        fetches, new_state = self._dispatch(
+            jitted, args, new_sig=feed_sig if first else None)
+        self._commit(scope, new_state)
+        return self._fetched(fetches, return_numpy)
 
     def _run_pipeline(self, program, feed, fetch_names, scope, return_numpy,
                       pp):
@@ -561,8 +603,6 @@ class Executor:
         schedule counters) stays replicated and mirrors to the scope each
         step like every other path.  One compile per feed signature
         (compile_count accounts it); steady-state steps never retrace."""
-        import time as _time
-
         from jax.sharding import NamedSharding, PartitionSpec
 
         from .flags import get_flag
@@ -582,13 +622,12 @@ class Executor:
                                           + (None,) * (a.ndim - 1))))
             return repl
 
-        t0 = _time.perf_counter()
-        feed_np = {n: np.asarray(v) for n, v in feed.items()}
-        with RecordEvent("feed_upload", cat="feed"):
-            feed_arrays = {n: jax.device_put(a, feed_sharding(a))
-                           for n, a in feed_np.items()}
-        self._host_feed_ms += (_time.perf_counter() - t0) * 1e3
+        def stage():
+            feed_np = {n: np.asarray(v) for n, v in feed.items()}
+            return {n: jax.device_put(a, feed_sharding(a))
+                    for n, a in feed_np.items()}
 
+        feed_arrays = self._upload(stage)
         feed_sig = tuple(sorted(
             (n, tuple(a.shape), str(a.dtype))
             for n, a in feed_arrays.items()))
@@ -599,7 +638,8 @@ class Executor:
                   tuple(fetch_names), id(scope),
                   bool(get_flag("use_pallas")), get_flag("prng_impl"))
         entry = cache.get(key_id)
-        if entry is None:
+        first = entry is None
+        if first:
             from .transpiler.pipeline import (build_pipeline_runtime,
                                               flush_pipeline_state)
 
@@ -608,15 +648,16 @@ class Executor:
             # signature re-packs, or it would train from stale weights
             flush_pipeline_state(program, scope)
             self._cache.compile_count += 1
-            runtime = build_pipeline_runtime(
-                program, plan, mesh, scope, feed_arrays, fetch_names)
-            entry = cache[key_id] = {
-                "runtime": runtime,
-                "state": runtime.pack_state(scope),
-            }
-            for n in runtime.shared_rw:
-                entry["state"][n] = jax.device_put(
-                    np.asarray(scope.find_var(n)), repl)
+            with RecordEvent("trace_compile", feed_sig=sig_text(feed_sig)):
+                runtime = build_pipeline_runtime(
+                    program, plan, mesh, scope, feed_arrays, fetch_names)
+                entry = cache[key_id] = {
+                    "runtime": runtime,
+                    "state": runtime.pack_state(scope),
+                }
+                for n in runtime.shared_rw:
+                    entry["state"][n] = jax.device_put(
+                        np.asarray(scope.find_var(n)), repl)
         runtime = entry["runtime"]
 
         def commit(n):
@@ -629,36 +670,44 @@ class Executor:
             return arr
 
         feeds = {n: feed_arrays[n] for n in runtime.feed_shardings}
-        ro_state = {n: commit(n) for n in runtime.shared_ro}
-        rw_state = entry["state"]
+        # stage-owned state stays packed in the entry: only the shared
+        # read-only variables are gathered, only the shared read-write
+        # ones mirror back to the scope
+        ro_state, _ = self._gather(commit, runtime.shared_ro, ())
         key = jax.device_put(self._rng_key(program), repl)
-        with RecordEvent("executor_run"):
-            fetches, new_state = runtime.jitted(feeds, ro_state, rw_state,
-                                                key)
+        fetches, new_state = self._dispatch(
+            runtime.jitted, (feeds, ro_state, entry["state"], key),
+            new_sig=feed_sig if first else None)
         entry["state"] = new_state
         program._pipeline_runtime = entry
-        for n in runtime.shared_rw:
-            scope.set(n, new_state[n])
-        if return_numpy:
-            return [as_numpy(f) for f in fetches]
-        return list(fetches)
+        self._commit(scope, {n: new_state[n] for n in runtime.shared_rw})
+        return self._fetched(fetches, return_numpy)
+
+    def compiled_steps(self, program):
+        """One CompiledStep per executable this executor has run for
+        `program` on the flat and GSPMD paths: which path, the feeds
+        (names, shapes, dtypes) and fetch names of the run that made it —
+        what a reader needs to run the same step again and hit the same
+        executable — and its optimized HLO on demand."""
+        steps = [CompiledStep("flat", cb.traced, cb.jitted, cb.avals)
+                 for cb in self._cache.blocks_for(program)
+                 if cb.avals is not None]
+        for key, (traced, jitted, _sh, avals) in (
+                getattr(self, "_spmd_cache", None) or {}).items():
+            if key[0] == id(program) and avals[0] is not None:
+                steps.append(CompiledStep("spmd", traced, jitted, avals[0]))
+        return steps
 
     def compiled_hlo(self, program):
         """Optimized HLO text of every executable this executor has run
-        for `program` (flat and GSPMD paths), AOT-lowered again at the
-        recorded first-call signature — what the device actually
+        for `program` (compiled_steps) — what the device actually
         executes: custom calls that survived, collectives the
-        partitioner emitted.  Costs a re-trace plus a compile (a
+        partitioner emitted, and in every instruction's `op_name` the
+        `<op_role>/<op type>/<index>` scope of the Fluid op it came from
+        (core/trace.py).  Costs a re-trace plus a compile (a
         persistent-cache read where the step took long enough to be
         written)."""
-        texts = [cb.jitted.lower(*cb.avals).compile().as_text()
-                 for cb in self._cache.blocks_for(program)
-                 if cb.avals is not None]
-        for key, (_traced, jitted, _sh, avals) in (
-                getattr(self, "_spmd_cache", None) or {}).items():
-            if key[0] == id(program) and avals[0] is not None:
-                texts.append(jitted.lower(*avals[0]).compile().as_text())
-        return texts
+        return [step.hlo() for step in self.compiled_steps(program)]
 
     def spmd_comm_stats(self, program):
         """Comm-bytes attribution for a GSPMD-stamped program's compiled
@@ -717,8 +766,6 @@ class Executor:
         updates must be replica-invariant (they are, whenever they flow
         from all-reduced grads; batch-stat ops like BN belong on the
         DistributedExecutor path instead)."""
-        import time as _time
-
         from jax.sharding import NamedSharding, PartitionSpec
 
         from .flags import get_flag
@@ -771,13 +818,10 @@ class Executor:
                 return PartitionSpec(axis)
             return PartitionSpec()
 
-        t0 = _time.perf_counter()
         feed_np = {n: np.asarray(v) for n, v in feed.items()}
         specs = {n: feed_spec(a) for n, a in feed_np.items()}
-        with RecordEvent("feed_upload", cat="feed"):
-            feed_arrays = {n: to_mesh(a, specs[n])
-                           for n, a in feed_np.items()}
-        self._host_feed_ms += (_time.perf_counter() - t0) * 1e3
+        feed_arrays = self._upload(
+            lambda: {n: to_mesh(a, specs[n]) for n, a in feed_np.items()})
 
         feed_sig = tuple(sorted(
             (n, tuple(a.shape), str(a.dtype)) for n, a in feed_np.items()))
@@ -788,12 +832,14 @@ class Executor:
         key_id = (id(program), program._version, feed_sig,
                   tuple(fetch_names), id(scope), axis, nranks)
         entry = cache.get(key_id)
-        if entry is None:
+        first = entry is None
+        if first:
             from .core.trace import build_traced_function
 
-            traced = build_traced_function(
-                program, 0, tuple(n for n, _, _ in feed_sig), fetch_names,
-                scope, collective_axis=(axis, nranks))
+            with RecordEvent("trace_compile", feed_sig=sig_text(feed_sig)):
+                traced = build_traced_function(
+                    program, 0, tuple(n for n, _, _ in feed_sig),
+                    fetch_names, scope, collective_axis=(axis, nranks))
 
             def stepfn(feeds, ro_state, rw_state, rng_key):
                 fetches, new_state = traced.fn(
@@ -841,18 +887,16 @@ class Executor:
             scope.set(n, arr)
             return arr
 
-        ro_state = {n: commit(n) for n in traced.ro_names}
-        rw_state = {n: commit(n) for n in traced.rw_names}
+        ro_state, rw_state = self._gather(commit, traced.ro_names,
+                                          traced.rw_names)
         key = to_mesh(self._rng_key(program), PartitionSpec())
-        with RecordEvent("executor_run"):
-            fetches, new_state = jitted(feed_arrays, ro_state, rw_state, key)
-        for n, v in new_state.items():
-            scope.set(n, v)
-        if return_numpy:
-            # P() out_specs are fully replicated: np.asarray reads the
-            # local shard even in multi-process runs
-            return [np.asarray(f) for f in fetches]
-        return list(fetches)
+        fetches, new_state = self._dispatch(
+            jitted, (feed_arrays, ro_state, rw_state, key),
+            new_sig=feed_sig if first else None)
+        self._commit(scope, new_state)
+        # P() out_specs are fully replicated: np.asarray reads the local
+        # shard even in multi-process runs
+        return self._fetched(fetches, return_numpy, to_numpy=np.asarray)
 
     def run_loop(
         self,

@@ -2,10 +2,13 @@
 analog).
 
 The reference wraps every op run in RecordEvent scopes and correlates CUPTI
-device activity into a chrome-trace timeline (tools/timeline.py).  Here host
-scopes are kept (RecordEvent spans around executor runs + user ranges) and
-device-side tracing delegates to jax.profiler (XLA/xplane — TensorBoard
-readable), with the host spans additionally dumped as chrome-trace JSON so
+device activity into a chrome-trace timeline (tools/timeline.py).  Here the
+step is one compiled program: device-side tracing delegates to jax.profiler
+(XLA/xplane — TensorBoard readable), in which every device op carries the
+`<op_role>/<op type>/<index>` scope of the Fluid op it came from
+(core/trace.py), and host scopes are RecordEvent spans (the Executor's
+boundaries + user ranges) that land on the host plane of that same trace,
+on its clock, and are additionally dumped as chrome-trace JSON so
 `profiler(state)`-style workflows keep their artifact.
 """
 
@@ -14,6 +17,8 @@ import json
 import os
 import threading
 import time
+
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "RecordEvent",
@@ -45,33 +50,57 @@ COMM_OPS = frozenset((
 
 
 class RecordEvent:
-    """RAII span (platform/profiler.h:73 RecordEvent parity).  `cat`
-    categorizes the span for comm-vs-compute attribution in the chrome
-    trace ("comm" for RPC sends/recvs, "feed" for host->device uploads;
-    unset spans are compute/host work)."""
+    """RAII span (platform/profiler.h:73 RecordEvent parity), collected in
+    two places from one enter/exit:
 
-    def __init__(self, name, cat=None):
+      * as `paddle_tpu:<name>` on the host plane of any running JAX
+        trace (`jax.profiler.TraceAnnotation`), i.e. on the clock of the
+        device ops in the same `.xplane.pb` — whoever started the trace:
+        `profiler(..., trace_dir=)`, `tpu_profiler`, or a plain
+        `jax.profiler.start_trace`.  `args` become the event's stats;
+      * under its bare name in this module's chrome-trace list while
+        `start_profiler` .. `stop_profiler` is collecting (what
+        `profiler()` prints and tools/timeline.py merges).
+
+    When neither collects, a span reads no clock and records nothing.
+    `cat` categorizes the span for comm-vs-compute attribution in the
+    chrome trace ("comm" for RPC sends/recvs, "feed" for host->device
+    uploads; unset spans are compute/host work)."""
+
+    __slots__ = ("name", "cat", "args", "t0", "_annotation")
+
+    def __init__(self, name, cat=None, **args):
         self.name = name
         self.cat = cat
+        self.args = args
         self.t0 = None
+        self._annotation = None
 
     def __enter__(self):
-        self.t0 = time.time()
+        self.t0 = time.time() if _enabled else None
+        if TraceAnnotation.is_enabled():
+            self._annotation = TraceAnnotation(
+                "paddle_tpu:" + self.name, **self.args)
+            self._annotation.__enter__()
         return self
 
     def __exit__(self, *exc):
-        if _enabled:
-            t1 = time.time()
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+            self._annotation = None
+        if _enabled and self.t0 is not None:
             ev = {
                 "name": self.name,
                 "ph": "X",
                 "ts": self.t0 * 1e6,
-                "dur": (t1 - self.t0) * 1e6,
+                "dur": (time.time() - self.t0) * 1e6,
                 "pid": os.getpid(),
                 "tid": threading.get_ident() % 10000,
             }
             if self.cat:
                 ev["cat"] = self.cat
+            if self.args:
+                ev["args"] = self.args
             with _events_lock:
                 _events.append(ev)
         return False
@@ -142,18 +171,24 @@ def profiler(state="All", sorted_key=None, profile_path="/tmp/profile", trace_di
 
 def per_op_timeline(program, feed, scope=None, path=None, warmup=1,
                     block_idx=0):
-    """Per-op host/device correlated timeline (device_tracer.h:26,49 +
-    tools/timeline.py:160 capability, re-expressed for a compile-first
-    engine).
-
-    The compiled path fuses the whole block into one XLA executable, so
-    per-op device attribution needs a diagnostic interpretation pass: each
-    op's lowering runs eagerly on concrete arrays, timed twice — cold
-    (host dispatch + compile + device) and warm (device-dominated re-run
-    under block_until_ready).  Both spans share a correlation id per op
+    """Per-op timeline of an EAGER, UNFUSED re-run of each lowering
+    (device_tracer.h:26,49 + tools/timeline.py:160 capability,
+    re-expressed for a compile-first engine) — a diagnostic
+    interpretation pass, NOT the compiled step: XLA fuses the block into
+    one executable whose ops this never sees, and both columns are host
+    clocks.  Each op's lowering runs on concrete arrays, timed twice —
+    cold (host dispatch + compile + device) and warm (the "device"
+    column: a re-run under block_until_ready, still a host clock around
+    an op-at-a-time program).  Both spans share a correlation id per op
     (the reference's CUPTI correlation contract) and land in ONE
-    chrome-trace JSON with separate host/device tracks.  Returns the rows
-    [(op_type, idx, host_ms, device_ms)] sorted by device time.
+    chrome-trace JSON with separate tracks.  Returns the rows [(op_type,
+    idx, host_ms, device_ms)] sorted by the warm time.
+
+    For where the compiled step's device time goes, trace it
+    (`tpu_profiler` / `profiler(trace_dir=)`): every device op carries
+    the `<op_role>/<op type>/<index>` scope of the Fluid op it was
+    lowered from (core/trace.py; Executor.compiled_hlo shows them), and
+    benchmark/readers/program_profile.py reduces such a trace by scope.
 
     Flat blocks only (while/cond sub-blocks time as their parent op would
     under the real executor — use the aggregate profiler for those).
