@@ -11,7 +11,12 @@ Gradients come from the lowering itself: for any op ``foo``, the op
 ``foo_grad`` is lowered generically via ``jax.vjp`` of foo's lowering — the
 TPU replacement for the reference's per-op ``GradOpDescMaker`` + hand-written
 grad kernels (``grad_op_desc_maker.h``).  XLA CSE merges the re-traced
-forward with the original, so no double compute survives compilation.
+forward with the original where both are the same computation on the same
+operands; that is no promise of single compute: under memory pressure the
+compiler's rematerialisation ran the vocabulary head's forward twice
+(``%fusion.5264.remat``, root PERF.md section 5), and a lowering with a
+custom VJP must keep its forward identical on both sides to be merged
+(``pallas_kernels.linear_xent_tiled`` leaves its scan one output for it).
 """
 
 

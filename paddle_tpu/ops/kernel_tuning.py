@@ -33,6 +33,7 @@ __all__ = [
     "tuned_params",
     "shape_bucket",
     "note_kernel",
+    "note_dense_vjp",
     "attribution",
     "reset_attribution",
     "measure_candidate",
@@ -47,6 +48,7 @@ _STATS_ZERO = {"hits": 0, "misses": 0, "searches": 0, "search_ms": 0.0,
                "failed_candidates": 0, "last_failure": ""}
 _stats = dict(_STATS_ZERO)
 _kernel_hits = {}  # family -> pallas dispatch count (trace-time)
+_dense_vjp_hits = {}  # family -> hand-written plain-XLA VJP engagements
 _searching = threading.local()  # candidate timing in flight on this thread
 _inflight = {}  # key -> threading.Event: a measured search under way
 
@@ -308,12 +310,20 @@ def note_kernel(family, n=1):
         _kernel_hits[family] = _kernel_hits.get(family, 0) + n
 
 
+def note_dense_vjp(family):
+    """Count a trace-time engagement of a hand-written VJP in plain XLA
+    ops (no Mosaic call: not a pallas hit) for `family`."""
+    with _lock:
+        _dense_vjp_hits[family] = _dense_vjp_hits.get(family, 0) + 1
+
+
 def attribution():
     """Snapshot for bench attribution: per-family pallas-hit counts plus
     tuning-cache hit/miss/search totals (search_ms summed)."""
     with _lock:
         return {
             "pallas_hits": dict(_kernel_hits),
+            "dense_vjp_hits": dict(_dense_vjp_hits),
             "tuning": {k: (round(v, 3) if isinstance(v, float) else v)
                        for k, v in _stats.items()},
         }
@@ -322,6 +332,7 @@ def attribution():
 def reset_attribution():
     with _lock:
         _kernel_hits.clear()
+        _dense_vjp_hits.clear()
         _stats.update(_STATS_ZERO)
 
 

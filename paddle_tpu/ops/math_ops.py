@@ -435,28 +435,36 @@ def _smooth_label_xent(ctx, ins, attrs):
 
 @register("fused_linear_xent", no_grad_inputs=("Label",))
 def _fused_linear_xent_op(ctx, ins, attrs):
-    """Logits-free projected cross entropy — the fused target of
+    """Projected cross entropy — the fused target of
     linear_xent_fuse_pass (the final [H, V] projection folded INTO
     softmax_with_cross_entropy / smooth_label_xent).  Inputs: X
     [..., H] hidden states, W [H, V] (or [V, H] with transpose_w, the
-    tied-embedding form), Label [..., 1] int.  Under FLAGS_use_pallas
-    the [R, V] f32 logits tensor never materializes in HBM: the
-    forward streams vocab tiles through an online logsumexp and the
-    backward recomputes per-tile softmax against W
-    (pallas_kernels.fused_linear_xent); the dense fallback is the
-    closed-form XLA reference.  Label convention matches
+    tied-embedding form), Label [..., 1] int.  Label convention matches
     smooth_label_xent: out-of-range labels contribute the smoothing
     term only.
 
-    transpose_w (the tied-embedding x @ W^T form) materializes a
-    physical [H, V] transposed copy of W per step — the kernels read
-    [H, V]-layout tiles; a weights-sized copy (~150 MB for gpt2) is
-    still far below the [R, V] logits the fusion eliminates (several
-    GB at bench config), but a [V, H]-layout kernel variant would
-    remove it (documented known limit)."""
+    Default flags (every measured cell): pallas_kernels.
+    linear_xent_tiled, a custom VJP in plain XLA ops that walks the rows
+    in tiles of at most ~256 MiB of f32 logits, so no [R, V] array
+    exists in either direction; the backward recomputes a tile's logits
+    from the saved lse, forms the logits gradient once, narrows it to
+    the operands' dtype and feeds both gradient matmuls.  transpose_w
+    is the dots' dimension numbers, not a copy of the table.  Under a
+    live GSPMD mesh (spmd_epilogue.mesh_ctx) the input is one tile and
+    there is no loop: the partitioner would all-reduce a scan's dw
+    carry over dp once per tile.  The engagement counts under
+    kernel_tuning.attribution()["dense_vjp_hits"]["xent"].
+
+    Under FLAGS_use_pallas the Mosaic kernels come first
+    (pallas_kernels.fused_linear_xent: vocab tiles through an online
+    logsumexp, logits never in HBM); they read [H, V]-layout tiles, so
+    transpose_w materializes a transposed copy of W per step there (a
+    [V, H]-layout kernel variant would remove it: known limit).
+    _linear_xent_dense (the [R, V] logits under jax's autodiff) is the
+    reference both are tested against."""
     from .pallas_kernels import (
-        _linear_xent_dense,
         fused_linear_xent,
+        linear_xent_tiled,
         use_pallas,
         use_pallas_unwrapped,
     )
@@ -465,22 +473,29 @@ def _fused_linear_xent_op(ctx, ins, attrs):
     w = ins["W"][0]
     label = ins["Label"][0]
     eps = float(attrs.get("epsilon", 0.0))
-    if attrs.get("transpose_w", False):
-        w = w.T
-    h = x.shape[-1]
-    x2 = x.reshape(-1, h)
-    lbl = label.reshape(-1).astype(jnp.int32)
-    loss2 = None
+    transpose_w = bool(attrs.get("transpose_w", False))
+    loss = None
     if use_pallas():
         from .spmd_epilogue import spmd_linear_xent
 
-        loss2 = spmd_linear_xent(ctx, x2, w, lbl, eps,
-                                 bool(attrs.get("transpose_w", False)))
-        if loss2 is None and use_pallas_unwrapped():
-            loss2 = fused_linear_xent(x2, w, lbl, eps)
-    if loss2 is None:
-        loss2 = _linear_xent_dense(x2, w, lbl, eps)
-    loss = loss2.reshape(tuple(x.shape[:-1]) + (1,)).astype(x.dtype)
+        wt = w.T if transpose_w else w
+        x2 = x.reshape(-1, x.shape[-1])
+        lbl = label.reshape(-1).astype(jnp.int32)
+        loss = spmd_linear_xent(ctx, x2, wt, lbl, eps, transpose_w)
+        if loss is None and use_pallas_unwrapped():
+            loss = fused_linear_xent(x2, wt, lbl, eps)
+    if loss is None:
+        from .spmd_epilogue import mesh_ctx
+
+        loss = linear_xent_tiled(x, w, label.reshape(x.shape[:-1]), eps,
+                                 transpose_w, mesh_ctx() is not None)
+    # the per-row loss leaves in f32 whatever X's dtype, like every
+    # other softmax statistic: under AMP (bf16 X) a cast to X's dtype
+    # rounds each row's loss to 8 bits before the cast-back op AMP
+    # appends.  XLA elided that rounding while the whole head was one
+    # fusion (xla_allow_excess_precision); across a loop boundary it
+    # keeps it, 3e-4..3e-3 on a mean loss of 7 (PERF.md, PR 24)
+    loss = loss.reshape(tuple(x.shape[:-1]) + (1,)).astype(jnp.float32)
     return {"Loss": [loss]}
 
 

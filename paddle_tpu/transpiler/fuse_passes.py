@@ -743,12 +743,17 @@ class ResidualLnFusePass(Pass):
 
 @register_pass("linear_xent_fuse_pass")
 class LinearXentFusePass(Pass):
-    """The logits-free loss rewrite: the final vocab projection
+    """The projected-loss rewrite: the final vocab projection
     (mul, or matmul(transpose_Y) for tied embeddings) feeding
     softmax_with_cross_entropy (hard label) or smooth_label_xent
-    becomes ONE fused_linear_xent op — under FLAGS_use_pallas the
-    [R, V] f32 logits tensor (and its gradient twin) never exists in
-    HBM (pallas_kernels.fused_linear_xent streams vocab tiles through
+    becomes ONE fused_linear_xent op, whose lowering owns its backward.
+    With default flags that is pallas_kernels.linear_xent_tiled: a
+    custom VJP in plain XLA ops over row tiles of at most ~256 MiB of
+    f32 logits — no [R, V] array in either direction, the logits
+    gradient formed once in the operands' dtype for both gradient
+    matmuls (one tile, so [R, V] after all, in a GSPMD-partitioned
+    program).  Under FLAGS_use_pallas the logits never reach HBM at
+    all (pallas_kernels.fused_linear_xent streams vocab tiles through
     an online logsumexp; the backward recomputes per-tile softmax
     against W).  Conservative: 2-D weight, hard labels, no
     ignore_index, the xent's Softmax output unused ANYWHERE (all

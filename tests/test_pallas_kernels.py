@@ -731,6 +731,151 @@ def test_fused_linear_xent_matches_dense(shape, eps):
                                    rtol=1e-4, atol=1e-5)
 
 
+def _tiled_case(shape, eps, transpose_w, dtype, seed=28, vocab=33):
+    """x, w, labels (two of them out of range), a non-uniform dy, and the
+    _linear_xent_dense twin of linear_xent_tiled on them."""
+    from paddle_tpu.ops.pallas_kernels import _linear_xent_dense
+
+    rng = np.random.RandomState(seed)
+    h = shape[-1]
+    x = jnp.asarray(rng.randn(*shape), dtype)
+    w = jnp.asarray(
+        rng.randn(*((vocab, h) if transpose_w else (h, vocab))) * 0.3, dtype)
+    lbl = rng.randint(0, vocab, shape[:-1])
+    lbl.flat[1], lbl.flat[2] = -1, vocab + 3
+    lbl = jnp.asarray(lbl, jnp.int32)
+    dy = jnp.asarray(rng.rand(*shape[:-1], 1) + 0.5, jnp.float32)
+
+    def dense(x, w):
+        return _linear_xent_dense(
+            x.reshape(-1, h), w.T if transpose_w else w, lbl.reshape(-1),
+            eps).reshape(shape[:-1] + (1,))
+
+    return x, w, lbl, dy, dense
+
+
+@pytest.fixture
+def four_row_tiles(monkeypatch):
+    """The head's tile budget cut to 8 rows of a 33-wide vocabulary, so
+    that toy shapes take several tiles."""
+    from paddle_tpu.ops import pallas_kernels
+
+    monkeypatch.setattr(pallas_kernels, "_LXENT_TILE_BYTES", 4 * 33 * 8)
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-5), ("bfloat16", 3e-2)])
+@pytest.mark.parametrize("transpose_w", [False, True])
+@pytest.mark.parametrize("eps", [0.0, 0.1])
+@pytest.mark.parametrize("shape,one_tile", [
+    ((32, 16), False),      # [R, H]: four tiles of 8 rows
+    ((30, 16), False),      # rows not divisible by the tile: padded tail
+    ((3, 20, 16), False),   # [B, T, H]: the time axis is the scanned one
+    ((3, 20, 16), True),    # what a GSPMD-partitioned program gets
+])
+def test_linear_xent_tiled_matches_dense_autodiff(
+        four_row_tiles, shape, one_tile, eps, transpose_w, dtype, tol):
+    """Loss and both gradients of the default head against jax's autodiff
+    of _linear_xent_dense, under a non-uniform dy, with out-of-range
+    labels among the rows."""
+    from paddle_tpu.ops.pallas_kernels import linear_xent_tiled
+
+    x, w, lbl, dy, dense = _tiled_case(shape, eps, transpose_w, dtype)
+    loss, vjp = jax.vjp(
+        lambda x, w: linear_xent_tiled(x, w, lbl, eps, transpose_w,
+                                       one_tile), x, w)
+    ref, ref_vjp = jax.vjp(dense, x, w)
+    assert loss.dtype == jnp.float32 and loss.shape == shape[:-1] + (1,)
+    np.testing.assert_allclose(np.asarray(loss), np.asarray(ref),
+                               rtol=tol, atol=tol)
+    for got, want in zip(vjp(dy), ref_vjp(dy)):
+        assert got.dtype == want.dtype and got.shape == want.shape
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(want, np.float32),
+            rtol=10 * tol, atol=tol)
+    if eps == 0.0:
+        # the label convention: zero loss, zero gradient out of range
+        dx = np.asarray(vjp(dy)[0], np.float32).reshape(-1, shape[-1])
+        assert not np.asarray(loss).reshape(-1)[1:3].any()
+        assert not dx[1:3].any()
+
+
+@pytest.mark.parametrize("batch,length,vocab,steps", [
+    (1, 4096, 50257, 1024),   # GPT-2's head as [R, H]
+    (4, 1024, 50257, 256),    # ... and as [B, T, H]: 1024 rows a tile
+    (128, 256, 10000, 32),    # Transformer-base: 4096 rows a tile
+    (1, 32768, 10000, 4096),
+    (1, 64, 300, 64),         # fits whole: one tile
+    (1, 1361, 50257, 1328),   # a prime length: padded last tile
+    (512, 7, 50257, 2),       # fewer than 8 steps fit: no alignment
+])
+def test_lxent_tile_len_comes_from_the_shapes(batch, length, vocab, steps):
+    from paddle_tpu.ops.pallas_kernels import (
+        _LXENT_TILE_BYTES,
+        _lxent_tile_len,
+    )
+
+    got = _lxent_tile_len(batch, length, vocab)
+    assert got == steps
+    assert got == length or 4 * batch * got * vocab <= _LXENT_TILE_BYTES
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_linear_xent_tiled_forms_the_logits_gradient_once(
+        four_row_tiles, dtype):
+    """The mechanism itself, read from the jaxpr of loss-and-gradients at
+    a shape with four tiles: no array of R x V elements exists, the
+    [tile, V] logits gradient is ONE array in the operands' dtype feeding
+    both gradient dots, three dots in the backward's loop body and one in
+    the forward's, and the trace counted one engagement and no pallas
+    hit."""
+    import jax.extend.core as jcore
+
+    from paddle_tpu.ops import kernel_tuning as kt
+    from paddle_tpu.ops.pallas_kernels import linear_xent_tiled
+
+    R, H, V = 32, 16, 33
+    x, w, lbl, dy, _dense = _tiled_case((R, H), 0.1, True, dtype)
+
+    def loss_and_grads(x, w):
+        loss, vjp = jax.vjp(
+            lambda x, w: linear_xent_tiled(x, w, lbl, 0.1, True), x, w)
+        return loss, vjp(dy)
+
+    kt.reset_attribution()
+    jaxpr = jax.make_jaxpr(loss_and_grads)(x, w).jaxpr
+    att = kt.attribution()
+    assert att["dense_vjp_hits"] == {"xent": 1} and att["pallas_hits"] == {}
+
+    def walk(jaxpr, depth, out):
+        for eqn in jaxpr.eqns:
+            out.append((eqn, depth))
+            for val in eqn.params.values():
+                for sub in (val if isinstance(val, (list, tuple))
+                            else [val]):
+                    if isinstance(sub, jcore.ClosedJaxpr):
+                        sub = sub.jaxpr
+                    if isinstance(sub, jcore.Jaxpr):
+                        walk(sub, depth + (eqn.primitive.name == "scan"),
+                             out)
+        return out
+
+    eqns = walk(jaxpr, 0, [])
+    sizes = [int(np.prod(v.aval.shape)) for eqn, _ in eqns
+             for v in list(eqn.invars) + list(eqn.outvars)
+             if getattr(getattr(v, "aval", None), "shape", None)]
+    assert max(sizes) < R * V
+    scans = [eqn for eqn, _ in eqns if eqn.primitive.name == "scan"]
+    assert [int(e.params["length"]) for e in scans] == [4, 4]
+    dots = [eqn for eqn, depth in eqns
+            if eqn.primitive.name == "dot_general" and depth == 1]
+    assert len(dots) == 4  # forward 1; backward: recompute, dx, dw
+    tile_v = (1, R // 4, V)
+    fed = [v for eqn in dots for v in eqn.invars
+           if tuple(v.aval.shape) == tile_v]
+    assert len(fed) == 2 and fed[0] is fed[1]  # one array, two dots
+    assert fed[0].aval.dtype == jnp.dtype(dtype)
+
+
 def test_fused_linear_xent_bf16_and_invalid_labels():
     """bf16 X/W with f32 internals; out-of-range labels contribute the
     smoothing term only (the one_hot convention)."""
@@ -1138,6 +1283,68 @@ def test_fused_linear_xent_op_pallas_dispatch_matches_dense():
     plain = _run_fused_op_program(build, feed, False)
     pallas = _run_fused_op_program(build, feed, True)
     np.testing.assert_allclose(plain[0], pallas[0], rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("form", ["mul", "tied_matmul", "mul_smoothed"])
+def test_fused_linear_xent_op_and_its_grad_match_the_unfused_chain(
+        form, monkeypatch):
+    """Through the op, default flags: fused_linear_xent and its generic
+    _grad op (jax.vjp of the lowering, so linear_xent_tiled's VJP, here
+    over three row tiles) train the head's weight AND the layer below it
+    exactly as the unfused projection -> xent chain does."""
+    from paddle_tpu.ops import pallas_kernels
+    from paddle_tpu.transpiler import apply_pass
+
+    B, T, H, V = 2, 6, 8, 20
+    monkeypatch.setattr(pallas_kernels, "_LXENT_TILE_BYTES", 4 * V * B * 2)
+    rng = np.random.RandomState(39)
+    feed = {"x": rng.rand(B, T, H).astype("float32"),
+            "lbl": rng.randint(0, V, (B, T, 1)).astype("int64")}
+
+    def run(fuse):
+        main, startup = fluid.Program(), fluid.Program()
+        with fluid.framework.program_guard(main, startup):
+            startup.random_seed = 12
+            x = layers.data("x", shape=[T, H])
+            lbl = layers.data("lbl", shape=[T, 1], dtype="int64")
+            hid = layers.fc(x, H, num_flatten_dims=2, act="tanh",
+                            param_attr=fluid.ParamAttr(name="below_w"))
+            if form == "tied_matmul":
+                table = layers.create_parameter(
+                    shape=[V, H], dtype="float32", name="head_w")
+                logits = layers.matmul(hid, table, transpose_y=True)
+            else:
+                logits = layers.fc(
+                    hid, V, num_flatten_dims=2, bias_attr=False,
+                    param_attr=fluid.ParamAttr(name="head_w"))
+            if form == "mul_smoothed":
+                soft = layers.label_smooth(
+                    layers.one_hot(lbl, V), epsilon=0.1)
+                cost = layers.softmax_with_cross_entropy(
+                    logits, soft, soft_label=True)
+            else:
+                cost = layers.softmax_with_cross_entropy(logits, lbl)
+            loss = layers.reduce_mean(cost)
+            if fuse:
+                apply_pass(main, "smooth_label_xent_fuse_pass")
+                apply_pass(main, "linear_xent_fuse_pass")
+                assert main._linear_xent_fused_count == 1
+            fluid.optimizer.SGD(0.5).minimize(loss)
+        types = [op.type for op in main.global_block().ops]
+        assert ("fused_linear_xent_grad" in types) == fuse
+        scope = fluid.Scope()
+        with fluid.scope_guard(scope):
+            exe = fluid.Executor(fluid.CPUPlace())
+            exe.run(startup)
+            losses = [float(np.asarray(exe.run(
+                main, feed=feed, fetch_list=[loss])[0])) for _ in range(3)]
+            return losses, [np.array(scope.get(n))
+                            for n in ("head_w", "below_w")]
+
+    (l0, w0), (l1, w1) = run(False), run(True)
+    np.testing.assert_allclose(l0, l1, rtol=1e-5, atol=1e-6)
+    for a, b in zip(w0, w1):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
 
 
 def test_fused_attention_qvec_explicit_flags_beyond_budget_dispatch():
