@@ -25,14 +25,25 @@ _BF16_OPS = ("mul", "matmul", "conv2d", "depthwise_conv2d",
              # logits-free fused loss: bf16 X/W tiles, f32 online
              # logsumexp internals — the projection is the single
              # biggest matmul in the LM programs
-             "fused_linear_xent")
+             "fused_linear_xent",
+             # routed experts: the two grouped matmuls read bf16 rows and
+             # bf16 expert weights; the router stays f32 (below)
+             "moe_ffn")
 
 # input slots that must stay float32 even when the op is rewritten
 # (additive -1e9 padding masks lose nothing in bf16, but keeping them f32
 # costs nothing and avoids surprises with user-supplied biases); int
 # label slots must never see a float cast at all
 _KEEP_F32_SLOTS = {"fused_attention": ("Bias",),
-                   "fused_linear_xent": ("Label",)}
+                   "fused_linear_xent": ("Label",),
+                   # the router's top-k is discontinuous: it reads X and
+                   # its own weight in f32, and the lowering narrows X to
+                   # the experts' dtype itself
+                   "moe_ffn": ("X", "RouterW")}
+
+# output slots that are not activations (counts, f32 statistics): they
+# keep their declared dtype and get no cast-back
+_KEEP_OUT_SLOTS = {"moe_ffn": ("TokensPerExpert", "AuxLoss")}
 
 # dtype-transparent trunk ops: (data input slots, flippable output slots).
 # When every data input of one of these is available in half precision,
@@ -50,6 +61,7 @@ _TRANSPARENT_OPS = {
     "pool2d": (("X",), ("Out",)),
     "batch_norm": (("X",), ("Y",)),
     "layer_norm": (("X",), ("Y",)),
+    "rms_norm": (("X",), ("Y",)),
     "dropout": (("X",), ("Out",)),
     "reshape2": (("X",), ("Out",)),
     "reshape": (("X",), ("Out",)),
@@ -153,7 +165,10 @@ def rewrite_bf16(program=None, ops=_BF16_OPS, dtype="bfloat16"):
             new_ops.append(op)
             # cast outputs back to f32, keeping downstream names intact:
             # the op writes <out>@RAW_BF16 and a cast restores <out>
+            keep_out = _KEEP_OUT_SLOTS.get(op.type, ())
             for slot, names in list(op.outputs.items()):
+                if slot in keep_out:
+                    continue
                 restored = []
                 for n in names:
                     raw, cast_back = _emit_raw_and_castback(

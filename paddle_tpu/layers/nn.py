@@ -34,6 +34,8 @@ __all__ = [
     "adaptive_pool2d",
     "batch_norm",
     "layer_norm",
+    "rms_norm",
+    "moe_ffn",
     "group_norm",
     "instance_norm",
     "dropout",
@@ -525,6 +527,65 @@ def layer_norm(
         attrs={"begin_norm_axis": begin_norm_axis, "epsilon": epsilon},
     )
     return helper.append_activation(out)
+
+
+def rms_norm(input, epsilon=1e-5, param_attr=None, name=None):
+    """x * rsqrt(mean(x^2) + epsilon) * w over the last axis, w a [d]
+    parameter initialised to 1 (no mean subtraction, no bias)."""
+    helper = LayerHelper("rms_norm", **locals())
+    dtype = helper.input_dtype()
+    w = helper.create_parameter(
+        attr=helper.param_attr, shape=[int(input.shape[-1])], dtype=dtype,
+        default_initializer=Constant(1.0))
+    out = helper.create_variable_for_type_inference(dtype)
+    helper.append_op("rms_norm", inputs={"X": [input], "Scale": [w]},
+                     outputs={"Y": [out]}, attrs={"epsilon": epsilon})
+    return out
+
+
+def moe_ffn(input, num_experts, expert_size, top_k, norm_topk_prob=False,
+            router_attr=None, gate_up_attr=None, down_attr=None,
+            stat_name="moe_tokens_per_expert", name=None):
+    """Token-choice mixture of SwiGLU experts over the last axis of
+    `input` (the `moe_ffn` op: softmax router, top-k, dropless).  The
+    experts' weights are stacked: gate and up side by side in one
+    [E, d, 2 * expert_size] parameter, down in [E, expert_size, d].
+
+    Returns (out, aux_loss, tokens_per_expert): aux_loss is [2] f32, the
+    load-balance loss E * sum_e F_e * P_e and the router z-loss, for the
+    builder to weigh into its loss; tokens_per_expert is a persistable
+    [E] int32 statistic the scope holds after every step (it sums to
+    tokens * top_k: no token is dropped), named `stat_name`_<n>: a
+    program that shares a scope with the training program (an evaluation
+    pass) gives its own so as not to overwrite the training step's."""
+    helper = LayerHelper("moe_ffn", **locals())
+    dtype = helper.input_dtype()
+    d = int(input.shape[-1])
+    router = helper.create_parameter(
+        attr=router_attr, shape=[d, num_experts], dtype=dtype)
+    gate_up = helper.create_parameter(
+        attr=gate_up_attr, shape=[num_experts, d, 2 * expert_size],
+        dtype=dtype)
+    down = helper.create_parameter(
+        attr=down_attr, shape=[num_experts, expert_size, d], dtype=dtype)
+    counts = helper.create_global_variable(
+        name=unique_name.generate(stat_name),
+        persistable=True, dtype="int32", shape=[num_experts])
+    counts.stop_gradient = True
+    helper.set_variable_initializer(counts, Constant(0))
+    out = helper.create_variable_for_type_inference(dtype)
+    aux = helper.create_variable_for_type_inference("float32")
+    helper.append_op(
+        "moe_ffn",
+        inputs={"X": [input], "RouterW": [router], "GateUpW": [gate_up],
+                "DownW": [down]},
+        outputs={"Y": [out], "TokensPerExpert": [counts], "AuxLoss": [aux]},
+        attrs={"top_k": int(top_k), "norm_topk_prob": bool(norm_topk_prob)})
+    # said here, not left to the abstract evaluation of the lowering: with
+    # an unknown batch that runs at a million sequences, whose rows times
+    # top_k no int32 index reaches
+    out.shape, aux.shape = tuple(input.shape), (2,)
+    return out, aux, counts
 
 
 def group_norm(

@@ -6,6 +6,7 @@ from . import (
     gpt2,
     machine_translation,
     mnist,
+    olmoe,
     resnet,
     se_resnext,
     sentiment,

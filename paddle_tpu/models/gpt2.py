@@ -25,6 +25,7 @@ __all__ = [
     "GPT2Config",
     "gpt2_lm",
     "gpt2_lm_program",
+    "lm_train_program",
     "gpt2_logits_program",
     "greedy_generate",
     "greedy_generate_cached",
@@ -171,6 +172,19 @@ def gpt2_lm_program(hp=GPT2Config, seq_len=128, lr=3e-4, is_test=False,
     moments shard like their param — ZeRO-style sharded optimizer
     state), batch feeds over the mesh's dp axis.  No model edits — the
     executor's _run_spmd path picks the stamp up."""
+    return lm_train_program(
+        lambda ids: (gpt2_lm(ids, hp, is_test), None), seq_len, lr, is_test,
+        use_bf16, mesh, getattr(hp, "partition_family", "gpt2"))
+
+
+def lm_train_program(trunk, seq_len, lr, is_test, use_bf16, mesh,
+                     partition_family):
+    """The causal-LM train-program plumbing every decoder-only builder
+    shares (gpt2_lm_program, olmoe.olmoe_lm_program): feeds, the weighted
+    token cross-entropy, the fuse passes, AMP, remat, Adam and the mesh
+    stamp.  `trunk(ids)` builds the model and returns ([B, T, vocab]
+    logits, extra) where extra is a scalar var added to the loss (a
+    mixture's router losses) or None."""
     import paddle_tpu as fluid
 
     main = fluid.Program()
@@ -180,7 +194,7 @@ def gpt2_lm_program(hp=GPT2Config, seq_len=128, lr=3e-4, is_test=False,
         lbl = layers.data("labels", shape=[seq_len], dtype="int64")
         w = layers.data("loss_weight", shape=[seq_len], dtype="float32")
 
-        logits = gpt2_lm(ids, hp, is_test)
+        logits, extra = trunk(ids)
         cost = layers.softmax_with_cross_entropy(
             logits, layers.unsqueeze(lbl, [2])
         )
@@ -190,6 +204,8 @@ def gpt2_lm_program(hp=GPT2Config, seq_len=128, lr=3e-4, is_test=False,
         loss = layers.elementwise_div(
             layers.reduce_sum(cost), layers.clip(tokens, 1e-5, 1e30)
         )
+        if extra is not None:
+            loss = layers.elementwise_add(loss, extra)
 
         # logits-free fused cross-entropy (the [B, T, V] f32 logits
         # tensor never reaches HBM under FLAGS_use_pallas) + the
@@ -213,8 +229,8 @@ def gpt2_lm_program(hp=GPT2Config, seq_len=128, lr=3e-4, is_test=False,
         from ..parallel.partition_rules import (annotate_spmd,
                                                 train_partition_rules_for)
 
-        annotate_spmd(main, mesh, train_partition_rules_for(
-            getattr(hp, "partition_family", "gpt2")))
+        annotate_spmd(main, mesh,
+                      train_partition_rules_for(partition_family))
     return main, startup, ["ids", "labels", "loss_weight"], [loss, tokens]
 
 

@@ -111,7 +111,8 @@ class _NumpyInit:
 def multi_head_attention(
     queries, keys, values, attn_bias, d_model, n_head, dropout_rate=0.0,
     is_test=False, cache=None, fused=False, kpad_bias=None, causal=False,
-    n_kv_head=None, rotary=False,
+    n_kv_head=None, rotary=False, qk_norm=False, qk_norm_eps=1e-5,
+    rotary_base=10000.0,
 ):
     """All heads in one qkv projection + batched matmuls (MXU-shaped).
     attn_bias: [B, 1 or H, Tq, Tk] additive mask (−1e9 at masked slots).
@@ -134,7 +135,12 @@ def multi_head_attention(
     rotary=True applies rotary position embedding (RoPE) to q and k after
     the head split — full-sequence positions arange(T), or the cache's
     current position on the decode path (cached keys store pre-rotated,
-    so relative rotations stay exact across steps).
+    so relative rotations stay exact across steps); rotary_base is RoPE's
+    theta.
+
+    qk_norm=True normalises the q and the k projection with an rms_norm
+    each (own [d] weight, over all heads jointly, as OLMoE does) before
+    the head split, and so before rotary.
 
     RAGGED cache mode (the continuous-batching serving step): a cache
     dict carrying "pos_rows" [B] + "width_rows" [B] (and "pos_mat"
@@ -156,6 +162,11 @@ def multi_head_attention(
                   param_attr=_pa("mha_k.w"))
     v = layers.fc(values, size=n_kv * dh, num_flatten_dims=2, bias_attr=False,
                   param_attr=_pa("mha_v.w"))
+    if qk_norm:
+        q = layers.rms_norm(q, epsilon=qk_norm_eps,
+                            param_attr=_pa("mha_q_norm.w"))
+        k = layers.rms_norm(k, epsilon=qk_norm_eps,
+                            param_attr=_pa("mha_k_norm.w"))
 
     def split_heads(x, heads):
         b, t = x.shape[0], x.shape[1]
@@ -193,8 +204,8 @@ def multi_head_attention(
             if rpos is None:
                 raise KeyError(
                     "cached rotary attention needs pos/pos_vec/pos_mat")
-        q = layers.rotary_embed(q, pos=rpos)
-        k = layers.rotary_embed(k, pos=rpos)
+        q = layers.rotary_embed(q, pos=rpos, base=rotary_base)
+        k = layers.rotary_embed(k, pos=rpos, base=rotary_base)
     if cache is not None:
         if attn_bias is not None or kpad_bias is not None:
             raise ValueError(

@@ -304,6 +304,20 @@ def _layer_norm(ctx, ins, attrs):
     }
 
 
+@register("rms_norm")
+def _rms_norm(ctx, ins, attrs):
+    """x * rsqrt(mean(x^2) + eps) * w over the last axis.  The statistic
+    and the normalisation run in f32 whatever X's dtype and Y leaves in
+    X's dtype, so the op is dtype-transparent for the AMP trunk pass like
+    layer_norm."""
+    x = ins["X"][0]
+    eps = attrs.get("epsilon", 1e-5)
+    xf = x.astype(jnp.float32)
+    inv = jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    y = xf * inv * ins["Scale"][0].astype(jnp.float32)
+    return {"Y": [y.astype(x.dtype)]}
+
+
 @register("group_norm")
 def _group_norm(ctx, ins, attrs):
     x = ins["X"][0]
@@ -1584,6 +1598,19 @@ def _ln_infer(op, ins):
         stat = VarInfo(x.shape[:begin], None)
     return {"Y": [VarInfo(x.shape, x.dtype)],
             "Mean": [stat], "Variance": [stat]}
+
+
+@register_infer("rms_norm", req_ins=("X", "Scale"), req_outs=("Y",))
+def _rms_infer(op, ins):
+    x, w = _vi(ins, "X"), _vi(ins, "Scale")
+    if x is None:
+        return {}
+    if (x.shape is not None and w is not None and w.shape is not None
+            and x.shape[-1] >= 0 and tuple(w.shape) != (x.shape[-1],)):
+        raise InferError(
+            "rms_norm Scale%s does not match X%s's last axis"
+            % (w.shape, x.shape))
+    return {"Y": [VarInfo(x.shape, x.dtype)]}
 
 
 @register_infer("dropout", req_ins=("X",))

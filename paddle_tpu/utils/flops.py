@@ -98,6 +98,20 @@ def program_flops(program, batch_hint=1):
             k = x[-1]
             n2 = w[0] if op.attrs.get("transpose_w", False) else w[1]
             total += factor * 2.0 * m * k * n2
+        elif t == "moe_ffn":
+            # the router, and the two grouped matmuls over the N * top_k
+            # rows that are routed: [., d] x [d, 2f] and [., f] x [f, d]
+            x = _shape(blk, op.inputs.get("X", [""])[0], batch_hint)
+            wr = _shape(blk, op.inputs.get("RouterW", [""])[0], batch_hint)
+            wd = _shape(blk, op.inputs.get("DownW", [""])[0], batch_hint)
+            if not x or not wr or not wd:
+                continue
+            rows, d = _prod(x[:-1]), x[-1]
+            # a grad op carries its forward's attrs under __fwd_attrs__
+            attrs = op.attrs.get("__fwd_attrs__", op.attrs)
+            routed = rows * int(attrs["top_k"])
+            total += factor * 2.0 * (rows * d * wr[-1]
+                                     + routed * 3 * d * wd[1])
         elif t == "matmul":
             x = _shape(blk, op.inputs.get("X", [""])[0], batch_hint)
             y = _shape(blk, op.inputs.get("Y", [""])[0], batch_hint)

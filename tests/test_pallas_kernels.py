@@ -1479,3 +1479,40 @@ def test_every_kernel_lowers_for_tpu_without_a_chip(monkeypatch):
                 x, w, b).lower(lowering_platforms=("tpu",))
     finally:
         flags.set_flags({"kernel_autotune": old})
+
+
+def test_grouped_matmul_lowers_for_tpu_without_a_chip(monkeypatch):
+    """moe_ffn's grouped matmuls are megablox's Pallas kernels on the chip
+    (ops/moe_ops.grouped_matmul chooses by platform and shape): cross-lower
+    the op's forward and backward at a tile-aligned size for the TPU
+    platform on this host, as the test above does for the repo's own
+    kernels.  Six Mosaic calls: two matmuls forward, and for each the
+    rows' gradient (gmm over rhs^T) and the weights' (tgmm)."""
+    from paddle_tpu.core.registry import LowerCtx
+    from paddle_tpu.ops import moe_ops
+    from paddle_tpu.ops import kernel_tuning as kt
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n, d, f, e, k = 256, 256, 128, 8, 2
+    assert moe_ops._megablox_fits(
+        jax.ShapeDtypeStruct((n * k, d), jnp.bfloat16),
+        jax.ShapeDtypeStruct((e, d, 2 * f), jnp.bfloat16))
+    assert not moe_ops._megablox_fits(
+        jax.ShapeDtypeStruct((n * k - 8, d), jnp.bfloat16),
+        jax.ShapeDtypeStruct((e, d, 2 * f), jnp.bfloat16))
+
+    def loss(x, wr, wgu, wd):
+        out = moe_ops._moe_ffn(
+            LowerCtx(), {"X": [x], "RouterW": [wr], "GateUpW": [wgu],
+                         "DownW": [wd]}, {"top_k": k})
+        return out["Y"][0].astype(jnp.float32).sum() + out["AuxLoss"][0].sum()
+
+    before = kt.attribution()["pallas_hits"].get("grouped_matmul", 0)
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).trace(
+        jax.ShapeDtypeStruct((n, d), jnp.float32),
+        jax.ShapeDtypeStruct((d, e), jnp.float32),
+        jax.ShapeDtypeStruct((e, d, 2 * f), jnp.bfloat16),
+        jax.ShapeDtypeStruct((e, f, d), jnp.bfloat16),
+    ).lower(lowering_platforms=("tpu",))
+    assert lowered.as_text().count("tpu_custom_call") == 6
+    assert kt.attribution()["pallas_hits"]["grouped_matmul"] == before + 2

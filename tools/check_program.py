@@ -151,6 +151,20 @@ def case_gpt2_train():
     return main, {"fetches": [v.name for v in fetches]}
 
 
+def case_olmoe_train_amp():
+    """Routed experts, RMSNorm and QK-norm under the bf16 AMP pass."""
+    from paddle_tpu.models import olmoe
+
+    class O(olmoe.OLMoEConfig):
+        vocab_size, hidden_size, intermediate_size = 64, 32, 16
+        num_hidden_layers, num_attention_heads, num_key_value_heads = 2, 2, 2
+        num_experts, num_experts_per_tok = 4, 2
+
+    main, _s, _f, fetches = olmoe.olmoe_lm_program(O, seq_len=SEQ,
+                                                   use_bf16=True)
+    return main, {"fetches": [v.name for v in fetches]}
+
+
 def case_gpt2_decode():
     from paddle_tpu.models import gpt2
 
@@ -275,6 +289,7 @@ CASES = [
     ("tfm_amp", case_tfm_amp, False),
     ("tfm_remat", case_tfm_remat, False),
     ("gpt2_train_fused", case_gpt2_train, False),
+    ("olmoe_train_amp", case_olmoe_train_amp, False),
     ("gpt2_decode_step", case_gpt2_decode, True),
     ("gpt2_ragged_serving", case_gpt2_ragged, True),
     ("gpt2_ragged_serving_tp", case_gpt2_ragged_tp, True),
