@@ -6,9 +6,10 @@ One process, no children (a chip belongs to one process).  In order:
   train      Transformer-base at full width (bf16 AMP, fused attention,
              Pallas kernels on) through Executor(TPUPlace(0)).run: startup,
              then 2 warm-up + 5 steps on one fixed batch.  Checks finite and
-             falling loss, flat compile count, loss on a tpu device, all four
-             kernel families dispatched AND Mosaic custom calls present in the
-             compiled step's HLO, every fuse pass fired.
+             falling loss, flat compile count, loss on a tpu device, the three
+             kernel families of this model dispatched AND Mosaic custom calls
+             present in the compiled step's HLO, the tiled vocabulary head
+             engaged, every fuse pass fired.
   numerics   the same program with use_pallas off, same seed, 2 steps: losses
              must agree with the kernel run within the bf16 fuse-pass contract.
   kernels    one compiled call (forward and, where the kernel has its own,
@@ -155,8 +156,10 @@ def phase_train(ctx):
                                           r["compiles_end"]))
     require(r["loss_devices"] == [ctx["platform"]], "loss-on-device",
             str(r["loss_devices"]))
-    for fam in ("attention", "layernorm", "matmul_epilogue", "xent"):
+    for fam in ("attention", "layernorm", "matmul_epilogue"):
         require(hits.get(fam, 0) > 0, "kernel-family-dispatched", fam)
+    require(r["attribution"]["dense_vjp_hits"].get("xent", 0) > 0,
+            "tiled-head-engaged", str(r["attribution"]["dense_vjp_hits"]))
     for k, n in fused.items():
         require(n > 0, "fuse-pass-fired", k)
     if ctx["rehearse"]:
@@ -231,7 +234,7 @@ def kernel_cases(rehearse):
 
     # Transformer-base train step shapes: bs128 x seq256, 8 heads of 64
     BH, T, D = S(1024, 4), S(256, 16), S(64, 32)
-    R, H, F, V = S(32768, 64), S(512, 64), S(2048, 128), S(10000, 300)
+    R, H, F = S(32768, 64), S(512, 64), S(2048, 128)
     scale = 1.0 / D ** 0.5
 
     def attn_args():
@@ -259,13 +262,6 @@ def kernel_cases(rehearse):
         lambda: (arr(0, (R, H), bf16), arr(1, (H, F), bf16, H ** -0.5),
                  arr(2, (F,), f32, 0.1)),
         "transformer FFN in-projection + relu")
-
-    cases["fused_linear_xent"] = (
-        with_grads(lambda x, w, l: pk.fused_linear_xent(x, w, l, 0.1), 2),
-        with_grads(lambda x, w, l: pk._linear_xent_dense(x, w, l, 0.1), 2),
-        lambda: (arr(0, (R, H), bf16), arr(1, (H, V), bf16, H ** -0.5),
-                 ints(2, (R,), V)),
-        "transformer label-smoothed loss, vocab %d" % V)
 
     # GPT-2 345M: 16 heads of 64, n_ctx 1024, d_model 1024
     SB, SW, ST = S(128, 4), S(8, 4), S(1024, 32)
@@ -330,24 +326,6 @@ def kernel_cases(rehearse):
         with_grads(sxent_dense, 1),
         lambda: (arr(0, (CR, CC), f32), ints(1, (CR,), CC)),
         "ResNet-50 classification loss")
-
-    # stacked LSTM / seq2seq GRU at the reference's width 512
-    RB, RT, RH = S(32, 8), S(32, 6), S(512, 16)
-    cases["fused_gru"] = (
-        lambda x, w, h, n: (pk.fused_gru(x, w, h, n),),
-        lambda x, w, h, n: (pk._gru_seq_dense(x, w, h, n),),
-        lambda: (arr(0, (RB, RT, 3 * RH), f32, 0.5),
-                 arr(1, (RH, 3 * RH), f32, RH ** -0.5),
-                 arr(2, (RB, RH), f32, 0.1), ints(3, (RB,), RT) + 1),
-        "seq2seq GRU encoder")
-    cases["fused_lstm"] = (
-        lambda x, w, h, c, n: pk.fused_lstm(x, w, h, c, n),
-        lambda x, w, h, c, n: pk._lstm_seq_dense(x, w, h, c, n),
-        lambda: (arr(0, (RB, RT, 4 * RH), f32, 0.5),
-                 arr(1, (RH, 4 * RH), f32, RH ** -0.5),
-                 arr(2, (RB, RH), f32, 0.1), arr(3, (RB, RH), f32, 0.1),
-                 ints(4, (RB,), RT) + 1),
-        "stacked dynamic LSTM")
     return cases
 
 

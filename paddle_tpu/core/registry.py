@@ -16,7 +16,7 @@ operands; that is no promise of single compute: under memory pressure the
 compiler's rematerialisation ran the vocabulary head's forward twice
 (``%fusion.5264.remat``, root PERF.md section 5), and a lowering with a
 custom VJP must keep its forward identical on both sides to be merged
-(``pallas_kernels.linear_xent_tiled`` leaves its scan one output for it).
+(``math_ops.linear_xent_tiled`` leaves its scan one output for it).
 """
 
 

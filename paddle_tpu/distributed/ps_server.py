@@ -3184,8 +3184,8 @@ def run_pserver(program, scope, executor=None):
     finally:
         server.shutdown()
         # recovery observability: the server-side sibling of the
-        # trainers' COUNTERS line (distinct prefix — bench.py sums
-        # trainer COUNTERS lines and must not fold these in)
+        # trainers' COUNTERS line (distinct prefix — a reader summing
+        # trainer COUNTERS lines must not fold these in)
         import json as _json
 
         with service._cv:

@@ -19,8 +19,8 @@ timings are meaningless, so misses seed the heuristic default and are
 counted, never searched.
 
 Attribution counters (`note_kernel` / `attribution()`): per-family
-pallas-hit counts and tuning hit/miss/search totals, read by bench.py so
-an MFU regression can be pinned to "kernel X stopped dispatching" or
+pallas-hit counts and tuning hit/miss/search totals (chip_smoke.py
+reads them), so an MFU regression can be pinned to "kernel X stopped dispatching" or
 "cache went cold" instead of guessed at.  Counts tick at TRACE time
 (once per compiled program, not per step) — they attribute what the
 compiled step contains, not how often it runs.
@@ -301,7 +301,7 @@ def tuned_params(kernel, shapes, dtype, candidates, default, measure=None):
 
 def note_kernel(family, n=1):
     """Count a pallas dispatch for `family` (attention / matmul-epilogue
-    / xent / layernorm / recurrent).  Trace-time counter; muted while a
+    / xent / layernorm).  Trace-time counter; muted while a
     block-size search times candidates (those traces are not program
     content)."""
     if getattr(_searching, "active", False):

@@ -6,12 +6,16 @@ is one pure JAX rule that XLA fuses into neighboring ops (replacing the
 hand-fused mkldnn/cudnn kernels and ``math/`` functor library).
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..core.registry import register
 from .common import bcast_y, jdt
+from .kernel_tuning import note_dense_vjp
+from .spmd_epilogue import mesh_ctx
 
 
 # ---------------------------------------------------------------------------
@@ -433,6 +437,200 @@ def _smooth_label_xent(ctx, ins, attrs):
     return {"Loss": [loss.astype(logits.dtype)]}
 
 
+def _linear_xent_dense(x2d, w, labels, eps=0.0):
+    """The reference linear_xent_tiled is tested against: the [R, V]
+    logits under jax's autodiff.  Same label convention as
+    smooth_label_xent: out-of-range labels contribute the smoothing
+    term only."""
+    lg = jnp.dot(x2d, w, preferred_element_type=jnp.float32)
+    v = lg.shape[-1]
+    lse = jax.scipy.special.logsumexp(lg, axis=-1, keepdims=True)
+    lbl = labels.astype(jnp.int32).reshape(-1)
+    onehot_gold = jnp.sum(
+        jnp.where(jnp.arange(v)[None, :] == lbl[:, None], lg, 0.0),
+        axis=-1, keepdims=True)
+    valid = ((lbl >= 0) & (lbl < v))[:, None]
+    loss = jnp.where(valid, (1.0 - eps) * (lse - onehot_gold), 0.0)
+    if eps:
+        loss = loss + eps * (lse - jnp.mean(lg, axis=-1, keepdims=True))
+    return loss
+
+
+# ---------------------------------------------------------------------------
+# the vocabulary head: _linear_xent_dense's arithmetic as a custom VJP in
+# plain XLA ops that walks the rows in tiles, so no [R, V] array exists
+# and the logits gradient is formed once, narrowed to the operands' dtype
+# and fed to both gradient matmuls
+# ---------------------------------------------------------------------------
+# the f32 [rows_t, V] logits block one tile may hold: 1024 rows at
+# V=50257, 4096 at V=10000
+_LXENT_TILE_BYTES = 256 << 20
+
+
+def _lxent_tile_len(batch, length, vocab):
+    """Steps of the scanned axis a tile takes, from the shapes alone: the
+    largest divisor of `length` (a multiple of 8 when tiling at all)
+    whose f32 [batch * steps, vocab] block fits _LXENT_TILE_BYTES and is
+    over half of what fits; without one, what fits rounded down to 8 and
+    a padded last tile."""
+    cap = max(1, _LXENT_TILE_BYTES // (4 * vocab * batch))
+    if cap >= length:
+        return length
+    align = 8 if cap >= 8 else 1
+    top = cap - cap % align
+    for steps in range(top, cap // 2, -align):
+        if length % steps == 0:
+            return steps
+    return top
+
+
+def _lxent_split(a, steps, fill):
+    """[B, T, ...] -> [n, B, steps, ...] tiles of axis 1, the last one
+    padded with `fill`."""
+    b, t = a.shape[:2]
+    n = -(-t // steps)
+    if n * steps != t:
+        pad = [(0, 0), (0, n * steps - t)] + [(0, 0)] * (a.ndim - 2)
+        a = jnp.pad(a, pad, constant_values=fill)
+    return jnp.moveaxis(a.reshape((b, n, steps) + a.shape[2:]), 1, 0)
+
+
+def _lxent_join(tiles, length):
+    """Inverse of _lxent_split: [n, B, steps, ...] -> [B, length, ...]."""
+    a = jnp.moveaxis(tiles, 0, 1)
+    a = a.reshape((a.shape[0], -1) + a.shape[3:])
+    return a[:, :length]
+
+
+def _lxent_scan(tile, init, tiles):
+    """lax.scan of `tile` over the leading axis of `tiles`; a single tile
+    is called in line, so a partitioned program holds no loop at all."""
+    if jax.tree_util.tree_leaves(tiles)[0].shape[0] > 1:
+        return jax.lax.scan(tile, init, tiles)
+    carry, out = tile(init, jax.tree_util.tree_map(lambda a: a[0], tiles))
+    return carry, out[None]
+
+
+def _lxent_as_tiles(x, w, labels, transpose_w, one_tile):
+    """(x tiles [n, B, steps, H], label tiles [n, B, steps], w in the
+    dots' dtype, V, (B, T)).  A [R, H] input tiles its rows; with a time
+    axis ([..., T, H]) that axis is the scanned one, so an axis the mesh
+    shards over dp (batch) stays whole in every tile."""
+    dt = jnp.result_type(x.dtype, w.dtype)
+    h = x.shape[-1]
+    t = x.shape[-2]
+    x3 = x.reshape(-1, t, h).astype(dt)
+    lbl = labels.astype(jnp.int32).reshape(x3.shape[:2])
+    vocab = w.shape[0 if transpose_w else 1]
+    steps = t if one_tile else _lxent_tile_len(x3.shape[0], t, vocab)
+    return (_lxent_split(x3, steps, 0), _lxent_split(lbl, steps, -1),
+            w.astype(dt), vocab, x3.shape[:2])
+
+
+def _lxent_tile_logits(x_t, w, transpose_w):
+    """f32 [B, steps, V] logits of one tile; the tied [V, H] table
+    contracts its second axis, never a transposed copy."""
+    return jax.lax.dot_general(
+        x_t, w, (((2,), (1 if transpose_w else 0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
+def _lxent_tiled_fwd(x, w, labels, eps, transpose_w, one_tile):
+    note_dense_vjp("xent")
+    xs, ls, wd, vocab, (_, t) = _lxent_as_tiles(x, w, labels, transpose_w,
+                                                one_tile)
+
+    def tile(_, x_l):
+        x_t, l_t = x_l
+        z = _lxent_tile_logits(x_t, wd, transpose_w)
+        lse = jax.scipy.special.logsumexp(z, axis=-1)
+        cols = jax.lax.broadcasted_iota(jnp.int32, z.shape, 2)
+        gold = jnp.sum(jnp.where(cols == l_t[..., None], z, 0.0), axis=-1)
+        valid = (l_t >= 0) & (l_t < vocab)
+        loss = jnp.where(valid, (1.0 - eps) * (lse - gold), 0.0)
+        if eps:
+            loss = loss + eps * (lse - jnp.mean(z, axis=-1))
+        # ONE output: the forward op reads the loss, the grad op's
+        # re-traced forward reads lse; as two outputs each side's dead
+        # code elimination would prune a different one, the two scans
+        # would differ and XLA's CSE could not merge them into one
+        return None, jnp.stack([loss, lse], axis=-1)
+
+    with jax.named_scope("tile_fwd"):
+        _, stats = _lxent_scan(tile, None, (xs, ls))
+    stats = _lxent_join(stats, t)
+    loss = stats[..., 0].reshape(x.shape[:-1] + (1,))
+    return loss, stats[..., 1]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def linear_xent_tiled(x, w, labels, eps=0.0, transpose_w=False,
+                      one_tile=False):
+    """Projected cross entropy in plain XLA ops with a hand-written VJP:
+    _linear_xent_dense's arithmetic (operands in their own dtype to the
+    MXU, f32 accumulation, f32 softmax statistics; out-of-range labels
+    contribute the smoothing term only) without an [R, V] array.  x
+    [..., H], w [H, V] ([V, H] with transpose_w), labels x.shape[:-1]
+    int; returns x.shape[:-1] + (1,) f32 losses.
+
+    Forward: a scan over row tiles (_lxent_as_tiles), per tile the f32
+    logits, reduced to the loss and lse; only lse is saved beside x, w
+    and the labels.  Backward: per tile the logits again, the logits
+    gradient formed ONCE in f32, narrowed to the operands' dtype, and
+    that one array fed to both gradient matmuls; dw accumulates in f32
+    across the tiles.
+
+    one_tile: the whole input as a single tile and no loop — for a
+    program GSPMD partitions, where the dw carried through a scan would
+    be all-reduced over dp once per tile instead of once."""
+    return _lxent_tiled_fwd(x, w, labels, eps, transpose_w, one_tile)[0]
+
+
+def _lxent_tiled_vjp_fwd(x, w, labels, eps, transpose_w, one_tile):
+    loss, lse = _lxent_tiled_fwd(x, w, labels, eps, transpose_w, one_tile)
+    return loss, (x, w, labels, lse)
+
+
+def _lxent_tiled_vjp_bwd(eps, transpose_w, one_tile, res, dy):
+    x, w, labels, lse = res
+    xs, ls, wd, vocab, (b, t) = _lxent_as_tiles(x, w, labels, transpose_w,
+                                                one_tile)
+    steps = xs.shape[2]
+    # a padded row takes dy = 0, so it reaches neither dx nor dw
+    dys = _lxent_split(dy.astype(jnp.float32).reshape(b, t), steps, 0)
+    lses = _lxent_split(lse, steps, 0)
+
+    def tile(dw, tile_in):
+        x_t, l_t, lse_t, dy_t = tile_in
+        z = _lxent_tile_logits(x_t, wd, transpose_w)
+        p = jnp.exp(z - lse_t[..., None])
+        cols = jax.lax.broadcasted_iota(jnp.int32, z.shape, 2)
+        onehot = (cols == l_t[..., None]).astype(jnp.float32)
+        valid = ((l_t >= 0) & (l_t < vocab)).astype(jnp.float32)
+        g = ((1.0 - eps) * valid)[..., None] * (p - onehot)
+        if eps:
+            g = g + eps * (p - 1.0 / vocab)
+        g = (g * dy_t[..., None]).astype(wd.dtype)
+        dx_t = jax.lax.dot_general(
+            g, wd, (((2,), (0 if transpose_w else 1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        lhs, rhs = (g, x_t) if transpose_w else (x_t, g)
+        dw = dw + jax.lax.dot_general(
+            lhs, rhs, (((0, 1), (0, 1)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return dw, dx_t.astype(x.dtype)
+
+    with jax.named_scope("tile_bwd"):
+        dw, dxs = _lxent_scan(tile, jnp.zeros(w.shape, jnp.float32),
+                              (xs, ls, lses, dys))
+    dx = _lxent_join(dxs, t).reshape(x.shape)
+    dlbl = np.zeros(labels.shape, dtype=jax.dtypes.float0)
+    return dx, dw.astype(w.dtype), dlbl
+
+
+linear_xent_tiled.defvjp(_lxent_tiled_vjp_fwd, _lxent_tiled_vjp_bwd)
+
+
 @register("fused_linear_xent", no_grad_inputs=("Label",))
 def _fused_linear_xent_op(ctx, ins, attrs):
     """Projected cross entropy — the fused target of
@@ -443,52 +641,23 @@ def _fused_linear_xent_op(ctx, ins, attrs):
     smooth_label_xent: out-of-range labels contribute the smoothing
     term only.
 
-    Default flags (every measured cell): pallas_kernels.
-    linear_xent_tiled, a custom VJP in plain XLA ops that walks the rows
-    in tiles of at most ~256 MiB of f32 logits, so no [R, V] array
-    exists in either direction; the backward recomputes a tile's logits
-    from the saved lse, forms the logits gradient once, narrows it to
-    the operands' dtype and feeds both gradient matmuls.  transpose_w
-    is the dots' dimension numbers, not a copy of the table.  Under a
-    live GSPMD mesh (spmd_epilogue.mesh_ctx) the input is one tile and
-    there is no loop: the partitioner would all-reduce a scan's dw
-    carry over dp once per tile.  The engagement counts under
-    kernel_tuning.attribution()["dense_vjp_hits"]["xent"].
-
-    Under FLAGS_use_pallas the Mosaic kernels come first
-    (pallas_kernels.fused_linear_xent: vocab tiles through an online
-    logsumexp, logits never in HBM); they read [H, V]-layout tiles, so
-    transpose_w materializes a transposed copy of W per step there (a
-    [V, H]-layout kernel variant would remove it: known limit).
-    _linear_xent_dense (the [R, V] logits under jax's autodiff) is the
-    reference both are tested against."""
-    from .pallas_kernels import (
-        fused_linear_xent,
-        linear_xent_tiled,
-        use_pallas,
-        use_pallas_unwrapped,
-    )
-
+    The lowering is linear_xent_tiled, a custom VJP in plain XLA ops
+    that walks the rows in tiles of at most ~256 MiB of f32 logits, so
+    no [R, V] array exists in either direction; the backward recomputes
+    a tile's logits from the saved lse, forms the logits gradient once,
+    narrows it to the operands' dtype and feeds both gradient matmuls.
+    transpose_w is the dots' dimension numbers, not a copy of the table.
+    Under a live GSPMD mesh (spmd_epilogue.mesh_ctx) the input is one
+    tile and there is no loop: the partitioner would all-reduce a scan's
+    dw carry over dp once per tile.  The engagement counts under
+    kernel_tuning.attribution()["dense_vjp_hits"]["xent"]."""
     x = ins["X"][0]
     w = ins["W"][0]
     label = ins["Label"][0]
     eps = float(attrs.get("epsilon", 0.0))
     transpose_w = bool(attrs.get("transpose_w", False))
-    loss = None
-    if use_pallas():
-        from .spmd_epilogue import spmd_linear_xent
-
-        wt = w.T if transpose_w else w
-        x2 = x.reshape(-1, x.shape[-1])
-        lbl = label.reshape(-1).astype(jnp.int32)
-        loss = spmd_linear_xent(ctx, x2, wt, lbl, eps, transpose_w)
-        if loss is None and use_pallas_unwrapped():
-            loss = fused_linear_xent(x2, wt, lbl, eps)
-    if loss is None:
-        from .spmd_epilogue import mesh_ctx
-
-        loss = linear_xent_tiled(x, w, label.reshape(x.shape[:-1]), eps,
-                                 transpose_w, mesh_ctx() is not None)
+    loss = linear_xent_tiled(x, w, label.reshape(x.shape[:-1]), eps,
+                             transpose_w, mesh_ctx() is not None)
     # the per-row loss leaves in f32 whatever X's dtype, like every
     # other softmax statistic: under AMP (bf16 X) a cast to X's dtype
     # rounds each row's loss to 8 bits before the cast-back op AMP

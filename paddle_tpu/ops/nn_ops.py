@@ -611,6 +611,35 @@ def _fused_embedding_fc_lstm(ctx, ins, attrs):
     }
 
 
+def _seq_lens(ins, bsz, t):
+    """[bsz] int32 row lengths: the SeqLen input, else every row is t."""
+    if not ins.get("SeqLen"):
+        return jnp.full((bsz,), t, jnp.int32)
+    return ins["SeqLen"][0].reshape(-1).astype(jnp.int32)
+
+
+def _lstm_seq_dense(xproj, w, h0, c0, lens, reverse=False):
+    """Masked LSTM recurrence over [B, T, 4H] projected gates: one
+    lax.scan over time (from the last step when `reverse`); a row holds
+    its state at steps >= its length, so the final carry IS its last
+    valid h/c.  Returns (hidden_seq, cell_seq, last_h, last_c)."""
+
+    def step(carry, inp):
+        h, c = carry
+        xt, t = inp
+        c_new, h_new = _lstm_cell(c, h, xt + h @ w)
+        act = (t < lens)[:, None].astype(h.dtype)
+        c_new = act * c_new + (1 - act) * c
+        h_new = act * h_new + (1 - act) * h
+        return (h_new, c_new), (h_new, c_new)
+
+    xs = jnp.swapaxes(xproj, 0, 1)
+    ts = jnp.arange(xproj.shape[1])
+    (h_fin, c_fin), (hs, cs) = jax.lax.scan(step, (h0, c0), (xs, ts),
+                                            reverse=reverse)
+    return jnp.swapaxes(hs, 0, 1), jnp.swapaxes(cs, 0, 1), h_fin, c_fin
+
+
 @register("padded_lstm")
 def _padded_lstm(ctx, ins, attrs):
     """TPU-native LSTM over padded [batch, time, 4*hidden] projected input.
@@ -623,73 +652,44 @@ def _padded_lstm(ctx, ins, attrs):
     """
     xproj = ins["Input"][0]  # [B, T, 4H]
     w = ins["Weight"][0]  # [H, 4H]
-    b = ins["Bias"][0] if ins.get("Bias") else None
-    seq_len = ins["SeqLen"][0] if ins.get("SeqLen") else None
     bsz, t, h4 = xproj.shape
     hid = h4 // 4
     h0 = ins["H0"][0] if ins.get("H0") else jnp.zeros((bsz, hid), xproj.dtype)
     c0 = ins["C0"][0] if ins.get("C0") else jnp.zeros((bsz, hid), xproj.dtype)
-    is_reverse = attrs.get("is_reverse", False)
+    if ins.get("Bias"):  # folds into the projected gates
+        xproj = xproj + ins["Bias"][0].reshape(1, 1, -1)
+    lens = _seq_lens(ins, bsz, t)
+    hs, cs, h_fin, c_fin = _lstm_seq_dense(
+        xproj, w, h0, c0, lens, attrs.get("is_reverse", False))
+    return {"Hidden": [hs], "CellSeq": [cs], "LastH": [h_fin],
+            "LastC": [c_fin]}
 
-    # forward direction: one shared masked recurrence (_lstm_seq_dense,
-    # also the fused path's backward recompute — the GRU pattern, no
-    # formula triplication), with the VMEM-resident fused kernel
-    # (jit_kernel lstm / fusion_lstm slot) when eligible: lane-aligned
-    # hidden, working set within VMEM.  Bias folds into the projected
-    # gates either way.
-    from .pallas_kernels import (
-        _lstm_seq_dense,
-        fused_lstm,
-        recurrent_ok,
-        use_pallas_unwrapped,
-    )
 
-    if not is_reverse:
-        lens = (
-            seq_len.reshape(-1).astype(jnp.int32)
-            if seq_len is not None
-            else jnp.full((bsz,), t, jnp.int32)
-        )
-        xg = xproj if b is None else xproj + b.reshape(1, 1, -1)
-        if use_pallas_unwrapped() and recurrent_ok(bsz, t, hid, 4):
-            hs, cs = fused_lstm(xg, w, h0, c0, lens)
-        else:
-            hs, cs = _lstm_seq_dense(xg, w, h0, c0, lens)
-        # masking holds state past each row's length: the final step IS
-        # the last valid h/c
-        return {
-            "Hidden": [hs],
-            "CellSeq": [cs],
-            "LastH": [hs[:, -1, :]],
-            "LastC": [cs[:, -1, :]],
-        }
-    # reverse direction only from here (the forward path returned above):
-    # scan the flipped sequence, flip the outputs back
-    xs = jnp.flip(jnp.swapaxes(xproj, 0, 1), 0)  # [T, B, 4H]
-    steps = jnp.flip(jnp.arange(t))
+def _gru_seq_dense(xproj, w, h0, lens, reverse=False):
+    """Masked GRU recurrence over [B, T, 3H] projected input, gate layout
+    [update|reset|candidate], blend h = u*c + (1-u)*h_prev
+    (math/detail/gru_kernel.h:58-63: out = prev - u*prev + u*state): one
+    lax.scan over time (from the last step when `reverse`); a row holds h
+    at steps >= its length, so the final carry IS its last valid hidden
+    state (a length-0 row yields h0).  Returns (hidden_seq, last_h)."""
+    hid = xproj.shape[-1] // 3
+    w_uz, w_c = w[:, : 2 * hid], w[:, 2 * hid:]
 
-    def step(carry, inp):
-        c_prev, h_prev = carry
-        x_t, t_idx = inp
-        gates = x_t + h_prev @ w
-        if b is not None:
-            gates = gates + b
-        c, h = _lstm_cell(c_prev, h_prev, gates)
-        if seq_len is not None:
-            m = (t_idx < seq_len).astype(h.dtype)[:, None]
-            c = m * c + (1 - m) * c_prev
-            h = m * h + (1 - m) * h_prev
-        return (c, h), (h, c)
+    def step(h, inp):
+        xt, t = inp
+        gates = xt[:, : 2 * hid] + h @ w_uz
+        u = jax.nn.sigmoid(gates[:, :hid])
+        r = jax.nn.sigmoid(gates[:, hid:])
+        c = jnp.tanh(xt[:, 2 * hid:] + (r * h) @ w_c)
+        h_new = u * c + (1.0 - u) * h
+        act = (t < lens)[:, None].astype(h.dtype)
+        h_new = act * h_new + (1 - act) * h
+        return h_new, h_new
 
-    (c_fin, h_fin), (hs, cs) = jax.lax.scan(step, (c0, h0), (xs, steps))
-    hs = jnp.flip(hs, 0)
-    cs = jnp.flip(cs, 0)
-    return {
-        "Hidden": [jnp.swapaxes(hs, 0, 1)],
-        "CellSeq": [jnp.swapaxes(cs, 0, 1)],
-        "LastH": [h_fin],
-        "LastC": [c_fin],
-    }
+    xs = jnp.swapaxes(xproj, 0, 1)
+    ts = jnp.arange(xproj.shape[1])
+    h_fin, hs = jax.lax.scan(step, h0, (xs, ts), reverse=reverse)
+    return jnp.swapaxes(hs, 0, 1), h_fin
 
 
 @register("padded_gru")
@@ -697,61 +697,13 @@ def _padded_gru(ctx, ins, attrs):
     """GRU over padded [batch, time, 3*hidden] projected input (gru_op analog)."""
     xproj = ins["Input"][0]
     w = ins["Weight"][0]  # [H, 3H] -> [update|reset, candidate]
-    seq_len = ins["SeqLen"][0] if ins.get("SeqLen") else None
     bsz, t, h3 = xproj.shape
-    hid = h3 // 3
-    h0 = ins["H0"][0] if ins.get("H0") else jnp.zeros((bsz, hid), xproj.dtype)
-    from .pallas_kernels import (
-        _gru_seq_dense,
-        fused_gru,
-        recurrent_ok,
-        use_pallas_unwrapped,
-    )
-
-    if not attrs.get("is_reverse", False):
-        lens = (
-            seq_len.reshape(-1).astype(jnp.int32)
-            if seq_len is not None
-            else jnp.full((bsz,), t, jnp.int32)
-        )
-        if use_pallas_unwrapped() and recurrent_ok(bsz, t, hid, 3):
-            hs = fused_gru(xproj, w, h0, lens)
-        else:
-            # one shared cell implementation (also the fused path's
-            # backward recompute) — no formula triplication
-            hs = _gru_seq_dense(xproj, w, h0, lens)
-        # masking holds h past each row's length, so the final step IS the
-        # last valid hidden state (lens==0 rows yield h0)
-        return {"Hidden": [hs], "LastH": [hs[:, -1, :]]}
-    w_rz = w[:, : 2 * hid]
-    w_c = w[:, 2 * hid :]
-    is_reverse = attrs.get("is_reverse", False)
-    xs = jnp.swapaxes(xproj, 0, 1)
-    if is_reverse:
-        xs = jnp.flip(xs, 0)
-    steps = jnp.arange(t)
-    if is_reverse:
-        steps = jnp.flip(steps)
-
-    def step(h_prev, inp):
-        x_t, t_idx = inp
-        x_rz = x_t[:, : 2 * hid]
-        x_c = x_t[:, 2 * hid :]
-        # gate layout [update|reset|state], blend h = u*c + (1-u)*h_prev
-        # (math/detail/gru_kernel.h:58-63: out = prev - u*prev + u*state)
-        uz = jax.nn.sigmoid(x_rz + h_prev @ w_rz)
-        u, r = jnp.split(uz, 2, axis=-1)
-        c = jnp.tanh(x_c + (r * h_prev) @ w_c)
-        h = u * c + (1 - u) * h_prev
-        if seq_len is not None:
-            m = (t_idx < seq_len).astype(h.dtype)[:, None]
-            h = m * h + (1 - m) * h_prev
-        return h, h
-
-    h_fin, hs = jax.lax.scan(step, h0, (xs, steps))
-    if is_reverse:
-        hs = jnp.flip(hs, 0)
-    return {"Hidden": [jnp.swapaxes(hs, 0, 1)], "LastH": [h_fin]}
+    h0 = (ins["H0"][0] if ins.get("H0")
+          else jnp.zeros((bsz, h3 // 3), xproj.dtype))
+    lens = _seq_lens(ins, bsz, t)
+    hs, h_fin = _gru_seq_dense(xproj, w, h0, lens,
+                               attrs.get("is_reverse", False))
+    return {"Hidden": [hs], "LastH": [h_fin]}
 
 
 # ---------------------------------------------------------------------------

@@ -27,10 +27,7 @@ __all__ = [
     "flash_attention_qvec",
     "fused_layer_norm",
     "fused_add_layer_norm",
-    "fused_gru",
-    "fused_lstm",
     "fused_softmax_xent",
-    "fused_linear_xent",
     "matmul_bias_act",
     "matmul_swiglu",
     "use_pallas",
@@ -45,9 +42,9 @@ def _interpret():
 
 # Mosaic's default scoped-VMEM limit on a v5e is 16 MiB, and Pallas
 # double-buffers every blocked operand: first contact with the chip
-# refused tile sets the dispatch gates admitted (fused_lstm B32/T63/H512:
-# "Scoped allocation with size 16.10M and limit 16.00M exceeded scoped
-# vmem limit").  Every kernel therefore asks for the limit below (the
+# refused tile sets the dispatch gates admitted ("Scoped allocation with
+# size 16.10M and limit 16.00M exceeded scoped vmem limit").  Every
+# kernel therefore asks for the limit below (the
 # v5e has 128 MiB of VMEM), and every gate admits a tile set only when
 # TWICE its resident bytes fit the budget — the limit less room for the
 # compiler's own temporaries.
@@ -782,243 +779,6 @@ def use_pallas_unwrapped():
 
 
 # ---------------------------------------------------------------------------
-# fused GRU sequence kernel (math/jit_kernel.h gru kernels + fused/fusion_gru
-# analog): the hidden state lives in VMEM across ALL timesteps, so the
-# recurrence reads/writes HBM once per sequence instead of once per step
-# ---------------------------------------------------------------------------
-def recurrent_ok(bsz, t, hid, n_gates):
-    """THE dispatch gate for fused_gru (n_gates 3) and fused_lstm (4):
-    a lane-aligned hidden size (Mosaic's 128 once compiled), and the
-    whole-sequence working set of one batch block — projected gates in,
-    hidden (and cell) sequences out, the recurrent weight — fitting the
-    tile budget double-buffered."""
-    blk = _row_block(bsz, 8)
-    n_out = 2 if n_gates == 4 else 1
-    resident = 4 * (blk * t * (n_gates + n_out) * hid + hid * n_gates * hid)
-    return (hid % (8 if _interpret() else 128) == 0
-            and 2 * resident < _TILE_BUDGET_BYTES)
-
-
-def _gru_seq_kernel(x_ref, w_ref, h0_ref, len_ref, o_ref, *, hid, seq_len):
-    w = w_ref[:].astype(jnp.float32)  # [H, 3H]
-    w_uz = w[:, : 2 * hid]
-    w_c = w[:, 2 * hid:]
-    lens = len_ref[:].astype(jnp.int32).reshape(-1)  # [Bblk, 1] -> [Bblk]
-
-    def step(t, h):
-        xt = x_ref[:, t, :].astype(jnp.float32)  # [Bblk, 3H]
-        gates = xt[:, : 2 * hid] + jax.lax.dot(
-            h, w_uz, preferred_element_type=jnp.float32
-        )
-        u = jax.nn.sigmoid(gates[:, :hid])
-        r = jax.nn.sigmoid(gates[:, hid:])
-        c = jnp.tanh(
-            xt[:, 2 * hid:]
-            + jax.lax.dot(r * h, w_c, preferred_element_type=jnp.float32)
-        )
-        h_new = u * c + (1.0 - u) * h
-        active = (t < lens)[:, None].astype(jnp.float32)
-        h_new = active * h_new + (1.0 - active) * h
-        o_ref[:, t, :] = h_new.astype(o_ref.dtype)
-        return h_new
-
-    jax.lax.fori_loop(0, seq_len, step, h0_ref[:].astype(jnp.float32))
-
-
-def _gru_seq_fwd(xproj, w, h0, lens, block_b=8):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    B, T, H3 = xproj.shape
-    hid = H3 // 3
-    _note("recurrent")
-    block_b = _row_block(B, block_b)
-    grid = (_cdiv(B, block_b),)
-    return pl.pallas_call(
-        functools.partial(_gru_seq_kernel, hid=hid, seq_len=T),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_b, T, H3), lambda i: (i, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((hid, H3), lambda i: (0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((block_b, hid), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-            # lens rides as [B, 1]: a 1D (block_b,) block is Mosaic-illegal
-            # for block_b < 128; (block_b, 1) matches the array's last dim
-            pl.BlockSpec((block_b, 1), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((block_b, T, hid), lambda i: (i, 0, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((B, T, hid), xproj.dtype),
-        compiler_params=_mosaic_params(),
-        interpret=_interpret(),
-    )(xproj, w, h0, lens.reshape(B, 1))
-
-
-def _gru_seq_dense(xproj, w, h0, lens):
-    """Reference scan (also the recompute path for the backward pass)."""
-    hid = xproj.shape[-1] // 3
-    w_uz, w_c = w[:, : 2 * hid], w[:, 2 * hid:]
-
-    def step(h, inp):
-        xt, t = inp
-        gates = xt[:, : 2 * hid] + h @ w_uz
-        u = jax.nn.sigmoid(gates[:, :hid])
-        r = jax.nn.sigmoid(gates[:, hid:])
-        c = jnp.tanh(xt[:, 2 * hid:] + (r * h) @ w_c)
-        h_new = u * c + (1.0 - u) * h
-        act = (t < lens)[:, None].astype(h.dtype)
-        h_new = act * h_new + (1 - act) * h
-        return h_new, h_new
-
-    xs = jnp.swapaxes(xproj, 0, 1)
-    ts = jnp.arange(xproj.shape[1])
-    _, hs = jax.lax.scan(step, h0, (xs, ts))
-    return jnp.swapaxes(hs, 0, 1)
-
-
-@jax.custom_vjp
-def fused_gru(xproj, w, h0, lens):
-    """VMEM-resident GRU over padded [B, T, 3H] projected inputs."""
-    return _gru_seq_fwd(xproj, w, h0, lens)
-
-
-def _gru_vjp_fwd(xproj, w, h0, lens):
-    return _gru_seq_fwd(xproj, w, h0, lens), (xproj, w, h0, lens)
-
-
-def _gru_vjp_bwd(res, dy):
-    xproj, w, h0, lens = res
-    _, vjp = jax.vjp(lambda x, w_, h_: _gru_seq_dense(x, w_, h_, lens),
-                     xproj, w, h0)
-    dx, dw, dh0 = vjp(dy)
-    return dx, dw, dh0, None
-
-
-fused_gru.defvjp(_gru_vjp_fwd, _gru_vjp_bwd)
-
-
-# ---------------------------------------------------------------------------
-# fused LSTM sequence kernel (math/jit_kernel.h lstm kernels +
-# fused/fusion_lstm analog): hidden AND cell state live in VMEM across all
-# timesteps — one HBM read of the projected gates and one write of each
-# output sequence per batch block, instead of per-step round trips
-# ---------------------------------------------------------------------------
-def _lstm_seq_kernel(x_ref, w_ref, h0_ref, c0_ref, len_ref, o_ref, cell_ref,
-                     *, hid, seq_len):
-    w = w_ref[:].astype(jnp.float32)  # [H, 4H]
-    lens = len_ref[:].astype(jnp.int32).reshape(-1)  # [Bblk, 1] -> [Bblk]
-
-    def step(t, hc):
-        h, c = hc
-        xt = x_ref[:, t, :].astype(jnp.float32)  # [Bblk, 4H]
-        gates = xt + jax.lax.dot(h, w, preferred_element_type=jnp.float32)
-        # gate order i|f|c_hat|o (lstm_op.cc / _lstm_cell layout)
-        i = jax.nn.sigmoid(gates[:, :hid])
-        f = jax.nn.sigmoid(gates[:, hid: 2 * hid])
-        c_hat = jnp.tanh(gates[:, 2 * hid: 3 * hid])
-        o = jax.nn.sigmoid(gates[:, 3 * hid:])
-        c_new = f * c + i * c_hat
-        h_new = o * jnp.tanh(c_new)
-        active = (t < lens)[:, None].astype(jnp.float32)
-        c_new = active * c_new + (1.0 - active) * c
-        h_new = active * h_new + (1.0 - active) * h
-        o_ref[:, t, :] = h_new.astype(o_ref.dtype)
-        cell_ref[:, t, :] = c_new.astype(cell_ref.dtype)
-        return (h_new, c_new)
-
-    jax.lax.fori_loop(
-        0, seq_len, step,
-        (h0_ref[:].astype(jnp.float32), c0_ref[:].astype(jnp.float32)),
-    )
-
-
-def _lstm_seq_fwd(xproj, w, h0, c0, lens, block_b=8):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    B, T, H4 = xproj.shape
-    hid = H4 // 4
-    _note("recurrent")
-    block_b = _row_block(B, block_b)
-    grid = (_cdiv(B, block_b),)
-    state_spec = pl.BlockSpec((block_b, hid), lambda i: (i, 0),
-                              memory_space=pltpu.VMEM)
-    seq_spec = pl.BlockSpec((block_b, T, hid), lambda i: (i, 0, 0),
-                            memory_space=pltpu.VMEM)
-    return pl.pallas_call(
-        functools.partial(_lstm_seq_kernel, hid=hid, seq_len=T),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_b, T, H4), lambda i: (i, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((hid, H4), lambda i: (0, 0),
-                         memory_space=pltpu.VMEM),
-            state_spec,
-            state_spec,
-            # lens rides as [B, 1] (1D sub-128 blocks are Mosaic-illegal)
-            pl.BlockSpec((block_b, 1), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=[seq_spec, seq_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, T, hid), xproj.dtype),
-            jax.ShapeDtypeStruct((B, T, hid), xproj.dtype),
-        ],
-        compiler_params=_mosaic_params(),
-        interpret=_interpret(),
-    )(xproj, w, h0, c0, lens.reshape(B, 1))
-
-
-def _lstm_seq_dense(xproj, w, h0, c0, lens):
-    """Reference scan (also the recompute path for the backward pass).
-    Reuses nn_ops._lstm_cell — one copy of the gate math outside the
-    hand-tiled kernel (which must slice refs explicitly)."""
-    from .nn_ops import _lstm_cell  # lazy: nn_ops imports this module
-
-    def step(carry, inp):
-        h, c = carry
-        xt, t = inp
-        gates = xt + h @ w
-        c_new, h_new = _lstm_cell(c, h, gates)
-        act = (t < lens)[:, None].astype(h.dtype)
-        c_new = act * c_new + (1 - act) * c
-        h_new = act * h_new + (1 - act) * h
-        return (h_new, c_new), (h_new, c_new)
-
-    xs = jnp.swapaxes(xproj, 0, 1)
-    ts = jnp.arange(xproj.shape[1])
-    _, (hs, cs) = jax.lax.scan(step, (h0, c0), (xs, ts))
-    return jnp.swapaxes(hs, 0, 1), jnp.swapaxes(cs, 0, 1)
-
-
-@jax.custom_vjp
-def fused_lstm(xproj, w, h0, c0, lens):
-    """VMEM-resident LSTM over padded [B, T, 4H] projected inputs;
-    returns (hidden_seq, cell_seq), each [B, T, H]."""
-    return _lstm_seq_fwd(xproj, w, h0, c0, lens)
-
-
-def _lstm_vjp_fwd(xproj, w, h0, c0, lens):
-    return _lstm_seq_fwd(xproj, w, h0, c0, lens), (xproj, w, h0, c0, lens)
-
-
-def _lstm_vjp_bwd(res, dy):
-    xproj, w, h0, c0, lens = res
-    _, vjp = jax.vjp(
-        lambda x, w_, h_, c_: _lstm_seq_dense(x, w_, h_, c_, lens),
-        xproj, w, h0, c0,
-    )
-    dx, dw, dh0, dc0 = vjp(dy)
-    return dx, dw, dh0, dc0, None
-
-
-fused_lstm.defvjp(_lstm_vjp_fwd, _lstm_vjp_bwd)
-
-
-# ---------------------------------------------------------------------------
 # fused softmax cross entropy (row-blocked logsumexp + label gather; the
 # backward is the analytic softmax(x) - onehot, no recompute needed)
 # ---------------------------------------------------------------------------
@@ -1505,740 +1265,3 @@ def _add_ln_vjp_bwd(eps, _block_rows, res, cts):
 
 
 fused_add_layer_norm.defvjp(_add_ln_vjp_fwd, _add_ln_vjp_bwd)
-
-
-# ---------------------------------------------------------------------------
-# logits-free fused cross entropy: the final [H, V] projection fused INTO
-# the loss.  Forward streams V in block_v-sized tiles — each tile's
-# logits exist only as a VMEM [block_r, block_v] accumulator feeding an
-# online logsumexp (flash-attention's trick applied to the vocab axis),
-# the gold logit gather, and the row logit-sum (label smoothing's mean
-# term) — so the [R, V] f32 logits tensor NEVER materializes in HBM (at
-# transformer-base bench config that is a 1.3 GB write + read per step
-# direction, plus its gradient twin).  Backward recomputes each tile's
-# softmax from the saved per-row lse and contracts in-kernel: dx
-# accumulates g @ w_tile^T across the v grid, dw writes one [H, block_v]
-# tile per v index accumulated across row blocks.  The vocab axis is
-# masked in-kernel (cols >= V contribute nothing), so ragged vocab sizes
-# (10000 / 30522 / 50257) need no padding copy of w.
-# ---------------------------------------------------------------------------
-def _lxent_fwd_kernel(x_ref, w_ref, lbl_ref, loss_ref, lse_ref,
-                      m_ref, l_ref, gold_ref, sum_ref,
-                      *, block_v, nv, vocab, eps):
-    from jax.experimental import pallas as pl
-
-    vi = pl.program_id(1)
-
-    @pl.when(vi == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        gold_ref[:] = jnp.zeros_like(gold_ref)
-        sum_ref[:] = jnp.zeros_like(sum_ref)
-
-    cols = vi * block_v + jax.lax.broadcasted_iota(
-        jnp.int32, (1, block_v), 1)  # global vocab columns of this tile
-    vmask = cols < vocab
-    # zero the out-of-vocab tail of the weight tile BEFORE the dot: the
-    # last block may read past [H, V] (padded garbage on-chip)
-    w = jnp.where(vmask, w_ref[:], 0.0)
-    z = jnp.dot(x_ref[:], w, preferred_element_type=jnp.float32)
-    lbl = lbl_ref[:].astype(jnp.int32).reshape(-1)  # [br]
-    gold_ref[:] += jnp.sum(
-        jnp.where(cols == lbl[:, None], z, 0.0), axis=1, keepdims=True)
-    sum_ref[:] += jnp.sum(jnp.where(vmask, z, 0.0), axis=1, keepdims=True)
-    zm = jnp.where(vmask, z, NEG_INF)
-    m_prev = m_ref[:]
-    m_new = jnp.maximum(m_prev, jnp.max(zm, axis=1, keepdims=True))
-    l_ref[:] = (l_ref[:] * jnp.exp(m_prev - m_new)
-                + jnp.sum(jnp.exp(zm - m_new), axis=1, keepdims=True))
-    m_ref[:] = m_new
-
-    @pl.when(vi == nv - 1)
-    def _write():
-        lse = m_ref[:] + jnp.log(l_ref[:])
-        lbl_f = lbl_ref[:].astype(jnp.int32)
-        valid = ((lbl_f >= 0) & (lbl_f < vocab)).astype(jnp.float32)
-        loss = valid * (1.0 - eps) * (lse - gold_ref[:])
-        if eps:
-            loss = loss + eps * (lse - sum_ref[:] / vocab)
-        loss_ref[:] = loss
-        lse_ref[:] = lse
-
-
-def _lxent_grad_tile(x, w, lbl, lse, dy, vi, block_v, vocab, eps,
-                     valid=None, vocab_total=None):
-    """Shared backward tile math: g = dy * d loss / d z for this
-    [br, block_v] logits tile, recomputed from the saved lse.  The
-    vocab-SHARDED form passes `valid` (row validity against the GLOBAL
-    vocab — local label coords can't derive it) and `vocab_total` (the
-    smoothing denominator spans every shard's columns)."""
-    cols = vi * block_v + jax.lax.broadcasted_iota(
-        jnp.int32, (1, block_v), 1)
-    vmask = cols < vocab
-    w = jnp.where(vmask, w, 0.0)
-    z = jnp.dot(x, w, preferred_element_type=jnp.float32)
-    p = jnp.where(vmask, jnp.exp(z - lse), 0.0)
-    lbl = lbl.astype(jnp.int32).reshape(-1)
-    onehot = (cols == lbl[:, None]).astype(jnp.float32)
-    if valid is None:
-        valid = ((lbl >= 0) & (lbl < vocab)).astype(jnp.float32)[:, None]
-    g = valid * (1.0 - eps) * (p - onehot)
-    if eps:
-        g = g + eps * (p - jnp.where(
-            vmask, 1.0 / (vocab_total or vocab), 0.0))
-    return g * dy, w
-
-
-def _lxent_dx_kernel(x_ref, w_ref, lbl_ref, lse_ref, dy_ref, dx_ref,
-                     dx_acc, *, block_v, nv, vocab, eps):
-    from jax.experimental import pallas as pl
-
-    vi = pl.program_id(1)
-
-    @pl.when(vi == 0)
-    def _init():
-        dx_acc[:] = jnp.zeros_like(dx_acc)
-
-    g, w = _lxent_grad_tile(
-        x_ref[:], w_ref[:], lbl_ref[:], lse_ref[:].astype(jnp.float32),
-        dy_ref[:].astype(jnp.float32), vi, block_v, vocab, eps)
-    dx_acc[:] += jnp.dot(g.astype(x_ref.dtype), w.T,
-                         preferred_element_type=jnp.float32)
-
-    @pl.when(vi == nv - 1)
-    def _write():
-        dx_ref[:] = dx_acc[:].astype(dx_ref.dtype)
-
-
-def _lxent_dw_kernel(x_ref, w_ref, lbl_ref, lse_ref, dy_ref, dw_ref,
-                     dw_acc, *, block_v, nr, vocab, rows, eps):
-    from jax.experimental import pallas as pl
-
-    vi = pl.program_id(0)  # this grid is (nv, nr) — v is OUTER
-    ri = pl.program_id(1)
-
-    @pl.when(ri == 0)
-    def _init():
-        dw_acc[:] = jnp.zeros_like(dw_acc)
-
-    g, _w = _lxent_grad_tile(
-        x_ref[:], w_ref[:], lbl_ref[:], lse_ref[:].astype(jnp.float32),
-        dy_ref[:].astype(jnp.float32), vi, block_v, vocab, eps)
-    # unlike loss/dx (whose padded-row outputs are simply discarded),
-    # dw SUMS over row tiles — zero the tail tile's out-of-range rows
-    # on BOTH dot operands before they reach the accumulator (block_r
-    # need not divide R; padded x rows can be NaN, and NaN * 0 = NaN)
-    br = g.shape[0]
-    rr = ri * br + jax.lax.broadcasted_iota(jnp.int32, (br, 1), 0)
-    rmask = rr < rows
-    g = jnp.where(rmask, g, 0.0)
-    xt = jnp.where(rmask, x_ref[:], 0)
-    dw_acc[:] += jnp.dot(xt.T, g.astype(x_ref.dtype),
-                         preferred_element_type=jnp.float32)
-
-    @pl.when(ri == nr - 1)
-    def _write():
-        dw_ref[:] = dw_acc[:].astype(dw_ref.dtype)
-
-
-def _lx_vmem_ok(H, br, bv):
-    """Worst-pass (dw) resident f32 upper bound: the x row tile
-    [br, H], the w input + dw output + dw_acc scratch tiles [H, bv]
-    each, and the recomputed logits/softmax tile [br, bv], double-
-    buffered, must fit the tile budget — the linear-xent twin of
-    _mm_vmem_ok."""
-    tile = (br * H + 3 * H * bv + 2 * br * bv) * 4
-    return 2 * tile < _TILE_BUDGET_BYTES
-
-
-def _lxent_default_blocks(R, H, V):
-    """The deterministic (block_r, block_v) seed — also the FIXED
-    choice inside shard_map (a per-shard tuning search there would
-    attribute collective time to block sizes, the qvec precedent)."""
-    br0 = _row_block(R, 256)
-    bv0 = min(V, 1024 if V % 128 == 0 else 2048)
-    # shrink the seeded default until the dw pass fits VMEM (consult-
-    # only regimes dispatch it unvalidated); halving keeps bv0 a
-    # multiple of 128 (Mosaic minor-dim rule) — a small non-multiple
-    # bv0 == V full-dim block can't legally shrink and stays put
-    while bv0 % 256 == 0 and bv0 > 128 and not _lx_vmem_ok(H, br0, bv0):
-        bv0 //= 2
-    return br0, bv0
-
-
-def _lxent_blocks(R, H, V, dtype):
-    cands = []
-    for br in (128, 256, 512):
-        if R % br:
-            continue
-        for bv in (512, 1024, 2048):
-            if _lx_vmem_ok(H, br, bv):
-                cands.append({"block_r": br, "block_v": bv})
-    br0, bv0 = _lxent_default_blocks(R, H, V)
-    default = {"block_r": br0, "block_v": bv0}
-    params = _tuned(
-        "linear_xent", [(R, H), (H, V)], dtype, cands, default,
-        build=lambda p: (lambda x, w, lb: _lxent_fwd(
-            x, w, lb, 0.0, p["block_r"], p["block_v"])),
-        arg_specs=[((R, H), dtype), ((H, V), dtype), ((R,), "int32")],
-    )
-    return _row_block(R, params["block_r"]), int(params["block_v"])
-
-
-def _lxent_specs(block_r, block_v, H, dw_grid=False):
-    """(x, w, row...) BlockSpecs; dw_grid flips which grid axis indexes
-    rows vs vocab tiles ((b, vi, ri) instead of (b-less) (ri, vi))."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    if dw_grid:
-        x_spec = pl.BlockSpec((block_r, H), lambda i, j: (j, 0),
-                              memory_space=pltpu.VMEM)
-        w_spec = pl.BlockSpec((H, block_v), lambda i, j: (0, i),
-                              memory_space=pltpu.VMEM)
-        row_spec = pl.BlockSpec((block_r, 1), lambda i, j: (j, 0),
-                                memory_space=pltpu.VMEM)
-    else:
-        x_spec = pl.BlockSpec((block_r, H), lambda i, j: (i, 0),
-                              memory_space=pltpu.VMEM)
-        w_spec = pl.BlockSpec((H, block_v), lambda i, j: (0, j),
-                              memory_space=pltpu.VMEM)
-        row_spec = pl.BlockSpec((block_r, 1), lambda i, j: (i, 0),
-                                memory_space=pltpu.VMEM)
-    return x_spec, w_spec, row_spec
-
-
-def _lxent_fwd(x2d, w, labels, eps, block_r, block_v):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    R, H = x2d.shape
-    V = w.shape[1]
-    _note("xent")
-    nr, nv = _cdiv(R, block_r), _cdiv(V, block_v)
-    x_spec, w_spec, row_spec = _lxent_specs(block_r, block_v, H)
-    loss, lse = pl.pallas_call(
-        functools.partial(_lxent_fwd_kernel, block_v=block_v, nv=nv,
-                          vocab=V, eps=float(eps)),
-        grid=(nr, nv),
-        in_specs=[x_spec, w_spec, row_spec],
-        out_specs=[row_spec, row_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((R, 1), jnp.float32),
-            jax.ShapeDtypeStruct((R, 1), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_r, 1), jnp.float32),
-            pltpu.VMEM((block_r, 1), jnp.float32),
-            pltpu.VMEM((block_r, 1), jnp.float32),
-            pltpu.VMEM((block_r, 1), jnp.float32),
-        ],
-        compiler_params=_mosaic_params(),
-        interpret=_interpret(),
-    )(x2d, w, labels.astype(jnp.int32).reshape(R, 1))
-    return loss, lse
-
-
-def _lxent_bwd(x2d, w, labels, lse, dy, eps, block_r, block_v):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    R, H = x2d.shape
-    V = w.shape[1]
-    nr, nv = _cdiv(R, block_r), _cdiv(V, block_v)
-    lbl = labels.astype(jnp.int32).reshape(R, 1)
-    lse2 = lse.reshape(R, 1)
-    dy2 = dy.reshape(R, 1).astype(jnp.float32)
-
-    x_spec, w_spec, row_spec = _lxent_specs(block_r, block_v, H)
-    dx = pl.pallas_call(
-        functools.partial(_lxent_dx_kernel, block_v=block_v, nv=nv,
-                          vocab=V, eps=float(eps)),
-        grid=(nr, nv),
-        in_specs=[x_spec, w_spec, row_spec, row_spec, row_spec],
-        out_specs=x_spec,
-        out_shape=jax.ShapeDtypeStruct((R, H), x2d.dtype),
-        scratch_shapes=[pltpu.VMEM((block_r, H), jnp.float32)],
-        compiler_params=_mosaic_params(),
-        interpret=_interpret(),
-    )(x2d, w, lbl, lse2, dy2)
-
-    x_spec, w_spec, row_spec = _lxent_specs(block_r, block_v, H,
-                                            dw_grid=True)
-    dw = pl.pallas_call(
-        functools.partial(_lxent_dw_kernel, block_v=block_v, nr=nr,
-                          vocab=V, rows=R, eps=float(eps)),
-        grid=(nv, nr),
-        in_specs=[x_spec, w_spec, row_spec, row_spec, row_spec],
-        out_specs=w_spec,
-        out_shape=jax.ShapeDtypeStruct((H, V), w.dtype),
-        scratch_shapes=[pltpu.VMEM((H, block_v), jnp.float32)],
-        compiler_params=_mosaic_params(),
-        interpret=_interpret(),
-    )(x2d, w, lbl, lse2, dy2)
-    return dx, dw
-
-
-def _linear_xent_dense(x2d, w, labels, eps=0.0):
-    """XLA reference: materializes the [R, V] logits (tests + the
-    non-pallas fallback).  Same label convention as the kernel and
-    smooth_label_xent: out-of-range labels contribute the smoothing
-    term only."""
-    lg = jnp.dot(x2d, w, preferred_element_type=jnp.float32)
-    v = lg.shape[-1]
-    lse = jax.scipy.special.logsumexp(lg, axis=-1, keepdims=True)
-    lbl = labels.astype(jnp.int32).reshape(-1)
-    onehot_gold = jnp.sum(
-        jnp.where(jnp.arange(v)[None, :] == lbl[:, None], lg, 0.0),
-        axis=-1, keepdims=True)
-    valid = ((lbl >= 0) & (lbl < v))[:, None]
-    loss = jnp.where(valid, (1.0 - eps) * (lse - onehot_gold), 0.0)
-    if eps:
-        loss = loss + eps * (lse - jnp.mean(lg, axis=-1, keepdims=True))
-    return loss
-
-
-# ---------------------------------------------------------------------------
-# the DEFAULT (no flag, no Mosaic) vocabulary head: _linear_xent_dense's
-# arithmetic as a custom VJP in plain XLA ops that walks the rows in
-# tiles, so no [R, V] array exists and the logits gradient is formed
-# once, narrowed to the operands' dtype and fed to both gradient matmuls
-# ---------------------------------------------------------------------------
-# the f32 [rows_t, V] logits block one tile may hold: 1024 rows at
-# V=50257, 4096 at V=10000
-_LXENT_TILE_BYTES = 256 << 20
-
-
-def _lxent_tile_len(batch, length, vocab):
-    """Steps of the scanned axis a tile takes, from the shapes alone: the
-    largest divisor of `length` (a multiple of 8 when tiling at all)
-    whose f32 [batch * steps, vocab] block fits _LXENT_TILE_BYTES and is
-    over half of what fits; without one, what fits rounded down to 8 and
-    a padded last tile."""
-    cap = max(1, _LXENT_TILE_BYTES // (4 * vocab * batch))
-    if cap >= length:
-        return length
-    align = 8 if cap >= 8 else 1
-    top = cap - cap % align
-    for steps in range(top, cap // 2, -align):
-        if length % steps == 0:
-            return steps
-    return top
-
-
-def _lxent_split(a, steps, fill):
-    """[B, T, ...] -> [n, B, steps, ...] tiles of axis 1, the last one
-    padded with `fill`."""
-    b, t = a.shape[:2]
-    n = -(-t // steps)
-    if n * steps != t:
-        pad = [(0, 0), (0, n * steps - t)] + [(0, 0)] * (a.ndim - 2)
-        a = jnp.pad(a, pad, constant_values=fill)
-    return jnp.moveaxis(a.reshape((b, n, steps) + a.shape[2:]), 1, 0)
-
-
-def _lxent_join(tiles, length):
-    """Inverse of _lxent_split: [n, B, steps, ...] -> [B, length, ...]."""
-    a = jnp.moveaxis(tiles, 0, 1)
-    a = a.reshape((a.shape[0], -1) + a.shape[3:])
-    return a[:, :length]
-
-
-def _lxent_scan(tile, init, tiles):
-    """lax.scan of `tile` over the leading axis of `tiles`; a single tile
-    is called in line, so a partitioned program holds no loop at all."""
-    if jax.tree_util.tree_leaves(tiles)[0].shape[0] > 1:
-        return jax.lax.scan(tile, init, tiles)
-    carry, out = tile(init, jax.tree_util.tree_map(lambda a: a[0], tiles))
-    return carry, out[None]
-
-
-def _lxent_as_tiles(x, w, labels, transpose_w, one_tile):
-    """(x tiles [n, B, steps, H], label tiles [n, B, steps], w in the
-    dots' dtype, V, (B, T)).  A [R, H] input tiles its rows; with a time
-    axis ([..., T, H]) that axis is the scanned one, so an axis the mesh
-    shards over dp (batch) stays whole in every tile."""
-    dt = jnp.result_type(x.dtype, w.dtype)
-    h = x.shape[-1]
-    t = x.shape[-2]
-    x3 = x.reshape(-1, t, h).astype(dt)
-    lbl = labels.astype(jnp.int32).reshape(x3.shape[:2])
-    vocab = w.shape[0 if transpose_w else 1]
-    steps = t if one_tile else _lxent_tile_len(x3.shape[0], t, vocab)
-    return (_lxent_split(x3, steps, 0), _lxent_split(lbl, steps, -1),
-            w.astype(dt), vocab, x3.shape[:2])
-
-
-def _lxent_tile_logits(x_t, w, transpose_w):
-    """f32 [B, steps, V] logits of one tile; the tied [V, H] table
-    contracts its second axis, never a transposed copy."""
-    return jax.lax.dot_general(
-        x_t, w, (((2,), (1 if transpose_w else 0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-
-
-def _lxent_tiled_fwd(x, w, labels, eps, transpose_w, one_tile):
-    from .kernel_tuning import note_dense_vjp
-
-    note_dense_vjp("xent")
-    xs, ls, wd, vocab, (_, t) = _lxent_as_tiles(x, w, labels, transpose_w,
-                                                one_tile)
-
-    def tile(_, x_l):
-        x_t, l_t = x_l
-        z = _lxent_tile_logits(x_t, wd, transpose_w)
-        lse = jax.scipy.special.logsumexp(z, axis=-1)
-        cols = jax.lax.broadcasted_iota(jnp.int32, z.shape, 2)
-        gold = jnp.sum(jnp.where(cols == l_t[..., None], z, 0.0), axis=-1)
-        valid = (l_t >= 0) & (l_t < vocab)
-        loss = jnp.where(valid, (1.0 - eps) * (lse - gold), 0.0)
-        if eps:
-            loss = loss + eps * (lse - jnp.mean(z, axis=-1))
-        # ONE output: the forward op reads the loss, the grad op's
-        # re-traced forward reads lse; as two outputs each side's dead
-        # code elimination would prune a different one, the two scans
-        # would differ and XLA's CSE could not merge them into one
-        return None, jnp.stack([loss, lse], axis=-1)
-
-    with jax.named_scope("tile_fwd"):
-        _, stats = _lxent_scan(tile, None, (xs, ls))
-    stats = _lxent_join(stats, t)
-    loss = stats[..., 0].reshape(x.shape[:-1] + (1,))
-    return loss, stats[..., 1]
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def linear_xent_tiled(x, w, labels, eps=0.0, transpose_w=False,
-                      one_tile=False):
-    """Projected cross entropy in plain XLA ops with a hand-written VJP:
-    _linear_xent_dense's arithmetic (operands in their own dtype to the
-    MXU, f32 accumulation, f32 softmax statistics; out-of-range labels
-    contribute the smoothing term only) without an [R, V] array.  x
-    [..., H], w [H, V] ([V, H] with transpose_w), labels x.shape[:-1]
-    int; returns x.shape[:-1] + (1,) f32 losses.
-
-    Forward: a scan over row tiles (_lxent_as_tiles), per tile the f32
-    logits, reduced to the loss and lse; only lse is saved beside x, w
-    and the labels.  Backward: per tile the logits again, the logits
-    gradient formed ONCE in f32, narrowed to the operands' dtype, and
-    that one array fed to both gradient matmuls; dw accumulates in f32
-    across the tiles.
-
-    one_tile: the whole input as a single tile and no loop — for a
-    program GSPMD partitions, where the dw carried through a scan would
-    be all-reduced over dp once per tile instead of once."""
-    return _lxent_tiled_fwd(x, w, labels, eps, transpose_w, one_tile)[0]
-
-
-def _lxent_tiled_vjp_fwd(x, w, labels, eps, transpose_w, one_tile):
-    loss, lse = _lxent_tiled_fwd(x, w, labels, eps, transpose_w, one_tile)
-    return loss, (x, w, labels, lse)
-
-
-def _lxent_tiled_vjp_bwd(eps, transpose_w, one_tile, res, dy):
-    x, w, labels, lse = res
-    xs, ls, wd, vocab, (b, t) = _lxent_as_tiles(x, w, labels, transpose_w,
-                                                one_tile)
-    steps = xs.shape[2]
-    # a padded row takes dy = 0, so it reaches neither dx nor dw
-    dys = _lxent_split(dy.astype(jnp.float32).reshape(b, t), steps, 0)
-    lses = _lxent_split(lse, steps, 0)
-
-    def tile(dw, tile_in):
-        x_t, l_t, lse_t, dy_t = tile_in
-        z = _lxent_tile_logits(x_t, wd, transpose_w)
-        p = jnp.exp(z - lse_t[..., None])
-        cols = jax.lax.broadcasted_iota(jnp.int32, z.shape, 2)
-        onehot = (cols == l_t[..., None]).astype(jnp.float32)
-        valid = ((l_t >= 0) & (l_t < vocab)).astype(jnp.float32)
-        g = ((1.0 - eps) * valid)[..., None] * (p - onehot)
-        if eps:
-            g = g + eps * (p - 1.0 / vocab)
-        g = (g * dy_t[..., None]).astype(wd.dtype)
-        dx_t = jax.lax.dot_general(
-            g, wd, (((2,), (0 if transpose_w else 1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        lhs, rhs = (g, x_t) if transpose_w else (x_t, g)
-        dw = dw + jax.lax.dot_general(
-            lhs, rhs, (((0, 1), (0, 1)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return dw, dx_t.astype(x.dtype)
-
-    with jax.named_scope("tile_bwd"):
-        dw, dxs = _lxent_scan(tile, jnp.zeros(w.shape, jnp.float32),
-                              (xs, ls, lses, dys))
-    dx = _lxent_join(dxs, t).reshape(x.shape)
-    dlbl = np.zeros(labels.shape, dtype=jax.dtypes.float0)
-    return dx, dw.astype(w.dtype), dlbl
-
-
-linear_xent_tiled.defvjp(_lxent_tiled_vjp_fwd, _lxent_tiled_vjp_bwd)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def fused_linear_xent(x2d, w, labels, eps=0.0, block_r=None, block_v=None):
-    """Logits-free projected cross entropy: -log softmax(x @ w)[label]
-    per row (label-smoothed by eps against the uniform prior), computed
-    without the [R, V] logits array ever reaching HBM.  x2d [R, H],
-    w [H, V], labels [R] int; returns [R, 1] f32 losses.  Out-of-range
-    labels (pad ids) contribute the smoothing term only (the one_hot
-    convention, matching smooth_label_xent)."""
-    if block_r is None or block_v is None:
-        block_r, block_v = _lxent_blocks(x2d.shape[0], x2d.shape[1],
-                                         w.shape[1], x2d.dtype)
-    loss, _lse = _lxent_fwd(x2d, w, labels, eps, block_r, block_v)
-    return loss
-
-
-def _lxent_vjp_fwd(x2d, w, labels, eps, block_r, block_v):
-    if block_r is None or block_v is None:
-        block_r, block_v = _lxent_blocks(x2d.shape[0], x2d.shape[1],
-                                         w.shape[1], x2d.dtype)
-    loss, lse = _lxent_fwd(x2d, w, labels, eps, block_r, block_v)
-    return loss, (x2d, w, labels, lse, block_r, block_v)
-
-
-def _lxent_vjp_bwd(eps, _block_r, _block_v, res, dy):
-    x2d, w, labels, lse, block_r, block_v = res
-    dx, dw = _lxent_bwd(x2d, w, labels, lse, dy, eps, block_r, block_v)
-    dlbl = np.zeros(labels.shape, dtype=jax.dtypes.float0)
-    return dx, dw, dlbl
-
-
-fused_linear_xent.defvjp(_lxent_vjp_fwd, _lxent_vjp_bwd)
-
-
-# ---------------------------------------------------------------------------
-# vocab-SHARDED linear xent: the per-shard body the spmd_epilogue layer
-# runs inside shard_map when the rule table vocab-shards the projection
-# (softmax_out.w / tied emb.w).  Each shard streams only its [H, V/n]
-# weight slab; the online-logsumexp state that the unsharded kernel
-# keeps per row across vocab TILES is here combined per row across
-# vocab SHARDS with three scalar-per-row collectives (pmax/psum of
-# lse/gold/sum) — the [R, V] logits still never exist anywhere, now not
-# even per device.
-# ---------------------------------------------------------------------------
-def _lxent_parts_kernel(x_ref, w_ref, lbl_ref, lse_ref, gold_ref, sum_ref,
-                        m_ref, l_ref, g_acc, s_acc, *, block_v, nv, vocab):
-    """The fwd kernel's streaming pass with the LOSS ASSEMBLY removed:
-    outputs the per-row (lse, gold, sum) partials of THIS vocab shard.
-    `lbl` is in LOCAL column coords (label - shard_offset) — an
-    out-of-shard label matches no real column, and a padded-tail column
-    it might alias carries a zeroed weight, so gold accumulates 0."""
-    from jax.experimental import pallas as pl
-
-    vi = pl.program_id(1)
-
-    @pl.when(vi == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        g_acc[:] = jnp.zeros_like(g_acc)
-        s_acc[:] = jnp.zeros_like(s_acc)
-
-    cols = vi * block_v + jax.lax.broadcasted_iota(
-        jnp.int32, (1, block_v), 1)
-    vmask = cols < vocab
-    w = jnp.where(vmask, w_ref[:], 0.0)
-    z = jnp.dot(x_ref[:], w, preferred_element_type=jnp.float32)
-    lbl = lbl_ref[:].astype(jnp.int32).reshape(-1)
-    g_acc[:] += jnp.sum(
-        jnp.where(cols == lbl[:, None], z, 0.0), axis=1, keepdims=True)
-    s_acc[:] += jnp.sum(jnp.where(vmask, z, 0.0), axis=1, keepdims=True)
-    zm = jnp.where(vmask, z, NEG_INF)
-    m_prev = m_ref[:]
-    m_new = jnp.maximum(m_prev, jnp.max(zm, axis=1, keepdims=True))
-    l_ref[:] = (l_ref[:] * jnp.exp(m_prev - m_new)
-                + jnp.sum(jnp.exp(zm - m_new), axis=1, keepdims=True))
-    m_ref[:] = m_new
-
-    @pl.when(vi == nv - 1)
-    def _write():
-        lse_ref[:] = m_ref[:] + jnp.log(l_ref[:])
-        gold_ref[:] = g_acc[:]
-        sum_ref[:] = s_acc[:]
-
-
-def _lxent_parts(x2d, w, lbl_local, block_r, block_v):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    R, H = x2d.shape
-    V = w.shape[1]
-    _note("xent")
-    nr, nv = _cdiv(R, block_r), _cdiv(V, block_v)
-    x_spec, w_spec, row_spec = _lxent_specs(block_r, block_v, H)
-    return pl.pallas_call(
-        functools.partial(_lxent_parts_kernel, block_v=block_v, nv=nv,
-                          vocab=V),
-        grid=(nr, nv),
-        in_specs=[x_spec, w_spec, row_spec],
-        out_specs=[row_spec, row_spec, row_spec],
-        out_shape=[jax.ShapeDtypeStruct((R, 1), jnp.float32)] * 3,
-        scratch_shapes=[pltpu.VMEM((block_r, 1), jnp.float32)] * 4,
-        compiler_params=_mosaic_params(),
-        interpret=_interpret(),
-    )(x2d, w, lbl_local.astype(jnp.int32).reshape(R, 1))
-
-
-def _lxent_dx_kernel_sharded(x_ref, w_ref, lbl_ref, vld_ref, lse_ref,
-                             dy_ref, dx_ref, dx_acc,
-                             *, block_v, nv, vocab, vocab_total, eps):
-    from jax.experimental import pallas as pl
-
-    vi = pl.program_id(1)
-
-    @pl.when(vi == 0)
-    def _init():
-        dx_acc[:] = jnp.zeros_like(dx_acc)
-
-    g, w = _lxent_grad_tile(
-        x_ref[:], w_ref[:], lbl_ref[:], lse_ref[:].astype(jnp.float32),
-        dy_ref[:].astype(jnp.float32), vi, block_v, vocab, eps,
-        valid=vld_ref[:].astype(jnp.float32), vocab_total=vocab_total)
-    dx_acc[:] += jnp.dot(g.astype(x_ref.dtype), w.T,
-                         preferred_element_type=jnp.float32)
-
-    @pl.when(vi == nv - 1)
-    def _write():
-        dx_ref[:] = dx_acc[:].astype(dx_ref.dtype)
-
-
-def _lxent_dw_kernel_sharded(x_ref, w_ref, lbl_ref, vld_ref, lse_ref,
-                             dy_ref, dw_ref, dw_acc,
-                             *, block_v, nr, vocab, vocab_total, rows, eps):
-    from jax.experimental import pallas as pl
-
-    vi = pl.program_id(0)
-    ri = pl.program_id(1)
-
-    @pl.when(ri == 0)
-    def _init():
-        dw_acc[:] = jnp.zeros_like(dw_acc)
-
-    g, _w = _lxent_grad_tile(
-        x_ref[:], w_ref[:], lbl_ref[:], lse_ref[:].astype(jnp.float32),
-        dy_ref[:].astype(jnp.float32), vi, block_v, vocab, eps,
-        valid=vld_ref[:].astype(jnp.float32), vocab_total=vocab_total)
-    br = g.shape[0]
-    rr = ri * br + jax.lax.broadcasted_iota(jnp.int32, (br, 1), 0)
-    rmask = rr < rows
-    g = jnp.where(rmask, g, 0.0)
-    xt = jnp.where(rmask, x_ref[:], 0)
-    dw_acc[:] += jnp.dot(xt.T, g.astype(x_ref.dtype),
-                         preferred_element_type=jnp.float32)
-
-    @pl.when(ri == nr - 1)
-    def _write():
-        dw_ref[:] = dw_acc[:].astype(dw_ref.dtype)
-
-
-def _lxent_bwd_sharded(x2d, w, lbl_local, vld, lse, dy, eps, vocab_total,
-                       block_r, block_v):
-    """(dx_partial, dw_local) for this vocab shard: dx sums only the
-    local columns' contributions (the caller psums it over the vocab
-    axis), dw is the full gradient of the local slab (the shard_map
-    transpose psums it over any axis the weight is replicated on)."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    R, H = x2d.shape
-    V = w.shape[1]
-    nr, nv = _cdiv(R, block_r), _cdiv(V, block_v)
-    lbl = lbl_local.astype(jnp.int32).reshape(R, 1)
-    vld2 = vld.reshape(R, 1).astype(jnp.float32)
-    lse2 = lse.reshape(R, 1)
-    dy2 = dy.reshape(R, 1).astype(jnp.float32)
-
-    x_spec, w_spec, row_spec = _lxent_specs(block_r, block_v, H)
-    dx = pl.pallas_call(
-        functools.partial(_lxent_dx_kernel_sharded, block_v=block_v,
-                          nv=nv, vocab=V, vocab_total=vocab_total,
-                          eps=float(eps)),
-        grid=(nr, nv),
-        in_specs=[x_spec, w_spec, row_spec, row_spec, row_spec, row_spec],
-        out_specs=x_spec,
-        out_shape=jax.ShapeDtypeStruct((R, H), x2d.dtype),
-        scratch_shapes=[pltpu.VMEM((block_r, H), jnp.float32)],
-        compiler_params=_mosaic_params(),
-        interpret=_interpret(),
-    )(x2d, w, lbl, vld2, lse2, dy2)
-
-    x_spec, w_spec, row_spec = _lxent_specs(block_r, block_v, H,
-                                            dw_grid=True)
-    dw = pl.pallas_call(
-        functools.partial(_lxent_dw_kernel_sharded, block_v=block_v,
-                          nr=nr, vocab=V, vocab_total=vocab_total,
-                          rows=R, eps=float(eps)),
-        grid=(nv, nr),
-        in_specs=[x_spec, w_spec, row_spec, row_spec, row_spec, row_spec],
-        out_specs=w_spec,
-        out_shape=jax.ShapeDtypeStruct((H, V), w.dtype),
-        scratch_shapes=[pltpu.VMEM((H, block_v), jnp.float32)],
-        compiler_params=_mosaic_params(),
-        interpret=_interpret(),
-    )(x2d, w, lbl, vld2, lse2, dy2)
-    return dx, dw
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def sharded_linear_xent(x2d, w_local, labels, eps, axis, vocab_total,
-                        block_r, block_v):
-    """Per-shard linear xent over the vocab axis `axis` of a live
-    shard_map: x2d [R, H] (this shard's rows), w_local [H, V/n] (this
-    shard's vocab slab), labels [R] in GLOBAL vocab coords.  Collectives
-    are per-row scalars only: pmax/psum combine each shard's online
-    (lse, gold, sum) into the global loss, and the backward psums dx
-    over the vocab shards.  Returns [R, 1] f32 losses on every shard."""
-    loss, _res = _sharded_lxent_fwd(x2d, w_local, labels, eps, axis,
-                                    vocab_total, block_r, block_v)
-    return loss
-
-
-def _sharded_lxent_fwd(x2d, w_local, labels, eps, axis, vocab_total,
-                       block_r, block_v):
-    R = x2d.shape[0]
-    v_local = w_local.shape[1]
-    col0 = jax.lax.axis_index(axis).astype(jnp.int32) * v_local
-    lbl = labels.astype(jnp.int32).reshape(R)
-    lbl_local = lbl - col0
-    lse_j, gold_j, sum_j = _lxent_parts(x2d, w_local, lbl_local,
-                                        block_r, block_v)
-    m = jax.lax.pmax(lse_j, axis)
-    lse = jnp.log(jax.lax.psum(jnp.exp(lse_j - m), axis)) + m
-    gold = jax.lax.psum(gold_j, axis)
-    sz = jax.lax.psum(sum_j, axis)
-    valid = ((lbl >= 0) & (lbl < vocab_total)).astype(
-        jnp.float32)[:, None]
-    loss = valid * (1.0 - eps) * (lse - gold)
-    if eps:
-        loss = loss + eps * (lse - sz / vocab_total)
-    return loss, (x2d, w_local, lbl_local, valid, lse)
-
-
-def _sharded_lxent_vjp_fwd(x2d, w_local, labels, eps, axis, vocab_total,
-                           block_r, block_v):
-    return _sharded_lxent_fwd(x2d, w_local, labels, eps, axis,
-                              vocab_total, block_r, block_v)
-
-
-def _sharded_lxent_vjp_bwd(eps, axis, vocab_total, block_r, block_v,
-                           res, dy):
-    x2d, w_local, lbl_local, valid, lse = res
-    # the loss leaves the enclosing shard_map through an out_spec that
-    # does NOT mention the vocab axis: the transpose SPLITS the global
-    # cotangent across the shards (only sum_j dy_j == dy is guaranteed).
-    # The tile math needs the full dy on every shard — reconstitute it
-    dy = jax.lax.psum(dy, axis)
-    dx_p, dw = _lxent_bwd_sharded(x2d, w_local, lbl_local, valid, lse,
-                                  dy, eps, vocab_total, block_r, block_v)
-    # dx stays the PARTIAL sum of this shard's columns: x enters the
-    # enclosing shard_map with the vocab axis unmentioned, and under
-    # check_vma=False the shard_map transpose itself psums such inputs'
-    # cotangents — an explicit psum here would double-count
-    dlbl = np.zeros(lbl_local.shape, dtype=jax.dtypes.float0)
-    return dx_p, dw, dlbl
-
-
-sharded_linear_xent.defvjp(_sharded_lxent_vjp_fwd, _sharded_lxent_vjp_bwd)

@@ -7,20 +7,19 @@ program an unwrapped kernel forces XLA to all-gather every operand onto
 each device, run the full kernel everywhere, and throw n-1 copies of
 the work away.  The qvec-attention lowering already solved this for the
 ragged serving step (``_qvec_attention_mesh``); this module generalizes
-the recipe to the fc / fused_swiglu / fused_residual_ln /
-fused_linear_xent lowerings:
+the recipe to the fc / fused_swiglu / fused_residual_ln lowerings:
 
 1. resolve the op's WEIGHT NAMES from the OpDesc being lowered
    (``ctx.block.ops[ctx.op_idx]`` — the grad-side re-run of a forward
    rule sees the same block through ``lower_grad_op``),
 2. look the names up in the live rule table (``current_spmd``) to
-   classify the layout — column-parallel, row-parallel, vocab-sharded,
-   or replicated-weights-with-dp-sharded-rows,
+   classify the layout — column-parallel, row-parallel, or
+   replicated-weights-with-dp-sharded-rows,
 3. run the SAME custom_vjp kernel per shard inside ``shard_map`` with
    matching in/out specs.  ``check_vma=False`` autodiff supplies the
    transpose-side psums for replicated operands; the only hand-written
-   collectives are the mathematical ones (the row-parallel epilogue's
-   partial-sum psum, the vocab-sharded xent's lse/gold/sum combine).
+   collective is the mathematical one (the row-parallel epilogue's
+   partial-sum psum).
 
 Block sizes inside shard_map are the deterministic defaults computed
 from the LOCAL shard shapes — a per-shard tuning search would attribute
@@ -39,8 +38,7 @@ import jax.numpy as jnp
 
 __all__ = [
     "mesh_ctx", "op_weight_name", "spmd_matmul_bias_act",
-    "spmd_matmul_swiglu", "spmd_add_layer_norm", "spmd_linear_xent",
-    "spmd_flash_attention",
+    "spmd_matmul_swiglu", "spmd_add_layer_norm", "spmd_flash_attention",
 ]
 
 
@@ -244,61 +242,6 @@ def spmd_add_layer_norm(ctx, x2, y2, gamma, beta, eps):
     rs = P(row, None)
     return _shard_map(mesh, body, (rs, rs, P(None), P(None)),
                       (rs, rs))(x2, y2, gamma, beta)
-
-
-def spmd_linear_xent(ctx, x2, w, labels, eps, transpose_w):
-    """Mesh-aware fused_linear_xent: when the projection weight is
-    vocab-sharded (softmax_out.w P(None, mp), or tied emb.w P(mp, None)
-    arriving transposed), each shard streams its own [H, V/n] slab
-    through sharded_linear_xent — per-row scalar collectives combine
-    the shards' online (lse, gold, sum).  Rows additionally shard over
-    dp.  `w` is the value ALREADY transposed to [H, V]; `transpose_w`
-    says which dim of the DECLARED weight the rule table sees as
-    vocab."""
-    from jax.sharding import PartitionSpec as P
-
-    from .pallas_kernels import _lxent_default_blocks, fused_linear_xent, \
-        sharded_linear_xent
-
-    mc = mesh_ctx()
-    if mc is None:
-        return None
-    mesh, rules, mp, nsh, dp_axis, ndp = mc
-    wname = op_weight_name(ctx, "fused_linear_xent", "W")
-    if wname is None:
-        return None
-    decl_shape = tuple(w.shape[::-1]) if transpose_w else tuple(w.shape)
-    spec = rules.spec_for(wname, decl_shape)
-    vdim = 0 if transpose_w else 1
-    R, H = x2.shape
-    V = w.shape[1]
-    if _dim_has(spec, 1 - vdim, mp):
-        return None  # hidden-sharded projection: not a supported layout
-    vocab_sharded = nsh > 1 and _dim_has(spec, vdim, mp) and V % nsh == 0
-    row = _row_axis(dp_axis, ndp, R)
-    nrow = ndp if row else 1
-    if not vocab_sharded and row is None:
-        return None
-
-    if vocab_sharded:
-        br, bv = _lxent_default_blocks(R // nrow, H, V // nsh)
-
-        def body(xl, wl, ll):
-            return sharded_linear_xent(xl, wl, ll.reshape(-1), eps, mp,
-                                       V, br, bv)
-
-        return _shard_map(
-            mesh, body, (P(row, None), P(None, mp), P(row)),
-            P(row, None))(x2, w, labels.reshape(R))
-
-    br, bv = _lxent_default_blocks(R // nrow, H, V)
-
-    def body(xl, wl, ll):
-        return fused_linear_xent(xl, wl, ll.reshape(-1), eps, br, bv)
-
-    return _shard_map(
-        mesh, body, (P(row, None), P(None, None), P(row)),
-        P(row, None))(x2, w, labels.reshape(R))
 
 
 def spmd_flash_attention(mc, q, k, v, kbias_b, seg_b, causal, scale, bq, bk,

@@ -47,7 +47,7 @@ level up, to knobs that select between whole PROGRAMS:
                           it on synthetic feeds; None = engine default
 * ``use_draft``         — consult-only serving knob: arm the draft
                           model at all ("self" / True / False / None);
-                          deposited by BENCH_SERVE_SPEC, never searched
+                          deposited by a serving run, never searched
 * ``prefix_chunk``      — consult-only serving knob: prefix-cache match
                           granularity (a multiple of the engine width);
                           None = engine default (== width)

@@ -747,17 +747,14 @@ class LinearXentFusePass(Pass):
     (mul, or matmul(transpose_Y) for tied embeddings) feeding
     softmax_with_cross_entropy (hard label) or smooth_label_xent
     becomes ONE fused_linear_xent op, whose lowering owns its backward.
-    With default flags that is pallas_kernels.linear_xent_tiled: a
-    custom VJP in plain XLA ops over row tiles of at most ~256 MiB of
-    f32 logits — no [R, V] array in either direction, the logits
-    gradient formed once in the operands' dtype for both gradient
-    matmuls (one tile, so [R, V] after all, in a GSPMD-partitioned
-    program).  Under FLAGS_use_pallas the logits never reach HBM at
-    all (pallas_kernels.fused_linear_xent streams vocab tiles through
-    an online logsumexp; the backward recomputes per-tile softmax
-    against W).  Conservative: 2-D weight, hard labels, no
-    ignore_index, the xent's Softmax output unused ANYWHERE (all
-    blocks), single-consumer logits, protected fetches respected.
+    That is math_ops.linear_xent_tiled: a custom VJP in plain XLA ops
+    over row tiles of at most ~256 MiB of f32 logits — no [R, V] array
+    in either direction, the logits gradient formed once in the
+    operands' dtype for both gradient matmuls (one tile, so [R, V]
+    after all, in a GSPMD-partitioned program).  Conservative: 2-D
+    weight, hard labels, no ignore_index, the xent's Softmax output
+    unused ANYWHERE (all blocks), single-consumer logits, protected
+    fetches respected.
 
     Label contract: OUT-OF-RANGE hard labels (stray pad ids) get zero
     loss and zero gradient after fusion — the fused op's documented
@@ -765,7 +762,7 @@ class LinearXentFusePass(Pass):
     (dense clamps the gather, the softmax_xent kernel yields lse), so
     the pass normalizes an undefined behavior rather than changing a
     defined one; in-range labels are unaffected
-    (test_fused_linear_xent_out_of_range_label_convention)."""
+    (test_linear_xent_tiled_matches_dense_autodiff draws such labels)."""
 
     def apply(self, program, scope=None):
         block = program.global_block()

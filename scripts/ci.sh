@@ -13,7 +13,7 @@ export XLA_FLAGS="${XLA_FLAGS:-} --xla_force_host_platform_device_count=8"
 export FLAGS_check_program=1
 
 echo "== byte-compile check =="
-python -m compileall -q paddle_tpu tools examples bench.py __graft_entry__.py
+python -m compileall -q paddle_tpu tools examples __graft_entry__.py
 
 echo "== static-analysis lane (tools/check_program.py) =="
 # every model-builder program (train / decode / ragged serving /
@@ -123,7 +123,7 @@ python -m pytest tests/test_dist_transpiler.py -q -m "" \
 echo "== pallas kernel pass (FLAGS_use_pallas=1, interpret mode) =="
 # the primitive-kernel layer end to end on the CPU mesh: every kernel's
 # interpret-mode numerics vs its dense reference (matmul-epilogue,
-# swiglu, residual-LN, logits-free xent, vector-qstart flash), the
+# swiglu, residual-LN, softmax xent, vector-qstart flash), the
 # fuse-pass rewrites, the tuning-cache contract, and the serving
 # churn-exactness suite with the ragged step's flash kernel live.
 # FLAGS_kernel_autotune=0 + the committed pinned cache mean CI NEVER
@@ -194,7 +194,7 @@ echo "== pipeline-parallel lane (4-device dp x mp x pp mesh) =="
 # steps with dropout LIVE, (dp,pp)=(2,2) and (1,4) mesh shapes, the
 # pp x remat x bf16-AMP compose, and on-device packed-state residency.
 # Program autotune rides CONSULT-ONLY against the committed pinned
-# cache — the pp bench decision ((1,1,4), M=8) resolves without search.
+# cache — the pinned pp decision ((1,1,4), M=8) resolves without search.
 XLA_FLAGS="--xla_force_host_platform_device_count=4" \
 FLAGS_program_autotune=0 \
 FLAGS_program_tune_cache=tests/data/ci_program_tune_cache.json \

@@ -312,7 +312,7 @@ def main():
 
             time.sleep(step_sleep)
     # comm evidence: client-side round trips / bytes plus feed-upload
-    # time — deterministic counters bench.py and the smoke tests read
+    # time — deterministic counters the smoke tests read
     from paddle_tpu.distributed import rpc as _rpc
 
     counters = _rpc.get_comm_stats()

@@ -649,7 +649,7 @@ def test_compile_cache_placed_from_outside_or_at_the_fixed_path():
 
     found = subprocess.run(
         ["grep", "-rl", "--include=*.py", "jax_compilation_cache_dir",
-         "paddle_tpu", "tools", "scripts", "examples", "bench.py",
+         "paddle_tpu", "tools", "scripts", "examples",
          "__graft_entry__.py"],
         cwd=root, capture_output=True, text=True).stdout.split()
     assert found == ["paddle_tpu/compile_cache.py"], found

@@ -1,7 +1,7 @@
 """Per-(kernel, shape-bucket) tuning cache (ops/kernel_tuning.py): seed/
 hit/search semantics, JSON persistence + reload, pinned consult-only
 mode, shape bucketing, corrupt-file tolerance, and the attribution
-counters bench.py reads."""
+counters."""
 
 import json
 import os
@@ -169,7 +169,7 @@ def test_measure_candidate_builds_and_times():
 def test_search_candidate_traces_do_not_tick_hit_counters():
     """Regression (review finding): candidate timing re-traces kernel
     bodies; those traces must not inflate the per-family pallas-hit
-    attribution bench.py reports."""
+    attribution."""
     flags.set_flags({"kernel_tune_cache": "", "kernel_autotune": True})
 
     def measure(p):

@@ -3,10 +3,11 @@ reference's test_conv2d_op.py / test_pool2d_op.py / test_batch_norm_op.py
 numpy-reference contract."""
 
 import numpy as np
+import pytest
 
 import paddle_tpu as fluid
 from paddle_tpu import layers
-from op_test import OpTest
+from op_test import OpTest, run_single_op
 
 rng = np.random.RandomState(7)
 
@@ -212,3 +213,64 @@ def test_gru_layer_forward():
     (h,) = exe.run(feed={"x": xv}, fetch_list=[hidden])
     assert np.asarray(h).shape == (4, 5, 8)
     assert np.isfinite(np.asarray(h)).all()
+
+
+def _sigmoid(v):
+    return 1.0 / (1.0 + np.exp(-v))
+
+
+def _ref_recurrence(kind, x, w, h0, c0, lens, reverse):
+    """Per-row numpy loops over the valid steps only: lstm gates
+    i|f|c_hat|o (lstm_op.cc), gru [update|reset|candidate] with
+    h = u*c + (1-u)*h_prev (gru_kernel.h).  A step at or past a row's
+    length repeats the state the row holds there."""
+    bsz, t, _ = x.shape
+    hid = h0.shape[1]
+    hs, cs = np.zeros((bsz, t, hid)), np.zeros((bsz, t, hid))
+    for b in range(bsz):
+        h, c = h0[b].astype("float64"), c0[b].astype("float64")
+        for step in (range(t - 1, -1, -1) if reverse else range(t)):
+            if step < lens[b]:
+                if kind == "lstm":
+                    i, f, g, o = np.split(x[b, step] + h @ w, 4)
+                    c = _sigmoid(f) * c + _sigmoid(i) * np.tanh(g)
+                    h = _sigmoid(o) * np.tanh(c)
+                else:
+                    u, r = np.split(_sigmoid(
+                        x[b, step, :2 * hid] + h @ w[:, :2 * hid]), 2)
+                    cand = np.tanh(x[b, step, 2 * hid:]
+                                   + (r * h) @ w[:, 2 * hid:])
+                    h = u * cand + (1 - u) * h
+            hs[b, step], cs[b, step] = h, c
+    last = 0 if reverse else t - 1
+    return hs, cs, hs[:, last], cs[:, last]
+
+
+@pytest.mark.parametrize("reverse", [False, True])
+@pytest.mark.parametrize("kind", ["lstm", "gru"])
+def test_padded_recurrence_matches_numpy(kind, reverse):
+    """padded_lstm / padded_gru (the one lax.scan lowering each op has)
+    against numpy loops, in both directions, with initial state, a bias
+    (lstm), and rows shorter than the batch's time axis, one of them
+    empty: every step's hidden (and cell) state and the last ones."""
+    bsz, t, hid = 4, 6, 5
+    n = 4 if kind == "lstm" else 3
+    r = np.random.RandomState(11)
+    x = r.randn(bsz, t, n * hid).astype("float32")
+    w = (r.randn(hid, n * hid) * 0.4).astype("float32")
+    h0 = r.randn(bsz, hid).astype("float32")
+    c0 = r.randn(bsz, hid).astype("float32")
+    lens = np.array([6, 3, 0, 1], "int32")
+    ins = {"Input": x, "Weight": w, "H0": h0, "SeqLen": lens}
+    if kind == "lstm":
+        bias = (r.randn(n * hid) * 0.2).astype("float32")
+        ins.update(C0=c0, Bias=bias)
+        slots = ["Hidden", "CellSeq", "LastH", "LastC"]
+        want = _ref_recurrence(kind, x + bias, w, h0, c0, lens, reverse)
+    else:
+        slots = ["Hidden", "LastH"]
+        want = _ref_recurrence(kind, x, w, h0, c0, lens, reverse)[0::2]
+    got = run_single_op("padded_" + kind, ins, {"is_reverse": reverse}, slots)
+    for slot, a, b in zip(slots, got, want):
+        np.testing.assert_allclose(np.asarray(a), b, rtol=1e-5, atol=1e-5,
+                                   err_msg=slot)

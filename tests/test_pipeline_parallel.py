@@ -247,13 +247,22 @@ def test_n_microbatches_is_a_consult_only_knob():
 
 def test_ci_pinned_pp_decision_consults_without_search():
     """The committed CI cache pins (mesh_shape=(1,1,4), M=8) for the
-    BENCH_SPMD_PP probe program: consult-only mode must return it
-    verbatim, never timing anything (FLAGS_program_autotune=0 is the
-    CI regime)."""
+    six-layer probe program below (the cache key is the program's
+    signature, so its widths are part of the pin): consult-only mode
+    must return it verbatim, never timing anything
+    (FLAGS_program_autotune=0 is the CI regime)."""
     from paddle_tpu.transpiler import autotune as at
     from paddle_tpu.utils import memory_analysis as ma
 
-    import bench
+    class ProbeHP(gpt2.GPT2Config):
+        vocab_size = 256
+        n_ctx = 32
+        d_model = 64
+        n_layer = 6          # deep enough that 4 stages stay balanced
+        n_head = 4
+        d_inner = 128
+        dropout = 0.0
+        tie_embeddings = False
 
     if not str(flags.get_flag("program_tune_cache")).endswith(
             "ci_program_tune_cache.json"):
@@ -262,7 +271,8 @@ def test_ci_pinned_pp_decision_consults_without_search():
     _fresh()
     at.clear_cache(forget_path=True)
     try:
-        _, probe, _, feeds, _ = bench._pp_bench_program(False, 16)
+        probe, _, feeds, _ = gpt2.gpt2_lm_program(ProbeHP, seq_len=16,
+                                                  lr=3e-4)
         spec = ma.program_feed_specs(probe, feeds, batch_hint=8)
         d = at.tune(probe, spec)
         assert d["mesh_shape"] == (1, 1, 4)

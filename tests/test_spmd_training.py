@@ -136,8 +136,55 @@ def test_epilogue_kernels_dispatch_inside_sharded_step():
     got, _, _, _ = _train(make_mesh({"dp": 2, "mp": 2}), use_pallas=True)
     hits = kernel_tuning.attribution()["pallas_hits"]
     assert hits.get("matmul_epilogue", 0) > 0, hits
-    assert hits.get("xent", 0) > 0, hits
     np.testing.assert_allclose(got, dense, rtol=1e-5)
+
+
+@needs_four_devices
+@pytest.mark.parametrize("eps", [0.0, 0.1])
+@pytest.mark.parametrize("transpose_w", [False, True])
+def test_linear_xent_op_under_dp2_mp2_mesh_equals_unsharded(
+        transpose_w, eps, monkeypatch):
+    """The vocabulary head's op traced under a live dp2 x mp2 mesh, rows
+    over dp and the vocabulary over mp: loss and both gradients equal the
+    unsharded op's, which here walks four row tiles; under the mesh the
+    input is one tile and the trace holds no loop (a scan's dw carry
+    would be all-reduced over dp once per tile)."""
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding
+
+    from paddle_tpu.core.registry import LowerCtx, get_op
+    from paddle_tpu.ops import math_ops
+    from paddle_tpu.parallel.partition_rules import spmd_lowering
+
+    B, T, H, V = 4, 8, 16, 32
+    monkeypatch.setattr(math_ops, "_LXENT_TILE_BYTES", 4 * V * B * 2)
+    rng = np.random.RandomState(40)
+    x = jnp.asarray(rng.randn(B, T, H), jnp.float32)
+    w = jnp.asarray(rng.randn(*((V, H) if transpose_w else (H, V))) * 0.3,
+                    jnp.float32)
+    lbl = rng.randint(0, V, (B, T, 1))
+    lbl.flat[1], lbl.flat[2] = -1, V + 3
+    lbl = jnp.asarray(lbl, jnp.int32)
+    dy = jnp.asarray(rng.rand(B, T, 1) + 0.5, jnp.float32)
+
+    def loss_and_grads(x, w):
+        loss, vjp = jax.vjp(lambda x, w: get_op("fused_linear_xent").lower(
+            LowerCtx(), {"X": [x], "W": [w], "Label": [lbl]},
+            {"epsilon": eps, "transpose_w": transpose_w})["Loss"][0], x, w)
+        return (loss,) + vjp(dy)
+
+    want = jax.jit(loss_and_grads)(x, w)
+    assert "scan" in str(jax.make_jaxpr(loss_and_grads)(x, w))
+    mesh = make_mesh({"dp": 2, "mp": 2}, jax.devices()[:4])
+    xs = jax.device_put(x, NamedSharding(mesh, P("dp", None, None)))
+    ws = jax.device_put(w, NamedSharding(
+        mesh, P("mp", None) if transpose_w else P(None, "mp")))
+    with spmd_lowering(mesh, train_partition_rules_for("gpt2")):
+        assert "scan" not in str(jax.make_jaxpr(loss_and_grads)(xs, ws))
+        got = jax.jit(loss_and_grads)(xs, ws)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-6)
 
 
 # ---------------------------------------------------------------------------
@@ -264,7 +311,7 @@ def test_sharded_train_step_lowers_for_tpu_without_chips(monkeypatch):
     finally:
         flags.set_flags({"kernel_autotune": autotune})
     hits = r["attribution"]["pallas_hits"]
-    for fam in ("attention", "layernorm", "matmul_epilogue", "xent"):
+    for fam in ("attention", "layernorm", "matmul_epilogue"):
         assert hits.get(fam, 0) > 0, hits  # dispatched, not dense
     monkeypatch.setattr(pk, "_interpret", lambda: False)
     jax.clear_caches()  # the interpreted trace must not be reused
