@@ -106,13 +106,18 @@ class LowerCtx:
 
     Carries the step RNG key (ops fold in their op index for independent
     streams — the analog of the reference's per-op seed attrs) and trace-wide
-    flags.
+    flags.  `platform`: the platform of the device(s) the step is placed
+    on ("tpu", "cpu"), as the Executor states it from its place; None
+    where a caller did not say (a lowering that chooses by platform then
+    asks jax.default_backend()).
     """
 
-    def __init__(self, rng_key=None, is_test=False, scope=None):
+    def __init__(self, rng_key=None, is_test=False, scope=None,
+                 platform=None):
         self.rng_key = rng_key
         self.is_test = is_test
         self.scope = scope
+        self.platform = platform
         self.op_idx = 0
         self.block = None
         self.trace_block = None  # fn(block_idx, env) for control-flow ops
@@ -169,7 +174,7 @@ def lower_grad_op(ctx, op, ins, attrs):
             if _is_float(v):
                 diff_pos.append((s, i))
 
-    sub_ctx = LowerCtx(ctx.rng_key, ctx.is_test, ctx.scope)
+    sub_ctx = LowerCtx(ctx.rng_key, ctx.is_test, ctx.scope, ctx.platform)
     sub_ctx.op_idx = attrs.get("__fwd_op_idx__", ctx.op_idx)
     sub_ctx.trace_block = ctx.trace_block
     # mesh-aware lowerings resolve the forward OpDesc (weight names ->

@@ -176,7 +176,8 @@ def analyze_block(program, block_idx, feed_names, fetch_names, keep=None):
 
 
 def build_traced_function(program, block_idx, feed_names, fetch_names, scope,
-                          collective_axis=None, spmd=None, keep=None):
+                          collective_axis=None, spmd=None, keep=None,
+                          platform=None):
     """`collective_axis`: optional ("axis_name", nranks) pair binding the
     collective-lowering context around the trace — c_allreduce_* ops then
     lower to jax.lax collectives over that axis instead of identity.  The
@@ -193,7 +194,11 @@ def build_traced_function(program, block_idx, feed_names, fetch_names, scope,
     `keep`: optional explicit per-op keep mask for `block_idx`, replacing
     the internal DCE mask.  Pipeline stage slicing passes its own masks so
     a stage traces exactly its op range — DCE would otherwise drag the
-    whole optimizer chain in through persistable writes."""
+    whole optimizer chain in through persistable writes.
+
+    `platform`: the platform of the device(s) the caller will run the
+    step on (LowerCtx.platform: lowerings that pick a kernel by platform
+    read it)."""
     if keep is None:
         keep = dce_mask(program, block_idx, fetch_names)
     reads, writes = analyze_block(program, block_idx, feed_names, fetch_names, keep)
@@ -236,7 +241,8 @@ def build_traced_function(program, block_idx, feed_names, fetch_names, scope,
         env.update(ro_state)
         env.update(rw_state)
         env.update(feeds)
-        ctx = LowerCtx(rng_key=rng_key, is_test=is_test, scope=scope)
+        ctx = LowerCtx(rng_key=rng_key, is_test=is_test, scope=scope,
+                       platform=platform)
 
         def trace_while(op, env):
             """Lower a `while` op to lax.while_loop (while_op.cc:36 analog:
@@ -448,7 +454,8 @@ class ExecutionCache:
         # occupancy churn must change feed VALUES only, never keys
         self.compile_count = 0
 
-    def get(self, program, block_idx, feed_sig, fetch_names, scope, donate=True):
+    def get(self, program, block_idx, feed_sig, fetch_names, scope, donate=True,
+            platform=None):
         # flags that change lowering decisions are part of the compile key —
         # toggling them must recompile, not hit a stale executable
         from ..flags import get_flag
@@ -473,8 +480,8 @@ class ExecutionCache:
         with RecordEvent("trace_compile", feed_sig=sig_text(feed_sig)):
             feed_names = tuple(n for n, _, _ in feed_sig)
             traced = build_traced_function(
-                program, block_idx, feed_names, fetch_names, scope
-            )
+                program, block_idx, feed_names, fetch_names, scope,
+                platform=platform)
             jitted = jax.jit(traced.fn,
                              donate_argnums=(2,) if donate else ())
             compiled = CompiledBlock(traced, jitted, feed_sig)

@@ -396,7 +396,8 @@ class Executor:
         feed_sig = tuple(
             sorted((n, tuple(a.shape), str(a.dtype)) for n, a in feed_arrays.items())
         )
-        compiled = self._cache.get(program, 0, feed_sig, fetch_names, scope)
+        compiled = self._cache.get(program, 0, feed_sig, fetch_names, scope,
+                                   platform=device.platform)
         traced = compiled.traced
         ro_state, rw_state = self._gather(
             lambda n: self._commit_state(n, scope.find_var(n), device, scope),
@@ -548,7 +549,8 @@ class Executor:
             with RecordEvent("trace_compile", feed_sig=sig_text(feed_sig)):
                 traced = build_traced_function(
                     program, 0, tuple(n for n, _, _ in feed_sig),
-                    fetch_names, scope, spmd=(mesh, rules))
+                    fetch_names, scope, spmd=(mesh, rules),
+                    platform=mesh.devices.flat[0].platform)
                 sh = {n: self._spmd_state_sharding(program, mesh, rules, n,
                                                   scope)
                       for n in set(traced.ro_names) | set(traced.rw_names)
@@ -972,8 +974,7 @@ class Executor:
 
             traced = build_traced_function(
                 program, 0, tuple(n for n, _, _ in feed_sig), fetch_names,
-                scope
-            )
+                scope, platform=device.platform)
             rw_set = set(traced.rw_names)
             fresh = [n for n in traced.updated if n not in rw_set]
 

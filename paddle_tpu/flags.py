@@ -236,10 +236,13 @@ DEFINE_flag("cudnn_deterministic", False,
             "compat; XLA compilation is deterministic already")
 DEFINE_flag("use_mkldnn", False, "compat no-op (XLA owns fusion)")
 DEFINE_flag("use_pallas", False,
-            "dispatch hot ops (attention, layer_norm) to the Pallas "
-            "kernel library instead of plain XLA lowerings")
+            "dispatch hot ops (decode-path attention, layer_norm, matmul "
+            "epilogues, softmax xent) to the Pallas kernel library "
+            "instead of plain XLA lowerings; fused_attention's training "
+            "path does not read it (platform and shape choose its kernel)")
 DEFINE_flag("flash_block_q", 0,
-            "flash-attention q-block rows (0 = the kernel default 128); "
+            "decode-path (QStart) flash-attention q-block rows (0 = the "
+            "kernel default 128); "
             "on-chip sweep knob: a multiple of 128 (or the full q "
             "length) that divides the q sequence length — the Mosaic "
             "minor-dim rule for the lse/delta specs (invalid values "

@@ -1501,7 +1501,8 @@ def log_loss(input, label, epsilon=1e-4, name=None):
 def fused_attention(q, k, v, causal=False, scale=None, bias=None,
                     window=0, segment_ids=None, qstart=None, name=None):
     """Fused scaled-dot-product attention over [batch, heads, T, d]
-    (flash-attention kernel under FLAGS_use_pallas).  bias: optional
+    (the blockwise flash kernel where the placed platform and the shape
+    choose it: ops/nn_ops._flash_engages).  bias: optional
     additive key-padding bias, rank-1 in the key axis ([B, Tk] or
     [B, 1, 1, Tk]) — covers padding masks without a [Tq, Tk] tensor;
     combine with causal=True for decoder self-attention.  window > 0
@@ -1510,8 +1511,8 @@ def fused_attention(q, k, v, causal=False, scale=None, bias=None,
     blocks are skipped in the flash kernels.  segment_ids: optional
     [B, T] int ids from sequence packing (reader.packing) — attention
     stays within each packed segment (ids compared on the fly, no
-    [T, T] mask tensor; rides the flash kernels under FLAGS_use_pallas
-    as two extra rank-1 operands, dense-XLA otherwise).  qstart:
+    [T, T] mask tensor; two extra rank-1 operands of the flash kernel
+    where it is engaged, fused into the dense softmax otherwise).  qstart:
     optional [1] int var (chunked KV-cached decode): query i sits at
     GLOBAL position qstart + i while keys sit at their cache indices —
     causal masking applies in global positions and Tq may differ from
@@ -1535,12 +1536,16 @@ def fused_attention(q, k, v, causal=False, scale=None, bias=None,
         inputs["SegmentIds"] = [segment_ids]
     if qstart is not None:
         inputs["QStart"] = [qstart]
-    helper.append_op(
+    # Out is Q's shape and dtype, said here: appended straight to the
+    # block, the op's lowering (a kernel, where platform and shape choose
+    # one) is never evaluated to build a program
+    helper.main_program.current_block().append_op(
         "fused_attention",
         inputs=inputs,
         outputs={"Out": [out]},
         attrs={"causal": causal, "scale": scale, "window": int(window)},
     )
+    out.shape = tuple(q.shape)
     return out
 
 

@@ -8,8 +8,8 @@ options on GPT2Config: n_kv_head (grouped-query attention), use_rotary
 ffn_gate.w/ffn_up.w replace ffn_in.w), tie_embeddings (logits reuse
 emb.w; no softmax_out.w exists).  Attention always goes through the
 fused_attention op with causal=True — no [T, T] mask tensor ever exists
-in the program (the op's flash kernel runs under FLAGS_use_pallas, fused
-XLA otherwise).  Parameter names reuse the transformer TP patterns
+in the program (the op takes its flash kernel from the placed platform
+and the shape, fused XLA otherwise).  Parameter names reuse the transformer TP patterns
 (mha_[qkv].w / mha_o.w / ffn_in.w or ffn_gate.w+ffn_up.w / ffn_out.w /
 emb.w / softmax_out.w) so `parallel.transformer_tp_rules` shards every
 option combination unchanged on a {dp, mp} mesh.

@@ -117,10 +117,11 @@ def multi_head_attention(
     """All heads in one qkv projection + batched matmuls (MXU-shaped).
     attn_bias: [B, 1 or H, Tq, Tk] additive mask (−1e9 at masked slots).
 
-    fused=True routes through the fused_attention op (flash kernel under
-    FLAGS_use_pallas, fused XLA otherwise): padding is expressed as the
-    rank-1 kpad_bias [B, Tk] and causality as a flag, so the [Tq, Tk]
-    score matrix never hits HBM.  Attention-prob dropout is folded away on
+    fused=True routes through the fused_attention op (the flash kernel
+    where the placed platform and the shape choose it, fused XLA
+    otherwise): padding is expressed as the rank-1 kpad_bias [B, Tk] and
+    causality as a flag, so no [Tq, Tk] mask is built (and, in the
+    kernel, no score matrix hits HBM).  Attention-prob dropout is folded away on
     this path (the probs are never materialized) — standard flash-attention
     practice; residual/ffn dropout still applies.
 

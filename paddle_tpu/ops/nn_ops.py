@@ -830,54 +830,23 @@ def _qvec_attention_mesh(q, k, v, qstart, scale, mesh, axis, bq_flag,
     return jax.lax.with_sharding_constraint(out, sh)
 
 
-@register("fused_attention", no_grad_inputs=("QStart",))
-def _fused_attention(ctx, ins, attrs):
-    """Fused scaled-dot-product attention (the cuDNN-fused-kernel slot of
-    the reference, TPU-style): flash kernel under FLAGS_use_pallas, dense
-    XLA otherwise.  Q/K/V: [batch, heads, T, d]."""
+def _qstart_attention(q, k, v, qstart, scale, window):
+    """fused_attention's decode paths: causal cutoffs in GLOBAL positions
+    from QStart (a scalar: chunked decode; [B]: the ragged serving step).
+    q/k/v: [B, H, Tq|Tk, D].  Their flash kernels (flash_attention_piece,
+    flash_attention_qvec) run under FLAGS_use_pallas, dense XLA otherwise."""
+    from ..flags import get_flag
     from .pallas_kernels import (
         _dense_attention,
-        flash_attention,
-        use_pallas,
+        flash_attention_piece,
         use_pallas_unwrapped,
     )
 
-    q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
-    causal = bool(attrs.get("causal", False))
-    window = int(attrs.get("window", 0) or 0)  # sliding-window (causal)
-    if window < 0:
-        raise ValueError("fused_attention: window must be >= 0")
-    if window and not causal:
-        raise ValueError(
-            "fused_attention: window requires causal=True (consistent "
-            "across the pallas and dense paths)")
-    scale = attrs.get("scale") or 1.0 / (q.shape[-1] ** 0.5)
     b, h, t, d = q.shape
     tk = k.shape[2]
-    # chunked-decode global query offset: query i at position QStart+i,
-    # keys at their cache indices — Tq may differ from Tk.  A size-1
-    # QStart is the classic scalar offset (one chunk position for the
-    # whole batch); size B keeps PER-ROW offsets (ragged serving step).
-    qstart = None
-    if ins.get("QStart"):
-        qstart = ins["QStart"][0].reshape(-1)
-        qstart = qstart.reshape(()) if qstart.shape[0] == 1 else qstart
-    if qstart is not None:
-        if not causal:
-            raise ValueError("fused_attention: QStart requires causal=True")
-        if ins.get("Bias") or ins.get("SegmentIds"):
-            raise ValueError(
-                "fused_attention: QStart owns the causal cutoffs — "
-                "Bias/SegmentIds are not combinable with it")
-    elif causal and t != tk:
-        raise ValueError(
-            "fused_attention: causal requires Tq == Tk, got %d vs %d" % (t, tk)
-        )
     qf = q.reshape(b * h, t, d)
     kf = k.reshape(b * h, tk, d)
     vf = v.reshape(b * h, tk, d)
-    from ..flags import get_flag
-
     bq_flag = int(get_flag("flash_block_q") or 0)
     bk_flag = int(get_flag("flash_block_k") or 0)
 
@@ -936,7 +905,7 @@ def _fused_attention(ctx, ins, attrs):
         return _legalize_blocks(int(params["block_q"]),
                                 int(params["block_k"]))
 
-    if qstart is not None and qstart.ndim > 0:
+    if qstart.ndim > 0:
         # PER-ROW offset-causal (the continuous-batching ragged step):
         # QStart is [B], row b's query i sits at global position
         # QStart[b] + i — every slot in the serving pool gets its own
@@ -975,9 +944,9 @@ def _fused_attention(ctx, ins, attrs):
             axis = rules.mp_axis
             nsh = mesh_axis_sizes(mesh).get(axis, 1)
             if nsh > 1 and h % nsh == 0:
-                return {"Out": [_qvec_attention_mesh(
+                return _qvec_attention_mesh(
                     q, k, v, qstart, float(scale), mesh, axis,
-                    bq_flag, bk_flag, _mosaic_legal)]}
+                    bq_flag, bk_flag, _mosaic_legal)
         if use_pallas_unwrapped():
             bq = 128 if t % 128 == 0 else t
             bk = 128 if tk % 128 == 0 else tk
@@ -1013,7 +982,7 @@ def _fused_attention(ctx, ins, attrs):
                 qsv = jnp.repeat(qstart.astype(jnp.int32), h)  # [B*H]
                 out = flash_attention_qvec(qf, kf, vf, qsv, float(scale),
                                            bq, bk)
-                return {"Out": [out.reshape(b, h, t, d)]}
+                return out.reshape(b, h, t, d)
 
         s = (jnp.einsum("bqd,bkd->bqk", qf, kf).astype(jnp.float32)
              * float(scale))  # [B*H, Tq, Tk]
@@ -1026,7 +995,87 @@ def _fused_attention(ctx, ins, attrs):
         s = jnp.where(keep, s, NEG_INF)
         p = jax.nn.softmax(s, axis=-1)
         out = jnp.einsum("bqk,bkd->bqd", p.astype(qf.dtype), vf)
-        return {"Out": [out.reshape(b, h, t, d)]}
+        return out.reshape(b, h, t, d)
+    if use_pallas_unwrapped() and (bq_flag or bk_flag):
+        # sweep knobs apply here too: validate loudly and USE them —
+        # silently benchmarking auto blocks (or the dense fallback)
+        # under the requested label is the misattribution the
+        # explicit-flag path exists to prevent
+        bq, bk = bq_flag or 128, bk_flag or 128
+        if bq <= 0 or bk <= 0 or not _mosaic_legal(bq, bk):
+            raise ValueError(
+                "FLAGS_flash_block_q/k (%d, %d) are not Mosaic-legal "
+                "for the chunked-decode shapes Tq=%d, Tk=%d"
+                % (bq, bk, t, tk))
+        out, _lse = flash_attention_piece(
+            qf, kf, vf, True, float(scale), bq, bk, window,
+            qstart.astype(jnp.int32))
+        return out.reshape(b, h, t, d)
+    bq = 128 if t % 128 == 0 else t
+    bk = 128 if tk % 128 == 0 else tk
+    if use_pallas_unwrapped() and bq <= 512 and bk <= 1024:
+        bq, bk = _auto_blocks(
+            "flash_attention_piece",
+            lambda p: (lambda q_, k_, v_: flash_attention_piece(
+                q_, k_, v_, True, float(scale), p["block_q"],
+                p["block_k"], window,
+                jnp.zeros((1,), jnp.int32))[0]))
+        # the ring's offset-causal piece IS chunked decode: the
+        # piece is softmax-normalized within its kv, and here the
+        # kv is the whole cache
+        out, _lse = flash_attention_piece(
+            qf, kf, vf, True, float(scale), bq, bk, window,
+            qstart.astype(jnp.int32))
+    else:
+        out = _dense_attention(qf, kf, vf, True, float(scale),
+                               window=window, qoff=qstart)
+    return out.reshape(b, h, t, d)
+
+
+@register("fused_attention", no_grad_inputs=("QStart",))
+def _fused_attention(ctx, ins, attrs):
+    """Fused scaled-dot-product attention (the cuDNN-fused-kernel slot of
+    the reference, TPU-style).  Q/K/V: [batch, heads, T, d].  Training
+    path (no QStart): the blockwise kernel where platform and shape say
+    so (_flash_engages), dense XLA otherwise.  QStart paths (chunked and
+    ragged decode): their flash kernels under FLAGS_use_pallas."""
+    from .pallas_kernels import _dense_attention
+
+    q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
+    causal = bool(attrs.get("causal", False))
+    window = int(attrs.get("window", 0) or 0)  # sliding-window (causal)
+    if window < 0:
+        raise ValueError("fused_attention: window must be >= 0")
+    if window and not causal:
+        raise ValueError(
+            "fused_attention: window requires causal=True (consistent "
+            "across the pallas and dense paths)")
+    scale = attrs.get("scale") or 1.0 / (q.shape[-1] ** 0.5)
+    b, h, t, d = q.shape
+    tk = k.shape[2]
+    # chunked-decode global query offset: query i at position QStart+i,
+    # keys at their cache indices — Tq may differ from Tk.  A size-1
+    # QStart is the classic scalar offset (one chunk position for the
+    # whole batch); size B keeps PER-ROW offsets (ragged serving step).
+    qstart = None
+    if ins.get("QStart"):
+        qstart = ins["QStart"][0].reshape(-1)
+        qstart = qstart.reshape(()) if qstart.shape[0] == 1 else qstart
+    if qstart is not None:
+        if not causal:
+            raise ValueError("fused_attention: QStart requires causal=True")
+        if ins.get("Bias") or ins.get("SegmentIds"):
+            raise ValueError(
+                "fused_attention: QStart owns the causal cutoffs — "
+                "Bias/SegmentIds are not combinable with it")
+        return {"Out": [_qstart_attention(q, k, v, qstart, scale, window)]}
+    if causal and t != tk:
+        raise ValueError(
+            "fused_attention: causal requires Tq == Tk, got %d vs %d" % (t, tk)
+        )
+    qf = q.reshape(b * h, t, d)
+    kf = k.reshape(b * h, tk, d)
+    vf = v.reshape(b * h, tk, d)
     kbias = kbias_b = seg_b = None
     if ins.get("Bias"):
         # additive key-padding bias, rank-1 in the key axis: [B, Tk] (or any
@@ -1049,97 +1098,56 @@ def _fused_attention(ctx, ins, attrs):
         seg_b = ins["SegmentIds"][0].reshape(b, t).astype(jnp.int32)
         seg = jnp.broadcast_to(
             seg_b[:, None, :], (b, h, t)).reshape(b * h, t)
-    if qstart is not None:
-        from .pallas_kernels import flash_attention_piece
+    # The training path (no QStart).  One algorithm, engaged by what the
+    # lowering can see: the blockwise kernel where _flash_engages says the
+    # step is placed on a TPU and the shape is one the chip sweep found
+    # it ahead at, dense XLA everywhere else.  No flag is read here.
+    if _flash_engages(ctx, t, tk, d):
+        from .kernel_tuning import note_kernel
+        from .pallas_kernels import flash_attention
+        from .spmd_epilogue import mesh_ctx, spmd_flash_attention
 
-        if use_pallas_unwrapped() and (bq_flag or bk_flag):
-            # sweep knobs apply here too: validate loudly and USE them —
-            # silently benchmarking auto blocks (or the dense fallback)
-            # under the requested label is the misattribution the
-            # explicit-flag path exists to prevent
-            bq, bk = bq_flag or 128, bk_flag or 128
-            if bq <= 0 or bk <= 0 or not _mosaic_legal(bq, bk):
-                raise ValueError(
-                    "FLAGS_flash_block_q/k (%d, %d) are not Mosaic-legal "
-                    "for the chunked-decode shapes Tq=%d, Tk=%d"
-                    % (bq, bk, t, tk))
-            out, _lse = flash_attention_piece(
-                qf, kf, vf, True, float(scale), bq, bk, window,
-                qstart.astype(jnp.int32))
-            return {"Out": [out.reshape(b, h, t, d)]}
-        bq = 128 if t % 128 == 0 else t
-        bk = 128 if tk % 128 == 0 else tk
-        if use_pallas_unwrapped() and bq <= 512 and bk <= 1024:
-            bq, bk = _auto_blocks(
-                "flash_attention_piece",
-                lambda p: (lambda q_, k_, v_: flash_attention_piece(
-                    q_, k_, v_, True, float(scale), p["block_q"],
-                    p["block_k"], window,
-                    jnp.zeros((1,), jnp.int32))[0]))
-            # the ring's offset-causal piece IS chunked decode: the
-            # piece is softmax-normalized within its kv, and here the
-            # kv is the whole cache
-            out, _lse = flash_attention_piece(
-                qf, kf, vf, True, float(scale), bq, bk, window,
-                qstart.astype(jnp.int32))
-        else:
-            out = _dense_attention(qf, kf, vf, True, float(scale),
-                                   window=window, qoff=qstart)
-        return {"Out": [out.reshape(b, h, t, d)]}
-    from .spmd_epilogue import mesh_ctx, spmd_flash_attention
-
-    mc = mesh_ctx()
-
-    def run_flash(bq, bk):
+        note_kernel("attention")
+        mc, blk = mesh_ctx(), _flash_block(t)
         if mc is None:
-            return flash_attention(
-                qf, kf, vf, kbias, causal, float(scale), block_q=bq,
-                block_k=bk, window=window, seg=seg)
-        return spmd_flash_attention(
-            mc, q, k, v, kbias_b, seg_b, causal, float(scale), bq, bk,
-            window).reshape(b * h, t, d)
-
-    if use_pallas() and (bq_flag or bk_flag):
-        # explicit sweep knobs: validate loudly — a silently-ignored
-        # flag would attribute fallback timings to the requested size
-        bq = bq_flag or 128
-        bk = bk_flag or 128
-        if bq <= 0 or bk <= 0 or not _mosaic_legal(bq, bk):
-            raise ValueError(
-                "FLAGS_flash_block_q/k (%d, %d) are not Mosaic-legal for "
-                "Tq=%d, Tk=%d: each block must divide its sequence length "
-                "and be a multiple of 128 (or equal the full length) — "
-                "the lse/delta/kbias BlockSpecs place the block in the "
-                "minor dim" % (bq, bk, t, tk))
-        out = run_flash(bq, bk)
-    elif use_pallas():
-        # auto path: 128-blocks when the lengths tile; otherwise a
-        # single full-dim block is still Mosaic-legal, so short or odd
-        # lengths ride flash too as long as the [bq, bk] score tile
-        # stays VMEM-friendly.  Anything else goes dense.  The choice
-        # among legal candidates goes through the tuning cache (searched
-        # at first real-device dispatch, seeded in interpret mode) —
-        # except under a live mesh, which keeps the deterministic
-        # defaults (the tuning search times STANDALONE kernels).
-        bq = 128 if t % 128 == 0 else t
-        bk = 128 if tk % 128 == 0 else tk
-        # this derivation is Mosaic-legal by construction (each block is
-        # 128-tiling or full-dim); only the VMEM score-tile budget gates
-        if bq <= 512 and bk <= 1024:
-            if mc is None:
-                bq, bk = _auto_blocks(
-                    "flash_attention",
-                    lambda p: (lambda q_, k_, v_: flash_attention(
-                        q_, k_, v_, None, causal, float(scale),
-                        p["block_q"], p["block_k"], window)))
-            out = run_flash(bq, bk)
-        else:
-            out = _dense_attention(qf, kf, vf, causal, float(scale), kbias,
-                                   window=window, seg=seg)
+            out = flash_attention(qf, kf, vf, kbias, causal, float(scale),
+                                  blk, blk, window, seg)
+        else:  # rows over dp, heads over mp: the sharding is the op's
+            out = spmd_flash_attention(
+                mc, q, k, v, kbias_b, seg_b, causal, float(scale), blk, blk,
+                window).reshape(b * h, t, d)
     else:
         out = _dense_attention(qf, kf, vf, causal, float(scale), kbias,
                                window=window, seg=seg)
     return {"Out": [out.reshape(b, h, t, d)]}
+
+
+# When fused_attention's training path takes the blockwise kernel
+# (pallas_kernels.flash_attention), and with which blocks: constants from
+# one sweep on a v5e over the transformer cells' attention shapes
+# (tools/attention_sweep.py; the table is in CHANGES.md, PR 29), not a
+# tuning-cache entry consulted at trace time.  Forward + backward alone,
+# bf16, kernel against dense: T = 256 3.64 against 2.85 ms (dense stays),
+# T = 512 0.77 against 1.11, T = 1024 0.95 against 2.66, T = 4096 4.0
+# against 29.5; at every length the largest square block was fastest.
+_FLASH_MIN_T = 512
+_FLASH_BLOCKS = (1024, 512, 256, 128)
+
+
+def _flash_block(t):
+    """The largest block that divides t (both sides of a tile take it);
+    t itself, one full-length block, for a t no block divides."""
+    return next((b for b in _FLASH_BLOCKS if t % b == 0), t)
+
+
+def _flash_engages(ctx, tq, tk, d):
+    """Self-attention on a TPU-placed step, T a multiple of 128 at or
+    above _FLASH_MIN_T, head dim 64 or 128.  The platform is the placed
+    device's (LowerCtx.platform, which the Executor states), the
+    process's default backend only where a caller did not say."""
+    platform = getattr(ctx, "platform", None) or jax.default_backend()
+    return (platform == "tpu" and tq == tk and tq % 128 == 0
+            and tq >= _FLASH_MIN_T and d in (64, 128))
 
 
 @register("sequence_conv")

@@ -119,11 +119,20 @@ def _row_block_candidates(n, sizes=(128, 256, 512, 1024)):
 # (sequential on a TPU core), carrying the online-softmax state (acc, m, l)
 # in VMEM scratch across k steps.  Only [block, d] tiles of K/V are ever
 # resident, so sequence length is bounded by HBM, not VMEM.  The forward
-# saves the per-row logsumexp; the backward is two Pallas kernels (dq and
-# dk/dv/dkbias) that rebuild [block_q, block_k] probability tiles from the
-# saved lse — the [T, T] score matrix never exists in HBM in either pass.
+# saves the per-row logsumexp; the backward rebuilds [block_q, block_k]
+# probability tiles from the saved lse — one fused kernel on the training
+# path (flash_attention), two (dq and dk/dv/dkbias) on the decode and ring
+# paths — so the [T, T] score matrix never exists in HBM in either pass.
+# q, k, v, do reach the MXU in their own dtype; tiles and state are f32.
 # Role parity: the cuDNN fused-attention kernels of SURVEY §2.6.
 # ---------------------------------------------------------------------------
+def _dot_nt(a, b):
+    """a [m, d] x b [n, d] -> a b^T [m, n] in f32: the contraction over
+    both minor dims that the MXU takes without a transpose."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
 def _unpack_flash_refs(refs, has_qoff, has_seg):
     """Shared operand unpack for the three flash kernels (fwd/dq/dkv):
     the optional leading q base — a whole-array SMEM operand, [1] for
@@ -173,10 +182,10 @@ def _flash_fwd_kernel(*refs, block_q, block_k, nk,
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0].astype(jnp.float32) * scale  # [bq, d]
-        k = k_ref[0].astype(jnp.float32)  # [bk, d]
-        v = v_ref[0].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # [bq, bk]
+        # operands reach the MXU in their own dtype (bf16 under AMP),
+        # accumulation and the softmax state are f32
+        q, k, v = q_ref[0], k_ref[0], v_ref[0]  # [bq, d], [bk, d] x2
+        s = _dot_nt(q, k) * scale  # [bq, bk]
         s = s + kb_ref[0].astype(jnp.float32)  # [1, bk] broadcast
         if has_seg:  # packing: keep within-segment scores only
             s = jnp.where(
@@ -188,7 +197,7 @@ def _flash_fwd_kernel(*refs, block_q, block_k, nk,
         alpha = jnp.exp(m_prev - m_new)
         l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
         acc_ref[:] = acc_ref[:] * alpha + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
         m_ref[:] = m_new
 
     @pl.when(ki == nk - 1)
@@ -199,10 +208,11 @@ def _flash_fwd_kernel(*refs, block_q, block_k, nk,
         lse_ref[0] = (m_ref[:] + jnp.log(safe_l)).reshape(1, -1)
 
 
-def _band(qi, ki, qo, block_q, block_k, causal, window):
-    """Shared causal/window band logic for the three flash kernels:
-    returns (run, keep_fn) — the block-skip predicate and a function
-    masking an [bq, bk] score tile in GLOBAL positions (q base = qo)."""
+def _band(qi, ki, qo, block_q, block_k, causal, window, transposed=False):
+    """Shared causal/window band logic for the flash kernels: returns
+    (run, keep_fn) — the block-skip predicate and a function masking an
+    [bq, bk] score tile ([bk, bq] when `transposed`) in GLOBAL positions
+    (q base = qo)."""
     run = (ki * block_k < (qi + 1) * block_q + qo) if causal else (ki >= 0)
     if window:
         run = run & (ki * block_k + block_k - 1
@@ -212,9 +222,9 @@ def _band(qi, ki, qo, block_q, block_k, causal, window):
         if not causal:
             return s
         q_pos = qo + qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 0)
+            jnp.int32, s.shape, int(transposed))
         k_pos = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
+            jnp.int32, s.shape, 1 - int(transposed))
         keep = q_pos >= k_pos
         if window:
             keep = keep & (q_pos - k_pos < window)
@@ -235,7 +245,7 @@ def _flash_blocks(Tq, Tk, block_q, block_k, causal):
 
 
 def _flash_fwd(q, k, v, kbias, causal, scale, block_q, block_k, window=0,
-               qoff=None, seg=None, qvec=None):
+               qoff=None, seg=None, qvec=None, interpret=None):
     """q: [BH, Tq, d], k/v: [BH, Tk, d], kbias: [BH, Tk] additive key bias.
     window > 0 (causal only): sliding-window attention — each query sees
     only the last `window` key positions.  qoff: optional [1] int32 GLOBAL
@@ -257,7 +267,8 @@ def _flash_fwd(q, k, v, kbias, causal, scale, block_q, block_k, window=0,
                                      and qvec is None)
     assert not (window and not causal), "window attention requires causal"
     assert seg is None or T == Tk, "segment ids require Tq == Tk"
-    _note("attention")
+    if interpret is None:
+        interpret = _interpret()
     nq, nk = T // block_q, Tk // block_k
     kernel = functools.partial(
         _flash_fwd_kernel, block_q=block_q, block_k=block_k, nk=nk,
@@ -312,7 +323,7 @@ def _flash_fwd(q, k, v, kbias, causal, scale, block_q, block_k, window=0,
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         compiler_params=_mosaic_params(),
-        interpret=_interpret(),
+        interpret=interpret,
     )(*args)
     return o, lse.reshape(BH, T)
 
@@ -335,13 +346,10 @@ def _flash_dq_kernel(*refs, block_q, block_k, nk, causal, scale,
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
+        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
         lse = lse_ref[0].reshape(-1, 1)  # [bq, 1]
         delta = delta_ref[0].reshape(-1, 1)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+        s = _dot_nt(q, k) * scale
         s = s + kb_ref[0].astype(jnp.float32)
         if has_seg:
             s = jnp.where(
@@ -352,10 +360,9 @@ def _flash_dq_kernel(*refs, block_q, block_k, nk, causal, scale,
         # contract, so their grads are 0 — without this guard
         # exp(s - lse) would be 1 on every masked entry of such rows
         p = jnp.where(lse <= NEG_INF / 2, 0.0, jnp.exp(s - lse))
-        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
+        ds = p * (_dot_nt(do, v) - delta)
         dq_acc[:] = dq_acc[:] + scale * jnp.dot(
-            ds, k, preferred_element_type=jnp.float32)
+            ds.astype(k.dtype), k, preferred_element_type=jnp.float32)
 
     @pl.when(ki == nk - 1)
     def _write():
@@ -385,13 +392,10 @@ def _flash_dkv_kernel(*refs, block_q, block_k, nq, causal, scale,
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
+        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
         lse = lse_ref[0].reshape(-1, 1)
         delta = delta_ref[0].reshape(-1, 1)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+        s = _dot_nt(q, k) * scale
         s = s + kb_ref[0].astype(jnp.float32)
         if has_seg:
             s = jnp.where(
@@ -400,11 +404,10 @@ def _flash_dkv_kernel(*refs, block_q, block_k, nq, causal, scale,
         # undefined-row grad guard (see _flash_dq_kernel)
         p = jnp.where(lse <= NEG_INF / 2, 0.0, jnp.exp(s - lse))
         dv_acc[:] = dv_acc[:] + jnp.dot(
-            p.T, do, preferred_element_type=jnp.float32)
-        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
+            p.T.astype(do.dtype), do, preferred_element_type=jnp.float32)
+        ds = p * (_dot_nt(do, v) - delta)
         dk_acc[:] = dk_acc[:] + scale * jnp.dot(
-            ds.T, q, preferred_element_type=jnp.float32)
+            ds.T.astype(q.dtype), q, preferred_element_type=jnp.float32)
         dkb_acc[:] = dkb_acc[:] + jnp.sum(ds, axis=0, keepdims=True)
 
     @pl.when(qi == nq - 1)
@@ -415,7 +418,8 @@ def _flash_dkv_kernel(*refs, block_q, block_k, nq, causal, scale,
 
 
 def _flash_bwd(q, k, v, kbias, o, lse, do, causal, scale, block_q, block_k,
-               dlse=None, window=0, qoff=None, seg=None, qvec=None):
+               dlse=None, window=0, qoff=None, seg=None, qvec=None,
+               interpret=None):
     """Blocked backward: returns (dq, dk, dv, dkbias[BH,Tk] f32).
 
     dlse: optional cotangent of the lse output (the chunk-merge path of
@@ -430,6 +434,8 @@ def _flash_bwd(q, k, v, kbias, o, lse, do, causal, scale, block_q, block_k,
     block_q, block_k = _flash_blocks(T, Tk, block_q, block_k,
                                      causal and qoff is None
                                      and qvec is None)
+    if interpret is None:
+        interpret = _interpret()
     nq, nk = T // block_q, Tk // block_k
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     if dlse is not None:
@@ -471,7 +477,7 @@ def _flash_bwd(q, k, v, kbias, o, lse, do, causal, scale, block_q, block_k,
         out_shape=_sds((BH, T, d), q.dtype, q, k, v, do),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=_mosaic_params(),
-        interpret=_interpret(),
+        interpret=interpret,
     )(*(qoff_arg + [q, k, v, kb3] + seg_args + [do, lse3, delta3]))
 
     # dk/dv pass: grid iterates q blocks innermost for each k block
@@ -504,7 +510,7 @@ def _flash_bwd(q, k, v, kbias, o, lse, do, causal, scale, block_q, block_k,
             pltpu.VMEM((1, block_k), jnp.float32),
         ],
         compiler_params=_mosaic_params(),
-        interpret=_interpret(),
+        interpret=interpret,
     )(*(qoff_arg + [q, k, v, kb3] + seg_args + [do, lse3, delta3]))
     return dq, dk, dv, dkb.reshape(BH, Tk)
 
@@ -542,44 +548,218 @@ def _dense_attention(q, k, v, causal, scale, kbias=None, window=0,
     return jnp.einsum("bqk,bkd->bqd", p.astype(q.dtype), v)
 
 
+# The training path's backward is ONE kernel.  Its grid walks (BH, nk, nq)
+# with q innermost, so dk / dv accumulate in a [bk, d] scratch per k block
+# as in the dk/dv kernel above, and dq — which sums over k blocks, the
+# OUTER loop — accumulates in a whole-sequence [T, d] f32 scratch that
+# stays resident while one (batch, head) row is walked and is written once.
+# The tiles are TRANSPOSED, [bk, bq]: the per-query lse / delta rows then
+# broadcast along sublanes as they are stored ((1, bq) blocks), dv and dk
+# are plain matmuls, and only dq contracts over the tile's major dim.
+# Against the two-kernel form it computes s, p and dp once instead of
+# twice (five matmuls a tile, not seven) and is one Mosaic call.
+_FUSED_BWD_DQ_BYTES = 4 * 2 ** 20  # [T, d] f32: T <= 8192 at d = 128
+
+
+def _flash_bwd_fused_kernel(*refs, block_q, block_k, nq, nk, causal, scale,
+                            window, has_kb, has_seg):
+    from jax.experimental import pallas as pl
+
+    refs = list(refs)
+    q_ref, k_ref, v_ref = refs[:3]
+    del refs[:3]
+    kb_ref = refs.pop(0) if has_kb else None
+    sq_ref, sk_ref = (refs.pop(0), refs.pop(0)) if has_seg else (None, None)
+    do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref = refs[:6]
+    del refs[:6]
+    dkb_ref = refs.pop(0) if has_kb else None
+    dq_acc, dk_acc, dv_acc = refs[:3]
+    dkb_acc = refs[3] if has_kb else None
+    ki = pl.program_id(1)
+    qi = pl.program_id(2)
+
+    @pl.when((ki == 0) & (qi == 0))
+    def _init_row():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
+
+    @pl.when(qi == 0)
+    def _init():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+        if has_kb:
+            dkb_acc[:] = jnp.zeros_like(dkb_acc)
+
+    run, keep_fn = _band(qi, ki, 0, block_q, block_k, causal, window,
+                         transposed=True)
+
+    @pl.when(run)
+    def _compute():
+        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
+        st = _dot_nt(k, q) * scale  # [bk, bq]
+        if has_kb:
+            st = st + kb_ref[0].astype(jnp.float32).reshape(-1, 1)
+        if has_seg:
+            st = jnp.where(sk_ref[0].reshape(-1, 1) == sq_ref[0], st, NEG_INF)
+        pt = jnp.exp(keep_fn(st) - lse_ref[0])  # lse, delta: [1, bq]
+        dv_acc[:] = dv_acc[:] + jnp.dot(
+            pt.astype(do.dtype), do, preferred_element_type=jnp.float32)
+        dst = pt * (_dot_nt(v, do) - delta_ref[0])
+        if has_kb:
+            dkb_acc[:] = dkb_acc[:] + jnp.sum(
+                dst, axis=1, keepdims=True).reshape(1, -1)
+        dsc = dst.astype(q.dtype)
+        dk_acc[:] = dk_acc[:] + scale * jnp.dot(
+            dsc, q, preferred_element_type=jnp.float32)
+        rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+        dq_acc[rows, :] = dq_acc[rows, :] + scale * jax.lax.dot_general(
+            dsc, k, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)  # [bq, d]
+
+    @pl.when(qi == nq - 1)
+    def _write():
+        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+        if has_kb:
+            dkb_ref[0] = dkb_acc[:]
+
+    @pl.when((ki == nk - 1) & (qi == nq - 1))
+    def _write_row():
+        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+
+
+def _flash_bwd_fused(q, k, v, kbias, seg, o, lse, do, causal, scale, block_q,
+                     block_k, window, interpret):
+    """(dq, dk, dv, dkbias [BH, T] f32 or None) from the saved o and lse,
+    self-attention (Tq == Tk) without a q offset: one pallas_call."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    BH, T, d = q.shape
+    block_q, block_k = _flash_blocks(T, T, block_q, block_k, causal)
+    nq, nk = T // block_q, T // block_k
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+
+    def spec(shape, index):
+        return pl.BlockSpec(shape, index, memory_space=pltpu.VMEM)
+
+    q_spec = spec((1, block_q, d), lambda b, i, j: (b, j, 0))
+    k_spec = spec((1, block_k, d), lambda b, i, j: (b, i, 0))
+    qrow_spec = spec((1, 1, block_q), lambda b, i, j: (b, 0, j))
+    krow_spec = spec((1, 1, block_k), lambda b, i, j: (b, 0, i))
+    in_specs, args = [q_spec, k_spec, k_spec], [q, k, v]
+    if kbias is not None:
+        in_specs.append(krow_spec)
+        args.append(kbias.reshape(BH, 1, T))
+    if seg is not None:
+        seg3 = seg.astype(jnp.int32).reshape(BH, 1, T)
+        in_specs += [qrow_spec, krow_spec]
+        args += [seg3, seg3]
+    in_specs += [q_spec, qrow_spec, qrow_spec]
+    args += [do, lse.reshape(BH, 1, T), delta.reshape(BH, 1, T)]
+    out_specs = [spec((1, T, d), lambda b, i, j: (b, 0, 0)), k_spec, k_spec]
+    out_shape = [_sds((BH, T, d), q.dtype, q, k, v, do),
+                 _sds((BH, T, d), k.dtype, q, k, v, do),
+                 _sds((BH, T, d), v.dtype, q, k, v, do)]
+    scratch = [pltpu.VMEM((T, d), jnp.float32),
+               pltpu.VMEM((block_k, d), jnp.float32),
+               pltpu.VMEM((block_k, d), jnp.float32)]
+    if kbias is not None:
+        out_specs.append(krow_spec)
+        out_shape.append(_sds((BH, 1, T), jnp.float32, q, k, v, do))
+        scratch.append(pltpu.VMEM((1, block_k), jnp.float32))
+    outs = pl.pallas_call(
+        functools.partial(
+            _flash_bwd_fused_kernel, block_q=block_q, block_k=block_k,
+            nq=nq, nk=nk, causal=causal, scale=scale, window=int(window),
+            has_kb=kbias is not None, has_seg=seg is not None),
+        grid=(BH, nk, nq),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
+        compiler_params=_mosaic_params(),
+        interpret=interpret,
+    )(*args)
+    dkb = outs[3].reshape(BH, T) if kbias is not None else None
+    return outs[0], outs[1], outs[2], dkb
+
+
+# The two entries of the training path are jitted at module level: every
+# layer of a model calls them with the same shapes and static
+# configuration, so the forward op, the grad op's re-traced forward and
+# the backward hit jax's trace cache after the first layer and lower to ONE
+# shared function each — a step's StableHLO carries each Mosaic payload
+# once, and the host traces each kernel body once, however deep the model.
+_FLASH_STATICS = ("causal", "scale", "block_q", "block_k", "window",
+                  "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_FLASH_STATICS)
+def _flash_fwd_call(q, k, v, kbias, seg, *, causal, scale, block_q, block_k,
+                    window, interpret):
+    kb = kbias if kbias is not None else jnp.zeros(k.shape[:2], jnp.float32)
+    return _flash_fwd(q, k, v, kb, causal, scale, block_q, block_k, window,
+                      seg=seg, interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=_FLASH_STATICS)
+def _flash_bwd_call(q, k, v, kbias, seg, o, lse, do, *, causal, scale,
+                    block_q, block_k, window, interpret):
+    T, d = q.shape[1:]
+    if T == k.shape[1] and T * d * 4 <= _FUSED_BWD_DQ_BYTES:
+        return _flash_bwd_fused(q, k, v, kbias, seg, o, lse, do, causal,
+                                scale, block_q, block_k, window, interpret)
+    kb = kbias if kbias is not None else jnp.zeros(k.shape[:2], jnp.float32)
+    dq, dk, dv, dkb = _flash_bwd(
+        q, k, v, kb, o, lse, do, causal, scale, block_q, block_k,
+        window=window, seg=seg, interpret=interpret)
+    return dq, dk, dv, (None if kbias is None else dkb)
+
+
+def _flash_statics(q, causal, scale, block_q, block_k, window):
+    if scale is None:
+        scale = 1.0 / (q.shape[-1] ** 0.5)
+    return dict(causal=bool(causal), scale=float(scale),
+                block_q=int(block_q), block_k=int(block_k),
+                window=int(window), interpret=_interpret())
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
 def flash_attention(q, k, v, kbias=None, causal=False, scale=None,
                     block_q=128, block_k=128, window=0, seg=None):
     """Fused attention, q: [BH, Tq, d], k/v: [BH, Tk, d] (flash-style
-    online softmax).  kbias: optional [BH, Tk] additive key bias (the
-    padding-mask row, indexed by key position).  window > 0 (causal):
-    sliding-window local attention over the last `window` positions —
-    fully-out-of-window blocks are skipped in all three kernels, so
-    compute scales with window, not T.  seg: optional [BH, T] int
-    segment ids (sequence packing, Tq == Tk): scores cross segment
-    boundaries are masked inside every kernel — rank-1 operands only,
-    no [T, T] mask."""
-    if scale is None:
-        scale = 1.0 / (q.shape[-1] ** 0.5)
-    kb = kbias if kbias is not None else jnp.zeros(k.shape[:2], jnp.float32)
-    o, _ = _flash_fwd(q, k, v, kb, causal, scale, block_q, block_k, window,
-                      seg=seg)
-    return o
+    online softmax): q, k, v reach the MXU in their own dtype, the scores,
+    the running max / sum and the saved logsumexp are f32 and never leave
+    VMEM.  kbias: optional [BH, Tk] additive key bias (the padding-mask
+    row, indexed by key position).  window > 0 (causal): sliding-window
+    local attention over the last `window` positions — blocks wholly above
+    the diagonal or out of the window are skipped in every kernel, so
+    compute scales with the band, not T^2.  seg: optional [BH, T] int
+    segment ids (sequence packing, Tq == Tk): scores across segment
+    boundaries are masked inside every kernel — rank-1 operands only, no
+    [T, T] mask.  Forward and backward are owned here (custom_vjp): the
+    backward rebuilds probability tiles from the saved o and lse in one
+    kernel (two where Tq != Tk or the sequence outgrows its dq scratch)."""
+    return _flash_vjp_fwd(q, k, v, kbias, causal, scale, block_q, block_k,
+                          window, seg)[0]
 
 
 def _flash_vjp_fwd(q, k, v, kbias, causal, scale, block_q, block_k,
                    window=0, seg=None):
-    if scale is None:
-        scale = 1.0 / (q.shape[-1] ** 0.5)
-    kb = kbias if kbias is not None else jnp.zeros(k.shape[:2], jnp.float32)
-    o, lse = _flash_fwd(q, k, v, kb, causal, scale, block_q, block_k, window,
-                        seg=seg)
+    # the primal above makes this same call and drops lse, so a forward
+    # op's kernel and its grad op's re-traced one are one instruction
+    # after CSE
+    o, lse = _flash_fwd_call(
+        q, k, v, kbias, seg,
+        **_flash_statics(q, causal, scale, block_q, block_k, window))
     return o, (q, k, v, kbias, seg, o, lse)
 
 
 def _flash_vjp_bwd(causal, scale, block_q, block_k, window, res, do):
     q, k, v, kbias, seg, o, lse = res
-    if scale is None:
-        scale = 1.0 / (q.shape[-1] ** 0.5)
-    kb = kbias if kbias is not None else jnp.zeros(k.shape[:2], jnp.float32)
-    dq, dk, dv, dkb = _flash_bwd(
-        q, k, v, kb, o, lse, do, causal, scale, block_q, block_k,
-        window=window, seg=seg)
+    dq, dk, dv, dkb = _flash_bwd_call(
+        q, k, v, kbias, seg, o, lse, do,
+        **_flash_statics(q, causal, scale, block_q, block_k, window))
     # integer segment ids get the mandatory float0 cotangent
     dseg = (None if seg is None
             else np.zeros(seg.shape, dtype=jax.dtypes.float0))
@@ -605,6 +785,7 @@ def flash_attention_piece(q, k, v, causal=False, scale=None,
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
     kb = jnp.zeros(k.shape[:2], jnp.float32)
+    _note("attention")
     return _flash_fwd(q, k, v, kb, causal, scale, block_q, block_k, window,
                       qoff)
 
@@ -614,6 +795,7 @@ def _piece_vjp_fwd(q, k, v, causal, scale, block_q, block_k, window=0,
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
     kb = jnp.zeros(k.shape[:2], jnp.float32)
+    _note("attention")
     o, lse = _flash_fwd(q, k, v, kb, causal, scale, block_q, block_k, window,
                         qoff)
     return (o, lse), (q, k, v, o, lse, qoff)
@@ -654,6 +836,7 @@ def flash_attention_qvec(q, k, v, qstart, scale=None, block_q=128,
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
     kb = jnp.zeros(k.shape[:2], jnp.float32)
+    _note("attention")
     o, _ = _flash_fwd(q, k, v, kb, True, scale, block_q, block_k,
                       qvec=qstart)
     return o
@@ -663,6 +846,7 @@ def _qvec_vjp_fwd(q, k, v, qstart, scale, block_q, block_k):
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
     kb = jnp.zeros(k.shape[:2], jnp.float32)
+    _note("attention")
     o, lse = _flash_fwd(q, k, v, kb, True, scale, block_q, block_k,
                         qvec=qstart)
     return o, (q, k, v, qstart, o, lse)

@@ -22,8 +22,8 @@ from jax.sharding import PartitionSpec as P
 
 
 def _attention(q, k, v, causal, scale, window=0):
-    """Full-sequence attention on local heads [B, h, T, D] — flash kernel
-    under FLAGS_use_pallas via the shared fused-attention dispatch
+    """Full-sequence attention on local heads [B, h, T, D] — the shared
+    fused-attention lowering, so the flash kernel wherever that chooses it
     (window: sliding-window masking, since every head sees the FULL
     sequence here the op's banded mask applies globally)."""
     from ..ops import nn_ops  # noqa: F401  (registers fused_attention)

@@ -151,11 +151,12 @@ def test_flash_segment_ids_match_dense():
                                            rtol=2e-4, atol=2e-5)
 
 
-def test_op_segment_ids_ride_flash_under_pallas_flag():
-    """FLAGS_use_pallas=1: the fused_attention op with SegmentIds routes
-    through the flash kernels (interpret mode on CPU) and matches the
-    dense path bit-for-tolerance."""
-    from paddle_tpu import flags
+def test_op_segment_ids_ride_the_flash_kernel_where_it_engages(monkeypatch):
+    """Where platform and shape engage the blockwise kernel (said here by
+    the test: the training path reads no flag), the fused_attention op
+    with SegmentIds routes through it (interpret mode on CPU) and matches
+    the dense path bit-for-tolerance."""
+    from paddle_tpu.ops import nn_ops
 
     rng = np.random.RandomState(5)
     h, t, d = 2, 16, 8
@@ -179,9 +180,8 @@ def test_op_segment_ids_ride_flash_under_pallas_flag():
         return np.asarray(o)
 
     dense = run()
-    flags.set_flags({"use_pallas": True})
-    try:
-        flash = run()
-    finally:
-        flags.set_flags({"use_pallas": False})
+    monkeypatch.setattr(nn_ops, "_flash_engages",
+                        lambda ctx, tq, tk, d: tq == tk)
+    flash = run()
+    assert not np.array_equal(flash, dense)  # another lowering ran
     np.testing.assert_allclose(flash, dense, rtol=2e-5, atol=2e-6)

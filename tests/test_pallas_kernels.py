@@ -83,13 +83,19 @@ def test_fused_layer_norm_matches_and_grads():
     np.testing.assert_allclose(np.asarray(gx), np.asarray(gx_ref), rtol=1e-3, atol=1e-4)
 
 
-def test_fused_attention_op_dispatch_and_training():
-    """The fused_attention layer trains identically with and without the
-    pallas kernel override."""
+def test_fused_attention_op_dispatch_and_training(monkeypatch):
+    """The fused_attention layer trains identically through the blockwise
+    kernel and the dense lowering.  The training path reads no flag: the
+    test says what platform and shape would (nn_ops._flash_engages)."""
+    from paddle_tpu.ops import kernel_tuning as kt
+    from paddle_tpu.ops import nn_ops
+
     rng = np.random.RandomState(3)
     xv = rng.rand(4, 2, 16, 8).astype("float32")
 
-    def run(use_pallas):
+    def run(engage):
+        monkeypatch.setattr(nn_ops, "_flash_engages",
+                            lambda ctx, tq, tk, d: engage)
         import paddle_tpu.framework as fw
         from paddle_tpu.core import scope as scope_mod
         from paddle_tpu import unique_name
@@ -104,17 +110,15 @@ def test_fused_attention_op_dispatch_and_training():
         q = layers.data("q", shape=[2, 16, 8])
         att = layers.fused_attention(q, q, q, causal=True)
         loss = layers.mean(layers.pow(att, 2.0))
-        flags.set_flags({"use_pallas": use_pallas})
-        try:
-            exe = fluid.Executor(fluid.CPUPlace())
-            exe.run(fluid.default_startup_program())
-            (lv,) = exe.run(feed={"q": xv}, fetch_list=[loss])
-        finally:
-            flags.set_flags({"use_pallas": False})
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(fluid.default_startup_program())
+        (lv,) = exe.run(feed={"q": xv}, fetch_list=[loss])
         return float(np.ravel(lv)[0])
 
     plain = run(False)
+    before = kt.attribution()["pallas_hits"].get("attention", 0)
     pallas = run(True)
+    assert kt.attribution()["pallas_hits"]["attention"] > before
     np.testing.assert_allclose(pallas, plain, rtol=1e-4)
 
 
