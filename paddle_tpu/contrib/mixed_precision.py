@@ -39,7 +39,7 @@ _KEEP_F32_SLOTS = {"fused_attention": ("Bias",),
                    # the router's top-k is discontinuous: it reads X and
                    # its own weight in f32, and the lowering narrows X to
                    # the experts' dtype itself
-                   "moe_ffn": ("X", "RouterW")}
+                   "moe_ffn": ("X", "RouterW", "ExpertBias")}
 
 # output slots that are not activations (counts, f32 statistics): they
 # keep their declared dtype and get no cast-back
@@ -62,6 +62,7 @@ _TRANSPARENT_OPS = {
     "batch_norm": (("X",), ("Y",)),
     "layer_norm": (("X",), ("Y",)),
     "rms_norm": (("X",), ("Y",)),
+    "short_conv": (("BCX",), ("Out",)),
     "dropout": (("X",), ("Out",)),
     "reshape2": (("X",), ("Out",)),
     "reshape": (("X",), ("Out",)),

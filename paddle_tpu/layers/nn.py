@@ -35,6 +35,7 @@ __all__ = [
     "batch_norm",
     "layer_norm",
     "rms_norm",
+    "short_conv",
     "moe_ffn",
     "group_norm",
     "instance_norm",
@@ -543,31 +544,71 @@ def rms_norm(input, epsilon=1e-5, param_attr=None, name=None):
     return out
 
 
+def short_conv(input, kernel_size=3, param_attr=None, name=None):
+    """Gated short convolution over the T axis of `input` [..., T, 3d]
+    (the `short_conv` op): the last axis holds B, C and u side by side,
+    the result [..., T, d] is C * causal_depthwise_conv(B * u) with one
+    [kernel_size] filter a channel and no bias.  The projections on
+    either side stay `fc` layers of the caller's."""
+    helper = LayerHelper("short_conv", **locals())
+    dtype = helper.input_dtype()
+    d = int(input.shape[-1]) // 3
+    if 3 * d != int(input.shape[-1]):
+        raise ValueError("short_conv wants a last axis of 3 d, got %s"
+                         % (input.shape,))
+    filt = helper.create_parameter(
+        attr=helper.param_attr, shape=[d, int(kernel_size)], dtype=dtype)
+    out = helper.create_variable_for_type_inference(dtype)
+    helper.append_op("short_conv", inputs={"BCX": [input], "Filter": [filt]},
+                     outputs={"Out": [out]})
+    return out
+
+
 def moe_ffn(input, num_experts, expert_size, top_k, norm_topk_prob=False,
             router_attr=None, gate_up_attr=None, down_attr=None,
-            stat_name="moe_tokens_per_expert", name=None):
+            stat_name="moe_tokens_per_expert", name=None, router="softmax",
+            expert_bias_attr=None, num_local_experts=None, expert_offset=0):
     """Token-choice mixture of SwiGLU experts over the last axis of
-    `input` (the `moe_ffn` op: softmax router, top-k, dropless).  The
-    experts' weights are stacked: gate and up side by side in one
-    [E, d, 2 * expert_size] parameter, down in [E, expert_size, d].
+    `input` (the `moe_ffn` op: top-k, dropless).  The experts' weights are
+    stacked: gate and up side by side in one [E, d, 2 * expert_size]
+    parameter, down in [E, expert_size, d].
+
+    `router` is "softmax" (OLMoE's) or "sigmoid": scores sigmoid(logits),
+    weights renormalised over the chosen with 1e-6 under `norm_topk_prob`;
+    with `expert_bias_attr` a [num_experts]
+    f32 buffer (a parameter that is not trainable) is added to the scores
+    for the selection alone.  `num_local_experts` < `num_experts` builds a
+    chip's share of the layer: the router stays [d, num_experts], the
+    expert weights hold experts [expert_offset, expert_offset +
+    num_local_experts), and what the others would add is left out.
 
     Returns (out, aux_loss, tokens_per_expert): aux_loss is [2] f32, the
-    load-balance loss E * sum_e F_e * P_e and the router z-loss, for the
-    builder to weigh into its loss; tokens_per_expert is a persistable
-    [E] int32 statistic the scope holds after every step (it sums to
-    tokens * top_k: no token is dropped), named `stat_name`_<n>: a
-    program that shares a scope with the training program (an evaluation
-    pass) gives its own so as not to overwrite the training step's."""
+    load-balance loss E * sum_e F_e * P_e and the router z-loss (zeros for
+    the sigmoid router), for the builder to weigh into its loss;
+    tokens_per_expert is a persistable [E] int32 statistic the scope holds
+    after every step (it sums to tokens * top_k: no token is dropped),
+    named `stat_name`_<n>: a program that shares a scope with the training
+    program (an evaluation pass) gives its own so as not to overwrite the
+    training step's."""
     helper = LayerHelper("moe_ffn", **locals())
     dtype = helper.input_dtype()
     d = int(input.shape[-1])
-    router = helper.create_parameter(
+    held = int(num_experts if num_local_experts is None
+               else num_local_experts)
+    router_w = helper.create_parameter(
         attr=router_attr, shape=[d, num_experts], dtype=dtype)
-    gate_up = helper.create_parameter(
-        attr=gate_up_attr, shape=[num_experts, d, 2 * expert_size],
-        dtype=dtype)
-    down = helper.create_parameter(
-        attr=down_attr, shape=[num_experts, expert_size, d], dtype=dtype)
+    inputs = {"X": [input], "RouterW": [router_w]}
+    if expert_bias_attr is not None:
+        if router != "sigmoid":
+            raise ValueError("moe_ffn: an expert bias selects for the "
+                             "sigmoid router only")
+        expert_bias_attr.trainable = False
+        inputs["ExpertBias"] = [helper.create_parameter(
+            attr=expert_bias_attr, shape=[num_experts], dtype="float32")]
+    inputs["GateUpW"] = [helper.create_parameter(
+        attr=gate_up_attr, shape=[held, d, 2 * expert_size], dtype=dtype)]
+    inputs["DownW"] = [helper.create_parameter(
+        attr=down_attr, shape=[held, expert_size, d], dtype=dtype)]
     counts = helper.create_global_variable(
         name=unique_name.generate(stat_name),
         persistable=True, dtype="int32", shape=[num_experts])
@@ -576,11 +617,10 @@ def moe_ffn(input, num_experts, expert_size, top_k, norm_topk_prob=False,
     out = helper.create_variable_for_type_inference(dtype)
     aux = helper.create_variable_for_type_inference("float32")
     helper.append_op(
-        "moe_ffn",
-        inputs={"X": [input], "RouterW": [router], "GateUpW": [gate_up],
-                "DownW": [down]},
+        "moe_ffn", inputs=inputs,
         outputs={"Y": [out], "TokensPerExpert": [counts], "AuxLoss": [aux]},
-        attrs={"top_k": int(top_k), "norm_topk_prob": bool(norm_topk_prob)})
+        attrs={"top_k": int(top_k), "norm_topk_prob": bool(norm_topk_prob),
+               "router": router, "expert_offset": int(expert_offset)})
     # said here, not left to the abstract evaluation of the lowering: with
     # an unknown batch that runs at a million sequences, whose rows times
     # top_k no int32 index reaches

@@ -141,7 +141,9 @@ def multi_head_attention(
 
     qk_norm=True normalises the q and the k projection with an rms_norm
     each (own [d] weight, over all heads jointly, as OLMoE does) before
-    the head split, and so before rotary.
+    the head split, and so before rotary.  qk_norm="head" normalises every
+    head on its own over head_dim (one [head_dim] weight for q's heads,
+    one for k's, as LFM2 does), after the head split and before rotary.
 
     RAGGED cache mode (the continuous-batching serving step): a cache
     dict carrying "pos_rows" [B] + "width_rows" [B] (and "pos_mat"
@@ -163,15 +165,20 @@ def multi_head_attention(
                   param_attr=_pa("mha_k.w"))
     v = layers.fc(values, size=n_kv * dh, num_flatten_dims=2, bias_attr=False,
                   param_attr=_pa("mha_v.w"))
-    if qk_norm:
+    if qk_norm not in (False, True, "head"):
+        raise ValueError("qk_norm is False, True (the whole projection) "
+                         "or 'head', got %r" % (qk_norm,))
+    if qk_norm and qk_norm != "head":
         q = layers.rms_norm(q, epsilon=qk_norm_eps,
                             param_attr=_pa("mha_q_norm.w"))
         k = layers.rms_norm(k, epsilon=qk_norm_eps,
                             param_attr=_pa("mha_k_norm.w"))
 
-    def split_heads(x, heads):
+    def split_heads(x, heads, norm_attr=None):
         b, t = x.shape[0], x.shape[1]
         x = layers.reshape(x, [b, t, heads, dh])
+        if norm_attr is not None:  # per head: one [dh] weight for all
+            x = layers.rms_norm(x, epsilon=qk_norm_eps, param_attr=norm_attr)
         return layers.transpose(x, [0, 2, 1, 3])  # [B, heads, T, Dh]
 
     def repeat_kv(x):
@@ -185,8 +192,10 @@ def multi_head_attention(
         x = layers.expand(x, [1, 1, g, 1, 1])
         return layers.reshape(x, [b, n_head, t, dh])
 
-    q = split_heads(q, n_head)
-    k, v = split_heads(k, n_kv), split_heads(v, n_kv)
+    per_head = qk_norm == "head"
+    q = split_heads(q, n_head, _pa("mha_q_norm.w") if per_head else None)
+    k = split_heads(k, n_kv, _pa("mha_k_norm.w") if per_head else None)
+    v = split_heads(v, n_kv)
     if rotary:
         # ragged serving feeds pos_mat [B, W] (per-row positions);
         # chunked decode feeds pos_vec (positions pos..pos+W-1); the

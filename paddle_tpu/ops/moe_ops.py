@@ -1,12 +1,22 @@
 """Routed experts as a Program op: `moe_ffn`.
 
-A token-choice mixture of SwiGLU experts (softmax router, top-k, no
-capacity: dropless under any imbalance) lowered with static shapes: the
-N*k (token, expert) assignments are sorted by expert, the tokens gathered
-into one [N*k, d] array, and the experts run as two grouped matmuls over
-its contiguous groups, whose sizes are data.  An expert that receives no
-token is a group of size zero.  Nothing here is a [tokens, experts,
-capacity] tensor (`parallel/moe.py`'s dispatch, which no op lowers to).
+A token-choice mixture of SwiGLU experts (softmax or sigmoid router,
+top-k, no capacity: dropless under any imbalance) lowered with static
+shapes: the N*k (token, expert) assignments are sorted by expert, the
+tokens gathered into one [N*k, d] array, and the experts run as two
+grouped matmuls over its contiguous groups, whose sizes are data.  An
+expert that receives no token is a group of size zero.  Nothing here is a
+[tokens, experts, capacity] tensor (`parallel/moe.py`'s dispatch, which no
+op lowers to).
+
+A chip's share of an expert layer: the op holds the experts
+[expert_offset, expert_offset + E_held) (E_held the leading dimension of
+its expert weights) of the E its router chooses among, routes over all E
+and computes its own experts' part of the result.  Assignments to experts
+held elsewhere sort behind the held ones, outside every group, so the
+grouped matmuls' work follows the live rows; what those experts would add
+is left out, forward and backward.  Nothing stands in for the other chips
+or their exchange.
 
 The lowering opens `route`, `dispatch`, `experts` and `combine` under the
 op's own `<role>/moe_ffn/<index>` scope, so a device trace splits the op's
@@ -34,18 +44,29 @@ _GMM_WEIGHT_TILE = 2048 * 1024
 _TGMM_MAX = 1024
 
 
+def _tile(width, cap):
+    """The widest tile up to `cap` that Mosaic takes and `width` divides
+    into: a multiple of 128 that divides it (LFM2's 1792 and 3584 are
+    14 and 28 x 128), else the width or the cap themselves."""
+    fits = [t for t in range(128, min(width, cap) + 1, 128)
+            if width % t == 0]
+    return fits[-1] if fits else min(width, cap)
+
+
 def _gmm_tile(k, n):
-    tk = min(k, _GMM_MAX_CONTRACTION)
-    return (_GMM_ROWS, tk, min(n, _GMM_WEIGHT_TILE // tk))
+    tk = _tile(k, _GMM_MAX_CONTRACTION)
+    return (_GMM_ROWS, tk, _tile(n, _GMM_WEIGHT_TILE // tk))
 
 
-def _megablox_fits(lhs, rhs):
-    """The Pallas grouped matmul is for the chip (it would be interpreted
-    elsewhere), for a single device (XLA cannot partition a Mosaic call
-    under a GSPMD mesh), and for rows and widths its tiles divide."""
+def _megablox_fits(ctx, lhs, rhs):
+    """The Pallas grouped matmul is for a step placed on the chip (it
+    would be interpreted elsewhere: LowerCtx.platform, which the Executor
+    states; a caller that did not say gets `ragged_dot`), for a single
+    device (XLA cannot partition a Mosaic call under a GSPMD mesh), and
+    for rows and widths its tiles divide."""
     from .spmd_epilogue import mesh_ctx
 
-    return (jax.default_backend() == "tpu" and mesh_ctx() is None
+    return (ctx.platform == "tpu" and mesh_ctx() is None
             and lhs.shape[0] % _GMM_ROWS == 0
             and rhs.shape[1] % 128 == 0 and rhs.shape[2] % 128 == 0)
 
@@ -72,7 +93,7 @@ def _mgmm_bwd(res, g):
     d_lhs = gmm(g, rhs, group_sizes, lhs.dtype, _gmm_tile(n, k),
                 transpose_rhs=True)
     d_rhs = tgmm(lhs.swapaxes(0, 1), g, group_sizes, rhs.dtype,
-                 (_GMM_ROWS, min(k, _TGMM_MAX), min(n, _TGMM_MAX)),
+                 (_GMM_ROWS, _tile(k, _TGMM_MAX), _tile(n, _TGMM_MAX)),
                  num_actual_groups=rhs.shape[0])
     return d_lhs, d_rhs, None
 
@@ -80,15 +101,17 @@ def _mgmm_bwd(res, g):
 _megablox_gmm.defvjp(_mgmm_fwd, _mgmm_bwd)
 
 
-def grouped_matmul(lhs, rhs, group_sizes):
+def grouped_matmul(ctx, lhs, rhs, group_sizes):
     """[M, K] x [G, K, N] -> [M, N]: rows of `lhs` in G contiguous groups
     of `group_sizes` rows, group g multiplied by rhs[g]; f32 accumulation,
-    result in lhs's dtype.  On the chip, megablox's Pallas `gmm` (and
-    `gmm` over rhs^T / `tgmm` for the two gradients); elsewhere
-    `jax.lax.ragged_dot`, whose transposes jax's autodiff supplies."""
+    result in lhs's dtype.  Rows behind the last group belong to none:
+    the kernel leaves them unwritten, `ragged_dot` zero, and the caller
+    reads neither.  On the chip, megablox's Pallas `gmm` (and `gmm` over
+    rhs^T / `tgmm` for the two gradients); elsewhere `jax.lax.ragged_dot`,
+    whose transposes jax's autodiff supplies."""
     from .kernel_tuning import note_dense_vjp, note_kernel
 
-    if _megablox_fits(lhs, rhs):
+    if _megablox_fits(ctx, lhs, rhs):
         note_kernel("grouped_matmul")
         return _megablox_gmm(lhs, rhs, group_sizes)
     note_dense_vjp("grouped_matmul")
@@ -143,22 +166,31 @@ def _feo_bwd(k, res, g):
 _from_expert_order.defvjp(_feo_fwd, _feo_bwd)
 
 
+def _router_logits(x2, router_w):
+    """Float32 whatever the operands' dtype: a top-k is discontinuous, and
+    logits rounded to bf16 change which experts run."""
+    return jnp.dot(x2.astype(jnp.float32), router_w.astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST)
+
+
+def _tokens_per_expert(top_e, n_experts):
+    # a compare-and-reduce, not a scatter-add of N*k ones
+    return (top_e.reshape(-1, 1) == jnp.arange(n_experts)).sum(
+        0, dtype=jnp.int32)
+
+
 def route(x2, router_w, top_k, norm_topk_prob):
-    """Router in float32 whatever the operands' dtype: a top-k is
-    discontinuous, and logits rounded to bf16 change which experts run.
-    Returns (top-k probabilities [N, k], their experts [N, k] int32,
-    tokens per expert [E] int32, aux [2] = load-balance and z loss)."""
+    """The softmax router (OLMoE's).  Returns (top-k probabilities [N, k],
+    their experts [N, k] int32, tokens per expert [E] int32, aux [2] =
+    load-balance and z loss)."""
     n_experts = router_w.shape[-1]
-    logits = jnp.dot(x2.astype(jnp.float32), router_w.astype(jnp.float32),
-                     precision=jax.lax.Precision.HIGHEST)
+    logits = _router_logits(x2, router_w)
     lse = jax.scipy.special.logsumexp(logits, axis=-1)
     probs = jnp.exp(logits - lse[:, None])
     top_p, top_e = jax.lax.top_k(probs, top_k)
     if norm_topk_prob:
         top_p = top_p / top_p.sum(-1, keepdims=True)
-    # a compare-and-reduce, not a scatter-add of N*k ones
-    counts = (top_e.reshape(-1, 1) == jnp.arange(n_experts)).sum(
-        0, dtype=jnp.int32)
+    counts = _tokens_per_expert(top_e, n_experts)
     # lb = E * sum_e F_e * P_e, F_e the routing decisions to e over N (a
     # count: no gradient), P_e the mean router probability; z = mean over
     # tokens of logsumexp(logits)^2
@@ -171,14 +203,41 @@ def route(x2, router_w, top_k, norm_topk_prob):
     return top_p, top_e, counts, jnp.stack([lb, z])
 
 
-@register("moe_ffn")
+def route_sigmoid(x2, router_w, bias, top_k, norm_topk_prob):
+    """The sigmoid router with a selection bias (LFM2's):
+    s = sigmoid(logits) in float32; the experts are the top-k of s + bias,
+    their weights the UNBIASED s, renormalised over the chosen with the
+    published 1e-6.  The bias is a buffer: no gradient.  No auxiliary
+    loss: aux is zeros.  Same returns as `route`."""
+    n_experts = router_w.shape[-1]
+    s = jax.nn.sigmoid(_router_logits(x2, router_w))
+    chooser = s if bias is None else s + jax.lax.stop_gradient(
+        bias.astype(jnp.float32))
+    _, top_e = jax.lax.top_k(chooser, top_k)
+    # s at the chosen experts by compare-and-reduce: a take_along_axis
+    # would transpose to a scatter-add
+    top_p = jnp.where(top_e[..., None] == jnp.arange(n_experts),
+                      s[:, None, :], 0.0).sum(-1)
+    if norm_topk_prob:
+        top_p = top_p / (top_p.sum(-1, keepdims=True) + 1e-6)
+    return (top_p, top_e, _tokens_per_expert(top_e, n_experts),
+            jnp.zeros((2,), jnp.float32))
+
+
+@register("moe_ffn", no_grad_inputs=("ExpertBias",))
 def _moe_ffn(ctx, ins, attrs):
     """Y = sum over a token's top-k experts of p_e * down_e(silu(gate_e x)
-    * up_e x).  Inputs: X [..., d], RouterW [d, E], GateUpW [E, d, 2f]
+    * up_e x).  Inputs: X [..., d], RouterW [d, E], GateUpW [E_held, d, 2f]
     (gate in [..., :f], up in [..., f:]: one grouped matmul reads the
-    gathered rows once), DownW [E, f, d].  Outputs: Y in the experts'
-    dtype, TokensPerExpert [E] int32, AuxLoss [2] f32 (load-balance, z).
-    The experts compute in GateUpW's dtype (bf16 under AMP) with f32
+    gathered rows once), DownW [E_held, f, d], optionally ExpertBias [E]
+    (sigmoid router: added to the scores for the selection alone).
+    Attributes: top_k, norm_topk_prob, router "softmax" (default) or
+    "sigmoid", expert_offset (0): the op holds
+    experts [expert_offset, expert_offset + E_held) and leaves out what
+    the others would add.  Outputs: Y in the experts' dtype,
+    TokensPerExpert [E] int32 (the router's decisions over all E),
+    AuxLoss [2] f32 (load-balance, z; zeros for the sigmoid router).  The
+    experts compute in GateUpW's dtype (bf16 under AMP) with f32
     accumulation; the router reads X as it is given (f32 under AMP)."""
     x = ins["X"][0]
     router_w = ins["RouterW"][0]
@@ -188,27 +247,74 @@ def _moe_ffn(ctx, ins, attrs):
     x2 = x.reshape(-1, d)
     n = x2.shape[0]
     cdt = w_gu.dtype
+    n_experts, held = router_w.shape[-1], w_gu.shape[0]
+    offset = int(attrs.get("expert_offset", 0))
+    if offset < 0 or offset + held > n_experts:
+        raise ValueError(
+            "moe_ffn holds experts [%d, %d) of a router over %d"
+            % (offset, offset + held, n_experts))
+    norm = bool(attrs.get("norm_topk_prob", False))
 
     with jax.named_scope("route"):
-        top_p, top_e, counts, aux = route(
-            x2, router_w, k, bool(attrs.get("norm_topk_prob", False)))
+        if attrs.get("router", "softmax") == "sigmoid":
+            bias = ins["ExpertBias"][0] if ins.get("ExpertBias") else None
+            top_p, top_e, counts, aux = route_sigmoid(
+                x2, router_w, bias, k, norm)
+        else:
+            top_p, top_e, counts, aux = route(x2, router_w, k, norm)
     with jax.named_scope("dispatch"):
+        sort_key, group_sizes, live = top_e.reshape(-1), counts, None
+        if held != n_experts:
+            # the held experts' rows first, by local expert; assignments
+            # to experts held elsewhere behind them, in no group
+            local = sort_key - offset
+            sort_key = jnp.where((local >= 0) & (local < held), local, held)
+            group_sizes = counts[offset:offset + held]
+            live = (jnp.arange(n * k) < group_sizes.sum())[:, None]
         # stable sort of the N*k assignments by expert; `inv` undoes it
-        order = jnp.argsort(top_e.reshape(-1), stable=True)
+        order = jnp.argsort(sort_key, stable=True)
         inv = jnp.argsort(order)
         tok = order // k
         rows = _to_expert_order(x2.astype(cdt), tok, inv, k)
         row_p = _to_expert_order(top_p.reshape(n * k, 1), order, inv, 1)
+        if live is not None:
+            # a dead row's gradient is whatever the kernel left there
+            rows = jnp.where(live, rows, 0)
     with jax.named_scope("experts"):
-        gu = grouped_matmul(rows, w_gu, counts)
+        gu = grouped_matmul(ctx, rows, w_gu, group_sizes)
         act = (jax.nn.silu(gu[:, :f].astype(jnp.float32))
                * gu[:, f:].astype(jnp.float32)).astype(cdt)
-        out = grouped_matmul(act, w_down, counts)
+        out = grouped_matmul(ctx, act, w_down, group_sizes)
     with jax.named_scope("combine"):
+        if live is not None:
+            out = jnp.where(live, out, 0)  # so is a dead row's result
         out = (out.astype(jnp.float32) * row_p).astype(cdt)
         y = _from_expert_order(out, tok, inv, k)
     return {"Y": [y.reshape(x.shape)], "TokensPerExpert": [counts],
             "AuxLoss": [aux]}
+
+
+# At this rate an expert chosen twice as often as the mean about halves in
+# one step: a chosen count doubles per 0.1 of bias at random weights
+# (PERF.md, PR 30).
+EXPERT_BIAS_RATE = 0.1
+
+
+@register("expert_bias_update",
+          no_grad_inputs=("ExpertBias", "TokensPerExpert"))
+def _expert_bias_update(ctx, ins, attrs):
+    """The balancing step of a selection bias (auxiliary-loss-free load
+    balancing, Wang et al. 2024, arXiv:2408.15664, in its proportional
+    form): after a step, an expert's bias moves against its share of the
+    step's routing decisions, b += EXPERT_BIAS_RATE * (1 - c / mean(c)):
+    one chosen twice as often as the mean comes down by the rate, one
+    never chosen goes up by it.  Inputs: ExpertBias [E] f32, TokensPerExpert [E] int32 (the
+    step's `moe_ffn` counts over all E, whatever share the chip holds).
+    Output: ExpertBiasOut, the same variable."""
+    bias = ins["ExpertBias"][0]
+    load = ins["TokensPerExpert"][0].astype(jnp.float32)
+    step = EXPERT_BIAS_RATE * (1.0 - load / load.mean())
+    return {"ExpertBiasOut": [bias + step.astype(bias.dtype)]}
 
 
 # ---------------------------------------------------------------------------
@@ -232,13 +338,18 @@ def _moe_ffn_infer(op, ins):
     n_experts = None
     if all(known):
         n_experts, f = wr.shape[-1], wd.shape[1]
-        d = wr.shape[0]
-        if (tuple(wgu.shape) != (n_experts, d, 2 * f)
-                or tuple(wd.shape) != (n_experts, f, d)):
+        d, held = wr.shape[0], wd.shape[0]
+        if (tuple(wgu.shape) != (held, d, 2 * f)
+                or tuple(wd.shape) != (held, f, d)):
             raise InferError(
                 "moe_ffn expert weights disagree: RouterW%s GateUpW%s "
-                "DownW%s (want [d, E], [E, d, 2f], [E, f, d])"
+                "DownW%s (want [d, E], [E_held, d, 2f], [E_held, f, d])"
                 % (wr.shape, wgu.shape, wd.shape))
+        offset = int(op.attrs.get("expert_offset", 0))
+        if offset < 0 or offset + held > n_experts:
+            raise InferError(
+                "moe_ffn holds experts [%d, %d) of a router over %d"
+                % (offset, offset + held, n_experts))
         if (x is not None and x.shape is not None and x.shape[-1] >= 0
                 and x.shape[-1] != d):
             raise InferError("moe_ffn hidden-dim mismatch: X%s vs RouterW%s"
@@ -246,6 +357,14 @@ def _moe_ffn_infer(op, ins):
         if int(op.attrs.get("top_k", 1)) > n_experts:
             raise InferError("moe_ffn top_k %s exceeds its %d experts"
                              % (op.attrs.get("top_k"), n_experts))
+        bias = _vi(ins, "ExpertBias")
+        if (bias is not None and bias.shape is not None
+                and tuple(bias.shape) != (n_experts,)):
+            raise InferError("moe_ffn ExpertBias%s is not [%d]"
+                             % (bias.shape, n_experts))
+    if op.attrs.get("router", "softmax") not in ("softmax", "sigmoid"):
+        raise InferError("moe_ffn router %r is neither softmax nor sigmoid"
+                         % (op.attrs.get("router"),))
     return {
         "Y": [VarInfo(x.shape, wgu.dtype if wgu is not None else None)
               if x is not None else None],
@@ -253,3 +372,17 @@ def _moe_ffn_infer(op, ins):
                             if n_experts is not None else None],
         "AuxLoss": [VarInfo((2,), "float32")],
     }
+
+
+@register_infer("expert_bias_update",
+                req_ins=("ExpertBias", "TokensPerExpert"),
+                req_outs=("ExpertBiasOut",))
+def _expert_bias_update_infer(op, ins):
+    bias, counts = _vi(ins, "ExpertBias"), _vi(ins, "TokensPerExpert")
+    if (bias is not None and counts is not None
+            and bias.shape is not None and counts.shape is not None
+            and tuple(bias.shape) != tuple(counts.shape)):
+        raise InferError("expert_bias_update: ExpertBias%s and "
+                         "TokensPerExpert%s differ"
+                         % (bias.shape, counts.shape))
+    return {"ExpertBiasOut": [bias]}

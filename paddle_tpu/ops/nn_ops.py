@@ -318,6 +318,91 @@ def _rms_norm(ctx, ins, attrs):
     return {"Y": [y.astype(x.dtype)]}
 
 
+def _windows(x, taps, ahead=False):
+    """The `taps` T-long windows of x [..., T, d] that a causal filter's
+    taps read: window j is x_{t - (taps-1) + j}, or with `ahead` (the
+    gradient's direction) x_{t + (taps-1) - j}; zeros beyond the ends.
+    Slices of ONE padded copy of x as it is given: a fusion then reads x
+    at `taps` offsets, where shifting a computed product makes the
+    compiler write the product to memory first."""
+    t = x.shape[-2]
+    edge = [(0, taps - 1), (0, 0)] if ahead else [(taps - 1, 0), (0, 0)]
+    xp = jnp.pad(x, [(0, 0)] * (x.ndim - 2) + edge)
+    starts = [taps - 1 - j for j in range(taps)] if ahead else range(taps)
+    return [xp[..., s:s + t, :] for s in starts]
+
+
+def _f32(x):
+    return x.astype(jnp.float32)
+
+
+def _conv_taps(bcx, d, taps):
+    """[(B u)_{t-(L-1)+j}]_j in f32, each from a window of bcx itself."""
+    return [_f32(w[..., :d]) * _f32(w[..., 2 * d:])
+            for w in _windows(bcx, taps)]
+
+
+def _filtered(windows, k):
+    """sum_j k[:, j] * windows[j]."""
+    return sum(w * k[:, j] for j, w in enumerate(windows))
+
+
+@jax.custom_vjp
+def gated_short_conv(bcx, filt):
+    """C * causal_depthwise_conv(B * u) over the T axis (second to last):
+    bcx [..., T, 3d] holds B, C and u side by side, filt [d, L] one
+    kernel a channel; v_t = sum_j filt[:, j] * (B u)_{t-(L-1)+j}, zeros
+    left of t = 0.  L multiply-adds in f32, result in bcx's dtype: one
+    pass over bcx."""
+    d, taps = filt.shape
+    v = _filtered(_conv_taps(bcx, d, taps), _f32(filt))
+    return (_f32(bcx[..., d:2 * d]) * v).astype(bcx.dtype)
+
+
+def _gsc_fwd(bcx, filt):
+    return gated_short_conv(bcx, filt), (bcx, filt)
+
+
+def _gsc_bwd(res, g):
+    """Written out, not derived: autodiff shifts the [.., T, 3d] gradient
+    once a tap through memory.  Here dv = g * C is read at L offsets
+    ahead, as the forward reads B u behind; v is recomputed (cheaper than
+    kept); one pass each over bcx and g for d bcx, one more for the
+    filter's gradient."""
+    bcx, filt = res
+    d, taps = filt.shape
+    k = _f32(filt)
+    bu = _conv_taps(bcx, d, taps)
+    v = _filtered(bu, k)
+    d_bu = _filtered(
+        [_f32(wg) * _f32(wx[..., d:2 * d]) for wg, wx in zip(
+            _windows(g, taps, ahead=True), _windows(bcx, taps, ahead=True))],
+        k)
+    d_bcx = jnp.concatenate(
+        [d_bu * _f32(bcx[..., 2 * d:]), _f32(g) * v,
+         d_bu * _f32(bcx[..., :d])], -1).astype(bcx.dtype)
+    dv = _f32(g) * _f32(bcx[..., d:2 * d])
+    rows = tuple(range(bcx.ndim - 1))
+    d_filt = jnp.stack([(dv * w).sum(rows) for w in bu], -1)
+    return d_bcx, d_filt.astype(filt.dtype)
+
+
+gated_short_conv.defvjp(_gsc_fwd, _gsc_bwd)
+
+
+@register("short_conv")
+def _short_conv(ctx, ins, attrs):
+    """The gated short convolution between an operator's two projections
+    (LFM2's conv layers): BCX [..., T, 3d] = h @ W_in, Filter [d, L], Out
+    [..., T, d] = C * conv(B * u).  Memory-bound elementwise work: f32
+    arithmetic whatever the dtype, Out in BCX's dtype (dtype-transparent
+    for the AMP trunk pass like rms_norm); the gradient is
+    gated_short_conv's own VJP."""
+    with jax.named_scope("gate_conv"):
+        out = gated_short_conv(ins["BCX"][0], ins["Filter"][0])
+    return {"Out": [out]}
+
+
 @register("group_norm")
 def _group_norm(ctx, ins, attrs):
     x = ins["X"][0]
@@ -1571,6 +1656,26 @@ def _rms_infer(op, ins):
             "rms_norm Scale%s does not match X%s's last axis"
             % (w.shape, x.shape))
     return {"Y": [VarInfo(x.shape, x.dtype)]}
+
+
+@register_infer("short_conv", req_ins=("BCX", "Filter"), req_outs=("Out",))
+def _short_conv_infer(op, ins):
+    x, k = _vi(ins, "BCX"), _vi(ins, "Filter")
+    if x is None or x.shape is None:
+        return {}
+    if len(x.shape) < 2:
+        raise InferError("short_conv wants BCX [..., T, 3d], got %s"
+                         % (x.shape,))
+    width = x.shape[-1]
+    if k is not None and k.shape is not None:
+        if len(k.shape) != 2 or (width >= 0 and width != 3 * k.shape[0]):
+            raise InferError(
+                "short_conv Filter%s does not match BCX%s (want [d, L] "
+                "against [..., T, 3d])" % (k.shape, x.shape))
+        width = k.shape[0]
+    elif width >= 0:
+        width //= 3
+    return {"Out": [VarInfo(tuple(x.shape[:-1]) + (width,), x.dtype)]}
 
 
 @register_infer("dropout", req_ins=("X",))

@@ -96,7 +96,9 @@ def program_flops(program, batch_hint=1):
                 continue
             m = _prod(x[:-1])
             k = x[-1]
-            n2 = w[0] if op.attrs.get("transpose_w", False) else w[1]
+            # a grad op carries its forward's attrs under __fwd_attrs__
+            attrs = op.attrs.get("__fwd_attrs__", op.attrs)
+            n2 = w[0] if attrs.get("transpose_w", False) else w[1]
             total += factor * 2.0 * m * k * n2
         elif t == "moe_ffn":
             # the router, and the two grouped matmuls over the N * top_k
@@ -109,9 +111,18 @@ def program_flops(program, batch_hint=1):
             rows, d = _prod(x[:-1]), x[-1]
             # a grad op carries its forward's attrs under __fwd_attrs__
             attrs = op.attrs.get("__fwd_attrs__", op.attrs)
-            routed = rows * int(attrs["top_k"])
+            # (a chip's share holds wd[0] of the router's wr[-1] experts
+            # and expects that share of the routed rows)
+            routed = rows * int(attrs["top_k"]) * wd[0] / float(wr[-1])
             total += factor * 2.0 * (rows * d * wr[-1]
                                      + routed * 3 * d * wd[1])
+        elif t == "short_conv":
+            # no matmul: B * u, L multiply-adds and the C gate a value
+            x = _shape(blk, op.inputs.get("BCX", [""])[0], batch_hint)
+            k = _shape(blk, op.inputs.get("Filter", [""])[0], batch_hint)
+            if not x or not k:
+                continue
+            total += factor * (2.0 * k[1] + 2.0) * _prod(x[:-1]) * k[0]
         elif t == "matmul":
             x = _shape(blk, op.inputs.get("X", [""])[0], batch_hint)
             y = _shape(blk, op.inputs.get("Y", [""])[0], batch_hint)

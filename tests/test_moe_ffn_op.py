@@ -1,8 +1,10 @@
-"""moe_ffn: routed SwiGLU experts (softmax router, top-k, dropless) against
-the loop-over-experts float32 reference of models/olmoe_reference.py:
-forward, every gradient, the experts chosen, and the router statistics,
-with a balanced router and with one so biased that one expert takes over
-half the rows and one takes none."""
+"""moe_ffn: routed SwiGLU experts (top-k, dropless) against the
+loop-over-experts float32 references of models/olmoe_reference.py (softmax
+router) and models/lfm2_reference.py (sigmoid router with a selection
+bias): forward, every gradient, the experts chosen, and the router
+statistics, with a balanced router, with one so biased that one expert
+takes over half the rows and one takes none, and with the op holding only
+a chip's share of the experts its router chooses among."""
 
 import functools
 
@@ -14,7 +16,7 @@ import pytest
 import paddle_tpu as fluid
 from paddle_tpu import analysis, layers
 from paddle_tpu.analysis.infer import InferError, VarInfo, get_infer_rule
-from paddle_tpu.models import olmoe_reference as ref
+from paddle_tpu.models import lfm2_reference, olmoe_reference as ref
 
 N, D, F, E, K = 48, 16, 8, 8, 2
 CFG = {"num_experts": E, "num_experts_per_tok": K}
@@ -43,29 +45,74 @@ def _weights(router):
     return w
 
 
-def _reference(w, norm_topk_prob):
-    cfg = dict(CFG, norm_topk_prob=norm_topk_prob)
+# case -> (weights, norm_topk_prob, router, expert bias, held range
+# (offset, count)).  The first three are OLMoE's.
+E_ALL = (0, E)
+CASE = {
+    "balanced": ("balanced", False, "softmax", False, E_ALL),
+    "balanced_norm": ("balanced", True, "softmax", False, E_ALL),
+    "skewed": ("skewed", False, "softmax", False, E_ALL),
+    "softmax_share": ("balanced", True, "softmax", False, (0, 4)),
+    "sigmoid": ("balanced", False, "sigmoid", False, E_ALL),
+    "sigmoid_norm_bias": ("balanced", True, "sigmoid", True, E_ALL),
+    "sigmoid_share": ("balanced", True, "sigmoid", True, (4, 2)),
+    "sigmoid_share_skewed": ("skewed", True, "sigmoid", True, (1, 2)),
+    "sigmoid_share_no_rows": ("skewed", True, "sigmoid", True, (6, 2)),
+}
+CASES = list(CASE)
+NAMES = ("x", "router", "gate_up", "down")
 
-    def loss(x, router, gate_up, down):
-        y, lb, z, _ = ref.moe(cfg, x, router, gate_up, down)
+
+def _bias():
+    """Large against the spread of the sigmoid scores, so that it changes
+    which experts are chosen."""
+    return np.random.RandomState(5).randn(E).astype("float32") * 0.5
+
+
+def _reference(case):
+    kind, norm, router, biased, (offset, held) = CASE[case]
+    w = _weights(kind)
+    cfg = dict(CFG, norm_topk_prob=norm, expert_offset=offset)
+    # an expert the op does not hold adds nothing: for the softmax
+    # reference, which knows no share, its weights are zero instead
+    absent = np.ones((E, 1, 1), "float32")
+    absent[offset:offset + held] = 0.0
+    bias = jnp.asarray(_bias()) if biased else None
+    sl = slice(offset, offset + held)
+
+    def outputs(x, router_w, gate_up, down):
+        if router == "sigmoid":
+            y, top_e = lfm2_reference.moe(cfg, x, router_w, bias,
+                                          gate_up[sl], down[sl])
+            return y, 0.0, 0.0, top_e
+        return ref.moe(cfg, x, router_w, gate_up * (1 - absent),
+                       down * (1 - absent))
+
+    def loss(*args):
+        y, lb, z, _ = outputs(*args)
         return (y * w["mix"]).sum() + 0.5 * lb + 0.25 * z
 
-    args = [jnp.asarray(w[k]) for k in ("x", "router", "gate_up", "down")]
+    args = [jnp.asarray(w[k]) for k in NAMES]
     with jax.default_matmul_precision("highest"):
-        y, lb, z, top_e = ref.moe(cfg, *args)
-        grads = jax.grad(loss, argnums=(0, 1, 2, 3))(*args)
-    return {"y": y, "aux": jnp.stack([lb, z]), "top_e": top_e,
-            "grads": dict(zip(("x", "router", "gate_up", "down"), grads))}
+        y, lb, z, top_e = outputs(*args)
+        grads = dict(zip(NAMES, jax.grad(loss, argnums=(0, 1, 2, 3))(*args)))
+    grads["gate_up"], grads["down"] = grads["gate_up"][sl], grads["down"][sl]
+    return {"y": y, "aux": jnp.stack([lb, z]).astype(jnp.float32),
+            "top_e": top_e, "grads": grads}
 
 
 @functools.lru_cache(maxsize=None)
-def _run(router, norm_topk_prob):
+def _run(case):
     """One program per case: Y, the statistics and all four gradients."""
     from paddle_tpu import framework, unique_name
     from paddle_tpu.initializer import NumpyArrayInitializer
     from paddle_tpu.param_attr import ParamAttr
 
-    w = _weights(router)
+    kind, norm, router, biased, (offset, held) = CASE[case]
+    w = _weights(kind)
+    init = dict(w, bias=_bias(),
+                gate_up=w["gate_up"][offset:offset + held],
+                down=w["down"][offset:offset + held])
     main, startup = fluid.Program(), fluid.Program()
     with framework.program_guard(main, startup), unique_name.guard():
         x = layers.data("x", shape=[N, D], append_batch_size=False)
@@ -74,12 +121,14 @@ def _run(router, norm_topk_prob):
 
         def attr(name):
             return ParamAttr(name=name,
-                             initializer=NumpyArrayInitializer(w[name]))
+                             initializer=NumpyArrayInitializer(init[name]))
 
         y, aux, counts = layers.moe_ffn(
-            x, E, F, K, norm_topk_prob=norm_topk_prob,
+            x, E, F, K, norm_topk_prob=norm,
             router_attr=attr("router"), gate_up_attr=attr("gate_up"),
-            down_attr=attr("down"))
+            down_attr=attr("down"), router=router,
+            expert_bias_attr=attr("bias") if biased else None,
+            num_local_experts=held, expert_offset=offset)
         coef = layers.assign(np.array([0.5, 0.25], "float32"))
         coef.stop_gradient = True
         loss = layers.elementwise_add(
@@ -87,63 +136,190 @@ def _run(router, norm_topk_prob):
             layers.reduce_sum(layers.elementwise_mul(aux, coef)))
         fluid.backward.append_backward(loss)
     names = main._grad_names
+    assert "bias" not in names  # a buffer: no gradient is built for it
     exe = fluid.Executor(fluid.CPUPlace())
     with fluid.scope_guard(fluid.Scope()):
         exe.run(startup)
         out = exe.run(
             main, feed={"x": w["x"], "mix": w["mix"]},
-            fetch_list=[y, aux, counts] + [names[n] for n in (
-                "x", "router", "gate_up", "down")])
+            fetch_list=[y, aux, counts] + [names[n] for n in NAMES])
     diags = analysis.verify_program(main, fetches=[loss])
     return {"y": out[0], "aux": out[1], "counts": out[2],
-            "grads": dict(zip(("x", "router", "gate_up", "down"), out[3:])),
-            "errors": [d for d in diags if d.is_error]}, \
-        _reference(w, norm_topk_prob)
+            "grads": dict(zip(NAMES, out[3:])),
+            "errors": [d for d in diags if d.is_error]}, _reference(case)
 
 
-CASES = [("balanced", False), ("balanced", True), ("skewed", False)]
-
-
-@pytest.mark.parametrize("router, norm", CASES)
-def test_forward_matches_the_loop_over_experts(router, norm):
-    got, want = _run(router, norm)
+@pytest.mark.parametrize("case", CASES)
+def test_forward_matches_the_loop_over_experts(case):
+    got, want = _run(case)
     np.testing.assert_allclose(got["y"], want["y"], rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(got["aux"], want["aux"], rtol=1e-5)
     assert not got["errors"]
+    if CASE[case][2] == "sigmoid":  # no auxiliary loss
+        np.testing.assert_array_equal(got["aux"], [0.0, 0.0])
 
 
-@pytest.mark.parametrize("wrt", ["x", "router", "gate_up", "down"])
-@pytest.mark.parametrize("router, norm", CASES)
-def test_gradient_matches_the_loop_over_experts(router, norm, wrt):
-    got, want = _run(router, norm)
+@pytest.mark.parametrize("wrt", NAMES)
+@pytest.mark.parametrize("case", CASES)
+def test_gradient_matches_the_loop_over_experts(case, wrt):
+    """With a share held, the reference leaves out what the absent experts
+    would add, and so must every gradient: the router's too."""
+    got, want = _run(case)
+    assert got["grads"][wrt].shape == want["grads"][wrt].shape
     np.testing.assert_allclose(got["grads"][wrt], want["grads"][wrt],
                                rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("router, norm", CASES)
-def test_no_routing_decision_is_dropped_and_choices_are_the_references(
-        router, norm):
-    """TokensPerExpert counts exactly the reference's top-k choices, and
-    sums to N k: dropless under any imbalance, an empty expert is legal."""
-    got, want = _run(router, norm)
+@pytest.mark.parametrize("case", CASES)
+def test_no_routing_decision_is_dropped_and_choices_are_the_references(case):
+    """TokensPerExpert counts exactly the reference's top-k choices over
+    ALL the router's experts, held here or not, and sums to N k: dropless
+    under any imbalance, an empty expert is legal."""
+    got, want = _run(case)
     counts = np.asarray(got["counts"])
     assert counts.dtype == np.int32 and counts.sum() == N * K
     np.testing.assert_array_equal(
         counts, np.bincount(np.asarray(want["top_e"]).reshape(-1),
                             minlength=E))
-    if router == "skewed":
+    if case == "skewed":
         assert counts[0] == N  # every token, far over half of them
         assert counts[E - 1] == 0
+    if case == "sigmoid_share_skewed":
+        # one held group is empty, the other takes every token
+        assert counts[1] == 0 and counts[2] == N
+    if case == "sigmoid_share_no_rows":
+        # no row at all is live here: the result and every gradient of
+        # the held experts are zero, which the reference agrees with
+        assert counts[6:8].sum() == 0 and not np.asarray(got["y"]).any()
 
 
-def _infer(x, wr, wgu, wd, top_k=K):
+def test_the_shares_of_a_layer_add_up_to_the_whole_layer():
+    """Four ops holding experts 0-1, 2-3, 4-5, 6-7 of the same layer: their
+    outputs sum to what the op holding all eight gives (the model-level
+    share test against the uncut reference is in test_lfm2_model.py)."""
+    from paddle_tpu.core.registry import LowerCtx
+    from paddle_tpu.ops import moe_ops
+
+    w = _weights("balanced")
+
+    def run(offset, held):
+        return moe_ops._moe_ffn(LowerCtx(platform="cpu"), {
+            "X": [jnp.asarray(w["x"])], "RouterW": [jnp.asarray(w["router"])],
+            "ExpertBias": [jnp.asarray(_bias())],
+            "GateUpW": [jnp.asarray(w["gate_up"][offset:offset + held])],
+            "DownW": [jnp.asarray(w["down"][offset:offset + held])]},
+            {"top_k": K, "router": "sigmoid", "norm_topk_prob": True,
+             "expert_offset": offset})
+
+    whole = run(0, E)
+    parts = [run(o, 2) for o in (0, 2, 4, 6)]
+    np.testing.assert_allclose(sum(p["Y"][0] for p in parts), whole["Y"][0],
+                               rtol=1e-5, atol=1e-6)
+    for p in parts:
+        np.testing.assert_array_equal(p["TokensPerExpert"][0],
+                                      whole["TokensPerExpert"][0])
+
+
+def _scores(x, router_w):
+    with jax.default_matmul_precision("highest"):
+        return jax.nn.sigmoid(jnp.asarray(x) @ jnp.asarray(router_w))
+
+
+def test_the_bias_selects_and_the_unbiased_score_weighs():
+    """Experts are the top-k of s + b; a weight's numerator is s.  A bias
+    that changes the chosen set changes no numerator."""
+    from paddle_tpu.ops import moe_ops
+
+    w = _weights("balanced")
+    x, wr, bias = (jnp.asarray(w["x"]), jnp.asarray(w["router"]),
+                   jnp.asarray(_bias()))
+    s = _scores(x, wr)
+    plain_p, plain_e, _, _ = moe_ops.route_sigmoid(x, wr, None, K, False)
+    top_p, top_e, counts, aux = moe_ops.route_sigmoid(x, wr, bias, K, False)
+    assert (np.sort(top_e, -1) != np.sort(plain_e, -1)).any()
+    _, want_e = jax.lax.top_k(s + bias, K)
+    np.testing.assert_array_equal(top_e, want_e)
+    np.testing.assert_allclose(top_p, jnp.take_along_axis(s, top_e, -1),
+                               rtol=1e-6)
+    np.testing.assert_allclose(plain_p, jax.lax.top_k(s, K)[0], rtol=1e-6)
+    assert counts.sum() == N * K and not np.asarray(aux).any()
+
+
+def test_sigmoid_weights_are_renormalised_with_the_published_epsilon():
+    """p = s / (sum of the chosen s + 1e-6): with scores of about 6e-6
+    the epsilon is a twelfth of the sum, and the weights sum to well
+    under one."""
+    from paddle_tpu.ops import moe_ops
+
+    w = _weights("balanced")
+    x = jnp.asarray(w["x"])
+    wr = jnp.asarray(w["router"]) * 1e-3
+    x = x.at[:, 0].set(1.0)
+    wr = wr.at[0].set(-12.0)  # every logit about -12
+    s = _scores(x, wr)
+    top_p, top_e, _, _ = moe_ops.route_sigmoid(x, wr, None, K, True)
+    chosen = jnp.take_along_axis(s, top_e, -1)
+    total = chosen.sum(-1, keepdims=True)
+    assert float(total.max()) < 2e-5
+    np.testing.assert_allclose(top_p, chosen / (total + 1e-6), rtol=1e-5)
+    assert float(top_p.sum(-1).max()) < 0.95
+
+
+def _prims(jaxpr, out):
+    for eqn in jaxpr.eqns:
+        out.append(eqn.primitive.name)
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                if hasattr(sub, "jaxpr"):
+                    _prims(sub.jaxpr, out)
+                elif hasattr(sub, "eqns"):
+                    _prims(sub, out)
+    return out
+
+
+@pytest.mark.parametrize("norm, n_prims, sha", [
+    (False, 88, "c539e7a1474d8748"), (True, 91, "81bf8274ff1c3b18")])
+def test_olmoes_attributes_lower_to_the_jaxpr_they_did(norm, n_prims, sha):
+    """The op learnt a second router and a share by attributes whose
+    defaults are OLMoE's: at OLMoE's attributes the lowering is primitive
+    for primitive what PR 25's was (count and digest of the primitive
+    sequence taken from the commit before the op learnt them), and saying
+    the defaults changes nothing."""
+    import hashlib
+
+    from paddle_tpu.core.registry import LowerCtx
+    from paddle_tpu.ops import moe_ops
+
+    def lowered(**more):
+        def f(x, wr, wgu, wd):
+            out = moe_ops._moe_ffn(
+                LowerCtx(platform="cpu"),
+                {"X": [x], "RouterW": [wr], "GateUpW": [wgu], "DownW": [wd]},
+                dict({"top_k": K, "norm_topk_prob": norm}, **more))
+            return out["Y"][0], out["TokensPerExpert"][0], out["AuxLoss"][0]
+
+        return jax.make_jaxpr(f)(*(
+            jax.ShapeDtypeStruct(shape, jnp.float32) for shape in (
+                (N, D), (D, E), (E, D, 2 * F), (E, F, D))))
+
+    unsaid = lowered()
+    prims = _prims(unsaid.jaxpr, [])
+    assert len(prims) == n_prims
+    assert hashlib.sha256(" ".join(prims).encode()).hexdigest()[:16] == sha
+    assert str(unsaid) == str(lowered(router="softmax", expert_offset=0))
+
+
+def _infer(x, wr, wgu, wd, top_k=K, bias=None, **attrs):
     class Op:
-        attrs = {"top_k": top_k}
+        pass
 
-    return get_infer_rule("moe_ffn").fn(Op, {
-        "X": [VarInfo(x, "float32")], "RouterW": [VarInfo(wr, "float32")],
-        "GateUpW": [VarInfo(wgu, "bfloat16")],
-        "DownW": [VarInfo(wd, "bfloat16")]})
+    Op.attrs = dict({"top_k": top_k}, **attrs)
+    ins = {"X": [VarInfo(x, "float32")], "RouterW": [VarInfo(wr, "float32")],
+           "GateUpW": [VarInfo(wgu, "bfloat16")],
+           "DownW": [VarInfo(wd, "bfloat16")]}
+    if bias is not None:
+        ins["ExpertBias"] = [VarInfo(bias, "float32")]
+    return get_infer_rule("moe_ffn").fn(Op, ins)
 
 
 def test_infer_rule_gives_the_three_outputs():
@@ -172,40 +348,99 @@ def test_infer_rule_refuses_more_choices_than_experts():
         _infer((4, D), (D, E), (E, D, 2 * F), (E, F, D), top_k=E + 1)
 
 
-def test_bf16_experts_keep_a_float32_router():
-    """Under the AMP pass the op reads X and RouterW in f32 and the
-    experts' weights in bf16: the experts chosen are those of the float32
-    router, whatever the experts' own precision, and the counts stay
-    int32 with no cast-back."""
+def test_infer_rule_takes_a_share_and_keeps_the_routers_width():
+    out = _infer((-1, 32, D), (D, E), (2, D, 2 * F), (2, F, D),
+                 bias=(E,), router="sigmoid", expert_offset=6)
+    assert out["Y"][0].shape == (-1, 32, D)
+    assert out["TokensPerExpert"][0].shape == (E,)
+
+
+@pytest.mark.parametrize("kwargs, message", [
+    (dict(expert_offset=7), "holds experts"),
+    (dict(bias=(E + 1,)), "ExpertBias"),
+    (dict(router="tanh"), "neither softmax nor sigmoid"),
+])
+def test_infer_rule_refuses_a_share_or_router_that_cannot_be(kwargs, message):
+    with pytest.raises(InferError, match=message):
+        _infer((4, D), (D, E), (2, D, 2 * F), (2, F, D), **kwargs)
+
+
+@pytest.mark.parametrize("router", ["softmax", "sigmoid"])
+def test_bf16_experts_keep_a_float32_router(router):
+    """Under the AMP pass the op reads X, RouterW and the expert bias in
+    f32 and the experts' weights in bf16: the experts chosen are those of
+    the float32 router, whatever the experts' own precision, and the
+    counts stay int32 with no cast-back."""
     from paddle_tpu import framework, unique_name
+    from paddle_tpu.param_attr import ParamAttr
     from paddle_tpu.transpiler.pass_registry import apply_pass
 
     w = _weights("balanced")
+    sigmoid = router == "sigmoid"
     main, startup = fluid.Program(), fluid.Program()
     with framework.program_guard(main, startup), unique_name.guard():
         x = layers.data("x", shape=[N, D], append_batch_size=False)
-        y, aux, counts = layers.moe_ffn(x, E, F, K)
+        y, aux, counts = layers.moe_ffn(
+            x, E, F, K, router=router,
+            expert_bias_attr=ParamAttr(name="bias") if sigmoid else None)
         apply_pass(main, "bf16_amp_pass")
     (op,) = [o for o in main.global_block().ops if o.type == "moe_ffn"]
     block = main.global_block()
     dtypes = {slot: str(block.var(names[0]).dtype)
               for slot, names in list(op.inputs.items())
               + list(op.outputs.items())}
-    assert dtypes == {"X": "float32", "RouterW": "float32",
-                      "GateUpW": "bfloat16", "DownW": "bfloat16",
-                      "Y": "bfloat16", "TokensPerExpert": "int32",
-                      "AuxLoss": "float32"}
+    assert dtypes == dict({"X": "float32", "RouterW": "float32",
+                           "GateUpW": "bfloat16", "DownW": "bfloat16",
+                           "Y": "bfloat16", "TokensPerExpert": "int32",
+                           "AuxLoss": "float32"},
+                          **({"ExpertBias": "float32"} if sigmoid else {}))
     assert op.outputs["TokensPerExpert"] == [counts.name]
     exe = fluid.Executor(fluid.CPUPlace())
     scope = fluid.Scope()
     with fluid.scope_guard(scope):
         exe.run(startup)
-        router = np.asarray(scope.find_var(op.inputs["RouterW"][0]))
+        router_w = np.asarray(scope.find_var(op.inputs["RouterW"][0]))
+        bias = np.asarray(scope.find_var("bias")) if sigmoid else 0.0
         got_y, got = exe.run(main, feed={"x": w["x"]},
                              fetch_list=[y, counts])
     assert got_y.dtype == np.float32  # the cast-back restores the name
     with jax.default_matmul_precision("highest"):
-        _, top_e = jax.lax.top_k(jax.nn.softmax(
-            jnp.asarray(w["x"]) @ jnp.asarray(router), -1), K)
+        logits = jnp.asarray(w["x"]) @ jnp.asarray(router_w)
+        _, top_e = jax.lax.top_k(
+            jax.nn.sigmoid(logits) + bias if sigmoid
+            else jax.nn.softmax(logits, -1), K)
     np.testing.assert_array_equal(
         got, np.bincount(np.asarray(top_e).reshape(-1), minlength=E))
+
+
+@pytest.mark.parametrize("counts, step", [
+    ([8, 8, 8, 8], [0.0, 0.0, 0.0, 0.0]),      # even load: nothing moves
+    ([16, 8, 8, 0], [-0.1, 0.0, 0.0, 0.1]),    # twice the mean, never chosen
+    ([32, 0, 0, 0], [-0.3, 0.1, 0.1, 0.1]),    # one expert has every row
+])
+def test_expert_bias_update_moves_a_bias_against_its_experts_load(counts,
+                                                                  step):
+    """b += 0.1 * (1 - c / mean(c)), in float32, for any share held."""
+    from paddle_tpu.core.registry import LowerCtx, get_op
+
+    bias = jnp.asarray([0.05, -0.1, 0.0, 0.2], jnp.float32)
+    out = get_op("expert_bias_update").lower(
+        LowerCtx(), {"ExpertBias": [bias],
+                     "TokensPerExpert": [jnp.asarray(counts, jnp.int32)]},
+        {})["ExpertBiasOut"][0]
+    assert out.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(out - bias), step, atol=1e-7)
+
+
+def test_expert_bias_update_infer_rule():
+    class Op:
+        attrs = {}
+
+    rule = get_infer_rule("expert_bias_update").fn
+    bias = VarInfo((E,), "float32")
+    out = rule(Op, {"ExpertBias": [bias],
+                    "TokensPerExpert": [VarInfo((E,), "int32")]})
+    assert out["ExpertBiasOut"][0] is bias
+    with pytest.raises(InferError, match="differ"):
+        rule(Op, {"ExpertBias": [bias],
+                  "TokensPerExpert": [VarInfo((E + 1,), "int32")]})

@@ -1166,38 +1166,48 @@ def test_every_kernel_lowers_for_tpu_without_a_chip(monkeypatch):
         flags.set_flags({"kernel_autotune": old})
 
 
-def test_grouped_matmul_lowers_for_tpu_without_a_chip(monkeypatch):
-    """moe_ffn's grouped matmuls are megablox's Pallas kernels on the chip
-    (ops/moe_ops.grouped_matmul chooses by platform and shape): cross-lower
-    the op's forward and backward at a tile-aligned size for the TPU
-    platform on this host, as the test above does for the repo's own
-    kernels.  Six Mosaic calls: two matmuls forward, and for each the
-    rows' gradient (gmm over rhs^T) and the weights' (tgmm)."""
+@pytest.mark.parametrize("held, router", [(8, "softmax"), (2, "sigmoid")],
+                         ids=["all_experts", "a_chips_share"])
+def test_grouped_matmul_lowers_for_tpu_without_a_chip(held, router):
+    """moe_ffn's grouped matmuls are megablox's Pallas kernels on a step
+    placed on the chip (ops/moe_ops.grouped_matmul chooses by the placed
+    platform, LowerCtx.platform, and shape): cross-lower the op's forward
+    and backward at a tile-aligned size for the TPU platform on this host,
+    as the test above does for the repo's own kernels.  Six Mosaic calls:
+    two matmuls forward, and for each the rows' gradient (gmm over rhs^T)
+    and the weights' (tgmm); the same six when the op holds 2 of the 8
+    experts its sigmoid router chooses among."""
     from paddle_tpu.core.registry import LowerCtx
     from paddle_tpu.ops import moe_ops
     from paddle_tpu.ops import kernel_tuning as kt
 
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    on_chip = LowerCtx(platform="tpu")
     n, d, f, e, k = 256, 256, 128, 8, 2
     assert moe_ops._megablox_fits(
-        jax.ShapeDtypeStruct((n * k, d), jnp.bfloat16),
-        jax.ShapeDtypeStruct((e, d, 2 * f), jnp.bfloat16))
+        on_chip, jax.ShapeDtypeStruct((n * k, d), jnp.bfloat16),
+        jax.ShapeDtypeStruct((held, d, 2 * f), jnp.bfloat16))
     assert not moe_ops._megablox_fits(
-        jax.ShapeDtypeStruct((n * k - 8, d), jnp.bfloat16),
-        jax.ShapeDtypeStruct((e, d, 2 * f), jnp.bfloat16))
+        on_chip, jax.ShapeDtypeStruct((n * k - 8, d), jnp.bfloat16),
+        jax.ShapeDtypeStruct((held, d, 2 * f), jnp.bfloat16))
+    # the same step placed on this host keeps ragged_dot
+    assert not moe_ops._megablox_fits(
+        LowerCtx(platform="cpu"),
+        jax.ShapeDtypeStruct((n * k, d), jnp.bfloat16),
+        jax.ShapeDtypeStruct((held, d, 2 * f), jnp.bfloat16))
 
     def loss(x, wr, wgu, wd):
         out = moe_ops._moe_ffn(
-            LowerCtx(), {"X": [x], "RouterW": [wr], "GateUpW": [wgu],
-                         "DownW": [wd]}, {"top_k": k})
+            on_chip, {"X": [x], "RouterW": [wr], "GateUpW": [wgu],
+                      "DownW": [wd]},
+            {"top_k": k, "router": router, "expert_offset": e - held})
         return out["Y"][0].astype(jnp.float32).sum() + out["AuxLoss"][0].sum()
 
     before = kt.attribution()["pallas_hits"].get("grouped_matmul", 0)
     lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).trace(
         jax.ShapeDtypeStruct((n, d), jnp.float32),
         jax.ShapeDtypeStruct((d, e), jnp.float32),
-        jax.ShapeDtypeStruct((e, d, 2 * f), jnp.bfloat16),
-        jax.ShapeDtypeStruct((e, f, d), jnp.bfloat16),
+        jax.ShapeDtypeStruct((held, d, 2 * f), jnp.bfloat16),
+        jax.ShapeDtypeStruct((held, f, d), jnp.bfloat16),
     ).lower(lowering_platforms=("tpu",))
     assert lowered.as_text().count("tpu_custom_call") == 6
     assert kt.attribution()["pallas_hits"]["grouped_matmul"] == before + 2

@@ -165,6 +165,26 @@ def case_olmoe_train_amp():
     return main, {"fetches": [v.name for v in fetches]}
 
 
+def case_lfm2_train_amp():
+    """Short-conv and GQA layers by `layer_types`, a dense layer, a chip's
+    share of sigmoid-routed experts, per-head QK-norm, a tied head, under
+    the bf16 AMP pass."""
+    from paddle_tpu.models import lfm2
+
+    class L(lfm2.LFM2MoEConfig):
+        vocab_size, hidden_size = 64, 32
+        intermediate_size, moe_intermediate_size = 48, 16
+        num_hidden_layers, num_dense_layers = 3, 1
+        layer_types = ["conv", "full_attention", "conv"]
+        num_attention_heads, num_key_value_heads = 2, 1
+        num_experts, num_experts_per_tok = 4, 2
+        num_local_experts, expert_offset = 2, 2
+
+    main, _s, _f, fetches = lfm2.lfm2_lm_program(L, seq_len=SEQ,
+                                                 use_bf16=True)
+    return main, {"fetches": [v.name for v in fetches]}
+
+
 def case_gpt2_decode():
     from paddle_tpu.models import gpt2
 
@@ -290,6 +310,7 @@ CASES = [
     ("tfm_remat", case_tfm_remat, False),
     ("gpt2_train_fused", case_gpt2_train, False),
     ("olmoe_train_amp", case_olmoe_train_amp, False),
+    ("lfm2_train_amp", case_lfm2_train_amp, False),
     ("gpt2_decode_step", case_gpt2_decode, True),
     ("gpt2_ragged_serving", case_gpt2_ragged, True),
     ("gpt2_ragged_serving_tp", case_gpt2_ragged_tp, True),
