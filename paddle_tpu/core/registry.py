@@ -127,9 +127,22 @@ class LowerCtx:
         the executor advances every run) is always in the mix so seeded
         dropout still varies per step; a nonzero `seed` attr replaces the
         op-position fold so ops sharing a seed share a stream (reference
-        per-op seed-attr semantics)."""
+        per-op seed-attr semantics).  Which generator the key draws from
+        is the Executor's choice (Executor._rng_impl: by the platform the
+        step is placed on); kernel_tuning.attribution()["rng_draws"]
+        counts the requests by it, at trace time (a context without a
+        step key, as shape inference's, draws from a placeholder and is
+        not counted)."""
         seed = int(attrs.get("seed", 0)) if attrs else 0
-        key = self.rng_key if self.rng_key is not None else jax.random.PRNGKey(0)
+        key = self.rng_key
+        if key is None:
+            key = jax.random.PRNGKey(0)
+        else:
+            from ..ops.kernel_tuning import note_rng_draw
+
+            typed = jnp.issubdtype(key.dtype, jax.dtypes.prng_key)
+            note_rng_draw(
+                str(jax.random.key_impl(key)) if typed else "threefry")
         if seed:
             key = jax.random.fold_in(key, seed)
         else:

@@ -456,8 +456,8 @@ class ExecutionCache:
 
     def get(self, program, block_idx, feed_sig, fetch_names, scope, donate=True,
             platform=None):
-        # flags that change lowering decisions are part of the compile key —
-        # toggling them must recompile, not hit a stale executable
+        # a flag that changes lowering decisions is part of the compile
+        # key — toggling it must recompile, not hit a stale executable
         from ..flags import get_flag
 
         key = (
@@ -468,7 +468,6 @@ class ExecutionCache:
             tuple(fetch_names),
             id(scope),
             bool(get_flag("use_pallas")),
-            get_flag("prng_impl"),
         )
         hit = self._cache.get(key)
         if hit is not None:

@@ -153,16 +153,25 @@ class Executor:
         with RecordEvent("fetch_to_host"):
             return [to_numpy(f) for f in fetches]
 
-    def _rng_base(self, program):
+    @staticmethod
+    def _rng_impl(platform):
+        """Which generator a step's in-program randomness (dropout,
+        *_random, sampling) draws from follows the platform it is placed
+        on: a TPU-placed step uses XLA's RngBitGenerator (a typed `rbg`
+        key: one HLO op a draw, where threefry hashes ~58 VPU operations a
+        mask element that XLA fuses into the matmuls around a dropout);
+        any other platform, and a path that states none (collective and
+        pipeline steps handle a raw key), keeps the raw threefry key, so
+        every CPU stream is what it always was.  Same distribution either
+        way; a seed's stream differs between a CPU and a TPU."""
+        return "rbg" if platform == "tpu" else "threefry"
+
+    def _rng_base(self, program, platform=None):
         # base key derives from the program's seed (per-program, so
         # main_program.random_seed is honored even after the startup run).
-        # FLAGS_prng_impl=rbg swaps the generator for the TPU-cheap
-        # hardware RBG (typed key so fold_in/bernoulli work unchanged);
-        # the default stays raw threefry for exact stream back-compat.
-        from .flags import get_flag
-
+        # The rbg key is typed, so fold_in/bernoulli work unchanged.
         seed = int(program.random_seed)
-        impl = get_flag("prng_impl")
+        impl = self._rng_impl(platform)
         base = self._key_cache.get((seed, impl))
         if base is None:
             s = seed if seed != 0 else 90157
@@ -173,7 +182,7 @@ class Executor:
             self._key_cache[(seed, impl)] = base
         return base
 
-    def _rng_key(self, program):
+    def _rng_key(self, program, platform=None):
         # folding in the step counter advances streams across runs.  The
         # fold is jitted: eagerly it binds ~6 primitives of host dispatch
         # per step (profiled at ~1ms on CPU — comparable to the whole
@@ -184,7 +193,7 @@ class Executor:
         if fold is None:
             fold = self._fold_fn = jax.jit(
                 lambda k, s: jax.random.fold_in(k, s))
-        key = fold(self._rng_base(program), np.uint32(self._step))
+        key = fold(self._rng_base(program, platform), np.uint32(self._step))
         self._step += 1
         return key
 
@@ -287,14 +296,13 @@ class Executor:
 
     @staticmethod
     def _fast_entry_holds(entry, feed):
-        """The fast path's preconditions: lowering flags as recorded, and
-        every feed a plain array of the recorded (shape, dtype) — a
+        """The fast path's preconditions: the lowering flag as recorded,
+        and every feed a plain array of the recorded (shape, dtype) — a
         LoDTensor or list feed takes the slow path."""
         from .flags import get_flag
 
-        if (bool(get_flag("use_pallas")),
-                get_flag("prng_impl")) != entry["flags"]:
-            return False  # lowering flags flipped: recompile path
+        if bool(get_flag("use_pallas")) != entry["use_pallas"]:
+            return False  # lowering flag flipped: recompile path
         spec = entry["feed_spec"]
         return all(
             isinstance(value, (np.ndarray, jax.Array))
@@ -323,7 +331,8 @@ class Executor:
             lambda n: self._commit_state(n, scope.find_var(n), device, scope),
             traced.ro_names, traced.rw_names)
         return self._finish_run(compiled, feed_arrays, ro_state, rw_state,
-                                program, fetch_names, scope, return_numpy)
+                                program, fetch_names, scope, return_numpy,
+                                device)
 
     def _maybe_verify_program(self, program, feed, fetch_names, scope):
         """Verify-before-first-run (FLAGS_check_program): the program
@@ -421,18 +430,18 @@ class Executor:
                     n: (tuple(v.shape), str(v.dtype))
                     for n, v in feed.items()
                 },
-                "flags": (bool(get_flag("use_pallas")),
-                          get_flag("prng_impl")),
+                "use_pallas": bool(get_flag("use_pallas")),
             }
 
         return self._finish_run(compiled, feed_arrays, ro_state, rw_state,
-                                program, fetch_names, scope, return_numpy)
+                                program, fetch_names, scope, return_numpy,
+                                device)
 
     def _finish_run(self, compiled, feed_arrays, ro_state, rw_state,
-                    program, fetch_names, scope, return_numpy):
+                    program, fetch_names, scope, return_numpy, device):
         from .flags import get_flag
 
-        key = self._rng_key(program)
+        key = self._rng_key(program, device.platform)
         timed = get_flag("benchmark")
         t0 = time.time() if timed else None
         fetches, new_state = self._dispatch(
@@ -538,7 +547,7 @@ class Executor:
             cache = self._spmd_cache = {}
         key_id = (id(program), program._version, feed_sig,
                   tuple(fetch_names), id(scope),
-                  bool(get_flag("use_pallas")), get_flag("prng_impl"))
+                  bool(get_flag("use_pallas")))
         entry = cache.get(key_id)
         if entry is None:
             from .core.trace import build_traced_function
@@ -583,7 +592,8 @@ class Executor:
 
         ro_state, rw_state = self._gather(commit, traced.ro_names,
                                           traced.rw_names)
-        key = jax.device_put(self._rng_key(program), repl)
+        key = jax.device_put(
+            self._rng_key(program, mesh.devices.flat[0].platform), repl)
         args = (feed_arrays, ro_state, rw_state, key)
         first = avals[0] is None
         if first:
@@ -638,7 +648,7 @@ class Executor:
             cache = self._pipeline_cache = {}
         key_id = (id(program), program._version, feed_sig,
                   tuple(fetch_names), id(scope),
-                  bool(get_flag("use_pallas")), get_flag("prng_impl"))
+                  bool(get_flag("use_pallas")))
         entry = cache.get(key_id)
         first = entry is None
         if first:
@@ -770,7 +780,6 @@ class Executor:
         DistributedExecutor path instead)."""
         from jax.sharding import NamedSharding, PartitionSpec
 
-        from .flags import get_flag
         from .parallel.mesh import shard_map
 
         # collective programs get the same verify-before-first-run as
@@ -778,11 +787,6 @@ class Executor:
         self._maybe_verify_program(program, feed, fetch_names, scope)
 
         axis, nranks = str(coll["axis"]), int(coll["nranks"])
-        if get_flag("prng_impl") != "threefry":
-            raise NotImplementedError(
-                "collective mode replicates the raw threefry step key "
-                "across the mesh; FLAGS_prng_impl=%s is not supported "
-                "here" % get_flag("prng_impl"))
         if any(op.type == "read" for op in program.global_block().ops):
             raise ValueError(
                 "collective mode feeds arrays directly; in-program "
@@ -891,6 +895,8 @@ class Executor:
 
         ro_state, rw_state = self._gather(commit, traced.ro_names,
                                           traced.rw_names)
+        # a raw threefry key whatever the mesh's platform: to_mesh
+        # replicates it through host numpy
         key = to_mesh(self._rng_key(program), PartitionSpec())
         fetches, new_state = self._dispatch(
             jitted, (feed_arrays, ro_state, rw_state, key),
@@ -963,7 +969,6 @@ class Executor:
         cache_key = (
             id(program), program._version, feed_sig, tuple(fetch_names),
             iters, id(scope), bool(get_flag("use_pallas")),
-            get_flag("prng_impl"),
         )
         hit = getattr(self, "_loop_cache", None)
         if hit is None:
@@ -1019,7 +1024,7 @@ class Executor:
         }
         # EXACT run() stream parity: iteration i uses fold_in(base,
         # step0 + i) — the same key i sequential run() calls would draw
-        base = self._rng_base(program)
+        base = self._rng_base(program, device.platform)
         step0 = self._step
         self._step += iters
         keys = jax.vmap(lambda i: jax.random.fold_in(base, i))(

@@ -303,12 +303,6 @@ DEFINE_flag("check_program", False,
             "tests/CI (conftest + scripts/ci.sh arm it); OFF by default "
             "in production hot paths — disabled, the check is a single "
             "flag read, zero per-step cost")
-DEFINE_flag("prng_impl", "threefry",
-            "JAX PRNG for in-program randomness (dropout, *_random, "
-            "sampling): 'threefry' (default; splittable counter stream, "
-            "exact back-compat) or 'rbg' (TPU hardware generator — much "
-            "cheaper mask generation in dropout-heavy models; different "
-            "stream, same distribution).  Toggling recompiles.")
 DEFINE_flag("tpu_bf16_matmul", False,
             "reserved: AMP is the explicit contrib.mixed_precision."
             "rewrite_bf16() program rewrite, not a global flag yet")

@@ -49,6 +49,7 @@ _STATS_ZERO = {"hits": 0, "misses": 0, "searches": 0, "search_ms": 0.0,
 _stats = dict(_STATS_ZERO)
 _kernel_hits = {}  # family -> pallas dispatch count (trace-time)
 _dense_vjp_hits = {}  # family -> hand-written plain-XLA VJP engagements
+_rng_draws = {}  # generator ("rbg" / "threefry") -> draw sites traced
 _searching = threading.local()  # candidate timing in flight on this thread
 _inflight = {}  # key -> threading.Event: a measured search under way
 
@@ -317,13 +318,22 @@ def note_dense_vjp(family):
         _dense_vjp_hits[family] = _dense_vjp_hits.get(family, 0) + 1
 
 
+def note_rng_draw(impl):
+    """Count a trace-time request of a key by a randomness-consuming op
+    (LowerCtx.rng), by the generator the key draws from."""
+    with _lock:
+        _rng_draws[impl] = _rng_draws.get(impl, 0) + 1
+
+
 def attribution():
-    """Snapshot for bench attribution: per-family pallas-hit counts plus
-    tuning-cache hit/miss/search totals (search_ms summed)."""
+    """Snapshot for bench attribution: per-family pallas-hit counts,
+    in-program random draws by generator, plus tuning-cache
+    hit/miss/search totals (search_ms summed)."""
     with _lock:
         return {
             "pallas_hits": dict(_kernel_hits),
             "dense_vjp_hits": dict(_dense_vjp_hits),
+            "rng_draws": {"rbg": 0, "threefry": 0, **_rng_draws},
             "tuning": {k: (round(v, 3) if isinstance(v, float) else v)
                        for k, v in _stats.items()},
         }
@@ -333,6 +343,7 @@ def reset_attribution():
     with _lock:
         _kernel_hits.clear()
         _dense_vjp_hits.clear()
+        _rng_draws.clear()
         _stats.update(_STATS_ZERO)
 
 

@@ -478,8 +478,8 @@ def _dropout(ctx, ins, attrs):
     mb = current_microbatch_rows()
     if mb is not None and x.ndim >= 1:
         # pipeline microbatch: draw the mask over the FULL global batch
-        # rows (bit-identical to the unpipelined trace — threefry is
-        # counter-based per position) and slice this microbatch's window
+        # rows (bit-identical to the unpipelined trace: the same key and
+        # shape give the same bits) and slice this microbatch's window
         total_rows, row_offset = mb
         keep = jax.random.bernoulli(
             ctx.rng(attrs), 1.0 - p, (total_rows,) + tuple(x.shape[1:])

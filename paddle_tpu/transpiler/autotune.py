@@ -24,8 +24,6 @@ level up, to knobs that select between whole PROGRAMS:
                           reason; FLAGS_hbm_budget_bytes forces it
                           outside the tuner when memory, not time, is
                           the binding constraint)
-* ``prng_impl``         — threefry vs the hardware RBG stream for
-                          dropout-heavy programs (flag knob)
 * ``use_pallas``        — kernel-layer dispatch on/off; searched on a
                           real accelerator only (interpret-mode timings
                           are noise), and each timed candidate consults
@@ -99,7 +97,6 @@ DEFAULT_DECISION = {
     #                              (dp-only sharding via the batch feeds)
     "bf16_amp": False,
     "remat": 0,
-    "prng_impl": "threefry",
     "use_pallas": None,          # None = inherit FLAGS_use_pallas
     "steps_per_dispatch": 1,
     "comm_bucket_bytes": None,   # consult-only knob
@@ -120,7 +117,7 @@ DEFAULT_DECISION = {
 # flag knob runs under) — the mesh before the rewrites that must compose
 # with it — dispatch-schedule last
 _KNOB_ORDER = ("mesh_shape", "rule_table", "bf16_amp", "remat",
-               "prng_impl", "use_pallas", "steps_per_dispatch")
+               "use_pallas", "steps_per_dispatch")
 
 _lock = threading.RLock()
 _cache = None
@@ -206,7 +203,7 @@ def tuned_flags(decision):
     program (flag knobs only; rebuild knobs are baked into the program
     the ``variants`` callback returned, and steps_per_dispatch is the
     driver's run()/run_loop() choice)."""
-    out = {"prng_impl": decision.get("prng_impl", "threefry")}
+    out = {}
     if decision.get("use_pallas") is not None:
         out["use_pallas"] = bool(decision["use_pallas"])
     return out
@@ -276,8 +273,6 @@ def _candidates_for(knob, rebuild, program, best=None):
             return []
         n = max(0, len(detect_segments(program)) - 1)
         return [0, n] if n else []
-    if knob == "prng_impl":
-        return ["threefry", "rbg"]
     if knob == "use_pallas":
         from ..ops.pallas_kernels import _interpret
 
@@ -295,7 +290,7 @@ def _measure_decision(decision, program, startup, feed_spec, fetches,
 
     from .. import executor as executor_mod
     from ..core import scope as scope_mod
-    from ..flags import flag_items, set_flags
+    from ..flags import get_flag, set_flags
     from ..places import default_place
 
     main, startup_p, fetch_list = program, startup, fetches
@@ -305,7 +300,7 @@ def _measure_decision(decision, program, startup, feed_spec, fetches,
                                 or decision.get("rule_table",
                                                 "family") != "family"):
         main, startup_p, fetch_list = rebuild(decision)
-    saved = flag_items()
+    saved = get_flag("use_pallas")
     set_flags(tuned_flags(decision))
     try:
         scope = scope_mod.Scope()
@@ -340,8 +335,7 @@ def _measure_decision(decision, program, startup, feed_spec, fetches,
             jax.block_until_ready(out)
             return steps / (time.perf_counter() - t0)
     finally:
-        set_flags({k: saved[k] for k in
-                   ("prng_impl", "use_pallas") if k in saved})
+        set_flags({"use_pallas": saved})
 
 
 def tune(program, feed_spec, startup=None, fetches=None, rebuild=None,

@@ -567,46 +567,48 @@ def test_op_coverage_vs_reference():
     assert proc.returncode == 0, proc.stdout + proc.stderr
 
 
-def test_prng_impl_flag_rbg():
-    """FLAGS_prng_impl=rbg swaps the in-program generator for the TPU
-    hardware RBG: dropout still masks at ~rate with correct scaling, the
+def test_generator_follows_the_place_not_a_flag(monkeypatch):
+    """Which generator draws in-program randomness is Executor._rng_impl's
+    answer for the platform the step is placed on (rbg on a TPU, the raw
+    threefry key elsewhere), not a flag.  With the TPU's answer forced on
+    this host: dropout still masks at ~rate with correct scaling, the
     run()/run_loop() stream parity contract holds (both draw
     fold_in(base, step)), and the stream differs from threefry's."""
     import numpy as np
     import paddle_tpu as fluid
     from paddle_tpu import flags, layers
 
+    assert "prng_impl" not in flags.flag_items()
     x = np.ones((64, 256), dtype="float32")
 
     def masked(impl):
-        flags.set_flags({"prng_impl": impl})
-        try:
-            main, startup = fluid.Program(), fluid.Program()
-            with fluid.framework.program_guard(main, startup):
-                inp = layers.data("x", shape=[256])
-                out = layers.dropout(inp, dropout_prob=0.4)
-            scope = fluid.Scope()
-            with fluid.scope_guard(scope):
-                exe = fluid.Executor(fluid.CPUPlace())
-                exe.run(startup)
-                (v,) = exe.run(main, feed={"x": x}, fetch_list=[out])
-                # run_loop must draw the SAME per-step keys as run()
-                exe2 = fluid.Executor(fluid.CPUPlace())
-                exe2.run(startup)  # align step counters with exe
-                (v_loop,) = exe2.run_loop(1, main, feed={"x": x},
-                                          fetch_list=[out])
-            return np.asarray(v), np.asarray(v_loop)
-        finally:
-            flags.set_flags({"prng_impl": "threefry"})
+        monkeypatch.setattr(fluid.Executor, "_rng_impl",
+                            staticmethod(lambda platform: impl))
+        main, startup = fluid.Program(), fluid.Program()
+        with fluid.framework.program_guard(main, startup):
+            inp = layers.data("x", shape=[256])
+            out = layers.dropout(inp, dropout_prob=0.4)
+        scope = fluid.Scope()
+        with fluid.scope_guard(scope):
+            exe = fluid.Executor(fluid.CPUPlace())
+            exe.run(startup)
+            (v,) = exe.run(main, feed={"x": x}, fetch_list=[out])
+            # run_loop must draw the SAME per-step keys as run()
+            exe2 = fluid.Executor(fluid.CPUPlace())
+            exe2.run(startup)  # align step counters with exe
+            (v_loop,) = exe2.run_loop(1, main, feed={"x": x},
+                                      fetch_list=[out])
+        return np.asarray(v), np.asarray(v_loop)
 
     rbg, rbg_loop = masked("rbg")
-    fry, _ = masked("threefry")
+    fry, fry_loop = masked("threefry")
     for v in (rbg, fry):
         rate = float((v == 0).mean())
         assert 0.3 < rate < 0.5, rate
         nz = v[v != 0]
         np.testing.assert_allclose(nz, nz[0], rtol=1e-6)  # 1/(1-p) scale
     np.testing.assert_allclose(rbg, rbg_loop)
+    np.testing.assert_allclose(fry, fry_loop)
     assert (rbg == 0).sum() != 0 and not np.array_equal(rbg == 0, fry == 0)
 
 

@@ -533,12 +533,10 @@ def test_autotune_search_cache_and_consult_only(tmp_path):
     main, _ = _mini_program()
     spec = {"at_x": ((4, 4), "float32")}
 
-    # injected measurer: rbg + window 8 is the planted optimum; the
-    # greedy search must find it and persist the decision
+    # injected measurer: window 8 is the planted optimum; the greedy
+    # search must find it and persist the decision
     def measure(decision):
         sps = 100.0
-        if decision.get("prng_impl") == "rbg":
-            sps += 50.0
         if decision.get("steps_per_dispatch", 1) > 1:
             sps += 25.0
         if decision.get("bf16_amp"):
@@ -551,9 +549,15 @@ def test_autotune_search_cache_and_consult_only(tmp_path):
     flags.set_flags({"program_tune_cache": path, "program_autotune": 1})
     try:
         d = at.tune(main, spec, measure=measure)
-        assert d["prng_impl"] == "rbg"
         assert d["steps_per_dispatch"] == 8
         assert d["bf16_amp"] is False
+        # the generator is no knob: it follows the place the step runs
+        # on (Executor._rng_impl), so no decision names it and the
+        # tuner has no flag to set for it
+        assert "prng_impl" not in d and "prng_impl" not in at._KNOB_ORDER
+        assert at.tuned_flags(d) == {}
+        assert at.tuned_flags(dict(d, use_pallas=True)) == {
+            "use_pallas": True}
         # hit path: no measurer needed
         d2 = at.tune(main, spec)
         assert d2 == d
@@ -602,7 +606,7 @@ def test_ci_pinned_program_tune_cache_consults_without_search():
         d = at.tune(main, {"at_x": ((4, 4), "float32")})
         # the committed searched decision (see tests/data/README note)
         assert d["steps_per_dispatch"] == 8, d
-        assert d["prng_impl"] == "threefry", d
+        assert set(d) == set(at.DEFAULT_DECISION), d  # no stale knob
         st = at.cache_stats()
         assert st["stats"]["searches"] == 0
         assert st["stats"]["hits"] == 1
