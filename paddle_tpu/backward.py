@@ -68,6 +68,8 @@ def _append_backward_impl(loss, program, block, no_grad, parameter_list):
     # grad contributions: var -> [grad var names]
     contribs = {}
     finalized = {}
+    # grad var name -> the name scope of the op that produced it
+    built_under = {}
 
     def finalize(name):
         """Materialize the single accumulated grad var for `name`."""
@@ -83,7 +85,13 @@ def _append_backward_impl(loss, program, block, no_grad, parameter_list):
         if gname in [x for x in c]:
             gname = unique_name.generate(gname + "_acc")
         _create_grad_var(block, name, gname)
-        block.append_op("sum", inputs={"X": list(c)}, outputs={"Out": [gname]})
+        # the fan-in belongs to its contributions' name scope where they
+        # share one, and to none where they differ (a shared weight's)
+        scopes = {built_under.get(g, "") for g in c}
+        scope = scopes.pop() if len(scopes) == 1 else ""
+        block.append_op("sum", inputs={"X": list(c)}, outputs={"Out": [gname]},
+                        attrs={"op_namescope": scope} if scope else None)
+        built_under[gname] = scope
         finalized[name] = gname
         return gname
 
@@ -159,6 +167,7 @@ def _append_backward_impl(loss, program, block, no_grad, parameter_list):
         opdef = OPS.get(op.type)
         no_grad_slots = opdef.no_grad_inputs if opdef else set()
         gout = {}
+        fwd_scope = op.attrs.get("op_namescope", "")
         for slot, names in op.inputs.items():
             if slot in no_grad_slots:
                 continue
@@ -177,6 +186,7 @@ def _append_backward_impl(loss, program, block, no_grad, parameter_list):
                 gname = unique_name.generate(grad_var_name(n))
                 _create_grad_var(block, n, gname)
                 contribs.setdefault(n, []).append(gname)
+                built_under[gname] = fwd_scope
                 outs.append(gname)
                 produce = True
             if produce:
@@ -191,6 +201,8 @@ def _append_backward_impl(loss, program, block, no_grad, parameter_list):
             inputs=gin,
             outputs=gout,
             attrs={
+                # a grad op belongs to its forward op's name scope
+                **({"op_namescope": fwd_scope} if fwd_scope else {}),
                 "__fwd_type__": op.type,
                 "__fwd_attrs__": dict(op.attrs),
                 "__fwd_in_slots__": list(op.inputs.keys()),

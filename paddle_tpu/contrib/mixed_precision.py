@@ -156,6 +156,7 @@ def rewrite_bf16(program=None, ops=_BF16_OPS, dtype="bfloat16"):
             and op.attrs.get("op_role", "forward") == "forward"
         ):
             count += 1
+            n_before = len(new_ops)
             keep_f32 = _KEEP_F32_SLOTS.get(op.type, ())
             for slot, names in list(op.inputs.items()):
                 if slot in keep_f32:
@@ -163,6 +164,8 @@ def rewrite_bf16(program=None, ops=_BF16_OPS, dtype="bfloat16"):
                 op.inputs[slot] = [
                     cast_var(n, dtype, tag) for n in names
                 ]
+            # the casts made for this op belong to its name scope
+            framework.inherit_namescope(op, *new_ops[n_before:])
             new_ops.append(op)
             # cast outputs back to f32, keeping downstream names intact:
             # the op writes <out>@RAW_BF16 and a cast restores <out>
@@ -177,6 +180,7 @@ def rewrite_bf16(program=None, ops=_BF16_OPS, dtype="bfloat16"):
                     restored.append((slot, raw, cast_back))
                 op.outputs[slot] = [r[1] for r in restored]
                 for _, _, cb in restored:
+                    framework.inherit_namescope(op, cb)
                     new_ops.append(cb)
                     # cast-back redefines the original name: a later bf16
                     # cast of it must re-derive from the new value
@@ -286,6 +290,7 @@ def propagate_half_through_trunk(program, dtype="bfloat16"):
             for s in out_slots:
                 for i, n in enumerate(list(op.outputs.get(s, []))):
                     raw, cb = _emit_raw_and_castback(block, n, dtype, tag)
+                    framework.inherit_namescope(op, cb)
                     op.outputs[s][i] = raw
                     new_ops.append(cb)
                     castback_src[n] = raw

@@ -305,16 +305,26 @@ def build_traced_function(program, block_idx, feed_names, fetch_names, scope,
             `<op_role>/<op type>/<index in its block>`: the scope reaches
             the optimized HLO's `op_name` and the device trace, so a
             fused instruction can be traced back to the Fluid ops in it.
-            Sub-block ops nest under their parent op's scope.  Scopes act
-            at trace time only: a compiled step pays nothing for them."""
+            Sub-block ops nest under their parent op's scope.  An op built
+            under `fluid.name_scope`s carries them as one more nested part
+            of the same form, `<op_role>/<the scopes joined by ".">/<how
+            many>` ("forward/fc/12/forward/ut2.layer0/2"), so whatever
+            reads `<role>/<type>/<index>` parts reads this one too; an op
+            built under none keeps the path it had.  Scopes act at trace
+            time only: a compiled step pays nothing for them."""
             blk = program.block(bidx)
             for idx, op in enumerate(blk.ops):
                 if op.type in ("feed", "fetch", "read", "create_py_reader"):
                     continue  # satisfied as implicit feeds / host state
                 if bidx == block_idx and not keep[idx]:
                     continue
-                with jax.named_scope("%s/%s/%d" % (
-                        op.attrs.get("op_role", "forward"), op.type, idx)):
+                role = op.attrs.get("op_role", "forward")
+                path = "%s/%s/%d" % (role, op.type, idx)
+                built_under = op.attrs.get("op_namescope")
+                if built_under:
+                    parts = built_under.split("/")
+                    path += "/%s/%s/%d" % (role, ".".join(parts), len(parts))
+                with jax.named_scope(path):
                     env = trace_op(blk, bidx, idx, op, env)
             return env
 

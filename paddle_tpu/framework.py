@@ -17,6 +17,7 @@ import collections
 import contextlib
 import copy
 import json
+import re
 
 import numpy as np
 
@@ -228,6 +229,10 @@ class Operator:
             rv = getattr(prog, "_op_role_var", None)
             if rv:
                 self.attrs["op_role_var"] = list(rv)
+        # the name scope the op was built under (op_proto_maker's
+        # OpNamescopeAttrName): core/trace.py carries it into the HLO
+        if _name_scope_stack and "op_namescope" not in self.attrs:
+            self.attrs["op_namescope"] = "/".join(_name_scope_stack)
 
     def input_arg_names(self):
         return [n for names in self.inputs.values() for n in names if n]
@@ -676,11 +681,33 @@ _name_scope_stack = []
 
 @contextlib.contextmanager
 def name_scope(prefix=None):
-    _name_scope_stack.append(prefix or "")
+    """Ops built inside carry `op_namescope`: the prefixes of the
+    enclosing name scopes joined by "/".  Grad ops inherit their forward
+    op's, and the scope reaches the lowered HLO (core/trace.py), where
+    nested prefixes are joined by "." and read back as one word: a prefix
+    is made of letters, digits and "_" (another character would drop the
+    op out of every reading of its scope, silently).  An empty prefix adds
+    nothing."""
+    if prefix:
+        if not re.fullmatch(r"\w+", prefix):
+            raise ValueError(
+                "name_scope(%r): a prefix is made of letters, digits and "
+                "\"_\"" % (prefix,))
+        _name_scope_stack.append(prefix)
     try:
         yield
     finally:
-        _name_scope_stack.pop()
+        if prefix:
+            _name_scope_stack.pop()
+
+
+def inherit_namescope(src_op, *new_ops):
+    """Ops a rewrite makes for `src_op` (a fused op for its chain, the
+    casts around an AMP op) belong to the name scope it was built under."""
+    scope = src_op.attrs.get("op_namescope")
+    if scope:
+        for op in new_ops:
+            op.attrs.setdefault("op_namescope", scope)
 
 
 def cpu_places(device_count=None):

@@ -147,6 +147,17 @@ class LayerHelper:
         main_block = self.main_program.global_block()
         startup_block = self.startup_program.global_block()
         shape = [int(s) for s in shape]
+        shared = main_block.vars.get(attr.name)
+        if isinstance(shared, framework.Parameter):
+            # a ParamAttr name a layer has used before shares that layer's
+            # weight: one parameter, initialised once, however many uses
+            if (list(shared.shape) != shape
+                    or shared.dtype != framework._to_dtype_str(dtype)):
+                raise ValueError(
+                    "parameter %r is shared by name as %s %s and asked for "
+                    "again as %s %s" % (attr.name, shared.dtype,
+                                        list(shared.shape), dtype, shape))
+            return shared
         param = main_block.create_parameter(
             shape=shape, dtype=dtype, **{k: v for k, v in attr._to_kwargs().items()}
         )

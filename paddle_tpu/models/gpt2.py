@@ -26,6 +26,7 @@ __all__ = [
     "gpt2_lm",
     "gpt2_lm_program",
     "lm_train_program",
+    "xent_cost",
     "gpt2_logits_program",
     "greedy_generate",
     "greedy_generate_cached",
@@ -173,8 +174,19 @@ def gpt2_lm_program(hp=GPT2Config, seq_len=128, lr=3e-4, is_test=False,
     state), batch feeds over the mesh's dp axis.  No model edits — the
     executor's _run_spmd path picks the stamp up."""
     return lm_train_program(
-        lambda ids: (gpt2_lm(ids, hp, is_test), None), seq_len, lr, is_test,
-        use_bf16, mesh, getattr(hp, "partition_family", "gpt2"))
+        lambda ids, labels: (xent_cost(gpt2_lm(ids, hp, is_test), labels),
+                             None),
+        seq_len, lr, is_test, use_bf16, mesh,
+        getattr(hp, "partition_family", "gpt2"))
+
+
+def xent_cost(logits, labels):
+    """[B, T, vocab] logits and [B, T] labels -> the [B, T, 1]
+    cross-entropy of every token: what a trunk with one set of logits
+    returns as its cost."""
+    return layers.softmax_with_cross_entropy(
+        logits, layers.unsqueeze(labels, [2])
+    )
 
 
 def lm_train_program(trunk, seq_len, lr, is_test, use_bf16, mesh,
@@ -182,9 +194,11 @@ def lm_train_program(trunk, seq_len, lr, is_test, use_bf16, mesh,
     """The causal-LM train-program plumbing every decoder-only builder
     shares (gpt2_lm_program, olmoe.olmoe_lm_program): feeds, the weighted
     token cross-entropy, the fuse passes, AMP, remat, Adam and the mesh
-    stamp.  `trunk(ids)` builds the model and returns ([B, T, vocab]
-    logits, extra) where extra is a scalar var added to the loss (a
-    mixture's router losses) or None."""
+    stamp.  `trunk(ids, labels)` builds the model and returns ([B, T, 1]
+    cost of every token, extra) where extra is a scalar var added to the
+    loss (a mixture's router losses) or None; a trunk with one set of
+    logits ends in `xent_cost(logits, labels)`, ouro's in its expected
+    loss over the exit steps."""
     import paddle_tpu as fluid
 
     main = fluid.Program()
@@ -194,10 +208,7 @@ def lm_train_program(trunk, seq_len, lr, is_test, use_bf16, mesh,
         lbl = layers.data("labels", shape=[seq_len], dtype="int64")
         w = layers.data("loss_weight", shape=[seq_len], dtype="float32")
 
-        logits, extra = trunk(ids)
-        cost = layers.softmax_with_cross_entropy(
-            logits, layers.unsqueeze(lbl, [2])
-        )
+        cost, extra = trunk(ids, lbl)
         cost = layers.elementwise_mul(cost, layers.unsqueeze(w, [2]))
         tokens = layers.reduce_sum(w)
         # epsilon guard: an all-pad batch yields loss 0, never 0/0 NaN

@@ -29,7 +29,7 @@ The train-program plumbing is `gpt2.lm_train_program`;
 
 from .. import framework, layers
 from . import transformer as tfm
-from .gpt2 import _pa, lm_train_program
+from .gpt2 import _pa, lm_train_program, xent_cost
 
 __all__ = ["LFM2MoEConfig", "lfm2_lm", "lfm2_lm_program"]
 
@@ -176,8 +176,9 @@ def lfm2_lm_program(hp=LFM2MoEConfig, seq_len=8192, lr=4e-4, is_test=False,
     """(main, startup, feeds, [loss, token_count]) as gpt2_lm_program
     returns them."""
     main, startup, feeds, fetches = lm_train_program(
-        lambda ids: (lfm2_lm(ids, hp, is_test), None), seq_len, lr, is_test,
-        use_bf16, mesh, hp.partition_family)
+        lambda ids, labels: (xent_cost(lfm2_lm(ids, hp, is_test), labels),
+                             None),
+        seq_len, lr, is_test, use_bf16, mesh, hp.partition_family)
     if hp.use_expert_bias and not is_test:
         _balance_expert_biases(main)
     return main, startup, feeds, fetches

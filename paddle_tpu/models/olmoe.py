@@ -17,7 +17,7 @@ import numpy as np
 
 from .. import layers
 from . import transformer as tfm
-from .gpt2 import _pa, lm_train_program
+from .gpt2 import _pa, lm_train_program, xent_cost
 
 __all__ = ["OLMoEConfig", "olmoe_lm", "olmoe_lm_program"]
 
@@ -88,6 +88,9 @@ def olmoe_lm_program(hp=OLMoEConfig, seq_len=4096, lr=4e-4, is_test=False,
                      use_bf16=False, mesh=None):
     """(main, startup, feeds, [loss, token_count]) as gpt2_lm_program
     returns them; the loss includes the router losses."""
-    return lm_train_program(
-        lambda ids: olmoe_lm(ids, hp, is_test), seq_len, lr, is_test,
-        use_bf16, mesh, hp.partition_family)
+    def trunk(ids, labels):
+        logits, router_loss = olmoe_lm(ids, hp, is_test)
+        return xent_cost(logits, labels), router_loss
+
+    return lm_train_program(trunk, seq_len, lr, is_test, use_bf16, mesh,
+                            hp.partition_family)

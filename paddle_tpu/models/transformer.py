@@ -112,7 +112,7 @@ def multi_head_attention(
     queries, keys, values, attn_bias, d_model, n_head, dropout_rate=0.0,
     is_test=False, cache=None, fused=False, kpad_bias=None, causal=False,
     n_kv_head=None, rotary=False, qk_norm=False, qk_norm_eps=1e-5,
-    rotary_base=10000.0,
+    rotary_base=10000.0, param_attr=None,
 ):
     """All heads in one qkv projection + batched matmuls (MXU-shaped).
     attn_bias: [B, 1 or H, Tq, Tk] additive mask (−1e9 at masked slots).
@@ -153,26 +153,32 @@ def multi_head_attention(
     slot a chunk, a free slot nothing) and masks attention with
     per-row offset-causal cutoffs (fused_attention vector qstart) —
     one dispatch serves a pool of requests at heterogeneous
-    positions."""
+    positions.
+
+    param_attr: `base name -> ParamAttr` for the layer's weights
+    ("mha_q.w", ...); a builder that runs a layer several times over one
+    set of weights gives each use the same names.  The default numbers
+    every call's weights anew."""
+    pa = param_attr or _pa
     dh = d_model // n_head
     n_kv = n_kv_head or n_head
     if n_head % n_kv:
         raise ValueError(
             "n_kv_head (%d) must divide n_head (%d)" % (n_kv, n_head))
     q = layers.fc(queries, size=d_model, num_flatten_dims=2, bias_attr=False,
-                  param_attr=_pa("mha_q.w"))
+                  param_attr=pa("mha_q.w"))
     k = layers.fc(keys, size=n_kv * dh, num_flatten_dims=2, bias_attr=False,
-                  param_attr=_pa("mha_k.w"))
+                  param_attr=pa("mha_k.w"))
     v = layers.fc(values, size=n_kv * dh, num_flatten_dims=2, bias_attr=False,
-                  param_attr=_pa("mha_v.w"))
+                  param_attr=pa("mha_v.w"))
     if qk_norm not in (False, True, "head"):
         raise ValueError("qk_norm is False, True (the whole projection) "
                          "or 'head', got %r" % (qk_norm,))
     if qk_norm and qk_norm != "head":
         q = layers.rms_norm(q, epsilon=qk_norm_eps,
-                            param_attr=_pa("mha_q_norm.w"))
+                            param_attr=pa("mha_q_norm.w"))
         k = layers.rms_norm(k, epsilon=qk_norm_eps,
-                            param_attr=_pa("mha_k_norm.w"))
+                            param_attr=pa("mha_k_norm.w"))
 
     def split_heads(x, heads, norm_attr=None):
         b, t = x.shape[0], x.shape[1]
@@ -193,8 +199,8 @@ def multi_head_attention(
         return layers.reshape(x, [b, n_head, t, dh])
 
     per_head = qk_norm == "head"
-    q = split_heads(q, n_head, _pa("mha_q_norm.w") if per_head else None)
-    k = split_heads(k, n_kv, _pa("mha_k_norm.w") if per_head else None)
+    q = split_heads(q, n_head, pa("mha_q_norm.w") if per_head else None)
+    k = split_heads(k, n_kv, pa("mha_k_norm.w") if per_head else None)
     v = split_heads(v, n_kv)
     if rotary:
         # ragged serving feeds pos_mat [B, W] (per-row positions);
@@ -348,7 +354,7 @@ def multi_head_attention(
     b, t = ctx.shape[0], ctx.shape[1]
     ctx = layers.reshape(ctx, [b, t, d_model])
     return layers.fc(ctx, size=d_model, num_flatten_dims=2, bias_attr=False,
-                     param_attr=_pa("mha_o.w"))
+                     param_attr=pa("mha_o.w"))
 
 
 def positionwise_ffn(x, d_inner, d_model, dropout_rate=0.0, is_test=False):

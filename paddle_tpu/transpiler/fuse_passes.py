@@ -59,6 +59,7 @@ def _replace_chain(block, program, chain, new_ops):
     """Swap a matched chain for new ops at the position of the LAST chain
     op (all producers of the fused inputs are defined by then)."""
     idx = block.ops.index(chain[-1]) - (len(chain) - 1)
+    _fw.inherit_namescope(chain[-1], *new_ops)
     for op in chain:
         block.ops.remove(op)
     for j, op in enumerate(new_ops):
@@ -415,6 +416,7 @@ class SeqexpandConcatFcFusePass(Pass):
                 )
                 # insert at the projection's position (all fused inputs
                 # are defined by then); the chain need not be contiguous
+                _fw.inherit_namescope(proj, fused)
                 block.ops.insert(block.ops.index(proj), fused)
                 for op in chain:
                     block.ops.remove(op)
@@ -651,6 +653,7 @@ class SwigluFusePass(Pass):
                 # insert at the elementwise_mul's slot: every fused
                 # input is defined there; the chain need not be
                 # contiguous
+                _fw.inherit_namescope(emul, fused)
                 block.ops.insert(block.ops.index(emul), fused)
                 for op in (gmul, act, umul, emul):
                     block.ops.remove(op)
@@ -730,6 +733,7 @@ class ResidualLnFusePass(Pass):
                 )
                 # land at the ADD's index (inputs defined there; Sum
                 # defined exactly where it used to be)
+                _fw.inherit_namescope(add, fused)
                 block.ops.insert(block.ops.index(add), fused)
                 block.ops.remove(add)
                 block.ops.remove(ln)
