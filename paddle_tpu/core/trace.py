@@ -371,6 +371,10 @@ def build_traced_function(program, block_idx, feed_names, fetch_names, scope,
                         s: [densify_maybe(v) for v in vals]
                         for s, vals in ins.items()
                     }
+                if spmd is not None:
+                    from ..ops.spmd_epilogue import grad_in_param_storage
+
+                    ins = grad_in_param_storage(op, ins)
                 if opdef is not None:
                     outs = opdef.lower(ctx, ins, op.attrs)
                 elif is_grad:

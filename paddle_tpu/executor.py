@@ -494,12 +494,22 @@ class Executor:
                   spmd):
         """Run a GSPMD-stamped program: ONE traced step jitted with the
         partition-rule table's in/out shardings — XLA's SPMD partitioner
-        emits the collectives (qkv/ffn all-reduces, vocab-sharded logits
-        merge) while the KV slot-pool persistables live SHARDED in HBM
-        (heads axis: pool bytes/device drop ~1/N).  Mesh-aware lowerings
-        (fused_attention's vector-QStart pallas kernel under shard_map,
-        slot_cache_write's sharding constraints) bind through the
-        spmd_lowering context during the trace.
+        emits the collectives (qkv/ffn all-reduces, the row statistics of
+        vocab-sharded logits) while the KV slot-pool persistables live
+        SHARDED in HBM (heads axis: pool bytes/device drop ~1/N).
+        Mesh-aware lowerings (fused_attention's vector-QStart pallas
+        kernel under shard_map, slot_cache_write's sharding constraints)
+        bind through the spmd_lowering context during the trace.
+
+        The in/out shardings are how state is STORED: the rule's spec
+        where every axis divides its dim, replicated where one does not
+        (rules.sharding_for's divisibility guard: a jax.Array argument
+        needs even shards, and the scope keeps the declared shape).  How
+        such a weight is COMPUTED is the lowerings': under a training
+        table lookup_table and fused_linear_xent constrain it to the
+        rule's spec, unevenly, and the optimizer's Grad is constrained
+        back to the stored sharding (ops/spmd_epilogue.rule_sharded_weight
+        / grad_in_param_storage; rules.uneven_log names the weights).
 
         The serving engine's two PR 9 contracts survive unchanged:
         occupancy churn changes feed VALUES only (one compile per feed

@@ -50,6 +50,7 @@ _stats = dict(_STATS_ZERO)
 _kernel_hits = {}  # family -> pallas dispatch count (trace-time)
 _dense_vjp_hits = {}  # family -> hand-written plain-XLA VJP engagements
 _rng_draws = {}  # generator ("rbg" / "threefry") -> draw sites traced
+_uneven_constraints = {}  # op type -> uneven weight constraints placed
 _searching = threading.local()  # candidate timing in flight on this thread
 _inflight = {}  # key -> threading.Event: a measured search under way
 
@@ -325,15 +326,24 @@ def note_rng_draw(impl):
         _rng_draws[impl] = _rng_draws.get(impl, 0) + 1
 
 
+def note_uneven_constraint(op_type):
+    """Count a trace-time sharding constraint that a lowering placed on a
+    weight stored replicated but computed in uneven shards
+    (PartitionRules.compute_spec_for), by the op type that placed it."""
+    with _lock:
+        _uneven_constraints[op_type] = _uneven_constraints.get(op_type, 0) + 1
+
+
 def attribution():
     """Snapshot for bench attribution: per-family pallas-hit counts,
-    in-program random draws by generator, plus tuning-cache
-    hit/miss/search totals (search_ms summed)."""
+    in-program random draws by generator, uneven weight constraints by op
+    type, plus tuning-cache hit/miss/search totals (search_ms summed)."""
     with _lock:
         return {
             "pallas_hits": dict(_kernel_hits),
             "dense_vjp_hits": dict(_dense_vjp_hits),
             "rng_draws": {"rbg": 0, "threefry": 0, **_rng_draws},
+            "uneven_constraints": dict(_uneven_constraints),
             "tuning": {k: (round(v, 3) if isinstance(v, float) else v)
                        for k, v in _stats.items()},
         }
@@ -344,6 +354,7 @@ def reset_attribution():
         _kernel_hits.clear()
         _dense_vjp_hits.clear()
         _rng_draws.clear()
+        _uneven_constraints.clear()
         _stats.update(_STATS_ZERO)
 
 

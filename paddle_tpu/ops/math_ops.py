@@ -15,7 +15,7 @@ import numpy as np
 from ..core.registry import register
 from .common import bcast_y, jdt
 from .kernel_tuning import note_dense_vjp
-from .spmd_epilogue import mesh_ctx
+from .spmd_epilogue import mesh_ctx, rule_sharded_weight
 
 
 # ---------------------------------------------------------------------------
@@ -649,10 +649,18 @@ def _fused_linear_xent_op(ctx, ins, attrs):
     transpose_w is the dots' dimension numbers, not a copy of the table.
     Under a live GSPMD mesh (spmd_epilogue.mesh_ctx) the input is one
     tile and there is no loop: the partitioner would all-reduce a scan's
-    dw carry over dp once per tile.  The engagement counts under
-    kernel_tuning.attribution()["dense_vjp_hits"]["xent"]."""
+    dw carry over dp once per tile.  GSPMD then splits that tile by W's
+    sharding: the stored one where mp divides the vocabulary; where it
+    does not, W arrives replicated and a training step constrains it
+    (forward and the grad op's re-traced forward alike) to the rule's
+    uneven spec (spmd_epilogue.rule_sharded_weight), so a rank holds
+    logits for its share of the columns, all-reduces two row statistics
+    over mp and reduces its rows of dw over dp; a serving table leaves W
+    whole.  The engagement counts under
+    kernel_tuning.attribution()["dense_vjp_hits"]["xent"], a placed
+    constraint under ["uneven_constraints"]["fused_linear_xent"]."""
     x = ins["X"][0]
-    w = ins["W"][0]
+    w = rule_sharded_weight(ctx, ("fused_linear_xent",), "W", ins["W"][0])
     label = ins["Label"][0]
     eps = float(attrs.get("epsilon", 0.0))
     transpose_w = bool(attrs.get("transpose_w", False))

@@ -37,7 +37,8 @@ import jax
 import jax.numpy as jnp
 
 __all__ = [
-    "mesh_ctx", "op_weight_name", "spmd_matmul_bias_act",
+    "mesh_ctx", "op_weight_name", "rule_sharded_weight",
+    "grad_in_param_storage", "spmd_matmul_bias_act",
     "spmd_matmul_swiglu", "spmd_add_layer_norm", "spmd_flash_attention",
 ]
 
@@ -63,23 +64,90 @@ def mesh_ctx():
     return mesh, rules, mp, nsh, dp_axis, ndp
 
 
-def op_weight_name(ctx, expected_type, slot):
-    """The var name feeding `slot` of the op being lowered, resolved
-    through ctx.block + ctx.op_idx ((block_idx << 20) | idx on the
-    forward trace, the plain forward index on the grad-side re-run).
-    None when the context carries no block or the op type disagrees —
-    callers MUST fall back to the unwrapped kernel then."""
+def _lowered_op(ctx, op_types):
+    """The OpDesc being lowered, resolved through ctx.block + ctx.op_idx
+    ((block_idx << 20) | idx on the forward trace, the plain forward
+    index on the grad-side re-run).  None when the context carries no
+    block or the op there is none of `op_types` (a lowering called from
+    another op's)."""
     blk = getattr(ctx, "block", None)
     if blk is None:
         return None
     idx = int(getattr(ctx, "op_idx", 0)) & ((1 << 20) - 1)
-    if idx >= len(blk.ops):
+    if idx >= len(blk.ops) or blk.ops[idx].type not in op_types:
         return None
-    op = blk.ops[idx]
-    if op.type != expected_type:
-        return None
-    names = op.input(slot)
+    return blk.ops[idx]
+
+
+def op_weight_name(ctx, expected_type, slot):
+    """The var name feeding `slot` of the op being lowered (_lowered_op).
+    None when the context carries no block or the op type disagrees —
+    callers MUST fall back to the unwrapped kernel then."""
+    op = _lowered_op(ctx, (expected_type,))
+    names = op.input(slot) if op is not None else ()
     return names[0] if names else None
+
+
+def _uneven(name, shape):
+    """(computed, stored) NamedShardings of a weight that a live
+    TRAINING mesh (the rule table names a dp axis) stores in another
+    sharding than its rule computes it in: the rule names an axis that
+    does not divide the dim, so the divisibility guard stores it
+    replicated (a jax.Array argument needs even shards) while
+    PartitionRules.compute_spec_for still shards the value, unevenly.
+    None everywhere else: no mesh, a serving table (no dp axis), a name
+    with no rule, every dim dividing."""
+    mc = mesh_ctx()
+    if mc is None or not mc[4] or name is None:
+        return None
+    from jax.sharding import NamedSharding
+
+    mesh, rules = mc[0], mc[1]
+    stored = rules.sharding_for(mesh, name, shape)
+    computed = NamedSharding(mesh, rules.compute_spec_for(mesh, name, shape))
+    if computed.is_equivalent_to(stored, len(shape)):
+        return None
+    return computed, stored
+
+
+def rule_sharded_weight(ctx, op_types, slot, w):
+    """`w`, the weight feeding `slot` of the op being lowered (one of
+    `op_types`), constrained to the uneven shards its rule computes it in
+    where storage and computation part (_uneven); without the constraint
+    every rank of the axis computes the whole of a replicated weight.
+    The constraint's transpose puts the weight's gradient in the same
+    shards.  Anywhere else `w` comes back as it is — a dividing dim is
+    stored as it is computed, and a serving step's local gather from a
+    replicated table beats a sharded gather and an all-reduce (pooled ==
+    solo stays bit for bit)."""
+    from .kernel_tuning import note_uneven_constraint
+
+    op = _lowered_op(ctx, op_types)
+    names = op.input(slot) if op is not None else ()
+    found = _uneven(names[0] if names else None, tuple(w.shape))
+    if found is None:
+        return w
+    note_uneven_constraint(op.type)
+    return jax.lax.with_sharding_constraint(w, found[0])
+
+
+def grad_in_param_storage(op, ins):
+    """The boundary back to storage: the dense `Grad` of an optimizer op
+    whose `Param` is computed in uneven shards (_uneven) constrained to
+    the Param's STORED sharding — the one all-gather of the summed
+    gradient.  Left alone, the partitioner runs the update in shards and
+    gathers each of ParamOut and the accumulators instead."""
+    from ..core.selected_rows import SelectedRows
+
+    if not (op.input("Param") and op.input("Grad")):
+        return ins
+    g = ins["Grad"][0]
+    if isinstance(g, SelectedRows):
+        return ins
+    found = _uneven(op.input("Param")[0], tuple(g.shape))
+    if found is None:
+        return ins
+    return dict(ins, Grad=[jax.lax.with_sharding_constraint(g, found[1])])
 
 
 def _dim_has(spec, d, axis):

@@ -13,6 +13,7 @@ import numpy as np
 
 from ..core.registry import register
 from .common import jdt
+from .spmd_epilogue import rule_sharded_weight
 
 
 # ---------------------------------------------------------------------------
@@ -316,7 +317,13 @@ def _scatter(ctx, ins, attrs):
 @register("lookup_table", no_grad_inputs=("Ids",))
 @register("lookup_table_v2", no_grad_inputs=("Ids",))
 def _lookup_table(ctx, ins, attrs):
+    """Rows of W by id.  Under a live training mesh the table is read in
+    the shards its partition rule computes it in (vocabulary over mp,
+    dividing or not): each rank gathers from its rows, the rows sum over
+    mp, and the generic vjp's scatter of lookup_table_grad fills only the
+    rank's rows (spmd_epilogue.rule_sharded_weight)."""
     w, ids = ins["W"][0], ins["Ids"][0]
+    w = rule_sharded_weight(ctx, ("lookup_table", "lookup_table_v2"), "W", w)
     ids = ids.astype(jnp.int32)
     if ids.ndim >= 2 and ids.shape[-1] == 1:
         ids = ids[..., 0]

@@ -58,6 +58,13 @@ class PartitionRules:
       does not divide by its axis size replicates — a 3-kv-head cache
       on a 2-way mesh must not half-shard.
 
+    ``sharding_for`` says how a persistable is STORED (a jax.Array
+    argument needs even shards).  ``compute_spec_for`` says how a value
+    of that name is COMPUTED inside the step: the same rule with the
+    divisibility guard lifted, since a sharding constraint admits uneven
+    shards.  Where the two differ the name is recorded in ``uneven_log``
+    as (name, dim, axis).
+
     Unmatched names fall through to REPLICATED and are logged once per
     name — the registry's contract is that nothing shards silently and
     nothing replicates invisibly."""
@@ -71,6 +78,10 @@ class PartitionRules:
         # same scope names does not grow it unboundedly
         self.replicated_log = []
         self._logged = set()
+        # (name, dim, axis) for every name whose stored sharding (the
+        # divisibility guard's) differs from the spec it is computed in
+        # (compute_spec_for), once per name
+        self.uneven_log = []
 
     def add(self, pattern, spec):
         self.rules.append((pattern, re.compile(pattern), spec))
@@ -122,6 +133,37 @@ class PartitionRules:
                             name, "dim %d !%% %s=%d"
                             % (dim, ax, sizes.get(ax, 1))))
         return NamedSharding(mesh, spec)
+
+    def compute_spec_for(self, mesh, name, shape):
+        """The rule's own PartitionSpec for a VALUE of `name` and `shape`
+        inside a step traced under `mesh`: ``spec_for``'s scalar and rank
+        guards stay, an axis the mesh lacks (or holds at size 1) drops
+        out, and the divisibility guard is lifted — a dim at least as
+        long as its axis is computed in shards whether or not the axis
+        divides it (GPT-2's 50257-row table over mp=2: 25129 rows a
+        rank, the last shard one row short).  The same placement as the
+        stored spec wherever every axis divides."""
+        from .mesh import mesh_axis_sizes
+
+        spec = self.spec_for(name, shape)
+        sizes = mesh_axis_sizes(mesh)
+        entries = []
+        for dim, axes in zip(shape, tuple(spec)):
+            axes = axes if isinstance(axes, tuple) else (axes,)
+            live = tuple(ax for ax in axes
+                         if ax is not None and int(sizes.get(ax, 1)) > 1)
+            n = int(np.prod([sizes[ax] for ax in live])) if live else 1
+            if not live or int(dim) < n:
+                entries.append(None)
+                continue
+            if int(dim) % n and not any(name == e[0]
+                                        for e in self.uneven_log):
+                self.uneven_log.append((name, int(dim), "+".join(live)))
+                log.info("partition_rules: %r stored replicated, computed "
+                         "in uneven shards (dim %d over %s=%d)",
+                         name, dim, "+".join(live), n)
+            entries.append(live[0] if len(live) == 1 else live)
+        return P(*entries)
 
     def match_table(self, named_shapes):
         """Resolve a whole {name: shape} table at once.  Returns
@@ -287,9 +329,16 @@ def _decoder_rules(mp):
         (r"ffn_(in|gate|up)\.w", P(None, mp)),
         (r"ffn_in\.b", P(mp)),
         (r"ffn_out\.w", P(mp, None)),
-        # token embedding vocab-sharded: the tied-embedding logits
-        # matmul (x @ emb.w^T) then emits vocab-sharded logits, same
-        # layout as the untied softmax_out.w below
+        # the token embedding over the vocabulary, like the untied
+        # softmax_out.w below.  STORED in shards where mp divides the
+        # vocabulary: lookup and the tied logits matmul (x @ emb.w^T)
+        # then run on the rank's rows and emit vocab-sharded logits.
+        # Where it does not (GPT-2's 50257 rows over mp=2) the
+        # divisibility guard stores the table, its moments and its bf16
+        # cast replicated; a TRAINING step still computes it in shards
+        # (compute_spec_for: 25129 rows a rank, the gradient reduced over
+        # dp in halves and gathered once on its way to the optimizer),
+        # a serving step reads it whole, as stored
         (r"emb\.w", P(mp, None)),
         (r"softmax_out\.w", P(None, mp)),
         # the serving slot-pool persistables [B, n_kv, T_max, Dh]:

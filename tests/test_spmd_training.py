@@ -139,24 +139,52 @@ def test_epilogue_kernels_dispatch_inside_sharded_step():
     np.testing.assert_allclose(got, dense, rtol=1e-5)
 
 
+def _ctx_of(op_type, **slots):
+    """A LowerCtx that names the op being lowered the way core/trace.py
+    does (ctx.block + ctx.op_idx), so a mesh-aware lowering can resolve
+    its weight's name: one op of `op_type` whose slots hold `slots`."""
+    from types import SimpleNamespace
+
+    from paddle_tpu.core.registry import LowerCtx
+
+    ctx = LowerCtx()
+    ctx.op_idx = 0
+    ctx.block = SimpleNamespace(ops=[SimpleNamespace(
+        type=op_type, input=lambda slot: list(slots.get(slot, ())))])
+    return ctx
+
+
+def _collectives(text, kind, shape):
+    """The `kind` instructions of a compiled step's text whose result or
+    operands carry `shape` ("[33,16]")."""
+    return [ln for ln in text.splitlines()
+            if (" %s(" % kind in ln or " %s-start(" % kind in ln)
+            and shape in ln]
+
+
 @needs_four_devices
+@pytest.mark.parametrize("vocab", [32, 33])
 @pytest.mark.parametrize("eps", [0.0, 0.1])
 @pytest.mark.parametrize("transpose_w", [False, True])
 def test_linear_xent_op_under_dp2_mp2_mesh_equals_unsharded(
-        transpose_w, eps, monkeypatch):
+        transpose_w, eps, vocab, monkeypatch):
     """The vocabulary head's op traced under a live dp2 x mp2 mesh, rows
     over dp and the vocabulary over mp: loss and both gradients equal the
     unsharded op's, which here walks four row tiles; under the mesh the
     input is one tile and the trace holds no loop (a scan's dw carry
-    would be all-reduced over dp once per tile)."""
+    would be all-reduced over dp once per tile).  A vocabulary mp does
+    not divide (33) arrives replicated, as the divisibility guard stores
+    it, and is still computed in shards: the weight's gradient crosses dp
+    in halves, never whole."""
     import jax.numpy as jnp
     from jax.sharding import NamedSharding
 
-    from paddle_tpu.core.registry import LowerCtx, get_op
+    from paddle_tpu.core.registry import get_op
     from paddle_tpu.ops import math_ops
     from paddle_tpu.parallel.partition_rules import spmd_lowering
 
-    B, T, H, V = 4, 8, 16, 32
+    B, T, H, V = 4, 8, 16, vocab
+    wname = "emb.w_0" if transpose_w else "softmax_out.w_0"
     monkeypatch.setattr(math_ops, "_LXENT_TILE_BYTES", 4 * V * B * 2)
     rng = np.random.RandomState(40)
     x = jnp.asarray(rng.randn(B, T, H), jnp.float32)
@@ -169,22 +197,181 @@ def test_linear_xent_op_under_dp2_mp2_mesh_equals_unsharded(
 
     def loss_and_grads(x, w):
         loss, vjp = jax.vjp(lambda x, w: get_op("fused_linear_xent").lower(
-            LowerCtx(), {"X": [x], "W": [w], "Label": [lbl]},
+            _ctx_of("fused_linear_xent", W=[wname]),
+            {"X": [x], "W": [w], "Label": [lbl]},
             {"epsilon": eps, "transpose_w": transpose_w})["Loss"][0], x, w)
         return (loss,) + vjp(dy)
 
     want = jax.jit(loss_and_grads)(x, w)
     assert "scan" in str(jax.make_jaxpr(loss_and_grads)(x, w))
     mesh = make_mesh({"dp": 2, "mp": 2}, jax.devices()[:4])
+    rules = train_partition_rules_for("gpt2")
     xs = jax.device_put(x, NamedSharding(mesh, P("dp", None, None)))
-    ws = jax.device_put(w, NamedSharding(
-        mesh, P("mp", None) if transpose_w else P(None, "mp")))
-    with spmd_lowering(mesh, train_partition_rules_for("gpt2")):
+    ws = jax.device_put(w, rules.sharding_for(mesh, wname, w.shape))
+    assert ws.sharding.spec == (P() if vocab % 2 else
+                                P("mp", None) if transpose_w
+                                else P(None, "mp"))
+    with spmd_lowering(mesh, rules):
         assert "scan" not in str(jax.make_jaxpr(loss_and_grads)(xs, ws))
-        got = jax.jit(loss_and_grads)(xs, ws)
+        step = jax.jit(loss_and_grads).lower(xs, ws).compile()
+    got = step(xs, ws)
     for a, b in zip(got, want):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-5, atol=1e-6)
+    whole = "[%d,%d]" % tuple(w.shape)
+    assert not _collectives(step.as_text(), "all-reduce", whole)
+    assert rules.uneven_log == ([(wname, 33, "mp")] if vocab % 2 else [])
+
+
+# ---------------------------------------------------------------------------
+# storage and computation part where an axis does not divide a dim
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("name,shape,axes,want,uneven", [
+    ("emb.w_0", (64, 32), {"dp": 2, "mp": 2}, P("mp", None), None),
+    ("emb.w_0", (33, 32), {"dp": 2, "mp": 2}, P("mp", None), (33, "mp")),
+    ("emb.w_0_moment1_0", (33, 32), {"dp": 1, "mp": 2}, P("mp", None),
+     (33, "mp")),
+    ("softmax_out.w_0", (32, 33), {"dp": 2, "mp": 2}, P(None, "mp"),
+     (33, "mp")),
+    # the guards that stay: an axis of size 1, a dim shorter than its
+    # axis, rank, scalar, no rule
+    ("emb.w_0", (33, 32), {"dp": 2, "mp": 1}, P(None, None), None),
+    ("emb.w_0", (1, 32), {"dp": 2, "mp": 2}, P(None, None), None),
+    ("emb.w_0_beta1_pow_acc_0", (1,), {"dp": 2, "mp": 2}, P(), None),
+    ("ffn_in.w_0", (33,), {"dp": 2, "mp": 2}, P(), None),
+    ("layer_norm_0.w_0", (33,), {"dp": 2, "mp": 2}, P(), None),
+])
+def test_compute_spec_lifts_the_divisibility_guard_alone(
+        name, shape, axes, want, uneven):
+    """`sharding_for` says how a persistable is stored (even shards or
+    replicated); `compute_spec_for` how its value is computed in a step:
+    the same rule with the divisibility guard lifted.  The two differ
+    exactly for a dim its axis does not divide, and `uneven_log` then
+    names it once."""
+    n = axes["dp"] * axes["mp"]
+    if len(jax.devices()) < n:
+        pytest.skip("needs %d devices" % n)
+    mesh = make_mesh(axes, jax.devices()[:n])
+    rules = train_partition_rules_for("gpt2")
+    got = rules.compute_spec_for(mesh, name, shape)
+    rules.compute_spec_for(mesh, name, shape)  # logged once a name
+    assert got == want
+    stored = rules.sharding_for(mesh, name, shape).spec
+    if uneven is None:
+        assert rules.uneven_log == []
+        assert got == stored or not any(tuple(got))
+    else:
+        assert stored == P() and rules.uneven_log == [(name,) + uneven]
+
+
+class TiedHP(TinyHP):
+    n_layer = 1
+    tie_embeddings = True
+
+
+def _tied_hp(vocab):
+    return type("TiedHP%d" % vocab, (TiedHP,), {"vocab_size": vocab})
+
+
+@needs_four_devices
+@pytest.mark.parametrize("vocab", [33, 32])
+def test_tied_table_step_under_dp2_mp2_mesh(vocab, monkeypatch):
+    """A tied `lookup_table` + head step with its Adam update on the
+    dp2 x mp2 mesh tracks the unsharded run at test_mp2_rtol_parity's
+    tolerance, whatever the vocabulary.
+
+    33 rows (mp does not divide): the table and its moments are stored
+    replicated at the shape the program declares, both of its consumers
+    are constrained to the rule's spec, so no all-reduce carries the
+    whole table, and the summed gradient is gathered ONCE on its way to
+    adam, whose outputs are not gathered.
+
+    32 rows: storage is the computed spec, no constraint is placed and
+    the step lowers to the text it lowers to without the mechanism."""
+    from paddle_tpu.ops import kernel_tuning, math_ops, spmd_epilogue, \
+        tensor_ops
+
+    hp = _tied_hp(vocab)
+    base, _, _, _ = _train(None, steps=3, hp=hp)
+    kernel_tuning.reset_attribution()
+    got, sc, main, exe = _train(make_mesh({"dp": 2, "mp": 2}), steps=3,
+                                hp=hp)
+    np.testing.assert_allclose(got, base, rtol=1e-5)
+    rules = main._spmd["rules"]
+    placed = kernel_tuning.attribution()["uneven_constraints"]
+    table = sc.find_var("emb.w_0")
+    assert table.shape == (vocab, hp.d_model)
+    if vocab % 2:
+        assert _spec_of(sc, "emb.w_0") == ()
+        assert _spec_of(sc, "emb.w_0_moment1_0") == ()
+        assert ("emb.w_0", 33, "mp") in rules.uneven_log
+        # forward + the grad op's re-traced forward, both op types
+        assert placed == {"lookup_table": 2, "fused_linear_xent": 2}
+        text, = exe.compiled_hlo(main)
+        assert not _collectives(text, "all-reduce", "[33,32]")
+        assert _collectives(text, "all-reduce", "[17,32]")
+        gathers = (_collectives(text, "all-gather", "[34,32]")
+                   + _collectives(text, "all-gather", "[2,17,32]"))
+        assert len(gathers) == 1 and "sum" in gathers[0], gathers
+        return
+    assert _spec_of(sc, "emb.w_0") == ("mp", None)
+    assert placed == {} and rules.uneven_log == []
+    (_traced, jitted, _sh, avals), = exe._spmd_cache.values()
+    with_mechanism = jitted.trace(*avals[0]).lower().as_text()
+    for mod in (math_ops, tensor_ops):
+        monkeypatch.setattr(mod, "rule_sharded_weight",
+                            lambda ctx, op_type, slot, w: w)
+    monkeypatch.setattr(spmd_epilogue, "grad_in_param_storage",
+                        lambda op, ins: ins)
+    jax.clear_caches()
+    assert jitted.trace(*avals[0]).lower().as_text() == with_mechanism
+
+
+@needs_four_devices
+@pytest.mark.parametrize("table,axes,constraints", [
+    ("serving", {"mp": 2}, 0),
+    ("training", {"dp": 2, "mp": 1}, 0),
+    ("training", {"mp": 2}, 2),
+])
+def test_only_a_training_table_over_a_live_axis_constrains_the_table(
+        table, axes, constraints):
+    """A serving rule table names no dp axis: under it a table mp does
+    not divide is read whole on every rank, as it is stored — a decode
+    step's local gather from a replicated table beats a sharded gather
+    and an all-reduce, and pooled == solo stays bit for bit.  Nor is
+    there anything to constrain where the rule's axis has one rank.  The
+    same ops under the training table over mp=2 place their constraint."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.core.registry import get_op
+    from paddle_tpu.ops import kernel_tuning
+    from paddle_tpu.parallel.partition_rules import (partition_rules_for,
+                                                     spmd_lowering)
+
+    w = jnp.zeros((33, 16), jnp.float32)
+    x = jnp.zeros((4, 8, 16), jnp.float32)
+    ids = jnp.zeros((4, 8), jnp.int32)
+
+    def both(w):
+        rows = get_op("lookup_table").lower(
+            _ctx_of("lookup_table", W=["emb.w_0"]),
+            {"W": [w], "Ids": [ids]}, {})["Out"][0]
+        loss = get_op("fused_linear_xent").lower(
+            _ctx_of("fused_linear_xent", W=["emb.w_0"]),
+            {"X": [x], "W": [w], "Label": [ids[..., None]]},
+            {"transpose_w": True})["Loss"][0]
+        return rows, loss
+
+    mesh = make_mesh(axes, jax.devices()[:int(np.prod(list(axes.values())))])
+    rules = (partition_rules_for if table == "serving"
+             else train_partition_rules_for)("gpt2")
+    kernel_tuning.reset_attribution()
+    with spmd_lowering(mesh, rules):
+        text = str(jax.make_jaxpr(both)(w))
+    assert text.count("sharding_constraint") == constraints
+    placed = kernel_tuning.attribution()["uneven_constraints"]
+    assert sum(placed.values()) == constraints
+    assert len(rules.uneven_log) == (1 if constraints else 0)
 
 
 # ---------------------------------------------------------------------------
