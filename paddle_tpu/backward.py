@@ -13,6 +13,7 @@ import numpy as np
 
 from . import framework, unique_name
 from .framework import Parameter, Variable, grad_var_name
+from .profiler import phase
 
 __all__ = ["append_backward", "calc_gradient"]
 
@@ -44,7 +45,7 @@ def append_backward(loss, parameter_list=None, no_grad_set=None, callbacks=None)
     program = loss.block.program
     block = program.global_block()
     no_grad = set(no_grad_set or ())
-    with program._op_role_guard("backward"):
+    with phase("build.backward"), program._op_role_guard("backward"):
         return _append_backward_impl(
             loss, program, block, no_grad, parameter_list
         )

@@ -16,10 +16,12 @@ vars — parameters under training) is donated, so updates alias in HBM; pure
 reads (``ro_state``) are not donated and stay valid across steps.
 """
 
+import contextlib
+
 import jax
 import jax.numpy as jnp
 
-from ..profiler import RecordEvent
+from ..profiler import phase
 from .registry import OPS, LowerCtx, get_op, lower_grad_op
 from .selected_rows import SelectedRows, densify_maybe
 
@@ -427,6 +429,9 @@ class CompiledBlock:
         self.traced = traced
         self.jitted = jitted
         self.feed_sig = feed_sig
+        # the trace_compile phase of the miss that made this block; the
+        # first call resumes it (ExecutionCache.miss)
+        self.compiling = None
         # abstract signature of the first call, so Executor.compiled_hlo
         # can AOT-lower the same executable later
         self.avals = None
@@ -439,8 +444,8 @@ class CompiledBlock:
 
 def sig_text(feed_sig):
     """A feed signature ((name, shape, dtype), ...) as one short string:
-    the argument of a trace_compile span, so that a recompile names its
-    cause in the trace."""
+    the argument of a trace_compile phase, so that a recompile names its
+    cause in the trace and in the set-up ledger."""
     return " ".join("%s:%s%s" % (n, dt, list(shape))
                     for n, shape, dt in feed_sig)
 
@@ -486,11 +491,7 @@ class ExecutionCache:
         hit = self._cache.get(key)
         if hit is not None:
             return hit
-        self.compile_count += 1
-        # the miss analyses the block; tracing and compiling wait for the
-        # executable's first call, which the Executor spans under the
-        # same name
-        with RecordEvent("trace_compile", feed_sig=sig_text(feed_sig)):
+        with self.miss(program, feed_sig, "flat") as compiling:
             feed_names = tuple(n for n, _, _ in feed_sig)
             traced = build_traced_function(
                 program, block_idx, feed_names, fetch_names, scope,
@@ -498,8 +499,29 @@ class ExecutionCache:
             jitted = jax.jit(traced.fn,
                              donate_argnums=(2,) if donate else ())
             compiled = CompiledBlock(traced, jitted, feed_sig)
+        compiled.compiling = compiling
         self._cache[key] = compiled
         return compiled
+
+    @contextlib.contextmanager
+    def miss(self, program, feed_sig, path):
+        """A cache miss of any run path (the Executor's mesh paths keep
+        their own tables and come here too): counted in compile_count,
+        and under it the `trace_compile` phase the new executable's two
+        spans share.  The first, here, is the block's analysis
+        (`analyse_s`); tracing, lowering and compiling wait for the
+        executable's first call, which the Executor runs under the same
+        phase, resumed (Executor._dispatch): one record in
+        profiler.phases() an executable, naming the feed signature, the
+        program (its id) and the path, with what JAX reports of the
+        inside of the compile (profiler._COMPILE_SPANS)."""
+        self.compile_count += 1
+        compiling = phase("trace_compile", feed_sig=sig_text(feed_sig),
+                          program=id(program), path=path)
+        with compiling:
+            yield compiling
+        record = compiling.record
+        record["args"]["analyse_s"] = record["t1"] - record["t0"]
 
     def blocks_for(self, program):
         """Every CompiledBlock cached for `program`."""

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 
 from . import framework
 from .core import scope as scope_mod
-from .core.trace import ExecutionCache, call_avals, sig_text
+from .core.trace import ExecutionCache, call_avals
 from .places import CPUPlace, default_place
 from .profiler import RecordEvent
 
@@ -110,9 +110,9 @@ class Executor:
     # ---- the spans of one run() call ------------------------------------
     # All five run paths open the same set through these helpers, nested
     # under run()'s outer `executor.run` span: feed_upload, state_gather,
-    # executor_run (trace_compile inside it on a new executable's first
-    # call), state_commit, fetch_to_host.  PERF.md section 3 names the
-    # metric that reads each.
+    # executor_run (the trace_compile phase inside it on a new
+    # executable's first call), state_commit, fetch_to_host.  PERF.md
+    # section 3 names the metric that reads each.
     def _upload(self, stage):
         """feed_upload: `stage()` puts the feeds on the device; its wall
         time also accumulates in host_feed_ms."""
@@ -129,14 +129,15 @@ class Executor:
             return ({n: commit(n) for n in ro_names},
                     {n: commit(n) for n in rw_names})
 
-    def _dispatch(self, jitted, args, new_sig=None):
-        """executor_run: the jitted call, i.e. dispatch.  `new_sig` is the
-        feed signature of an executable that has not run yet: its first
-        call traces and compiles, under trace_compile."""
+    def _dispatch(self, jitted, args, compiling=None):
+        """executor_run: the jitted call, i.e. dispatch.  `compiling` is
+        the trace_compile phase of an executable that has not run yet
+        (ExecutionCache.miss): its first call traces, lowers and compiles
+        under that phase, resumed."""
         with RecordEvent("executor_run"):
-            if new_sig is None:
+            if compiling is None:
                 return jitted(*args)
-            with RecordEvent("trace_compile", feed_sig=sig_text(new_sig)):
+            with compiling:
                 return jitted(*args)
 
     def _commit(self, scope, new_state):
@@ -446,7 +447,7 @@ class Executor:
         t0 = time.time() if timed else None
         fetches, new_state = self._dispatch(
             compiled, (feed_arrays, ro_state, rw_state, key),
-            new_sig=compiled.feed_sig if compiled.avals is None else None)
+            compiling=compiled.compiling if compiled.avals is None else None)
         if timed:
             # FLAGS_benchmark contract: per-run timing log with a device
             # barrier so the number is real
@@ -558,14 +559,13 @@ class Executor:
         key_id = (id(program), program._version, feed_sig,
                   tuple(fetch_names), id(scope),
                   bool(get_flag("use_pallas")))
-        entry = cache.get(key_id)
+        entry, compiling = cache.get(key_id), None
         if entry is None:
             from .core.trace import build_traced_function
 
-            # a fresh trace+compile: count it where the engine's
+            # a fresh trace+compile: counted where the engine's
             # no-retrace contract looks (Executor.compile_count)
-            self._cache.compile_count += 1
-            with RecordEvent("trace_compile", feed_sig=sig_text(feed_sig)):
+            with self._cache.miss(program, feed_sig, "spmd") as compiling:
                 traced = build_traced_function(
                     program, 0, tuple(n for n, _, _ in feed_sig),
                     fetch_names, scope, spmd=(mesh, rules),
@@ -605,11 +605,10 @@ class Executor:
         key = jax.device_put(
             self._rng_key(program, mesh.devices.flat[0].platform), repl)
         args = (feed_arrays, ro_state, rw_state, key)
-        first = avals[0] is None
-        if first:
+        if avals[0] is None:
             avals[0] = call_avals(args)
         fetches, new_state = self._dispatch(
-            jitted, args, new_sig=feed_sig if first else None)
+            jitted, args, compiling=compiling)
         self._commit(scope, new_state)
         return self._fetched(fetches, return_numpy)
 
@@ -659,9 +658,8 @@ class Executor:
         key_id = (id(program), program._version, feed_sig,
                   tuple(fetch_names), id(scope),
                   bool(get_flag("use_pallas")))
-        entry = cache.get(key_id)
-        first = entry is None
-        if first:
+        entry, compiling = cache.get(key_id), None
+        if entry is None:
             from .transpiler.pipeline import (build_pipeline_runtime,
                                               flush_pipeline_state)
 
@@ -669,8 +667,8 @@ class Executor:
             # stage-owned state — flush them to the scope before the new
             # signature re-packs, or it would train from stale weights
             flush_pipeline_state(program, scope)
-            self._cache.compile_count += 1
-            with RecordEvent("trace_compile", feed_sig=sig_text(feed_sig)):
+            with self._cache.miss(program, feed_sig,
+                                  "pipeline") as compiling:
                 runtime = build_pipeline_runtime(
                     program, plan, mesh, scope, feed_arrays, fetch_names)
                 entry = cache[key_id] = {
@@ -699,7 +697,7 @@ class Executor:
         key = jax.device_put(self._rng_key(program), repl)
         fetches, new_state = self._dispatch(
             runtime.jitted, (feeds, ro_state, entry["state"], key),
-            new_sig=feed_sig if first else None)
+            compiling=compiling)
         entry["state"] = new_state
         program._pipeline_runtime = entry
         self._commit(scope, {n: new_state[n] for n in runtime.shared_rw})
@@ -847,12 +845,12 @@ class Executor:
         # one (docs/FAULT_TOLERANCE.md "Elastic autoscaling")
         key_id = (id(program), program._version, feed_sig,
                   tuple(fetch_names), id(scope), axis, nranks)
-        entry = cache.get(key_id)
-        first = entry is None
-        if first:
+        entry, compiling = cache.get(key_id), None
+        if entry is None:
             from .core.trace import build_traced_function
 
-            with RecordEvent("trace_compile", feed_sig=sig_text(feed_sig)):
+            with self._cache.miss(program, feed_sig,
+                                  "collective") as compiling:
                 traced = build_traced_function(
                     program, 0, tuple(n for n, _, _ in feed_sig),
                     fetch_names, scope, collective_axis=(axis, nranks))
@@ -910,7 +908,7 @@ class Executor:
         key = to_mesh(self._rng_key(program), PartitionSpec())
         fetches, new_state = self._dispatch(
             jitted, (feed_arrays, ro_state, rw_state, key),
-            new_sig=feed_sig if first else None)
+            compiling=compiling)
         self._commit(scope, new_state)
         # P() out_specs are fully replicated: np.asarray reads the local
         # shard even in multi-process runs

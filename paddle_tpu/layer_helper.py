@@ -18,6 +18,7 @@ from . import framework, unique_name
 from .core.registry import LowerCtx, get_op, is_registered
 from .initializer import Constant, Xavier
 from .param_attr import ParamAttr
+from .profiler import counted
 from .ops.common import jdt
 
 # sentinel for unknown (-1) dims during abstract shape inference; a large
@@ -41,7 +42,8 @@ def _abstract_inputs(op, block):
 
 
 def infer_shape(op, block):
-    """Set output var shapes/dtypes by abstract evaluation of the lowering."""
+    """Set output var shapes/dtypes by abstract evaluation of the lowering
+    (counted, with its time, under profiler.counters()["infer_shape"])."""
     if not is_registered(op.type):
         return
     ins = _abstract_inputs(op, block)
@@ -54,7 +56,8 @@ def infer_shape(op, block):
         return opdef.lower(ctx, ins_, op.attrs)
 
     try:
-        outs = jax.eval_shape(f, ins)
+        with counted("infer_shape"):
+            outs = jax.eval_shape(f, ins)
     except Exception:
         return
     for slot, names in op.outputs.items():

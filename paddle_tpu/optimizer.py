@@ -14,6 +14,7 @@ from .backward import append_backward
 from .framework import Variable
 from .initializer import Constant
 from .layer_helper import LayerHelper
+from .profiler import phase
 
 __all__ = [
     "SGD",
@@ -153,8 +154,9 @@ class Optimizer:
         return self._create_optimization_pass(params_grads)
 
     def minimize(self, loss, startup_program=None, parameter_list=None, no_grad_set=None):
-        params_grads = self.backward(loss, startup_program, parameter_list, no_grad_set)
-        optimize_ops = self.apply_gradients(params_grads)
+        with phase("build.minimize"):
+            params_grads = self.backward(loss, startup_program, parameter_list, no_grad_set)
+            optimize_ops = self.apply_gradients(params_grads)
         return optimize_ops, params_grads
 
 
@@ -659,6 +661,11 @@ class GradientMergeOptimizer:
 
     def minimize(self, loss, startup_program=None, parameter_list=None,
                  no_grad_set=None):
+        with phase("build.minimize"):
+            return self._minimize(loss, startup_program, parameter_list,
+                                  no_grad_set)
+
+    def _minimize(self, loss, startup_program, parameter_list, no_grad_set):
         from .initializer import Constant
         from .layers import nn as _nn
 

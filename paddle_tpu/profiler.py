@@ -10,6 +10,11 @@ step is one compiled program: device-side tracing delegates to jax.profiler
 boundaries + user ranges) that land on the host plane of that same trace,
 on its clock, and are additionally dumped as chrome-trace JSON so
 `profiler(state)`-style workflows keep their artifact.
+
+Work that happens once a process or once a compile (the import, building
+a program, a cache miss's analysis / trace / lowering / compile) is a
+`phase`: always recorded, in the set-up ledger `phases()`, beside the
+`counters()` of sites too frequent for a record each.  A step opens none.
 """
 
 import contextlib
@@ -18,11 +23,16 @@ import os
 import threading
 import time
 
+import jax.monitoring
 from jax.profiler import TraceAnnotation
 
 __all__ = [
     "RecordEvent",
     "record_event",
+    "phase",
+    "phases",
+    "counted",
+    "counters",
     "profiler",
     "start_profiler",
     "stop_profiler",
@@ -63,6 +73,10 @@ class RecordEvent:
         `profiler()` prints and tools/timeline.py merges).
 
     When neither collects, a span reads no clock and records nothing.
+    The clocks: the trace's spans are on the profiler's own clock, with
+    the device ops; the chrome list is on `time.time()`, so that
+    tools/timeline.py can merge the lists of several workers; a `phase`
+    record is on `time.perf_counter()`, a process's own clock.
     `cat` categorizes the span for comm-vs-compute attribution in the
     chrome trace ("comm" for RPC sends/recvs, "feed" for host->device
     uploads; unset spans are compute/host work)."""
@@ -110,6 +124,191 @@ class RecordEvent:
 def record_event(name, cat=None):
     with RecordEvent(name, cat=cat):
         yield
+
+
+# ---- the set-up ledger -----------------------------------------------------
+# What happens once a process or once a compile is recorded always: two
+# clock reads and one append a phase, some tens of phases a run, none of
+# them on a steady step's path.  PERF.md section 3 names the metric that
+# reads each phase and counter.
+PHASE_LIMIT = 4096  # records kept; later ones are counted as phases_dropped
+
+_ledger_lock = threading.Lock()
+_phases = []
+_counters = {}  # name -> [calls, seconds]
+_open = threading.local()  # .stack: this thread's open phases
+
+
+def _open_stack():
+    stack = getattr(_open, "stack", None)
+    if stack is None:
+        stack = _open.stack = []
+    return stack
+
+
+def _count(name, seconds=0.0):
+    with _ledger_lock:
+        c = _counters.get(name)
+        if c is None:
+            c = _counters[name] = [0, 0.0]
+        c[0] += 1
+        c[1] += seconds
+
+
+def counters():
+    """{name: {"calls", "seconds"}} of the process so far: the sites too
+    frequent for a record each (`counted`), what JAX reported of compiles
+    under no `trace_compile` phase (`compile.*`), and `phases_dropped`."""
+    with _ledger_lock:
+        return {n: {"calls": c[0], "seconds": c[1]}
+                for n, c in _counters.items()}
+
+
+@contextlib.contextmanager
+def counted(name):
+    """Count one call of a site under `name` in `counters()`, with the
+    time it took."""
+    t0 = time.perf_counter()
+    try:
+        yield
+    finally:
+        _count(name, time.perf_counter() - t0)
+
+
+def phases():
+    """The set-up ledger: one record a phase, in the order they opened:
+    {"name", "t0", "t1" (None while open), "args", "thread", "depth",
+    "counters", "counters_end"}.  The times are `time.perf_counter()`;
+    `depth` is how many phases the opening thread had open; the two
+    `counters` are `counters()` as the phase opened and as it last
+    closed, so that a reader can tell what was counted before, inside
+    and after it by order alone.  Copies: a caller may keep them."""
+    with _ledger_lock:
+        return [dict(r, args=dict(r["args"])) for r in _phases]
+
+
+class phase:
+    """Span of work that happens once a process or once a compile, never
+    once a step.  Unlike a RecordEvent it always records, in `phases()`;
+    it also enters a RecordEvent of the same name and `args`, so under a
+    running JAX trace it is a `paddle_tpu:<name>` span like every other.
+
+    `t0` is for a start that was read before this module could be
+    imported.  Entering a phase object again resumes its record: the new
+    span is a second `paddle_tpu:<name>` event, `t1` moves to its end,
+    and the record stays one (a compile's analysis at the cache miss and
+    its first call: core/trace.ExecutionCache.miss)."""
+
+    __slots__ = ("name", "args", "record", "_t0", "_event", "_outermost")
+
+    def __init__(self, name, t0=None, **args):
+        self.name = name
+        self.args = args
+        self.record = None
+        self._t0 = t0
+        self._event = None
+        self._outermost = []  # _on_compile_span's: (start, field, seconds)
+
+    def __enter__(self):
+        stack = _open_stack()
+        rec = self.record
+        if rec is None:
+            now = time.perf_counter()
+            rec = self.record = {
+                "name": self.name,
+                "t0": now if self._t0 is None else self._t0, "t1": None,
+                "args": dict(self.args),
+                "thread": threading.get_ident(), "depth": len(stack),
+                "counters": counters(), "counters_end": None,
+            }
+            with _ledger_lock:
+                kept = len(_phases) < PHASE_LIMIT
+                if kept:
+                    _phases.append(rec)
+            if not kept:
+                _count("phases_dropped")
+        stack.append(self)
+        self._event = RecordEvent(self.name, **self.args)
+        self._event.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._event.__exit__(*exc)
+        _open_stack().remove(self)
+        self.record["counters_end"] = counters()
+        self.record["t1"] = time.perf_counter()
+        return False
+
+
+# What JAX 0.9.0 announces of the inside of a compile (jax.monitoring),
+# by the field of a `trace_compile` record it adds to.  The first three
+# come as time spans on one clock (time.time()) and nest: tracing a step
+# traces every inner jit, and a lowering that computes a constant eagerly
+# compiles inside the outer trace.  Only the outermost spans count, so
+# the three fields never sum to more than the record's length.
+_COMPILE_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    "/jax/core/compile/backend_compile_duration": "backend_compile_s",
+}
+_CACHE_READ = "/jax/compilation_cache/cache_retrieval_time_sec"
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "cache_hits",
+    "/jax/compilation_cache/cache_misses": "cache_misses",
+}
+
+
+def _compiling():
+    """The calling thread's open `trace_compile` phase, innermost first."""
+    for ph in reversed(getattr(_open, "stack", None) or ()):
+        if ph.name == "trace_compile":
+            return ph
+    return None
+
+
+def _announced(field, seconds=None):
+    """One announcement (a duration, or with no `seconds` an event to
+    count) goes to the calling thread's open `trace_compile` record, or
+    with none open to the process counter `compile.<field>`.  Returns the
+    open phase."""
+    ph = _compiling()
+    if ph is None:
+        _count("compile." + field, seconds or 0.0)
+    else:
+        args = ph.record["args"]
+        args[field] = args.get(field, 0) + (1 if seconds is None else seconds)
+    return ph
+
+
+def _on_compile_span(event, start, end, **_):
+    field = _COMPILE_SPANS.get(event)
+    if field is None:
+        return
+    ph = _announced(field, end - start)
+    if ph is not None:
+        args, outermost = ph.record["args"], ph._outermost
+        while outermost and outermost[-1][0] >= start:  # nested in this one
+            _, inner_field, inner_s = outermost.pop()
+            args[inner_field] -= inner_s
+        outermost.append((start, field, end - start))
+
+
+def _on_compile_duration(event, seconds, **_):
+    if event == _CACHE_READ:
+        _announced("cache_read_s", seconds)
+
+
+def _on_compile_event(event, **_):
+    field = _CACHE_EVENTS.get(event)
+    if field is not None:
+        _announced(field)
+
+
+# registered once a process: a cached executable's call announces nothing,
+# so a steady step never reaches them
+jax.monitoring.register_event_time_span_listener(_on_compile_span)
+jax.monitoring.register_event_duration_secs_listener(_on_compile_duration)
+jax.monitoring.register_event_listener(_on_compile_event)
 
 
 def reset_profiler():

@@ -82,7 +82,10 @@ def apply_pass(program, name, scope=None):
     ill-formed program fails HERE with the pass and offending op named
     instead of at trace time.  Flag off = one flag read, no other cost.
     """
-    out = get_pass(name).apply(program, scope=scope)
+    from ..profiler import phase
+
+    with phase("build.pass", **{"pass": name}):
+        out = get_pass(name).apply(program, scope=scope)
     out = out if out is not None else program
     from ..flags import get_flag
 

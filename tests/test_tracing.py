@@ -2,12 +2,16 @@
 (core/trace.py's `<op_role>/<op type>/<index>` scopes in the optimized
 HLO), RecordEvent on the device trace's clock (`paddle_tpu:<name>` on the
 host plane of any running JAX trace) beside its chrome-trace list, the
-Executor's spans (one set per run, the same from every run path), and
-Executor.compiled_steps."""
+Executor's spans (one set per run, the same from every run path),
+Executor.compiled_steps, and the set-up ledger (profiler.phases() /
+counters(): what happens once a process or once a compile, recorded
+always, and never by a steady step)."""
 
+import collections
 import glob
 import os
 import re
+import threading
 
 import numpy as np
 import pytest
@@ -21,6 +25,17 @@ ROLES = ("forward", "backward", "optimize", "lrsched", "loss", "rpc")
 SCOPE = re.compile(r"(?:^|[/(])(%s)/([\w.]+)/(\d+)(?=[/)]|$)"
                    % "|".join(ROLES))
 INNER = ("feed_upload", "state_gather", "executor_run", "state_commit")
+
+# everything JAX announces through jax.monitoring while these tests run:
+# what paddle_tpu's own listeners (profiler._on_compile_*) would be
+# called for
+JAX_ANNOUNCED = []
+jax.monitoring.register_event_listener(
+    lambda event, **kw: JAX_ANNOUNCED.append(event))
+jax.monitoring.register_event_duration_secs_listener(
+    lambda event, seconds, **kw: JAX_ANNOUNCED.append(event))
+jax.monitoring.register_event_time_span_listener(
+    lambda event, start, end, **kw: JAX_ANNOUNCED.append(event))
 
 
 def _small_train_program():
@@ -102,33 +117,81 @@ def _host_spans(trace_dir):
     return sorted(spans, key=lambda s: (s[0], -s[1]))
 
 
+def _collective_program():
+    """A two-layer MLP whose dense gradients all-reduce in the step:
+    DistributeTranspiler(mode="collective") over two replicas."""
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.framework.program_guard(main, startup):
+        x = layers.data("x", shape=[4])
+        y = layers.data("y", shape=[1])
+        pred = layers.fc(layers.fc(x, size=8, act="relu"), size=1)
+        loss = layers.mean(layers.square_error_cost(pred, y))
+        fluid.optimizer.SGD(0.1).minimize(loss)
+    config = fluid.DistributeTranspilerConfig()
+    config.mode = "collective"
+    transpiler = fluid.DistributeTranspiler(config=config)
+    transpiler.transpile(0, program=main, pservers="", trainers=2,
+                         sync_mode=True, startup_program=startup)
+    return transpiler.get_trainer_program(), startup, loss
+
+
+def _gpt2_program(path):
+    """A one- or two-layer GPT-2 on two devices: GSPMD over mp ("spmd"),
+    or two pipeline stages of two microbatches ("pipeline")."""
+    from paddle_tpu.models import gpt2
+    from paddle_tpu.parallel import make_mesh
+    from paddle_tpu.transpiler.pipeline import pipeline_program
+
+    class TinyHP(gpt2.GPT2Config):
+        vocab_size, n_ctx, d_model = 64, 16, 32
+        n_layer = 1 if path == "spmd" else 2
+        n_head, d_inner, dropout, tie_embeddings = 4, 64, 0.0, False
+
+    old_main = fluid.framework.switch_main_program(fluid.Program())
+    old_startup = fluid.framework.switch_startup_program(fluid.Program())
+    try:
+        main, startup, _, fetches = gpt2.gpt2_lm_program(
+            TinyHP, seq_len=8, lr=3e-3,
+            mesh=make_mesh({"dp": 1, "mp": 2}, devices=jax.devices()[:2])
+            if path == "spmd" else None)
+        if path == "pipeline":
+            main = pipeline_program(
+                main, make_mesh({"pp": 2}, devices=jax.devices()[:2]),
+                n_microbatches=2, schedule="1f1b")
+    finally:
+        fluid.framework.switch_main_program(old_main)
+        fluid.framework.switch_startup_program(old_startup)
+    return (main, startup, fetches,
+            lambda batch: gpt2.make_fake_lm_batch(batch, 8, TinyHP, seed=0))
+
+
+def _ledger_mark(exe=None):
+    """Where the set-up ledger, its counters, JAX's announcements and an
+    executor's compile_count stand."""
+    return (len(profiler.phases()), profiler.counters(), len(JAX_ANNOUNCED),
+            exe.compile_count if exe is not None else 0)
+
+
+Traced = collections.namedtuple(
+    "Traced", "path runs spans main startup setup_phases")
+# one Executor.run under the trace: did compile_count rise, were numpy
+# fetches asked for, and the _ledger_mark before and after it
+Run = collections.namedtuple("Run", "compiled as_numpy before after")
+
+
 def _traced_runs(tmp_path_factory, path):
-    """Run a train program five times under a plain jax.profiler trace
-    (three steady steps, one with numpy fetches, one at a new batch size)
-    through `path`; ([(compile_count rose, return_numpy)] per run, spans)."""
+    """Build a train program, run its startup program and one step, then
+    five more steps under a plain jax.profiler trace (three steady ones,
+    one with numpy fetches, one at a new batch size and a steady one
+    after it) through `path`."""
     from paddle_tpu.core import scope as scope_mod
 
-    if path == "spmd":
-        from paddle_tpu.models import gpt2
-        from paddle_tpu.parallel import make_mesh
-
-        class TinyHP(gpt2.GPT2Config):
-            vocab_size, n_ctx, d_model, n_layer = 64, 16, 32, 1
-            n_head, d_inner, dropout, tie_embeddings = 4, 64, 0.0, False
-
-        old_main = fluid.framework.switch_main_program(fluid.Program())
-        old_startup = fluid.framework.switch_startup_program(fluid.Program())
-        try:
-            main, startup, _, fetches = gpt2.gpt2_lm_program(
-                TinyHP, seq_len=8, lr=3e-3,
-                mesh=make_mesh({"dp": 1, "mp": 2},
-                               devices=jax.devices()[:2]))
-        finally:
-            fluid.framework.switch_main_program(old_main)
-            fluid.framework.switch_startup_program(old_startup)
-
-        def feed(batch):
-            return gpt2.make_fake_lm_batch(batch, 8, TinyHP, seed=0)
+    n_before = len(profiler.phases())
+    if path in ("spmd", "pipeline"):
+        main, startup, fetches, feed = _gpt2_program(path)
+    elif path == "collective":
+        main, startup, loss = _collective_program()
+        fetches, feed = [loss], _feed
     else:
         main, startup, loss, _ = _small_train_program()
         fetches, feed = [loss], _feed
@@ -139,26 +202,31 @@ def _traced_runs(tmp_path_factory, path):
         exe = fluid.Executor(fluid.CPUPlace())
         exe.run(startup)
         exe.run(main, feed=feed(2), fetch_list=fetches)  # compile outside
+        setup_phases = profiler.phases()[n_before:]
         jax.profiler.start_trace(trace_dir)
         try:
             for batch, as_numpy in ((2, False), (2, False), (2, True),
                                     (4, False), (4, False)):
-                before = exe.compile_count
+                before = _ledger_mark(exe)
                 out = exe.run(main, feed=feed(batch), fetch_list=fetches,
                               return_numpy=as_numpy)
-                runs.append((exe.compile_count > before, as_numpy))
+                after = _ledger_mark(exe)
+                runs.append(Run(after[3] > before[3], as_numpy, before,
+                                after))
             jax.block_until_ready(out)
         finally:
             jax.profiler.stop_trace()
-    return runs, _host_spans(trace_dir)
+    return Traced(path, runs, _host_spans(trace_dir), main, startup,
+                  setup_phases)
 
 
-@pytest.fixture(scope="module", params=["fast", "spmd"])
+@pytest.fixture(scope="module", params=[
+    "fast", "spmd", "collective",
+    pytest.param("pipeline", marks=pytest.mark.slow)])
 def traced(request, tmp_path_factory):
-    if request.param == "spmd" and len(jax.devices()) < 2:
+    if request.param != "fast" and len(jax.devices()) < 2:
         pytest.skip("needs two virtual devices")
-    runs, spans = _traced_runs(tmp_path_factory, request.param)
-    return request.param, runs, spans
+    return _traced_runs(tmp_path_factory, request.param)
 
 
 def _calls(spans):
@@ -169,12 +237,12 @@ def _calls(spans):
 
 
 def test_every_run_emits_one_nested_set_of_spans(traced):
-    path, runs, spans = traced
+    path, runs, spans = traced[:3]
     calls = _calls(spans)
     assert len(calls) == len(runs)
     assert sum(len(inside) for _, inside in calls) + len(calls) \
         == len(spans), "a span outside every executor.run"
-    for (outer, inside), (compiled, as_numpy) in zip(calls, runs):
+    for (outer, inside), (compiled, as_numpy, _, _) in zip(calls, runs):
         names = [s[2] for s in inside]
         for name in INNER:
             assert names.count(name) == 1, (path, names)
@@ -183,26 +251,212 @@ def test_every_run_emits_one_nested_set_of_spans(traced):
         order = [n for n in names if n in INNER]
         assert order == list(INNER), order
         # a first run at a signature takes the slow path; steady ones the
-        # memoised one (the GSPMD path has one route for both)
-        want = "spmd" if path == "spmd" else ("slow" if compiled else "fast")
+        # memoised one (a mesh path has one route for both)
+        want = path if path != "fast" else ("slow" if compiled else "fast")
         assert outer[3].get("path") == want, outer
 
 
 def test_the_run_paths_emit_the_same_names(traced):
-    _, runs, spans = traced
+    spans = traced.spans
     names = {s[2] for s in spans}
     assert names == {"executor.run", "trace_compile", "fetch_to_host",
                      *INNER}
 
 
 def test_trace_compile_exactly_when_compile_count_rises(traced):
-    _, runs, spans = traced
-    assert [c for c, _ in runs] == [False, False, False, True, False]
-    for (outer, inside), (compiled, _) in zip(_calls(spans), runs):
+    runs, spans = traced.runs, traced.spans
+    assert [r.compiled for r in runs] == [False, False, False, True, False]
+    for (outer, inside), run in zip(_calls(spans), runs):
         compiles = [s for s in inside if s[2] == "trace_compile"]
-        assert bool(compiles) == compiled, (outer, compiles)
+        # the block's analysis at the miss, then the first call
+        assert len(compiles) == (2 if run.compiled else 0), (outer, compiles)
         for s in compiles:  # the cause is on the span
             assert "[4, " in s[3]["feed_sig"], s
+
+
+# ---- the set-up ledger: profiler.phases() and counters() ----
+
+COMPILE_FIELDS = ("trace_s", "lower_s", "backend_compile_s")
+
+
+def test_phases_arrive_in_the_order_set_up_runs_in(traced):
+    """import, then the build, then the startup program's trace_compile,
+    then the train step's: each closed, no shorter than what JAX reported
+    inside it, naming its program and path."""
+    first = profiler.phases()[0]
+    assert (first["name"], first["depth"]) == ("import", 0)
+    records = traced.setup_phases
+    assert first["t1"] <= records[0]["t0"]
+    names = [r["name"] for r in records]
+    build = [n for n in names if n.startswith("build.")]
+    assert names == build + ["trace_compile", "trace_compile"], names
+    assert "build.minimize" in build and "build.backward" in build
+    if traced.path == "spmd":  # gpt2's builder applies its fuse passes
+        passes = [r["args"]["pass"] for r in records
+                  if r["name"] == "build.pass"]
+        assert "matmul_epilogue_fuse_pass" in passes, passes
+    for r in records:
+        assert r["t1"] >= r["t0"], r
+        assert r["thread"] == threading.get_ident()
+    by_name = {r["name"]: r for r in records}
+    assert by_name["build.minimize"]["depth"] == 0
+    assert by_name["build.backward"]["depth"] == 1  # minimize calls it
+    for r in records[1:]:  # in the order they opened
+        assert r["t0"] >= records[0]["t0"]
+    want_path = "flat" if traced.path == "fast" else traced.path
+    for r, program in zip(records[-2:], (traced.startup, traced.main)):
+        args = r["args"]
+        assert args["program"] == id(program) and r["depth"] == 0
+        # (a startup program carries no mesh stamp unless its builder
+        # gave it one: it may run flat beside a mesh-path train step)
+        assert args["path"] in (want_path, "flat")
+        inside = sum(args.get(f, 0.0) for f in COMPILE_FIELDS)
+        # JAX's spans are on time.time(), the record on perf_counter()
+        assert 0 < inside <= r["t1"] - r["t0"] + 1e-3, r
+        assert 0 <= args["analyse_s"] <= r["t1"] - r["t0"]
+        assert all(args[f] > 0 for f in COMPILE_FIELDS), r
+    assert records[-2]["args"]["feed_sig"] == ""  # a startup program's
+    assert "[2, " in records[-1]["args"]["feed_sig"]
+    assert records[-1]["args"]["path"] == want_path
+
+
+def test_steady_steps_append_no_phase_and_fire_no_listener(traced):
+    steady = [r for r in traced.runs if not r.compiled]
+    assert len(steady) == 4
+    for run in steady:
+        assert run.after == run.before, (
+            "a steady Executor.run opened a phase, counted, or made JAX "
+            "announce an event", run)
+
+
+def test_a_new_feed_shape_appends_one_trace_compile(traced):
+    (run,) = [r for r in traced.runs if r.compiled]
+    assert run.after[3] == run.before[3] + 1  # compile_count, every path
+    assert run.after[0] == run.before[0] + 1  # two spans, ONE record
+    record = profiler.phases()[run.before[0]]
+    assert record["name"] == "trace_compile"
+    assert "[4, " in record["args"]["feed_sig"]
+    assert record["args"]["program"] == id(traced.main)
+    assert run.after[2] > run.before[2]  # and JAX announced its compile
+    assert run.after[1] == run.before[1]  # into the record, not a total
+
+
+def test_a_second_compile_of_a_step_reads_the_cache(tmp_path):
+    """With a persistent cache directory, the first compile of a step
+    misses (and writes) and a second executor's compile of the same step
+    reports a hit, no miss, and the read's time."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    keep = {name: getattr(jax.config, name) for name in (
+        "jax_compilation_cache_dir", "jax_enable_compilation_cache",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes")}
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    jax.config.update("jax_enable_compilation_cache", True)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    compilation_cache.reset_cache()
+    try:
+        main, startup, loss, _ = _small_train_program()
+        records = []
+        for _ in range(2):
+            exe = fluid.Executor(fluid.CPUPlace())
+            with fluid.scope_guard(fluid.Scope()):
+                exe.run(startup)
+                exe.run(main, feed=_feed(), fetch_list=[loss])
+            records.append(profiler.phases()[-1]["args"])
+    finally:
+        for name, value in keep.items():
+            jax.config.update(name, value)
+        compilation_cache.reset_cache()
+    cold, warm = records
+    assert cold["program"] == warm["program"] == id(main)
+    assert cold.get("cache_misses", 0) >= 1 and "cache_hits" not in cold
+    assert warm["cache_hits"] >= 1 and warm.get("cache_misses", 0) == 0
+    assert 0 < warm["cache_read_s"] <= warm["backend_compile_s"]
+
+
+def test_phases_are_spans_of_a_running_trace(tmp_path):
+    trace_dir = str(tmp_path)
+    jax.profiler.start_trace(trace_dir)
+    try:
+        main, startup, loss, _ = _small_train_program()
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+    finally:
+        jax.profiler.stop_trace()
+    spans = _host_spans(trace_dir)
+    by_name = collections.defaultdict(list)
+    for span in spans:
+        by_name[span[2]].append(span)
+    (minimize,), (backward,) = by_name["build.minimize"], by_name[
+        "build.backward"]
+    assert minimize[0] <= backward[0] and backward[1] <= minimize[1]
+    analysis, first_call = by_name["trace_compile"]
+    for span in (analysis, first_call):
+        assert span[3]["path"] == "flat"
+        assert int(span[3]["program"]) == id(startup)
+    (outer,) = by_name["executor.run"]
+    assert outer[0] <= analysis[0] and first_call[1] <= outer[1]
+
+
+def test_the_ledger_is_bounded(monkeypatch):
+    monkeypatch.setattr(profiler, "PHASE_LIMIT", len(profiler.phases()) + 2)
+    dropped = profiler.counters().get("phases_dropped", {"calls": 0})
+    for i in range(5):
+        with profiler.phase("test.bounded", i=i) as ph:
+            assert ph.record["t1"] is None
+        assert ph.record["t1"] >= ph.record["t0"]  # dropped or not
+    kept = [r["args"]["i"] for r in profiler.phases()
+            if r["name"] == "test.bounded"]
+    assert kept == [0, 1] and len(profiler.phases()) == profiler.PHASE_LIMIT
+    assert profiler.counters()["phases_dropped"]["calls"] \
+        == dropped["calls"] + 3
+
+
+def test_a_compile_on_another_thread_does_not_nest_under_the_main_one():
+    """A prefetch thread's compile while the main thread is inside a
+    phase: its record has depth 0, and what JAX reports on that thread
+    goes to its record, not to the main thread's open trace_compile."""
+    main, startup, loss, _ = _small_train_program()
+    scope = fluid.Scope()
+    done = []
+
+    def worker():
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup, scope=scope)
+        done.append(threading.get_ident())
+
+    n = len(profiler.phases())
+    with profiler.phase("trace_compile", why="the main thread's") as mine:
+        thread = threading.Thread(target=worker)
+        thread.start()
+        thread.join(timeout=120)
+        assert not thread.is_alive() and done
+    ours, theirs = profiler.phases()[n:]
+    assert ours["args"] == {"why": "the main thread's"}  # no trace_s
+    assert (theirs["name"], theirs["depth"]) == ("trace_compile", 0)
+    assert theirs["thread"] == done[0] != ours["thread"]
+    assert theirs["args"]["backend_compile_s"] > 0
+    assert mine.record["t0"] <= theirs["t0"] <= theirs["t1"] \
+        <= mine.record["t1"]
+
+
+def test_infer_shape_is_counted_and_a_phase_snapshots_the_counters():
+    """A phase carries counters() as it opened and as it closed: what
+    was counted inside it is their difference, by order and no clock."""
+    none = {"calls": 0, "seconds": 0.0}
+    before = profiler.counters().get("infer_shape", none)
+    with profiler.phase("test.build") as ph:
+        _small_train_program()
+    after = profiler.counters()["infer_shape"]
+    assert after["calls"] >= before["calls"] + 10  # one a layer's op
+    assert after["seconds"] > before["seconds"]
+    record = profiler.phases()[-3]  # then build.minimize > build.backward
+    assert record["name"] == "test.build"
+    assert record["counters"].get("infer_shape", none) == before
+    assert record["counters_end"]["infer_shape"] == after == \
+        ph.record["counters_end"]["infer_shape"]
 
 
 def test_record_event_off_reads_no_clock_and_keeps_no_event(monkeypatch):
