@@ -491,6 +491,36 @@ class Executor:
             shape = tuple(var.shape) if var is not None else None
         return rules.sharding_for(mesh, name, shape)
 
+    @staticmethod
+    def _jit_spmd_step(traced, mesh, feed_shardings, state_shardings):
+        """(jitted step, its compile options): `traced.fn` jitted over
+        `mesh` with the feeds' and the state's shardings in and out, rw
+        state donated, and the compile options the mesh calls for
+        (parallel.mesh.mesh_compile_options: asynchronous collectives on a
+        mesh of TPUs, none on any other).  The run path's one jit site:
+        compiled_steps hands the readers this same object, so
+        compiled_hlo re-lowers with the same options, and a test compiles
+        it for a described topology."""
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        from .parallel.mesh import mesh_compile_options
+
+        options = mesh_compile_options(mesh)
+        sh = state_shardings
+        jitted = jax.jit(
+            traced.fn,
+            in_shardings=(
+                feed_shardings,
+                {n: sh[n] for n in traced.ro_names},
+                {n: sh[n] for n in traced.rw_names},
+                NamedSharding(mesh, PartitionSpec()),
+            ),
+            out_shardings=(None, {n: sh[n] for n in traced.updated}),
+            donate_argnums=(2,),
+            compiler_options=options,
+        )
+        return jitted, options
+
     def _run_spmd(self, program, feed, fetch_names, scope, return_numpy,
                   spmd):
         """Run a GSPMD-stamped program: ONE traced step jitted with the
@@ -511,6 +541,15 @@ class Executor:
         rule's spec, unevenly, and the optimizer's Grad is constrained
         back to the stored sharding (ops/spmd_epilogue.rule_sharded_weight
         / grad_in_param_storage; rules.uneven_log names the weights).
+
+        How the step is COMPILED follows the mesh too (_jit_spmd_step):
+        on a mesh of several TPUs the compiler is asked for asynchronous
+        all-reduces (parallel.mesh.mesh_compile_options), so the
+        activation sums over mp ride inside the fusions scheduled beside
+        them instead of stopping the chip; a CPU mesh and a mesh of one
+        device compile with no option.  The same collectives over the same
+        members in the same dtypes, on another schedule; the options are
+        named in the step's trace_compile record (`compiler_options`).
 
         The serving engine's two PR 9 contracts survive unchanged:
         occupancy churn changes feed VALUES only (one compile per feed
@@ -574,18 +613,10 @@ class Executor:
                                                   scope)
                       for n in set(traced.ro_names) | set(traced.rw_names)
                       | set(traced.updated)}
-                jitted = jax.jit(
-                    traced.fn,
-                    in_shardings=(
-                        {n: feed_arrays[n].sharding for n in feed_arrays},
-                        {n: sh[n] for n in traced.ro_names},
-                        {n: sh[n] for n in traced.rw_names},
-                        repl,
-                    ),
-                    out_shardings=(None,
-                                   {n: sh[n] for n in traced.updated}),
-                    donate_argnums=(2,),
-                )
+                jitted, options = self._jit_spmd_step(
+                    traced, mesh,
+                    {n: a.sharding for n, a in feed_arrays.items()}, sh)
+                compiling.record["args"]["compiler_options"] = sorted(options)
             # avals[0] records the first call's abstract args so
             # compiled_hlo can AOT-lower the same signature later
             entry = cache[key_id] = (traced, jitted, sh, [None])

@@ -7,7 +7,7 @@ from jax import shard_map
 from jax.sharding import Mesh
 
 __all__ = ["make_mesh", "default_mesh", "mesh_axis_sizes", "dp_mesh",
-           "shard_map", "vma_of", "pcast_varying"]
+           "mesh_compile_options", "shard_map", "vma_of", "pcast_varying"]
 
 
 def vma_of(*xs):
@@ -47,6 +47,37 @@ def default_mesh(axis_name="dp"):
 
 def mesh_axis_sizes(mesh):
     return dict(zip(mesh.axis_names, mesh.devices.shape))
+
+
+# What the TPU compiler is asked for when it compiles ONE step for a mesh
+# of several chips (Executor._run_spmd): under its defaults an all-reduce
+# is a synchronous op, and nothing else runs on the chip while the links
+# carry it.  With these two, and only with both, an all-reduce may become
+# an asynchronous collective fusion: its pieces ride inside the matmul
+# fusions scheduled beside it (the backward's activation sums over mp
+# under the deferred dW matmuls).  The same all-reduces over the same
+# members in the same dtypes, on another schedule.  Kept because the step
+# compiled for a described v5e:2x2 differs with them and the chip's step
+# is shorter; the options that changed nothing in that HLO, or nothing on
+# the chip, are named with their evidence in docs/PERFORMANCE.md ("The
+# mesh step's compile options") and PERF.md section 6, PR 35.
+_TPU_MESH_COMPILE_OPTIONS = {
+    "xla_enable_async_all_reduce": True,
+    "xla_tpu_enable_async_collective_fusion_fuse_all_reduce": True,
+}
+
+
+def mesh_compile_options(mesh):
+    """`compiler_options` for jitting one step over `mesh`, chosen from
+    what the mesh shows: the asynchronous-collective scheduling above
+    where its devices are TPUs and there is more than one, nothing
+    anywhere else (a CPU compile refuses an `xla_tpu_` option outright,
+    and one chip has no collective to hide).  The one place the option
+    names live."""
+    devices = mesh.devices
+    if devices.size > 1 and devices.flat[0].platform == "tpu":
+        return dict(_TPU_MESH_COMPILE_OPTIONS)
+    return {}
 
 
 def dp_mesh(nranks, axis_name="dp"):

@@ -512,3 +512,238 @@ def test_sharded_train_step_lowers_for_tpu_without_chips(monkeypatch):
     text = jitted.trace(*avals[0]).lower(
         lowering_platforms=("tpu",)).as_text()
     assert text.count("tpu_custom_call") > 0
+
+
+# ---------------------------------------------------------------------------
+# the mesh step's compile options (parallel.mesh.mesh_compile_options)
+# ---------------------------------------------------------------------------
+class _Device:
+    def __init__(self, platform):
+        self.platform = platform
+
+
+def _mesh_of(platform, *shape):
+    """What mesh_compile_options looks at, without the devices: an array
+    of things that state a platform."""
+    import types
+
+    devices = np.empty(int(np.prod(shape)), dtype=object)
+    devices[:] = [_Device(platform) for _ in devices]
+    return types.SimpleNamespace(devices=devices.reshape(shape))
+
+
+@pytest.mark.parametrize("platform,shape,engaged", [
+    ("tpu", (2, 2), True),    # the benchmark's dp2 x mp2
+    ("tpu", (4,), True),      # a serving mesh: mp alone, the same sums
+    ("tpu", (1, 1), False),   # one chip: no collective to hide
+    ("cpu", (2, 2), False),   # every tier-1 mesh: the compile would refuse
+    ("cpu", (1,), False),
+    ("gpu", (2, 2), False),
+])
+def test_mesh_compile_options_follow_the_mesh(platform, shape, engaged):
+    from paddle_tpu.parallel import mesh as mesh_mod
+
+    got = mesh_mod.mesh_compile_options(_mesh_of(platform, *shape))
+    if not engaged:
+        assert got == {}
+        return
+    assert got == mesh_mod._TPU_MESH_COMPILE_OPTIONS and got
+    assert all(k.startswith("xla_") and v is True for k, v in got.items())
+    got.clear()  # the caller's copy: the table itself stays whole
+    assert mesh_mod.mesh_compile_options(_mesh_of(platform, *shape))
+
+
+@needs_four_devices
+def test_cpu_mesh_step_compiles_with_no_option_and_matches_one_device():
+    """The forced-host mesh gets an empty dict (a CPU compile answers
+    "No such compile option" to any xla_tpu_ name), trains as the
+    one-device step does, and its trace_compile record says so."""
+    from paddle_tpu import profiler
+    from paddle_tpu.parallel.mesh import mesh_compile_options
+
+    mesh = make_mesh({"dp": 2, "mp": 2}, jax.devices()[:4])
+    assert mesh_compile_options(mesh) == {}
+    n_phases = len(profiler.phases())
+    got, _, main, _ = _train(mesh, steps=3)
+    np.testing.assert_allclose(got, _base_losses(steps=3), rtol=1e-5)
+    named = [r["args"]["compiler_options"]
+             for r in profiler.phases()[n_phases:]
+             if r["name"] == "trace_compile"
+             and r["args"].get("program") == id(main)]
+    assert named == [[]]
+
+
+@needs_four_devices
+def test_run_path_and_compiled_hlo_compile_with_the_same_options(
+        monkeypatch):
+    """Whatever mesh_compile_options answers reaches BOTH the executable
+    that runs and the one compiled_hlo re-lowers for the readers (an
+    option the CPU compiler takes stands in for the TPU's), and the
+    trace_compile record names it."""
+    from paddle_tpu import profiler
+    from paddle_tpu.parallel import mesh as mesh_mod
+
+    lowered_with = []
+    real_jit = jax.jit
+
+    def jit(fn, **kw):
+        jitted = real_jit(fn, **kw)
+        if "in_shardings" in kw:  # the mesh step, not a helper jit
+            lowered_with.append((jitted, kw.get("compiler_options")))
+        return jitted
+
+    monkeypatch.setattr(jax, "jit", jit)
+    monkeypatch.setattr(mesh_mod, "mesh_compile_options",
+                        lambda mesh: {"xla_embed_ir_in_executable": True})
+    n_phases = len(profiler.phases())
+    _, _, main, exe = _train(make_mesh({"dp": 2, "mp": 2},
+                                       jax.devices()[:4]), steps=1)
+    (jitted, options), = lowered_with
+    assert options == {"xla_embed_ir_in_executable": True}
+    step = exe.compiled_steps(main)[-1]
+    assert step.path == "spmd" and step._jitted is jitted
+    assert "all-reduce" in exe.compiled_hlo(main)[-1]
+    named = [r["args"]["compiler_options"]
+             for r in profiler.phases()[n_phases:]
+             if r["name"] == "trace_compile"
+             and r["args"].get("program") == id(main)]
+    assert named == [["xla_embed_ir_in_executable"]]
+
+
+def test_no_mesh_run_paths_pass_no_compile_option(monkeypatch):
+    """One chip's steps share no line with the mesh step's options: the
+    flat path's jit sites are handed no compiler_options, and the option
+    names live in parallel/mesh.py alone."""
+    import pathlib
+
+    import paddle_tpu
+
+    kwargs_seen = []
+    real_jit = jax.jit
+
+    def jit(fn, **kw):
+        kwargs_seen.append(kw)
+        return real_jit(fn, **kw)
+
+    monkeypatch.setattr(jax, "jit", jit)
+    losses = _train(None, steps=2)[0]
+    assert np.isfinite(losses).all() and kwargs_seen
+    assert not [kw for kw in kwargs_seen if "compiler_options" in kw]
+    root = pathlib.Path(paddle_tpu.__file__).parent
+    passing, naming = set(), set()
+    for path in root.rglob("*.py"):
+        text = path.read_text()
+        if "compiler_options=" in text:
+            passing.add(path.relative_to(root).as_posix())
+        if "xla_tpu_" in text or "xla_enable_" in text:
+            naming.add(path.relative_to(root).as_posix())
+    assert passing == {"executor.py"} and naming == {"parallel/mesh.py"}
+
+
+@pytest.fixture(scope="module")
+def v5e_2x2():
+    """A described (not attached) 2 x 2 of v5e chips: the TPU compiler
+    compiles for it on this host.  In a fixture, and in this file alone,
+    because the process that describes it holds libtpu until it exits."""
+    import os
+
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here, or another process holds it
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+
+
+class LaneHP(TinyHP):  # TinyHP at widths the TPU's tiling takes
+    vocab_size = 1001  # mp does not divide it, as GPT-2's 50257
+    n_ctx = 128
+    d_model = 256
+    n_head = 2
+    d_inner = 1024
+    dropout = 0.1
+    tie_embeddings = True
+
+
+def _described_step_hlo(topo):
+    """Optimized HLO of LaneHP's dp2 x mp2 train step compiled for the
+    described chips through _run_spmd's own jit site, over a scope of
+    shapes (nothing can be put on a described device)."""
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding
+
+    from paddle_tpu.core.trace import build_traced_function
+    from paddle_tpu.executor import Executor
+
+    _fresh()
+    mesh = make_mesh({"dp": 2, "mp": 2}, list(topo.devices))
+    main, startup, _feeds, fetches = gpt2.gpt2_lm_program(
+        LaneHP, seq_len=128, lr=3e-3, use_bf16=True, mesh=mesh)
+    rules = main._spmd["rules"]
+    scope = scope_mod.Scope()
+    for block in (main.global_block(), startup.global_block()):
+        for name, var in block.vars.items():
+            if var.persistable and all(int(d) >= 0 for d in var.shape):
+                scope.set(name, jax.ShapeDtypeStruct(
+                    tuple(int(d) for d in var.shape),
+                    jnp.dtype(str(var.dtype))))
+    batch = gpt2.make_fake_lm_batch(4, 128, LaneHP, seed=0)
+    traced = build_traced_function(
+        main, 0, tuple(sorted(batch)), [fetches[0].name], scope,
+        spmd=(mesh, rules), platform="tpu")
+    sh = {n: rules.sharding_for(mesh, n, scope.find_var(n).shape)
+          for n in set(traced.ro_names) | set(traced.rw_names)
+          | set(traced.updated)}
+    rows = NamedSharding(mesh, P("dp"))
+    jitted, options = Executor._jit_spmd_step(
+        traced, mesh, {n: rows for n in batch}, sh)
+
+    def shaped(n):
+        v = scope.find_var(n)
+        return jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=sh[n])
+
+    key = jax.eval_shape(lambda: jax.random.key(1, impl="rbg"))
+    hlo = jitted.lower(
+        {n: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rows)
+         for n, a in batch.items()},
+        {n: shaped(n) for n in traced.ro_names},
+        {n: shaped(n) for n in traced.rw_names},
+        jax.ShapeDtypeStruct(key.shape, key.dtype,
+                             sharding=NamedSharding(mesh, P()))
+    ).compile().as_text()
+    return hlo, options
+
+
+def test_tpu_mesh_step_schedules_asynchronous_reductions(v5e_2x2,
+                                                         monkeypatch):
+    """The mechanism, read where it acts: compiled for a described
+    v5e:2x2 with the mesh's options the step's optimized HLO hands
+    all-reduces to AsyncCollectiveStart (what the benchmark's
+    async_collective_ops counts, by its own pattern); compiled with none,
+    as before this rule, it hands none.  A compile is not a chip run:
+    what the schedule pays is measured in the cell."""
+    import json
+    import pathlib
+
+    from jax.experimental.compilation_cache import compilation_cache
+    from paddle_tpu.parallel import mesh as mesh_mod
+
+    pattern = json.loads((
+        pathlib.Path(__file__).parent.parent / "benchmark" / "layer_metrics"
+        / "async_collective_ops.json").read_text())["args"]["pattern"]
+    cache_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()  # a described compile cannot be read back
+    try:
+        with_options, options = _described_step_hlo(v5e_2x2)
+        monkeypatch.setattr(mesh_mod, "mesh_compile_options", lambda m: {})
+        without, none = _described_step_hlo(v5e_2x2)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_on)
+        compilation_cache.reset_cache()
+    assert options == mesh_mod._TPU_MESH_COMPILE_OPTIONS and none == {}
+    assert without.count(" all-reduce(") > 0
+    assert without.count(pattern) == 0
+    assert with_options.count(pattern) > 0
