@@ -567,17 +567,21 @@ def short_conv(input, kernel_size=3, param_attr=None, name=None):
 def moe_ffn(input, num_experts, expert_size, top_k, norm_topk_prob=False,
             router_attr=None, gate_up_attr=None, down_attr=None,
             stat_name="moe_tokens_per_expert", name=None, router="softmax",
-            expert_bias_attr=None, num_local_experts=None, expert_offset=0):
+            expert_bias_attr=None, num_local_experts=None, expert_offset=0,
+            routed_scaling_factor=1.0, norm_topk_eps=1e-6):
     """Token-choice mixture of SwiGLU experts over the last axis of
     `input` (the `moe_ffn` op: top-k, dropless).  The experts' weights are
     stacked: gate and up side by side in one [E, d, 2 * expert_size]
     parameter, down in [E, expert_size, d].
 
     `router` is "softmax" (OLMoE's) or "sigmoid": scores sigmoid(logits),
-    weights renormalised over the chosen with 1e-6 under `norm_topk_prob`;
-    with `expert_bias_attr` a [num_experts]
+    weights renormalised over the chosen with `norm_topk_eps` added to
+    their sum under `norm_topk_prob` (LFM2 publishes 1e-6, the
+    DeepSeek-V3 family 1e-20); with `expert_bias_attr` a [num_experts]
     f32 buffer (a parameter that is not trainable) is added to the scores
-    for the selection alone.  `num_local_experts` < `num_experts` builds a
+    for the selection alone.  `routed_scaling_factor` multiplies the
+    chosen experts' weights after the renormalisation (either router; 1
+    adds no instruction).  `num_local_experts` < `num_experts` builds a
     chip's share of the layer: the router stays [d, num_experts], the
     expert weights hold experts [expert_offset, expert_offset +
     num_local_experts), and what the others would add is left out.
@@ -620,7 +624,9 @@ def moe_ffn(input, num_experts, expert_size, top_k, norm_topk_prob=False,
         "moe_ffn", inputs=inputs,
         outputs={"Y": [out], "TokensPerExpert": [counts], "AuxLoss": [aux]},
         attrs={"top_k": int(top_k), "norm_topk_prob": bool(norm_topk_prob),
-               "router": router, "expert_offset": int(expert_offset)})
+               "router": router, "expert_offset": int(expert_offset),
+               "routed_scaling_factor": float(routed_scaling_factor),
+               "norm_topk_eps": float(norm_topk_eps)})
     # said here, not left to the abstract evaluation of the lowering: with
     # an unknown batch that runs at a million sequences, whose rows times
     # top_k no int32 index reaches
@@ -1542,7 +1548,10 @@ def fused_attention(q, k, v, causal=False, scale=None, bias=None,
                     window=0, segment_ids=None, qstart=None, name=None):
     """Fused scaled-dot-product attention over [batch, heads, T, d]
     (the blockwise flash kernel where the placed platform and the shape
-    choose it: ops/nn_ops._flash_engages).  bias: optional
+    choose it: ops/nn_ops._flash_engages).  V may be of another width
+    than Q and K ([batch, heads, Tk, d_v]: latent attention scores 192
+    wide over 128-wide values); the result is [batch, heads, Tq, d_v].
+    bias: optional
     additive key-padding bias, rank-1 in the key axis ([B, Tk] or
     [B, 1, 1, Tk]) — covers padding masks without a [Tq, Tk] tensor;
     combine with causal=True for decoder self-attention.  window > 0
@@ -1576,16 +1585,16 @@ def fused_attention(q, k, v, causal=False, scale=None, bias=None,
         inputs["SegmentIds"] = [segment_ids]
     if qstart is not None:
         inputs["QStart"] = [qstart]
-    # Out is Q's shape and dtype, said here: appended straight to the
-    # block, the op's lowering (a kernel, where platform and shape choose
-    # one) is never evaluated to build a program
+    # Out is Q's shape at V's width, in Q's dtype, said here: appended
+    # straight to the block, the op's lowering (a kernel, where platform
+    # and shape choose one) is never evaluated to build a program
     helper.main_program.current_block().append_op(
         "fused_attention",
         inputs=inputs,
         outputs={"Out": [out]},
         attrs={"causal": causal, "scale": scale, "window": int(window)},
     )
-    out.shape = tuple(q.shape)
+    out.shape = tuple(q.shape[:-1]) + (v.shape[-1],)
     return out
 
 
@@ -1607,19 +1616,26 @@ def slot_cache_write(cache, new, pos, width, name=None):
     return out
 
 
-def rotary_embed(x, pos=None, base=10000.0, name=None):
+def rotary_embed(x, pos=None, base=10000.0, interleaved=False, name=None):
     """Rotary position embedding over per-head projections [B, H, T, Dh]
     (rotate-half).  pos: optional int positions [T] — the KV-cached
     decode path passes the current position so cached keys are stored
     pre-rotated; default arange(T).  A [B, T] pos keeps per-row
-    positions (ragged serving step)."""
+    positions (ragged serving step).  interleaved=True is for weights
+    published in the (2i, 2i+1) pairing (DeepSeek-V3's `rope_interleave`):
+    the last axis is first de-interleaved to (i, i + Dh/2) and then
+    rotated as above, and the result stays in that order: the same
+    permutation on q and k leaves every score as it was."""
     helper = LayerHelper("rotary_embed", **locals())
     out = helper.create_variable_for_type_inference(x.dtype)
     inputs = {"X": [x]}
     if pos is not None:
         inputs["Pos"] = [pos]
+    attrs = {"base": base}
+    if interleaved:  # the default leaves the op as every program has it
+        attrs["interleaved"] = True
     helper.append_op("rotary_embed", inputs=inputs,
-                     outputs={"Out": [out]}, attrs={"base": base})
+                     outputs={"Out": [out]}, attrs=attrs)
     return out
 
 

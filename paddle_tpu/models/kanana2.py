@@ -1,0 +1,219 @@
+"""kanana-2-30b-a3b (kakaocorp; model type `deepseek_v3`,
+https://huggingface.co/kakaocorp/kanana-2-30b-a3b-instruct-2601): a
+decoder-only LM of the DeepSeek-V3 shape.  Every layer mixes tokens with
+multi-head latent attention (`transformer.latent_attention`: a 512-wide
+key/value latent all heads share, a decoupled 64-wide rotary part, scores
+192 wide over 128-wide values); its feed-forward is a dense SwiGLU MLP in
+the first `first_k_dense_replace` layers and, after them, shared experts
+every token passes through beside a token-choice mixture of routed ones.
+
+Block i: x += MLA(rms(x)); x += F_i(rms(x)); a final rms; an untied head.
+No bias anywhere.
+
+  dense    one SwiGLU MLP of `intermediate_size`.
+  experts  Shared(h) + Routed(h).
+           Routed: one `moe_ffn` op, the `noaux_tc` router with one group:
+           s = sigmoid(h W_r) in f32, the top-k of s +
+           e_score_correction_bias, weights the unbiased s renormalised
+           over the chosen (+ 1e-20) and multiplied by
+           `routed_scaling_factor`; no auxiliary loss; a training program
+           moves the bias against the load after every step
+           (`expert_bias_update`, as lfm2's).  `num_local_experts` /
+           `expert_offset` build one chip's share of every expert layer
+           (the router keeps its width).
+           Shared: the `n_shared_experts` shared experts are ONE SwiGLU
+           MLP of n_shared_experts x moe_intermediate_size (the published
+           code builds them so), computed alike on every chip.
+
+The train-program plumbing is `gpt2.lm_train_program`;
+`kanana2_reference.py` is the plain float32 statement of the same
+equations.
+"""
+
+from .. import framework, layers
+from ..layer_helper import LayerHelper
+from . import transformer as tfm
+from .gpt2 import _pa, lm_train_program, xent_cost
+from .lfm2 import balance_expert_biases
+
+__all__ = ["Kanana2Config", "kanana2_lm", "kanana2_lm_program"]
+
+# e_score_correction_bias is a buffer in the published modeling code, zero
+# at initialisation, and the training rule that moves it is not in the
+# config.  Here it is seeded non-zero, so that selection (score + bias)
+# and weights (score alone) differ from the first step, and balanced after
+# every training step, as lfm2's expert_bias (models/lfm2.py).
+_EXPERT_BIAS_STD = 0.1
+# what the family adds to the chosen scores' sum before it divides
+_NORM_TOPK_EPS = 1e-20
+# what a forward-only program leaves in the scope: every token's
+# cross-entropy, [B, T] float32 (an evaluation pairs it with a reference's)
+EVAL_ROWS = "kanana2_eval_rows"
+
+
+class Kanana2Config:
+    """kanana-2-30b-a3b-instruct-2601 under the keys of its published
+    config.json; subclass to shrink for tests or to cut to a chip's
+    share."""
+
+    vocab_size = 128256
+    hidden_size = 2048
+    intermediate_size = 6144       # width of the dense layers' MLP
+    moe_intermediate_size = 768    # width of one expert
+    num_hidden_layers = 48
+    first_k_dense_replace = 1
+    moe_layer_freq = 1
+    num_attention_heads = 32
+    num_key_value_heads = 32       # MLA: every head has its own k and v
+    kv_lora_rank = 512
+    q_lora_rank = None
+    qk_nope_head_dim = 128
+    qk_rope_head_dim = 64
+    v_head_dim = 128
+    n_routed_experts = 128         # the router's width
+    n_shared_experts = 2
+    num_experts_per_tok = 6
+    n_group = 1
+    topk_group = 1
+    scoring_func = "sigmoid"
+    topk_method = "noaux_tc"
+    norm_topk_prob = True
+    routed_scaling_factor = 2.448
+    rms_norm_eps = 1e-6
+    rope_theta = 1000000.0
+    rope_interleave = True
+    rope_scaling = None
+    max_position_embeddings = 32768
+    tie_word_embeddings = False
+    # a chip's share of every expert layer: None holds all the experts
+    num_local_experts = None
+    expert_offset = 0
+    partition_family = "gpt2"
+
+
+def _weight(base):
+    """normal(0, 0.02) for a matrix, ones for a norm's gain."""
+    return tfm._pa(base) if "norm" in base else _pa(base)
+
+
+def _check(hp):
+    """What the builder would have to guess, it refuses."""
+    if hp.n_group != 1 or hp.topk_group != 1:
+        raise NotImplementedError(
+            "n_group %r / topk_group %r: the router here chooses among all "
+            "experts at once (one group, where the group limit is the "
+            "identity)" % (hp.n_group, hp.topk_group))
+    if hp.scoring_func != "sigmoid" or hp.topk_method != "noaux_tc":
+        raise NotImplementedError(
+            "scoring_func %r / topk_method %r: the router here is sigmoid "
+            "scores with a selection bias (noaux_tc)"
+            % (hp.scoring_func, hp.topk_method))
+    if hp.q_lora_rank is not None:
+        raise NotImplementedError(
+            "q_lora_rank %r: latent_attention projects the query straight "
+            "from the hidden state" % (hp.q_lora_rank,))
+    if hp.rope_scaling is not None:
+        raise NotImplementedError(
+            "rope_scaling %r: rotary_embed has no scaled frequencies and "
+            "the softmax scale no mscale" % (hp.rope_scaling,))
+    if hp.moe_layer_freq != 1:
+        raise NotImplementedError(
+            "moe_layer_freq %r: every layer after the leading dense ones "
+            "is an expert layer here" % (hp.moe_layer_freq,))
+    if hp.num_key_value_heads != hp.num_attention_heads:
+        raise ValueError(
+            "num_key_value_heads %d is not num_attention_heads %d: latent "
+            "attention expands a key and a value for every head"
+            % (hp.num_key_value_heads, hp.num_attention_heads))
+
+
+def _swiglu_mlp(h, width, d, prefix):
+    """The three `fc` ops the fuse pass turns into `fused_swiglu`."""
+    gate = layers.fc(h, size=width, num_flatten_dims=2, act="swish",
+                     bias_attr=False, param_attr=_pa(prefix + "_gate.w"))
+    up = layers.fc(h, size=width, num_flatten_dims=2, bias_attr=False,
+                   param_attr=_pa(prefix + "_up.w"))
+    return layers.fc(layers.elementwise_mul(gate, up), size=d,
+                     num_flatten_dims=2, bias_attr=False,
+                     param_attr=_pa(prefix + "_out.w"))
+
+
+def _experts(h, hp, is_test):
+    routed, _, _ = layers.moe_ffn(
+        h, hp.n_routed_experts, hp.moe_intermediate_size,
+        hp.num_experts_per_tok, norm_topk_prob=hp.norm_topk_prob,
+        router="sigmoid",
+        expert_bias_attr=_pa("moe_e_score_correction_bias.b",
+                             std=_EXPERT_BIAS_STD),
+        num_local_experts=hp.num_local_experts,
+        expert_offset=hp.expert_offset,
+        routed_scaling_factor=hp.routed_scaling_factor,
+        norm_topk_eps=_NORM_TOPK_EPS,
+        router_attr=_pa("moe_router.w"), gate_up_attr=_pa("moe_gate_up.w"),
+        down_attr=_pa("moe_down.w"),
+        stat_name=("moe_tokens_per_expert_eval" if is_test
+                   else "moe_tokens_per_expert"))
+    if not hp.n_shared_experts:
+        return routed
+    with framework.name_scope("shared_expert"):
+        shared = _swiglu_mlp(
+            h, hp.n_shared_experts * hp.moe_intermediate_size,
+            hp.hidden_size, "shared_ffn")
+        return layers.elementwise_add(shared, routed)
+
+
+def _block(x, hp, i, is_test):
+    h = layers.rms_norm(x, hp.rms_norm_eps,
+                        param_attr=tfm._pa("attn_norm.w"))
+    a = tfm.latent_attention(
+        h, hp.num_attention_heads, hp.kv_lora_rank, hp.qk_nope_head_dim,
+        hp.qk_rope_head_dim, hp.v_head_dim, norm_eps=hp.rms_norm_eps,
+        rotary_base=float(hp.rope_theta),
+        rotary_interleaved=bool(hp.rope_interleave), param_attr=_weight)
+    x = layers.elementwise_add(x, a)
+    h = layers.rms_norm(x, hp.rms_norm_eps, param_attr=tfm._pa("ffn_norm.w"))
+    m = (_swiglu_mlp(h, hp.intermediate_size, hp.hidden_size, "ffn")
+         if i < hp.first_k_dense_replace else _experts(h, hp, is_test))
+    return layers.elementwise_add(x, m)
+
+
+def kanana2_lm(ids, hp=Kanana2Config, is_test=False):
+    """[B, T] token ids -> [B, T, vocab] next-token logits; the head is
+    its own matrix (`tie_word_embeddings` false)."""
+    _check(hp)
+    if hp.tie_word_embeddings:
+        raise NotImplementedError("the published head is untied")
+    x = layers.embedding(ids, size=[hp.vocab_size, hp.hidden_size],
+                         param_attr=_pa("emb.w"))
+    for i in range(hp.num_hidden_layers):
+        x = _block(x, hp, i, is_test)
+    x = layers.rms_norm(x, hp.rms_norm_eps,
+                        param_attr=tfm._pa("final_norm.w"))
+    return layers.fc(x, size=hp.vocab_size, num_flatten_dims=2,
+                     bias_attr=False, param_attr=_pa("softmax_out.w"))
+
+
+def _token_cost(ids, labels, hp, seq_len, is_test):
+    cost = xent_cost(kanana2_lm(ids, hp, is_test), labels)  # [B, T, 1]
+    if is_test:
+        rows = LayerHelper(EVAL_ROWS).create_global_variable(
+            name=EVAL_ROWS, persistable=True, dtype="float32",
+            shape=[-1, seq_len])
+        rows.stop_gradient = True
+        layers.assign(layers.reshape(cost, [-1, seq_len]), output=rows)
+    return cost
+
+
+def kanana2_lm_program(hp=Kanana2Config, seq_len=4096, lr=4e-4,
+                       is_test=False, use_bf16=False, mesh=None):
+    """(main, startup, feeds, [loss, token_count]) as gpt2_lm_program
+    returns them; a training step ends with the selection biases'
+    balancing step, as lfm2_lm_program's; an `is_test` program leaves
+    every token's cost in the scope under EVAL_ROWS."""
+    main, startup, feeds, fetches = lm_train_program(
+        lambda ids, labels: (_token_cost(ids, labels, hp, seq_len, is_test),
+                             None),
+        seq_len, lr, is_test, use_bf16, mesh, hp.partition_family)
+    if not is_test:
+        balance_expert_biases(main)
+    return main, startup, feeds, fetches

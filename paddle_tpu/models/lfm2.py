@@ -60,7 +60,7 @@ class LFM2MoEConfig:
     num_experts_per_tok = 4
     norm_topk_prob = True
     use_expert_bias = True
-    routed_scaling_factor = 1.0    # published; the builder knows no other
+    routed_scaling_factor = 1.0    # published: lowers to no instruction
     norm_eps = 1e-5
     rope_theta = 1000000.0
     conv_L_cache = 3
@@ -111,6 +111,7 @@ def _experts(h, hp, is_test):
         norm_topk_prob=hp.norm_topk_prob, router="sigmoid",
         expert_bias_attr=bias, num_local_experts=hp.num_local_experts,
         expert_offset=hp.expert_offset,
+        routed_scaling_factor=hp.routed_scaling_factor,
         router_attr=_pa("moe_router.w"), gate_up_attr=_pa("moe_gate_up.w"),
         down_attr=_pa("moe_down.w"),
         stat_name=("moe_tokens_per_expert_eval" if is_test
@@ -138,10 +139,6 @@ def _block(x, hp, i, is_test):
 def lfm2_lm(ids, hp=LFM2MoEConfig, is_test=False):
     """[B, T] token ids -> [B, T, vocab] next-token logits; the head is
     the embedding (config.json has no key for it; the family ties)."""
-    if hp.routed_scaling_factor != 1:
-        raise NotImplementedError(
-            "routed_scaling_factor %r: moe_ffn multiplies by none (the "
-            "published value is 1)" % (hp.routed_scaling_factor,))
     if len(hp.layer_types) != hp.num_hidden_layers:
         raise ValueError("layer_types names %d layers, num_hidden_layers "
                          "is %d" % (len(hp.layer_types),
@@ -156,7 +153,7 @@ def lfm2_lm(ids, hp=LFM2MoEConfig, is_test=False):
     return layers.matmul(x, emb, transpose_y=True)
 
 
-def _balance_expert_biases(main):
+def balance_expert_biases(main):
     """After the optimizer, one `expert_bias_update` per mixture layer:
     the layer's selection bias follows the step's own counts."""
     block = main.global_block()
@@ -180,5 +177,5 @@ def lfm2_lm_program(hp=LFM2MoEConfig, seq_len=8192, lr=4e-4, is_test=False,
                              None),
         seq_len, lr, is_test, use_bf16, mesh, hp.partition_family)
     if hp.use_expert_bias and not is_test:
-        _balance_expert_biases(main)
+        balance_expert_biases(main)
     return main, startup, feeds, fetches

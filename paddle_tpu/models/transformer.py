@@ -11,7 +11,7 @@ paddle_tpu.parallel (GSPMD replaces the DistributeTranspiler).
 
 import numpy as np
 
-from .. import layers, unique_name
+from .. import framework, layers, unique_name
 from ..initializer import Normal
 from ..param_attr import ParamAttr
 
@@ -24,6 +24,7 @@ def _pa(base):
 __all__ = [
     "ModelHyperParams",
     "transformer",
+    "latent_attention",
     "wmt_transformer_program",
     "transformer_logits_program",
     "greedy_translate",
@@ -144,6 +145,11 @@ def multi_head_attention(
     the head split, and so before rotary.  qk_norm="head" normalises every
     head on its own over head_dim (one [head_dim] weight for q's heads,
     one for k's, as LFM2 does), after the head split and before rotary.
+
+    q, k and v are of ONE head width, d_model / n_head, and rotary turns
+    the whole head; the flash kernel takes it at 64 or 128.  Scores of
+    another width than the values, and a decoupled rotary part, are
+    `latent_attention`'s.
 
     RAGGED cache mode (the continuous-batching serving step): a cache
     dict carrying "pos_rows" [B] + "width_rows" [B] (and "pos_mat"
@@ -355,6 +361,87 @@ def multi_head_attention(
     ctx = layers.reshape(ctx, [b, t, d_model])
     return layers.fc(ctx, size=d_model, num_flatten_dims=2, bias_attr=False,
                      param_attr=pa("mha_o.w"))
+
+
+def latent_attention(
+    x, n_head, kv_lora_rank, qk_nope_head_dim, qk_rope_head_dim, v_head_dim,
+    norm_eps=1e-6, rotary_base=10000.0, rotary_interleaved=True,
+    param_attr=None,
+):
+    """Multi-head latent attention (MLA, DeepSeek-V2/V3), the causal
+    training path, beside `multi_head_attention`: keys and values are not
+    projected from x head by head but expanded from one `kv_lora_rank`-wide
+    latent that all heads share, and position goes through a decoupled
+    `qk_rope_head_dim`-wide rotary part, of which the KEY has one for all
+    heads.  Per token, h = x_t:
+
+      q            = h W_q                 -> [H, nope + rope]
+      [c, k_rot]   = h W_kv_a              -> [kv_lora_rank], [rope]
+      [k_nope, v]  = rms(c; own gain) W_kv_b -> [H, nope], [H, v_head_dim]
+      RoPE on q's last `rope` and on k_rot (`rotary_interleaved`: the
+      published (2i, 2i+1) pairing, see `layers.rotary_embed`)
+      o = softmax([q_nope, q_rot] [k_nope, k_rot]^T (nope + rope)^-0.5,
+                  causal) v                -> [H, v_head_dim]
+      out = concat(o) W_o
+
+    The scores are nope + rope wide and the values v_head_dim: one
+    `fused_attention` op takes both (the flash kernel at (192, 128) on the
+    chip, ops/nn_ops._flash_engages; dense XLA elsewhere).  No bias, no
+    query latent (the configurations that have one publish `q_lora_rank`;
+    none is built here), no dropout, no cache: serving keeps the latent
+    and the rotary key instead of K and V, which is another path.
+
+    Built under `name_scope("mla")` with inner scopes `down` (the
+    projections from x and the latent's norm), `up` (the expansion of the
+    latent), `rope`, `core` (the fused_attention op) and `out`, so the
+    lowered HLO carries them.  param_attr as `multi_head_attention`'s."""
+    pa = param_attr or _pa
+    d_model = int(x.shape[-1])
+    b, t = x.shape[0], x.shape[1]
+    d_qk = qk_nope_head_dim + qk_rope_head_dim
+
+    def heads(y, width):  # [B, T, H * width] -> [B, H, T, width]
+        return layers.transpose(
+            layers.reshape(y, [b, t, n_head, width]), [0, 2, 1, 3])
+
+    with framework.name_scope("mla"):
+        with framework.name_scope("down"):
+            q = layers.fc(x, size=n_head * d_qk, num_flatten_dims=2,
+                          bias_attr=False, param_attr=pa("mla_q.w"))
+            latent = layers.fc(x, size=kv_lora_rank + qk_rope_head_dim,
+                               num_flatten_dims=2, bias_attr=False,
+                               param_attr=pa("mla_kv_a.w"))
+            c, k_rot = layers.split(
+                latent, [kv_lora_rank, qk_rope_head_dim], dim=-1)
+            c = layers.rms_norm(c, epsilon=norm_eps,
+                                param_attr=pa("mla_kv_a_norm.w"))
+        with framework.name_scope("up"):
+            kv = layers.fc(c, size=n_head * (qk_nope_head_dim + v_head_dim),
+                           num_flatten_dims=2, bias_attr=False,
+                           param_attr=pa("mla_kv_b.w"))
+            k_nope, v = layers.split(
+                heads(kv, qk_nope_head_dim + v_head_dim),
+                [qk_nope_head_dim, v_head_dim], dim=-1)
+        with framework.name_scope("rope"):
+            q_nope, q_rot = layers.split(
+                heads(q, d_qk), [qk_nope_head_dim, qk_rope_head_dim], dim=-1)
+            q_rot = layers.rotary_embed(q_rot, base=rotary_base,
+                                        interleaved=rotary_interleaved)
+            # one rotary key for all heads, rotated once and then repeated
+            k_rot = layers.rotary_embed(
+                layers.reshape(k_rot, [b, 1, t, qk_rope_head_dim]),
+                base=rotary_base, interleaved=rotary_interleaved)
+            k_rot = layers.expand(k_rot, [1, n_head, 1, 1])
+            q = layers.concat([q_nope, q_rot], axis=3)
+            k = layers.concat([k_nope, k_rot], axis=3)
+        with framework.name_scope("core"):
+            ctx = layers.fused_attention(q, k, v, causal=True,
+                                         scale=d_qk ** -0.5)
+        with framework.name_scope("out"):
+            ctx = layers.reshape(layers.transpose(ctx, [0, 2, 1, 3]),
+                                 [b, t, n_head * v_head_dim])
+            return layers.fc(ctx, size=d_model, num_flatten_dims=2,
+                             bias_attr=False, param_attr=pa("mla_o.w"))
 
 
 def positionwise_ffn(x, d_inner, d_model, dropout_rate=0.0, is_test=False):

@@ -203,12 +203,14 @@ def route(x2, router_w, top_k, norm_topk_prob):
     return top_p, top_e, counts, jnp.stack([lb, z])
 
 
-def route_sigmoid(x2, router_w, bias, top_k, norm_topk_prob):
-    """The sigmoid router with a selection bias (LFM2's):
-    s = sigmoid(logits) in float32; the experts are the top-k of s + bias,
-    their weights the UNBIASED s, renormalised over the chosen with the
-    published 1e-6.  The bias is a buffer: no gradient.  No auxiliary
-    loss: aux is zeros.  Same returns as `route`."""
+def route_sigmoid(x2, router_w, bias, top_k, norm_topk_prob, norm_eps=1e-6):
+    """The sigmoid router with a selection bias (LFM2's, DeepSeek-V3's
+    `noaux_tc` with one group): s = sigmoid(logits) in float32; the
+    experts are the top-k of s + bias, their weights the UNBIASED s,
+    renormalised over the chosen with the family's `norm_eps` (LFM2
+    publishes 1e-6, DeepSeek-V3 1e-20).  The bias is a buffer: no
+    gradient.  No auxiliary loss: aux is zeros.  Same returns as
+    `route`."""
     n_experts = router_w.shape[-1]
     s = jax.nn.sigmoid(_router_logits(x2, router_w))
     chooser = s if bias is None else s + jax.lax.stop_gradient(
@@ -219,7 +221,7 @@ def route_sigmoid(x2, router_w, bias, top_k, norm_topk_prob):
     top_p = jnp.where(top_e[..., None] == jnp.arange(n_experts),
                       s[:, None, :], 0.0).sum(-1)
     if norm_topk_prob:
-        top_p = top_p / (top_p.sum(-1, keepdims=True) + 1e-6)
+        top_p = top_p / (top_p.sum(-1, keepdims=True) + norm_eps)
     return (top_p, top_e, _tokens_per_expert(top_e, n_experts),
             jnp.zeros((2,), jnp.float32))
 
@@ -232,9 +234,12 @@ def _moe_ffn(ctx, ins, attrs):
     gathered rows once), DownW [E_held, f, d], optionally ExpertBias [E]
     (sigmoid router: added to the scores for the selection alone).
     Attributes: top_k, norm_topk_prob, router "softmax" (default) or
-    "sigmoid", expert_offset (0): the op holds
-    experts [expert_offset, expert_offset + E_held) and leaves out what
-    the others would add.  Outputs: Y in the experts' dtype,
+    "sigmoid", norm_topk_eps (1e-6: what the sigmoid router adds to the
+    chosen scores' sum before it divides), routed_scaling_factor (1: the
+    chosen experts' weights are multiplied by it after the
+    renormalisation; 1 lowers to no instruction), expert_offset (0): the
+    op holds experts [expert_offset, expert_offset + E_held) and leaves
+    out what the others would add.  Outputs: Y in the experts' dtype,
     TokensPerExpert [E] int32 (the router's decisions over all E),
     AuxLoss [2] f32 (load-balance, z; zeros for the sigmoid router).  The
     experts compute in GateUpW's dtype (bf16 under AMP) with f32
@@ -259,9 +264,13 @@ def _moe_ffn(ctx, ins, attrs):
         if attrs.get("router", "softmax") == "sigmoid":
             bias = ins["ExpertBias"][0] if ins.get("ExpertBias") else None
             top_p, top_e, counts, aux = route_sigmoid(
-                x2, router_w, bias, k, norm)
+                x2, router_w, bias, k, norm,
+                float(attrs.get("norm_topk_eps", 1e-6)))
         else:
             top_p, top_e, counts, aux = route(x2, router_w, k, norm)
+        scaling = float(attrs.get("routed_scaling_factor", 1.0))
+        if scaling != 1.0:
+            top_p = top_p * scaling
     with jax.named_scope("dispatch"):
         sort_key, group_sizes, live = top_e.reshape(-1), counts, None
         if held != n_experts:

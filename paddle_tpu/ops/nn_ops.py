@@ -1137,7 +1137,7 @@ def _fused_attention(ctx, ins, attrs):
             "across the pallas and dense paths)")
     scale = attrs.get("scale") or 1.0 / (q.shape[-1] ** 0.5)
     b, h, t, d = q.shape
-    tk = k.shape[2]
+    tk, dv = k.shape[2], v.shape[3]
     # chunked-decode global query offset: query i at position QStart+i,
     # keys at their cache indices — Tq may differ from Tk.  A size-1
     # QStart is the classic scalar offset (one chunk position for the
@@ -1153,6 +1153,10 @@ def _fused_attention(ctx, ins, attrs):
             raise ValueError(
                 "fused_attention: QStart owns the causal cutoffs — "
                 "Bias/SegmentIds are not combinable with it")
+        if dv != d:
+            raise ValueError(
+                "fused_attention: the QStart (cached decode) paths take V "
+                "at Q's width, got %d against %d" % (dv, d))
         return {"Out": [_qstart_attention(q, k, v, qstart, scale, window)]}
     if causal and t != tk:
         raise ValueError(
@@ -1160,7 +1164,7 @@ def _fused_attention(ctx, ins, attrs):
         )
     qf = q.reshape(b * h, t, d)
     kf = k.reshape(b * h, tk, d)
-    vf = v.reshape(b * h, tk, d)
+    vf = v.reshape(b * h, tk, dv)
     kbias = kbias_b = seg_b = None
     if ins.get("Bias"):
         # additive key-padding bias, rank-1 in the key axis: [B, Tk] (or any
@@ -1187,12 +1191,14 @@ def _fused_attention(ctx, ins, attrs):
     # lowering can see: the blockwise kernel where _flash_engages says the
     # step is placed on a TPU and the shape is one the chip sweep found
     # it ahead at, dense XLA everywhere else.  No flag is read here.
-    if _flash_engages(ctx, t, tk, d):
+    if _flash_engages(ctx, t, tk, d, dv):
         from .kernel_tuning import note_kernel
         from .pallas_kernels import flash_attention
         from .spmd_epilogue import mesh_ctx, spmd_flash_attention
 
         note_kernel("attention")
+        if dv != d:  # which widths the kernel engaged with, when not one
+            note_kernel("attention_qk%d_v%d" % (d, dv))
         mc, blk = mesh_ctx(), _flash_block(t)
         if mc is None:
             out = flash_attention(qf, kf, vf, kbias, causal, float(scale),
@@ -1200,11 +1206,11 @@ def _fused_attention(ctx, ins, attrs):
         else:  # rows over dp, heads over mp: the sharding is the op's
             out = spmd_flash_attention(
                 mc, q, k, v, kbias_b, seg_b, causal, float(scale), blk, blk,
-                window).reshape(b * h, t, d)
+                window).reshape(b * h, t, dv)
     else:
         out = _dense_attention(qf, kf, vf, causal, float(scale), kbias,
                                window=window, seg=seg)
-    return {"Out": [out.reshape(b, h, t, d)]}
+    return {"Out": [out.reshape(b, h, t, dv)]}
 
 
 # When fused_attention's training path takes the blockwise kernel
@@ -1225,14 +1231,24 @@ def _flash_block(t):
     return next((b for b in _FLASH_BLOCKS if t % b == 0), t)
 
 
-def _flash_engages(ctx, tq, tk, d):
+# (width of Q and K, width of V) the kernel takes: one head width, 64 or
+# 128, or latent attention's 192-wide scores (128 without position + 64
+# rotary) over 128-wide values, the score tile ONE 192-wide contraction
+# (tools/mla_kernel_sweep.py on a v5e; the table is in CHANGES.md, PR 37).
+_FLASH_WIDTHS = ((64, 64), (128, 128), (192, 128))
+
+
+def _flash_engages(ctx, tq, tk, d, dv=None):
     """Self-attention on a TPU-placed step, T a multiple of 128 at or
-    above _FLASH_MIN_T, head dim 64 or 128.  The platform is the placed
-    device's (LowerCtx.platform, which the Executor states), the
-    process's default backend only where a caller did not say."""
+    above _FLASH_MIN_T, (Q/K width, V width) one of _FLASH_WIDTHS: 64 or
+    128 for both, or 192 over 128 (V's width is Q's where a caller gives
+    none).  The platform is the placed device's (LowerCtx.platform, which
+    the Executor states), the process's default backend only where a
+    caller did not say."""
     platform = getattr(ctx, "platform", None) or jax.default_backend()
     return (platform == "tpu" and tq == tk and tq % 128 == 0
-            and tq >= _FLASH_MIN_T and d in (64, 128))
+            and tq >= _FLASH_MIN_T
+            and (d, d if dv is None else dv) in _FLASH_WIDTHS)
 
 
 @register("sequence_conv")
@@ -1500,6 +1516,9 @@ def _rotary_embed(ctx, ins, attrs):
     to per-head projections [B, H, T, Dh].  Pos: optional int positions
     [T] (defaults to arange(T)); the cached decode path feeds the single
     current position so cache-resident keys are stored pre-rotated.
+    Attribute `interleaved` (False): the input's pairs are (2i, 2i+1), as
+    DeepSeek-V3's weights are published; it is de-interleaved first and
+    the result is left in the rotate-half order.
     Beyond-reference (the reference era used learned/sinusoid absolute
     positions); standard in modern decoder LMs."""
     x = ins["X"][0]
@@ -1510,6 +1529,9 @@ def _rotary_embed(ctx, ins, attrs):
             "rotary_embed: head dim must be even (rotate-half pairs), "
             "got %d" % x.shape[-1])
     half = x.shape[-1] // 2
+    if attrs.get("interleaved", False):
+        # published pairs (2i, 2i+1) -> (i, i + half); the result stays so
+        x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], -1)
     freq = base ** (-jnp.arange(half, dtype=jnp.float32) / half)
     if ins.get("Pos") and ins["Pos"][0].ndim == 2:
         # PER-ROW positions [B, T] (ragged serving step: each pool slot
@@ -1736,7 +1758,12 @@ def _fattn_infer(op, ins):
         raise InferError(
             "fused_attention head-dim mismatch: Q%s vs K%s"
             % (q.shape, k.shape))
-    return {"Out": [VarInfo(q.shape if q else None, q.dtype if q else None)]}
+    # [B, H, Tq, d_v]: V's width, which is Q's everywhere but under latent
+    # attention
+    shape = q.shape if q else None
+    if shape is not None and v is not None and v.shape is not None:
+        shape = tuple(shape[:-1]) + (v.shape[-1],)
+    return {"Out": [VarInfo(shape, q.dtype if q else None)]}
 
 
 register_infer("seq_cache_write", req_ins=("Cache", "New", "Pos"))(

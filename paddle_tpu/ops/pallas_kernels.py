@@ -246,7 +246,10 @@ def _flash_blocks(Tq, Tk, block_q, block_k, causal):
 
 def _flash_fwd(q, k, v, kbias, causal, scale, block_q, block_k, window=0,
                qoff=None, seg=None, qvec=None, interpret=None):
-    """q: [BH, Tq, d], k/v: [BH, Tk, d], kbias: [BH, Tk] additive key bias.
+    """q: [BH, Tq, d], k: [BH, Tk, d], v: [BH, Tk, dv] (the result is
+    [BH, Tq, dv]; dv is d everywhere but under latent attention, whose
+    scores are 192 wide over 128-wide values), kbias: [BH, Tk] additive
+    key bias.
     window > 0 (causal only): sliding-window attention — each query sees
     only the last `window` key positions.  qoff: optional [1] int32 GLOBAL
     q-position base relative to k's (traced; SMEM scalar) — the ring
@@ -260,7 +263,7 @@ def _flash_fwd(q, k, v, kbias, causal, scale, block_q, block_k, window=0,
     from jax.experimental.pallas import tpu as pltpu
 
     BH, T, d = q.shape
-    Tk = k.shape[1]
+    Tk, dv = k.shape[1], v.shape[2]
     assert qoff is None or qvec is None, "qoff and qvec are exclusive"
     block_q, block_k = _flash_blocks(T, Tk, block_q, block_k,
                                      causal and qoff is None
@@ -284,7 +287,7 @@ def _flash_fwd(q, k, v, kbias, causal, scale, block_q, block_k, window=0,
                      memory_space=pltpu.VMEM),
         pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0),
+        pl.BlockSpec((1, block_k, dv), lambda b, i, j: (b, j, 0),
                      memory_space=pltpu.VMEM),
         pl.BlockSpec((1, 1, block_k), lambda b, i, j: (b, 0, j),
                      memory_space=pltpu.VMEM),
@@ -308,17 +311,17 @@ def _flash_fwd(q, k, v, kbias, causal, scale, block_q, block_k, window=0,
         grid=(BH, nq, nk),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0),
+            pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i),
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
-            _sds((BH, T, d), q.dtype, q, k, v),
+            _sds((BH, T, dv), q.dtype, q, k, v),
             _sds((BH, 1, T), jnp.float32, q, k, v),
         ],  # lse is over q rows; k-side shapes use Tk
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
@@ -429,7 +432,7 @@ def _flash_bwd(q, k, v, kbias, o, lse, do, causal, scale, block_q, block_k,
     from jax.experimental.pallas import tpu as pltpu
 
     BH, T, d = q.shape
-    Tk = k.shape[1]
+    Tk, dv = k.shape[1], v.shape[2]
     assert qoff is None or qvec is None, "qoff and qvec are exclusive"
     block_q, block_k = _flash_blocks(T, Tk, block_q, block_k,
                                      causal and qoff is None
@@ -457,6 +460,11 @@ def _flash_bwd(q, k, v, kbias, o, lse, do, causal, scale, block_q, block_k,
                             memory_space=pltpu.VMEM)
     k_spec_q = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0),
                             memory_space=pltpu.VMEM)
+    # v and do are dv wide (d everywhere but under latent attention)
+    v_spec_q = pl.BlockSpec((1, block_k, dv), lambda b, i, j: (b, j, 0),
+                            memory_space=pltpu.VMEM)
+    do_spec_q = pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0),
+                             memory_space=pltpu.VMEM)
     kb_spec_q = pl.BlockSpec((1, 1, block_k), lambda b, i, j: (b, 0, j),
                              memory_space=pltpu.VMEM)
     row_spec_q = pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i),
@@ -471,8 +479,8 @@ def _flash_bwd(q, k, v, kbias, o, lse, do, causal, scale, block_q, block_k,
                           window=int(window), has_qoff=has_qoff,
                           has_seg=seg is not None),
         grid=(BH, nq, nk),
-        in_specs=smem + [q_spec_q, k_spec_q, k_spec_q, kb_spec_q]
-        + seg_specs_q + [q_spec_q, row_spec_q, row_spec_q],
+        in_specs=smem + [q_spec_q, k_spec_q, v_spec_q, kb_spec_q]
+        + seg_specs_q + [do_spec_q, row_spec_q, row_spec_q],
         out_specs=q_spec_q,
         out_shape=_sds((BH, T, d), q.dtype, q, k, v, do),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
@@ -485,6 +493,10 @@ def _flash_bwd(q, k, v, kbias, o, lse, do, causal, scale, block_q, block_k,
                             memory_space=pltpu.VMEM)
     k_spec_k = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0),
                             memory_space=pltpu.VMEM)
+    v_spec_k = pl.BlockSpec((1, block_k, dv), lambda b, i, j: (b, i, 0),
+                            memory_space=pltpu.VMEM)
+    do_spec_k = pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, j, 0),
+                             memory_space=pltpu.VMEM)
     kb_spec_k = pl.BlockSpec((1, 1, block_k), lambda b, i, j: (b, 0, i),
                              memory_space=pltpu.VMEM)
     row_spec_k = pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, j),
@@ -496,17 +508,17 @@ def _flash_bwd(q, k, v, kbias, o, lse, do, causal, scale, block_q, block_k,
                           window=int(window), has_qoff=has_qoff,
                           has_seg=seg is not None),
         grid=(BH, nk, nq),
-        in_specs=smem + [q_spec_k, k_spec_k, k_spec_k, kb_spec_k]
-        + seg_specs_k + [q_spec_k, row_spec_k, row_spec_k],
-        out_specs=[k_spec_k, k_spec_k, kb_spec_k],
+        in_specs=smem + [q_spec_k, k_spec_k, v_spec_k, kb_spec_k]
+        + seg_specs_k + [do_spec_k, row_spec_k, row_spec_k],
+        out_specs=[k_spec_k, v_spec_k, kb_spec_k],
         out_shape=[
             _sds((BH, Tk, d), k.dtype, q, k, v, do),
-            _sds((BH, Tk, d), v.dtype, q, k, v, do),
+            _sds((BH, Tk, dv), v.dtype, q, k, v, do),
             _sds((BH, 1, Tk), jnp.float32, q, k, v, do),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, dv), jnp.float32),
             pltpu.VMEM((1, block_k), jnp.float32),
         ],
         compiler_params=_mosaic_params(),
@@ -559,6 +571,11 @@ def _dense_attention(q, k, v, causal, scale, kbias=None, window=0,
 # Against the two-kernel form it computes s, p and dp once instead of
 # twice (five matmuls a tile, not seven) and is one Mosaic call.
 _FUSED_BWD_DQ_BYTES = 4 * 2 ** 20  # [T, d] f32: T <= 8192 at d = 128
+# latent attention's 192-wide q: the same T <= 8192 (tools/
+# mla_kernel_sweep.py on a v5e, PR 37: one kernel 13.4 against two 17.5 ms
+# at T = 6144, 23.2 against 29.7 at 8192; narrower heads keep the limit
+# they were swept under)
+_FUSED_BWD_DQ_BYTES_WIDE = 6 * 2 ** 20
 
 
 def _flash_bwd_fused_kernel(*refs, block_q, block_k, nq, nk, causal, scale,
@@ -635,6 +652,7 @@ def _flash_bwd_fused(q, k, v, kbias, seg, o, lse, do, causal, scale, block_q,
     from jax.experimental.pallas import tpu as pltpu
 
     BH, T, d = q.shape
+    dv = v.shape[2]  # v, do and dv's width: d but under latent attention
     block_q, block_k = _flash_blocks(T, T, block_q, block_k, causal)
     nq, nk = T // block_q, T // block_k
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
@@ -644,9 +662,11 @@ def _flash_bwd_fused(q, k, v, kbias, seg, o, lse, do, causal, scale, block_q,
 
     q_spec = spec((1, block_q, d), lambda b, i, j: (b, j, 0))
     k_spec = spec((1, block_k, d), lambda b, i, j: (b, i, 0))
+    do_spec = spec((1, block_q, dv), lambda b, i, j: (b, j, 0))
+    v_spec = spec((1, block_k, dv), lambda b, i, j: (b, i, 0))
     qrow_spec = spec((1, 1, block_q), lambda b, i, j: (b, 0, j))
     krow_spec = spec((1, 1, block_k), lambda b, i, j: (b, 0, i))
-    in_specs, args = [q_spec, k_spec, k_spec], [q, k, v]
+    in_specs, args = [q_spec, k_spec, v_spec], [q, k, v]
     if kbias is not None:
         in_specs.append(krow_spec)
         args.append(kbias.reshape(BH, 1, T))
@@ -654,15 +674,15 @@ def _flash_bwd_fused(q, k, v, kbias, seg, o, lse, do, causal, scale, block_q,
         seg3 = seg.astype(jnp.int32).reshape(BH, 1, T)
         in_specs += [qrow_spec, krow_spec]
         args += [seg3, seg3]
-    in_specs += [q_spec, qrow_spec, qrow_spec]
+    in_specs += [do_spec, qrow_spec, qrow_spec]
     args += [do, lse.reshape(BH, 1, T), delta.reshape(BH, 1, T)]
-    out_specs = [spec((1, T, d), lambda b, i, j: (b, 0, 0)), k_spec, k_spec]
+    out_specs = [spec((1, T, d), lambda b, i, j: (b, 0, 0)), k_spec, v_spec]
     out_shape = [_sds((BH, T, d), q.dtype, q, k, v, do),
                  _sds((BH, T, d), k.dtype, q, k, v, do),
-                 _sds((BH, T, d), v.dtype, q, k, v, do)]
+                 _sds((BH, T, dv), v.dtype, q, k, v, do)]
     scratch = [pltpu.VMEM((T, d), jnp.float32),
                pltpu.VMEM((block_k, d), jnp.float32),
-               pltpu.VMEM((block_k, d), jnp.float32)]
+               pltpu.VMEM((block_k, dv), jnp.float32)]
     if kbias is not None:
         out_specs.append(krow_spec)
         out_shape.append(_sds((BH, 1, T), jnp.float32, q, k, v, do))
@@ -706,7 +726,8 @@ def _flash_fwd_call(q, k, v, kbias, seg, *, causal, scale, block_q, block_k,
 def _flash_bwd_call(q, k, v, kbias, seg, o, lse, do, *, causal, scale,
                     block_q, block_k, window, interpret):
     T, d = q.shape[1:]
-    if T == k.shape[1] and T * d * 4 <= _FUSED_BWD_DQ_BYTES:
+    if T == k.shape[1] and T * d * 4 <= (
+            _FUSED_BWD_DQ_BYTES if d <= 128 else _FUSED_BWD_DQ_BYTES_WIDE):
         return _flash_bwd_fused(q, k, v, kbias, seg, o, lse, do, causal,
                                 scale, block_q, block_k, window, interpret)
     kb = kbias if kbias is not None else jnp.zeros(k.shape[:2], jnp.float32)
@@ -727,8 +748,11 @@ def _flash_statics(q, causal, scale, block_q, block_k, window):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
 def flash_attention(q, k, v, kbias=None, causal=False, scale=None,
                     block_q=128, block_k=128, window=0, seg=None):
-    """Fused attention, q: [BH, Tq, d], k/v: [BH, Tk, d] (flash-style
-    online softmax): q, k, v reach the MXU in their own dtype, the scores,
+    """Fused attention, q: [BH, Tq, d], k: [BH, Tk, d], v: [BH, Tk, dv]
+    -> [BH, Tq, dv] (flash-style online softmax; dv may differ from d:
+    latent attention scores 192 wide over 128-wide values, the score
+    tile one 192-wide contraction): q, k, v reach the MXU in their own
+    dtype, the scores,
     the running max / sum and the saved logsumexp are f32 and never leave
     VMEM.  kbias: optional [BH, Tk] additive key bias (the padding-mask
     row, indexed by key position).  window > 0 (causal): sliding-window

@@ -327,6 +327,10 @@ def spmd_flash_attention(mc, q, k, v, kbias_b, seg_b, causal, scale, bq, bk,
 
     mesh, _rules, mp, nsh, dp_axis, ndp = mc
     b, h = q.shape[:2]
+    if v.shape[-1] != q.shape[-1]:
+        raise ValueError(
+            "spmd_flash_attention takes V at Q's width, got %d against %d"
+            % (v.shape[-1], q.shape[-1]))
     rows = _row_axis(dp_axis, ndp, b)
     heads = mp if (nsh > 1 and h % nsh == 0) else None
     p4, p2 = P(rows, heads, None, None), P(rows, None)

@@ -143,10 +143,12 @@ def program_flops(program, batch_hint=1):
                 continue
             b, h, tq, d = q
             tk = k[2]
+            v = _shape(blk, op.inputs.get("V", [""])[0], batch_hint)
+            dv = v[-1] if v and len(v) == 4 else d  # latent: 192 over 128
             window = int(op.attrs.get("window", 0) or 0)
             if window:  # sliding window: compute scales with the band
                 tk = min(tk, window)
-            total += factor * 2.0 * 2.0 * b * h * tq * tk * d
+            total += factor * 2.0 * b * h * tq * tk * (d + dv)
     return total
 
 

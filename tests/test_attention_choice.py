@@ -332,3 +332,145 @@ print("NO_PALLAS")
                        capture_output=True, text=True, timeout=600)
     assert r.returncode == 0 and "NO_PALLAS" in r.stdout, (
         r.stdout[-2000:] + r.stderr[-4000:])
+
+
+# --- V of another width than Q and K (latent attention) ---------------------
+def _qkv_wide(b, h, t, d=192, dv=128, seed=3):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return tuple(jax.random.normal(k, (b, h, t, w), jnp.float32).astype(
+        jnp.bfloat16) for k, w in zip(keys, (d, d, dv)))
+
+
+def _dense_wide(q, k, v, window=0, seg=None):
+    b, h, t, d = q.shape
+    flat = [a.reshape(b * h, t, a.shape[-1]) for a in (q, k, v)]
+    seg = None if seg is None else jnp.broadcast_to(
+        seg[:, None, :], (b, h, t)).reshape(b * h, t)
+    return pk._dense_attention(*flat, True, d ** -0.5, window=window,
+                               seg=seg).reshape(b, h, t, v.shape[-1])
+
+
+WIDE_CHOICE = [
+    ("kanana2_30b_a3b_train: scores 192 wide over 128-wide values", 6144,
+     192, 128, True),
+    ("one head width, as before: V's width unsaid", 1024, 128, None, True),
+    ("V as wide as the scores: not swept", 6144, 192, 192, False),
+    ("a narrower V under 128-wide scores: not swept", 1024, 128, 64, False),
+    ("192 over 128 under the length threshold", 256, 192, 128, False),
+]
+
+
+@pytest.mark.parametrize("what,t,d,dv,engages", WIDE_CHOICE,
+                         ids=[c[0].split(":")[0] for c in WIDE_CHOICE])
+def test_choice_table_takes_the_latent_widths(what, t, d, dv, engages):
+    assert nn_ops._flash_engages(TPU, t, t, d, dv) is engages, what
+
+
+@pytest.mark.parametrize("backward", ["one_kernel", "two_kernels"])
+def test_a_v_of_another_width_matches_dense_forward_and_gradients(
+        monkeypatch, backward):
+    """Q, K [B, H, T, 192] and V [B, H, T, 128] through the op placed on a
+    TPU (the kernel, interpreted here) against the dense lowering: the
+    result is [B, H, T, 128], and it and all three gradients agree, with
+    the one-kernel backward (what T <= 8192 takes at 192) and with the dq
+    and dk/dv kernels (what longer sequences take)."""
+    if backward == "two_kernels":
+        monkeypatch.setattr(pk, "_FUSED_BWD_DQ_BYTES_WIDE", 0)
+        jax.clear_caches()
+    q, k, v = _qkv_wide(1, 2, 512)
+    before = kt.attribution()["pallas_hits"].get("attention_qk192_v128", 0)
+    chosen = lambda q, k, v: _op(TPU, q, k, v)  # noqa: E731
+    out, grads = jax.jit(jax.value_and_grad(
+        lambda *a: _loss(chosen)(*a), argnums=(0, 1, 2)))(q, k, v)
+    assert (kt.attribution()["pallas_hits"]["attention_qk192_v128"]
+            > before)  # the widths the kernel engaged with, on record
+    ref, ref_grads = jax.jit(jax.value_and_grad(
+        lambda *a: _loss(_dense_wide)(*a), argnums=(0, 1, 2)))(q, k, v)
+    got = jax.jit(chosen)(q, k, v)
+    assert got.shape == (1, 2, 512, 128) and got.dtype == jnp.bfloat16
+    _close(got, _dense_wide(q, k, v))
+    np.testing.assert_allclose(float(out), float(ref), rtol=2e-2)
+    for g, r, like in zip(grads, ref_grads, (q, k, v)):
+        assert g.dtype == jnp.bfloat16 and g.shape == r.shape == like.shape
+        _close(g, r)
+    if backward == "two_kernels":
+        jax.clear_caches()  # the next test traces its own
+
+
+def test_a_v_of_another_width_on_the_cpu_is_the_dense_lowering():
+    q, k, v = _qkv_wide(2, 2, 64, d=24, dv=16)
+    got = _op(LowerCtx(platform="cpu"), q, k, v)
+    assert got.shape == (2, 2, 64, 16)
+    _close(got, _dense_wide(q, k, v), tol=1e-6)
+
+
+def test_window_and_segment_ids_stay_right_at_the_latent_widths():
+    """A sliding window and packed segments do not look at a width: the
+    kernel (interpreted) against the dense lowering, forward and the
+    gradients, with V narrower than Q and K."""
+    q, k, v = _qkv_wide(1, 2, 512, seed=4)
+    seg = jnp.asarray(np.repeat([[0, 1, 2, 3]], 128, -1).reshape(1, 512))
+    for attrs, kwargs in (({"window": 160}, {"window": 160}),
+                          ({}, {"seg": seg})):
+        ins = {"SegmentIds": [seg]} if "seg" in kwargs else {}
+
+        def chosen(q, k, v):
+            return nn_ops._fused_attention(
+                TPU, dict({"Q": [q], "K": [k], "V": [v]}, **ins),
+                dict({"causal": True}, **attrs))["Out"][0]
+
+        dense = lambda q, k, v: _dense_wide(q, k, v, **kwargs)  # noqa: E731
+        _close(jax.jit(chosen)(q, k, v), dense(q, k, v))
+        grads = jax.jit(jax.grad(_loss(chosen), argnums=(0, 1, 2)))(q, k, v)
+        want = jax.jit(jax.grad(_loss(dense), argnums=(0, 1, 2)))(q, k, v)
+        for g, r in zip(grads, want):
+            _close(g, r)
+
+
+def test_cached_decode_and_the_mesh_path_refuse_another_v_width():
+    """The QStart (cached decode) lowerings and spmd_flash_attention were
+    written for one head width and say so."""
+    from paddle_tpu.ops.spmd_epilogue import spmd_flash_attention
+
+    q, k, v = _qkv_wide(1, 2, 128, d=24, dv=16)
+    with pytest.raises(ValueError, match="QStart .* take V at Q's width"):
+        nn_ops._fused_attention(
+            TPU, {"Q": [q], "K": [k], "V": [v],
+                  "QStart": [jnp.zeros((1,), jnp.int32)]}, {"causal": True})
+    with pytest.raises(ValueError, match="takes V at Q's width"):
+        spmd_flash_attention((None,) * 6, q, k, v, None, None, True, 1.0,
+                             128, 128, 0)
+
+
+def test_layer_and_infer_rule_state_the_result_at_vs_width():
+    from paddle_tpu.analysis.infer import VarInfo, get_infer_rule
+
+    q = layers.data("q", shape=[2, 128, 24], dtype="float32")
+    v = layers.data("v", shape=[2, 128, 16], dtype="float32")
+    out = layers.fused_attention(q, q, v, causal=True)
+    assert tuple(out.shape) == (-1, 2, 128, 16) and out.dtype == q.dtype
+
+    class Op:
+        attrs = {}
+
+    shapes = {"Q": (4, 2, 128, 24), "K": (4, 2, 128, 24),
+              "V": (4, 2, 128, 16)}
+    got = get_infer_rule("fused_attention").fn(
+        Op, {s: [VarInfo(shape, "bfloat16")] for s, shape in shapes.items()})
+    assert tuple(got["Out"][0].shape) == (4, 2, 128, 16)
+    assert got["Out"][0].dtype == "bfloat16"
+
+
+@pytest.mark.parametrize("t, calls", [(4096, 2), (6144, 2), (16384, 3)])
+def test_latent_kernel_cross_lowers_for_the_tpu_on_this_host(monkeypatch, t,
+                                                             calls):
+    """32 heads of 192 over 128 at the cell's candidate lengths, blocks as
+    the lowering sets them: one forward and the one-kernel backward up to
+    T = 8192; the dq and dk/dv kernels beyond."""
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    qk = jax.ShapeDtypeStruct((1, 32, t, 192), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((1, 32, t, 128), jnp.bfloat16)
+    lowered = jax.jit(jax.grad(
+        _loss(lambda q, k, v: _op(TPU, q, k, v)), argnums=(0, 1, 2))).trace(
+            qk, qk, v).lower(lowering_platforms=("tpu",))
+    assert lowered.as_text().count("tpu_custom_call") == calls

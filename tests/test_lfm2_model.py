@@ -241,12 +241,21 @@ def test_a_training_step_counts_three_forwards_tied_head_included():
         3.0 * program_flops(forward, batch_hint=BATCH))
 
 
-def test_a_routed_scaling_factor_other_than_one_is_refused():
+def test_a_routed_scaling_factor_reaches_the_op():
+    """The published factor is 1 and lowers to no instruction; another is
+    `moe_ffn`'s attribute (it was refused before the op had one), and the
+    op list is the same either way."""
     class Scaled(HP):
         routed_scaling_factor = 2.5
 
-    with pytest.raises(NotImplementedError, match="routed_scaling_factor"):
-        lfm2.lfm2_lm_program(Scaled, seq_len=SEQ)
+    scaled, _, _, _ = lfm2.lfm2_lm_program(Scaled, seq_len=SEQ)
+    plain = _run(False)[4]
+    for main, factor in ((scaled, 2.5), (plain, 1.0)):
+        assert [op.attrs["routed_scaling_factor"]
+                for op in main.global_block().ops
+                if op.type == "moe_ffn"] == [factor] * 3
+    assert ([op.type for op in scaled.global_block().ops]
+            == [op.type for op in plain.global_block().ops])
 
 
 def test_layer_types_must_name_every_layer():

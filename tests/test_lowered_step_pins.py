@@ -1,0 +1,133 @@
+"""The lowered train step of GPT-2, OLMoE and LFM2, and a long-sequence
+attention core, as they were before the flash kernel learnt a V of another
+width than Q and K, `moe_ffn` a scaling factor and an epsilon, and
+`rotary_embed` a pairing (PR 37): each program is lowered for the TPU on
+this host (the kernels engage: T = 512, heads of 64 and 128), and its
+StableHLO text is digested with every Mosaic payload replaced by the
+digest of its module printed WITHOUT debug locations (a payload carries
+the kernels' source line numbers, which an edit anywhere above them in
+pallas_kernels.py moves).  The digests below were taken from commit
+ec9cdf7, the parent of PR 37, by this file's own `_digest`: the text an
+accepted cell's step lowers to did not change by a byte, and the kernels'
+modules did not change by an instruction.
+
+A later PR that changes one of these lowerings on purpose re-takes the
+digests from its own tree and says so."""
+
+import base64
+import hashlib
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+import paddle_tpu as fluid
+from paddle_tpu.core.trace import build_traced_function
+from paddle_tpu.models import gpt2, lfm2, olmoe
+from paddle_tpu.ops import pallas_kernels as pk
+
+SEQ = 512
+BODY = re.compile(r'\\22body\\22: \\22([^\\]*)\\22')
+
+
+class G(gpt2.GPT2Config):
+    vocab_size, n_ctx, d_model, n_layer, n_head = 512, 512, 128, 2, 2
+
+
+class O(olmoe.OLMoEConfig):
+    vocab_size, hidden_size, intermediate_size = 512, 128, 128
+    num_hidden_layers, num_attention_heads, num_key_value_heads = 2, 1, 1
+    num_experts, num_experts_per_tok = 8, 2
+
+
+class L(lfm2.LFM2MoEConfig):
+    vocab_size, hidden_size, intermediate_size = 512, 128, 128
+    moe_intermediate_size, num_hidden_layers, num_dense_layers = 128, 3, 1
+    layer_types = ["conv", "full_attention", "conv"]
+    num_attention_heads, num_key_value_heads = 2, 1
+    num_experts, num_experts_per_tok = 8, 2
+    num_local_experts, expert_offset = 2, 2
+
+
+PROGRAMS = {"gpt2": (gpt2.gpt2_lm_program, G),
+            "olmoe": (olmoe.olmoe_lm_program, O),
+            "lfm2": (lfm2.lfm2_lm_program, L)}
+
+# name -> (sha1 of the normalised text, Mosaic calls in it) at ec9cdf7
+BEFORE = {
+    "gpt2": ("df7ec28481d2c6f9e45f238963591867ce97786d", 3),
+    "olmoe": ("10b230dd86c559798a6caed82c488d59280933fd", 9),
+    "lfm2": ("acc9a6df62719e48e6213e06f252bfe6e90098db", 9),
+    "two_kernel_backward": ("3f2ec33fc054ccb7413c50a5286bea4b68cd0550", 3),
+}
+
+
+def _module_without_locations(payload):
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    ctx = ir.Context()
+    tpu.register_dialect(ctx)
+    ctx.allow_unregistered_dialects = True
+    ctx.load_all_available_dialects()
+    with ctx:
+        module = ir.Module.parse(base64.b64decode(payload))
+        return module.operation.get_asm(enable_debug_info=False)
+
+
+def _digest(text):
+    bodies = BODY.findall(text)
+    normalised = BODY.sub(
+        lambda m: hashlib.sha1(_module_without_locations(
+            m.group(1)).encode()).hexdigest(), text)
+    return hashlib.sha1(normalised.encode()).hexdigest(), len(bodies)
+
+
+def _step_text(build, hp):
+    main, startup, _, fetches = build(hp, seq_len=SEQ, lr=1e-3,
+                                      use_bf16=True)
+    scope = fluid.Scope()
+    for block in (main.global_block(), startup.global_block()):
+        for name, var in block.vars.items():
+            if var.persistable and all(int(d) >= 0 for d in var.shape):
+                scope.set(name, jax.ShapeDtypeStruct(
+                    tuple(int(d) for d in var.shape),
+                    jnp.dtype(str(var.dtype))))
+    feeds = {"ids": jax.ShapeDtypeStruct((2, SEQ), jnp.int32),
+             "labels": jax.ShapeDtypeStruct((2, SEQ), jnp.int32),
+             "loss_weight": jax.ShapeDtypeStruct((2, SEQ), jnp.float32)}
+    traced = build_traced_function(
+        main, 0, tuple(sorted(feeds)), [fetches[0].name], scope,
+        platform="tpu")
+
+    def shaped(n):
+        v = scope.find_var(n)
+        return jax.ShapeDtypeStruct(v.shape, v.dtype)
+
+    key = jax.eval_shape(lambda: jax.random.key(1, impl="rbg"))
+    return jax.jit(traced.fn).trace(
+        feeds, {n: shaped(n) for n in traced.ro_names},
+        {n: shaped(n) for n in traced.rw_names}, key).lower(
+            lowering_platforms=("tpu",)).as_text()
+
+
+def _two_kernel_text():
+    """A sequence that outgrows the one-kernel backward's dq scratch
+    (T = 16384 at 128): forward, dq and dk/dv kernels."""
+    x = jax.ShapeDtypeStruct((2, 16384, 128), jnp.bfloat16)
+    return jax.jit(jax.grad(
+        lambda q, k, v: jnp.sum(pk.flash_attention(
+            q, k, v, None, True, 128 ** -0.5, 1024, 1024).astype(
+                jnp.float32)), argnums=(0, 1, 2))).trace(x, x, x).lower(
+                    lowering_platforms=("tpu",)).as_text()
+
+
+@pytest.mark.parametrize("name", sorted(BEFORE))
+def test_the_lowered_step_is_what_it_was_before_pr_37(monkeypatch, name):
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    jax.clear_caches()  # an interpreted trace of these shapes would hide
+    text = (_two_kernel_text() if name == "two_kernel_backward"
+            else _step_text(*PROGRAMS[name]))
+    assert _digest(text) == BEFORE[name]
+    jax.clear_caches()  # and these would hide from a later interpreted one

@@ -444,3 +444,155 @@ def test_expert_bias_update_infer_rule():
     with pytest.raises(InferError, match="differ"):
         rule(Op, {"ExpertBias": [bias],
                   "TokensPerExpert": [VarInfo((E + 1,), "int32")]})
+
+
+# --- routed_scaling_factor, norm_topk_eps (DeepSeek-V3's router) ---
+def _noaux_tc(attrs, offset=4, held=2, scale_router=1.0):
+    """moe_ffn's lowering as kanana2 says its attributes, and
+    models/kanana2_reference.routed, on one share: (y, grads), (y, grads)
+    over (x, router, gate_up, down)."""
+    from paddle_tpu.core.registry import LowerCtx
+    from paddle_tpu.models import kanana2_reference
+    from paddle_tpu.ops import moe_ops
+
+    w = _weights("balanced")
+    sl = slice(offset, offset + held)
+    bias = jnp.asarray(_bias())
+    args = [jnp.asarray(w["x"]), jnp.asarray(w["router"]) * scale_router,
+            jnp.asarray(w["gate_up"][sl]), jnp.asarray(w["down"][sl])]
+    mix = jnp.asarray(w["mix"])
+
+    def op(x, wr, wgu, wd):
+        return moe_ops._moe_ffn(
+            LowerCtx(platform="cpu"),
+            {"X": [x], "RouterW": [wr], "ExpertBias": [bias],
+             "GateUpW": [wgu], "DownW": [wd]},
+            dict({"top_k": K, "norm_topk_prob": True, "router": "sigmoid",
+                  "expert_offset": offset}, **attrs))["Y"][0]
+
+    cfg = {"num_experts_per_tok": K, "expert_offset": offset,
+           "norm_topk_prob": True,
+           "routed_scaling_factor": attrs.get("routed_scaling_factor", 1.0)}
+
+    def reference(x, wr, wgu, wd):
+        return kanana2_reference.routed(cfg, x, wr, bias, wgu, wd)[0]
+
+    out = []
+    with jax.default_matmul_precision("highest"):
+        for fn in (op, reference):
+            y, grads = jax.value_and_grad(
+                lambda *a: (fn(*a) * mix).sum(), argnums=(0, 1, 2, 3))(*args)
+            out.append((fn(*args), y, grads))
+    return out
+
+
+def test_the_scaling_factor_and_the_families_epsilon_match_its_reference():
+    """routed_scaling_factor 2.448 and norm_topk_eps 1e-20 on a chip's
+    share, forward and every gradient, against the float32 loop over
+    experts of models/kanana2_reference.py; and the factor is a factor."""
+    attrs = {"routed_scaling_factor": 2.448, "norm_topk_eps": 1e-20}
+    (y, loss, grads), (want_y, want_loss, want_grads) = _noaux_tc(attrs)
+    np.testing.assert_allclose(y, want_y, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(loss, want_loss, rtol=1e-5)
+    for g, w in zip(grads, want_grads):
+        assert np.abs(g - w).max() <= 1e-4 * np.abs(w).max()
+    (plain, _, _), _ = _noaux_tc({"norm_topk_eps": 1e-20})
+    np.testing.assert_allclose(y, 2.448 * np.asarray(plain), rtol=1e-5,
+                               atol=1e-6)
+
+
+def test_the_two_epsilons_differ_where_the_chosen_scores_are_tiny():
+    """With every logit about -12 the chosen scores sum to ~1e-5: 1e-6 is
+    a tenth of that sum and the weights fall short of one; 1e-20 is
+    nothing beside it and they add up to one.  A layer built with the
+    other family's epsilon is told from the reference there."""
+    from paddle_tpu.ops import moe_ops
+
+    w = _weights("balanced")
+    x = jnp.asarray(w["x"]).at[:, 0].set(1.0)
+    wr = (jnp.asarray(w["router"]) * 1e-3).at[0].set(-12.0)
+    lfm2_p, top_e, _, _ = moe_ops.route_sigmoid(x, wr, None, K, True)
+    v3_p, v3_e, _, _ = moe_ops.route_sigmoid(x, wr, None, K, True, 1e-20)
+    np.testing.assert_array_equal(top_e, v3_e)
+    np.testing.assert_allclose(v3_p.sum(-1), 1.0, rtol=1e-5)
+    assert float(lfm2_p.sum(-1).max()) < 0.95
+    chosen = jnp.take_along_axis(_scores(x, wr), top_e, -1)
+    np.testing.assert_allclose(
+        v3_p, chosen / chosen.sum(-1, keepdims=True), rtol=1e-5)
+
+
+@pytest.mark.parametrize("router", ["softmax", "sigmoid"])
+def test_the_new_attributes_at_their_defaults_lower_to_no_instruction(
+        router):
+    """routed_scaling_factor 1 and norm_topk_eps 1e-6, said or unsaid, are
+    the jaxpr OLMoE's and LFM2's layers had: layers.moe_ffn now says both
+    on every op it builds."""
+    from paddle_tpu.core.registry import LowerCtx
+    from paddle_tpu.ops import moe_ops
+
+    def lowered(**more):
+        def f(x, wr, b, wgu, wd):
+            ins = {"X": [x], "RouterW": [wr], "GateUpW": [wgu],
+                   "DownW": [wd]}
+            if router == "sigmoid":
+                ins["ExpertBias"] = [b]
+            return moe_ops._moe_ffn(
+                LowerCtx(platform="cpu"), ins,
+                dict({"top_k": K, "norm_topk_prob": True, "router": router},
+                     **more))["Y"][0]
+
+        return str(jax.make_jaxpr(f)(*(
+            jax.ShapeDtypeStruct(shape, jnp.float32) for shape in (
+                (N, D), (D, E), (E,), (E, D, 2 * F), (E, F, D)))))
+
+    unsaid = lowered()
+    assert lowered(routed_scaling_factor=1.0, norm_topk_eps=1e-6) == unsaid
+    assert lowered(routed_scaling_factor=2.448) != unsaid
+    main = fluid.Program()
+    with fluid.program_guard(main, fluid.Program()):
+        layers.moe_ffn(layers.data("x", shape=[N, D],
+                                   append_batch_size=False), E, F, K)
+    (op,) = [o for o in main.global_block().ops if o.type == "moe_ffn"]
+    assert (op.attrs["routed_scaling_factor"], op.attrs["norm_topk_eps"]
+            ) == (1.0, 1e-6)
+
+
+def test_bf16_rows_into_the_router_change_the_chosen_experts():
+    """What the float32 router is for: the same rows rounded to bfloat16
+    before the router choose other experts for some tokens (a top-k is
+    discontinuous), and the layer's result moves by far more than
+    rounding the result would."""
+    from paddle_tpu.ops import moe_ops
+
+    rng = np.random.RandomState(2)
+    x = jnp.asarray(rng.randn(16384, D).astype("float32"))
+    wr = jnp.asarray(rng.randn(D, E).astype("float32"))
+    bias = jnp.asarray(_bias())
+    _, exact, _, _ = moe_ops.route_sigmoid(x, wr, bias, K, True, 1e-20)
+    _, rounded, _, _ = moe_ops.route_sigmoid(
+        x.astype(jnp.bfloat16).astype(jnp.float32),
+        wr.astype(jnp.bfloat16).astype(jnp.float32), bias, K, True, 1e-20)
+    flipped = (np.sort(exact, -1) != np.sort(rounded, -1)).any(-1)
+    assert 0 < flipped.sum() < 0.2 * len(flipped)
+
+
+@pytest.mark.parametrize("n_experts, top_k, want", [
+    (32, 4, [-0.7, 0.1, 0.1]), (128, 6, [-2.0333333, 0.1, 0.1])])
+def test_expert_bias_update_step_grows_with_the_routers_width(
+        n_experts, top_k, want):
+    """A router whose first top_k experts took every token: c / mean is
+    E / k, so the proportional step is (1 - E / k) rates: -0.7 over 32
+    with top-4, -2.03 over 128 with top-6, twice the range of a sigmoid
+    score (what a wide router's collapse meets: PERF.md, PR 37).  An
+    expert never chosen goes up by one rate whatever the width."""
+    from paddle_tpu.core.registry import LowerCtx
+    from paddle_tpu.ops import moe_ops
+
+    counts = np.zeros(n_experts, "int32")
+    counts[:top_k] = 6144
+    out = moe_ops._expert_bias_update(
+        LowerCtx(platform="cpu"),
+        {"ExpertBias": [jnp.zeros(n_experts, jnp.float32)],
+         "TokensPerExpert": [jnp.asarray(counts)]}, {})["ExpertBiasOut"][0]
+    np.testing.assert_allclose(
+        [out[0], out[top_k], out[n_experts - 1]], want, rtol=1e-5)

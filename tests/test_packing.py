@@ -181,7 +181,7 @@ def test_op_segment_ids_ride_the_flash_kernel_where_it_engages(monkeypatch):
 
     dense = run()
     monkeypatch.setattr(nn_ops, "_flash_engages",
-                        lambda ctx, tq, tk, d: tq == tk)
+                        lambda ctx, tq, tk, d, dv=None: tq == tk)
     flash = run()
     assert not np.array_equal(flash, dense)  # another lowering ran
     np.testing.assert_allclose(flash, dense, rtol=2e-5, atol=2e-6)

@@ -95,7 +95,7 @@ def test_fused_attention_op_dispatch_and_training(monkeypatch):
 
     def run(engage):
         monkeypatch.setattr(nn_ops, "_flash_engages",
-                            lambda ctx, tq, tk, d: engage)
+                            lambda ctx, tq, tk, d, dv=None: engage)
         import paddle_tpu.framework as fw
         from paddle_tpu.core import scope as scope_mod
         from paddle_tpu import unique_name

@@ -486,7 +486,8 @@ def test_sharded_train_step_lowers_for_tpu_without_chips(monkeypatch):
     # attention's training path reads no flag: say here what a TPU-placed
     # mesh and an engaged length would
     monkeypatch.setattr(nn_ops, "_flash_engages",
-                        lambda ctx, tq, tk, d: tq == tk and tq % 128 == 0)
+                        lambda ctx, tq, tk, d, dv=None: (tq == tk
+                                                         and tq % 128 == 0))
 
     class HP(tfm.ModelHyperParams):  # small, but Mosaic-legal blocks
         d_model, d_inner_hid, n_head, n_layer = 256, 512, 2, 1

@@ -666,6 +666,51 @@ BEFORE = {
 }
 
 
+def _scoped_lm_programs():
+    from paddle_tpu.models import kanana2, ouro
+
+    class U(ouro.OuroConfig):
+        vocab_size, hidden_size, intermediate_size = 256, 64, 96
+        num_hidden_layers, num_attention_heads, num_key_value_heads = 2, 2, 2
+        head_dim, total_ut_steps = 32, 3
+        layer_types = ["full_attention"] * 2
+
+    class K(kanana2.Kanana2Config):
+        vocab_size, hidden_size, intermediate_size = 256, 64, 96
+        moe_intermediate_size, num_hidden_layers = 32, 3
+        num_attention_heads = num_key_value_heads = 2
+        kv_lora_rank, qk_nope_head_dim, qk_rope_head_dim = 32, 16, 8
+        v_head_dim, n_routed_experts, num_experts_per_tok = 16, 8, 2
+
+    return {"ouro": (ouro.ouro_lm_program, U),
+            "kanana2": (kanana2.kanana2_lm_program, K)}
+
+
+# the builders whose ops DO carry name scopes (the digest reads them):
+# Ouro's as it was at PR 37's parent (ec9cdf7, computed there by the same
+# function), kanana2's as PR 37 made it
+SCOPED = {
+    ("ouro", False): ("bfdc9a33a9492d67483c6f5267264b11f824829d", 421),
+    ("ouro", True): ("8baa0cde1fdba6a009b7324ce94a57a105bb14ab", 630),
+    ("kanana2", False): ("f27302c28b0eb18e1f2ce6cac7d226866550c306", 240),
+    ("kanana2", True): ("1e9d62e1622bfbb69bdf45b99e31d572fe24f8a3", 390),
+}
+
+
+@pytest.mark.parametrize("model, use_bf16", sorted(SCOPED))
+def test_a_scoped_builders_op_list_is_what_it_was(model, use_bf16):
+    """Ouro's and kanana2's train programs, whose ops carry name scopes
+    (ut<t>, exit; mla > down | up | rope | core | out, shared_expert):
+    types, roles, variable names and scopes, in order.  A new attribute at
+    its default (moe_ffn's, rotary_embed's, fused_attention's V width)
+    leaves the digest alone."""
+    build, hp = _scoped_lm_programs()[model]
+    main, _, _, _ = build(hp, seq_len=16, lr=1e-3, use_bf16=use_bf16)
+    assert [op.type for op in main.global_block().ops
+            if "op_namescope" in op.attrs]
+    assert _op_list_digest(main) == SCOPED[model, use_bf16]
+
+
 @pytest.mark.parametrize("model, use_bf16", sorted(BEFORE))
 def test_a_program_built_under_no_name_scope_is_what_it_was(model, use_bf16):
     """No op of GPT-2's, OLMoE's or LFM2's train program carries a name

@@ -1,0 +1,104 @@
+"""Compile one benchmark cell's train step, at its real sizes, for a
+DESCRIBED (not attached) TPU v5e on this host, and print the TPU
+compiler's memory counts: what the chip's compiler would refuse costs no
+chip time (on-chip-measurement guide, section 2).  Nothing runs: no time,
+no result.  One-chip cells only.
+
+    JAX_PLATFORMS=cpu python tools/compile_cell_for_chip.py \
+        --workload kanana2_30b_a3b_train [--seq-len 6144]
+
+The process that describes the topology holds libtpu until it exits."""
+
+import argparse
+import importlib.util
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+
+def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seq-len", type=int, default=None,
+                    help="override the cell's seq_len")
+    ap.add_argument("--hlo", default=None,
+                    help="write the optimized HLO text here")
+    args = ap.parse_args()
+
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    import paddle_tpu as fluid
+    from paddle_tpu.core.trace import build_traced_function
+
+    # benchmark/run.py as a module: the registry is read as it reads it
+    run_spec = importlib.util.spec_from_file_location(
+        "benchmark_run", os.path.join(ROOT, "benchmark", "run.py"))
+    run = importlib.util.module_from_spec(run_spec)
+    run_spec.loader.exec_module(run)
+    spec = run.load_json(ROOT, "BENCHMARK.json")
+    cell = run.find(spec["workloads"], args.workload, "workload")
+    if int(cell["chips"]) != 1:
+        raise SystemExit("compile_cell_for_chip: one-chip cells only")
+    cfg = run.merged(run.load_json(ROOT, run.find(
+        spec["configs"], cell["config"], "config")["file"]), False)
+    work = run.merged(run.load_json(
+        run.BENCH_DIR, "workloads", cell["name"] + ".json"), False)
+    if args.seq_len:
+        work["seq_len"] = args.seq_len
+    adapter = run.load_module("adapters", cfg["adapter"])
+
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    chip = SingleDeviceSharding(topo.devices[0])
+    built = adapter.build(cfg, work)
+    main_p, startup = built["main"], built["startup"]
+    scope = fluid.Scope()
+    for block in (main_p.global_block(), startup.global_block()):
+        for name, var in block.vars.items():
+            if var.persistable and all(int(d) >= 0 for d in var.shape):
+                scope.set(name, jax.ShapeDtypeStruct(
+                    tuple(int(d) for d in var.shape),
+                    jnp.dtype(str(var.dtype))))
+    batch = adapter.make_batch(cfg, work, 0)
+    traced = build_traced_function(
+        main_p, 0, tuple(sorted(batch)), [built["loss"].name], scope,
+        platform="tpu")
+
+    def shaped(n):
+        v = scope.find_var(n)
+        return jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=chip)
+
+    key = jax.eval_shape(lambda: jax.random.key(1, impl="rbg"))
+    compiled = jax.jit(traced.fn, donate_argnums=(2,)).lower(
+        {n: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip)
+         for n, a in batch.items()},
+        {n: shaped(n) for n in traced.ro_names},
+        {n: shaped(n) for n in traced.rw_names},
+        jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=chip),
+    ).compile()
+    m = compiled.memory_analysis()
+    gib = 2.0 ** 30
+    print("cell %s seq_len %d: arguments %.2f GiB, temporaries %.2f GiB, "
+          "output %.2f GiB, aliased %.2f GiB, generated code %.2f GiB: "
+          "arguments + temporaries + output - aliased = %.2f GiB"
+          % (cell["name"], int(work["seq_len"]),
+             m.argument_size_in_bytes / gib, m.temp_size_in_bytes / gib,
+             m.output_size_in_bytes / gib, m.alias_size_in_bytes / gib,
+             m.generated_code_size_in_bytes / gib,
+             (m.argument_size_in_bytes + m.temp_size_in_bytes
+              + m.output_size_in_bytes - m.alias_size_in_bytes) / gib))
+    text = compiled.as_text()
+    print("tpu_custom_call instructions: %d" % text.count("tpu_custom_call"))
+    if args.hlo:
+        with open(args.hlo, "w") as f:
+            f.write(text)
+
+
+if __name__ == "__main__":
+    main()

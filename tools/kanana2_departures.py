@@ -1,0 +1,124 @@
+"""Does the comparison that decides `correct` in kanana2_30b_a3b_train
+catch a wrong model, and the stated precision's neighbour below?  Trains
+the cell's program as the benchmark does (the same adapter, batches and
+seeds) and, after each of `--steps` steps (98 is about what a 20 s window
+reaches after its 8 warm-up steps, 110 what a traced run's 12 further
+steps do), makes the harness's own comparison (`abs(program loss -
+adapter.reference_loss(...)) <= adapter.TOLERANCE`, inside the scope the
+forward-only program ran in, so the adapter pairs the program's rows with
+the reference's and answers NaN where a paired reading is over its limit)
+against the adapter's plain reference on the sampled row with the same
+weights: exactly, with each of its deliberate errors (adapter.DEPARTURES:
+rotary over all 192, the scale 128^-0.5, kv_a_layernorm left out, the
+rotate-half pairing on the published weights, routed_scaling_factor left
+out, the shared expert left out, the bias in the weights as well as the
+selection), and exactly but with everything in bfloat16.  The exact one
+has to pass and every other to fail: `ok` says whether they did.  Run on
+a TPU:
+
+    python3 tools/kanana2_departures.py --seed 7 [--steps 98,110]
+
+(`--rehearse` runs the cell's rehearsal sizes on the CPU.)
+
+Prints one JSON line a step count (the adapter's own lines, with every
+reading, go to stderr).  PERF.md (PR 37) keeps what it read; what the
+comparison does not catch is pinned on the CPU
+(tests/test_kanana2_model.py).
+"""
+
+import argparse
+import importlib.util
+import json
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+CELL = "kanana2_30b_a3b_train"
+
+
+def _run_py():
+    """benchmark/run.py as a module: the registry is read as it reads it."""
+    spec = importlib.util.spec_from_file_location(
+        "benchmark_run", os.path.join(ROOT, "benchmark", "run.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--seed", type=int, default=7)
+    ap.add_argument("--steps", default="98,110")
+    ap.add_argument("--rehearse", action="store_true",
+                    help="the cell's rehearsal sizes on the CPU: proves the "
+                         "plumbing, its readings mean nothing")
+    args = ap.parse_args()
+
+    import jax
+    import numpy as np
+
+    import paddle_tpu as fluid
+
+    if not args.rehearse and jax.devices()[0].platform != "tpu":
+        raise SystemExit("kanana2_departures: needs a TPU, jax found %s"
+                         % jax.devices())
+    run = _run_py()
+    spec = run.load_json(ROOT, "BENCHMARK.json")
+    cell = run.find(spec["workloads"], CELL, "workload")
+    cfg = run.merged(run.load_json(ROOT, run.find(
+        spec["configs"], cell["config"], "config")["file"]), args.rehearse)
+    work = run.merged(run.load_json(
+        run.BENCH_DIR, "workloads", CELL + ".json"), args.rehearse)
+    adapter = run.load_module("adapters", cfg["adapter"])
+
+    built = adapter.build(cfg, work)
+    built["startup"].random_seed = built["main"].random_seed = args.seed + 1
+    fwd = adapter.build(cfg, work, forward_only=True)
+    ring = [adapter.make_batch(cfg, work, args.seed * 1000 + i)
+            for i in range(int(work["ring"]))]
+    sample = {k: v[:int(work["reference_rows"])] for k, v in ring[0].items()}
+    place = fluid.CPUPlace() if args.rehearse else fluid.TPUPlace(0)
+    exe, scope = fluid.Executor(place), fluid.Scope()
+    ok, done = True, 0
+    with fluid.scope_guard(scope):
+        exe.run(built["startup"])
+        for steps in [int(n) for n in args.steps.split(",")]:
+            for i in range(done, steps):
+                out = exe.run(built["main"], feed=ring[i % len(ring)],
+                              fetch_list=[built["loss"]], return_numpy=False)
+            done = steps
+            result = {"seed": args.seed, "steps": steps,
+                      "train_loss": float(np.asarray(out[0]).reshape(-1)[0]),
+                      "tolerance": adapter.TOLERANCE,
+                      "limits": adapter.LIMITS, "abs_diff": {},
+                      "readings": {}, "passes": {}}
+            got = float(np.asarray(exe.run(
+                fwd["main"], feed=sample,
+                fetch_list=[fwd["loss"]])[0]).reshape(-1)[0])
+            result["program_loss"] = got
+            params = [(p.name, scope.find_var(p.name))
+                      for p in fwd["main"].global_block().all_parameters()]
+            unit = adapter.bf16_unit(cfg, params, sample)
+            for dtype, departure in (
+                    [("float32", d) for d in (None,) + adapter.DEPARTURES]
+                    + [("bfloat16", None)]):
+                name = departure or ("exact" if dtype == "float32"
+                                     else "all_" + dtype)
+                # what `reference_loss` hands the harness, with the
+                # readings behind it
+                told, loss, found = adapter.compare(
+                    cfg, params, sample, departure, dtype, unit)
+                result["abs_diff"][name] = abs(got - loss)
+                result["readings"][name] = found
+                result["passes"][name] = bool(
+                    abs(got - told) <= adapter.TOLERANCE)
+            result["ok"] = all(v == (k == "exact")
+                               for k, v in result["passes"].items())
+            ok = ok and result["ok"]
+            print(json.dumps(result), flush=True)
+    return 0 if ok else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())
