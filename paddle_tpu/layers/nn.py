@@ -620,16 +620,18 @@ def moe_ffn(input, num_experts, expert_size, top_k, norm_topk_prob=False,
     helper.set_variable_initializer(counts, Constant(0))
     out = helper.create_variable_for_type_inference(dtype)
     aux = helper.create_variable_for_type_inference("float32")
-    helper.append_op(
-        "moe_ffn", inputs=inputs,
-        outputs={"Y": [out], "TokensPerExpert": [counts], "AuxLoss": [aux]},
-        attrs={"top_k": int(top_k), "norm_topk_prob": bool(norm_topk_prob),
-               "router": router, "expert_offset": int(expert_offset),
-               "routed_scaling_factor": float(routed_scaling_factor),
-               "norm_topk_eps": float(norm_topk_eps)})
-    # said here, not left to the abstract evaluation of the lowering: with
+    # The output shapes are said here and the op is appended without the
+    # abstract evaluation of its lowering that helper.append_op makes: with
     # an unknown batch that runs at a million sequences, whose rows times
-    # top_k no int32 index reaches
+    # top_k no int32 index reaches, and every layer's build would trace a
+    # share's chunk loops at that size.
+    helper.main_program.current_block().append_op(
+        "moe_ffn", inputs,
+        {"Y": [out], "TokensPerExpert": [counts], "AuxLoss": [aux]},
+        {"top_k": int(top_k), "norm_topk_prob": bool(norm_topk_prob),
+         "router": router, "expert_offset": int(expert_offset),
+         "routed_scaling_factor": float(routed_scaling_factor),
+         "norm_topk_eps": float(norm_topk_eps)})
     out.shape, aux.shape = tuple(input.shape), (2,)
     return out, aux, counts
 

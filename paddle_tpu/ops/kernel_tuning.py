@@ -51,6 +51,8 @@ _kernel_hits = {}  # family -> pallas dispatch count (trace-time)
 _dense_vjp_hits = {}  # family -> hand-written plain-XLA VJP engagements
 _rng_draws = {}  # generator ("rbg" / "threefry") -> draw sites traced
 _uneven_constraints = {}  # op type -> uneven weight constraints placed
+# moe_ffn lowerings that ran a share's row work over the live chunks
+_moe_live_chunks = {"ops": 0, "chunk_rows": {}}  # chunk_rows: N*k -> rows a chunk
 _searching = threading.local()  # candidate timing in flight on this thread
 _inflight = {}  # key -> threading.Event: a measured search under way
 
@@ -334,16 +336,30 @@ def note_uneven_constraint(op_type):
         _uneven_constraints[op_type] = _uneven_constraints.get(op_type, 0) + 1
 
 
+def note_live_chunks(rows, chunk_rows):
+    """Count a trace-time lowering of a `moe_ffn` that holds a share of
+    its experts, whose row work runs over the live chunks of its `rows`
+    (N k) row buffers, and keep the rows of a chunk it chose."""
+    with _lock:
+        _moe_live_chunks["ops"] += 1
+        _moe_live_chunks["chunk_rows"][int(rows)] = int(chunk_rows)
+
+
 def attribution():
     """Snapshot for bench attribution: per-family pallas-hit counts,
     in-program random draws by generator, uneven weight constraints by op
-    type, plus tuning-cache hit/miss/search totals (search_ms summed)."""
+    type, the moe_ffn lowerings that took the live-chunk path with the
+    rows of a chunk by buffer size, plus tuning-cache hit/miss/search
+    totals (search_ms summed)."""
     with _lock:
         return {
             "pallas_hits": dict(_kernel_hits),
             "dense_vjp_hits": dict(_dense_vjp_hits),
             "rng_draws": {"rbg": 0, "threefry": 0, **_rng_draws},
             "uneven_constraints": dict(_uneven_constraints),
+            "moe_live_chunks": {
+                "ops": _moe_live_chunks["ops"],
+                "chunk_rows": dict(_moe_live_chunks["chunk_rows"])},
             "tuning": {k: (round(v, 3) if isinstance(v, float) else v)
                        for k, v in _stats.items()},
         }
@@ -355,6 +371,7 @@ def reset_attribution():
         _dense_vjp_hits.clear()
         _rng_draws.clear()
         _uneven_constraints.clear()
+        _moe_live_chunks.update(ops=0, chunk_rows={})
         _stats.update(_STATS_ZERO)
 
 

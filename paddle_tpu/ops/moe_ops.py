@@ -14,9 +14,16 @@ A chip's share of an expert layer: the op holds the experts
 its expert weights) of the E its router chooses among, routes over all E
 and computes its own experts' part of the result.  Assignments to experts
 held elsewhere sort behind the held ones, outside every group, so the
-grouped matmuls' work follows the live rows; what those experts would add
-is left out, forward and backward.  Nothing stands in for the other chips
-or their exchange.
+grouped matmuls' work follows the live rows (the kernels' grids stop at
+the last live tile), and so does the row work around them: the gather of
+the tokens' rows, the SwiGLU, the weighting and their transposes run over
+the chunks of C rows that hold a live row, ceil(live / C) trips of a loop
+whose body is traced once, and never touch the rest.  C comes from the
+shape alone: the largest multiple of the kernels' 256-row tile up to
+`_CHUNK_ROWS` that divides N*k, the whole buffer where there is none.  An
+op that holds every expert has nothing to skip and keeps the whole-size
+lowering.  What the experts held elsewhere would add is left out, forward
+and backward.  Nothing stands in for the other chips or their exchange.
 
 The lowering opens `route`, `dispatch`, `experts` and `combine` under the
 op's own `<role>/moe_ffn/<index>` scope, so a device trace splits the op's
@@ -29,6 +36,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.registry import register
+from . import kernel_tuning
 
 
 # megablox tiles, from a sweep alone on a v5e at the OLMoE shape (65,536
@@ -101,6 +109,37 @@ def _mgmm_bwd(res, g):
 _megablox_gmm.defvjp(_mgmm_fwd, _mgmm_bwd)
 
 
+def _product(kernel, lhs, rhs, group_sizes):
+    if kernel:
+        return _megablox_gmm(lhs, rhs, group_sizes)
+    return jax.lax.ragged_dot(lhs, rhs, group_sizes,
+                              preferred_element_type=lhs.dtype)
+
+
+def _product_grads(kernel, lhs, rhs, group_sizes, g):
+    """(d lhs, d rhs) of _product: the kernels' transposes, or what jax's
+    autodiff makes of ragged_dot.  The two leave together: the scheduler
+    would otherwise put every layer's weight gradient off to the end of
+    the backward and hold its two N*k-row operands until then."""
+    if kernel:
+        grads = _mgmm_bwd((lhs, rhs, group_sizes), g)[:2]
+    else:
+        grads = jax.vjp(
+            lambda lhs, rhs: _product(False, lhs, rhs, group_sizes),
+            lhs, rhs)[1](g)
+    return jax.lax.optimization_barrier(grads)
+
+
+def _takes_kernel(ctx, lhs, rhs):
+    """_megablox_fits, counted at trace time by what it answered."""
+    fits = _megablox_fits(ctx, lhs, rhs)
+    if fits:
+        kernel_tuning.note_kernel("grouped_matmul")
+    else:
+        kernel_tuning.note_dense_vjp("grouped_matmul")
+    return fits
+
+
 def grouped_matmul(ctx, lhs, rhs, group_sizes):
     """[M, K] x [G, K, N] -> [M, N]: rows of `lhs` in G contiguous groups
     of `group_sizes` rows, group g multiplied by rhs[g]; f32 accumulation,
@@ -109,14 +148,7 @@ def grouped_matmul(ctx, lhs, rhs, group_sizes):
     reads neither.  On the chip, megablox's Pallas `gmm` (and `gmm` over
     rhs^T / `tgmm` for the two gradients); elsewhere `jax.lax.ragged_dot`,
     whose transposes jax's autodiff supplies."""
-    from .kernel_tuning import note_dense_vjp, note_kernel
-
-    if _megablox_fits(ctx, lhs, rhs):
-        note_kernel("grouped_matmul")
-        return _megablox_gmm(lhs, rhs, group_sizes)
-    note_dense_vjp("grouped_matmul")
-    return jax.lax.ragged_dot(lhs, rhs, group_sizes,
-                              preferred_element_type=lhs.dtype)
+    return _product(_takes_kernel(ctx, lhs, rhs), lhs, rhs, group_sizes)
 
 
 def _sum_slots(rows, inv, k):
@@ -164,6 +196,284 @@ def _feo_bwd(k, res, g):
 
 
 _from_expert_order.defvjp(_feo_fwd, _feo_bwd)
+
+
+
+def _whole(ctx, x2, router_w, bias, w_gu, w_down, *, k, sigmoid, offset,
+           norm, eps, scaling):
+    """(Y [N, d], tokens per expert [E], aux [2]) of x2 [N, d] where the op
+    holds every expert: all N*k rows are live."""
+    (top_p, aux), (top_e, counts) = _routed(x2, router_w, bias, k, sigmoid,
+                                            norm, eps, scaling)
+    cdt, f = w_gu.dtype, w_down.shape[1]
+    with jax.named_scope("dispatch"):
+        order, inv, group_sizes = _expert_order(top_e, counts, offset,
+                                                w_gu.shape[0])
+        tok = order // k
+        rows = _to_expert_order(x2.astype(cdt), tok, inv, k)
+        row_p = _to_expert_order(top_p.reshape(-1, 1), order, inv, 1)
+    with jax.named_scope("experts"):
+        gu = grouped_matmul(ctx, rows, w_gu, group_sizes)
+        act = (jax.nn.silu(gu[:, :f].astype(jnp.float32))
+               * gu[:, f:].astype(jnp.float32)).astype(cdt)
+        out = grouped_matmul(ctx, act, w_down, group_sizes)
+    with jax.named_scope("combine"):
+        out = (out.astype(jnp.float32) * row_p).astype(cdt)
+        return _from_expert_order(out, tok, inv, k), counts, aux
+
+
+# ---------------------------------------------------------------------------
+# a chip's share: the row work over the live chunks
+# ---------------------------------------------------------------------------
+# Where the op holds a share, the n_live rows of the held experts come
+# first in expert order and everything behind them belongs to no group.
+# The kernels' grids stop at the last live tile by themselves; the XLA
+# ops around them do not, so they run as loops over static chunks of
+# `chunk` rows whose trip count, ceil(n_live / chunk), is data.  Such a
+# loop has no reverse-mode rule, so the layer states its transpose
+# (_share_bwd), in which each loop's is a loop of the same kind or a
+# gather.  A buffer a loop fills starts as zeros (a memset); a buffer a
+# kernel fills holds whatever memory held behind the live rows, and whoever
+# reads it masks.  The loops are functions of their own inside the layer's
+# two (jitted too: a device trace names them, and a test counts them).
+
+# Rows of a chunk: the largest multiple of the kernels' row tile up to
+# this that divides N*k (a sweep of both share cells on a v5e: PERF.md,
+# PR 39); one chunk, the whole buffer, where none does.
+_CHUNK_ROWS = 2048
+
+
+def _chunk_rows(m):
+    fits = [c for c in range(_GMM_ROWS, min(m, _CHUNK_ROWS) + 1, _GMM_ROWS)
+            if m % c == 0]
+    return fits[-1] if fits else m
+
+
+def _rows(buf, start, chunk):
+    return jax.lax.dynamic_slice_in_dim(buf, start, chunk, 0)
+
+
+def _put(buf, rows, start):
+    return jax.lax.dynamic_update_slice_in_dim(buf, rows, start, 0)
+
+
+def _live_chunks(n_live, chunk, carry, body):
+    """`carry` after body(start, live, carry) over the chunks of `chunk`
+    rows that hold a live row, first to last; `live` [chunk, 1] says which
+    rows of the chunk at `start` are (all but in the last one)."""
+    def step(c, carry):
+        start = c * chunk
+        live = (start + jnp.arange(chunk, dtype=jnp.int32) < n_live)[:, None]
+        return body(start, live, carry)
+
+    return jax.lax.fori_loop(0, (n_live + chunk - 1) // chunk, step, carry)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk",))
+def _gather_live(x, idx, n_live, *, chunk):
+    """out[p] = x[idx[p]] for the live rows p < n_live, zero behind."""
+    def body(start, live, out):
+        got = x[_rows(idx, start, chunk)]
+        return _put(out, jnp.where(live, got, 0), start)
+
+    return _live_chunks(
+        n_live, chunk, jnp.zeros((idx.shape[0], x.shape[1]), x.dtype), body)
+
+
+def _live_slots(rows, inv, n_live, k, weigh=None):
+    """rows [N*k, d] in expert order -> [N, d] f32: each token's live rows
+    summed, a slot at a time (each [N, d] gather feeds the sum's fusion;
+    [N, k, d] would be laid out anew first).  The gathers read the dead
+    rows they are pointed at, which hold whatever a kernel left: the mask
+    comes after them.  A scatter-add of the live rows is what the TPU
+    serialises."""
+    slots = inv.reshape(-1, k)
+    total = 0.0
+    for j in range(k):
+        got = rows[slots[:, j]].astype(jnp.float32)
+        if weigh is not None:
+            got = got * weigh[:, j, None]
+        total = total + jnp.where(slots[:, j, None] < n_live, got, 0)
+    return total
+
+
+@functools.partial(jax.jit, static_argnames=("k",))
+def _sum_live_slots(rows, inv, n_live, *, k):
+    return _live_slots(rows, inv, n_live, k).astype(rows.dtype)
+
+
+def _swiglu(gu):
+    f = gu.shape[1] // 2
+    return (jax.nn.silu(gu[:, :f].astype(jnp.float32))
+            * gu[:, f:].astype(jnp.float32)).astype(gu.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk",))
+def _swiglu_live(gu, n_live, *, chunk):
+    """silu(gate) * up in f32 over the live rows of gu [N*k, 2f]."""
+    def body(start, live, act):
+        return _put(act, jnp.where(live, _swiglu(_rows(gu, start, chunk)), 0),
+                    start)
+
+    return _live_chunks(
+        n_live, chunk, jnp.zeros((gu.shape[0], gu.shape[1] // 2), gu.dtype),
+        body)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk",))
+def _swiglu_live_bwd(gu, g, n_live, *, chunk):
+    def body(start, live, d_gu):
+        _, vjp = jax.vjp(_swiglu, _rows(gu, start, chunk))
+        (d,) = vjp(_rows(g, start, chunk))
+        return _put(d_gu, jnp.where(live, d, 0), start)
+
+    return _live_chunks(n_live, chunk, jnp.zeros_like(gu), body)
+
+
+@functools.partial(jax.jit, static_argnames=("k",))
+def _weigh_to_tokens(out, top_p, inv, n_live, *, k):
+    """Y[t] = sum over token t's live slots of p * out[its row], in f32:
+    the weighting rides in the sum's fusion, on the tokens' side."""
+    return _live_slots(out, inv, n_live, k, top_p).astype(out.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "chunk"))
+def _weigh_to_tokens_bwd(out, top_p, order, inv, g, n_live, *, k, chunk):
+    p_flat = top_p.reshape(-1)
+
+    def body(start, live, carry):
+        d_out, d_p = carry
+        at = _rows(order, start, chunk)
+        g_rows = g[at // k].astype(jnp.float32)
+        d_o = jnp.where(live, g_rows * p_flat[at][:, None], 0)
+        d_pc = jnp.where(
+            live[:, 0],
+            (_rows(out, start, chunk).astype(jnp.float32) * g_rows).sum(-1),
+            0)
+        return (_put(d_out, d_o.astype(out.dtype), start),
+                _put(d_p, d_pc, start))
+
+    d_out, d_p = _live_chunks(
+        n_live, chunk,
+        (jnp.zeros_like(out), jnp.zeros(order.shape, jnp.float32)), body)
+    return d_out, d_p[inv].reshape(top_p.shape)
+
+
+def _routed(x2, router_w, bias, k, sigmoid, norm, eps, scaling):
+    """((top-k weights [N, k], aux [2]), (their experts [N, k], tokens per
+    expert [E])) under the op's `route` scope: the part of the layer that
+    autodiff transposes."""
+    with jax.named_scope("route"):
+        if sigmoid:
+            top_p, top_e, counts, aux = route_sigmoid(
+                x2, router_w, bias, k, norm, eps)
+        else:
+            top_p, top_e, counts, aux = route(x2, router_w, k, norm)
+        if scaling != 1.0:
+            top_p = top_p * scaling
+    return (top_p, aux), (top_e, counts)
+
+
+def _expert_order(top_e, counts, offset, held):
+    """The stable sort of the N*k assignments by expert (`inv` undoes it)
+    and the groups' sizes; where the op holds a share, the held experts'
+    rows first, by local expert, and the assignments to experts held
+    elsewhere behind them, in no group."""
+    sort_key, group_sizes = top_e.reshape(-1), counts
+    if held != counts.shape[0]:
+        local = sort_key - offset
+        sort_key = jnp.where((local >= 0) & (local < held), local, held)
+        group_sizes = counts[offset:offset + held]
+    order = jnp.argsort(sort_key, stable=True)
+    return order, jnp.argsort(order), group_sizes
+
+
+# A share's layer whole, forward and backward, as two functions jitted at
+# module level: every layer of a model calls them with the same shapes and
+# static configuration, so the host traces and lowers each once however
+# deep the model, and a grad op's forward again is the forward op's
+# function (one instruction each after CSE: same operands, same kernel
+# payloads).  The router's part is transposed by autodiff inside the
+# forward (its vjp's residuals travel with the others); the rest states
+# its transpose, since a loop with a traced trip count has none.  What the
+# backward can make again over the live chunks alone from what it keeps
+# anyway, it does not keep: the gathered rows (from x) and the SwiGLU's
+# result (from gu) are N*k-row buffers a layer, and the cells that hold a
+# share run within a few hundred MB of the chip's memory.  The barriers tie
+# what is made again to the incoming gradient, or the compiler would merge
+# it with the forward's and keep that.
+_SHARE_STATICS = ("k", "sigmoid", "offset", "norm", "eps", "scaling",
+                  "chunk", "kernels")
+
+
+@functools.partial(jax.jit, static_argnames=_SHARE_STATICS)
+def _share_fwd(x2, router_w, bias, w_gu, w_down, *, k, sigmoid, offset, norm,
+               eps, scaling, chunk, kernels):
+    ((top_p, aux), route_vjp, (top_e, counts)) = jax.vjp(
+        lambda x2, router_w: _routed(x2, router_w, bias, k, sigmoid, norm,
+                                     eps, scaling),
+        x2, router_w, has_aux=True)
+    with jax.named_scope("dispatch"):
+        order, inv, group_sizes = _expert_order(top_e, counts, offset,
+                                                w_gu.shape[0])
+        n_live = group_sizes.sum()
+        x = x2.astype(w_gu.dtype)  # the experts' dtype
+        rows = _gather_live(x, order // k, n_live, chunk=chunk)
+    with jax.named_scope("experts"):
+        gu = _product(kernels[0], rows, w_gu, group_sizes)
+        act = _swiglu_live(gu, n_live, chunk=chunk)
+        out = _product(kernels[1], act, w_down, group_sizes)
+    with jax.named_scope("combine"):
+        y = _weigh_to_tokens(out, top_p, inv, n_live, k=k)
+    return (y, counts, aux), (route_vjp, x, w_gu, w_down, top_p, order, inv,
+                              group_sizes, n_live, gu, out)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "chunk", "kernels"))
+def _share_bwd(res, g_y, g_aux, *, k, chunk, kernels):
+    (route_vjp, x, w_gu, w_down, top_p, order, inv, group_sizes, n_live, gu,
+     out) = res
+    with jax.named_scope("combine"):
+        d_out, d_p = _weigh_to_tokens_bwd(out, top_p, order, inv, g_y,
+                                          n_live, k=k, chunk=chunk)
+    with jax.named_scope("experts"):
+        gu, d_out = jax.lax.optimization_barrier((gu, d_out))
+        act = _swiglu_live(gu, n_live, chunk=chunk)
+        d_act, d_w_down = _product_grads(kernels[1], act, w_down,
+                                         group_sizes, d_out)
+        d_gu = _swiglu_live_bwd(gu, d_act, n_live, chunk=chunk)
+    with jax.named_scope("dispatch"):
+        x, d_gu = jax.lax.optimization_barrier((x, d_gu))
+        rows = _gather_live(x, order // k, n_live, chunk=chunk)
+    with jax.named_scope("experts"):
+        d_rows, d_w_gu = _product_grads(kernels[0], rows, w_gu, group_sizes,
+                                        d_gu)
+    with jax.named_scope("dispatch"):
+        d_x = _sum_live_slots(d_rows, inv, n_live, k=k)
+    d_x2, d_router_w = route_vjp((d_p, g_aux))
+    return d_x2 + d_x.astype(d_x2.dtype), d_router_w, d_w_gu, d_w_down
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _share_layer(x2, router_w, bias, w_gu, w_down, said):
+    """(Y [N, d], tokens per expert [E], aux [2]) of x2 [N, d] where the op
+    holds a share; `said`: _SHARE_STATICS as a dict's items (hashable)."""
+    return _share_fwd(x2, router_w, bias, w_gu, w_down, **dict(said))[0]
+
+
+def _share_layer_fwd(x2, router_w, bias, w_gu, w_down, said):
+    return _share_fwd(x2, router_w, bias, w_gu, w_down, **dict(said))
+
+
+def _share_layer_bwd(said, res, g):
+    said = dict(said)
+    d_x2, d_router_w, d_w_gu, d_w_down = _share_bwd(
+        res, g[0], g[2], k=said["k"], chunk=said["chunk"],
+        kernels=said["kernels"])
+    return d_x2, d_router_w, None, d_w_gu, d_w_down
+
+
+_share_layer.defvjp(_share_layer_fwd, _share_layer_bwd)
 
 
 def _router_logits(x2, router_w):
@@ -247,58 +557,33 @@ def _moe_ffn(ctx, ins, attrs):
     x = ins["X"][0]
     router_w = ins["RouterW"][0]
     w_gu, w_down = ins["GateUpW"][0], ins["DownW"][0]
-    k = int(attrs["top_k"])
-    d, f = x.shape[-1], w_down.shape[1]
-    x2 = x.reshape(-1, d)
-    n = x2.shape[0]
-    cdt = w_gu.dtype
     n_experts, held = router_w.shape[-1], w_gu.shape[0]
     offset = int(attrs.get("expert_offset", 0))
     if offset < 0 or offset + held > n_experts:
         raise ValueError(
             "moe_ffn holds experts [%d, %d) of a router over %d"
             % (offset, offset + held, n_experts))
-    norm = bool(attrs.get("norm_topk_prob", False))
-
-    with jax.named_scope("route"):
-        if attrs.get("router", "softmax") == "sigmoid":
-            bias = ins["ExpertBias"][0] if ins.get("ExpertBias") else None
-            top_p, top_e, counts, aux = route_sigmoid(
-                x2, router_w, bias, k, norm,
-                float(attrs.get("norm_topk_eps", 1e-6)))
-        else:
-            top_p, top_e, counts, aux = route(x2, router_w, k, norm)
-        scaling = float(attrs.get("routed_scaling_factor", 1.0))
-        if scaling != 1.0:
-            top_p = top_p * scaling
-    with jax.named_scope("dispatch"):
-        sort_key, group_sizes, live = top_e.reshape(-1), counts, None
-        if held != n_experts:
-            # the held experts' rows first, by local expert; assignments
-            # to experts held elsewhere behind them, in no group
-            local = sort_key - offset
-            sort_key = jnp.where((local >= 0) & (local < held), local, held)
-            group_sizes = counts[offset:offset + held]
-            live = (jnp.arange(n * k) < group_sizes.sum())[:, None]
-        # stable sort of the N*k assignments by expert; `inv` undoes it
-        order = jnp.argsort(sort_key, stable=True)
-        inv = jnp.argsort(order)
-        tok = order // k
-        rows = _to_expert_order(x2.astype(cdt), tok, inv, k)
-        row_p = _to_expert_order(top_p.reshape(n * k, 1), order, inv, 1)
-        if live is not None:
-            # a dead row's gradient is whatever the kernel left there
-            rows = jnp.where(live, rows, 0)
-    with jax.named_scope("experts"):
-        gu = grouped_matmul(ctx, rows, w_gu, group_sizes)
-        act = (jax.nn.silu(gu[:, :f].astype(jnp.float32))
-               * gu[:, f:].astype(jnp.float32)).astype(cdt)
-        out = grouped_matmul(ctx, act, w_down, group_sizes)
-    with jax.named_scope("combine"):
-        if live is not None:
-            out = jnp.where(live, out, 0)  # so is a dead row's result
-        out = (out.astype(jnp.float32) * row_p).astype(cdt)
-        y = _from_expert_order(out, tok, inv, k)
+    sigmoid = attrs.get("router", "softmax") == "sigmoid"
+    bias = ins["ExpertBias"][0] if sigmoid and ins.get("ExpertBias") else None
+    said = dict(
+        k=int(attrs["top_k"]), sigmoid=sigmoid, offset=offset,
+        norm=bool(attrs.get("norm_topk_prob", False)),
+        eps=float(attrs.get("norm_topk_eps", 1e-6)),
+        scaling=float(attrs.get("routed_scaling_factor", 1.0)))
+    x2 = x.reshape(-1, x.shape[-1])
+    if held == n_experts:
+        y, counts, aux = _whole(ctx, x2, router_w, bias, w_gu, w_down,
+                                **said)
+    else:
+        m, f = x2.shape[0] * said["k"], w_down.shape[1]
+        chunk = _chunk_rows(m)
+        kernel_tuning.note_live_chunks(m, chunk)
+        rows = functools.partial(jax.ShapeDtypeStruct, dtype=w_gu.dtype)
+        kernels = (_takes_kernel(ctx, rows((m, x2.shape[1])), w_gu),
+                   _takes_kernel(ctx, rows((m, f)), w_down))
+        y, counts, aux = _share_layer(
+            x2, router_w, bias, w_gu, w_down,
+            tuple(dict(said, chunk=chunk, kernels=kernels).items()))
     return {"Y": [y.reshape(x.shape)], "TokensPerExpert": [counts],
             "AuxLoss": [aux]}
 

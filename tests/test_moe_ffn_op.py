@@ -596,3 +596,299 @@ def test_expert_bias_update_step_grows_with_the_routers_width(
          "TokensPerExpert": [jnp.asarray(counts)]}, {})["ExpertBiasOut"][0]
     np.testing.assert_allclose(
         [out[0], out[top_k], out[n_experts - 1]], want, rtol=1e-5)
+
+
+# --- a chip's share: the row work over the live chunks (PR 39) ---
+# case -> (router, weights, (offset, held), rows a chunk, NaN planted)
+ROWS = N * K
+SHARE = {
+    "softmax_router": ("softmax", "balanced", (0, 4), 16, False),
+    "sigmoid_router": ("sigmoid", "balanced", (4, 2), 16, False),
+    "offset_at_the_start": ("sigmoid", "balanced", (0, 2), 16, False),
+    "offset_in_the_middle": ("sigmoid", "balanced", (3, 3), 16, False),
+    "offset_at_the_end": ("softmax", "balanced", (6, 2), 16, False),
+    "no_live_rows": ("sigmoid", "skewed", (6, 2), 16, False),
+    "all_rows_live": ("softmax", "both_held", (0, 2), 16, False),
+    "live_rows_no_multiple_of_a_chunk": ("softmax", "balanced", (2, 3), 32,
+                                         False),
+    "a_group_straddles_a_chunk": ("sigmoid", "balanced", (0, 3), 8, False),
+    "nan_behind_the_live_rows": ("sigmoid", "balanced", (3, 3), 16, True),
+}
+
+
+def _share_weights(kind):
+    w = _weights("balanced" if kind == "both_held" else kind)
+    if kind == "both_held":
+        # every token's two largest scores are experts 0 and 1
+        w["x"][:, 0] = 4.0
+        w["router"][0] = 0.0
+        w["router"][0, :2] = [5.0, 4.5]
+    return w
+
+
+def _nan_behind(rows, n_live):
+    return jnp.where((jnp.arange(rows.shape[0]) < n_live)[:, None], rows,
+                     jnp.nan)
+
+
+def _share_run(monkeypatch, case, chunk, plant=False):
+    """Y, its gradients over (x, router, gate_up, down) and the counts, the
+    share's row work in chunks of `chunk` rows."""
+    from paddle_tpu.core.registry import LowerCtx
+    from paddle_tpu.ops import moe_ops
+
+    router, kind, (offset, held), _, _ = SHARE[case]
+    w = _share_weights(kind)
+    sl = slice(offset, offset + held)
+    monkeypatch.setattr(moe_ops, "_chunk_rows", lambda m: chunk)
+    if plant:
+        # a kernel writes no row behind the last group: whatever memory
+        # held stays there, forward and backward
+        product, grads = moe_ops._product, moe_ops._product_grads
+        monkeypatch.setattr(
+            moe_ops, "_product", lambda kernel, lhs, rhs, sizes: _nan_behind(
+                product(kernel, lhs, rhs, sizes), sizes.sum()))
+
+        def planted_grads(kernel, lhs, rhs, sizes, g):
+            d_lhs, d_rhs = grads(kernel, lhs, rhs, sizes, g)
+            return _nan_behind(d_lhs, sizes.sum()), d_rhs
+
+        monkeypatch.setattr(moe_ops, "_product_grads", planted_grads)
+    mix = jnp.asarray(w["mix"])
+
+    def outputs(x, wr, wgu, wd):
+        out = moe_ops._moe_ffn(
+            LowerCtx(platform="cpu"),
+            {"X": [x], "RouterW": [wr], "GateUpW": [wgu], "DownW": [wd],
+             "ExpertBias": [jnp.asarray(_bias())]},
+            {"top_k": K, "router": router, "norm_topk_prob": True,
+             "expert_offset": offset})
+        y = out["Y"][0]
+        return (y * mix).sum() + out["AuxLoss"][0].sum(), (
+            y, out["TokensPerExpert"][0])
+
+    args = [jnp.asarray(w["x"]), jnp.asarray(w["router"]),
+            jnp.asarray(w["gate_up"][sl]), jnp.asarray(w["down"][sl])]
+    with jax.default_matmul_precision("highest"):
+        (_, (y, counts)), grads = jax.value_and_grad(
+            outputs, argnums=(0, 1, 2, 3), has_aux=True)(*args)
+    return y, grads, np.asarray(counts)[sl]
+
+
+@pytest.mark.parametrize("case", list(SHARE))
+def test_a_shares_live_chunks_give_what_the_whole_buffer_gives(monkeypatch,
+                                                              case):
+    """Where the op holds a share its row work runs over the chunks that
+    hold a live row: Y and all four gradients are those of one chunk as
+    long as the buffers (C = N k), in float32, for both routers, a share
+    at the start, in the middle and at the end of the experts, no live row
+    at all, every row live, a live count that is no multiple of C, a group
+    across a chunk's edge, and NaN in every row a kernel does not write."""
+    _, _, _, chunk, plant = SHARE[case]
+    want_y, want_grads, held_counts = _share_run(monkeypatch, case, ROWS)
+    if plant:  # the layer is jitted: an unplanted trace would be found
+        jax.clear_caches()
+    got_y, got_grads, _ = _share_run(monkeypatch, case, chunk, plant)
+    if plant:  # and a later test would find the planted one
+        jax.clear_caches()
+    n_live = int(held_counts.sum())
+    edges = np.cumsum(held_counts)
+    if case == "no_live_rows":
+        assert n_live == 0 and not np.asarray(got_y).any()
+    elif case == "all_rows_live":
+        assert n_live == ROWS
+    elif case == "live_rows_no_multiple_of_a_chunk":
+        assert 0 < n_live % chunk
+    elif case == "a_group_straddles_a_chunk":
+        assert any(lo // chunk != (hi - 1) // chunk and lo % chunk
+                   for lo, hi in zip(edges[:-1], edges[1:]))
+    else:
+        assert 0 < n_live < ROWS
+    np.testing.assert_allclose(got_y, want_y, rtol=1e-6, atol=1e-6)
+    for got, want in zip(got_grads, want_grads):
+        assert np.isfinite(np.asarray(got)).all()
+        np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+
+
+def test_a_buffer_a_chunk_loop_fills_is_zero_behind_the_live_rows():
+    """Every buffer a loop carries starts as zeros and is written in the
+    live rows alone, whatever its sources hold behind them (NaN here, as
+    a kernel's result may)."""
+    from paddle_tpu.ops import moe_ops
+
+    rng = np.random.RandomState(3)
+    m, n_live, chunk = ROWS, 37, 16
+    order = jnp.asarray(rng.permutation(m).astype("int32"))
+    inv, tok = jnp.argsort(order), order // K
+    x = jnp.asarray(rng.randn(N, D).astype("float32"))
+    gu = _nan_behind(jnp.asarray(rng.randn(m, 2 * F).astype("float32")),
+                     n_live)
+    out = _nan_behind(jnp.asarray(rng.randn(m, D).astype("float32")), n_live)
+    top_p = jnp.asarray(rng.rand(N, K).astype("float32"))
+    rows = moe_ops._gather_live(x, tok, n_live, chunk=chunk)
+    act = moe_ops._swiglu_live(gu, n_live, chunk=chunk)
+    d_gu = moe_ops._swiglu_live_bwd(gu, _nan_behind(act, n_live), n_live,
+                                    chunk=chunk)
+    d_out, d_p = moe_ops._weigh_to_tokens_bwd(
+        out, top_p, order, inv, x, n_live, k=K, chunk=chunk)
+    np.testing.assert_array_equal(rows[:n_live], x[tok[:n_live]])
+    np.testing.assert_allclose(act[:n_live], moe_ops._swiglu(gu[:n_live]),
+                               rtol=1e-6)
+    for buf in (rows, act, d_gu, d_out):
+        assert np.isfinite(np.asarray(buf)).all()
+        assert np.abs(np.asarray(buf[:n_live])).sum() > 0
+        assert not np.asarray(buf[n_live:]).any()
+    assert np.isfinite(np.asarray(d_p)).all()
+    # a token's slot that is not live has no part in the weights' gradient
+    assert not np.asarray(d_p)[np.asarray(inv).reshape(N, K) >= n_live].any()
+
+
+@pytest.mark.parametrize("m, want", [
+    (65536, 2048), (36864, 2048), (4096, 2048), (6144, 2048), (1536, 1536),
+    (768, 768), (ROWS, ROWS), (1000, 1000)])
+def test_rows_of_a_chunk_come_from_the_shape_alone(m, want):
+    """A multiple of the kernels' 256-row tile that divides N k, the
+    largest up to `_CHUNK_ROWS`; the whole buffer where there is none (the
+    small shapes of these tests)."""
+    from paddle_tpu.ops import moe_ops
+
+    assert moe_ops._chunk_rows(m) == want
+    assert m % want == 0
+
+
+SHARE_WIDTHS = {"lfm2": (1792, 32, 8, 4), "kanana2": (768, 128, 16, 6)}
+
+
+def _functions(text, name):
+    """{function: its body} of the StableHLO's private functions that jit
+    named `name` (a second jaxpr of one name gets a number behind it)."""
+    import re
+
+    return {m.group(1): text[m.start():text.index("\n  }\n", m.start())]
+            for m in re.finditer(
+                r"func\.func private @(%s(?:_\d+)?)\(" % name, text)}
+
+
+@pytest.mark.parametrize("model", sorted(SHARE_WIDTHS))
+def test_a_share_cross_lowers_for_the_tpu_with_six_kernels_a_layer(model):
+    """The share path at LFM2's and kanana-2's expert widths (d = 2048),
+    lowered for the TPU on this host: the six Mosaic calls a layer the
+    whole-size lowering has (the chunk loops carry none), and every loop
+    one shared function: forward the gather and the SwiGLU; backward the
+    combine's transpose, the SwiGLU's, and the gather and the SwiGLU made
+    again rather than kept."""
+    from paddle_tpu.core.registry import LowerCtx
+    from paddle_tpu.ops import moe_ops
+
+    f, e, held, k = SHARE_WIDTHS[model]
+    n, d = 1024, 2048
+    on_chip = LowerCtx(platform="tpu")
+
+    def loss(x, wr, wgu, wd):
+        out = moe_ops._moe_ffn(
+            on_chip, {"X": [x], "RouterW": [wr], "GateUpW": [wgu],
+                      "DownW": [wd]},
+            {"top_k": k, "router": "sigmoid", "norm_topk_prob": True,
+             "expert_offset": e - held})
+        return out["Y"][0].astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).trace(
+        jax.ShapeDtypeStruct((n, d), jnp.float32),
+        jax.ShapeDtypeStruct((d, e), jnp.float32),
+        jax.ShapeDtypeStruct((held, d, 2 * f), jnp.bfloat16),
+        jax.ShapeDtypeStruct((held, f, d), jnp.bfloat16),
+    ).lower(lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") == 6
+    # what the backward makes again is traced in the backward's context:
+    # a function of its own
+    for name, functions in (("_gather_live", 2), ("_swiglu_live", 2),
+                            ("_swiglu_live_bwd", 1),
+                            ("_weigh_to_tokens_bwd", 1)):
+        found = _functions(text, name)
+        assert len(found) == functions
+        for body in found.values():
+            assert body.count("stablehlo.while") == 1
+            assert "tpu_custom_call" not in body
+        assert sum(text.count("call @%s(" % f) for f in found) == functions
+
+
+def test_a_two_layer_share_program_traces_each_chunk_loop_once(monkeypatch):
+    """The host-cost pin: LFM2 at toy widths with two expert layers that
+    hold 2 of 8 experts, the train step lowered as the Executor lowers it.
+    A share's layer is traced once forward and once backward, whatever the
+    depth and although every grad op traces its forward again, and the
+    StableHLO holds the layer as functions that the layers call."""
+    from paddle_tpu.core.trace import build_traced_function
+    from paddle_tpu.models import lfm2
+    from paddle_tpu.ops import kernel_tuning as kt, moe_ops
+
+    class HP(lfm2.LFM2MoEConfig):
+        vocab_size, hidden_size, intermediate_size = 64, 32, 32
+        moe_intermediate_size, num_hidden_layers, num_dense_layers = 16, 3, 1
+        layer_types = ["conv", "full_attention", "conv"]
+        num_attention_heads, num_key_value_heads = 2, 1
+        num_experts, num_experts_per_tok = 8, 2
+        num_local_experts, expert_offset = 2, 2
+
+    seq, traced_bodies = 24, []
+    rows = 2 * seq * HP.num_experts_per_tok
+    live_chunks = moe_ops._live_chunks
+
+    def counted(n_live, chunk, carry, body):
+        leaf = jax.tree.leaves(carry)[0]
+        if leaf.shape[0] == rows:  # not layer_helper.infer_shape's batch
+            traced_bodies.append(leaf.shape)
+        return live_chunks(n_live, chunk, carry, body)
+
+    monkeypatch.setattr(moe_ops, "_live_chunks", counted)
+    monkeypatch.setattr(moe_ops, "_chunk_rows", lambda m: 16)
+    jax.clear_caches()  # an earlier test's trace of these shapes would hide
+    main, startup, _, fetches = lfm2.lfm2_lm_program(HP, seq_len=seq, lr=1e-3)
+    scope = fluid.Scope()
+    for block in (main.global_block(), startup.global_block()):
+        for name, var in block.vars.items():
+            if var.persistable and all(int(d) >= 0 for d in var.shape):
+                scope.set(name, jax.ShapeDtypeStruct(
+                    tuple(int(d) for d in var.shape),
+                    jnp.dtype(str(var.dtype))))
+    feeds = {"ids": jax.ShapeDtypeStruct((2, seq), jnp.int32),
+             "labels": jax.ShapeDtypeStruct((2, seq), jnp.int32),
+             "loss_weight": jax.ShapeDtypeStruct((2, seq), jnp.float32)}
+    traced = build_traced_function(
+        main, 0, tuple(sorted(feeds)), [fetches[0].name], scope,
+        platform="cpu")
+
+    def shaped(name):
+        v = scope.find_var(name)
+        return jax.ShapeDtypeStruct(v.shape, v.dtype)
+
+    before = kt.attribution()["moe_live_chunks"]["ops"]
+    text = jax.jit(traced.fn).trace(
+        feeds, {n: shaped(n) for n in traced.ro_names},
+        {n: shaped(n) for n in traced.rw_names},
+        jax.eval_shape(lambda: jax.random.key(1))).lower().as_text()
+    layers_ = sum(op.type == "moe_ffn" for op in main.global_block().ops)
+    assert layers_ == 2
+    # forward: the gather and the SwiGLU; backward: the combine's and the
+    # SwiGLU's transposes, and the gather and the SwiGLU made again (jax
+    # traces under another context there: a trace and a function of their
+    # own), once whatever the depth
+    assert len(traced_bodies) == 6
+    # the layers call one forward function from their forward ops (jit
+    # prunes the residuals nobody reads there: a jaxpr of its own), one
+    # from their grad ops, and one backward function
+    for name, functions in (("_share_fwd", 2), ("_share_bwd", 1)):
+        found = _functions(text, name)
+        assert len(found) == functions
+        assert all(text.count("call @%s(" % f) == layers_ for f in found)
+    # and those three hold every loop: one call site each
+    for name, functions, calls in (
+            ("_gather_live", 2, 3), ("_swiglu_live", 2, 3),
+            ("_swiglu_live_bwd", 1, 1), ("_weigh_to_tokens_bwd", 1, 1)):
+        found = _functions(text, name)
+        assert len(found) == functions
+        assert sum(text.count("call @%s(" % f) for f in found) == calls
+    found = kt.attribution()["moe_live_chunks"]
+    assert found["ops"] - before == 2 * layers_
+    assert found["chunk_rows"][rows] == 16
+    jax.clear_caches()
