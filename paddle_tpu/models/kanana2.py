@@ -193,14 +193,20 @@ def kanana2_lm(ids, hp=Kanana2Config, is_test=False):
                      bias_attr=False, param_attr=_pa("softmax_out.w"))
 
 
+def leave_eval_rows(cost, name, seq_len):
+    """Every token's cost, [B, T, 1], left in the scope as the persistable
+    [B, T] float32 `name` (what a forward-only program hands an evaluation
+    that pairs rows with a reference's)."""
+    rows = LayerHelper(name).create_global_variable(
+        name=name, persistable=True, dtype="float32", shape=[-1, seq_len])
+    rows.stop_gradient = True
+    layers.assign(layers.reshape(cost, [-1, seq_len]), output=rows)
+
+
 def _token_cost(ids, labels, hp, seq_len, is_test):
     cost = xent_cost(kanana2_lm(ids, hp, is_test), labels)  # [B, T, 1]
     if is_test:
-        rows = LayerHelper(EVAL_ROWS).create_global_variable(
-            name=EVAL_ROWS, persistable=True, dtype="float32",
-            shape=[-1, seq_len])
-        rows.stop_gradient = True
-        layers.assign(layers.reshape(cost, [-1, seq_len]), output=rows)
+        leave_eval_rows(cost, EVAL_ROWS, seq_len)
     return cost
 
 

@@ -153,9 +153,14 @@ def lfm2_lm(ids, hp=LFM2MoEConfig, is_test=False):
     return layers.matmul(x, emb, transpose_y=True)
 
 
-def balance_expert_biases(main):
+def balance_expert_biases(main, rate=None, max_step=None):
     """After the optimizer, one `expert_bias_update` per mixture layer:
-    the layer's selection bias follows the step's own counts."""
+    the layer's selection bias follows the step's own counts.  `rate` and
+    `max_step` become the op's attributes where given (a fine-tuning
+    schedule's smaller, bounded step); left out, the op is the one every
+    program before had, attribute for attribute."""
+    attrs = {k: float(v) for k, v in (("rate", rate), ("max_step", max_step))
+             if v is not None}
     block = main.global_block()
     with main._op_role_guard("optimize"):
         for op in list(block.ops):
@@ -165,7 +170,7 @@ def balance_expert_biases(main):
                     "expert_bias_update",
                     inputs={"ExpertBias": bias,
                             "TokensPerExpert": op.outputs["TokensPerExpert"]},
-                    outputs={"ExpertBiasOut": bias})
+                    outputs={"ExpertBiasOut": bias}, attrs=attrs)
 
 
 def lfm2_lm_program(hp=LFM2MoEConfig, seq_len=8192, lr=4e-4, is_test=False,

@@ -9,6 +9,8 @@ XLA program.  Tensor-parallel sharding rules for the qkv/ffn weights live in
 paddle_tpu.parallel (GSPMD replaces the DistributeTranspiler).
 """
 
+import contextlib
+
 import numpy as np
 
 from .. import framework, layers, unique_name
@@ -113,7 +115,8 @@ def multi_head_attention(
     queries, keys, values, attn_bias, d_model, n_head, dropout_rate=0.0,
     is_test=False, cache=None, fused=False, kpad_bias=None, causal=False,
     n_kv_head=None, rotary=False, qk_norm=False, qk_norm_eps=1e-5,
-    rotary_base=10000.0, param_attr=None,
+    rotary_base=10000.0, param_attr=None, head_dim=None, window=0,
+    out_gate=False, scopes=False,
 ):
     """All heads in one qkv projection + batched matmuls (MXU-shaped).
     attn_bias: [B, 1 or H, Tq, Tk] additive mask (−1e9 at masked slots).
@@ -146,10 +149,28 @@ def multi_head_attention(
     head on its own over head_dim (one [head_dim] weight for q's heads,
     one for k's, as LFM2 does), after the head split and before rotary.
 
-    q, k and v are of ONE head width, d_model / n_head, and rotary turns
-    the whole head; the flash kernel takes it at 64 or 128.  Scores of
-    another width than the values, and a decoupled rotary part, are
-    `latent_attention`'s.
+    q, k and v are of ONE head width, `head_dim` (d_model / n_head where
+    none is given: Trinity-Mini's 32 heads of 128 over a hidden size of
+    2048 project to 4096 and back), and rotary turns the whole head; the
+    flash kernel takes it at 64 or 128.  Scores of another width than the
+    values, and a decoupled rotary part, are `latent_attention`'s.
+
+    window > 0 (the fused causal training path alone): sliding-window
+    attention, key j visible to query i iff 0 <= i - j < window; the
+    attribute reaches the `fused_attention` op, whose kernels skip the
+    blocks outside the band.  A cache that keeps the last `window`
+    positions only is not built here.
+
+    out_gate=True multiplies the heads' output, [B, T, n_head * head_dim],
+    by sigmoid(queries W_g) (its own projection, "mha_gate.w", of that
+    width) before the output projection.
+
+    scopes=True builds the `fused_attention` op under the name scope
+    `core` and the gate's sigmoid and product under `attn_gate`, inside
+    whatever scope the caller builds the layer under (trinity's
+    `attn_window` / `attn_full`), so that the lowered step says which
+    time is the core's; the default builds under none, as every program
+    before it.
 
     RAGGED cache mode (the continuous-batching serving step): a cache
     dict carrying "pos_rows" [B] + "width_rows" [B] (and "pos_mat"
@@ -166,17 +187,31 @@ def multi_head_attention(
     set of weights gives each use the same names.  The default numbers
     every call's weights anew."""
     pa = param_attr or _pa
-    dh = d_model // n_head
+    dh = int(head_dim or d_model // n_head)
     n_kv = n_kv_head or n_head
     if n_head % n_kv:
         raise ValueError(
             "n_kv_head (%d) must divide n_head (%d)" % (n_kv, n_head))
-    q = layers.fc(queries, size=d_model, num_flatten_dims=2, bias_attr=False,
-                  param_attr=pa("mha_q.w"))
+    window = int(window or 0)
+    if window and not (fused and causal and cache is None):
+        raise ValueError(
+            "window (%d) is the fused causal training path's: pass "
+            "fused=True, causal=True and no cache" % window)
+
+    def scoped(name):
+        return (framework.name_scope(name) if scopes
+                else contextlib.nullcontext())
+
+    q = layers.fc(queries, size=n_head * dh, num_flatten_dims=2,
+                  bias_attr=False, param_attr=pa("mha_q.w"))
     k = layers.fc(keys, size=n_kv * dh, num_flatten_dims=2, bias_attr=False,
                   param_attr=pa("mha_k.w"))
     v = layers.fc(values, size=n_kv * dh, num_flatten_dims=2, bias_attr=False,
                   param_attr=pa("mha_v.w"))
+    gate = None
+    if out_gate:
+        gate = layers.fc(queries, size=n_head * dh, num_flatten_dims=2,
+                         bias_attr=False, param_attr=pa("mha_gate.w"))
     if qk_norm not in (False, True, "head"):
         raise ValueError("qk_norm is False, True (the whole projection) "
                          "or 'head', got %r" % (qk_norm,))
@@ -343,10 +378,12 @@ def multi_head_attention(
                 "(plus causal=True for decoder self-attention) or use "
                 "fused=False"
             )
-        ctx = layers.fused_attention(
-            q, repeat_kv(k), repeat_kv(v), bias=kpad_bias, causal=causal,
-            scale=dh ** -0.5
-        )  # [B, H, Tq, Dh]
+        k, v = repeat_kv(k), repeat_kv(v)
+        with scoped("core"):
+            ctx = layers.fused_attention(
+                q, k, v, bias=kpad_bias, causal=causal, scale=dh ** -0.5,
+                window=window,
+            )  # [B, H, Tq, Dh]
     else:
         k, v = repeat_kv(k), repeat_kv(v)
         product = layers.matmul(q, k, transpose_y=True, alpha=dh ** -0.5)
@@ -358,7 +395,10 @@ def multi_head_attention(
         ctx = layers.matmul(weights, v)  # [B, H, Tq, Dh]
     ctx = layers.transpose(ctx, [0, 2, 1, 3])
     b, t = ctx.shape[0], ctx.shape[1]
-    ctx = layers.reshape(ctx, [b, t, d_model])
+    ctx = layers.reshape(ctx, [b, t, n_head * dh])
+    if gate is not None:
+        with scoped("attn_gate"):
+            ctx = layers.elementwise_mul(ctx, layers.sigmoid(gate))
     return layers.fc(ctx, size=d_model, num_flatten_dims=2, bias_attr=False,
                      param_attr=pa("mha_o.w"))
 

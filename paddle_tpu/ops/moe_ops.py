@@ -600,14 +600,22 @@ def _expert_bias_update(ctx, ins, attrs):
     """The balancing step of a selection bias (auxiliary-loss-free load
     balancing, Wang et al. 2024, arXiv:2408.15664, in its proportional
     form): after a step, an expert's bias moves against its share of the
-    step's routing decisions, b += EXPERT_BIAS_RATE * (1 - c / mean(c)):
-    one chosen twice as often as the mean comes down by the rate, one
-    never chosen goes up by it.  Inputs: ExpertBias [E] f32, TokensPerExpert [E] int32 (the
-    step's `moe_ffn` counts over all E, whatever share the chip holds).
-    Output: ExpertBiasOut, the same variable."""
+    step's routing decisions, b += rate * (1 - c / mean(c)): one chosen
+    twice as often as the mean comes down by the rate, one never chosen
+    goes up by it.  Attributes: `rate` (EXPERT_BIAS_RATE where the op has
+    none) and `max_step` (none: unbounded), which bounds one step to
+    [-max_step, +max_step]: over a wide router an expert that took every
+    token would else come down by E / k rates at once.  Inputs: ExpertBias
+    [E] f32, TokensPerExpert [E] int32 (the step's `moe_ffn` counts over
+    all E, whatever share the chip holds).  Output: ExpertBiasOut, the same
+    variable."""
     bias = ins["ExpertBias"][0]
     load = ins["TokensPerExpert"][0].astype(jnp.float32)
-    step = EXPERT_BIAS_RATE * (1.0 - load / load.mean())
+    step = float(attrs.get("rate", EXPERT_BIAS_RATE)) * (
+        1.0 - load / load.mean())
+    if attrs.get("max_step") is not None:
+        step = jnp.clip(step, -float(attrs["max_step"]),
+                        float(attrs["max_step"]))
     return {"ExpertBiasOut": [bias + step.astype(bias.dtype)]}
 
 

@@ -145,7 +145,9 @@ def program_flops(program, batch_hint=1):
             tk = k[2]
             v = _shape(blk, op.inputs.get("V", [""])[0], batch_hint)
             dv = v[-1] if v and len(v) == 4 else d  # latent: 192 over 128
-            window = int(op.attrs.get("window", 0) or 0)
+            # a grad op carries its forward's attrs under __fwd_attrs__
+            attrs = op.attrs.get("__fwd_attrs__", op.attrs)
+            window = int(attrs.get("window", 0) or 0)
             if window:  # sliding window: compute scales with the band
                 tk = min(tk, window)
             total += factor * 2.0 * b * h * tq * tk * (d + dv)

@@ -474,3 +474,95 @@ def test_latent_kernel_cross_lowers_for_the_tpu_on_this_host(monkeypatch, t,
         _loss(lambda q, k, v: _op(TPU, q, k, v)), argnums=(0, 1, 2))).trace(
             qk, qk, v).lower(lowering_platforms=("tpu",))
     assert lowered.as_text().count("tpu_custom_call") == calls
+
+
+# --- a window, a head width of its own and an output gate (PR 40) ---
+def test_multi_head_attention_hands_the_op_its_window_and_head_width():
+    """`head_dim` 32 over d_model 64 with 4 heads (not 64 / 4): q and the
+    gate project to 128, k and v to 2 x 32, the output back from 128; the
+    `window` is the fused_attention op's attribute, the gate a sigmoid
+    and a product before the output projection; without the new arguments
+    the layer builds what it built (no gate, no window, d_model / n_head)."""
+    import paddle_tpu as fluid
+    from paddle_tpu import framework
+    from paddle_tpu.models import transformer as tfm
+
+    def build(**kw):
+        main, startup = fluid.Program(), fluid.Program()
+        with framework.program_guard(main, startup), \
+                fluid.unique_name.guard():
+            x = layers.data("x", shape=[2, 16, 64], append_batch_size=False)
+            out = tfm.multi_head_attention(
+                x, x, x, None, 64, 4, fused=True, causal=True, n_kv_head=2,
+                **kw)
+        block = main.global_block()
+        shapes = {p.name.rsplit("_", 1)[0]: tuple(p.shape)
+                  for p in block.all_parameters()}
+        return block, shapes, out
+
+    block, shapes, out = build(head_dim=32, window=5, out_gate=True,
+                               scopes=True)
+    assert shapes == {"mha_q.w": (64, 128), "mha_k.w": (64, 64),
+                      "mha_v.w": (64, 64), "mha_gate.w": (64, 128),
+                      "mha_o.w": (128, 64)}
+    assert tuple(out.shape) == (2, 16, 64)
+    (core,) = [op for op in block.ops if op.type == "fused_attention"]
+    assert core.attrs["window"] == 5 and core.attrs["causal"]
+    assert core.attrs["scale"] == 32 ** -0.5
+    assert tuple(block.var(core.inputs["Q"][0]).shape) == (2, 4, 16, 32)
+    assert core.attrs["op_namescope"] == "core"
+    gate = [op.type for op in block.ops
+            if op.attrs.get("op_namescope") == "attn_gate"]
+    assert gate == ["sigmoid", "elementwise_mul"]
+    types = [op.type for op in block.ops]
+    assert types.index("elementwise_mul") > types.index("fused_attention")
+
+    block, shapes, _ = build()
+    assert shapes == {"mha_q.w": (64, 64), "mha_k.w": (64, 32),
+                      "mha_v.w": (64, 32), "mha_o.w": (64, 64)}
+    (core,) = [op for op in block.ops if op.type == "fused_attention"]
+    assert core.attrs["window"] == 0
+    assert not [op for op in block.ops if "op_namescope" in op.attrs]
+    assert "sigmoid" not in [op.type for op in block.ops]
+
+
+@pytest.mark.parametrize("kw, error", [
+    ({"fused": False, "causal": True}, "fused causal training path"),
+    ({"fused": True, "causal": False}, "fused causal training path"),
+    ({"fused": True, "causal": False,
+      "cache": {"k": None, "v": None, "pos": None}},
+     "fused causal training path"),
+])
+def test_a_window_outside_the_fused_causal_path_is_refused(kw, error):
+    from paddle_tpu.models import transformer as tfm
+
+    x = layers.data("x", shape=[2, 16, 64], append_batch_size=False)
+    with pytest.raises(ValueError, match=error):
+        tfm.multi_head_attention(x, x, x, None, 64, 4, window=4, **kw)
+
+
+@pytest.mark.parametrize("window", [200, 384, 512, 600])
+def test_a_window_on_the_chosen_path_is_the_dense_lowerings_mask(window):
+    """Heads of 128 at T = 512 through the op placed on a TPU (the kernel,
+    interpreted here, in 512-blocks: one tile a head, so the band is all
+    the kernel's mask) against the dense lowering, which a CPU-placed
+    step takes: the same `0 <= i - j < window`, forward and gradients, at
+    windows T is no multiple of, at T and beyond it, where both are full
+    causal attention."""
+    q, k, v = _qkv(1, 2, 512, 128, seed=6)
+    chosen = lambda q, k, v: _op(TPU, q, k, v, window=window)  # noqa: E731
+    dense = lambda q, k, v: _op(  # noqa: E731
+        LowerCtx(platform="cpu"), q, k, v, window=window)
+    _close(jax.jit(chosen)(q, k, v), dense(q, k, v))
+    grads = jax.jit(jax.grad(_loss(chosen), argnums=(0, 1, 2)))(q, k, v)
+    want = jax.jit(jax.grad(_loss(dense), argnums=(0, 1, 2)))(q, k, v)
+    for g, r in zip(grads, want):
+        _close(g, r)
+    if window >= 512:
+        np.testing.assert_array_equal(
+            np.asarray(dense(q, k, v), np.float32),
+            np.asarray(_op(LowerCtx(platform="cpu"), q, k, v), np.float32))
+        np.testing.assert_array_equal(
+            np.asarray(jax.jit(chosen)(q, k, v), np.float32),
+            np.asarray(jax.jit(lambda q, k, v: _op(TPU, q, k, v))(q, k, v),
+                       np.float32))

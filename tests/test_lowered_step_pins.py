@@ -19,7 +19,14 @@ its row work runs over the live chunks (ops/moe_ops.py), so the LFM2 step's
 text changed on purpose and its digest below is taken from PR 39's tree by
 this file's `_digest`.  Its Mosaic-call count stayed 9, and `gpt2`, `olmoe`
 and `two_kernel_backward` stayed what they were at ec9cdf7: no kernel
-instance was added, and a step whose op holds every expert did not move."""
+instance was added, and a step whose op holds every expert did not move.
+
+PR 40 added `trinity` (a head width of its own, a window on three of four
+attention layers, an output gate, `expert_bias_update` with a rate and a
+bound), its digest taken from PR 40's tree by this file's `_digest`; the
+others stayed what they were: `multi_head_attention`'s new arguments at
+their defaults, and an `expert_bias_update` without attributes, lower to
+the text they lowered to."""
 
 import base64
 import hashlib
@@ -31,7 +38,7 @@ import pytest
 
 import paddle_tpu as fluid
 from paddle_tpu.core.trace import build_traced_function
-from paddle_tpu.models import gpt2, lfm2, olmoe
+from paddle_tpu.models import gpt2, lfm2, olmoe, trinity
 from paddle_tpu.ops import pallas_kernels as pk
 
 SEQ = 512
@@ -57,13 +64,30 @@ class L(lfm2.LFM2MoEConfig):
     num_local_experts, expert_offset = 2, 2
 
 
+class T(trinity.TrinityConfig):
+    vocab_size, hidden_size, intermediate_size = 512, 128, 128
+    moe_intermediate_size, num_hidden_layers, num_dense_layers = 128, 4, 1
+    layer_types = ["sliding_attention"] * 3 + ["full_attention"]
+    num_attention_heads, num_key_value_heads, head_dim = 2, 1, 128
+    sliding_window = 256
+    num_experts, num_experts_per_tok = 8, 2
+    num_local_experts, expert_offset = 2, 2
+
+
+def _trinity_program(hp, **kw):
+    return trinity.trinity_lm_program(hp, bias_rate=0.03, bias_max_step=0.03,
+                                      **kw)
+
+
 PROGRAMS = {"gpt2": (gpt2.gpt2_lm_program, G),
             "olmoe": (olmoe.olmoe_lm_program, O),
-            "lfm2": (lfm2.lfm2_lm_program, L)}
+            "lfm2": (lfm2.lfm2_lm_program, L),
+            "trinity": (_trinity_program, T)}
 
 # name -> (sha1 of the normalised text, Mosaic calls in it) at ec9cdf7
-# (`lfm2`: at PR 39)
+# (`lfm2`: at PR 39; `trinity`: at PR 40)
 BEFORE = {
+    "trinity": ("12416b47e9d02155b186ce8f38c8ee118bdb5539", 12),
     "gpt2": ("df7ec28481d2c6f9e45f238963591867ce97786d", 3),
     "olmoe": ("10b230dd86c559798a6caed82c488d59280933fd", 9),
     "lfm2": ("1991430ba2fa7bbe38f4bdb0225a591ed0238837", 9),

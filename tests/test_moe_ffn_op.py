@@ -432,6 +432,56 @@ def test_expert_bias_update_moves_a_bias_against_its_experts_load(counts,
     np.testing.assert_allclose(np.asarray(out - bias), step, atol=1e-7)
 
 
+@pytest.mark.parametrize("attrs, step", [
+    ({"rate": 0.03}, [-0.09, 0.03, 0.03, 0.03]),   # 0.03 x (1 - 32 / 8)
+    ({"rate": 0.03, "max_step": 0.03}, [-0.03, 0.03, 0.03, 0.03]),
+    ({"max_step": 0.05}, [-0.05, 0.05, 0.05, 0.05]),  # the default rate
+    ({"rate": 0.03, "max_step": None}, [-0.09, 0.03, 0.03, 0.03]),
+])
+def test_expert_bias_update_takes_a_rate_and_a_bound(attrs, step):
+    """`rate` scales the proportional step, `max_step` bounds it to
+    [-max_step, +max_step] (an expert that took every token comes down by
+    the bound, not by E / k rates); float32 for any share held."""
+    from paddle_tpu.core.registry import LowerCtx, get_op
+
+    bias = jnp.asarray([0.05, -0.1, 0.0, 0.2], jnp.float32)
+    out = get_op("expert_bias_update").lower(
+        LowerCtx(), {"ExpertBias": [bias],
+                     "TokensPerExpert": [jnp.asarray([32, 0, 0, 0],
+                                                     jnp.int32)]},
+        attrs)["ExpertBiasOut"][0]
+    assert out.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(out - bias), step, atol=1e-7)
+
+
+def test_expert_bias_update_without_attributes_is_what_it_was():
+    """The op LFM2's and kanana-2's programs carry has no attribute: its
+    lowering is bit-equal to b + 0.1 * (1 - c / mean(c)) as it was
+    written before the attributes came back, and its jaxpr has no clamp."""
+    from paddle_tpu.core.registry import LowerCtx
+    from paddle_tpu.ops import moe_ops
+
+    rng = np.random.RandomState(3)
+    bias = jnp.asarray(rng.randn(128).astype("float32") * 0.1)
+    counts = jnp.asarray(rng.randint(0, 900, 128).astype("int32"))
+
+    def lowered(b, c):
+        return moe_ops._expert_bias_update(
+            LowerCtx(platform="cpu"),
+            {"ExpertBias": [b], "TokensPerExpert": [c]}, {})[
+                "ExpertBiasOut"][0]
+
+    def before(b, c):
+        load = c.astype(jnp.float32)
+        return b + (0.1 * (1.0 - load / load.mean())).astype(b.dtype)
+
+    np.testing.assert_array_equal(np.asarray(jax.jit(lowered)(bias, counts)),
+                                  np.asarray(jax.jit(before)(bias, counts)))
+    assert str(jax.make_jaxpr(lowered)(bias, counts)) == str(
+        jax.make_jaxpr(before)(bias, counts))
+    assert moe_ops.EXPERT_BIAS_RATE == 0.1
+
+
 def test_expert_bias_update_infer_rule():
     class Op:
         attrs = {}

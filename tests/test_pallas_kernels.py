@@ -337,6 +337,68 @@ def test_flash_attention_sliding_window_matches_dense(window):
                                    rtol=2e-3, atol=2e-4)
 
 
+def _masked_softmax_attention(q, k, v, scale, window):
+    """The dense mask written from positions, apart from
+    `_dense_attention`: key j visible to query i iff 0 <= i - j < window
+    (window 0: plain causal)."""
+    t = q.shape[1]
+    dist = jnp.arange(t)[:, None] - jnp.arange(t)[None, :]
+    keep = (dist >= 0) & ((dist < window) if window else True)
+    s = jnp.where(keep[None], jnp.einsum("bqd,bkd->bqk", q, k) * scale,
+                  -jnp.inf)
+    return jnp.einsum("bqk,bkd->bqd", jax.nn.softmax(s, -1), v)
+
+
+@pytest.mark.parametrize("backward", ["one_kernel", "two_kernels"])
+@pytest.mark.parametrize("window", [5, 12, 27, 32, 40])
+def test_flash_window_any_length_matches_the_mask_from_positions(
+        monkeypatch, window, backward):
+    """T = 32 in blocks of 8 under windows that are no multiple of a block
+    (5, 12, 27: T is no multiple of them either), the whole length (32)
+    and beyond it (40): the forward kernel and both backward forms (the
+    one-kernel backward a training step takes up to T = 8192 at 128, the
+    dq and dk/dv kernels beyond) against a softmax under the mask built
+    from positions; a window that reaches every key IS full causal
+    attention, to the bit."""
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    if backward == "two_kernels":
+        monkeypatch.setattr(pk, "_FUSED_BWD_DQ_BYTES", 0)
+    jax.clear_caches()
+    rng = np.random.RandomState(13)
+    bh, t, d = 2, 32, 8
+    q, k, v = (jnp.asarray(rng.randn(bh, t, d).astype("float32"))
+               for _ in range(3))
+    scale = 1.0 / np.sqrt(d)
+
+    def loss(fn):
+        w = jnp.cos(jnp.arange(bh * t * d, dtype=jnp.float32)).reshape(
+            bh, t, d)
+        return lambda q, k, v: jnp.sum(fn(q, k, v) * w)
+
+    kernel = lambda q, k, v: flash_attention(  # noqa: E731
+        q, k, v, None, True, scale, 8, 8, window)
+    mask = lambda q, k, v: _masked_softmax_attention(  # noqa: E731
+        q, k, v, scale, window)
+    np.testing.assert_allclose(np.asarray(kernel(q, k, v)),
+                               np.asarray(mask(q, k, v)),
+                               rtol=2e-4, atol=2e-5)
+    got = jax.grad(loss(kernel), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(mask), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-3, atol=2e-4)
+    if window >= t:
+        full = lambda q, k, v: flash_attention(  # noqa: E731
+            q, k, v, None, True, scale, 8, 8, 0)
+        np.testing.assert_array_equal(np.asarray(kernel(q, k, v)),
+                                      np.asarray(full(q, k, v)))
+        for a, b in zip(got, jax.grad(loss(full), argnums=(0, 1, 2))(q, k,
+                                                                     v)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    jax.clear_caches()  # the patched limit must not outlive the test
+
+
 def test_fused_attention_layer_window():
     """The window attr flows through the op and layer (dense path here;
     the pallas path shares the masks by the kernel test above)."""

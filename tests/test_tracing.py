@@ -745,3 +745,53 @@ def test_a_program_built_under_no_name_scope_is_what_it_was(model, use_bf16):
         for role, typ, idx in found:
             assert (ops[int(idx)].type, ops[int(idx)].attrs["op_role"]) == (
                 typ, role), op_name
+
+
+def test_trinitys_attention_scopes_reach_the_lowered_steps_op_names():
+    """attn_window / attn_full around a layer's attention, core around its
+    fused_attention op, attn_gate around the gate's sigmoid and product,
+    shared_expert: the nested part `<role>/<scopes joined by .>/<depth>`
+    of the optimized HLO's op names carries each of them, forward and
+    backward, under the op type the benchmark's readers match first
+    (`[a-z]+/fused_attention(_grad)?/<i>` then `/attn_window.core/`)."""
+    from paddle_tpu.models import gpt2, trinity
+
+    class T(trinity.TrinityConfig):
+        vocab_size, hidden_size, intermediate_size = 256, 64, 96
+        moe_intermediate_size, num_hidden_layers, num_dense_layers = 32, 3, 1
+        layer_types = ["sliding_attention", "full_attention",
+                       "sliding_attention"]
+        num_attention_heads, num_key_value_heads, head_dim = 4, 2, 32
+        sliding_window, num_experts, num_experts_per_tok = 8, 8, 2
+
+    main, startup, _, fetches = trinity.trinity_lm_program(T, seq_len=16,
+                                                           lr=1e-3)
+    startup.random_seed = main.random_seed = 5
+    exe, scope = fluid.Executor(fluid.CPUPlace()), fluid.Scope()
+    with fluid.scope_guard(scope):
+        exe.run(startup)
+        exe.run(main, feed=gpt2.make_fake_lm_batch(2, 16, T, seed=1),
+                fetch_list=[fetches[0]])
+        (text,) = exe.compiled_hlo(main)
+    ops, nested = main.global_block().ops, {}
+    for op_name in re.findall(r'op_name="jit\(program_step\)/([^"]*)"', text):
+        found = SCOPE.findall(op_name)
+        want = ops[int(found[0][2])].attrs.get("op_namescope")
+        if want is None:
+            continue
+        (role, typ, _), (_, scopes, depth) = found[:2]
+        assert (scopes, int(depth)) == (want.replace("/", "."),
+                                        want.count("/") + 1), op_name
+        nested.setdefault(scopes, set()).add((role, typ))
+    assert {"attn_window", "attn_window.core", "attn_window.attn_gate",
+            "attn_full", "attn_full.core", "attn_full.attn_gate",
+            "shared_expert"} <= set(nested)
+    for kind in ("attn_window", "attn_full"):
+        assert nested[kind + ".core"] == {
+            ("forward", "fused_attention"),
+            ("backward", "fused_attention_grad")}
+        assert ("forward", "sigmoid") in nested[kind + ".attn_gate"]
+        assert ("backward", "elementwise_mul_grad") in nested[
+            kind + ".attn_gate"]
+    assert ("forward", "rotary_embed") in nested["attn_window"]
+    assert not [t for _, t in nested["attn_full"] if "rotary" in t]

@@ -18,7 +18,13 @@ a TPU:
 
     python3 tools/kanana2_departures.py --seed 7 [--steps 98,110]
 
-(`--rehearse` runs the cell's rehearsal sizes on the CPU.)
+(`--rehearse` runs the cell's rehearsal sizes on the CPU.)  `--cell
+trinity_mini_train` makes the same comparison for another cell whose
+adapter has `DEPARTURES`, `compare` and `bf16_unit` (PR 40: the window
+left out or off by one, rotary on the wrong kind of layer, the gate, the
+per-head norms, the norms on a branch's output, route_scale, route_norm
+and the embedding's scale; `--steps 120,132` is what its 32 warm-up
+steps and a 20 s window reach).
 
 Prints one JSON line a step count (the adapter's own lines, with every
 reading, go to stderr).  PERF.md (PR 37) keeps what it read; what the
@@ -48,6 +54,7 @@ def _run_py():
 
 def main():
     ap = argparse.ArgumentParser()
+    ap.add_argument("--cell", default=CELL)
     ap.add_argument("--seed", type=int, default=7)
     ap.add_argument("--steps", default="98,110")
     ap.add_argument("--rehearse", action="store_true",
@@ -65,11 +72,11 @@ def main():
                          % jax.devices())
     run = _run_py()
     spec = run.load_json(ROOT, "BENCHMARK.json")
-    cell = run.find(spec["workloads"], CELL, "workload")
+    cell = run.find(spec["workloads"], args.cell, "workload")
     cfg = run.merged(run.load_json(ROOT, run.find(
         spec["configs"], cell["config"], "config")["file"]), args.rehearse)
     work = run.merged(run.load_json(
-        run.BENCH_DIR, "workloads", CELL + ".json"), args.rehearse)
+        run.BENCH_DIR, "workloads", args.cell + ".json"), args.rehearse)
     adapter = run.load_module("adapters", cfg["adapter"])
 
     built = adapter.build(cfg, work)
