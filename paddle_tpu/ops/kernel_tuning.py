@@ -53,6 +53,9 @@ _rng_draws = {}  # generator ("rbg" / "threefry") -> draw sites traced
 _uneven_constraints = {}  # op type -> uneven weight constraints placed
 # moe_ffn lowerings that ran a share's row work over the live chunks
 _moe_live_chunks = {"ops": 0, "chunk_rows": {}}  # chunk_rows: N*k -> rows a chunk
+# windowed fused_attention lowerings that took the flash kernel, with the
+# forward grid steps a head walks and the tiles its band computes
+_attention_band_grid = {"ops": 0, "steps": {}}  # "TxWxBQxBK" -> [walked, computed]
 _searching = threading.local()  # candidate timing in flight on this thread
 _inflight = {}  # key -> threading.Event: a measured search under way
 
@@ -345,12 +348,23 @@ def note_live_chunks(rows, chunk_rows):
         _moe_live_chunks["chunk_rows"][int(rows)] = int(chunk_rows)
 
 
+def note_band_grid(t, window, block_q, block_k, walked, computed):
+    """Count a trace-time lowering of a windowed `fused_attention` to the
+    flash kernel, and keep by shape the grid steps a head's forward walks
+    and the tiles its band lets compute."""
+    with _lock:
+        _attention_band_grid["ops"] += 1
+        _attention_band_grid["steps"]["%dx%dx%dx%d" % (
+            t, window, block_q, block_k)] = [int(walked), int(computed)]
+
+
 def attribution():
     """Snapshot for bench attribution: per-family pallas-hit counts,
     in-program random draws by generator, uneven weight constraints by op
     type, the moe_ffn lowerings that took the live-chunk path with the
-    rows of a chunk by buffer size, plus tuning-cache hit/miss/search
-    totals (search_ms summed)."""
+    rows of a chunk by buffer size, the windowed flash lowerings with their
+    forward grid steps walked and computed by shape, plus tuning-cache
+    hit/miss/search totals (search_ms summed)."""
     with _lock:
         return {
             "pallas_hits": dict(_kernel_hits),
@@ -360,6 +374,10 @@ def attribution():
             "moe_live_chunks": {
                 "ops": _moe_live_chunks["ops"],
                 "chunk_rows": dict(_moe_live_chunks["chunk_rows"])},
+            "attention_band_grid": {
+                "ops": _attention_band_grid["ops"],
+                "steps": {k: list(v) for k, v in
+                          _attention_band_grid["steps"].items()}},
             "tuning": {k: (round(v, 3) if isinstance(v, float) else v)
                        for k, v in _stats.items()},
         }
@@ -372,6 +390,7 @@ def reset_attribution():
         _rng_draws.clear()
         _uneven_constraints.clear()
         _moe_live_chunks.update(ops=0, chunk_rows={})
+        _attention_band_grid.update(ops=0, steps={})
         _stats.update(_STATS_ZERO)
 
 

@@ -1192,14 +1192,17 @@ def _fused_attention(ctx, ins, attrs):
     # step is placed on a TPU and the shape is one the chip sweep found
     # it ahead at, dense XLA everywhere else.  No flag is read here.
     if _flash_engages(ctx, t, tk, d, dv):
-        from .kernel_tuning import note_kernel
-        from .pallas_kernels import flash_attention
+        from .kernel_tuning import note_band_grid, note_kernel
+        from .pallas_kernels import band_grid_steps, flash_attention
         from .spmd_epilogue import mesh_ctx, spmd_flash_attention
 
         note_kernel("attention")
         if dv != d:  # which widths the kernel engaged with, when not one
             note_kernel("attention_qk%d_v%d" % (d, dv))
         mc, blk = mesh_ctx(), _flash_block(t)
+        if window:  # the grid a head's forward walks against its band
+            note_band_grid(t, window, blk, blk,
+                           *band_grid_steps(t, blk, blk, window))
         if mc is None:
             out = flash_attention(qf, kf, vf, kbias, causal, float(scale),
                                   blk, blk, window, seg)

@@ -163,22 +163,26 @@ def _unpack_flash_refs(refs, has_qoff, has_seg):
 
 def _flash_fwd_kernel(*refs, block_q, block_k, nk,
                       causal, scale, window=0, has_qoff=False,
-                      has_seg=False):
+                      has_seg=False, band=0):
     from jax.experimental import pallas as pl
 
     qo, q_ref, k_ref, v_ref, kb_ref, sq_ref, sk_ref, refs = \
         _unpack_flash_refs(refs, has_qoff, has_seg)
     o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    ki = step = pl.program_id(2)
+    if band:  # the innermost axis walks q block qi's band, `band` steps
+        ki, live = _band_step(qi, step, block_q, block_k, window, nk)
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
     run, keep_fn = _band(qi, ki, qo, block_q, block_k, causal, window)
+    if band:
+        run = run & live
 
     @pl.when(run)
     def _compute():
@@ -200,7 +204,7 @@ def _flash_fwd_kernel(*refs, block_q, block_k, nk,
             p.astype(v.dtype), v, preferred_element_type=jnp.float32)
         m_ref[:] = m_new
 
-    @pl.when(ki == nk - 1)
+    @pl.when(step == (band or nk) - 1)
     def _write():
         l = l_ref[:]
         safe_l = jnp.where(l == 0.0, 1.0, l)
@@ -233,6 +237,81 @@ def _band(qi, ki, qo, block_q, block_k, causal, window, transposed=False):
     return run, keep_fn
 
 
+# The band as a grid.  A windowed kernel without a traced q offset knows
+# its band when it is traced, so its innermost grid axis is the band's
+# width in blocks and its index maps address the band's blocks alone: the
+# pipeline fetches no block that _band would then skip.  Forward (and the
+# dq kernel) walk q block i's k blocks; the backward kernels, q innermost,
+# walk k block j's q blocks (`transposed`).  A walk shorter than the
+# widest one (the sequence's start, or its end transposed) repeats its
+# last block, which the pipeline does not fetch again and `live` keeps
+# from computing twice.
+def _band_span(o, block_q, block_k, window, n_inner, transposed=False,
+               traced=True):
+    """(first, last) inner block that _band runs (qo = 0, causal, window)
+    for outer block `o`: k blocks of q block o, or with `transposed` q
+    blocks of k block o.  `o` is a traced int32 scalar, or with
+    traced=False a numpy array or int."""
+    if traced:
+        mx, mn = jnp.maximum, jnp.minimum
+
+        def div(a, b):  # a >= 0
+            return jax.lax.div(a, jnp.int32(b))
+    else:
+        mx, mn, div = np.maximum, np.minimum, np.floor_divide
+    if transposed:
+        first = div(o * block_k, block_q)
+        last = mn(div(o * block_k + (block_k + window - 2), block_q),
+                  n_inner - 1)
+    else:
+        first = div(mx(o * block_q - (window - 1), 0), block_k)
+        last = mn(div(o * block_q + (block_q - 1), block_k), n_inner - 1)
+    return first, last
+
+
+def _band_step(o, step, block_q, block_k, window, n_inner, transposed=False):
+    """Step `step` of outer block o's walk: (inner block, live)."""
+    first, last = _band_span(o, block_q, block_k, window, n_inner,
+                             transposed)
+    return jnp.minimum(first + step, last), first + step <= last
+
+
+def _band_inner(band, block_q, block_k, window, n_inner, transposed=False):
+    """An index map's inner block of grid step (outer block, step): the
+    step itself on the full grid (band == 0)."""
+    if not band:
+        return lambda o, step: step
+    return lambda o, step: _band_step(o, step, block_q, block_k, window,
+                                      n_inner, transposed)[0]
+
+
+def _band_grid(tq, tk, block_q, block_k, causal, window, transposed=False):
+    """Steps of the innermost grid axis where a flash kernel walks the band
+    alone, 0 where it keeps the full grid: no window, a window that covers
+    the sequence, or a band as wide as the grid."""
+    if not (causal and 0 < window < tq == tk):
+        return 0
+    n_outer, n_inner = tk // block_k, tq // block_q
+    if not transposed:
+        n_outer, n_inner = n_inner, n_outer
+    first, last = _band_span(np.arange(n_outer), block_q, block_k, window,
+                             n_inner, transposed, traced=False)
+    width = int(np.max(last - first)) + 1
+    return width if width < n_inner else 0
+
+
+def band_grid_steps(t, block_q, block_k, window):
+    """(grid steps a head's forward walks, tiles _band lets run) of a
+    causal windowed flash_attention at length t, for the lowering's
+    attribution: the full grid's count where _band_grid keeps it."""
+    block_q, block_k = min(block_q, t), min(block_k, t)
+    nq, nk = t // block_q, t // block_k
+    first, last = _band_span(np.arange(nq), block_q, block_k, window, nk,
+                             traced=False)
+    width = _band_grid(t, t, block_q, block_k, True, window)
+    return nq * (width or nk), int(np.sum(last - first + 1))
+
+
 def _flash_blocks(Tq, Tk, block_q, block_k, causal):
     block_q = min(block_q, Tq)
     block_k = min(block_k, Tk)
@@ -251,7 +330,8 @@ def _flash_fwd(q, k, v, kbias, causal, scale, block_q, block_k, window=0,
     scores are 192 wide over 128-wide values), kbias: [BH, Tk] additive
     key bias.
     window > 0 (causal only): sliding-window attention — each query sees
-    only the last `window` key positions.  qoff: optional [1] int32 GLOBAL
+    only the last `window` key positions; without a traced offset the
+    grid's innermost axis is the band's width (_band_grid).  qoff: optional [1] int32 GLOBAL
     q-position base relative to k's (traced; SMEM scalar) — the ring
     passes its chunk offset so causal/window masks apply in global
     positions.  qvec: optional [BH] int32 PER-ROW q-position bases (the
@@ -273,11 +353,14 @@ def _flash_fwd(q, k, v, kbias, causal, scale, block_q, block_k, window=0,
     if interpret is None:
         interpret = _interpret()
     nq, nk = T // block_q, Tk // block_k
+    band = _band_grid(T, Tk, block_q, block_k,
+                      causal and qoff is None and qvec is None, int(window))
+    kblock = _band_inner(band, block_q, block_k, int(window), nk)
     kernel = functools.partial(
         _flash_fwd_kernel, block_q=block_q, block_k=block_k, nk=nk,
         causal=causal, scale=scale, window=int(window),
         has_qoff=("vec" if qvec is not None else qoff is not None),
-        has_seg=seg is not None,
+        has_seg=seg is not None, band=band,
     )
     # 2D [BH, X] operands ride as [BH, 1, X] so every block keeps a
     # Mosaic-legal last-two-dims shape ((1, blk): second-minor equals the
@@ -285,11 +368,11 @@ def _flash_fwd(q, k, v, kbias, causal, scale, block_q, block_k, window=0,
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0),
+        pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, kblock(i, j), 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, dv), lambda b, i, j: (b, j, 0),
+        pl.BlockSpec((1, block_k, dv), lambda b, i, j: (b, kblock(i, j), 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, 1, block_k), lambda b, i, j: (b, 0, j),
+        pl.BlockSpec((1, 1, block_k), lambda b, i, j: (b, 0, kblock(i, j)),
                      memory_space=pltpu.VMEM),
     ]
     args = [q, k, v, kbias.reshape(BH, 1, Tk)]
@@ -298,7 +381,8 @@ def _flash_fwd(q, k, v, kbias, causal, scale, block_q, block_k, window=0,
         in_specs += [
             pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, block_k), lambda b, i, j: (b, 0, j),
+            pl.BlockSpec((1, 1, block_k),
+                         lambda b, i, j: (b, 0, kblock(i, j)),
                          memory_space=pltpu.VMEM),
         ]
         args += [seg3, seg3]
@@ -308,7 +392,7 @@ def _flash_fwd(q, k, v, kbias, causal, scale, block_q, block_k, window=0,
                         else qvec.astype(jnp.int32).reshape(BH)))
     o, lse = pl.pallas_call(
         kernel,
-        grid=(BH, nq, nk),
+        grid=(BH, nq, band or nk),
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0),
@@ -332,20 +416,24 @@ def _flash_fwd(q, k, v, kbias, causal, scale, block_q, block_k, window=0,
 
 
 def _flash_dq_kernel(*refs, block_q, block_k, nk, causal, scale,
-                     window=0, has_qoff=False, has_seg=False):
+                     window=0, has_qoff=False, has_seg=False, band=0):
     from jax.experimental import pallas as pl
 
     qo, q_ref, k_ref, v_ref, kb_ref, sq_ref, sk_ref, refs = \
         _unpack_flash_refs(refs, has_qoff, has_seg)
     do_ref, lse_ref, delta_ref, dq_ref, dq_acc = refs
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    ki = step = pl.program_id(2)
+    if band:  # as the forward: `band` steps over q block qi's k blocks
+        ki, live = _band_step(qi, step, block_q, block_k, window, nk)
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
     run, keep_fn = _band(qi, ki, qo, block_q, block_k, causal, window)
+    if band:
+        run = run & live
 
     @pl.when(run)
     def _compute():
@@ -367,13 +455,13 @@ def _flash_dq_kernel(*refs, block_q, block_k, nk, causal, scale,
         dq_acc[:] = dq_acc[:] + scale * jnp.dot(
             ds.astype(k.dtype), k, preferred_element_type=jnp.float32)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(step == (band or nk) - 1)
     def _write():
         dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
 
 def _flash_dkv_kernel(*refs, block_q, block_k, nq, causal, scale,
-                      window=0, has_qoff=False, has_seg=False):
+                      window=0, has_qoff=False, has_seg=False, band=0):
     from jax.experimental import pallas as pl
 
     qo, q_ref, k_ref, v_ref, kb_ref, sq_ref, sk_ref, refs = \
@@ -381,9 +469,12 @@ def _flash_dkv_kernel(*refs, block_q, block_k, nq, causal, scale,
     (do_ref, lse_ref, delta_ref,
      dk_ref, dv_ref, dkb_ref, dk_acc, dv_acc, dkb_acc) = refs
     ki = pl.program_id(1)
-    qi = pl.program_id(2)
+    qi = step = pl.program_id(2)
+    if band:  # `band` steps over k block ki's q blocks
+        qi, live = _band_step(ki, step, block_q, block_k, window, nq,
+                              transposed=True)
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
@@ -392,6 +483,8 @@ def _flash_dkv_kernel(*refs, block_q, block_k, nq, causal, scale,
     run, keep_fn = _band(qi, ki, qo, block_q, block_k, causal, window)
     if not causal:
         run = qi >= 0  # this grid iterates q innermost
+    if band:
+        run = run & live
 
     @pl.when(run)
     def _compute():
@@ -413,7 +506,7 @@ def _flash_dkv_kernel(*refs, block_q, block_k, nq, causal, scale,
             ds.T.astype(q.dtype), q, preferred_element_type=jnp.float32)
         dkb_acc[:] = dkb_acc[:] + jnp.sum(ds, axis=0, keepdims=True)
 
-    @pl.when(qi == nq - 1)
+    @pl.when(step == (band or nq) - 1)
     def _write():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
@@ -455,17 +548,29 @@ def _flash_bwd(q, k, v, kbias, o, lse, do, causal, scale, block_q, block_k,
     delta3 = delta.reshape(BH, 1, T)
     seg3 = (seg.astype(jnp.int32).reshape(BH, 1, T)
             if seg is not None else None)
+    # the band grids of the forward (dq pass) and of the fused backward
+    # (dk/dv pass), where there is no traced offset
+    static = causal and qoff is None and qvec is None
+    band_k = _band_grid(T, Tk, block_q, block_k, static, int(window))
+    band_q = _band_grid(T, Tk, block_q, block_k, static, int(window),
+                        transposed=True)
+    kblock = _band_inner(band_k, block_q, block_k, int(window), nk)
+    qblock = _band_inner(band_q, block_q, block_k, int(window), nq,
+                         transposed=True)
 
     q_spec_q = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0),
                             memory_space=pltpu.VMEM)
-    k_spec_q = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0),
+    k_spec_q = pl.BlockSpec((1, block_k, d),
+                            lambda b, i, j: (b, kblock(i, j), 0),
                             memory_space=pltpu.VMEM)
     # v and do are dv wide (d everywhere but under latent attention)
-    v_spec_q = pl.BlockSpec((1, block_k, dv), lambda b, i, j: (b, j, 0),
+    v_spec_q = pl.BlockSpec((1, block_k, dv),
+                            lambda b, i, j: (b, kblock(i, j), 0),
                             memory_space=pltpu.VMEM)
     do_spec_q = pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0),
                              memory_space=pltpu.VMEM)
-    kb_spec_q = pl.BlockSpec((1, 1, block_k), lambda b, i, j: (b, 0, j),
+    kb_spec_q = pl.BlockSpec((1, 1, block_k),
+                             lambda b, i, j: (b, 0, kblock(i, j)),
                              memory_space=pltpu.VMEM)
     row_spec_q = pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i),
                               memory_space=pltpu.VMEM)
@@ -477,8 +582,8 @@ def _flash_bwd(q, k, v, kbias, o, lse, do, causal, scale, block_q, block_k,
         functools.partial(_flash_dq_kernel, block_q=block_q, block_k=block_k,
                           nk=nk, causal=causal, scale=scale,
                           window=int(window), has_qoff=has_qoff,
-                          has_seg=seg is not None),
-        grid=(BH, nq, nk),
+                          has_seg=seg is not None, band=band_k),
+        grid=(BH, nq, band_k or nk),
         in_specs=smem + [q_spec_q, k_spec_q, v_spec_q, kb_spec_q]
         + seg_specs_q + [do_spec_q, row_spec_q, row_spec_q],
         out_specs=q_spec_q,
@@ -489,25 +594,28 @@ def _flash_bwd(q, k, v, kbias, o, lse, do, causal, scale, block_q, block_k,
     )(*(qoff_arg + [q, k, v, kb3] + seg_args + [do, lse3, delta3]))
 
     # dk/dv pass: grid iterates q blocks innermost for each k block
-    q_spec_k = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, j, 0),
+    q_spec_k = pl.BlockSpec((1, block_q, d),
+                            lambda b, i, j: (b, qblock(i, j), 0),
                             memory_space=pltpu.VMEM)
     k_spec_k = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0),
                             memory_space=pltpu.VMEM)
     v_spec_k = pl.BlockSpec((1, block_k, dv), lambda b, i, j: (b, i, 0),
                             memory_space=pltpu.VMEM)
-    do_spec_k = pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, j, 0),
+    do_spec_k = pl.BlockSpec((1, block_q, dv),
+                             lambda b, i, j: (b, qblock(i, j), 0),
                              memory_space=pltpu.VMEM)
     kb_spec_k = pl.BlockSpec((1, 1, block_k), lambda b, i, j: (b, 0, i),
                              memory_space=pltpu.VMEM)
-    row_spec_k = pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, j),
+    row_spec_k = pl.BlockSpec((1, 1, block_q),
+                              lambda b, i, j: (b, 0, qblock(i, j)),
                               memory_space=pltpu.VMEM)
     seg_specs_k = ([row_spec_k, kb_spec_k] if seg is not None else [])
     dk, dv, dkb = pl.pallas_call(
         functools.partial(_flash_dkv_kernel, block_q=block_q, block_k=block_k,
                           nq=nq, causal=causal, scale=scale,
                           window=int(window), has_qoff=has_qoff,
-                          has_seg=seg is not None),
-        grid=(BH, nk, nq),
+                          has_seg=seg is not None, band=band_q),
+        grid=(BH, nk, band_q or nq),
         in_specs=smem + [q_spec_k, k_spec_k, v_spec_k, kb_spec_k]
         + seg_specs_k + [do_spec_k, row_spec_k, row_spec_k],
         out_specs=[k_spec_k, v_spec_k, kb_spec_k],
@@ -579,7 +687,7 @@ _FUSED_BWD_DQ_BYTES_WIDE = 6 * 2 ** 20
 
 
 def _flash_bwd_fused_kernel(*refs, block_q, block_k, nq, nk, causal, scale,
-                            window, has_kb, has_seg):
+                            window, has_kb, has_seg, band=0):
     from jax.experimental import pallas as pl
 
     refs = list(refs)
@@ -593,13 +701,16 @@ def _flash_bwd_fused_kernel(*refs, block_q, block_k, nq, nk, causal, scale,
     dq_acc, dk_acc, dv_acc = refs[:3]
     dkb_acc = refs[3] if has_kb else None
     ki = pl.program_id(1)
-    qi = pl.program_id(2)
+    qi = step = pl.program_id(2)
+    if band:  # `band` steps over k block ki's q blocks
+        qi, live = _band_step(ki, step, block_q, block_k, window, nq,
+                              transposed=True)
 
-    @pl.when((ki == 0) & (qi == 0))
+    @pl.when((ki == 0) & (step == 0))
     def _init_row():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
@@ -608,6 +719,8 @@ def _flash_bwd_fused_kernel(*refs, block_q, block_k, nq, nk, causal, scale,
 
     run, keep_fn = _band(qi, ki, 0, block_q, block_k, causal, window,
                          transposed=True)
+    if band:
+        run = run & live
 
     @pl.when(run)
     def _compute():
@@ -632,14 +745,14 @@ def _flash_bwd_fused_kernel(*refs, block_q, block_k, nq, nk, causal, scale,
             dsc, k, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)  # [bq, d]
 
-    @pl.when(qi == nq - 1)
+    @pl.when(step == (band or nq) - 1)
     def _write():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
         if has_kb:
             dkb_ref[0] = dkb_acc[:]
 
-    @pl.when((ki == nk - 1) & (qi == nq - 1))
+    @pl.when((ki == nk - 1) & (step == (band or nq) - 1))
     def _write_row():
         dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
@@ -655,16 +768,20 @@ def _flash_bwd_fused(q, k, v, kbias, seg, o, lse, do, causal, scale, block_q,
     dv = v.shape[2]  # v, do and dv's width: d but under latent attention
     block_q, block_k = _flash_blocks(T, T, block_q, block_k, causal)
     nq, nk = T // block_q, T // block_k
+    band = _band_grid(T, T, block_q, block_k, causal, int(window),
+                      transposed=True)
+    qblock = _band_inner(band, block_q, block_k, int(window), nq,
+                         transposed=True)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
 
     def spec(shape, index):
         return pl.BlockSpec(shape, index, memory_space=pltpu.VMEM)
 
-    q_spec = spec((1, block_q, d), lambda b, i, j: (b, j, 0))
+    q_spec = spec((1, block_q, d), lambda b, i, j: (b, qblock(i, j), 0))
     k_spec = spec((1, block_k, d), lambda b, i, j: (b, i, 0))
-    do_spec = spec((1, block_q, dv), lambda b, i, j: (b, j, 0))
+    do_spec = spec((1, block_q, dv), lambda b, i, j: (b, qblock(i, j), 0))
     v_spec = spec((1, block_k, dv), lambda b, i, j: (b, i, 0))
-    qrow_spec = spec((1, 1, block_q), lambda b, i, j: (b, 0, j))
+    qrow_spec = spec((1, 1, block_q), lambda b, i, j: (b, 0, qblock(i, j)))
     krow_spec = spec((1, 1, block_k), lambda b, i, j: (b, 0, i))
     in_specs, args = [q_spec, k_spec, v_spec], [q, k, v]
     if kbias is not None:
@@ -691,8 +808,8 @@ def _flash_bwd_fused(q, k, v, kbias, seg, o, lse, do, causal, scale, block_q,
         functools.partial(
             _flash_bwd_fused_kernel, block_q=block_q, block_k=block_k,
             nq=nq, nk=nk, causal=causal, scale=scale, window=int(window),
-            has_kb=kbias is not None, has_seg=seg is not None),
-        grid=(BH, nk, nq),
+            has_kb=kbias is not None, has_seg=seg is not None, band=band),
+        grid=(BH, nk, band or nq),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
@@ -757,8 +874,10 @@ def flash_attention(q, k, v, kbias=None, causal=False, scale=None,
     VMEM.  kbias: optional [BH, Tk] additive key bias (the padding-mask
     row, indexed by key position).  window > 0 (causal): sliding-window
     local attention over the last `window` positions — blocks wholly above
-    the diagonal or out of the window are skipped in every kernel, so
-    compute scales with the band, not T^2.  seg: optional [BH, T] int
+    the diagonal or out of the window are skipped in every kernel, and a
+    window inside the sequence makes the band the kernels' grid
+    (_band_grid: no block outside it is fetched), so compute and grid
+    steps scale with the band, not T^2.  seg: optional [BH, T] int
     segment ids (sequence packing, Tq == Tk): scores across segment
     boundaries are masked inside every kernel — rank-1 operands only, no
     [T, T] mask.  Forward and backward are owned here (custom_vjp): the

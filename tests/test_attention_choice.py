@@ -566,3 +566,227 @@ def test_a_window_on_the_chosen_path_is_the_dense_lowerings_mask(window):
             np.asarray(jax.jit(chosen)(q, k, v), np.float32),
             np.asarray(jax.jit(lambda q, k, v: _op(TPU, q, k, v))(q, k, v),
                        np.float32))
+
+
+# (T, window, block_q, block_k): a window under a block, one block, no
+# multiple of one (200, 600), the whole length and beyond it, and blocks
+# that are not square at T = 2048
+BAND_TABLE = [
+    (512, 50, 128, 128),
+    (512, 128, 128, 128),
+    (1024, 200, 128, 128),
+    (1024, 600, 256, 256),
+    (1024, 600, 128, 256),
+    (512, 512, 128, 128),
+    (512, 700, 128, 128),
+    (2048, 300, 512, 1024),
+    (2048, 300, 1024, 512),
+]
+
+
+def _band_runs(t, window, bq, bk):
+    """_band's `run` by brute force: [nq, nk] of bool."""
+    return np.array([[bool(pk._band(i, j, 0, bq, bk, True, window)[0])
+                      for j in range(t // bk)] for i in range(t // bq)])
+
+
+@pytest.mark.parametrize("t, window, bq, bk", BAND_TABLE)
+def test_band_span_is_the_band_that_band_runs(t, window, bq, bk):
+    """The band grid's helper against `_band` itself, both ways round: for
+    every outer block the first and last inner block `_band` runs, as
+    numpy and as the traced scalars an index map computes; the grid's
+    width is the widest walk (0, the full grid, where that is as wide as
+    the grid or the window covers the sequence); every walk visits just
+    its live blocks, in order, and then repeats the last one."""
+    runs = _band_runs(t, window, bq, bk)
+    for transposed, table in ((False, runs), (True, runs.T)):
+        n_outer, n_inner = table.shape
+        assert table.any(axis=1).all()  # every walk computes something
+        first = table.argmax(axis=1)
+        last = n_inner - 1 - table[:, ::-1].argmax(axis=1)
+        # a band is contiguous: what lies between first and last runs
+        assert (table.sum(axis=1) == last - first + 1).all()
+        got = pk._band_span(np.arange(n_outer), bq, bk, window, n_inner,
+                            transposed, traced=False)
+        np.testing.assert_array_equal(got[0], first)
+        np.testing.assert_array_equal(got[1], last)
+        widest = int((last - first).max()) + 1
+        width = pk._band_grid(t, t, bq, bk, True, window, transposed)
+        assert width == (widest if widest < n_inner and window < t else 0)
+        for o in range(n_outer):
+            lo, hi = pk._band_span(jnp.int32(o), bq, bk, window, n_inner,
+                                   transposed)
+            assert (int(lo), int(hi)) == (first[o], last[o])
+            walk = [pk._band_step(jnp.int32(o), jnp.int32(s), bq, bk, window,
+                                  n_inner, transposed)
+                    for s in range(width)]
+            live = [int(b) for b, ok in walk if ok]
+            if width:
+                assert live == list(range(first[o], last[o] + 1))
+                assert all(int(b) == last[o] for b, ok in walk if not ok)
+    walked, computed = pk.band_grid_steps(t, bq, bk, window)
+    nb = pk._band_grid(t, t, bq, bk, True, window)
+    assert computed == runs.sum()
+    assert walked == runs.shape[0] * (nb or runs.shape[1])
+    assert pk._band_grid(t, t, bq, bk, True, 0) == 0  # no window: no band
+    assert pk._band_grid(t, t, bq, bk, False, 0) == 0
+
+
+@pytest.mark.parametrize("extra", ["plain", "kbias", "seg"])
+@pytest.mark.parametrize("backward", ["one_kernel", "two_kernels"])
+@pytest.mark.parametrize("t, window, bq, bk", BAND_TABLE)
+def test_band_grid_kernels_match_dense(monkeypatch, t, window, bq, bk,
+                                       backward, extra):
+    """The kernels on the band grid (and on the full grid where the table's
+    row keeps it) against `_dense_attention` under the same window:
+    forward, dq, dk, dv and the key bias' gradient, the one-kernel backward
+    and the dq + dk/dv pair, with a key bias and with packed segments."""
+    if backward == "two_kernels":
+        monkeypatch.setattr(pk, "_FUSED_BWD_DQ_BYTES", 0)
+    jax.clear_caches()
+    rng = np.random.RandomState(t + window + bq)
+    bh, d = 2, 8
+    q, k, v = (jnp.asarray(rng.randn(bh, t, d).astype("float32"))
+               for _ in range(3))
+    kbias = (jnp.asarray(rng.randn(bh, t).astype("float32"))
+             if extra == "kbias" else None)
+    # segments of uneven lengths, so a boundary falls inside a block
+    seg = (jnp.asarray(np.broadcast_to(np.searchsorted(
+        [t // 5, t // 2 + 3], np.arange(t), side="right"), (bh, t)),
+        jnp.int32) if extra == "seg" else None)
+    scale = 1.0 / np.sqrt(d)
+    w = jnp.cos(jnp.arange(bh * t * d, dtype=jnp.float32)).reshape(bh, t, d)
+
+    def kernel(q, k, v, kb):
+        return pk.flash_attention(q, k, v, kb, True, scale, bq, bk, window,
+                                  seg)
+
+    def dense(q, k, v, kb):
+        return pk._dense_attention(q, k, v, True, scale, kb, window=window,
+                                   seg=seg)
+
+    np.testing.assert_allclose(np.asarray(kernel(q, k, v, kbias)),
+                               np.asarray(dense(q, k, v, kbias)),
+                               rtol=2e-4, atol=2e-5)
+    argnums = (0, 1, 2, 3) if kbias is not None else (0, 1, 2)
+    got = jax.grad(lambda *a: jnp.sum(kernel(*a) * w), argnums)(
+        q, k, v, kbias)
+    want = jax.grad(lambda *a: jnp.sum(dense(*a) * w), argnums)(
+        q, k, v, kbias)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-3, atol=2e-4)
+    jax.clear_caches()  # the patched limit must not outlive the test
+
+
+def _grids(fn, *args):
+    """The grid of every pallas_call under fn's jaxpr, shard_map bodies and
+    nested calls included."""
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn.params["grid_mapping"].grid
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from walk(sub)
+    return list(walk(jax.make_jaxpr(fn)(*args).jaxpr))
+
+
+@pytest.mark.skipif(len(jax.devices()) < 4, reason="needs 4 virtual devices")
+def test_a_window_under_a_dp2_mp2_mesh_walks_the_band(monkeypatch):
+    """spmd_flash_attention hands the same entry the window: inside the
+    shard_map each device's kernels walk the band's 3 of 4 blocks (T = 512
+    in blocks of 128, said by the test, under a window of 200), forward and
+    backward, and match the dense lowering under the same window."""
+    from paddle_tpu.parallel.mesh import make_mesh
+    from paddle_tpu.parallel.partition_rules import (
+        spmd_lowering, train_partition_rules_for)
+
+    monkeypatch.setattr(nn_ops, "_FLASH_BLOCKS", (128,))
+    mesh = make_mesh({"dp": 2, "mp": 2}, jax.devices()[:4])
+    rules = train_partition_rules_for("gpt2")
+    q, k, v = _qkv(2, 2, 512, 64, seed=8)
+
+    def sharded(q, k, v):
+        with spmd_lowering(mesh, rules):
+            return _op(TPU, q, k, v, window=200)
+
+    def dense(q, k, v):
+        return _op(LowerCtx(platform="cpu"), q, k, v, window=200)
+
+    f = jax.jit(jax.value_and_grad(
+        lambda *a: _loss(sharded)(*a), argnums=(0, 1, 2)))
+    assert "shard_map" in str(jax.make_jaxpr(f)(q, k, v))
+    grids = _grids(f, q, k, v)
+    assert grids and all(g == (1, 4, 3) for g in grids)
+    out, grads = f(q, k, v)
+    ref, ref_grads = jax.jit(jax.value_and_grad(
+        lambda *a: _loss(dense)(*a), argnums=(0, 1, 2)))(q, k, v)
+    np.testing.assert_allclose(float(out), float(ref), rtol=2e-2)
+    for g, r in zip(grads, ref_grads):
+        _close(g, r)
+    assert kt.attribution()["attention_band_grid"]["steps"][
+        "512x200x128x128"] == [12, 9]
+
+
+def test_the_lowering_records_the_band_grid_of_a_windowed_op_only():
+    """attribution()["attention_band_grid"], at trace time, by the op's
+    lowering: Trinity-Mini's window layers (T 8192, window 2048, blocks of
+    1024) walk 24 forward steps a head and compute 21 (the full grid's 64
+    is what the kernel no longer walks); an op without a window records
+    nothing; one a lowering, however often the jitted kernel is shared."""
+    x = jax.ShapeDtypeStruct((1, 2, 8192, 128), jnp.bfloat16)
+    kt.reset_attribution()
+    jax.eval_shape(lambda q, k, v: _op(TPU, q, k, v), x, x, x)
+    assert kt.attribution()["pallas_hits"]["attention"] == 1
+    assert kt.attribution()["attention_band_grid"] == {"ops": 0, "steps": {}}
+    for _ in range(2):
+        jax.eval_shape(lambda q, k, v: _op(TPU, q, k, v, window=2048),
+                       x, x, x)
+    assert kt.attribution()["attention_band_grid"] == {
+        "ops": 2, "steps": {"8192x2048x1024x1024": [24, 21]}}
+    # a window that covers the sequence keeps the full grid, and says so
+    jax.eval_shape(lambda q, k, v: _op(TPU, q, k, v, window=8192), x, x, x)
+    assert kt.attribution()["attention_band_grid"]["steps"][
+        "8192x8192x1024x1024"] == [64, 36]
+    # the dense lowering (a CPU-placed step) records none
+    kt.reset_attribution()
+    jax.eval_shape(lambda q, k, v: _op(LowerCtx(platform="cpu"), q, k, v,
+                                       window=2048), x, x, x)
+    assert kt.attribution()["attention_band_grid"] == {"ops": 0, "steps": {}}
+
+
+def test_window_grid_live_share_is_in_the_benchmark_by_name(monkeypatch):
+    """BENCHMARK.json carries `window_grid_live_share` (found by name, not
+    by position), its layer_metrics file names a reader that imports, and
+    the reader answers None on a program that records no band grid (the
+    parent commit's, or one whose windowed ops never took the kernel),
+    87.5 from Trinity-Mini's record."""
+    import importlib.util
+    import json
+
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        spec = json.load(f)
+    entry = [m for m in spec["per_layer"]
+             if m["name"] == "window_grid_live_share"]
+    assert len(entry) == 1
+    assert entry[0]["workloads"] == ["trinity_mini_train"]
+    assert (entry[0]["unit"], entry[0]["better"], entry[0]["moves"]) == (
+        "%", "higher", "train_mfu")
+    with open(os.path.join(ROOT, "benchmark", "layer_metrics",
+                           "window_grid_live_share.json")) as f:
+        how = json.load(f)
+    path = os.path.join(ROOT, "benchmark", "readers", how["reader"] + ".py")
+    mod_spec = importlib.util.spec_from_file_location("band_grid_stat", path)
+    reader = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(reader)
+    ctx = {"log": lambda msg: None}
+    kt.reset_attribution()
+    assert reader.read(ctx, **how.get("args", {})) is None
+    before = kt.attribution()
+    monkeypatch.setattr(kt, "attribution", lambda: {
+        k: v for k, v in before.items() if k != "attention_band_grid"})
+    assert reader.read(ctx) is None  # a program from before the counter
+    monkeypatch.undo()
+    kt.note_band_grid(8192, 2048, 1024, 1024, 24, 21)
+    assert reader.read(ctx) == 87.5
+    kt.reset_attribution()

@@ -148,6 +148,21 @@ def test_attribution_counters_and_reset():
     assert att["pallas_hits"] == {} and att["tuning"]["hits"] == 0
 
 
+def test_band_grid_attribution_and_reset():
+    """note_band_grid counts a lowering and keeps [walked, computed] by
+    T x window x block_q x block_k; reset clears both."""
+    kt.reset_attribution()
+    assert kt.attribution()["attention_band_grid"] == {"ops": 0, "steps": {}}
+    kt.note_band_grid(8192, 2048, 1024, 1024, 24, 21)
+    kt.note_band_grid(8192, 2048, 1024, 1024, 24, 21)
+    kt.note_band_grid(4096, 512, 512, 512, 16, 15)
+    assert kt.attribution()["attention_band_grid"] == {
+        "ops": 3, "steps": {"8192x2048x1024x1024": [24, 21],
+                            "4096x512x512x512": [16, 15]}}
+    kt.reset_attribution()
+    assert kt.attribution()["attention_band_grid"] == {"ops": 0, "steps": {}}
+
+
 def test_device_kind_isolates_interpret_entries():
     """Interpret-mode (CPU) cache keys carry their own device universe,
     so a CI cache can never leak block sizes onto a real chip."""

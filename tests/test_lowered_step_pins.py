@@ -26,7 +26,18 @@ attention layers, an output gate, `expert_bias_update` with a rate and a
 bound), its digest taken from PR 40's tree by this file's `_digest`; the
 others stayed what they were: `multi_head_attention`'s new arguments at
 their defaults, and an `expert_bias_update` without attributes, lower to
-the text they lowered to."""
+the text they lowered to.
+
+PR 41 gave the windowed flash kernels the band as their grid.  All five
+digests and Mosaic counts above stayed what they were, `trinity`'s too: its
+tiny program runs T = 512 in one block, where the band is the grid.  Three
+attention cores alone at Trinity-Mini's length (T = 8192, heads of 128,
+blocks of 1024) were added: `full_causal_8192` (no window) and
+`window_covers_8192` (window 8192), both taken from PR 41's PARENT commit
+6e8e801 by this file's `_digest` (without a window, or under one that covers
+the sequence, the kernels are the parent's to the instruction), and
+`window_2048_of_8192`, taken from PR 41's tree, which is NOT what the parent
+lowered (538099ce...: the full grid): its grid is the band's 3 blocks."""
 
 import base64
 import hashlib
@@ -92,7 +103,14 @@ BEFORE = {
     "olmoe": ("10b230dd86c559798a6caed82c488d59280933fd", 9),
     "lfm2": ("1991430ba2fa7bbe38f4bdb0225a591ed0238837", 9),
     "two_kernel_backward": ("3f2ec33fc054ccb7413c50a5286bea4b68cd0550", 3),
+    "full_causal_8192": ("1d2658a220612308dabdfadc170a714c52702bcb", 2),
+    "window_covers_8192": ("f33f5c775539a38a0bbcc60e17df72e7754d8a78", 2),
+    "window_2048_of_8192": ("7969f8a89b4c29447cefb9f8dc35fd4424fd33ec", 2),
 }
+# name -> (T, window) of an attention core alone, forward + backward
+CORES = {"two_kernel_backward": (16384, 0), "full_causal_8192": (8192, 0),
+         "window_covers_8192": (8192, 8192),
+         "window_2048_of_8192": (8192, 2048)}
 
 
 def _module_without_locations(payload):
@@ -144,13 +162,14 @@ def _step_text(build, hp):
             lowering_platforms=("tpu",)).as_text()
 
 
-def _two_kernel_text():
-    """A sequence that outgrows the one-kernel backward's dq scratch
-    (T = 16384 at 128): forward, dq and dk/dv kernels."""
-    x = jax.ShapeDtypeStruct((2, 16384, 128), jnp.bfloat16)
+def _core_text(t, window):
+    """An attention core alone, forward + backward, heads of 128 in blocks
+    of 1024.  T = 16384 outgrows the one-kernel backward's dq scratch:
+    forward, dq and dk/dv kernels."""
+    x = jax.ShapeDtypeStruct((2, t, 128), jnp.bfloat16)
     return jax.jit(jax.grad(
         lambda q, k, v: jnp.sum(pk.flash_attention(
-            q, k, v, None, True, 128 ** -0.5, 1024, 1024).astype(
+            q, k, v, None, True, 128 ** -0.5, 1024, 1024, window).astype(
                 jnp.float32)), argnums=(0, 1, 2))).trace(x, x, x).lower(
                     lowering_platforms=("tpu",)).as_text()
 
@@ -159,7 +178,7 @@ def _two_kernel_text():
 def test_the_lowered_step_is_what_it_was_before_pr_37(monkeypatch, name):
     monkeypatch.setattr(pk, "_interpret", lambda: False)
     jax.clear_caches()  # an interpreted trace of these shapes would hide
-    text = (_two_kernel_text() if name == "two_kernel_backward"
+    text = (_core_text(*CORES[name]) if name in CORES
             else _step_text(*PROGRAMS[name]))
     assert _digest(text) == BEFORE[name]
     jax.clear_caches()  # and these would hide from a later interpreted one

@@ -399,6 +399,75 @@ def test_flash_window_any_length_matches_the_mask_from_positions(
     jax.clear_caches()  # the patched limit must not outlive the test
 
 
+def test_band_grid_at_the_cells_shape():
+    """Trinity-Mini's window layers: 3 of 8 k blocks a q block, 24 steps
+    a head where the full grid walked 64, 21 of them computed."""
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    assert pk._band_grid(8192, 8192, 1024, 1024, True, 2048) == 3
+    assert pk._band_grid(8192, 8192, 1024, 1024, True, 2048, True) == 3
+    assert pk.band_grid_steps(8192, 1024, 1024, 2048) == (24, 21)
+    assert pk.band_grid_steps(8192, 512, 512, 2048) == (80, 70)
+    assert pk.band_grid_steps(8192, 1024, 1024, 8192) == (64, 36)
+
+
+def _pallas_eqns(jaxpr):
+    """Every pallas_call equation under `jaxpr`, nested calls included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _pallas_eqns(sub)
+    return found
+
+
+@pytest.mark.parametrize("backward", ["one_kernel", "two_kernels"])
+def test_without_a_window_the_traced_kernels_are_the_full_grid(monkeypatch,
+                                                               backward):
+    """window == 0 (and a window that covers the sequence) trace what they
+    traced before the band grid: the (BH, nq, nk) / (BH, nk, nq) grids,
+    index maps that hand a grid index on without arithmetic, and kernel
+    bodies with no division or minimum; a window inside the sequence
+    traces the band's width and maps that compute.  (The Mosaic modules
+    themselves are pinned in tests/test_lowered_step_pins.py.)"""
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    if backward == "two_kernels":
+        monkeypatch.setattr(pk, "_FUSED_BWD_DQ_BYTES", 0)
+    jax.clear_caches()
+    bh, t, d, blk = 2, 64, 8, 8
+    x = jnp.zeros((bh, t, d), jnp.float32)
+
+    def calls(window):
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda q, k, v: jnp.sum(flash_attention(
+                q, k, v, None, True, 1.0, blk, blk, window)),
+            argnums=(0, 1, 2)))(x, x, x)
+        return _pallas_eqns(jaxpr.jaxpr)
+
+    def arithmetic(eqn):
+        """(index-map equations, has the body a div or a min)"""
+        maps = sum(len(bm.index_map_jaxpr.jaxpr.eqns)
+                   for bm in eqn.params["grid_mapping"].block_mappings)
+        body = {e.primitive.name for e in eqn.params["jaxpr"].eqns}
+        return maps, bool(body & {"div", "min"})
+
+    n = t // blk
+    for window in (0, t, t + 5):
+        got = calls(window)
+        assert len(got) == (2 if backward == "one_kernel" else 3)
+        for eqn in got:
+            assert eqn.params["grid_mapping"].grid == (bh, n, n)
+            assert arithmetic(eqn) == (0, False)
+    got = calls(2 * blk)  # 3 blocks a walk: its own, two of the window
+    for eqn in got:
+        assert eqn.params["grid_mapping"].grid == (bh, n, 3)
+        maps, body = arithmetic(eqn)
+        assert maps > 0 and body
+    jax.clear_caches()
+
+
 def test_fused_attention_layer_window():
     """The window attr flows through the op and layer (dense path here;
     the pallas path shares the masks by the kernel test above)."""
