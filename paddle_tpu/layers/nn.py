@@ -1627,7 +1627,11 @@ def rotary_embed(x, pos=None, base=10000.0, interleaved=False, name=None):
     published in the (2i, 2i+1) pairing (DeepSeek-V3's `rope_interleave`):
     the last axis is first de-interleaved to (i, i + Dh/2) and then
     rotated as above, and the result stays in that order: the same
-    permutation on q and k leaves every score as it was."""
+    permutation on q and k leaves every score as it was.  The pairing is
+    undone by a product with a constant Dh x Dh permutation matrix, bit
+    for bit what strided slices give: a float32 input is multiplied at
+    precision HIGHEST (the default would round it to bfloat16 on a TPU's
+    MXU), and a NaN or infinity in x reaches its whole row of Dh."""
     helper = LayerHelper("rotary_embed", **locals())
     out = helper.create_variable_for_type_inference(x.dtype)
     inputs = {"X": [x]}

@@ -1513,6 +1513,34 @@ def _decode_pos_mask(ctx, ins, attrs):
     return {"Out": [jnp.broadcast_to(row[None, :], (b, t)).astype(jnp.float32)]}
 
 
+def _pairing_permutation(dh):
+    """P [Dh, Dh] of zeros and ones with x @ P = concatenate([x[..., 0::2],
+    x[..., 1::2]], -1): input lane `src[i]` lands on output lane i."""
+    src = np.concatenate([np.arange(0, dh, 2), np.arange(1, dh, 2)])
+    perm = np.zeros((dh, dh), np.float32)
+    perm[src, np.arange(dh)] = 1
+    return perm
+
+
+def _deinterleave(x):
+    """Published pairs (2i, 2i+1) of the last axis -> (i, i + Dh/2): what
+    concatenate([x[..., 0::2], x[..., 1::2]], -1) gives, as x @ P with P a
+    constant permutation, so that nothing strided moves along the lane
+    axis: JAX lowers that index to a gather and its transpose to a
+    scatter-add (`lax.slice` strides: to an interior pad), and a TPU step
+    paid for them in copies around each (PERF.md section 6, PR 43).  Exact
+    in every dtype: each output is one input times 1 plus zeros.  A float32
+    product asks for HIGHEST, at which the MXU multiplies all three
+    bfloat16 pieces of a value (by 1: they sum back to it); at the default
+    it would round x to bfloat16.  The VJP is the product with P's
+    transpose at the same precision.  One departure from the index: a NaN
+    or an infinity in x reaches every lane of its own row of Dh (0 x inf),
+    not only its own."""
+    perm = _pairing_permutation(x.shape[-1])
+    return jnp.matmul(x, jnp.asarray(perm, x.dtype),
+                      precision=jax.lax.Precision.HIGHEST)
+
+
 @register("rotary_embed", no_grad_inputs=("Pos",))
 def _rotary_embed(ctx, ins, attrs):
     """Rotary position embedding (RoPE, rotate-half convention) applied
@@ -1521,7 +1549,9 @@ def _rotary_embed(ctx, ins, attrs):
     current position so cache-resident keys are stored pre-rotated.
     Attribute `interleaved` (False): the input's pairs are (2i, 2i+1), as
     DeepSeek-V3's weights are published; it is de-interleaved first and
-    the result is left in the rotate-half order.
+    the result is left in the rotate-half order.  The de-interleave is a
+    product with a constant Dh x Dh permutation matrix (`_deinterleave`),
+    bit for bit what two lane-strided slices give, on the MXU.
     Beyond-reference (the reference era used learned/sinusoid absolute
     positions); standard in modern decoder LMs."""
     x = ins["X"][0]
@@ -1533,8 +1563,7 @@ def _rotary_embed(ctx, ins, attrs):
             "got %d" % x.shape[-1])
     half = x.shape[-1] // 2
     if attrs.get("interleaved", False):
-        # published pairs (2i, 2i+1) -> (i, i + half); the result stays so
-        x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], -1)
+        x = _deinterleave(x)  # the result stays in that order
     freq = base ** (-jnp.arange(half, dtype=jnp.float32) / half)
     if ins.get("Pos") and ins["Pos"][0].ndim == 2:
         # PER-ROW positions [B, T] (ragged serving step: each pool slot

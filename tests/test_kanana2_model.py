@@ -516,6 +516,112 @@ def _rotary(x, interleaved):
         return exe.run(main, feed={"x": x}, fetch_list=[out])[0]
 
 
+@pytest.mark.parametrize("pos", ["none", "T", "BT"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_permutation_product_is_the_strided_slices_bit_for_bit(dtype,
+                                                                   pos):
+    """`interleaved` undoes the published pairing with a constant
+    permutation matmul: the op's result AND its gradient with respect to X
+    are the strided slices' to the bit, in float32 (every mantissa bit set:
+    not a cast-back of bfloat16) and in bfloat16, whatever `Pos` is."""
+    from paddle_tpu.core.registry import LowerCtx
+    from paddle_tpu.ops import nn_ops
+
+    rng = np.random.RandomState(11)
+    x = jnp.asarray(rng.randn(2, 3, 16, 8), dtype)
+    g = jnp.asarray(rng.randn(2, 3, 16, 8), dtype)
+    p = {"none": None, "T": jnp.asarray(rng.randint(0, 99, (16,)), "int32"),
+         "BT": jnp.asarray(rng.randint(0, 99, (2, 16)), "int32")}[pos]
+
+    def op(x, **attrs):
+        ins = {"X": [x]} if p is None else {"X": [x], "Pos": [p]}
+        return nn_ops._rotary_embed(
+            LowerCtx(), ins, dict(attrs, base=1e6))["Out"][0]
+
+    def by_slices(x):  # the formulation before PR 43, kept as the reference
+        return op(jnp.concatenate([x[..., 0::2], x[..., 1::2]], -1))
+
+    def both(fn):
+        out, back = jax.vjp(fn, x)
+        return out, back(g)[0]
+
+    got = jax.jit(lambda: both(lambda x: op(x, interleaved=True)))()
+    want = jax.jit(lambda: both(by_slices))()
+    assert got[0].dtype == want[0].dtype == jnp.dtype(dtype)
+    assert np.abs(np.asarray(want[1], "float32")).mean() > 0.1
+    for a, b in zip(got, want):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_the_lowered_rope_scope_has_no_strided_slice_and_no_interior_pad(
+        monkeypatch):
+    """The counter that says the mechanism engages: in a tiny kanana-2's
+    train step lowered for the TPU, nothing under `mla.rope` is a `gather`
+    or a `scatter` (what JAX 0.9 makes of `x[..., 0::2]` and of its
+    transpose: the lowering before PR 43), no `stablehlo.slice` there has a
+    stride other than 1 and no `stablehlo.pad` interior padding (what
+    `lax.slice` strides would be), forward or backward; the de-interleaves
+    are `dot_general`s, one a `rotary_embed` forward and one backward, and
+    on a float32 operand (what the AMP pass leaves the scope) they carry
+    HIGHEST."""
+    import re
+
+    import test_lowered_step_pins as pins
+
+    monkeypatch.setattr(pins.pk, "_interpret", lambda: False)
+    jax.clear_caches()  # as the pins lower: the kernels for Mosaic
+    text = pins._lowered_step(*pins.PROGRAMS["kanana2"]).as_text(
+        debug_info=True)
+    jax.clear_caches()
+    named = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    rope = {}
+    for line in text.splitlines():
+        at = re.search(r"loc\((#loc\d+)\)\s*$", line)
+        op = re.search(r"= stablehlo\.(\w+)", line)
+        if at and op and "/mla.rope/" in named.get(at.group(1), ""):
+            rope.setdefault(op.group(1), []).append(line)
+    assert not {"gather", "scatter"} & set(rope)
+    assert len(rope["slice"]) >= 16  # the splits and the 32-lane halves
+    for line in rope["slice"]:
+        spans = re.search(r"\[([^\]]*)\] :", line).group(1).split(", ")
+        assert all(len(s.split(":")) == 2 for s in spans), line
+    for line in rope["pad"]:
+        assert re.search(r"interior = \[0(, 0)*\]", line), line
+    dots = rope["dot_general"]
+    assert len(dots) == 2 * 2 * 2  # q and k, forward and backward, 2 layers
+    for line in dots:
+        assert "xf32>, tensor<64x64xf32>" in line
+        assert "precision = [HIGHEST, HIGHEST]" in line
+
+
+def test_rotary_sweep_runs_tiny_and_its_forms_agree():
+    """tools/rotary_sweep.py (a chip tool) runs at its rehearsal shapes, and
+    every form of the de-interleave it times gives the strided slices'
+    result and gradient: bit for bit the op (the permutation product), real
+    strided slices and the reshape-transpose, in both dtypes, and the two
+    forms that fold the rotation into the product in bfloat16; in float32
+    those two round a contracted multiply-add in another order on this
+    host, so they are held to one part in a million."""
+    spec = importlib.util.spec_from_file_location(
+        "rotary_sweep", os.path.join(ROOT, "tools", "rotary_sweep.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    lines = tool.sweep(tool.TINY, (jnp.float32, jnp.bfloat16), iters=1)
+    assert len(lines) == len(tool.TINY) * 2 * 7
+    exact = {"op", "strided_slices", "reshape_transpose"}
+    seen = set()
+    for line in lines:
+        assert line["ms"] > 0 and line["bytes"] > 0
+        seen.add(line["form"])
+        if line["form"] in exact or (line["dtype"] == "bfloat16"
+                                     and "equal" in line):
+            assert line["equal"] and line["equal_grad"], line
+        elif "equal" in line:
+            assert line["max_diff"] < 1e-6, line
+    assert seen == exact | {"slices", "fused_matmul", "two_matmuls",
+                            "rotate_half"}
+
+
 def test_rotary_embeds_two_pairings_agree_after_the_permutation():
     """The published pairing is a de-interleave followed by rotate-half:
     interleaved(x) == rotate_half(x de-interleaved), the result left in

@@ -37,7 +37,18 @@ blocks of 1024) were added: `full_causal_8192` (no window) and
 6e8e801 by this file's `_digest` (without a window, or under one that covers
 the sequence, the kernels are the parent's to the instruction), and
 `window_2048_of_8192`, taken from PR 41's tree, which is NOT what the parent
-lowered (538099ce...: the full grid): its grid is the band's 3 blocks."""
+lowered (538099ce...: the full grid): its grid is the band's 3 blocks.
+
+PR 43 undid `rotary_embed`'s published (2i, 2i+1) pairing with a constant
+permutation matmul in place of a strided index (ops/nn_ops._deinterleave),
+under the op's `interleaved` attribute alone.  The other eight digests and
+Mosaic counts did not move: no pinned program sets the attribute, and a
+rotate-half `rotary_embed` lowers to the text it lowered to.  `kanana2` (a
+tiny kanana-2: latent attention at the flash kernel's (192, 128), the
+attribute set, a share of the experts held) was added, its digest taken
+from PR 43's tree by this file's `_digest`; it is NOT what the parent
+lowered (5974c9c2...: two gathers forward and two scatters backward a
+`rotary_embed`)."""
 
 import base64
 import hashlib
@@ -49,7 +60,7 @@ import pytest
 
 import paddle_tpu as fluid
 from paddle_tpu.core.trace import build_traced_function
-from paddle_tpu.models import gpt2, lfm2, olmoe, trinity
+from paddle_tpu.models import gpt2, kanana2, lfm2, olmoe, trinity
 from paddle_tpu.ops import pallas_kernels as pk
 
 SEQ = 512
@@ -85,6 +96,14 @@ class T(trinity.TrinityConfig):
     num_local_experts, expert_offset = 2, 2
 
 
+class K(kanana2.Kanana2Config):
+    vocab_size, hidden_size, intermediate_size = 512, 128, 128
+    moe_intermediate_size, num_hidden_layers, kv_lora_rank = 128, 2, 64
+    num_attention_heads = num_key_value_heads = 2
+    n_routed_experts, num_experts_per_tok = 8, 2
+    num_local_experts, expert_offset = 2, 2
+
+
 def _trinity_program(hp, **kw):
     return trinity.trinity_lm_program(hp, bias_rate=0.03, bias_max_step=0.03,
                                       **kw)
@@ -93,11 +112,13 @@ def _trinity_program(hp, **kw):
 PROGRAMS = {"gpt2": (gpt2.gpt2_lm_program, G),
             "olmoe": (olmoe.olmoe_lm_program, O),
             "lfm2": (lfm2.lfm2_lm_program, L),
-            "trinity": (_trinity_program, T)}
+            "trinity": (_trinity_program, T),
+            "kanana2": (kanana2.kanana2_lm_program, K)}
 
 # name -> (sha1 of the normalised text, Mosaic calls in it) at ec9cdf7
-# (`lfm2`: at PR 39; `trinity`: at PR 40)
+# (`lfm2`: at PR 39; `trinity`: at PR 40; `kanana2`: at PR 43)
 BEFORE = {
+    "kanana2": ("442939de9116ac8230c03beb34b17b378ccc0476", 9),
     "trinity": ("12416b47e9d02155b186ce8f38c8ee118bdb5539", 12),
     "gpt2": ("df7ec28481d2c6f9e45f238963591867ce97786d", 3),
     "olmoe": ("10b230dd86c559798a6caed82c488d59280933fd", 9),
@@ -134,7 +155,7 @@ def _digest(text):
     return hashlib.sha1(normalised.encode()).hexdigest(), len(bodies)
 
 
-def _step_text(build, hp):
+def _lowered_step(build, hp):
     main, startup, _, fetches = build(hp, seq_len=SEQ, lr=1e-3,
                                       use_bf16=True)
     scope = fluid.Scope()
@@ -159,7 +180,7 @@ def _step_text(build, hp):
     return jax.jit(traced.fn).trace(
         feeds, {n: shaped(n) for n in traced.ro_names},
         {n: shaped(n) for n in traced.rw_names}, key).lower(
-            lowering_platforms=("tpu",)).as_text()
+            lowering_platforms=("tpu",))
 
 
 def _core_text(t, window):
@@ -179,6 +200,6 @@ def test_the_lowered_step_is_what_it_was_before_pr_37(monkeypatch, name):
     monkeypatch.setattr(pk, "_interpret", lambda: False)
     jax.clear_caches()  # an interpreted trace of these shapes would hide
     text = (_core_text(*CORES[name]) if name in CORES
-            else _step_text(*PROGRAMS[name]))
+            else _lowered_step(*PROGRAMS[name]).as_text())
     assert _digest(text) == BEFORE[name]
     jax.clear_caches()  # and these would hide from a later interpreted one
