@@ -3,19 +3,17 @@
 
 One process, no children (a chip belongs to one process).  In order:
 
-  train      Transformer-base at full width (bf16 AMP, fused attention,
-             Pallas kernels on) through Executor(TPUPlace(0)).run: startup,
-             then 2 warm-up + 5 steps on one fixed batch.  Checks finite and
-             falling loss, flat compile count, loss on a tpu device, the two
-             flag-gated kernel families of this model dispatched AND Mosaic custom calls
-             present in the compiled step's HLO, the tiled vocabulary head
+  train      Transformer-base at full width (bf16 AMP, fused attention)
+             through Executor(TPUPlace(0)).run: startup, then 2 warm-up + 5
+             steps on one fixed batch.  Checks finite and falling loss, flat
+             compile count, loss on a tpu device, the tiled vocabulary head
              engaged, every fuse pass fired.
-  numerics   the same program with use_pallas off, same seed, 2 steps: losses
-             must agree with the kernel run within the bf16 fuse-pass contract.
-  kernels    one compiled call (forward and, where the kernel has its own,
-             backward) of every kernel in pallas_kernels.__all__ against its
-             dense twin, at a shape one of the repo's models uses;
-             flash_attention through fused_attention's default lowering.
+  numerics   the same program in float32 (no AMP), same seed, 2 steps: the
+             bfloat16 step's losses must agree with it within LOSS_TOL.
+  kernels    one compiled call (forward and backward) of every kernel in
+             pallas_kernels.__all__ against its dense twin, at a shape one of
+             the repo's models uses; flash_attention through
+             fused_attention's default lowering.
   spmd       (--devices 4 only) the same widths over a dp=2 x mp=2 mesh
              through Executor._run_spmd.
 
@@ -26,8 +24,8 @@ script exits non-zero before any phase.  --rehearse runs tiny widths on the
 CPU with the kernels interpreted, to debug the command before chip time is
 spent; it says REHEARSAL and never prints the pass line.
 
-This is a does-it-start check, not a benchmark: it prints how long set-up
-took because compilation dominates it, and claims nothing about speed.
+This is a does-it-start check, pass or fail, and not a benchmark: it times
+no step (it says how long each phase took because compilation dominates it).
 """
 
 import argparse
@@ -39,7 +37,10 @@ import time
 import traceback
 
 SEED = 20260926
-LOSS_TOL = 5e-2  # bf16 fuse-pass parity contract (kernels on vs off)
+# the bf16 step against its float32 reference: bf16 rounding (3e-4 at the
+# rehearsal's widths with dropout off) plus the two programs' dropout masks,
+# which differ (3e-2 over the rehearsal's 64 tokens; less over more tokens)
+LOSS_TOL = 5e-2
 SPMD_LOSS_TOL = 1e-2
 SPMD_STATE_RATIO = 0.55  # per-device state bytes vs unsharded at mp=2
 
@@ -86,7 +87,7 @@ def make_hp(rehearse, devices):
     return HP
 
 
-def train(hp, batch_np, seq, place, use_pallas, steps, mesh=None):
+def train(hp, batch_np, seq, place, steps, mesh=None, use_bf16=True):
     """Build, start and step the program; returns a dict of what the
     checks read.  lr: noam with a short warm-up so seven steps on one
     batch move the loss well clear of dropout noise (the builder's
@@ -95,33 +96,24 @@ def train(hp, batch_np, seq, place, use_pallas, steps, mesh=None):
     import numpy as np
 
     import paddle_tpu as fluid
-    from paddle_tpu import flags
     from paddle_tpu.models import transformer as tfm
     from paddle_tpu.ops import kernel_tuning
 
-    # a searched block size would change the HLO (and the compile-cache
-    # key) from run to run: consult-only
-    flags.set_flags({"use_pallas": use_pallas, "kernel_autotune": False})
     kernel_tuning.reset_attribution()
     main, startup, _feeds, fetches = tfm.wmt_transformer_program(
         hp, src_len=seq, trg_len=seq, learning_rate=1.0, warmup_steps=100,
-        use_bf16=True, mesh=mesh)
+        use_bf16=use_bf16, mesh=mesh)
     startup.random_seed = main.random_seed = SEED
     scope = fluid.Scope()
-    out = {"main": main, "scope": scope, "losses": [], "step_s": []}
+    out = {"main": main, "scope": scope, "losses": []}
     with fluid.scope_guard(scope):
         exe = out["exe"] = fluid.Executor(place)
-        t0 = time.perf_counter()
         exe.run(startup)
         for i in range(steps):
-            t1 = time.perf_counter()
             fetched = exe.run(main, feed=batch_np, fetch_list=fetches,
                               return_numpy=False)
             jax.block_until_ready(fetched)
-            now = time.perf_counter()
-            out["step_s"].append(now - t1)
             if i == 0:
-                out["setup_s"] = now - t0
                 out["compiles_after_first"] = exe.compile_count
             out["losses"].append(float(np.asarray(fetched[0]).reshape(-1)[0]))
             out["loss_devices"] = sorted(
@@ -135,12 +127,9 @@ def phase_train(ctx):
     import numpy as np
 
     r = ctx["train"] = train(ctx["hp"], ctx["batch"], ctx["seq"],
-                             ctx["place"], True, steps=7)
+                             ctx["place"], steps=7)
     main, losses = r["main"], r["losses"]
     log("  losses (2 warm-up + 5): %s" % " ".join("%.4f" % v for v in losses))
-    log("  set-up (startup + compile + first step): %.1f s; later steps "
-        "%.0f ms each (host wall, not a benchmark)"
-        % (r["setup_s"], 1e3 * float(np.mean(r["step_s"][2:]))))
     log("  compile_count after first step %d, at end %d"
         % (r["compiles_after_first"], r["compiles_end"]))
     hits = r["attribution"]["pallas_hits"]
@@ -157,40 +146,26 @@ def phase_train(ctx):
                                           r["compiles_end"]))
     require(r["loss_devices"] == [ctx["platform"]], "loss-on-device",
             str(r["loss_devices"]))
-    # attention is not among them: at T = 256 its lowering stays dense
-    # by shape, flag or no flag (the kernels phase covers the engaged one)
-    for fam in ("layernorm", "matmul_epilogue"):
-        require(hits.get(fam, 0) > 0, "kernel-family-dispatched", fam)
+    # at T = 256 attention lowers densely by shape, so this step holds
+    # no Mosaic call (the kernels phase covers the engaged lowering)
     require(r["attribution"]["dense_vjp_hits"].get("xent", 0) > 0,
             "tiled-head-engaged", str(r["attribution"]["dense_vjp_hits"]))
     for k, n in fused.items():
         require(n > 0, "fuse-pass-fired", k)
-    if ctx["rehearse"]:
-        log("  mosaic custom calls: not checked (kernels interpreted)")
-    else:
-        n = sum(t.count("tpu_custom_call")
-                for t in r["exe"].compiled_hlo(main))
-        log("  mosaic custom calls in the compiled step: %d" % n)
-        require(n > 0, "mosaic-custom-calls-in-hlo")
 
 
 def phase_numerics(ctx):
     require("train" in ctx and len(ctx["train"]["losses"]) >= 2,
-            "numerics-needs-train", "the kernel run produced no losses")
-    dense = train(ctx["hp"], ctx["batch"], ctx["seq"], ctx["place"], False,
-                  steps=2)
-    got, ref = ctx["train"]["losses"][:2], dense["losses"]
+            "numerics-needs-train", "the train phase produced no losses")
+    exact = train(ctx["hp"], ctx["batch"], ctx["seq"], ctx["place"], steps=2,
+                  use_bf16=False)
+    got, ref = ctx["train"]["losses"][:2], exact["losses"]
     diffs = [abs(a - b) for a, b in zip(got, ref)]
-    log("  kernels on  %s" % " ".join("%.5f" % v for v in got))
-    log("  kernels off %s" % " ".join("%.5f" % v for v in ref))
-    log("  |diff| %s (tolerance %g); dense set-up %.1f s, second step "
-        "%.0f ms (host wall, not a benchmark)"
-        % (" ".join("%.2e" % d for d in diffs), LOSS_TOL, dense["setup_s"],
-           1e3 * dense["step_s"][1]))
-    require(sum(dense["attribution"]["pallas_hits"].values()) == 0,
-            "dense-run-dispatched-no-kernel",
-            str(dense["attribution"]["pallas_hits"]))
-    require(max(diffs) <= LOSS_TOL, "kernel-vs-dense-loss", str(diffs))
+    log("  bfloat16 %s" % " ".join("%.5f" % v for v in got))
+    log("  float32  %s" % " ".join("%.5f" % v for v in ref))
+    log("  |diff| %s (tolerance %g)"
+        % (" ".join("%.2e" % d for d in diffs), LOSS_TOL))
+    require(max(diffs) <= LOSS_TOL, "bf16-vs-float32-loss", str(diffs))
 
 
 # --------------------------------------------------------------------------
@@ -214,10 +189,6 @@ def kernel_cases(rehearse):
         return (jax.random.normal(jax.random.fold_in(root, i), shape, f32)
                 * scale).astype(dtype)
 
-    def ints(i, shape, hi):
-        return jax.random.randint(jax.random.fold_in(root, i), shape, 0, hi,
-                                  jnp.int32)
-
     def with_grads(f, n_diff):
         """f(*args) -> out (array or tuple); returns fn giving outputs
         plus d(sum of outputs)/d(first n_diff args)."""
@@ -235,9 +206,7 @@ def kernel_cases(rehearse):
     S = (lambda real, tiny: tiny) if rehearse else (lambda real, tiny: real)
     cases = {}
 
-    # Transformer-base train step shapes: bs128 x seq256, 8 heads of 64
-    BH, T, D = S(1024, 4), S(256, 16), S(64, 32)
-    R, H, F = S(32768, 64), S(512, 64), S(2048, 128)
+    D = S(64, 32)  # Transformer-base's head width
     scale = 1.0 / D ** 0.5
 
     # GPT-2 345M's training attention, through the fused_attention op's
@@ -265,38 +234,6 @@ def kernel_cases(rehearse):
         "GPT-2 345M causal self-attention, T %d, as fused_attention "
         "lowers it by default" % AT)
 
-    cases["fused_add_layer_norm"] = (
-        lambda x, y, g, b: pk.fused_add_layer_norm(x, y, g, b, 1e-5),
-        lambda x, y, g, b: pk._add_ln_dense(x, y, g, b, 1e-5),
-        lambda: (arr(0, (R, H), bf16), arr(1, (R, H), bf16),
-                 1.0 + arr(2, (H,), f32, 0.1), arr(3, (H,), f32, 0.1)),
-        "transformer residual + layer norm")
-
-    cases["matmul_bias_act"] = (
-        lambda x, w, b: (pk.matmul_bias_act(x, w, b, "relu"),),
-        lambda x, w, b: (pk._mm_dense(x, w, b, "relu"),),
-        lambda: (arr(0, (R, H), bf16), arr(1, (H, F), bf16, H ** -0.5),
-                 arr(2, (F,), f32, 0.1)),
-        "transformer FFN in-projection + relu")
-
-    # GPT-2 345M: 16 heads of 64, n_ctx 1024, d_model 1024
-    SB, SW, ST = S(128, 4), S(8, 4), S(1024, 32)
-
-    def qvec_dense(q, k, v, qs):
-        s = jnp.einsum("bqd,bkd->bqk", q, k).astype(f32) * scale
-        keep = (qs[:, None] + jnp.arange(SW)[None, :])[:, :, None] \
-            >= jnp.arange(ST)[None, None, :]
-        p = jax.nn.softmax(jnp.where(keep, s, pk.NEG_INF), axis=-1)
-        return jnp.einsum("bqk,bkd->bqd", p, v)
-
-    cases["flash_attention_qvec"] = (
-        with_grads(lambda q, k, v, qs: pk.flash_attention_qvec(
-            q, k, v, qs, scale, SW, S(128, ST)), 3),
-        with_grads(qvec_dense, 3),
-        lambda: (arr(0, (SB, SW, D), f32), arr(1, (SB, ST, D), f32),
-                 arr(2, (SB, ST, D), f32), ints(3, (SB,), ST - SW)),
-        "GPT-2 345M ragged serving step, 8 slots x 16 heads, width %d" % SW)
-
     PB, PT = S(32, 2), S(256, 16)
 
     def piece_dense(q, k, v, qoff):
@@ -314,34 +251,6 @@ def kernel_cases(rehearse):
         lambda: (arr(0, (PB, PT, D), bf16), arr(1, (PB, PT, D), bf16),
                  arr(2, (PB, PT, D), bf16), jnp.full((1,), PT // 2, jnp.int32)),
         "ring-attention chunk of %d with q offset %d" % (PT, PT // 2))
-
-    GM, GK, GN = S(4096, 64), S(1024, 64), S(2816, 128)
-    cases["matmul_swiglu"] = (
-        lambda x, wg, wu: (pk.matmul_swiglu(x, wg, wu),),
-        lambda x, wg, wu: (pk._swiglu_dense(x, wg, wu),),
-        lambda: (arr(0, (GM, GK), bf16), arr(1, (GK, GN), bf16, GK ** -0.5),
-                 arr(2, (GK, GN), bf16, GK ** -0.5)),
-        "GPT-2 345M SwiGLU FFN (hidden %d)" % GN)
-
-    cases["fused_layer_norm"] = (
-        lambda x, g, b: (pk.fused_layer_norm(x, g, b, 1e-5),),
-        lambda x, g, b: (pk._ln_dense(x, g, b, 1e-5),),
-        lambda: (arr(0, (S(8192, 64), GK), f32),
-                 1.0 + arr(1, (GK,), f32, 0.1), arr(2, (GK,), f32, 0.1)),
-        "GPT-2 345M pre-attention layer norm")
-
-    # ResNet-50 head: bs128 x 1000 classes
-    CR, CC = S(128, 16), S(1000, 40)
-
-    def sxent_dense(lg, lb):
-        logp = jax.nn.log_softmax(lg, axis=-1)
-        return -jnp.take_along_axis(logp, lb[:, None], axis=-1)
-
-    cases["fused_softmax_xent"] = (
-        with_grads(lambda lg, lb: pk.fused_softmax_xent(lg, lb), 1),
-        with_grads(sxent_dense, 1),
-        lambda: (arr(0, (CR, CC), f32), ints(1, (CR,), CC)),
-        "ResNet-50 classification loss")
     return cases
 
 
@@ -349,12 +258,10 @@ def phase_kernels(ctx):
     import jax
     import numpy as np
 
-    from paddle_tpu import flags
     from paddle_tpu.ops import pallas_kernels as pk
 
-    flags.set_flags({"use_pallas": True, "kernel_autotune": False})
     cases = kernel_cases(ctx["rehearse"])
-    names = [n for n in pk.__all__ if n != "use_pallas"]
+    names = list(pk.__all__)
     require(sorted(cases) == sorted(names), "kernel-list-covers-__all__",
             str(sorted(set(names) ^ set(cases))))
     bad = []
@@ -394,9 +301,6 @@ def phase_kernels(ctx):
             log("  %-22s FAILED (%s): %s" % (name, where, str(e)[:1500]))
             if not isinstance(e, Check):
                 traceback.print_exc()
-    # none is withdrawn today; a kernel Mosaic refuses beyond local
-    # repair would lower densely at its dispatch site and be listed here
-    # with the compiler's reason (ROADMAP S3)
     require(not bad, "every-kernel-compiled", ", ".join(bad))
 
 
@@ -419,14 +323,11 @@ def phase_spmd(ctx):
 
     devices = jax.devices()[:4]
     mesh = make_mesh({"dp": 2, "mp": 2}, devices)
-    r = train(ctx["hp"], ctx["batch"], ctx["seq"], ctx["place"], True,
-              steps=3, mesh=mesh)
+    r = train(ctx["hp"], ctx["batch"], ctx["seq"], ctx["place"], steps=3,
+              mesh=mesh)
     main, scope, exe = r["main"], r["scope"], r["exe"]
     log("  losses %s; one-chip first %.5f"
         % (" ".join("%.5f" % v for v in r["losses"]), one_chip_first))
-    log("  first step (startup + host round trip of state + compile): "
-        "%.1f s; later steps %.0f ms (host wall, not a benchmark)"
-        % (r["setup_s"], 1e3 * float(np.mean(r["step_s"][1:]))))
     require(abs(r["losses"][0] - one_chip_first) <= SPMD_LOSS_TOL,
             "spmd-first-loss", "%.5f vs %.5f" % (r["losses"][0],
                                                  one_chip_first))

@@ -14,25 +14,23 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import numpy as np
 
 import paddle_tpu as fluid
-from paddle_tpu import flags, layers, parallel
+from paddle_tpu import layers, parallel
 
 
 def single_chip_sliding_window():
     """Mistral-style local attention: each token sees the last 64
-    positions; the flash kernels skip fully-out-of-window blocks, so
-    compute scales with the window, not the sequence length."""
+    positions.  Placed on a TPU at a length the kernel takes
+    (nn_ops._flash_engages), the flash kernels walk the window's band
+    only, so compute scales with the window, not the sequence length;
+    here, on any device, the dense lowering builds the same band."""
     x = layers.data("x", shape=[4, 256, 32])  # [heads, T, d]
     att = layers.fused_attention(x, x, x, causal=True, window=64)
     out = layers.reduce_mean(att)
-    flags.set_flags({"use_pallas": True})  # flash kernel path
-    try:
-        exe = fluid.Executor()
-        exe.run(fluid.default_startup_program())
-        xv = np.random.RandomState(0).rand(2, 4, 256, 32).astype("float32")
-        (val,) = exe.run(feed={"x": xv}, fetch_list=[out])
-        print("sliding-window attention mean:", float(np.ravel(val)[0]))
-    finally:
-        flags.set_flags({"use_pallas": False})
+    exe = fluid.Executor()
+    exe.run(fluid.default_startup_program())
+    xv = np.random.RandomState(0).rand(2, 4, 256, 32).astype("float32")
+    (val,) = exe.run(feed={"x": xv}, fetch_list=[out])
+    print("sliding-window attention mean:", float(np.ravel(val)[0]))
 
 
 def sequence_parallel_ring_and_ulysses():

@@ -5,8 +5,9 @@
 Ragged token sequences (lengths 3..14) pack into fixed [N, 16] rows
 (`reader.pack_sequences`) — ~2x fewer rows than one-per-sequence
 padding.  Per-token segment ids keep attention within each original
-sequence (`fused_attention(segment_ids=...)`, flash kernels under
-FLAGS_use_pallas), per-segment positions index the position table, and
+sequence (`fused_attention(segment_ids=...)`; the flash kernels where
+platform and shape engage them), per-segment positions index the
+position table, and
 the loss masks padding (`segment_ids > 0`).  One compiled shape serves
 the whole ragged stream: the TPU-form of the reference's LoD
 no-padding efficiency.

@@ -18,9 +18,9 @@ from .. import framework
 
 _BF16_OPS = ("mul", "matmul", "conv2d", "depthwise_conv2d",
              "fused_attention",
-             # the matmul-epilogue fused ops (fuse_passes): their pallas
-             # kernels/dense paths consume the input dtype and accumulate
-             # f32, so bf16 inputs run the MXU at full rate
+             # the matmul-epilogue fused ops (fuse_passes): their
+             # lowerings consume the input dtype and accumulate f32, so
+             # bf16 inputs run the MXU at full rate
              "fc", "fused_swiglu",
              # logits-free fused loss: bf16 X/W tiles, f32 online
              # logsumexp internals — the projection is the single

@@ -475,10 +475,6 @@ class ExecutionCache:
 
     def get(self, program, block_idx, feed_sig, fetch_names, scope, donate=True,
             platform=None):
-        # a flag that changes lowering decisions is part of the compile
-        # key — toggling it must recompile, not hit a stale executable
-        from ..flags import get_flag
-
         key = (
             id(program),
             program._version,
@@ -486,7 +482,6 @@ class ExecutionCache:
             feed_sig,
             tuple(fetch_names),
             id(scope),
-            bool(get_flag("use_pallas")),
         )
         hit = self._cache.get(key)
         if hit is not None:

@@ -297,13 +297,9 @@ class Executor:
 
     @staticmethod
     def _fast_entry_holds(entry, feed):
-        """The fast path's preconditions: the lowering flag as recorded,
-        and every feed a plain array of the recorded (shape, dtype) — a
-        LoDTensor or list feed takes the slow path."""
-        from .flags import get_flag
-
-        if bool(get_flag("use_pallas")) != entry["use_pallas"]:
-            return False  # lowering flag flipped: recompile path
+        """The fast path's precondition: every feed a plain array of the
+        recorded (shape, dtype) — a LoDTensor or list feed takes the slow
+        path."""
         spec = entry["feed_spec"]
         return all(
             isinstance(value, (np.ndarray, jax.Array))
@@ -418,8 +414,6 @@ class Executor:
         if not readers and all(
             isinstance(v, (np.ndarray, jax.Array)) for v in feed.values()
         ):
-            from .flags import get_flag
-
             # spec records the RAW feed's (shape, dtype) — a float64
             # numpy feed canonicalizes to f32 on staging, and matching
             # against the staged dtype would miss the fast path on every
@@ -431,7 +425,6 @@ class Executor:
                     n: (tuple(v.shape), str(v.dtype))
                     for n, v in feed.items()
                 },
-                "use_pallas": bool(get_flag("use_pallas")),
             }
 
         return self._finish_run(compiled, feed_arrays, ro_state, rw_state,
@@ -558,8 +551,6 @@ class Executor:
         never mix slots), so pooled == solo bit-for-bit."""
         from jax.sharding import NamedSharding, PartitionSpec
 
-        from .flags import get_flag
-
         mesh, rules = spmd["mesh"], spmd["rules"]
         self._maybe_verify_program(program, feed, fetch_names, scope)
         repl = NamedSharding(mesh, PartitionSpec())
@@ -596,8 +587,7 @@ class Executor:
         if cache is None:
             cache = self._spmd_cache = {}
         key_id = (id(program), program._version, feed_sig,
-                  tuple(fetch_names), id(scope),
-                  bool(get_flag("use_pallas")))
+                  tuple(fetch_names), id(scope))
         entry, compiling = cache.get(key_id), None
         if entry is None:
             from .core.trace import build_traced_function
@@ -657,7 +647,6 @@ class Executor:
         (compile_count accounts it); steady-state steps never retrace."""
         from jax.sharding import NamedSharding, PartitionSpec
 
-        from .flags import get_flag
         from .parallel.mesh import mesh_axis_sizes
 
         mesh, plan = pp["mesh"], pp["plan"]
@@ -687,8 +676,7 @@ class Executor:
         if cache is None:
             cache = self._pipeline_cache = {}
         key_id = (id(program), program._version, feed_sig,
-                  tuple(fetch_names), id(scope),
-                  bool(get_flag("use_pallas")))
+                  tuple(fetch_names), id(scope))
         entry, compiling = cache.get(key_id), None
         if entry is None:
             from .transpiler.pipeline import (build_pipeline_runtime,
@@ -1003,11 +991,9 @@ class Executor:
             sorted((n, tuple(a.shape), str(a.dtype))
                    for n, a in feed_arrays.items())
         )
-        from .flags import get_flag
-
         cache_key = (
             id(program), program._version, feed_sig, tuple(fetch_names),
-            iters, id(scope), bool(get_flag("use_pallas")),
+            iters, id(scope),
         )
         hit = getattr(self, "_loop_cache", None)
         if hit is None:

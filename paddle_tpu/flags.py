@@ -235,36 +235,6 @@ DEFINE_flag("feed_prefetch", 2,
 DEFINE_flag("cudnn_deterministic", False,
             "compat; XLA compilation is deterministic already")
 DEFINE_flag("use_mkldnn", False, "compat no-op (XLA owns fusion)")
-DEFINE_flag("use_pallas", False,
-            "dispatch hot ops (decode-path attention, layer_norm, matmul "
-            "epilogues, softmax xent) to the Pallas kernel library "
-            "instead of plain XLA lowerings; fused_attention's training "
-            "path does not read it (platform and shape choose its kernel)")
-DEFINE_flag("flash_block_q", 0,
-            "decode-path (QStart) flash-attention q-block rows (0 = the "
-            "kernel default 128); "
-            "on-chip sweep knob: a multiple of 128 (or the full q "
-            "length) that divides the q sequence length — the Mosaic "
-            "minor-dim rule for the lse/delta specs (invalid values "
-            "raise)")
-DEFINE_flag("flash_block_k", 0,
-            "flash-attention k-block columns (0 = default 128); a "
-            "multiple of 128 (or the full k length) dividing the k "
-            "sequence length")
-DEFINE_flag("kernel_tune_cache", "",
-            "path of the persisted per-(kernel, shape-bucket, dtype, "
-            "device kind) block-size tuning cache consulted by every "
-            "pallas_call site (ops/kernel_tuning.py): searched decisions "
-            "are written back atomically so later processes dispatch "
-            "without searching.  Empty = in-memory only for this process")
-DEFINE_flag("kernel_autotune", True,
-            "allow the measured block-size search at the first "
-            "real-device dispatch of a (kernel, shape-bucket) the tuning "
-            "cache has not seen (synthetic operands, standalone jit — "
-            "compile-time work).  0 = consult-only: misses seed the "
-            "heuristic default and never search (the CI regime, with a "
-            "pinned FLAGS_kernel_tune_cache).  Interpret-mode (CPU) runs "
-            "never search regardless — their timings are meaningless")
 DEFINE_flag("hbm_budget_bytes", 0,
             "peak-activation HBM budget (bytes) for the rematerialization "
             "pass (transpiler.remat): model builders partition the forward "
@@ -282,8 +252,7 @@ DEFINE_flag("program_tune_cache", "",
             "transpiler.autotune.tune(): searched decisions (AMP on/off, "
             "remat segments, prng impl, steps-per-dispatch window) are "
             "written back atomically so later processes apply the tuned "
-            "configuration without re-searching — same bucketing "
-            "discipline as FLAGS_kernel_tune_cache.  Empty = in-memory "
+            "configuration without re-searching.  Empty = in-memory "
             "only for this process")
 DEFINE_flag("program_autotune", True,
             "allow transpiler.autotune.tune() to SEARCH (clone the "

@@ -145,8 +145,9 @@ def bert_pretrain_program(hp=BertConfig, seq_len=128, lr=1e-4, is_test=False,
         )
         total = layers.elementwise_add(mlm_loss, nsp_loss)
 
-        # logits-free MLM loss (the [B, T, V] f32 logits never reach HBM
-        # under FLAGS_use_pallas) + matmul-epilogue fusions, applied
+        # logits-free MLM loss (fused_linear_xent lowers to
+        # linear_xent_tiled: the [B, T, V] f32 logits exist a vocabulary
+        # tile at a time) + the fc / fused_residual_ln ops, applied
         # before minimize so grads differentiate through the fused ops
         from ..transpiler.pass_registry import apply_pass
 

@@ -218,10 +218,12 @@ def lm_train_program(trunk, seq_len, lr, is_test, use_bf16, mesh,
         if extra is not None:
             loss = layers.elementwise_add(loss, extra)
 
-        # logits-free fused cross-entropy (the [B, T, V] f32 logits
-        # tensor never reaches HBM under FLAGS_use_pallas) + the
-        # matmul-epilogue layer for the FFN/residual-LN chains — both
-        # BEFORE minimize so grads differentiate through the fused ops
+        # logits-free fused cross-entropy (fused_linear_xent lowers to
+        # linear_xent_tiled: the [B, T, V] f32 logits exist a vocabulary
+        # tile at a time) + the fc / fused_swiglu / fused_residual_ln
+        # ops for the FFN/residual-LN chains (one dense lowering each,
+        # their epilogues fused by XLA) — both BEFORE minimize so grads
+        # differentiate through the fused ops
         from ..transpiler.pass_registry import apply_pass
 
         apply_pass(main, "linear_xent_fuse_pass")
@@ -359,8 +361,8 @@ def gpt2_decode_step_program(hp=GPT2Config, batch=1, t_max=None, width=1,
                                        else [])
         # PR 11 closed-gap: the matmul-epilogue fuse bundle now rewrites
         # DECODE programs too (fc bias+act, SwiGLU diamonds, residual-LN
-        # pairs -> the fused ops / pallas kernels).  Row-independent
-        # kernels keep the serving exactness contract intact; the fetch
+        # pairs -> the fused ops).  Row-independent
+        # lowerings keep the serving exactness contract intact; the fetch
         # is protected so no fuse can fold it away.
         _apply_decode_epilogue_passes(main, logits)
     return main, cache_startup, feeds, [logits], cache_names
@@ -458,7 +460,7 @@ def gpt2_ragged_step_program(hp=GPT2Config, batch=4, t_max=None, width=8,
         logits = _tied_logits(x, hp, emb_attr.name)
         # the continuous-batching step gets the same matmul-epilogue
         # bundle as the classic decode step (PR 11's "training programs
-        # only" limit closed); per-row kernels preserve pooled == solo
+        # only" limit closed); per-row lowerings preserve pooled == solo
         _apply_decode_epilogue_passes(main, logits)
     feeds = ["step_ids", "pos_rows", "width_rows", "pos_mat"]
     return main, cache_startup, feeds, [logits], cache_names

@@ -645,10 +645,11 @@ def wmt_transformer_program(hp=ModelHyperParams, src_len=64, trg_len=64, learnin
 
         apply_pass(main, "smooth_label_xent_fuse_pass")
         # then fold the [H, V] projection INTO the loss (logits-free
-        # fused cross-entropy: the [B, T, V] f32 logits tensor never
-        # reaches HBM under FLAGS_use_pallas) and collapse the FFN
-        # mul+bias+act / residual-add+layer_norm chains onto the
-        # matmul-epilogue kernel layer
+        # fused cross-entropy: fused_linear_xent lowers to
+        # linear_xent_tiled, the [B, T, V] f32 logits a vocabulary tile
+        # at a time) and collapse the FFN mul+bias+act /
+        # residual-add+layer_norm chains into fc / fused_residual_ln
+        # (one dense lowering each, their epilogues fused by XLA)
         apply_pass(main, "linear_xent_fuse_pass")
         apply_pass(main, "matmul_epilogue_fuse_pass")
 

@@ -368,23 +368,6 @@ def _cross_entropy(ctx, ins, attrs):
 @register("softmax_with_cross_entropy", no_grad_inputs=("Label",))
 def _softmax_xent(ctx, ins, attrs):
     logits, label = ins["Logits"][0], ins["Label"][0]
-    from .pallas_kernels import fused_softmax_xent, use_pallas_unwrapped
-
-    if (
-        use_pallas_unwrapped()
-        and not attrs.get("soft_label", False)
-        and attrs.get("ignore_index", -100) < 0
-        and logits.ndim == 2
-    ):
-        # fused logsumexp+gather kernel; Softmax output stays lazy (XLA
-        # computes it only if a consumer asks)
-        loss = fused_softmax_xent(
-            logits, label.reshape(-1).astype(jnp.int32)
-        ).astype(logits.dtype)
-        return {
-            "Softmax": [jax.nn.softmax(logits, axis=-1)],
-            "Loss": [loss],
-        }
     logp = jax.nn.log_softmax(logits, axis=-1)
     if attrs.get("soft_label", False):
         loss = -jnp.sum(label * logp, axis=-1, keepdims=True)

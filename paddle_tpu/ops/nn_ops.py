@@ -257,31 +257,6 @@ def _layer_norm(ctx, ins, attrs):
     x = ins["X"][0]
     begin = attrs.get("begin_norm_axis", 1)
     eps = attrs.get("epsilon", 1e-5)
-    # pallas kernel override when the norm is over the last axis only
-    # (the transformer case) — FLAGS_use_pallas, library-override analog
-    from .pallas_kernels import fused_layer_norm, use_pallas_unwrapped
-
-    if (
-        use_pallas_unwrapped()
-        and begin == x.ndim - 1
-        and ins.get("Scale")
-        and ins.get("Bias")
-    ):
-        h = x.shape[-1]
-        x2d = x.reshape(-1, h)
-        y = fused_layer_norm(
-            x2d, ins["Scale"][0].reshape(h), ins["Bias"][0].reshape(h), eps
-        ).reshape(x.shape)
-        # stats in f32 regardless of input dtype (same invariant as the
-        # fallback path below; the kernel already normalizes in f32)
-        xf32 = x.astype(jnp.float32)
-        mean = jnp.mean(xf32, axis=-1)
-        var = jnp.var(xf32, axis=-1)
-        return {
-            "Y": [y],
-            "Mean": [jax.lax.stop_gradient(mean)],
-            "Variance": [jax.lax.stop_gradient(var)],
-        }
     # statistics + normalization in f32 regardless of input dtype (bf16
     # inputs under AMP keep f32-quality stats; the upcast fuses into the
     # same loop), Y returned in the input dtype so the op is
@@ -515,111 +490,78 @@ def _lstm_unit(ctx, ins, attrs):
     return {"C": [c], "H": [h]}
 
 
+def _mm_act(z, act):
+    """fc's epilogue activation (exact erf gelu / beta-1 swish: the same
+    defaults as the op lowerings in math_ops.ACTIVATIONS)."""
+    if act in ("", "identity"):
+        return z
+    if act == "relu":
+        return jnp.maximum(z, 0.0)
+    if act == "tanh":
+        return jnp.tanh(z)
+    if act == "sigmoid":
+        return jax.nn.sigmoid(z)
+    if act == "gelu":
+        return jax.nn.gelu(z, approximate=False)
+    if act == "swish":
+        return z * jax.nn.sigmoid(z)
+    raise ValueError("matmul epilogue: unsupported activation %r" % (act,))
+
+
 @register("fc")
 def _fc(ctx, ins, attrs):
     """Fused fully-connected (fc_op of fc_fuse_pass.cc): mul + bias-add +
-    activation in one op.  Under FLAGS_use_pallas the blocked
-    matmul-epilogue kernel applies bias + activation to the accumulator
-    tile in VMEM (matmul_bias_act); otherwise one MXU matmul with an
-    XLA-fused epilogue."""
-    from .pallas_kernels import (
-        _mm_act,
-        matmul_bias_act,
-        mm_epilogue_ok,
-        use_pallas,
-        use_pallas_unwrapped,
-    )
-
+    activation in one op: one MXU matmul with an XLA-fused epilogue."""
     x, w = ins["Input"][0], ins["W"][0]
     k = int(attrs.get("in_num_col_dims", 1))
     x2 = x.reshape((int(np.prod(x.shape[:k])), -1))
     act = attrs.get("activation_type", "") or ""
     bias = ins["Bias"][0].reshape(-1) if ins.get("Bias") else None
-    M, K = x2.shape
-    if (
-        use_pallas()
-        and w.ndim == 2
-        and (bias is None or bias.shape[0] == w.shape[-1])
-        and mm_epilogue_ok(M, K, w.shape[-1], act)
-    ):
-        from .spmd_epilogue import spmd_matmul_bias_act
-
-        out = spmd_matmul_bias_act(ctx, x2, w, bias, act)
-        if out is None and use_pallas_unwrapped():
-            out = matmul_bias_act(x2, w, bias, act)
-        if out is not None:
-            return {"Out": [out.reshape(
-                tuple(x.shape[:k]) + (w.shape[-1],))]}
     out = x2 @ w
     out = out.reshape(tuple(x.shape[:k]) + (w.shape[-1],))
     if bias is not None:
         out = out + bias.reshape((1,) * k + (-1,))
-    # ONE activation table for both paths (the kernel epilogue's):
-    # dense fallback and pallas epilogue can never drift apart
     return {"Out": [_mm_act(out, act)]}
+
+
+def _swiglu_dense(x2d, wg, wu):
+    g = jnp.dot(x2d, wg, preferred_element_type=jnp.float32)
+    u = jnp.dot(x2d, wu, preferred_element_type=jnp.float32)
+    return (g * jax.nn.sigmoid(g) * u).astype(x2d.dtype)
 
 
 @register("fused_swiglu")
 def _fused_swiglu(ctx, ins, attrs):
     """Fused SwiGLU gating (swiglu_fuse_pass target): silu(x @ GateW) *
-    (x @ UpW) in one op — the pallas kernel computes both projections of
-    a row tile and the gate product in VMEM (matmul_swiglu); the dense
-    path is the XLA reference."""
-    from .pallas_kernels import (
-        _swiglu_dense,
-        matmul_swiglu,
-        mm_epilogue_ok,
-        use_pallas,
-        use_pallas_unwrapped,
-    )
-
+    (x @ UpW) in one op, both projections accumulated in f32."""
     x, wg, wu = ins["X"][0], ins["GateW"][0], ins["UpW"][0]
     k = int(attrs.get("x_num_col_dims", 1))
     x2 = x.reshape((int(np.prod(x.shape[:k])), -1))
-    M, K = x2.shape
-    N = wg.shape[-1]
-    out = None
-    if use_pallas() and mm_epilogue_ok(M, K, N, extra_w=2):
-        from .spmd_epilogue import spmd_matmul_swiglu
+    out = _swiglu_dense(x2, wg, wu)
+    return {"Out": [out.reshape(tuple(x.shape[:k]) + (wg.shape[-1],))]}
 
-        out = spmd_matmul_swiglu(ctx, x2, wg, wu)
-        if out is None and use_pallas_unwrapped():
-            out = matmul_swiglu(x2, wg, wu)
-    if out is None:
-        out = _swiglu_dense(x2, wg, wu)
-    return {"Out": [out.reshape(tuple(x.shape[:k]) + (N,))]}
+
+def _add_ln_dense(x2d, y2d, gamma, beta, eps):
+    s = x2d.astype(jnp.float32) + y2d.astype(jnp.float32)
+    mean = jnp.mean(s, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(s - mean), axis=-1, keepdims=True)
+    yn = (s - mean) * jax.lax.rsqrt(var + eps)
+    return (s.astype(x2d.dtype),
+            (yn * gamma + beta).astype(x2d.dtype))
 
 
 @register("fused_residual_ln")
 def _fused_residual_ln(ctx, ins, attrs):
-    """Residual add + layer norm (residual_ln_fuse_pass target): the add
-    is the LN kernel's prologue — the sum forms on the row tile in VMEM,
-    normalizes in the same pass, and BOTH the sum (the residual stream
-    downstream consumers keep reading under its original name) and the
-    normalized output write out once.  Stats in f32 like layer_norm."""
-    from .pallas_kernels import (
-        _add_ln_dense,
-        fused_add_layer_norm,
-        use_pallas,
-        use_pallas_unwrapped,
-    )
-
+    """Residual add + layer norm (residual_ln_fuse_pass target): BOTH the
+    sum (the residual stream downstream consumers keep reading under its
+    original name) and the normalized output come out of one op.  Stats
+    in f32 like layer_norm."""
     x, y = ins["X"][0], ins["Y"][0]
     eps = attrs.get("epsilon", 1e-5)
     h = x.shape[-1]
-    x2 = x.reshape(-1, h)
-    y2 = y.reshape(-1, h)
-    gamma = ins["Scale"][0].reshape(h)
-    beta = ins["Bias"][0].reshape(h)
-    res = None
-    if use_pallas():
-        from .spmd_epilogue import spmd_add_layer_norm
-
-        res = spmd_add_layer_norm(ctx, x2, y2, gamma, beta, eps)
-        if res is None and use_pallas_unwrapped():
-            res = fused_add_layer_norm(x2, y2, gamma, beta, eps)
-    s2, o2 = res if res is not None else _add_ln_dense(
-        x2, y2, gamma, beta, eps)
+    s2, o2 = _add_ln_dense(
+        x.reshape(-1, h), y.reshape(-1, h), ins["Scale"][0].reshape(h),
+        ins["Bias"][0].reshape(h), eps)
     s = s2.reshape(x.shape)
     sf = s.astype(jnp.float32)
     mean = jnp.mean(sf, axis=-1)
@@ -850,56 +792,18 @@ def _pixel_shuffle(ctx, ins, attrs):
     return {"Out": [x.reshape(n, c // (r * r), h * r, w * r)]}
 
 
-def _qvec_attention_mesh(q, k, v, qstart, scale, mesh, axis, bq_flag,
-                         bk_flag, mosaic_legal):
+def _qvec_attention_mesh(q, k, v, qstart, scale, mesh, axis):
     """The vector-QStart attention lowered MESH-CLEAN over `axis`
-    (heads): under FLAGS_use_pallas the flash_attention_qvec kernel runs
-    per-device inside shard_map (each shard sees its own [B, H/n, T, D]
-    head slice and the full replicated qstart — per-row causal cutoffs
-    are head-independent); otherwise a 4-D dense einsum bracketed by
-    sharding constraints so the SPMD partitioner keeps the KV pool's
-    heads-axis placement instead of re-laying it out.  q/k/v: rank-4
-    [B, H, Tq|Tk, D]; qstart: [B]."""
-    from ..flags import get_flag
-    from ..parallel.mesh import shard_map
-    from .pallas_kernels import NEG_INF, flash_attention_qvec, use_pallas
+    (heads): a 4-D dense einsum bracketed by sharding constraints, so the
+    SPMD partitioner keeps the KV pool's heads-axis placement instead of
+    re-laying it out (per-row causal cutoffs are head-independent).
+    q/k/v: rank-4 [B, H, Tq|Tk, D]; qstart: [B]."""
+    from .pallas_kernels import NEG_INF
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     b, h, t, d = q.shape
     tk = k.shape[2]
-    p4 = P(None, axis, None, None)
-    if use_pallas():
-        bq = 128 if t % 128 == 0 else t
-        bk = 128 if tk % 128 == 0 else tk
-        if bq_flag or bk_flag:
-            bq, bk = bq_flag or bq, bk_flag or bk
-            if bq <= 0 or bk <= 0 or not mosaic_legal(bq, bk):
-                raise ValueError(
-                    "FLAGS_flash_block_q/k (%d, %d) are not Mosaic-legal "
-                    "for the sharded ragged-step shapes Tq=%d, Tk=%d"
-                    % (bq, bk, t, tk))
-            dispatch = True
-        else:
-            # deterministic defaults under the mesh (the tuning-cache
-            # search times STANDALONE kernels; a per-shard search inside
-            # shard_map would attribute collective time to block sizes)
-            dispatch = bq <= 512 and bk <= 1024
-        if dispatch:
-            def body(q4, k4, v4, qs):
-                lb, lh, lt, ld = q4.shape
-                ltk = k4.shape[2]
-                qsv = jnp.repeat(qs.reshape(-1).astype(jnp.int32), lh)
-                o = flash_attention_qvec(
-                    q4.reshape(lb * lh, lt, ld),
-                    k4.reshape(lb * lh, ltk, ld),
-                    v4.reshape(lb * lh, ltk, ld),
-                    qsv, scale, bq, bk)
-                return o.reshape(lb, lh, lt, ld)
-
-            return shard_map(
-                body, mesh=mesh, in_specs=(p4, p4, p4, P()),
-                out_specs=p4, check_vma=False)(q, k, v, qstart)
-    sh = NamedSharding(mesh, p4)
+    sh = NamedSharding(mesh, P(None, axis, None, None))
     qc = jax.lax.with_sharding_constraint(q, sh)
     kc = jax.lax.with_sharding_constraint(k, sh)
     vc = jax.lax.with_sharding_constraint(v, sh)
@@ -916,204 +820,62 @@ def _qvec_attention_mesh(q, k, v, qstart, scale, mesh, axis, bq_flag,
 
 
 def _qstart_attention(q, k, v, qstart, scale, window):
-    """fused_attention's decode paths: causal cutoffs in GLOBAL positions
-    from QStart (a scalar: chunked decode; [B]: the ragged serving step).
-    q/k/v: [B, H, Tq|Tk, D].  Their flash kernels (flash_attention_piece,
-    flash_attention_qvec) run under FLAGS_use_pallas, dense XLA otherwise."""
-    from ..flags import get_flag
-    from .pallas_kernels import (
-        _dense_attention,
-        flash_attention_piece,
-        use_pallas_unwrapped,
-    )
+    """fused_attention's decode paths, dense XLA: causal cutoffs in GLOBAL
+    positions from QStart (a scalar: chunked decode; [B]: the ragged
+    serving step).  q/k/v: [B, H, Tq|Tk, D]."""
+    from .pallas_kernels import NEG_INF, _dense_attention
 
     b, h, t, d = q.shape
     tk = k.shape[2]
     qf = q.reshape(b * h, t, d)
     kf = k.reshape(b * h, tk, d)
     vf = v.reshape(b * h, tk, d)
-    bq_flag = int(get_flag("flash_block_q") or 0)
-    bk_flag = int(get_flag("flash_block_k") or 0)
-
-    # Mosaic BlockSpec rule, per side: a block lands in the MINOR dim of
-    # the lifted [BH, 1, X] lse/delta specs ((1, 1, block_q)) and the
-    # kbias spec ((1, 1, block_k)), where it must be a multiple of 128
-    # or cover the full dimension — and it must divide the dimension.
-    # (Interpret mode does not enforce this; only a real-chip compile
-    # does.)  The ONE statement of the rule: _mosaic_legal and
-    # _legalize_blocks both consult these.
-    def _legal_q(bq):
-        return (bq % 128 == 0 or bq == t) and t % bq == 0
-
-    def _legal_k(bk):
-        return (bk % 128 == 0 or bk == tk) and tk % bk == 0
-
-    def _mosaic_legal(bq, bk):
-        return _legal_q(bq) and _legal_k(bk)
-
-    def _legalize_blocks(bq, bk):
-        """Re-legalize (possibly cached) block params against THIS
-        call's seq lens: the tuning cache buckets row dims by pow2, so
-        an entry seeded at a different Tq/Tk in the same bucket can
-        carry blocks that do not divide these lengths — each illegal
-        side falls back to its heuristic default instead of tripping
-        the kernel's divisibility assert."""
-        if not _legal_q(bq):
-            bq = 128 if t % 128 == 0 else t
-        if not _legal_k(bk):
-            bk = 128 if tk % 128 == 0 else tk
-        return bq, bk
-
-    def _auto_blocks(kernel_tag, build):
-        """Auto block choice through the persisted tuning cache: the
-        legal (Mosaic + VMEM score-tile) candidate set is searched on a
-        real-device miss; the heuristic 128-or-full default seeds
-        interpret-mode entries.  build(params) -> standalone callable
-        over (q, k, v) for the on-chip candidate timing."""
-        from .pallas_kernels import _tuned
-
-        default = {"block_q": 128 if t % 128 == 0 else t,
-                   "block_k": 128 if tk % 128 == 0 else tk}
-        cands = [
-            {"block_q": cq, "block_k": ck}
-            for cq in (128, 256, 512)
-            for ck in (128, 256, 512, 1024)
-            if _mosaic_legal(cq, ck)
-        ]
-        params = _tuned(
-            kernel_tag, [(b * h, t, d), (b * h, tk, d)], q.dtype,
-            cands, default, build=build,
-            arg_specs=[((b * h, t, d), q.dtype),
-                       ((b * h, tk, d), k.dtype),
-                       ((b * h, tk, d), v.dtype)],
-        )
-        return _legalize_blocks(int(params["block_q"]),
-                                int(params["block_k"]))
-
-    if qstart.ndim > 0:
-        # PER-ROW offset-causal (the continuous-batching ragged step):
-        # QStart is [B], row b's query i sits at global position
-        # QStart[b] + i — every slot in the serving pool gets its own
-        # causal cutoff inside ONE dispatch.  Under FLAGS_use_pallas
-        # this rides the vector-qstart flash kernel (per-row SMEM
-        # bases; row math is row-independent, so the serving
-        # bit-exactness contract — a slot equals its solo run through
-        # the same kernel — holds); dense XLA otherwise.
-        if int(qstart.shape[0]) != b:
-            raise ValueError(
-                "fused_attention: vector QStart must be [batch]=%d, got %s"
-                % (b, tuple(qstart.shape)))
-        if window:
-            raise ValueError(
-                "fused_attention: window is not supported with per-row "
-                "QStart")
-        from .pallas_kernels import NEG_INF, flash_attention_qvec
-
-        # GSPMD serving mesh (executor._run_spmd binds the context):
-        # heads are embarrassingly parallel under per-row qstart, so the
-        # mesh-clean form shards the HEADS axis — the pallas kernel
-        # under shard_map (pallas_call has no SPMD partition rule; an
-        # unwrapped call would force an all-gather of the sharded KV
-        # pool), the dense form as a 4-D einsum with sharding
-        # constraints (the flattened [B*H] layout would interleave
-        # shards across batch rows).  Row math is untouched either way:
-        # the serving exactness contract (pooled == solo through the
-        # SAME program) rides through sharding.
-        from ..parallel.partition_rules import current_spmd
-
-        spmd = current_spmd()
-        if spmd is not None:
-            from ..parallel.mesh import mesh_axis_sizes
-
-            mesh, rules = spmd
-            axis = rules.mp_axis
-            nsh = mesh_axis_sizes(mesh).get(axis, 1)
-            if nsh > 1 and h % nsh == 0:
-                return _qvec_attention_mesh(
-                    q, k, v, qstart, float(scale), mesh, axis,
-                    bq_flag, bk_flag, _mosaic_legal)
-        if use_pallas_unwrapped():
-            bq = 128 if t % 128 == 0 else t
-            bk = 128 if tk % 128 == 0 else tk
-            if bq_flag or bk_flag:
-                # explicit sweep knobs: validate loudly and ALWAYS
-                # dispatch — the auto path's VMEM-budget gate below
-                # must not silently re-route a requested block size
-                # onto the dense path (misattributed sweep timings)
-                bq, bk = bq_flag or bq, bk_flag or bk
-                if bq <= 0 or bk <= 0 or not _mosaic_legal(bq, bk):
-                    raise ValueError(
-                        "FLAGS_flash_block_q/k (%d, %d) are not "
-                        "Mosaic-legal for the ragged-step shapes Tq=%d, "
-                        "Tk=%d" % (bq, bk, t, tk))
-                dispatch = True
-            else:
-                dispatch = bq <= 512 and bk <= 1024
-                if dispatch:
-                    # auto blocks ride the tuning cache like every other
-                    # pallas_call site (searched at first on-chip
-                    # dispatch)
-                    bq, bk = _auto_blocks(
-                        "flash_attention_qvec",
-                        lambda p: (lambda q_, k_, v_:
-                                   flash_attention_qvec(
-                                       q_, k_, v_,
-                                       jnp.zeros((q_.shape[0],),
-                                                 jnp.int32),
-                                       float(scale), p["block_q"],
-                                       p["block_k"])))
-            if dispatch:
-                # each head row carries its batch row's base
-                qsv = jnp.repeat(qstart.astype(jnp.int32), h)  # [B*H]
-                out = flash_attention_qvec(qf, kf, vf, qsv, float(scale),
-                                           bq, bk)
-                return out.reshape(b, h, t, d)
-
-        s = (jnp.einsum("bqd,bkd->bqk", qf, kf).astype(jnp.float32)
-             * float(scale))  # [B*H, Tq, Tk]
-        q_pos = (qstart.reshape(b, 1).astype(jnp.int32)
-                 + jnp.arange(t, dtype=jnp.int32)[None, :])  # [B, Tq]
-        keep = q_pos[:, :, None] >= jnp.arange(tk, dtype=jnp.int32)[
-            None, None, :]  # [B, Tq, Tk]
-        keep = jnp.broadcast_to(keep[:, None], (b, h, t, tk)).reshape(
-            b * h, t, tk)
-        s = jnp.where(keep, s, NEG_INF)
-        p = jax.nn.softmax(s, axis=-1)
-        out = jnp.einsum("bqk,bkd->bqd", p.astype(qf.dtype), vf)
-        return out.reshape(b, h, t, d)
-    if use_pallas_unwrapped() and (bq_flag or bk_flag):
-        # sweep knobs apply here too: validate loudly and USE them —
-        # silently benchmarking auto blocks (or the dense fallback)
-        # under the requested label is the misattribution the
-        # explicit-flag path exists to prevent
-        bq, bk = bq_flag or 128, bk_flag or 128
-        if bq <= 0 or bk <= 0 or not _mosaic_legal(bq, bk):
-            raise ValueError(
-                "FLAGS_flash_block_q/k (%d, %d) are not Mosaic-legal "
-                "for the chunked-decode shapes Tq=%d, Tk=%d"
-                % (bq, bk, t, tk))
-        out, _lse = flash_attention_piece(
-            qf, kf, vf, True, float(scale), bq, bk, window,
-            qstart.astype(jnp.int32))
-        return out.reshape(b, h, t, d)
-    bq = 128 if t % 128 == 0 else t
-    bk = 128 if tk % 128 == 0 else tk
-    if use_pallas_unwrapped() and bq <= 512 and bk <= 1024:
-        bq, bk = _auto_blocks(
-            "flash_attention_piece",
-            lambda p: (lambda q_, k_, v_: flash_attention_piece(
-                q_, k_, v_, True, float(scale), p["block_q"],
-                p["block_k"], window,
-                jnp.zeros((1,), jnp.int32))[0]))
-        # the ring's offset-causal piece IS chunked decode: the
-        # piece is softmax-normalized within its kv, and here the
-        # kv is the whole cache
-        out, _lse = flash_attention_piece(
-            qf, kf, vf, True, float(scale), bq, bk, window,
-            qstart.astype(jnp.int32))
-    else:
+    if qstart.ndim == 0:
         out = _dense_attention(qf, kf, vf, True, float(scale),
                                window=window, qoff=qstart)
+        return out.reshape(b, h, t, d)
+    # PER-ROW offset-causal (the continuous-batching ragged step):
+    # QStart is [B], row b's query i sits at global position
+    # QStart[b] + i — every slot in the serving pool gets its own
+    # causal cutoff inside ONE dispatch.  Row math is row-independent,
+    # so the serving bit-exactness contract — a slot equals its solo
+    # run through the same program — holds.
+    if int(qstart.shape[0]) != b:
+        raise ValueError(
+            "fused_attention: vector QStart must be [batch]=%d, got %s"
+            % (b, tuple(qstart.shape)))
+    if window:
+        raise ValueError(
+            "fused_attention: window is not supported with per-row "
+            "QStart")
+    # GSPMD serving mesh (executor._run_spmd binds the context): heads
+    # are embarrassingly parallel under per-row qstart, so the mesh-clean
+    # form shards the HEADS axis of a 4-D einsum (the flattened [B*H]
+    # layout would interleave shards across batch rows).  Row math is
+    # untouched: pooled == solo rides through sharding.
+    from ..parallel.partition_rules import current_spmd
+
+    spmd = current_spmd()
+    if spmd is not None:
+        from ..parallel.mesh import mesh_axis_sizes
+
+        mesh, rules = spmd
+        axis = rules.mp_axis
+        nsh = mesh_axis_sizes(mesh).get(axis, 1)
+        if nsh > 1 and h % nsh == 0:
+            return _qvec_attention_mesh(q, k, v, qstart, float(scale),
+                                        mesh, axis)
+    s = (jnp.einsum("bqd,bkd->bqk", qf, kf).astype(jnp.float32)
+         * float(scale))  # [B*H, Tq, Tk]
+    q_pos = (qstart.reshape(b, 1).astype(jnp.int32)
+             + jnp.arange(t, dtype=jnp.int32)[None, :])  # [B, Tq]
+    keep = q_pos[:, :, None] >= jnp.arange(tk, dtype=jnp.int32)[
+        None, None, :]  # [B, Tq, Tk]
+    keep = jnp.broadcast_to(keep[:, None], (b, h, t, tk)).reshape(
+        b * h, t, tk)
+    s = jnp.where(keep, s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    out = jnp.einsum("bqk,bkd->bqd", p.astype(qf.dtype), vf)
     return out.reshape(b, h, t, d)
 
 
@@ -1123,7 +885,7 @@ def _fused_attention(ctx, ins, attrs):
     the reference, TPU-style).  Q/K/V: [batch, heads, T, d].  Training
     path (no QStart): the blockwise kernel where platform and shape say
     so (_flash_engages), dense XLA otherwise.  QStart paths (chunked and
-    ragged decode): their flash kernels under FLAGS_use_pallas."""
+    ragged decode): dense XLA (_qstart_attention)."""
     from .pallas_kernels import _dense_attention
 
     q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]

@@ -1,16 +1,14 @@
-"""Pallas TPU kernels: the hand-fused hot-op layer.
+"""Pallas TPU kernels: blockwise (flash) attention.
 
 Role parity with the reference's specialized kernel libraries — the cuDNN
-kernel variants and operators/math/ JIT kernels (SURVEY §2.6 math/,
-fused/) — but written for the TPU memory hierarchy: q-blocked
-flash attention with online softmax (keeps the [T,T] score matrix out of
-HBM) and a row-blocked fused layer_norm.  Backward passes use custom_vjp
-with XLA-fused recompute (the standard memory-for-FLOPs trade on TPU).
+fused-attention kernels (SURVEY §2.6) — but written for the TPU memory
+hierarchy: attention blocked over q and k with an online softmax, so the
+[T, T] score matrix never exists in HBM, forward or backward (custom_vjp).
 
 Kernels run compiled on TPU and in interpreter mode elsewhere, so the same
-code path is unit-testable on the CPU mesh.  Dispatch happens inside the
-regular op lowerings when FLAGS_use_pallas is on (the analog of the
-reference's OpKernelType.library_type kernel override).
+code path is unit-testable on the CPU mesh.  `fused_attention`'s training
+path chooses `flash_attention` from platform and shape
+(nn_ops._flash_engages); `flash_attention_piece` is parallel/ring.py's.
 """
 
 import functools
@@ -20,17 +18,10 @@ import jax.numpy as jnp
 import numpy as np
 
 # the public primitive-kernel surface (tools/print_signatures tracks it
-# in API.spec): the closed composable set the hot paths dispatch to
+# in API.spec)
 __all__ = [
     "flash_attention",
     "flash_attention_piece",
-    "flash_attention_qvec",
-    "fused_layer_norm",
-    "fused_add_layer_norm",
-    "fused_softmax_xent",
-    "matmul_bias_act",
-    "matmul_swiglu",
-    "use_pallas",
 ]
 
 NEG_INF = -1e30
@@ -42,14 +33,10 @@ def _interpret():
 
 # Mosaic's default scoped-VMEM limit on a v5e is 16 MiB, and Pallas
 # double-buffers every blocked operand: first contact with the chip
-# refused tile sets the dispatch gates admitted ("Scoped allocation with
-# size 16.10M and limit 16.00M exceeded scoped vmem limit").  Every
-# kernel therefore asks for the limit below (the
-# v5e has 128 MiB of VMEM), and every gate admits a tile set only when
-# TWICE its resident bytes fit the budget — the limit less room for the
-# compiler's own temporaries.
+# refused a tile set at 16.10 MiB ("Scoped allocation with size 16.10M and
+# limit 16.00M exceeded scoped vmem limit").  Every kernel therefore asks
+# for the limit below (the v5e has 128 MiB of VMEM).
 _VMEM_LIMIT_BYTES = 32 * 2 ** 20
-_TILE_BUDGET_BYTES = 24 * 2 ** 20
 
 
 def _mosaic_params():
@@ -66,50 +53,11 @@ def _sds(shape, dtype, *xs):
     return jax.ShapeDtypeStruct(shape, dtype, vma=vma_of(*xs))
 
 
-def _cdiv(a, b):
-    return (a + b - 1) // b
-
-
-def _row_block(n, default, row_bytes=0):
-    """Shared row/batch tiling heuristic: the default block when it
-    divides n, else the largest of (8, 1) that does.  row_bytes: what
-    one row of the block costs in VMEM over the call's row-blocked
-    operands and tile-sized temporaries; the default halves until the
-    double-buffered block fits the tile budget (a [512, 50257] f32
-    logits block would ask for 100 MB)."""
-    while default > 8 and 2 * default * row_bytes > _TILE_BUDGET_BYTES:
-        default //= 2
-    blk = min(default, n)
-    if n % blk != 0:
-        blk = 1 if n % 8 else 8
-    return blk
-
-
 def _note(family, n=1):
     """Trace-time pallas dispatch counter (bench attribution)."""
     from .kernel_tuning import note_kernel
 
     note_kernel(family, n)
-
-
-def _tuned(kernel, shapes, dtype, candidates, default, build=None,
-           arg_specs=None):
-    """Consult the persisted tuning cache for this call site's block
-    sizes; on a real-device miss with FLAGS_kernel_autotune, time the
-    candidates on synthetic operands via `build(params) -> callable over
-    arg_specs arrays`.  Interpret-mode misses seed `default`."""
-    from . import kernel_tuning as kt
-
-    measure = None
-    if build is not None and arg_specs and not _interpret():
-        measure = kt.measure_candidate(build, arg_specs)
-    return kt.tuned_params(kernel, shapes, str(dtype), candidates, default,
-                           measure)
-
-
-def _row_block_candidates(n, sizes=(128, 256, 512, 1024)):
-    """Row-block search space: the legal (dividing) members of `sizes`."""
-    return [{"block_rows": s} for s in sizes if s <= n and n % s == 0]
 
 
 # ---------------------------------------------------------------------------
@@ -121,8 +69,9 @@ def _row_block_candidates(n, sizes=(128, 256, 512, 1024)):
 # resident, so sequence length is bounded by HBM, not VMEM.  The forward
 # saves the per-row logsumexp; the backward rebuilds [block_q, block_k]
 # probability tiles from the saved lse — one fused kernel on the training
-# path (flash_attention), two (dq and dk/dv/dkbias) on the decode and ring
-# paths — so the [T, T] score matrix never exists in HBM in either pass.
+# path (flash_attention), two (dq and dk/dv/dkbias) where that one does not
+# apply and on the ring path — so the [T, T] score matrix never exists in
+# HBM in either pass.
 # q, k, v, do reach the MXU in their own dtype; tiles and state are f32.
 # Role parity: the cuDNN fused-attention kernels of SURVEY §2.6.
 # ---------------------------------------------------------------------------
@@ -135,24 +84,13 @@ def _dot_nt(a, b):
 
 def _unpack_flash_refs(refs, has_qoff, has_seg):
     """Shared operand unpack for the three flash kernels (fwd/dq/dkv):
-    the optional leading q base — a whole-array SMEM operand, [1] for
-    the scalar offset or [BH] when has_qoff == "vec" (each grid-b cell
-    reads ITS row's base by program_id(0) — the vector-qstart ragged
-    serving step; Mosaic refuses a (1, 1)-blocked SMEM spec, whose last
-    two block dims must be (8, 128)-divisible or full) — then
-    q/k/v/kbias and the optional segment-id pair.
+    the optional leading q base — a whole-array [1] SMEM operand, the
+    scalar offset — then q/k/v/kbias and the optional segment-id pair.
     Returns (qo, q, k, v, kbias, seg_q, seg_k, remaining_refs); ONE
-    copy so a new qstart encoding cannot silently miss a backward
+    copy so a new offset encoding cannot silently miss a backward
     kernel's causal base."""
-    from jax.experimental import pallas as pl
-
     refs = list(refs)
-    if has_qoff == "vec":
-        qo = refs.pop(0)[pl.program_id(0)]
-    elif has_qoff:
-        qo = refs.pop(0)[0]
-    else:
-        qo = 0
+    qo = refs.pop(0)[0] if has_qoff else 0
     q_ref, k_ref, v_ref, kb_ref = refs[:4]
     del refs[:4]
     sq_ref, sk_ref = (refs[:2] if has_seg else (None, None))
@@ -324,7 +262,7 @@ def _flash_blocks(Tq, Tk, block_q, block_k, causal):
 
 
 def _flash_fwd(q, k, v, kbias, causal, scale, block_q, block_k, window=0,
-               qoff=None, seg=None, qvec=None, interpret=None):
+               qoff=None, seg=None, interpret=None):
     """q: [BH, Tq, d], k: [BH, Tk, d], v: [BH, Tk, dv] (the result is
     [BH, Tq, dv]; dv is d everywhere but under latent attention, whose
     scores are 192 wide over 128-wide values), kbias: [BH, Tk] additive
@@ -334,9 +272,7 @@ def _flash_fwd(q, k, v, kbias, causal, scale, block_q, block_k, window=0,
     grid's innermost axis is the band's width (_band_grid).  qoff: optional [1] int32 GLOBAL
     q-position base relative to k's (traced; SMEM scalar) — the ring
     passes its chunk offset so causal/window masks apply in global
-    positions.  qvec: optional [BH] int32 PER-ROW q-position bases (the
-    continuous-batching ragged step: every serving slot carries its own
-    causal cutoff) riding whole in SMEM — mutually exclusive with qoff.  seg: optional [BH, T] int32 segment ids (sequence
+    positions.  seg: optional [BH, T] int32 segment ids (sequence
     packing; requires Tq == Tk) — rides as two more [BH, 1, X] rank-1
     operands, compared per score tile.  Returns (o, lse)."""
     from jax.experimental import pallas as pl
@@ -344,22 +280,20 @@ def _flash_fwd(q, k, v, kbias, causal, scale, block_q, block_k, window=0,
 
     BH, T, d = q.shape
     Tk, dv = k.shape[1], v.shape[2]
-    assert qoff is None or qvec is None, "qoff and qvec are exclusive"
     block_q, block_k = _flash_blocks(T, Tk, block_q, block_k,
-                                     causal and qoff is None
-                                     and qvec is None)
+                                     causal and qoff is None)
     assert not (window and not causal), "window attention requires causal"
     assert seg is None or T == Tk, "segment ids require Tq == Tk"
     if interpret is None:
         interpret = _interpret()
     nq, nk = T // block_q, Tk // block_k
     band = _band_grid(T, Tk, block_q, block_k,
-                      causal and qoff is None and qvec is None, int(window))
+                      causal and qoff is None, int(window))
     kblock = _band_inner(band, block_q, block_k, int(window), nk)
     kernel = functools.partial(
         _flash_fwd_kernel, block_q=block_q, block_k=block_k, nk=nk,
         causal=causal, scale=scale, window=int(window),
-        has_qoff=("vec" if qvec is not None else qoff is not None),
+        has_qoff=qoff is not None,
         has_seg=seg is not None, band=band,
     )
     # 2D [BH, X] operands ride as [BH, 1, X] so every block keeps a
@@ -386,10 +320,9 @@ def _flash_fwd(q, k, v, kbias, causal, scale, block_q, block_k, window=0,
                          memory_space=pltpu.VMEM),
         ]
         args += [seg3, seg3]
-    if qoff is not None or qvec is not None:
+    if qoff is not None:
         in_specs.insert(0, pl.BlockSpec(memory_space=pltpu.SMEM))
-        args.insert(0, (qoff.astype(jnp.int32).reshape(1) if qoff is not None
-                        else qvec.astype(jnp.int32).reshape(BH)))
+        args.insert(0, qoff.astype(jnp.int32).reshape(1))
     o, lse = pl.pallas_call(
         kernel,
         grid=(BH, nq, band or nk),
@@ -514,8 +447,7 @@ def _flash_dkv_kernel(*refs, block_q, block_k, nq, causal, scale,
 
 
 def _flash_bwd(q, k, v, kbias, o, lse, do, causal, scale, block_q, block_k,
-               dlse=None, window=0, qoff=None, seg=None, qvec=None,
-               interpret=None):
+               dlse=None, window=0, qoff=None, seg=None, interpret=None):
     """Blocked backward: returns (dq, dk, dv, dkbias[BH,Tk] f32).
 
     dlse: optional cotangent of the lse output (the chunk-merge path of
@@ -526,21 +458,16 @@ def _flash_bwd(q, k, v, kbias, o, lse, do, causal, scale, block_q, block_k,
 
     BH, T, d = q.shape
     Tk, dv = k.shape[1], v.shape[2]
-    assert qoff is None or qvec is None, "qoff and qvec are exclusive"
     block_q, block_k = _flash_blocks(T, Tk, block_q, block_k,
-                                     causal and qoff is None
-                                     and qvec is None)
+                                     causal and qoff is None)
     if interpret is None:
         interpret = _interpret()
     nq, nk = T // block_q, Tk // block_k
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     if dlse is not None:
         delta = delta - dlse.astype(jnp.float32)
-    qoff_arg = (
-        [qoff.astype(jnp.int32).reshape(1)] if qoff is not None
-        else [qvec.astype(jnp.int32).reshape(BH)]
-        if qvec is not None else [])
-    has_qoff = "vec" if qvec is not None else qoff is not None
+    has_qoff = qoff is not None
+    qoff_arg = [qoff.astype(jnp.int32).reshape(1)] if has_qoff else []
     # 2D [BH, X] operands ride as [BH, 1, X] (Mosaic-legal blocks; see
     # _flash_fwd)
     kb3 = kbias.reshape(BH, 1, Tk)
@@ -550,7 +477,7 @@ def _flash_bwd(q, k, v, kbias, o, lse, do, causal, scale, block_q, block_k,
             if seg is not None else None)
     # the band grids of the forward (dq pass) and of the fused backward
     # (dk/dv pass), where there is no traced offset
-    static = causal and qoff is None and qvec is None
+    static = causal and not has_qoff
     band_k = _band_grid(T, Tk, block_q, block_k, static, int(window))
     band_q = _band_grid(T, Tk, block_q, block_k, static, int(window),
                         transposed=True)
@@ -574,8 +501,7 @@ def _flash_bwd(q, k, v, kbias, o, lse, do, causal, scale, block_q, block_k,
                              memory_space=pltpu.VMEM)
     row_spec_q = pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i),
                               memory_space=pltpu.VMEM)
-    smem = ([pl.BlockSpec(memory_space=pltpu.SMEM)]
-            if qoff is not None or qvec is not None else [])
+    smem = [pl.BlockSpec(memory_space=pltpu.SMEM)] if has_qoff else []
     seg_specs_q = ([row_spec_q, kb_spec_q] if seg is not None else [])
     seg_args = ([seg3, seg3] if seg is not None else [])
     dq = pl.pallas_call(
@@ -957,638 +883,3 @@ def _piece_vjp_bwd(causal, scale, block_q, block_k, window, res, cts):
 
 
 flash_attention_piece.defvjp(_piece_vjp_fwd, _piece_vjp_bwd)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def flash_attention_qvec(q, k, v, qstart, scale=None, block_q=128,
-                         block_k=128):
-    """PER-ROW-qstart causal flash attention: q [BH, Tq, d] against
-    k/v [BH, Tk, d] where row b's query i sits at global position
-    qstart[b] + i and keys at their cache indices (Tq may differ from
-    Tk).  qstart: [BH] int — rides whole in SMEM, and each grid cell
-    reads ITS row's causal cutoff; out-of-band K blocks are still
-    skipped per row.  This is the ragged continuous-batching serving
-    step's attention (PR 9's documented single biggest serving-perf
-    lever): one dispatch serves a pool of requests at heterogeneous
-    positions without the [B, Tq, Tk] mask or score matrix ever
-    existing in HBM.  Row math is row-independent (the serving
-    exactness contract: a slot's output is bit-identical to the same
-    row running solo).  Shares the band machinery (_band) with the
-    training kernels; differentiable in q/k/v for draft-training and
-    prefix-tuning setups that backprop through ragged steps."""
-    if scale is None:
-        scale = 1.0 / (q.shape[-1] ** 0.5)
-    kb = jnp.zeros(k.shape[:2], jnp.float32)
-    _note("attention")
-    o, _ = _flash_fwd(q, k, v, kb, True, scale, block_q, block_k,
-                      qvec=qstart)
-    return o
-
-
-def _qvec_vjp_fwd(q, k, v, qstart, scale, block_q, block_k):
-    if scale is None:
-        scale = 1.0 / (q.shape[-1] ** 0.5)
-    kb = jnp.zeros(k.shape[:2], jnp.float32)
-    _note("attention")
-    o, lse = _flash_fwd(q, k, v, kb, True, scale, block_q, block_k,
-                        qvec=qstart)
-    return o, (q, k, v, qstart, o, lse)
-
-
-def _qvec_vjp_bwd(scale, block_q, block_k, res, do):
-    q, k, v, qstart, o, lse = res
-    if scale is None:
-        scale = 1.0 / (q.shape[-1] ** 0.5)
-    kb = jnp.zeros(k.shape[:2], jnp.float32)
-    dq, dk, dv, _ = _flash_bwd(q, k, v, kb, o, lse, do, True, scale,
-                               block_q, block_k, qvec=qstart)
-    # integer positions get the mandatory float0 cotangent
-    dqs = np.zeros(qstart.shape, dtype=jax.dtypes.float0)
-    return dq, dk, dv, dqs
-
-
-flash_attention_qvec.defvjp(_qvec_vjp_fwd, _qvec_vjp_bwd)
-
-
-# ---------------------------------------------------------------------------
-# fused layer norm
-# ---------------------------------------------------------------------------
-def _ln_kernel(x_ref, g_ref, b_ref, o_ref, *, eps):
-    x = x_ref[:].astype(jnp.float32)
-    mean = jnp.mean(x, axis=-1, keepdims=True)
-    var = jnp.mean(jnp.square(x - mean), axis=-1, keepdims=True)
-    y = (x - mean) * jax.lax.rsqrt(var + eps)
-    o_ref[:] = (y * g_ref[:].astype(jnp.float32)
-                + b_ref[:].astype(jnp.float32)).astype(o_ref.dtype)
-
-
-def _ln_fwd(x2d, gamma, beta, eps, block_rows=None):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    R, H = x2d.shape
-    if block_rows is None:
-        block_rows = _tuned(
-            "layer_norm", [x2d.shape], x2d.dtype,
-            _row_block_candidates(R),
-            {"block_rows": _row_block(R, 256)},
-            build=lambda p: (lambda x, g, b: _ln_fwd(
-                x, g, b, eps, p["block_rows"])),
-            arg_specs=[(x2d.shape, x2d.dtype), (gamma.shape, gamma.dtype),
-                       (beta.shape, beta.dtype)],
-        )["block_rows"]
-    _note("layernorm")
-    block_rows = _row_block(R, block_rows, 3 * H * 4)
-    grid = (_cdiv(R, block_rows),)
-    return pl.pallas_call(
-        functools.partial(_ln_kernel, eps=eps),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_rows, H), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((H,), lambda i: (0,), memory_space=pltpu.VMEM),
-            pl.BlockSpec((H,), lambda i: (0,), memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((block_rows, H), lambda i: (i, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((R, H), x2d.dtype),
-        compiler_params=_mosaic_params(),
-        interpret=_interpret(),
-    )(x2d, gamma, beta)
-
-
-def _ln_dense(x2d, gamma, beta, eps):
-    x = x2d.astype(jnp.float32)
-    mean = jnp.mean(x, axis=-1, keepdims=True)
-    var = jnp.mean(jnp.square(x - mean), axis=-1, keepdims=True)
-    y = (x - mean) * jax.lax.rsqrt(var + eps)
-    return (y * gamma + beta).astype(x2d.dtype)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def fused_layer_norm(x2d, gamma, beta, eps=1e-5):
-    """Row-fused layer norm over [rows, hidden]."""
-    return _ln_fwd(x2d, gamma, beta, eps)
-
-
-def _ln_vjp_fwd(x2d, gamma, beta, eps):
-    return _ln_fwd(x2d, gamma, beta, eps), (x2d, gamma, beta)
-
-
-def _ln_vjp_bwd(eps, res, dy):
-    x2d, gamma, beta = res
-    _, vjp = jax.vjp(lambda x, g, b: _ln_dense(x, g, b, eps), x2d, gamma, beta)
-    return vjp(dy)
-
-
-fused_layer_norm.defvjp(_ln_vjp_fwd, _ln_vjp_bwd)
-
-
-def use_pallas():
-    """Kernel-override dispatch switch (OpKernelType.library analog)."""
-    from ..flags import get_flag
-
-    return get_flag("use_pallas")
-
-
-def use_pallas_unwrapped():
-    """May a dispatch site call a kernel with NO shard_map around it?
-    The flag, and not while tracing under a live GSPMD mesh: XLA cannot
-    partition a Mosaic custom call ("Mosaic kernels cannot be
-    automatically partitioned. Please wrap the call in a shard_map" —
-    the first four-chip run; interpret mode lowers to plain HLO and
-    never said so).  Under a mesh a kernel runs through its shard_map
-    form (ops/spmd_epilogue, nn_ops._qvec_attention_mesh) or the op
-    lowers densely and GSPMD partitions that."""
-    from .spmd_epilogue import mesh_ctx
-
-    return use_pallas() and mesh_ctx() is None
-
-
-# ---------------------------------------------------------------------------
-# fused softmax cross entropy (row-blocked logsumexp + label gather; the
-# backward is the analytic softmax(x) - onehot, no recompute needed)
-# ---------------------------------------------------------------------------
-def _sxent_kernel(x_ref, lbl_ref, o_ref):
-    x = x_ref[:].astype(jnp.float32)  # [Bblk, C]
-    m = jnp.max(x, axis=-1, keepdims=True)
-    lse = jnp.log(jnp.sum(jnp.exp(x - m), axis=-1, keepdims=True)) + m
-    lbl = lbl_ref[:].astype(jnp.int32).reshape(-1)  # [Bblk, 1] -> [Bblk]
-    cols = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
-    gold = jnp.sum(jnp.where(cols == lbl[:, None], x, 0.0), axis=-1,
-                   keepdims=True)
-    o_ref[:] = (lse - gold).astype(o_ref.dtype)
-
-
-def _sxent_fwd_call(logits, labels, block_rows=None):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    R, C = logits.shape
-    if block_rows is None:
-        block_rows = _tuned(
-            "softmax_xent", [logits.shape], logits.dtype,
-            _row_block_candidates(R),
-            {"block_rows": _row_block(R, 512)},
-            build=lambda p: (lambda lg, lb: _sxent_fwd_call(
-                lg, lb, p["block_rows"])),
-            arg_specs=[(logits.shape, logits.dtype),
-                       ((R,), "int32")],
-        )["block_rows"]
-    _note("xent")
-    block_rows = _row_block(R, block_rows, 3 * C * 4)
-    grid = (_cdiv(R, block_rows),)
-    return pl.pallas_call(
-        _sxent_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_rows, C), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-            # labels ride as [R, 1] (1D sub-128 blocks are Mosaic-illegal)
-            pl.BlockSpec((block_rows, 1), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((block_rows, 1), lambda i: (i, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((R, 1), jnp.float32),
-        compiler_params=_mosaic_params(),
-        interpret=_interpret(),
-    )(logits, labels.reshape(R, 1))
-
-
-def _sxent_validate(logits, labels):
-    """Loud shape contract: 2-D logits + one int label per row.  A
-    mis-shaped labels array used to broadcast through the gather
-    (plausible wrong losses); now it raises at trace time."""
-    if logits.ndim != 2:
-        raise ValueError(
-            "fused_softmax_xent: logits must be 2-D [rows, classes], got "
-            "shape %s — reshape leading dims into rows first"
-            % (tuple(logits.shape),))
-    lbl_n = int(np.prod(labels.shape)) if labels.ndim else 0
-    if labels.ndim > 2 or lbl_n != int(logits.shape[0]) or (
-            labels.ndim == 2 and labels.shape[1] != 1):
-        raise ValueError(
-            "fused_softmax_xent: labels must be [rows]=%d (or [rows, 1]) "
-            "ints, got shape %s — a mismatched labels array would "
-            "mis-broadcast against the row blocks"
-            % (int(logits.shape[0]), tuple(labels.shape)))
-    if not jnp.issubdtype(labels.dtype, jnp.integer):
-        raise ValueError(
-            "fused_softmax_xent: labels must be integers, got %s"
-            % labels.dtype)
-
-
-def _sxent_bwd_kernel(x_ref, lbl_ref, dy_ref, dx_ref):
-    """Row-blocked analytic backward: dx = (softmax(x) - onehot) * dy.
-    The one-hot is an iota compare inside the tile — no [R, C] one-hot
-    (or separately materialized softmax) array in HBM; dx is the
-    gradient itself and unavoidable."""
-    x = x_ref[:].astype(jnp.float32)
-    m = jnp.max(x, axis=-1, keepdims=True)
-    e = jnp.exp(x - m)
-    p = e / jnp.sum(e, axis=-1, keepdims=True)
-    lbl = lbl_ref[:].astype(jnp.int32).reshape(-1)
-    cols = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
-    onehot = (cols == lbl[:, None]).astype(jnp.float32)
-    dx_ref[:] = ((p - onehot) * dy_ref[:].astype(jnp.float32)).astype(
-        dx_ref.dtype)
-
-
-def _sxent_bwd_call(logits, labels, dy, block_rows=None):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    R, C = logits.shape
-    if block_rows is None:
-        block_rows = _tuned(
-            "softmax_xent_bwd", [logits.shape], logits.dtype,
-            _row_block_candidates(R),
-            {"block_rows": _row_block(R, 512)},
-            build=lambda p: (lambda lg, lb, g: _sxent_bwd_call(
-                lg, lb, g, p["block_rows"])),
-            arg_specs=[(logits.shape, logits.dtype), ((R,), "int32"),
-                       ((R, 1), "float32")],
-        )["block_rows"]
-    block_rows = _row_block(R, block_rows, 5 * C * 4)
-    row_spec = pl.BlockSpec((block_rows, 1), lambda i: (i, 0),
-                            memory_space=pltpu.VMEM)
-    return pl.pallas_call(
-        _sxent_bwd_kernel,
-        grid=(_cdiv(R, block_rows),),
-        in_specs=[
-            pl.BlockSpec((block_rows, C), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-            row_spec,
-            row_spec,
-        ],
-        out_specs=pl.BlockSpec((block_rows, C), lambda i: (i, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((R, C), logits.dtype),
-        compiler_params=_mosaic_params(),
-        interpret=_interpret(),
-    )(logits, labels.reshape(R, 1), dy.reshape(R, 1).astype(jnp.float32))
-
-
-@jax.custom_vjp
-def fused_softmax_xent(logits, labels):
-    """Per-row -log softmax[label] over [rows, classes] + int labels [rows]."""
-    _sxent_validate(logits, labels)
-    return _sxent_fwd_call(logits, labels)
-
-
-def _sxent_vjp_fwd(logits, labels):
-    _sxent_validate(logits, labels)
-    return _sxent_fwd_call(logits, labels), (logits, labels)
-
-
-def _sxent_vjp_bwd(res, dy):
-    logits, labels = res
-    # blocked kernel backward (the dense softmax + one_hot pair this used
-    # to materialize was 2x the [R, C] traffic of the gradient itself)
-    return _sxent_bwd_call(logits, labels.reshape(-1), dy), None
-
-
-fused_softmax_xent.defvjp(_sxent_vjp_fwd, _sxent_vjp_bwd)
-
-
-# ---------------------------------------------------------------------------
-# matmul-epilogue fusions (TPP-style primitive kernels, ROADMAP item 1):
-# a blocked [M, K] @ [K, N] with the bias add + activation (or the SwiGLU
-# gate product) applied to the accumulator TILE in VMEM before it ever
-# reaches HBM — the XLA form writes the pre-activation [M, N] out and
-# reads it back per epilogue op.  Grid (nm, nn), full-K per tile (the
-# bench shapes keep K = d_model-ish, so an x/w tile pair fits VMEM
-# comfortably); dots consume the input dtype (bf16 under AMP runs the
-# MXU at full rate) and accumulate f32.  Backwards recompute through the
-# dense reference (plain MXU matmuls — nothing to hand-fuse there).
-# ---------------------------------------------------------------------------
-_MM_ACTS = ("", "identity", "relu", "tanh", "sigmoid", "gelu", "swish")
-
-
-def _erf_mosaic(x):
-    """erf for kernel bodies.  The Pallas TPU lowering has neither erf
-    nor erfc (jax 0.9.0: "Unimplemented primitive in Pallas TPU lowering
-    for KernelType.TC: erfc"), so the exact-gelu epilogue could not
-    compile.  Abramowitz & Stegun 7.1.26: |error| <= 1.5e-7, f32
-    resolution, from an exp and a divide, which Mosaic has."""
-    a = jnp.abs(x)
-    t = 1.0 / (1.0 + 0.3275911 * a)
-    poly = t * (0.254829592 + t * (-0.284496736 + t * (
-        1.421413741 + t * (-1.453152027 + t * 1.061405429))))
-    y = 1.0 - poly * jnp.exp(-a * a)
-    return jnp.where(x < 0, -y, y)
-
-
-def _mm_act(z, act, in_kernel=False):
-    """f32 epilogue activation (exact erf gelu / beta-1 swish: the same
-    defaults as the op lowerings in math_ops.ACTIVATIONS).  in_kernel:
-    the caller is a Pallas kernel body, where gelu's erf must be
-    _erf_mosaic; the dense twin keeps XLA's own."""
-    if act in ("", "identity"):
-        return z
-    if act == "relu":
-        return jnp.maximum(z, 0.0)
-    if act == "tanh":
-        return jnp.tanh(z)
-    if act == "sigmoid":
-        return jax.nn.sigmoid(z)
-    if act == "gelu":
-        if in_kernel:
-            return 0.5 * z * (1.0 + _erf_mosaic(z * 0.7071067811865476))
-        return jax.nn.gelu(z, approximate=False)
-    if act == "swish":
-        return z * jax.nn.sigmoid(z)
-    raise ValueError("matmul epilogue: unsupported activation %r" % (act,))
-
-
-def _mm_kernel(*refs, act, has_bias):
-    x_ref, w_ref = refs[0], refs[1]
-    b_ref = refs[2] if has_bias else None
-    o_ref = refs[-1]
-    z = jnp.dot(x_ref[:], w_ref[:], preferred_element_type=jnp.float32)
-    if has_bias:
-        z = z + b_ref[:].astype(jnp.float32)  # [1, bn] broadcast
-    o_ref[:] = _mm_act(z, act, in_kernel=True).astype(o_ref.dtype)
-
-
-def _mm_col_block(n, default):
-    """Lane-dim tiling: a multiple of 128 dividing n, else the full dim
-    (a full minor-dim block is always Mosaic-legal)."""
-    blk = min(default, n)
-    if n % 128 == 0 and blk % 128 == 0 and n % blk == 0:
-        return blk
-    return n
-
-
-def _mm_blocks(M, K, N, dtype, kernel, extra_w=1):
-    """Tuned (block_m, block_n) for an [M, K] @ [K, N] epilogue kernel;
-    extra_w doubles the per-tile weight footprint (SwiGLU reads two)."""
-    cands = []
-    for bm in (128, 256, 512):
-        if M % bm:
-            continue
-        for bn in (128, 256, 512):
-            if N % bn or bn % 128:
-                continue
-            if _mm_vmem_ok(M, K, N, bm, bn, extra_w):
-                cands.append({"block_m": bm, "block_n": bn})
-    default = {"block_m": _row_block(M, 256), "block_n": _mm_col_block(N, 256)}
-    if extra_w == 2:
-        # measure the kernel actually being tuned: SwiGLU runs two dots
-        # plus the gate against each x tile — a plain-matmul timing
-        # would rank candidates by the wrong weight traffic
-        build = lambda p: (lambda x, wg, wu: _swiglu_call(
-            x, wg, wu, p["block_m"], p["block_n"]))
-        arg_specs = [((M, K), dtype), ((K, N), dtype), ((K, N), dtype)]
-    else:
-        build = lambda p: (lambda x, w: _mm_call(
-            x, w, None, "", p["block_m"], p["block_n"]))
-        arg_specs = [((M, K), dtype), ((K, N), dtype)]
-    params = _tuned(
-        kernel, [(M, K), (K, N)], dtype, cands, default,
-        build=build, arg_specs=arg_specs,
-    )
-    bm = _row_block(M, params["block_m"])
-    bn = _mm_col_block(N, params["block_n"])
-    return bm, bn
-
-
-def _mm_vmem_ok(M, K, N, bm, bn, extra_w=1):
-    """x/w/out tiles plus the accumulator (f32 upper bound), double-
-    buffered, must fit the tile budget."""
-    tile = (bm * K + extra_w * K * bn + 2 * bm * bn + bn) * 4
-    return 2 * tile < _TILE_BUDGET_BYTES
-
-
-def mm_epilogue_ok(M, K, N, act="", extra_w=1):
-    """THE dispatch gate for the matmul-epilogue kernels (fc /
-    fused_swiglu lowerings call this instead of re-deriving tiling
-    policy): activation supported and the heuristic DEFAULT tile pair
-    fits VMEM — tuned candidates are themselves VMEM-filtered in
-    _mm_blocks, so a True here can never select a tile the kernel
-    rejects."""
-    return (act in _MM_ACTS
-            and _mm_vmem_ok(M, K, N, _row_block(M, 256),
-                            _mm_col_block(N, 256), extra_w))
-
-
-def _mm_call(x2d, w, bias, act, block_m, block_n):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    M, K = x2d.shape
-    N = w.shape[1]
-    _note("matmul_epilogue")
-    grid = (_cdiv(M, block_m), _cdiv(N, block_n))
-    in_specs = [
-        pl.BlockSpec((block_m, K), lambda i, j: (i, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((K, block_n), lambda i, j: (0, j),
-                     memory_space=pltpu.VMEM),
-    ]
-    args = [x2d, w]
-    if bias is not None:
-        in_specs.append(pl.BlockSpec((1, block_n), lambda i, j: (0, j),
-                                     memory_space=pltpu.VMEM))
-        args.append(bias.reshape(1, N))
-    return pl.pallas_call(
-        functools.partial(_mm_kernel, act=act, has_bias=bias is not None),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((block_m, block_n), lambda i, j: (i, j),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((M, N), x2d.dtype),
-        compiler_params=_mosaic_params(),
-        interpret=_interpret(),
-    )(*args)
-
-
-def _mm_dense(x2d, w, bias, act):
-    """XLA reference (also the backward recompute path)."""
-    z = jnp.dot(x2d, w, preferred_element_type=jnp.float32)
-    if bias is not None:
-        z = z + bias.reshape(1, -1).astype(jnp.float32)
-    return _mm_act(z, act).astype(x2d.dtype)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def matmul_bias_act(x2d, w, bias=None, act="", block_m=None, block_n=None):
-    """Blocked matmul with fused bias + activation epilogue over
-    [M, K] @ [K, N] (+ bias [N]); act in "", relu, tanh, sigmoid, gelu
-    (exact erf), swish.  block_m/block_n default to the tuning cache's
-    decision for this shape bucket."""
-    if block_m is None or block_n is None:
-        block_m, block_n = _mm_blocks(x2d.shape[0], x2d.shape[1],
-                                      w.shape[1], x2d.dtype, "matmul_bias_act")
-    return _mm_call(x2d, w, bias, act, block_m, block_n)
-
-
-def _mm_vjp_fwd(x2d, w, bias, act, block_m, block_n):
-    return (matmul_bias_act(x2d, w, bias, act, block_m, block_n),
-            (x2d, w, bias))
-
-
-def _mm_vjp_bwd(act, block_m, block_n, res, dy):
-    x2d, w, bias = res
-    if bias is None:
-        _, vjp = jax.vjp(lambda x, w_: _mm_dense(x, w_, None, act), x2d, w)
-        dx, dw = vjp(dy)
-        return dx, dw, None
-    _, vjp = jax.vjp(lambda x, w_, b: _mm_dense(x, w_, b, act), x2d, w, bias)
-    return vjp(dy)
-
-
-matmul_bias_act.defvjp(_mm_vjp_fwd, _mm_vjp_bwd)
-
-
-def _swiglu_kernel(x_ref, wg_ref, wu_ref, o_ref):
-    x = x_ref[:]
-    g = jnp.dot(x, wg_ref[:], preferred_element_type=jnp.float32)
-    u = jnp.dot(x, wu_ref[:], preferred_element_type=jnp.float32)
-    o_ref[:] = (g * jax.nn.sigmoid(g) * u).astype(o_ref.dtype)
-
-
-def _swiglu_call(x2d, wg, wu, block_m, block_n):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    M, K = x2d.shape
-    N = wg.shape[1]
-    _note("matmul_epilogue")
-    w_spec = pl.BlockSpec((K, block_n), lambda i, j: (0, j),
-                          memory_space=pltpu.VMEM)
-    return pl.pallas_call(
-        _swiglu_kernel,
-        grid=(_cdiv(M, block_m), _cdiv(N, block_n)),
-        in_specs=[
-            pl.BlockSpec((block_m, K), lambda i, j: (i, 0),
-                         memory_space=pltpu.VMEM),
-            w_spec,
-            w_spec,
-        ],
-        out_specs=pl.BlockSpec((block_m, block_n), lambda i, j: (i, j),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((M, N), x2d.dtype),
-        compiler_params=_mosaic_params(),
-        interpret=_interpret(),
-    )(x2d, wg, wu)
-
-
-def _swiglu_dense(x2d, wg, wu):
-    g = jnp.dot(x2d, wg, preferred_element_type=jnp.float32)
-    u = jnp.dot(x2d, wu, preferred_element_type=jnp.float32)
-    return (g * jax.nn.sigmoid(g) * u).astype(x2d.dtype)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def matmul_swiglu(x2d, wg, wu, block_m=None, block_n=None):
-    """Fused SwiGLU gating: silu(x @ wg) * (x @ wu) over [M, K] with
-    wg/wu [K, N].  BOTH projections of a tile and the gate product
-    happen against one resident x tile — the gate/up pre-activations
-    never exist in HBM (the unfused form writes and re-reads both)."""
-    if block_m is None or block_n is None:
-        block_m, block_n = _mm_blocks(x2d.shape[0], x2d.shape[1],
-                                      wg.shape[1], x2d.dtype,
-                                      "matmul_swiglu", extra_w=2)
-    return _swiglu_call(x2d, wg, wu, block_m, block_n)
-
-
-def _swiglu_vjp_fwd(x2d, wg, wu, block_m, block_n):
-    return matmul_swiglu(x2d, wg, wu, block_m, block_n), (x2d, wg, wu)
-
-
-def _swiglu_vjp_bwd(block_m, block_n, res, dy):
-    x2d, wg, wu = res
-    _, vjp = jax.vjp(_swiglu_dense, x2d, wg, wu)
-    return vjp(dy)
-
-
-matmul_swiglu.defvjp(_swiglu_vjp_fwd, _swiglu_vjp_bwd)
-
-
-# ---------------------------------------------------------------------------
-# residual-add + layer norm: the transformer pre/post-process pair
-# (x + sublayer -> LN) with the add as the LN kernel's PROLOGUE — the sum
-# is formed on the row tile already in VMEM, normalized in the same pass,
-# and both the sum (the residual stream the next block reads) and the
-# normalized output write out once.
-# ---------------------------------------------------------------------------
-def _add_ln_kernel(x_ref, y_ref, g_ref, b_ref, s_ref, o_ref, *, eps):
-    s = x_ref[:].astype(jnp.float32) + y_ref[:].astype(jnp.float32)
-    s_ref[:] = s.astype(s_ref.dtype)
-    mean = jnp.mean(s, axis=-1, keepdims=True)
-    var = jnp.mean(jnp.square(s - mean), axis=-1, keepdims=True)
-    yn = (s - mean) * jax.lax.rsqrt(var + eps)
-    o_ref[:] = (yn * g_ref[:].astype(jnp.float32)
-                + b_ref[:].astype(jnp.float32)).astype(o_ref.dtype)
-
-
-def _add_ln_call(x2d, y2d, gamma, beta, eps, block_rows):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    R, H = x2d.shape
-    _note("layernorm")
-    block_rows = _row_block(R, block_rows, 5 * H * 4)
-    row_spec = pl.BlockSpec((block_rows, H), lambda i: (i, 0),
-                            memory_space=pltpu.VMEM)
-    vec_spec = pl.BlockSpec((H,), lambda i: (0,), memory_space=pltpu.VMEM)
-    return pl.pallas_call(
-        functools.partial(_add_ln_kernel, eps=eps),
-        grid=(_cdiv(R, block_rows),),
-        in_specs=[row_spec, row_spec, vec_spec, vec_spec],
-        out_specs=[row_spec, row_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((R, H), x2d.dtype),
-            jax.ShapeDtypeStruct((R, H), x2d.dtype),
-        ],
-        compiler_params=_mosaic_params(),
-        interpret=_interpret(),
-    )(x2d, y2d, gamma, beta)
-
-
-def _add_ln_dense(x2d, y2d, gamma, beta, eps):
-    s = x2d.astype(jnp.float32) + y2d.astype(jnp.float32)
-    mean = jnp.mean(s, axis=-1, keepdims=True)
-    var = jnp.mean(jnp.square(s - mean), axis=-1, keepdims=True)
-    yn = (s - mean) * jax.lax.rsqrt(var + eps)
-    return (s.astype(x2d.dtype),
-            (yn * gamma + beta).astype(x2d.dtype))
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def fused_add_layer_norm(x2d, y2d, gamma, beta, eps=1e-5, block_rows=None):
-    """Residual add + row layer norm over [rows, hidden]; returns
-    (sum, normalized) — the sum IS the residual stream, so callers that
-    need it downstream read the fused op's first output instead of
-    keeping a separate add.  An explicit `block_rows` skips the tuning
-    search (shard_map bodies pin deterministic per-shard blocks)."""
-    R, H = x2d.shape
-    if block_rows is None:
-        block_rows = _tuned(
-            "add_layer_norm", [x2d.shape], x2d.dtype,
-            _row_block_candidates(R),
-            {"block_rows": _row_block(R, 256)},
-            build=lambda p: (lambda x, y, g, b: _add_ln_call(
-                x, y, g, b, eps, p["block_rows"])),
-            arg_specs=[(x2d.shape, x2d.dtype)] * 2
-            + [(gamma.shape, gamma.dtype), (beta.shape, beta.dtype)],
-        )["block_rows"]
-    return _add_ln_call(x2d, y2d, gamma, beta, eps, block_rows)
-
-
-def _add_ln_vjp_fwd(x2d, y2d, gamma, beta, eps, block_rows):
-    return (fused_add_layer_norm(x2d, y2d, gamma, beta, eps, block_rows),
-            (x2d, y2d, gamma, beta))
-
-
-def _add_ln_vjp_bwd(eps, _block_rows, res, cts):
-    x2d, y2d, gamma, beta = res
-    _, vjp = jax.vjp(
-        lambda x, y, g, b: _add_ln_dense(x, y, g, b, eps),
-        x2d, y2d, gamma, beta)
-    return vjp(cts)
-
-
-fused_add_layer_norm.defvjp(_add_ln_vjp_fwd, _add_ln_vjp_bwd)

@@ -7,9 +7,10 @@ chunk and maintains a flash-style running logsumexp — memory O(T_local),
 compute overlapped with the rotation by XLA's async collective scheduling.
 
 The ring is a `lax.scan` (HLO size is O(1) in ring size, unlike an
-unrolled loop), and each chunk-vs-chunk piece runs through the Pallas
-flash kernel when FLAGS_use_pallas is on — so neither the per-chunk
-[T_local, T_local] score matrix nor the fwd residuals ever hit HBM.
+unrolled loop), and with use_flash=True each chunk-vs-chunk piece runs
+through the Pallas flash kernel (flash_attention_piece) — so neither the
+per-chunk [T_local, T_local] score matrix nor the fwd residuals ever hit
+HBM; by default the pieces are dense XLA.
 Differentiable end-to-end (scan + ppermute + custom-vjp flash piece).
 
 Use `ring_attention(...)` inside shard_map (see `ring_attention_sharded`
@@ -55,16 +56,8 @@ def _flash_piece_bhtd(q, k, v, causal, scale, window=0):
             lse.reshape(B, H, T))
 
 
-def _use_flash(t_local, flag=None):
-    if flag is None:
-        from ..ops.pallas_kernels import use_pallas
-
-        flag = use_pallas()
-    return flag and t_local >= 8 and t_local % 8 == 0
-
-
 def ring_attention(q, k, v, axis_name, causal=False, scale=None,
-                   use_flash=None, window=0):
+                   use_flash=False, window=0):
     """Per-shard attention with K/V ring rotation.
 
     q, k, v: local chunks [B, H, T_local, D]; global sequence is the
@@ -89,7 +82,7 @@ def ring_attention(q, k, v, axis_name, causal=False, scale=None,
     if scale is None:
         scale = q.shape[-1] ** -0.5
     scale = float(scale)
-    flash = _use_flash(t_local, use_flash)
+    flash = use_flash and t_local >= 8 and t_local % 8 == 0
     q_pos = my * t_local + jnp.arange(t_local)  # global positions of local q
     # device-varying types for anything a cond/scan branch must produce
     from .mesh import pcast_varying, vma_of
@@ -179,7 +172,7 @@ def ring_attention(q, k, v, axis_name, causal=False, scale=None,
 
 
 def ring_attention_sharded(q, k, v, mesh, axis_name="sp", causal=False,
-                           use_flash=None, window=0):
+                           use_flash=False, window=0):
     """Convenience wrapper: shard q/k/v over `axis_name` on the time dim and
     run ring_attention under shard_map.  q,k,v: [B, H, T, D] global."""
     from .mesh import shard_map

@@ -2,9 +2,7 @@
 PROGRAM knob space per (program-signature, shape-bucket), with a
 persisted decision cache.
 
-PR 11's ``ops/kernel_tuning.py`` made every pallas_call's block sizes a
-searched, cached decision; this module lifts the same discipline one
-level up, to knobs that select between whole PROGRAMS:
+The knobs select between whole PROGRAMS:
 
 * ``mesh_shape``        — (dp, mp) or (dp, mp, pp) training mesh, None
                           = no mesh (a rebuild knob: the builder stamps
@@ -24,11 +22,6 @@ level up, to knobs that select between whole PROGRAMS:
                           reason; FLAGS_hbm_budget_bytes forces it
                           outside the tuner when memory, not time, is
                           the binding constraint)
-* ``use_pallas``        — kernel-layer dispatch on/off; searched on a
-                          real accelerator only (interpret-mode timings
-                          are noise), and each timed candidate consults
-                          the PR 11 kernel tuning cache for its block
-                          sizes — the two cache layers compose
 * ``steps_per_dispatch``— K steps per device dispatch via
                           Executor.run_loop's compiled lax.scan (the
                           host-dispatch-tax knob; applies to
@@ -63,8 +56,7 @@ kept before moving on) bounded by ``max_trials`` timings; each timing
 jits the candidate program on synthetic operands and measures
 steady-state steps/s.  Decisions persist as JSON at
 ``FLAGS_program_tune_cache`` keyed (signature | feed shape-bucket |
-device kind) with the exact bucketing discipline of
-FLAGS_kernel_tune_cache (pow2 leading dims, exact feature dims), and
+device kind; pow2 leading dims, exact feature dims), and
 ``FLAGS_program_autotune=0`` is the CI regime: consult-only, misses
 return the all-defaults decision and never time anything.
 
@@ -97,7 +89,6 @@ DEFAULT_DECISION = {
     #                              (dp-only sharding via the batch feeds)
     "bf16_amp": False,
     "remat": 0,
-    "use_pallas": None,          # None = inherit FLAGS_use_pallas
     "steps_per_dispatch": 1,
     "comm_bucket_bytes": None,   # consult-only knob
     # consult-only SERVING knobs (ServingEngine fast path): deposited by
@@ -113,11 +104,10 @@ DEFAULT_DECISION = {
     "n_microbatches": None,      # None = pipeline default (M == S)
 }
 
-# search order: rebuild knobs first (they change the op mix every later
-# flag knob runs under) — the mesh before the rewrites that must compose
-# with it — dispatch-schedule last
+# search order: rebuild knobs first (they change the op mix) — the mesh
+# before the rewrites that must compose with it — dispatch-schedule last
 _KNOB_ORDER = ("mesh_shape", "rule_table", "bf16_amp", "remat",
-               "use_pallas", "steps_per_dispatch")
+               "steps_per_dispatch")
 
 _lock = threading.RLock()
 _cache = None
@@ -149,11 +139,36 @@ def program_signature(program):
     return h.hexdigest()[:16]
 
 
-def _key(program, feed_spec):
-    from ..ops.kernel_tuning import _device_kind, shape_bucket
+def _device_kind():
+    """Stable device identity for cache keys; CPU runs are their own
+    universe so a CI cache never leaks onto a real chip."""
+    import jax
 
+    try:
+        d = jax.devices()[0]
+    except RuntimeError:
+        return "unknown"
+    if d.platform != "tpu":
+        return "interpret-%s" % d.platform
+    return (getattr(d, "device_kind", "") or d.platform).replace(" ", "_")
+
+
+def _shape_bucket(shapes):
+    """Canonical bucket string: leading (row/batch) dims round up to the
+    next power of two — one searched entry serves every batch in the
+    bucket — while the last (feature) dim of each operand stays exact."""
+    def pow2(n):
+        return 1 << max(0, int(n) - 1).bit_length()
+
+    return ",".join(
+        "x".join([str(pow2(d)) for d in shape[:-1]]
+                 + [str(int(d)) for d in shape[-1:]])
+        for shape in shapes)
+
+
+def _key(program, feed_spec):
     shapes = [shape for _, (shape, _dtype) in sorted(feed_spec.items())]
-    return "|".join([program_signature(program), shape_bucket(shapes),
+    return "|".join([program_signature(program), _shape_bucket(shapes),
                      _device_kind()])
 
 
@@ -174,8 +189,7 @@ def _load_locked():
 
 def _save_locked():
     # searched decisions only, merged with concurrent writers, atomic
-    # replace — the shared utils.tune_cache discipline kernel_tuning
-    # established
+    # replace (utils.tune_cache)
     from ..utils.tune_cache import save_entries
 
     save_entries(_cache_path, _cache, _entry_valid,
@@ -202,11 +216,8 @@ def tuned_flags(decision):
     """The FLAGS_* mapping a driver applies before running the tuned
     program (flag knobs only; rebuild knobs are baked into the program
     the ``variants`` callback returned, and steps_per_dispatch is the
-    driver's run()/run_loop() choice)."""
-    out = {}
-    if decision.get("use_pallas") is not None:
-        out["use_pallas"] = bool(decision["use_pallas"])
-    return out
+    driver's run()/run_loop() choice).  No knob is a flag today."""
+    return {}
 
 
 def serving_knobs(decision):
@@ -273,10 +284,6 @@ def _candidates_for(knob, rebuild, program, best=None):
             return []
         n = max(0, len(detect_segments(program)) - 1)
         return [0, n] if n else []
-    if knob == "use_pallas":
-        from ..ops.pallas_kernels import _interpret
-
-        return [] if _interpret() else [False, True]
     if knob == "steps_per_dispatch":
         return [1, 8]
     return []
@@ -284,13 +291,12 @@ def _candidates_for(knob, rebuild, program, best=None):
 
 def _measure_decision(decision, program, startup, feed_spec, fetches,
                       rebuild, steps, warmup, seed):
-    """steps/s of one candidate: (re)build under the rebuild knobs, set
-    the flag knobs, jit on synthetic operands, time steady state."""
+    """steps/s of one candidate: (re)build under the rebuild knobs, jit
+    on synthetic operands, time steady state."""
     import jax
 
     from .. import executor as executor_mod
     from ..core import scope as scope_mod
-    from ..flags import get_flag, set_flags
     from ..places import default_place
 
     main, startup_p, fetch_list = program, startup, fetches
@@ -300,42 +306,37 @@ def _measure_decision(decision, program, startup, feed_spec, fetches,
                                 or decision.get("rule_table",
                                                 "family") != "family"):
         main, startup_p, fetch_list = rebuild(decision)
-    saved = get_flag("use_pallas")
-    set_flags(tuned_flags(decision))
-    try:
-        scope = scope_mod.Scope()
-        with scope_mod.scope_guard(scope):
-            exe = executor_mod.Executor(default_place())
-            if startup_p is not None:
-                startup_p.random_seed = 1234
-                exe.run(startup_p, scope=scope)
-            feeds = _synthesize_feeds(feed_spec, seed)
-            window = int(decision.get("steps_per_dispatch", 1) or 1)
-            if window > 1:
-                out = exe.run_loop(window, main, feed=feeds,
-                                   fetch_list=fetch_list,
-                                   scope=scope, return_numpy=False)
-                jax.block_until_ready(out)
-                t0 = time.perf_counter()
-                out = exe.run_loop(window, main, feed=feeds,
-                                   fetch_list=fetch_list,
-                                   scope=scope, return_numpy=False)
-                jax.block_until_ready(out)
-                return window / (time.perf_counter() - t0)
-            out = None
-            for _ in range(max(1, warmup)):  # >= 1: the first run is
-                # the compile; timing it would measure XLA, not the step
-                out = exe.run(main, feed=feeds, fetch_list=fetch_list,
-                              scope=scope, return_numpy=False)
+    scope = scope_mod.Scope()
+    with scope_mod.scope_guard(scope):
+        exe = executor_mod.Executor(default_place())
+        if startup_p is not None:
+            startup_p.random_seed = 1234
+            exe.run(startup_p, scope=scope)
+        feeds = _synthesize_feeds(feed_spec, seed)
+        window = int(decision.get("steps_per_dispatch", 1) or 1)
+        if window > 1:
+            out = exe.run_loop(window, main, feed=feeds,
+                               fetch_list=fetch_list,
+                               scope=scope, return_numpy=False)
             jax.block_until_ready(out)
             t0 = time.perf_counter()
-            for _ in range(steps):
-                out = exe.run(main, feed=feeds, fetch_list=fetch_list,
-                              scope=scope, return_numpy=False)
+            out = exe.run_loop(window, main, feed=feeds,
+                               fetch_list=fetch_list,
+                               scope=scope, return_numpy=False)
             jax.block_until_ready(out)
-            return steps / (time.perf_counter() - t0)
-    finally:
-        set_flags({"use_pallas": saved})
+            return window / (time.perf_counter() - t0)
+        out = None
+        for _ in range(max(1, warmup)):  # >= 1: the first run is
+            # the compile; timing it would measure XLA, not the step
+            out = exe.run(main, feed=feeds, fetch_list=fetch_list,
+                          scope=scope, return_numpy=False)
+        jax.block_until_ready(out)
+        t0 = time.perf_counter()
+        for _ in range(steps):
+            out = exe.run(main, feed=feeds, fetch_list=fetch_list,
+                          scope=scope, return_numpy=False)
+        jax.block_until_ready(out)
+        return steps / (time.perf_counter() - t0)
 
 
 def tune(program, feed_spec, startup=None, fetches=None, rebuild=None,
@@ -394,10 +395,7 @@ def tune(program, feed_spec, startup=None, fetches=None, rebuild=None,
                 if trials >= max_trials:
                     break
                 for cand in _candidates_for(knob, rebuild, program, best):
-                    if cand == best.get(knob) or (
-                            knob == "use_pallas"
-                            and best.get(knob) is None
-                            and cand == bool(_flag("use_pallas"))):
+                    if cand == best.get(knob):
                         continue  # already measured as part of `best`
                     if trials >= max_trials:
                         break

@@ -19,8 +19,8 @@ import paddle_tpu.framework as _fw
 from .pass_registry import OpPattern, Pass, register_pass
 
 _ACTS = ("relu", "tanh", "sigmoid")
-# fc epilogue activations: the fc lowering's matmul-epilogue kernel set
-# (pallas_kernels._MM_ACTS).  gelu fuses only in its exact-erf default
+# fc epilogue activations: the fc lowering's table (nn_ops._mm_act).
+# gelu fuses only in its exact-erf default
 # form and swish only at beta=1 — _act_fusable checks the attrs.
 _FC_ACTS = ("relu", "tanh", "sigmoid", "gelu", "swish")
 
@@ -572,10 +572,9 @@ def _consumers_all_blocks(program, name, exclude=()):
 class SwigluFusePass(Pass):
     """mul(x, Wg) -> swish  alongside  mul(x, Wu), joined by
     elementwise_mul  =>  ONE fused_swiglu op (the gpt2 use_swiglu FFN
-    diamond).  The fused lowering runs both projections of a row tile
-    and the gate product against ONE resident x tile
-    (pallas_kernels.matmul_swiglu under FLAGS_use_pallas), so the gate
-    and up pre-activations never reach HBM.  Conservative: beta-1
+    diamond).  The fused op lowers to two f32-accumulated matmuls and
+    the gate product (nn_ops._swiglu_dense), which XLA fuses.
+    Conservative: beta-1
     swish, same x input and flatten dims on both muls, 2-D same-shape
     weights, single-consumer intermediates (checked across ALL blocks),
     protected fetches respected."""
@@ -668,8 +667,8 @@ class SwigluFusePass(Pass):
 @register_pass("residual_ln_fuse_pass")
 class ResidualLnFusePass(Pass):
     """elementwise_add(x, y) -> layer_norm  =>  ONE fused_residual_ln op
-    whose lowering forms the sum as the LN kernel's PROLOGUE
-    (pallas_kernels.fused_add_layer_norm under FLAGS_use_pallas).  The
+    whose lowering forms the sum and normalizes it in f32
+    (nn_ops._add_ln_dense).  The
     SUM stays a real output under its original name, AND the fused op
     lands at the ADD's position — so every other consumer of the sum
     (gpt2: the add feeds BOTH the norm and the next residual add) reads
@@ -844,8 +843,7 @@ class LinearXentFusePass(Pass):
 def _matmul_epilogue_fuse(program, scope):
     """The training-program epilogue bundle (ROADMAP item 1): fc
     (mul+bias+act), SwiGLU diamonds, and residual-add+layer_norm pairs
-    collapse into their fused ops so the model builders get the pallas
-    matmul-epilogue kernels without model edits.  Apply BEFORE
+    collapse into their fused ops without model edits.  Apply BEFORE
     Optimizer.minimize (grad ops must differentiate through the fused
     ops) and before any AMP rewrite."""
     from .pass_registry import apply_pass

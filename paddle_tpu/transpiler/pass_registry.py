@@ -307,10 +307,11 @@ class AttentionFusePass(Pass):
           -> softmax [-> dropout(is_test)]
           -> matmul(weights, V)
 
-    becomes ONE fused_attention op — flash kernel under FLAGS_use_pallas,
-    fused XLA otherwise.  Conservative conditions: single-consumer chain
-    (the matcher guarantees it), Q rank-4 [B, H, Tq, Dh], bias with key
-    axis only (shape [..., 1, Tk]), softmax over the default last axis,
+    becomes ONE fused_attention op — the flash kernel where platform and
+    shape say so (nn_ops._flash_engages), fused XLA otherwise.
+    Conservative conditions: single-consumer chain (the matcher
+    guarantees it), Q rank-4 [B, H, Tq, Dh], bias with key axis only
+    (shape [..., 1, Tk]), softmax over the default last axis,
     inference-mode dropout only.
     """
 

@@ -1,10 +1,7 @@
-"""Shared persistence for tuning decision caches.
+"""Persistence for a tuning decision cache.
 
-Both decision caches — the per-kernel block-size cache
-(``ops/kernel_tuning.py``) and the per-program knob cache
-(``transpiler/autotune.py``) — persist as the same JSON shape
-(``{"version": 1, "entries": {key: entry}}``) under the same
-discipline:
+The per-program knob cache (``transpiler/autotune.py``) persists as JSON
+(``{"version": 1, "entries": {key: entry}}``) under this discipline:
 
 * load tolerates a missing/corrupt file with a loud warning (never an
   exception at consult time) and drops malformed entries;
@@ -14,10 +11,6 @@ discipline:
   concurrent processes sharing one path don't drop each other's
   searched keys (ours still override), and lands atomically via
   ``os.replace``.
-
-One implementation keeps the two caches' formats and merge semantics
-from drifting (the PR 11 round-2 "searched entries only" fix had to be
-learned once; it must not need re-learning per cache).
 """
 
 import json

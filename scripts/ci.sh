@@ -120,20 +120,15 @@ launch_accepts"
 python -m pytest tests/test_dist_transpiler.py -q -m "" \
     -k "stable_shards or elastic_pserver_program"
 
-echo "== pallas kernel pass (FLAGS_use_pallas=1, interpret mode) =="
-# the primitive-kernel layer end to end on the CPU mesh: every kernel's
-# interpret-mode numerics vs its dense reference (matmul-epilogue,
-# swiglu, residual-LN, softmax xent, vector-qstart flash), the
-# fuse-pass rewrites, the tuning-cache contract, and the serving
-# churn-exactness suite with the ragged step's flash kernel live.
-# FLAGS_kernel_autotune=0 + the committed pinned cache mean CI NEVER
-# searches block sizes (interpret timings would be noise anyway);
-# consult-only misses seed the deterministic defaults.
-FLAGS_use_pallas=1 FLAGS_kernel_autotune=0 \
-FLAGS_kernel_tune_cache=tests/data/ci_tuning_cache.json \
-    python -m pytest tests/test_pallas_kernels.py \
-    tests/test_kernel_tuning.py tests/test_fuse_passes.py \
-    tests/test_serving.py -q -m ""
+echo "== kernel pass (interpret mode) =="
+# the kernels that a step can hold, on the CPU mesh: the flash kernels'
+# interpret-mode numerics vs their dense reference and their
+# cross-lowering for the TPU, the fused ops' one lowering against numpy,
+# the fuse-pass rewrites and the attribution counters (-m "" adds the
+# slow-marked cases; tests/test_serving.py rides the serving pass below)
+python -m pytest tests/test_pallas_kernels.py \
+    tests/test_dense_lowerings.py tests/test_kernel_tuning.py \
+    tests/test_fuse_passes.py -q -m ""
 # the chip smoke's rehearsal: the command a chip run sends, at tiny
 # widths with the kernels interpreted (every phase must pass; it never
 # prints the pass line)
@@ -157,17 +152,9 @@ echo "== sharded-serving lane (2-device GSPMD tensor-parallel mesh) =="
 # partition-rule resolution (precedence / guards / logged replicate
 # fallback) and the sharded engine holding BOTH PR 9 contracts — churn
 # exactness + zero retraces — through the GSPMD executor path, with the
-# full serving exactness suite riding the same 2-device topology.  Both
-# attention variants run: dense XLA (use_pallas=0) and the
-# flash_attention_qvec kernel under shard_map (use_pallas=1, interpret
-# mode, pinned tuning cache — CI never searches block sizes).
+# full serving exactness suite riding the same 2-device topology (the
+# ragged step's attention is the sharding-constrained dense einsum).
 XLA_FLAGS="--xla_force_host_platform_device_count=2" \
-FLAGS_use_pallas=0 \
-    python -m pytest tests/test_serving_tp.py tests/test_serving.py \
-    -q -m ""
-XLA_FLAGS="--xla_force_host_platform_device_count=2" \
-FLAGS_use_pallas=1 FLAGS_kernel_autotune=0 \
-FLAGS_kernel_tune_cache=tests/data/ci_tuning_cache.json \
     python -m pytest tests/test_serving_tp.py tests/test_serving.py \
     -q -m ""
 
@@ -177,12 +164,8 @@ echo "== spmd-training lane (4-device GSPMD dp x mp mesh) =="
 # param — ZeRO-style state, provably sharded by per-device bytes),
 # mp=1 bit-exactness vs the unstamped program, mp=2 rtol parity across
 # all three mesh shapes, the remat / bf16-AMP compose legs, comm-stats
-# reporting, and the shard_map-wrapped epilogue kernels dispatching
-# inside the sharded step (interpret mode, pinned tuning cache — CI
-# never searches block sizes)
+# reporting, and the sharded step's cross-lowering for the TPU
 XLA_FLAGS="--xla_force_host_platform_device_count=4" \
-FLAGS_kernel_autotune=0 \
-FLAGS_kernel_tune_cache=tests/data/ci_tuning_cache.json \
     python -m pytest tests/test_spmd_training.py -q -m ""
 
 echo "== pipeline-parallel lane (4-device dp x mp x pp mesh) =="
@@ -237,17 +220,10 @@ echo "== speculative + prefix serving pass (decode/prefill fast path) =="
 # compile-count pin across occupancy with the draft program live,
 # prefix-hit streams bit-identical to cold with the prefill-chunk
 # saving asserted, spec+prefix composed, and the consult-only autotune
-# knobs.  The same subset then re-runs under FLAGS_use_pallas=1 so the
-# vector-qstart flash kernel verifies width-k anchor+draft chunks and
-# prefix-resumed prefill offsets (interpret mode, pinned tuning cache
-# — CI never searches block sizes).  The process-mode spec+prefix
+# knobs.  The process-mode spec+prefix
 # SIGKILL failover and prefix-aware placement legs ride the fabric
 # pass above (test_serving_fabric.py -m "").
 python -m pytest tests/test_serving.py -q -m "" \
-    -k "spec or prefix or row_copy"
-FLAGS_use_pallas=1 FLAGS_kernel_autotune=0 \
-FLAGS_kernel_tune_cache=tests/data/ci_tuning_cache.json \
-    python -m pytest tests/test_serving.py -q -m "" \
     -k "spec or prefix or row_copy"
 
 echo "== orphaned-child check =="

@@ -41,18 +41,3 @@ def fresh_programs():
     framework.switch_startup_program(old_startup)
     unique_name.switch(old_gen)
     scope_mod._switch_scope(old_scope)
-
-
-@pytest.fixture(autouse=True)
-def restore_use_pallas_flag():
-    """Flag-toggling tests must not leak their final use_pallas value
-    into the rest of the process: the ci.sh pallas pass arms
-    FLAGS_use_pallas=1 in the ENVIRONMENT for a whole multi-file pytest
-    run, and a test's hardcoded `set_flags({"use_pallas": False})`
-    cleanup would silently put every later test back on the dense
-    path — the exact coverage the pass exists for."""
-    from paddle_tpu import flags as _pflags
-
-    old = _pflags.get_flag("use_pallas")
-    yield
-    _pflags.set_flags({"use_pallas": old})

@@ -677,7 +677,7 @@ def test_chip_smoke_refuses_to_run_without_a_chip():
     assert '"ok"' not in proc.stdout
 
 
-@pytest.mark.slow  # full rehearsal (~40 s); rides the ci.sh pallas lane
+@pytest.mark.slow  # full rehearsal (~40 s); rides the ci.sh kernel pass
 def test_chip_smoke_rehearsal_runs_every_phase_and_never_passes():
     proc = _run_chip_smoke("--rehearse")
     assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-3000:]

@@ -570,7 +570,7 @@ def test_the_lowered_rope_scope_has_no_strided_slice_and_no_interior_pad(
 
     monkeypatch.setattr(pins.pk, "_interpret", lambda: False)
     jax.clear_caches()  # as the pins lower: the kernels for Mosaic
-    text = pins._lowered_step(*pins.PROGRAMS["kanana2"]).as_text(
+    text = pins._lowered_step(*pins.PROGRAMS["kanana2"]()).as_text(
         debug_info=True)
     jax.clear_caches()
     named = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))

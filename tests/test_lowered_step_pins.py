@@ -48,7 +48,20 @@ tiny kanana-2: latent attention at the flash kernel's (192, 128), the
 attribute set, a share of the experts held) was added, its digest taken
 from PR 43's tree by this file's `_digest`; it is NOT what the parent
 lowered (5974c9c2...: two gathers forward and two scatters backward a
-`rotary_embed`)."""
+`rotary_embed`).
+
+PR 44 took the lowering flag and the six kernels behind it away: `fc`,
+`fused_swiglu`, `fused_residual_ln`, `layer_norm` and
+`softmax_with_cross_entropy` are their dense bodies.  The nine digests above
+did not move.  Three programs whose ops that PR edited were added, their
+digests taken from its PARENT commit 6a7549d by this file's `_digest`:
+`transformer` (Transformer-base's shape at tiny widths: `fc` with bias and
+activation, `fused_residual_ln` (every layer norm of the post-LN stack is
+fused into one; `gpt2` above holds the one free-standing `layer_norm`);
+dense attention at 64, no Mosaic call), `resnet` (ResNet-50 on 32 x 32
+images; its loss is `softmax` + `cross_entropy`, so it holds none of the
+edited ops and is the control) and `ouro` (`fused_swiglu`): the one lowering
+left is the one the ledger measured."""
 
 import base64
 import hashlib
@@ -60,7 +73,8 @@ import pytest
 
 import paddle_tpu as fluid
 from paddle_tpu.core.trace import build_traced_function
-from paddle_tpu.models import gpt2, kanana2, lfm2, olmoe, trinity
+from paddle_tpu.models import (gpt2, kanana2, lfm2, olmoe, ouro, resnet,
+                               transformer, trinity)
 from paddle_tpu.ops import pallas_kernels as pk
 
 SEQ = 512
@@ -104,20 +118,77 @@ class K(kanana2.Kanana2Config):
     num_local_experts, expert_offset = 2, 2
 
 
+class U(ouro.OuroConfig):
+    vocab_size, hidden_size, intermediate_size = 512, 128, 256
+    num_hidden_layers, num_attention_heads, num_key_value_heads = 2, 2, 2
+    head_dim = 64
+
+
+class W(transformer.ModelHyperParams):
+    src_vocab_size = trg_vocab_size = 512
+    max_length, d_model, d_inner_hid, n_head, n_layer = 64, 128, 256, 2, 2
+    fused_attn = True
+
+
 def _trinity_program(hp, **kw):
     return trinity.trinity_lm_program(hp, bias_rate=0.03, bias_max_step=0.03,
                                       **kw)
 
 
-PROGRAMS = {"gpt2": (gpt2.gpt2_lm_program, G),
-            "olmoe": (olmoe.olmoe_lm_program, O),
-            "lfm2": (lfm2.lfm2_lm_program, L),
-            "trinity": (_trinity_program, T),
-            "kanana2": (kanana2.kanana2_lm_program, K)}
+def _shapes(batch):
+    """A host batch as the executor would upload it (jnp.asarray's dtypes)."""
+    return {n: jax.ShapeDtypeStruct(a.shape, jnp.asarray(a[:0]).dtype)
+            for n, a in batch.items()}
+
+
+def _lm(build, hp):
+    """(main, startup, loss name, feeds) of a causal LM builder's train
+    program at 2 x SEQ in bfloat16."""
+    main, startup, _, fetches = build(hp, seq_len=SEQ, lr=1e-3,
+                                      use_bf16=True)
+    return main, startup, fetches[0].name, {
+        "ids": jax.ShapeDtypeStruct((2, SEQ), jnp.int32),
+        "labels": jax.ShapeDtypeStruct((2, SEQ), jnp.int32),
+        "loss_weight": jax.ShapeDtypeStruct((2, SEQ), jnp.float32)}
+
+
+def _transformer():
+    """Transformer-base's program shape at tiny widths, 4 x 64 + 64 (dense
+    attention, as the cells run it at 64 and 256): `fc` with bias and
+    activation, `fused_residual_ln`."""
+    main, startup, _, fetches = transformer.wmt_transformer_program(
+        W, src_len=64, trg_len=64, use_bf16=True)
+    return main, startup, fetches[0].name, _shapes(
+        transformer.make_fake_batch(4, 64, 64, W))
+
+
+def _resnet():
+    """ResNet-50 on 8 x 3 x 32 x 32 over 10 classes, bfloat16: the
+    `resnet50_train` cell's rehearsal."""
+    main, startup, _, fetches = resnet.build_resnet_train_program(
+        image_shape=(3, 32, 32), class_dim=10, depth=50, lr=0.01,
+        use_bf16=True)
+    return main, startup, fetches[0].name, {
+        "image": jax.ShapeDtypeStruct((8, 3, 32, 32), jnp.float32),
+        "label": jax.ShapeDtypeStruct((8, 1), jnp.int32)}
+
+
+PROGRAMS = {"gpt2": lambda: _lm(gpt2.gpt2_lm_program, G),
+            "olmoe": lambda: _lm(olmoe.olmoe_lm_program, O),
+            "lfm2": lambda: _lm(lfm2.lfm2_lm_program, L),
+            "trinity": lambda: _lm(_trinity_program, T),
+            "kanana2": lambda: _lm(kanana2.kanana2_lm_program, K),
+            "ouro": lambda: _lm(ouro.ouro_lm_program, U),
+            "transformer": _transformer,
+            "resnet": _resnet}
 
 # name -> (sha1 of the normalised text, Mosaic calls in it) at ec9cdf7
-# (`lfm2`: at PR 39; `trinity`: at PR 40; `kanana2`: at PR 43)
+# (`lfm2`: at PR 39; `trinity`: at PR 40; `kanana2`: at PR 43; `transformer`,
+# `resnet`, `ouro`: at 6a7549d, PR 44's parent)
 BEFORE = {
+    "transformer": ("e83306d8f28e59df41bc44b9fe7303322f29a58b", 0),
+    "resnet": ("84575b13d140437bb64cb8461436105337774a6d", 0),
+    "ouro": ("b1722471731c977327d882d12eb8e5106bcca444", 3),
     "kanana2": ("442939de9116ac8230c03beb34b17b378ccc0476", 9),
     "trinity": ("12416b47e9d02155b186ce8f38c8ee118bdb5539", 12),
     "gpt2": ("df7ec28481d2c6f9e45f238963591867ce97786d", 3),
@@ -155,9 +226,7 @@ def _digest(text):
     return hashlib.sha1(normalised.encode()).hexdigest(), len(bodies)
 
 
-def _lowered_step(build, hp):
-    main, startup, _, fetches = build(hp, seq_len=SEQ, lr=1e-3,
-                                      use_bf16=True)
+def _lowered_step(main, startup, loss, feeds):
     scope = fluid.Scope()
     for block in (main.global_block(), startup.global_block()):
         for name, var in block.vars.items():
@@ -165,12 +234,8 @@ def _lowered_step(build, hp):
                 scope.set(name, jax.ShapeDtypeStruct(
                     tuple(int(d) for d in var.shape),
                     jnp.dtype(str(var.dtype))))
-    feeds = {"ids": jax.ShapeDtypeStruct((2, SEQ), jnp.int32),
-             "labels": jax.ShapeDtypeStruct((2, SEQ), jnp.int32),
-             "loss_weight": jax.ShapeDtypeStruct((2, SEQ), jnp.float32)}
     traced = build_traced_function(
-        main, 0, tuple(sorted(feeds)), [fetches[0].name], scope,
-        platform="tpu")
+        main, 0, tuple(sorted(feeds)), [loss], scope, platform="tpu")
 
     def shaped(n):
         v = scope.find_var(n)
@@ -200,6 +265,6 @@ def test_the_lowered_step_is_what_it_was_before_pr_37(monkeypatch, name):
     monkeypatch.setattr(pk, "_interpret", lambda: False)
     jax.clear_caches()  # an interpreted trace of these shapes would hide
     text = (_core_text(*CORES[name]) if name in CORES
-            else _lowered_step(*PROGRAMS[name]).as_text())
+            else _lowered_step(*PROGRAMS[name]()).as_text())
     assert _digest(text) == BEFORE[name]
     jax.clear_caches()  # and these would hide from a later interpreted one

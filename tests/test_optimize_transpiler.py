@@ -556,8 +556,6 @@ def test_autotune_search_cache_and_consult_only(tmp_path):
         # tuner has no flag to set for it
         assert "prng_impl" not in d and "prng_impl" not in at._KNOB_ORDER
         assert at.tuned_flags(d) == {}
-        assert at.tuned_flags(dict(d, use_pallas=True)) == {
-            "use_pallas": True}
         # hit path: no measurer needed
         d2 = at.tune(main, spec)
         assert d2 == d
@@ -619,6 +617,26 @@ def test_ci_pinned_program_tune_cache_consults_without_search():
         at.clear_cache(forget_path=True)
 
 
+def test_autotune_key_buckets_leading_dims_and_names_the_device():
+    """The decision key: leading (row/batch) dims bucket to the next
+    power of two, the last dim stays exact, operands join in order; a
+    CPU run's device kind is its own universe, so a CI cache can never
+    land on a chip."""
+    from paddle_tpu.transpiler import autotune as at
+
+    assert at._shape_bucket([(100, 768)]) == "128x768"
+    assert at._shape_bucket([(128, 768)]) == "128x768"
+    assert at._shape_bucket([(3, 5, 96)]) == "4x8x96"
+    assert at._shape_bucket([(7,)]) == "7"
+    assert at._shape_bucket([(100, 64), (64, 50)]) == "128x64,64x50"
+    assert at._device_kind().startswith("interpret-")
+    main, _ = _mini_program()
+    assert (at._key(main, {"at_x": ((100, 4), "float32")})
+            == at._key(main, {"at_x": ((128, 4), "float32")}))
+    assert (at._key(main, {"at_x": ((100, 4), "float32")})
+            != at._key(main, {"at_x": ((129, 4), "float32")}))
+
+
 def test_autotune_signature_stable_and_value_insensitive():
     from paddle_tpu.transpiler.autotune import program_signature
 
@@ -664,8 +682,8 @@ def test_autotuned_window_matches_per_step_trajectory():
 def test_decode_and_ragged_builders_get_epilogue_fusions():
     """PR 11's 'epilogue passes rewrite training programs only' limit is
     closed: the classic decode step AND the continuous-batching ragged
-    step carry fused fc / residual-LN ops (the churn-exactness suite
-    under FLAGS_use_pallas=1 guards the numerics)."""
+    step carry fused fc / residual-LN ops (tests/test_serving.py's
+    pooled == solo churn tests guard the numerics)."""
     from paddle_tpu.models import gpt2
 
     class HP(gpt2.GPT2Config):

@@ -1,6 +1,7 @@
-"""Pallas kernel library: flash attention + fused layer_norm vs dense XLA
-references (forward and gradients), and the FLAGS_use_pallas op dispatch.
-Runs in interpreter mode on the CPU mesh; the same kernels compile on TPU."""
+"""Pallas kernel library: flash attention vs dense XLA references (forward
+and gradients), the tiled vocabulary head, and the cross-lowering of every
+kernel for the TPU.  Runs in interpreter mode on the CPU mesh; the same
+kernels compile on TPU."""
 
 import numpy as np
 import jax
@@ -8,12 +9,8 @@ import jax.numpy as jnp
 import pytest
 
 import paddle_tpu as fluid
-from paddle_tpu import flags, layers
-from paddle_tpu.ops.pallas_kernels import (
-    _dense_attention,
-    flash_attention,
-    fused_layer_norm,
-)
+from paddle_tpu import layers
+from paddle_tpu.ops.pallas_kernels import _dense_attention, flash_attention
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -61,28 +58,6 @@ def test_flash_attention_grads_match_dense():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-3, atol=1e-4)
 
 
-def test_fused_layer_norm_matches_and_grads():
-    rng = np.random.RandomState(2)
-    x = jnp.asarray(rng.randn(24, 64).astype("float32"))
-    g = jnp.asarray(rng.rand(64).astype("float32") + 0.5)
-    b = jnp.asarray(rng.randn(64).astype("float32"))
-
-    out = fused_layer_norm(x, g, b, 1e-5)
-    mean = np.mean(np.asarray(x), -1, keepdims=True)
-    var = np.var(np.asarray(x), -1, keepdims=True)
-    ref = (np.asarray(x) - mean) / np.sqrt(var + 1e-5) * np.asarray(g) + np.asarray(b)
-    np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-4, atol=2e-5)
-
-    gx = jax.grad(lambda x: jnp.sum(fused_layer_norm(x, g, b, 1e-5) ** 2))(x)
-    gx_ref = jax.grad(
-        lambda x: jnp.sum(
-            ((x - jnp.mean(x, -1, keepdims=True))
-             * jax.lax.rsqrt(jnp.var(x, -1, keepdims=True) + 1e-5) * g + b) ** 2
-        )
-    )(x)
-    np.testing.assert_allclose(np.asarray(gx), np.asarray(gx_ref), rtol=1e-3, atol=1e-4)
-
-
 def test_fused_attention_op_dispatch_and_training(monkeypatch):
     """The fused_attention layer trains identically through the blockwise
     kernel and the dense lowering.  The training path reads no flag: the
@@ -120,98 +95,6 @@ def test_fused_attention_op_dispatch_and_training(monkeypatch):
     pallas = run(True)
     assert kt.attribution()["pallas_hits"]["attention"] > before
     np.testing.assert_allclose(pallas, plain, rtol=1e-4)
-
-
-def test_layer_norm_pallas_dispatch_matches():
-    rng = np.random.RandomState(4)
-    xv = rng.rand(6, 64).astype("float32")
-
-    def run(use_pallas):
-        import paddle_tpu.framework as fw
-        from paddle_tpu.core import scope as scope_mod
-        from paddle_tpu import unique_name
-
-        fw.switch_main_program(fluid.Program())
-        fw.switch_startup_program(fluid.Program())
-        unique_name.switch()
-        scope_mod._switch_scope(scope_mod.Scope())
-
-        x = layers.data("x", shape=[64])
-        y = layers.layer_norm(x, begin_norm_axis=1)
-        flags.set_flags({"use_pallas": use_pallas})
-        try:
-            exe = fluid.Executor(fluid.CPUPlace())
-            exe.run(fluid.default_startup_program())
-            (out,) = exe.run(feed={"x": xv}, fetch_list=[y])
-        finally:
-            flags.set_flags({"use_pallas": False})
-        return np.asarray(out)
-
-    np.testing.assert_allclose(run(True), run(False), rtol=2e-4, atol=2e-5)
-
-
-def test_fused_softmax_xent_matches_dense():
-    import jax
-    import jax.numpy as jnp
-    from paddle_tpu.ops.pallas_kernels import fused_softmax_xent
-
-    R, C = 16, 10
-    rng = np.random.RandomState(1)
-    logits = jnp.asarray(rng.randn(R, C).astype("float32"))
-    labels = jnp.asarray(rng.randint(0, C, (R,)).astype("int32"))
-    out = fused_softmax_xent(logits, labels)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    ref = -jnp.take_along_axis(logp, labels[:, None].astype(jnp.int32), 1)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=1e-5, atol=1e-5)
-
-    g = jax.grad(lambda l: jnp.sum(fused_softmax_xent(l, labels)))(logits)
-    rg = jax.grad(lambda l: jnp.sum(
-        -jnp.take_along_axis(jax.nn.log_softmax(l, -1),
-                             labels[:, None].astype(jnp.int32), 1)))(logits)
-    np.testing.assert_allclose(np.asarray(g), np.asarray(rg), rtol=1e-4,
-                               atol=1e-5)
-
-
-def test_use_pallas_flag_dispatches_softmax_xent():
-    """FLAGS_use_pallas routes softmax_with_cross_entropy to the fused
-    kernel with unchanged results (kernel-override contract)."""
-    from paddle_tpu.flags import set_flags
-
-    B, C = 2, 12
-    rng = np.random.RandomState(2)
-    lg = rng.randn(B, C).astype("float32")
-    lb = rng.randint(0, C, (B, 1)).astype("int64")
-
-    def run():
-        prog = fluid.Program()
-        startup = fluid.Program()
-        with fluid.framework.program_guard(prog, startup):
-            blk = prog.global_block()
-            for n, a in [("plg", lg), ("plb", lb)]:
-                blk.create_var(name=n, shape=a.shape, dtype=str(a.dtype),
-                               is_data=True)
-            sm = blk.create_var(name="psm", dtype="float32", shape=None)
-            ls = blk.create_var(name="pls", dtype="float32", shape=None)
-            blk.append_op(
-                "softmax_with_cross_entropy",
-                inputs={"Logits": ["plg"], "Label": ["plb"]},
-                outputs={"Softmax": [sm], "Loss": [ls]},
-            )
-        exe = fluid.Executor(fluid.CPUPlace())
-        with fluid.scope_guard(fluid.Scope()):
-            return exe.run(prog, feed={"plg": lg, "plb": lb},
-                           fetch_list=[ls])
-
-    set_flags({"use_pallas": False})
-    plain = run()
-    set_flags({"use_pallas": True})
-    try:
-        fused = run()
-    finally:
-        set_flags({"use_pallas": False})
-    np.testing.assert_allclose(np.asarray(plain[0]), np.asarray(fused[0]),
-                               rtol=1e-4, atol=1e-5)
 
 
 def test_flash_attention_bf16_inputs():
@@ -571,153 +454,6 @@ def test_flash_qoff_undefined_rows_zero_grads():
 # ---------------------------------------------------------------------------
 # matmul-epilogue kernels (PR 11 primitive-kernel layer)
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("act", ["", "relu", "tanh", "sigmoid", "gelu",
-                                 "swish"])
-def test_matmul_bias_act_matches_dense(act):
-    from paddle_tpu.ops.pallas_kernels import _mm_dense, matmul_bias_act
-
-    rng = np.random.RandomState(20)
-    x = jnp.asarray(rng.randn(24, 40).astype("float32"))
-    w = jnp.asarray(rng.randn(40, 48).astype("float32") * 0.2)
-    b = jnp.asarray(rng.randn(48).astype("float32"))
-    out = matmul_bias_act(x, w, b, act, 8, 48)
-    ref = _mm_dense(x, w, b, act)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=1e-6, atol=1e-6)
-    # no-bias form
-    out_nb = matmul_bias_act(x, w, None, act, 8, 48)
-    ref_nb = _mm_dense(x, w, None, act)
-    np.testing.assert_allclose(np.asarray(out_nb), np.asarray(ref_nb),
-                               rtol=1e-6, atol=1e-6)
-
-
-def test_matmul_bias_act_odd_shapes_and_bf16():
-    """Odd row counts (block_rows falls back to 1) and bf16 inputs with
-    f32 accumulation."""
-    from paddle_tpu.ops.pallas_kernels import _mm_dense, matmul_bias_act
-
-    rng = np.random.RandomState(21)
-    x = jnp.asarray(rng.randn(7, 12).astype("float32"))  # 7 % 8 != 0
-    w = jnp.asarray(rng.randn(12, 20).astype("float32") * 0.3)
-    b = jnp.asarray(rng.randn(20).astype("float32"))
-    out = matmul_bias_act(x, w, b, "gelu", 1, 20)
-    ref = _mm_dense(x, w, b, "gelu")
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=1e-6, atol=1e-6)
-
-    xb = jnp.asarray(rng.randn(16, 24).astype("float32")).astype(
-        jnp.bfloat16)
-    wb = jnp.asarray((rng.randn(24, 16) * 0.3).astype("float32")).astype(
-        jnp.bfloat16)
-    bb = jnp.asarray(rng.randn(16).astype("float32")).astype(jnp.bfloat16)
-    out = matmul_bias_act(xb, wb, bb, "swish", 8, 16)
-    assert out.dtype == jnp.bfloat16
-    ref = _mm_dense(xb, wb, bb, "swish")
-    np.testing.assert_allclose(
-        np.asarray(out, dtype=np.float32), np.asarray(ref, np.float32),
-        rtol=3e-2, atol=3e-2)
-
-
-def test_matmul_bias_act_grads_match_dense():
-    from paddle_tpu.ops.pallas_kernels import _mm_dense, matmul_bias_act
-
-    rng = np.random.RandomState(22)
-    x = jnp.asarray(rng.randn(16, 24).astype("float32"))
-    w = jnp.asarray(rng.randn(24, 32).astype("float32") * 0.2)
-    b = jnp.asarray(rng.randn(32).astype("float32"))
-    gf = jax.grad(lambda x, w, b: jnp.sum(
-        matmul_bias_act(x, w, b, "gelu", 8, 32) ** 2),
-        argnums=(0, 1, 2))(x, w, b)
-    gd = jax.grad(lambda x, w, b: jnp.sum(
-        _mm_dense(x, w, b, "gelu") ** 2), argnums=(0, 1, 2))(x, w, b)
-    for a, r in zip(gf, gd):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(r),
-                                   rtol=1e-5, atol=1e-5)
-
-
-def test_matmul_swiglu_matches_dense_and_grads():
-    from paddle_tpu.ops.pallas_kernels import _swiglu_dense, matmul_swiglu
-
-    rng = np.random.RandomState(23)
-    x = jnp.asarray(rng.randn(24, 20).astype("float32"))
-    wg = jnp.asarray(rng.randn(20, 16).astype("float32") * 0.3)
-    wu = jnp.asarray(rng.randn(20, 16).astype("float32") * 0.3)
-    out = matmul_swiglu(x, wg, wu, 8, 16)
-    ref = _swiglu_dense(x, wg, wu)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=1e-6, atol=1e-6)
-    gf = jax.grad(lambda x, g, u: jnp.sum(
-        matmul_swiglu(x, g, u, 8, 16) ** 2), argnums=(0, 1, 2))(x, wg, wu)
-    gd = jax.grad(lambda x, g, u: jnp.sum(
-        _swiglu_dense(x, g, u) ** 2), argnums=(0, 1, 2))(x, wg, wu)
-    for a, r in zip(gf, gd):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(r),
-                                   rtol=1e-5, atol=1e-5)
-
-
-def test_swiglu_tuning_measures_the_swiglu_kernel(monkeypatch):
-    """Regression (review finding): the tuning consult for matmul_swiglu
-    must hand the measurer the ACTUAL two-dot-plus-gate kernel (three
-    operands), not a plain single-matmul stand-in — a candidate ranked
-    on half the per-tile weight traffic can be the loser for the real
-    kernel, and that wrong choice would persist in the cache."""
-    from paddle_tpu.ops import pallas_kernels as pk
-
-    seen = {}
-    real_tuned = pk._tuned
-
-    def spy(kernel, shapes, dtype, cands, default, build=None,
-            arg_specs=None):
-        seen[kernel] = (build, arg_specs)
-        return real_tuned(kernel, shapes, dtype, cands, default,
-                          build=build, arg_specs=arg_specs)
-
-    monkeypatch.setattr(pk, "_tuned", spy)
-    pk._mm_blocks(256, 16, 128, jnp.float32, "matmul_swiglu", extra_w=2)
-    build, arg_specs = seen["matmul_swiglu"]
-    assert len(arg_specs) == 3  # x, wg, wu — not a single-weight matmul
-    rng = np.random.RandomState(44)
-    x = jnp.asarray(rng.randn(256, 16).astype("float32"))
-    wg = jnp.asarray(rng.randn(16, 128).astype("float32") * 0.3)
-    wu = jnp.asarray(rng.randn(16, 128).astype("float32") * 0.3)
-    out = build({"block_m": 128, "block_n": 128})(x, wg, wu)
-    np.testing.assert_allclose(np.asarray(out),
-                               np.asarray(pk._swiglu_dense(x, wg, wu)),
-                               rtol=1e-6, atol=1e-6)
-
-
-def test_fused_add_layer_norm_matches_dense_and_grads():
-    """Both outputs (sum + normalized) match; grads flow through BOTH
-    cotangents (the sum is the residual stream)."""
-    from paddle_tpu.ops.pallas_kernels import (
-        _add_ln_dense,
-        fused_add_layer_norm,
-    )
-
-    rng = np.random.RandomState(24)
-    x = jnp.asarray(rng.randn(24, 32).astype("float32"))
-    y = jnp.asarray(rng.randn(24, 32).astype("float32"))
-    g = jnp.asarray(rng.rand(32).astype("float32") + 0.5)
-    b = jnp.asarray(rng.randn(32).astype("float32"))
-    s, o = fused_add_layer_norm(x, y, g, b, 1e-5)
-    sr, orf = _add_ln_dense(x, y, g, b, 1e-5)
-    np.testing.assert_allclose(np.asarray(s), np.asarray(sr),
-                               rtol=1e-6, atol=1e-6)
-    np.testing.assert_allclose(np.asarray(o), np.asarray(orf),
-                               rtol=1e-5, atol=1e-6)
-
-    def loss(fn):
-        def f(x, y, g, b):
-            s, o = fn(x, y, g, b, 1e-5)
-            return jnp.sum(s ** 2) + jnp.sum(o * 0.5)
-        return f
-
-    gf = jax.grad(loss(fused_add_layer_norm), argnums=(0, 1, 2, 3))(
-        x, y, g, b)
-    gd = jax.grad(loss(_add_ln_dense), argnums=(0, 1, 2, 3))(x, y, g, b)
-    for a, r in zip(gf, gd):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(r),
-                                   rtol=1e-4, atol=1e-5)
 
 
 # ---------------------------------------------------------------------------
@@ -884,223 +620,6 @@ def test_linear_xent_tiled_forms_the_logits_gradient_once(
     assert fed[0].aval.dtype == jnp.dtype(dtype)
 
 
-# ---------------------------------------------------------------------------
-# vector-qstart flash attention (the ragged serving step's kernel)
-# ---------------------------------------------------------------------------
-def test_flash_attention_qvec_matches_dense_per_row():
-    """Every row's output equals the scalar-qoff dense reference run on
-    THAT row alone — per-row cutoffs and row independence (the serving
-    exactness prerequisite)."""
-    from paddle_tpu.ops.pallas_kernels import (
-        _dense_attention,
-        flash_attention_qvec,
-    )
-
-    rng = np.random.RandomState(30)
-    bh, tq, tk, d = 6, 8, 16, 8
-    q = jnp.asarray(rng.randn(bh, tq, d).astype("float32"))
-    k = jnp.asarray(rng.randn(bh, tk, d).astype("float32"))
-    v = jnp.asarray(rng.randn(bh, tk, d).astype("float32"))
-    qs = jnp.asarray(np.array([0, 3, 5, 8, 2, 7], "int32"))
-    scale = 1.0 / np.sqrt(d)
-    out = flash_attention_qvec(q, k, v, qs, None, 8, 8)
-    for b in range(bh):
-        ref = _dense_attention(q[b:b + 1], k[b:b + 1], v[b:b + 1], True,
-                               scale, qoff=qs[b])
-        np.testing.assert_allclose(np.asarray(out[b:b + 1]),
-                                   np.asarray(ref), rtol=2e-4, atol=2e-5)
-
-
-def test_flash_attention_qvec_grads_match_dense():
-    from paddle_tpu.ops.pallas_kernels import (
-        _dense_attention,
-        flash_attention_qvec,
-    )
-
-    rng = np.random.RandomState(31)
-    bh, tq, tk, d = 4, 8, 16, 8
-    q = jnp.asarray(rng.randn(bh, tq, d).astype("float32"))
-    k = jnp.asarray(rng.randn(bh, tk, d).astype("float32"))
-    v = jnp.asarray(rng.randn(bh, tk, d).astype("float32"))
-    qs = jnp.asarray(np.array([1, 4, 6, 8], "int32"))
-    scale = 1.0 / np.sqrt(d)
-
-    def dref(q, k, v):
-        outs = [_dense_attention(q[b:b + 1], k[b:b + 1], v[b:b + 1],
-                                 True, scale, qoff=qs[b])
-                for b in range(bh)]
-        return jnp.concatenate(outs, 0)
-
-    gf = jax.grad(lambda q, k, v: jnp.sum(
-        flash_attention_qvec(q, k, v, qs, None, 8, 8) ** 2),
-        argnums=(0, 1, 2))(q, k, v)
-    gd = jax.grad(lambda q, k, v: jnp.sum(dref(q, k, v) ** 2),
-                  argnums=(0, 1, 2))(q, k, v)
-    for a, r in zip(gf, gd):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(r),
-                                   rtol=2e-3, atol=2e-4)
-
-
-def test_fused_attention_op_vector_qstart_pallas_matches_dense():
-    """The op-level contract: the vector-QStart branch under
-    FLAGS_use_pallas (flash qvec kernel) equals the dense-XLA branch."""
-    import paddle_tpu.framework as fw
-    from paddle_tpu.core import scope as scope_mod
-    from paddle_tpu import unique_name
-
-    rng = np.random.RandomState(32)
-    B, H, W, T, D = 3, 2, 4, 16, 8
-    qv = rng.rand(B, H, W, D).astype("float32")
-    kv = rng.rand(B, H, T, D).astype("float32")
-    vv = rng.rand(B, H, T, D).astype("float32")
-    qs = np.array([0, 5, 9], "int64")
-
-    def run(use_pallas):
-        fw.switch_main_program(fluid.Program())
-        fw.switch_startup_program(fluid.Program())
-        unique_name.switch()
-        scope_mod._switch_scope(scope_mod.Scope())
-        q = layers.data("q", shape=[B, H, W, D], append_batch_size=False)
-        k = layers.data("k", shape=[B, H, T, D], append_batch_size=False)
-        v = layers.data("v", shape=[B, H, T, D], append_batch_size=False)
-        st = layers.data("qs", shape=[B], dtype="int64",
-                         append_batch_size=False)
-        att = layers.fused_attention(q, k, v, causal=True, qstart=st)
-        flags.set_flags({"use_pallas": use_pallas})
-        try:
-            exe = fluid.Executor(fluid.CPUPlace())
-            exe.run(fluid.default_startup_program())
-            (out,) = exe.run(feed={"q": qv, "k": kv, "v": vv, "qs": qs},
-                             fetch_list=[att])
-        finally:
-            flags.set_flags({"use_pallas": False})
-        return np.asarray(out)
-
-    np.testing.assert_allclose(run(True), run(False), rtol=2e-4,
-                               atol=2e-5)
-
-
-# ---------------------------------------------------------------------------
-# fused_softmax_xent hardening + blocked backward
-# ---------------------------------------------------------------------------
-def test_fused_softmax_xent_rejects_bad_shapes_loudly():
-    from paddle_tpu.ops.pallas_kernels import fused_softmax_xent
-
-    rng = np.random.RandomState(33)
-    lg = jnp.asarray(rng.randn(8, 12).astype("float32"))
-    good = jnp.asarray(rng.randint(0, 12, (8,)).astype("int32"))
-    with pytest.raises(ValueError, match="2-D"):
-        fused_softmax_xent(lg.reshape(2, 4, 12), good)
-    with pytest.raises(ValueError, match="mis-broadcast"):
-        fused_softmax_xent(lg, good[:4])
-    with pytest.raises(ValueError, match="mis-broadcast"):
-        fused_softmax_xent(lg, jnp.stack([good, good], 1))
-    with pytest.raises(ValueError, match="integers"):
-        fused_softmax_xent(lg, good.astype(jnp.float32))
-    # [rows, 1] labels stay accepted (the op lowering's legacy form)
-    out = fused_softmax_xent(lg, good.reshape(8, 1))
-    assert out.shape == (8, 1)
-
-
-def test_sxent_blocked_backward_matches_analytic():
-    """The row-blocked bwd kernel == softmax - onehot (no [R, C] one-hot
-    in HBM; dx is computed tile-by-tile)."""
-    from paddle_tpu.ops.pallas_kernels import _sxent_bwd_call
-
-    rng = np.random.RandomState(34)
-    R, C = 24, 17
-    lg = jnp.asarray(rng.randn(R, C).astype("float32"))
-    lb = jnp.asarray(rng.randint(0, C, (R,)).astype("int32"))
-    dy = jnp.asarray(rng.randn(R, 1).astype("float32"))
-    got = _sxent_bwd_call(lg, lb, dy, 8)
-    p = jax.nn.softmax(lg, axis=-1)
-    onehot = jax.nn.one_hot(lb, C, dtype=jnp.float32)
-    ref = (p - onehot) * dy
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               rtol=1e-5, atol=1e-6)
-
-
-# ---------------------------------------------------------------------------
-# op-level pallas dispatch parity for the new fused ops
-# ---------------------------------------------------------------------------
-def _run_fused_op_program(build, feed, use_pallas):
-    import paddle_tpu.framework as fw
-    from paddle_tpu.core import scope as scope_mod
-    from paddle_tpu import unique_name
-
-    fw.switch_main_program(fluid.Program())
-    fw.switch_startup_program(fluid.Program())
-    unique_name.switch()
-    scope_mod._switch_scope(scope_mod.Scope())
-    fluid.default_startup_program().random_seed = 9
-    fetches = build()
-    flags.set_flags({"use_pallas": use_pallas})
-    try:
-        exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(fluid.default_startup_program())
-        out = exe.run(feed=feed, fetch_list=fetches)
-    finally:
-        flags.set_flags({"use_pallas": False})
-    return [np.asarray(o) for o in out]
-
-
-def test_fc_op_pallas_dispatch_matches_dense():
-    rng = np.random.RandomState(35)
-    xv = rng.rand(4, 6, 16).astype("float32")
-
-    def build():
-        x = layers.data("x", shape=[6, 16])
-        y = layers.fc(x, 24, num_flatten_dims=2, act="gelu")
-        return [y]
-
-    plain = _run_fused_op_program(build, {"x": xv}, False)
-    pallas = _run_fused_op_program(build, {"x": xv}, True)
-    np.testing.assert_allclose(plain[0], pallas[0], rtol=1e-5, atol=1e-6)
-
-
-def test_fused_swiglu_op_pallas_dispatch_matches_dense():
-    rng = np.random.RandomState(36)
-    xv = rng.rand(2, 4, 8).astype("float32")
-
-    def build():
-        from paddle_tpu.transpiler import apply_pass
-
-        x = layers.data("x", shape=[4, 8])
-        gate = layers.fc(x, 12, num_flatten_dims=2, act="swish",
-                         bias_attr=False)
-        up = layers.fc(x, 12, num_flatten_dims=2, bias_attr=False)
-        y = layers.elementwise_mul(gate, up)
-        apply_pass(fluid.default_main_program(), "swiglu_fuse_pass")
-        assert fluid.default_main_program()._swiglu_fused_count == 1
-        return [y]
-
-    plain = _run_fused_op_program(build, {"x": xv}, False)
-    pallas = _run_fused_op_program(build, {"x": xv}, True)
-    np.testing.assert_allclose(plain[0], pallas[0], rtol=1e-5, atol=1e-6)
-
-
-def test_fused_residual_ln_op_pallas_dispatch_matches_dense():
-    rng = np.random.RandomState(37)
-    av = rng.rand(2, 4, 16).astype("float32")
-    bv = rng.rand(2, 4, 16).astype("float32")
-
-    def build():
-        from paddle_tpu.transpiler import apply_pass
-
-        a = layers.data("a", shape=[4, 16])
-        b = layers.data("b", shape=[4, 16])
-        s = layers.elementwise_add(a, b)
-        y = layers.layer_norm(s, begin_norm_axis=2)
-        apply_pass(fluid.default_main_program(), "residual_ln_fuse_pass")
-        assert fluid.default_main_program()._residual_ln_fused_count == 1
-        return [s, y]
-
-    plain = _run_fused_op_program(build, {"a": av, "b": bv}, False)
-    pallas = _run_fused_op_program(build, {"a": av, "b": bv}, True)
-    for p, q in zip(plain, pallas):
-        np.testing.assert_allclose(p, q, rtol=1e-5, atol=1e-6)
-
-
 @pytest.mark.parametrize("form", ["mul", "tied_matmul", "mul_smoothed"])
 def test_fused_linear_xent_op_and_its_grad_match_the_unfused_chain(
         form, monkeypatch):
@@ -1163,138 +682,24 @@ def test_fused_linear_xent_op_and_its_grad_match_the_unfused_chain(
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
 
 
-def test_fused_attention_qvec_explicit_flags_beyond_budget_dispatch():
-    """Regression (review finding): explicit FLAGS_flash_block_q/k that
-    are Mosaic-legal but exceed the AUTO path's 512/1024 VMEM-budget
-    gate must still dispatch the flash kernel — silently re-routing a
-    requested block size onto the dense path misattributes sweep
-    timings (the loud-validation contract of every explicit-flag
-    branch)."""
-    from paddle_tpu.ops import kernel_tuning as kt
-
-    rng = np.random.RandomState(41)
-    B, H, W, T, D = 2, 1, 4, 2048, 8
-    qv = rng.rand(B, H, W, D).astype("float32")
-    kv = rng.rand(B, H, T, D).astype("float32")
-    vv = rng.rand(B, H, T, D).astype("float32")
-    qs = np.array([0, 7], "int64")
-
-    def run(use_pallas):
-        import paddle_tpu.framework as fw
-        from paddle_tpu.core import scope as scope_mod
-        from paddle_tpu import unique_name
-
-        fw.switch_main_program(fluid.Program())
-        fw.switch_startup_program(fluid.Program())
-        unique_name.switch()
-        scope_mod._switch_scope(scope_mod.Scope())
-        q = layers.data("q", shape=[B, H, W, D], append_batch_size=False)
-        k = layers.data("k", shape=[B, H, T, D], append_batch_size=False)
-        v = layers.data("v", shape=[B, H, T, D], append_batch_size=False)
-        st = layers.data("qs", shape=[B], dtype="int64",
-                         append_batch_size=False)
-        att = layers.fused_attention(q, k, v, causal=True, qstart=st)
-        flags.set_flags({"use_pallas": use_pallas})
-        exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(fluid.default_startup_program())
-        (out,) = exe.run(feed={"q": qv, "k": kv, "v": vv, "qs": qs},
-                         fetch_list=[att])
-        return np.asarray(out)
-
-    prior = flags.get_flag("use_pallas")
-    flags.set_flags({"flash_block_k": 2048})  # legal (2048 % T == 0),
-    # but past the auto path's bk <= 1024 budget gate
-    try:
-        before = kt.attribution()["pallas_hits"].get("attention", 0)
-        got = run(True)
-        hits = kt.attribution()["pallas_hits"].get("attention", 0)
-        assert hits > before, "explicit-flag qvec fell to the dense path"
-        ref = run(False)
-        np.testing.assert_allclose(got, ref, rtol=2e-4, atol=2e-5)
-    finally:
-        flags.set_flags({"flash_block_k": 0, "use_pallas": prior})
-
-
-def test_fused_attention_qvec_bucket_aliased_cache_relegalizes():
-    """Regression (review finding): the tuning cache pow2-buckets row
-    dims, so a block size seeded at Tq=12 lands in the same bucket as
-    Tq=16 — the second dispatch must RE-LEGALIZE the cached blocks
-    against its own lengths instead of tripping the kernel's
-    divisibility assert."""
-    from paddle_tpu.ops import kernel_tuning as kt
-
-    kt.clear_cache(forget_path=True)
-    rng = np.random.RandomState(40)
-
-    def run(W):
-        import paddle_tpu.framework as fw
-        from paddle_tpu.core import scope as scope_mod
-        from paddle_tpu import unique_name
-
-        fw.switch_main_program(fluid.Program())
-        fw.switch_startup_program(fluid.Program())
-        unique_name.switch()
-        scope_mod._switch_scope(scope_mod.Scope())
-        B, H, T, D = 2, 2, 16, 8
-        qv = rng.rand(B, H, W, D).astype("float32")
-        kv = rng.rand(B, H, T, D).astype("float32")
-        vv = rng.rand(B, H, T, D).astype("float32")
-        q = layers.data("q", shape=[B, H, W, D], append_batch_size=False)
-        k = layers.data("k", shape=[B, H, T, D], append_batch_size=False)
-        v = layers.data("v", shape=[B, H, T, D], append_batch_size=False)
-        st = layers.data("qs", shape=[B], dtype="int64",
-                         append_batch_size=False)
-        att = layers.fused_attention(q, k, v, causal=True, qstart=st)
-        exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(fluid.default_startup_program())
-        (out,) = exe.run(
-            feed={"q": qv, "k": kv, "v": vv,
-                  "qs": np.array([0, 4], "int64")},
-            fetch_list=[att])
-        return np.asarray(out)
-
-    flags.set_flags({"use_pallas": True})
-    try:
-        run(12)  # seeds block_q=12 under the pow2 bucket 16
-        run(16)  # same bucket; cached 12 does not divide 16 -> relegalize
-    finally:
-        flags.set_flags({"use_pallas": False})
-        kt.clear_cache(forget_path=True)
-
-
 def test_every_kernel_lowers_for_tpu_without_a_chip(monkeypatch):
     """Cross-lower each chip_smoke kernel case (the repo's model shapes,
     forward and backward) for the TPU platform on this host.  That runs
     the Pallas -> Mosaic lowering rule and its block-spec legality checks,
-    which interpret mode skips: the (1, 1)-blocked SMEM spec of the
-    vector-qstart kernel passed every interpreted test and was refused
-    at this stage on first contact with the chip.  What Mosaic itself
-    does with the lowered module still needs the chip (chip_smoke.py)."""
+    which interpret mode skips: a (1, 1)-blocked SMEM spec once passed
+    every interpreted test and was refused at this stage on first contact
+    with the chip.  What Mosaic itself does with the lowered module still
+    needs the chip (chip_smoke.py)."""
     import chip_smoke
     from paddle_tpu.ops import pallas_kernels as pk
 
     monkeypatch.setattr(pk, "_interpret", lambda: False)
-    old = flags.get_flag("kernel_autotune")
-    flags.set_flags({"use_pallas": True, "kernel_autotune": False})
-    try:
-        cases = chip_smoke.kernel_cases(rehearse=False)
-        assert sorted(cases) == sorted(
-            n for n in pk.__all__ if n != "use_pallas")
-        for name, (kernel, _dense, make_args, _where) in cases.items():
-            lowered = jax.jit(kernel).trace(
-                *jax.eval_shape(make_args)).lower(lowering_platforms=("tpu",))
-            assert "tpu_custom_call" in lowered.as_text(), name
-        # every epilogue activation the dispatch gate admits: exact
-        # gelu's erfc has no Pallas TPU lowering (refused on the chip
-        # in PR 21 — the kernel body now uses _erf_mosaic)
-        x = jax.ShapeDtypeStruct((256, 512), jnp.bfloat16)
-        w = jax.ShapeDtypeStruct((512, 512), jnp.bfloat16)
-        b = jax.ShapeDtypeStruct((512,), jnp.float32)
-        for act in pk._MM_ACTS:
-            jax.jit(lambda x, w, b: pk.matmul_bias_act(x, w, b, act)).trace(
-                x, w, b).lower(lowering_platforms=("tpu",))
-    finally:
-        flags.set_flags({"kernel_autotune": old})
+    cases = chip_smoke.kernel_cases(rehearse=False)
+    assert sorted(cases) == sorted(pk.__all__)
+    for name, (kernel, _dense, make_args, _where) in cases.items():
+        lowered = jax.jit(kernel).trace(
+            *jax.eval_shape(make_args)).lower(lowering_platforms=("tpu",))
+        assert "tpu_custom_call" in lowered.as_text(), name
 
 
 @pytest.mark.parametrize("held, router", [(8, "softmax"), (2, "sigmoid")],

@@ -61,16 +61,13 @@ def _make_engine(hp=TinyHP, n_slots=4, width=4, t_max=24, seed=7, **kw):
 
     MEMOIZED per config: run() fully resets an engine (counters,
     results, cache startups), so tests with the same (hp, shape, seed,
-    kwargs, pallas flag) share one compiled engine — living in its own
+    kwargs) share one compiled engine — living in its own
     pinned scope, see _PinnedScopeExecutor — instead of paying ~4s of
     tracing each, the single biggest cost in this file.  Not cached:
     engines with `prefix_rows` (a PrefixCache keeps registered rows
     ACROSS runs by design, so sharing would leak registrations between
     tests)."""
-    from paddle_tpu import flags
-
     key = (hp.__name__, n_slots, width, t_max, seed,
-           bool(flags.get_flag("use_pallas")),
            tuple(sorted(kw.items())))
     cacheable = not kw.get("prefix_rows")
     if cacheable and key in _ENGINE_CACHE:
@@ -300,23 +297,6 @@ def test_engine_churn_exactness_sampled():
     reqs = _churn_trace(TinyHP.vocab_size, greedy_only=False, seed=5)
     assert any(not r.greedy for r in reqs)
     _assert_churn_exact(eng, reqs)
-
-
-def test_engine_churn_exactness_pallas_kernels():
-    """The exactness contract under FLAGS_use_pallas=1: the ragged
-    step's attention rides the VECTOR-QSTART flash kernel (per-row SMEM
-    cutoff bases; interpret mode on CPU, the same kernel Mosaic
-    compiles on chip) and every pooled stream — greedy and seeded
-    sampled — stays bit-identical to its solo run under churn."""
-    from paddle_tpu import flags
-
-    flags.set_flags({"use_pallas": True})
-    try:
-        _, eng = _make_engine()
-        reqs = _churn_trace(TinyHP.vocab_size, greedy_only=False, seed=3)
-        _assert_churn_exact(eng, reqs)
-    finally:
-        flags.set_flags({"use_pallas": False})
 
 
 def test_engine_compiles_once_across_occupancy():

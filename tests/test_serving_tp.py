@@ -190,50 +190,3 @@ def test_tp_engine_compiles_once_across_occupancy():
         assert exe.compile_count == baseline, (
             "occupancy churn retraced the sharded serving step: %d -> %d"
             % (baseline, exe.compile_count))
-
-
-@needs_two_devices
-def test_tp_engine_pallas_qvec_under_shard_map():
-    """FLAGS_use_pallas=1 on the mesh: the ragged step's attention
-    rides flash_attention_qvec inside shard_map (each device runs the
-    kernel on its own head slice; interpret mode on CPU, the same
-    kernel Mosaic compiles on chip) and churn exactness holds."""
-    from paddle_tpu import flags
-
-    flags.set_flags({"use_pallas": True})
-    scope = fluid.Scope()
-    with fluid.scope_guard(scope):
-        exe, eng = _tp_engine(scope, seed=5)
-        reqs = _churn_trace(TinyHP.vocab_size, seed=3)[:6]
-        results, stats = eng.run(list(reqs))
-        assert stats["finished"] == len(reqs)
-        base = exe.compile_count
-        for r in reqs:
-            solo, _ = eng.run_solo(r)
-            np.testing.assert_array_equal(results[r.rid]["tokens"], solo)
-        assert exe.compile_count == base
-
-
-@pytest.mark.slow  # second pallas engine compile; rides ci.sh TP lane (-m "")
-@needs_two_devices
-def test_tp_engine_epilogue_kernels_dispatch_under_shard_map():
-    """The matmul-epilogue kernels run shard_map-wrapped per-device
-    inside the sharded serving step — the PR 14 limit (they used to
-    operand-replicate, all-gathering the sharded weight) is closed.
-    Attribution counters prove dispatch; churn exactness still holds."""
-    from paddle_tpu import flags
-    from paddle_tpu.ops import kernel_tuning
-
-    flags.set_flags({"use_pallas": True})
-    kernel_tuning.reset_attribution()
-    scope = fluid.Scope()
-    with fluid.scope_guard(scope):
-        exe, eng = _tp_engine(scope, seed=11)
-        reqs = _churn_trace(TinyHP.vocab_size, seed=5)[:4]
-        results, stats = eng.run(list(reqs))
-        assert stats["finished"] == len(reqs)
-        hits = kernel_tuning.attribution()["pallas_hits"]
-        assert hits.get("matmul_epilogue", 0) > 0, hits
-        for r in reqs[:2]:
-            solo, _ = eng.run_solo(r)
-            np.testing.assert_array_equal(results[r.rid]["tokens"], solo)

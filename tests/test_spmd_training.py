@@ -43,15 +43,13 @@ def _fresh():
     scope_mod._switch_scope(scope_mod.Scope())
 
 
-def _train(mesh, steps=4, use_pallas=False, use_bf16=False, hp=TinyHP,
-           extra_flags=None, batch=4, seq=8):
+def _train(mesh, steps=4, use_bf16=False, hp=TinyHP, extra_flags=None,
+           batch=4, seq=8):
     """Fresh scope+programs, `steps` Adam steps on the fake-LM batch;
     returns (losses, scope, main_program, executor)."""
     _fresh()
-    names = ["use_pallas", "kernel_autotune"] + sorted(extra_flags or ())
-    old = {k: flags.get_flag(k) for k in names}
-    flags.set_flags(dict({"use_pallas": use_pallas,
-                          "kernel_autotune": False}, **(extra_flags or {})))
+    old = {k: flags.get_flag(k) for k in extra_flags or ()}
+    flags.set_flags(dict(extra_flags or {}))
     try:
         main, startup, feeds, fetches = gpt2.gpt2_lm_program(
             hp, seq_len=seq, lr=3e-3, use_bf16=use_bf16, mesh=mesh)
@@ -120,23 +118,6 @@ def test_mp2_rtol_parity_across_mesh_shapes():
         got, _, _, _ = _train(make_mesh({"dp": dp, "mp": mp}), steps=3)
         np.testing.assert_allclose(got, base, rtol=1e-5,
                                    err_msg="dp=%d mp=%d" % (dp, mp))
-
-
-@pytest.mark.slow  # interpret-mode kernels + second compile; ci.sh spmd lane
-@needs_four_devices
-def test_epilogue_kernels_dispatch_inside_sharded_step():
-    """FLAGS_use_pallas on the dp2 x mp2 mesh: the shard_map-wrapped
-    epilogue kernels DISPATCH (kernel-attribution counters move — no
-    operand replication fallback) and parity holds vs the dense mesh
-    run."""
-    from paddle_tpu.ops import kernel_tuning
-
-    dense, _, _, _ = _train(make_mesh({"dp": 2, "mp": 2}))
-    kernel_tuning.reset_attribution()
-    got, _, _, _ = _train(make_mesh({"dp": 2, "mp": 2}), use_pallas=True)
-    hits = kernel_tuning.attribution()["pallas_hits"]
-    assert hits.get("matmul_epilogue", 0) > 0, hits
-    np.testing.assert_allclose(got, dense, rtol=1e-5)
 
 
 def _ctx_of(op_type, **slots):
@@ -477,7 +458,7 @@ def test_sharded_train_step_lowers_for_tpu_without_chips(monkeypatch):
     first four-chip run refused the unwrapped train flash_attention with
     "Mosaic kernels cannot be automatically partitioned".  Cross-lowering
     the sharded step for the TPU platform runs that check on the CPU
-    host, for every dispatch site the transformer step reaches."""
+    host, for the dispatch site the transformer step reaches."""
     import chip_smoke
     from paddle_tpu.models import transformer as tfm
     from paddle_tpu.ops import nn_ops
@@ -497,16 +478,11 @@ def test_sharded_train_step_lowers_for_tpu_without_chips(monkeypatch):
 
     _fresh()
     mesh = make_mesh({"dp": 2, "mp": 2}, jax.devices()[:4])
-    autotune = flags.get_flag("kernel_autotune")  # train() pins it off
-    try:
-        r = chip_smoke.train(
-            HP, tfm.make_fake_batch(8, 128, 128, HP, seed=0), 128,
-            fluid.CPUPlace(), True, steps=1, mesh=mesh)
-    finally:
-        flags.set_flags({"kernel_autotune": autotune})
+    r = chip_smoke.train(
+        HP, tfm.make_fake_batch(8, 128, 128, HP, seed=0), 128,
+        fluid.CPUPlace(), steps=1, mesh=mesh)
     hits = r["attribution"]["pallas_hits"]
-    for fam in ("attention", "layernorm", "matmul_epilogue"):
-        assert hits.get(fam, 0) > 0, hits  # dispatched, not dense
+    assert hits.get("attention", 0) > 0, hits  # dispatched, not dense
     monkeypatch.setattr(pk, "_interpret", lambda: False)
     jax.clear_caches()  # the interpreted trace must not be reused
     (_traced, jitted, _sh, avals), = r["exe"]._spmd_cache.values()

@@ -45,6 +45,7 @@ MODULES = [
     "paddle_tpu.ops.kernel_tuning",
     "paddle_tpu.analysis",
     "paddle_tpu.transpiler.autotune",
+    "paddle_tpu.models.transformer",
     "paddle_tpu.utils.memory_analysis",
     "paddle_tpu.dataset.mnist",
     "paddle_tpu.dataset.movielens",
