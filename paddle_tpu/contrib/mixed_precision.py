@@ -28,7 +28,11 @@ _BF16_OPS = ("mul", "matmul", "conv2d", "depthwise_conv2d",
              "fused_linear_xent",
              # routed experts: the two grouped matmuls read bf16 rows and
              # bf16 expert weights; the router stays f32 (below)
-             "moe_ffn")
+             "moe_ffn",
+             # Kimi Delta Attention: q, k, v are the operands of its
+             # products; the decay, beta and everything the lowering
+             # derives from them stay f32 (below and in ops/kda_ops.py)
+             "kda_attention")
 
 # input slots that must stay float32 even when the op is rewritten
 # (additive -1e9 padding masks lose nothing in bf16, but keeping them f32
@@ -39,7 +43,9 @@ _KEEP_F32_SLOTS = {"fused_attention": ("Bias",),
                    # the router's top-k is discontinuous: it reads X and
                    # its own weight in f32, and the lowering narrows X to
                    # the experts' dtype itself
-                   "moe_ffn": ("X", "RouterW", "ExpertBias")}
+                   "moe_ffn": ("X", "RouterW", "ExpertBias"),
+                   # the log-decay is summed over a chunk and exponentiated
+                   "kda_attention": ("G", "Beta")}
 
 # output slots that are not activations (counts, f32 statistics): they
 # keep their declared dtype and get no cast-back
@@ -63,6 +69,7 @@ _TRANSPARENT_OPS = {
     "layer_norm": (("X",), ("Y",)),
     "rms_norm": (("X",), ("Y",)),
     "short_conv": (("BCX",), ("Out",)),
+    "causal_conv": (("X",), ("Out",)),
     "dropout": (("X",), ("Out",)),
     "reshape2": (("X",), ("Out",)),
     "reshape": (("X",), ("Out",)),

@@ -36,6 +36,8 @@ __all__ = [
     "layer_norm",
     "rms_norm",
     "short_conv",
+    "causal_conv",
+    "kda_attention",
     "moe_ffn",
     "group_norm",
     "instance_norm",
@@ -561,6 +563,47 @@ def short_conv(input, kernel_size=3, param_attr=None, name=None):
     out = helper.create_variable_for_type_inference(dtype)
     helper.append_op("short_conv", inputs={"BCX": [input], "Filter": [filt]},
                      outputs={"Out": [out]})
+    return out
+
+
+def causal_conv(input, kernel_size=4, act=None, param_attr=None, name=None):
+    """Depthwise causal convolution over the T axis of `input` [..., T, d]
+    (the `causal_conv` op): one [kernel_size] filter a channel, zeros left
+    of t = 0, no bias, no gate; `act` "silu" applies SiLU to the result
+    inside the op.  Kimi Linear's q, k and v pass through one each."""
+    if act not in (None, "silu"):
+        raise ValueError("causal_conv act %r is neither silu nor None"
+                         % (act,))
+    helper = LayerHelper("causal_conv", **locals())
+    dtype = helper.input_dtype()
+    filt = helper.create_parameter(
+        attr=helper.param_attr, shape=[int(input.shape[-1]), int(kernel_size)],
+        dtype=dtype)
+    out = helper.create_variable_for_type_inference(dtype)
+    helper.append_op("causal_conv", inputs={"X": [input], "Filter": [filt]},
+                     outputs={"Out": [out]}, attrs={"act": act or ""})
+    return out
+
+
+def kda_attention(q, k, v, g, beta, scale=None, name=None):
+    """Kimi Delta Attention over [batch, heads, T, d] (the `kda_attention`
+    op, ops/kda_ops.py): per head a dk x dv state that every token decays
+    channel by channel (`g` [batch, heads, T, dk] float32, the log-decay,
+    <= 0), corrects by the delta rule with step `beta` [batch, heads, T]
+    and reads with q times `scale` (dk^-0.5 where None).  The result is
+    [batch, heads, T, dv] in v's dtype.  No initial state and no state
+    handed back: the causal training path."""
+    helper = LayerHelper("kda_attention", **locals())
+    out = helper.create_variable_for_type_inference(v.dtype)
+    # Out is V's shape and dtype, said here: appended straight to the block
+    # like fused_attention, the chunked lowering is never evaluated to
+    # build a program
+    helper.main_program.current_block().append_op(
+        "kda_attention",
+        inputs={"Q": [q], "K": [k], "V": [v], "G": [g], "Beta": [beta]},
+        outputs={"Out": [out]},
+        attrs={"scale": None if scale is None else float(scale)})
+    out.shape = tuple(v.shape)
     return out
 
 

@@ -406,7 +406,7 @@ def multi_head_attention(
 def latent_attention(
     x, n_head, kv_lora_rank, qk_nope_head_dim, qk_rope_head_dim, v_head_dim,
     norm_eps=1e-6, rotary_base=10000.0, rotary_interleaved=True,
-    param_attr=None,
+    param_attr=None, rotary=True,
 ):
     """Multi-head latent attention (MLA, DeepSeek-V2/V3), the causal
     training path, beside `multi_head_attention`: keys and values are not
@@ -430,6 +430,10 @@ def latent_attention(
     query latent (the configurations that have one publish `q_lora_rank`;
     none is built here), no dropout, no cache: serving keeps the latent
     and the rotary key instead of K and V, which is another path.
+    `rotary=False` (Kimi Linear's `mla_use_nope`) is the same layer without
+    any position encoding: q's `rope`-wide part and the shared key part go
+    into the scores as projected, the key part still ONE for all heads,
+    repeated and concatenated; q is then never split.
 
     Built under `name_scope("mla")` with inner scopes `down` (the
     projections from x and the latent's norm), `up` (the expansion of the
@@ -463,16 +467,20 @@ def latent_attention(
                 heads(kv, qk_nope_head_dim + v_head_dim),
                 [qk_nope_head_dim, v_head_dim], dim=-1)
         with framework.name_scope("rope"):
-            q_nope, q_rot = layers.split(
-                heads(q, d_qk), [qk_nope_head_dim, qk_rope_head_dim], dim=-1)
-            q_rot = layers.rotary_embed(q_rot, base=rotary_base,
-                                        interleaved=rotary_interleaved)
-            # one rotary key for all heads, rotated once and then repeated
-            k_rot = layers.rotary_embed(
-                layers.reshape(k_rot, [b, 1, t, qk_rope_head_dim]),
-                base=rotary_base, interleaved=rotary_interleaved)
+            q = heads(q, d_qk)
+            if rotary:
+                q_nope, q_rot = layers.split(
+                    q, [qk_nope_head_dim, qk_rope_head_dim], dim=-1)
+                q_rot = layers.rotary_embed(q_rot, base=rotary_base,
+                                            interleaved=rotary_interleaved)
+            k_rot = layers.reshape(k_rot, [b, 1, t, qk_rope_head_dim])
+            if rotary:
+                # one rotary key for all heads, rotated once, then repeated
+                k_rot = layers.rotary_embed(k_rot, base=rotary_base,
+                                            interleaved=rotary_interleaved)
             k_rot = layers.expand(k_rot, [1, n_head, 1, 1])
-            q = layers.concat([q_nope, q_rot], axis=3)
+            if rotary:
+                q = layers.concat([q_nope, q_rot], axis=3)
             k = layers.concat([k_nope, k_rot], axis=3)
         with framework.name_scope("core"):
             ctx = layers.fused_attention(q, k, v, causal=True,

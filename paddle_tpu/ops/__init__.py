@@ -11,6 +11,7 @@ from . import math_ops  # noqa: F401
 from . import tensor_ops  # noqa: F401
 from . import nn_ops  # noqa: F401
 from . import moe_ops  # noqa: F401
+from . import kda_ops  # noqa: F401
 from . import optimizer_ops  # noqa: F401
 from . import loss_ops  # noqa: F401
 from . import image_ops  # noqa: F401

@@ -1,7 +1,8 @@
 """Trace-time attribution counters of the op lowerings (`note_*` /
 `attribution()`): per-family pallas-hit counts, hand-written VJPs, random
-draws by generator, uneven weight constraints, `moe_ffn`'s live chunks and
-the windowed flash kernels' band grids (chip_smoke.py and the benchmark's
+draws by generator, uneven weight constraints, `moe_ffn`'s live chunks, the
+windowed flash kernels' band grids and `kda_attention`'s chunking
+(chip_smoke.py and the benchmark's
 readers read them), so an MFU regression can be pinned to "kernel X
 stopped dispatching" instead of guessed at.  Counts tick at TRACE time
 (once per compiled program, not per step) — they attribute what the
@@ -27,6 +28,9 @@ _moe_live_chunks = {"ops": 0, "chunk_rows": {}}  # chunk_rows: N*k -> rows a chu
 # windowed fused_attention lowerings that took the flash kernel, with the
 # forward grid steps a head walks and the tiles its band computes
 _attention_band_grid = {"ops": 0, "steps": {}}  # "TxWxBQxBK" -> [walked, computed]
+# kda_attention lowerings (a grad op lowers its forward again), with the
+# chunking each length got
+_kda_chunks = {"ops": 0, "lengths": {}}  # T -> [chunk, chunks a group, T, padded T]
 
 
 def note_kernel(family, n=1):
@@ -77,12 +81,23 @@ def note_band_grid(t, window, block_q, block_k, walked, computed):
             t, window, block_q, block_k)] = [int(walked), int(computed)]
 
 
+def note_kda_chunks(t, padded_t, chunk, group):
+    """Count a trace-time lowering of a `kda_attention`, and keep by length
+    the chunk it ran at, the chunks whose inside it computes at once and
+    the length it padded to."""
+    with _lock:
+        _kda_chunks["ops"] += 1
+        _kda_chunks["lengths"][int(t)] = [int(chunk), int(group), int(t),
+                                          int(padded_t)]
+
+
 def attribution():
     """Snapshot for bench attribution: per-family pallas-hit counts,
     in-program random draws by generator, uneven weight constraints by op
     type, the moe_ffn lowerings that took the live-chunk path with the
     rows of a chunk by buffer size, the windowed flash lowerings with their
-    forward grid steps walked and computed by shape."""
+    forward grid steps walked and computed by shape, the kda_attention
+    lowerings with [chunk, chunks a group, T, padded T] by length."""
     with _lock:
         return {
             "pallas_hits": dict(_kernel_hits),
@@ -96,6 +111,10 @@ def attribution():
                 "ops": _attention_band_grid["ops"],
                 "steps": {k: list(v) for k, v in
                           _attention_band_grid["steps"].items()}},
+            "kda_chunks": {
+                "ops": _kda_chunks["ops"],
+                "lengths": {k: list(v) for k, v in
+                            _kda_chunks["lengths"].items()}},
         }
 
 
@@ -107,3 +126,4 @@ def reset_attribution():
         _uneven_constraints.clear()
         _moe_live_chunks.update(ops=0, chunk_rows={})
         _attention_band_grid.update(ops=0, steps={})
+        _kda_chunks.update(ops=0, lengths={})

@@ -9,6 +9,8 @@ interpreter), and gradients fall out of ``jax.vjp`` — including scan-based
 RNNs.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -376,6 +378,57 @@ def _short_conv(ctx, ins, attrs):
     with jax.named_scope("gate_conv"):
         out = gated_short_conv(ins["BCX"][0], ins["Filter"][0])
     return {"Out": [out]}
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def causal_conv(x, filt, silu):
+    """Depthwise causal convolution over the T axis (second to last) of x
+    [..., T, d], one filter a channel, filt [d, L]: c_t = sum_j filt[:, j]
+    x_{t-(L-1)+j}, zeros left of t = 0; SiLU on the result where `silu`.
+    The UNGATED form beside gated_short_conv (Kimi Linear's q, k and v):
+    L multiply-adds in f32, result in x's dtype, one pass over x."""
+    c = _filtered([_f32(w) for w in _windows(x, filt.shape[1])], _f32(filt))
+    return (jax.nn.silu(c) if silu else c).astype(x.dtype)
+
+
+def _cc_fwd(x, filt, silu):
+    return causal_conv(x, filt, silu), (x, filt)
+
+
+def _cc_bwd(silu, res, g):
+    """Written out as gated_short_conv's: c is made again from x (cheaper
+    than kept), dc = g silu'(c) is read at L offsets ahead for dx as the
+    forward reads x behind, and against x's windows for the filter."""
+    x, filt = res
+    taps, k = filt.shape[1], _f32(filt)
+    xs = [_f32(w) for w in _windows(x, taps)]
+    dc = _f32(g)
+    if silu:
+        c = _filtered(xs, k)
+        sig = jax.nn.sigmoid(c)
+        dc = dc * sig * (1.0 + c * (1.0 - sig))
+    dx = _filtered(_windows(dc, taps, ahead=True), k)
+    rows = tuple(range(x.ndim - 1))
+    d_filt = jnp.stack([(dc * w).sum(rows) for w in xs], -1)
+    return dx.astype(x.dtype), d_filt.astype(filt.dtype)
+
+
+causal_conv.defvjp(_cc_fwd, _cc_bwd)
+
+
+@register("causal_conv")
+def _causal_conv(ctx, ins, attrs):
+    """X [..., T, d], Filter [d, L] -> Out [..., T, d]: a depthwise causal
+    convolution of L taps with no gate, `act` "silu" or none on its
+    result.  f32 arithmetic whatever the dtype, Out in X's dtype
+    (dtype-transparent for the AMP trunk pass like short_conv); the
+    gradient is causal_conv's own VJP."""
+    act = attrs.get("act") or ""
+    if act not in ("", "silu"):
+        raise ValueError("causal_conv act %r is neither silu nor none"
+                         % (act,))
+    return {"Out": [causal_conv(ins["X"][0], ins["Filter"][0],
+                                act == "silu")]}
 
 
 @register("group_norm")
@@ -1492,6 +1545,22 @@ def _short_conv_infer(op, ins):
     elif width >= 0:
         width //= 3
     return {"Out": [VarInfo(tuple(x.shape[:-1]) + (width,), x.dtype)]}
+
+
+@register_infer("causal_conv", req_ins=("X", "Filter"), req_outs=("Out",))
+def _causal_conv_infer(op, ins):
+    x, k = _vi(ins, "X"), _vi(ins, "Filter")
+    if x is None or x.shape is None:
+        return {}
+    if len(x.shape) < 2:
+        raise InferError("causal_conv wants X [..., T, d], got %s"
+                         % (x.shape,))
+    if (k is not None and k.shape is not None
+            and (len(k.shape) != 2
+                 or (x.shape[-1] >= 0 and x.shape[-1] != k.shape[0]))):
+        raise InferError("causal_conv Filter%s does not match X%s (want "
+                         "[d, L] against [..., T, d])" % (k.shape, x.shape))
+    return {"Out": [VarInfo(x.shape, x.dtype)]}
 
 
 @register_infer("dropout", req_ins=("X",))

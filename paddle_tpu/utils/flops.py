@@ -123,6 +123,20 @@ def program_flops(program, batch_hint=1):
             if not x or not k:
                 continue
             total += factor * (2.0 * k[1] + 2.0) * _prod(x[:-1]) * k[0]
+        elif t == "kda_attention":
+            # the chunkwise form at C = 64, the triangular solve's C^3
+            # left out: a token a head three [C, dk] and two [C, dv]
+            # products against the chunk (A_kk, A_qk and the solve's W;
+            # the solve's U0 and A_qk U) and three dk x dv ones against
+            # the state (W S, Q S, K^T U)
+            q = _shape(blk, op.inputs.get("Q", [""])[0], batch_hint)
+            v = _shape(blk, op.inputs.get("V", [""])[0], batch_hint)
+            if not q or not v or len(q) != 4:
+                continue
+            from ..ops.kda_ops import CHUNK
+            b, h, tq, dk = q
+            total += factor * b * h * tq * (
+                2.0 * CHUNK * (3 * dk + 2 * v[-1]) + 6.0 * dk * v[-1])
         elif t == "matmul":
             x = _shape(blk, op.inputs.get("X", [""])[0], batch_hint)
             y = _shape(blk, op.inputs.get("Y", [""])[0], batch_hint)

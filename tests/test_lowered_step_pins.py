@@ -61,7 +61,15 @@ fused into one; `gpt2` above holds the one free-standing `layer_norm`);
 dense attention at 64, no Mosaic call), `resnet` (ResNet-50 on 32 x 32
 images; its loss is `softmax` + `cross_entropy`, so it holds none of the
 edited ops and is the control) and `ouro` (`fused_swiglu`): the one lowering
-left is the one the ledger measured."""
+left is the one the ledger measured.
+
+PR 45 added `kimi_linear` (a tiny Kimi-Linear: two KDA layers around the
+chunkwise `kda_attention` op, three `causal_conv` ops a layer, and
+`latent_attention(rotary=False)` at the flash kernel's (192, 128); a share
+of the experts held), its digest taken from PR 45's tree by this file's
+`_digest`.  The twelve digests above did not move, `kanana2`'s above all:
+`latent_attention`'s new switch builds the ops it built, in their order,
+where rotary stays on."""
 
 import base64
 import hashlib
@@ -73,8 +81,8 @@ import pytest
 
 import paddle_tpu as fluid
 from paddle_tpu.core.trace import build_traced_function
-from paddle_tpu.models import (gpt2, kanana2, lfm2, olmoe, ouro, resnet,
-                               transformer, trinity)
+from paddle_tpu.models import (gpt2, kanana2, kimi_linear, lfm2, olmoe, ouro,
+                               resnet, transformer, trinity)
 from paddle_tpu.ops import pallas_kernels as pk
 
 SEQ = 512
@@ -118,6 +126,17 @@ class K(kanana2.Kanana2Config):
     num_local_experts, expert_offset = 2, 2
 
 
+class M(kimi_linear.KimiLinearConfig):
+    vocab_size, hidden_size, intermediate_size = 512, 128, 128
+    moe_intermediate_size, num_hidden_layers, kv_lora_rank = 128, 3, 64
+    linear_attn_config = {"kda_layers": [1, 2], "full_attn_layers": [3],
+                          "num_heads": 2, "head_dim": 128,
+                          "short_conv_kernel_size": 4}
+    num_attention_heads = num_key_value_heads = 2
+    num_experts, num_experts_per_token = 8, 2
+    num_local_experts, expert_offset = 2, 2
+
+
 class U(ouro.OuroConfig):
     vocab_size, hidden_size, intermediate_size = 512, 128, 256
     num_hidden_layers, num_attention_heads, num_key_value_heads = 2, 2, 2
@@ -133,6 +152,11 @@ class W(transformer.ModelHyperParams):
 def _trinity_program(hp, **kw):
     return trinity.trinity_lm_program(hp, bias_rate=0.03, bias_max_step=0.03,
                                       **kw)
+
+
+def _kimi_program(hp, **kw):
+    return kimi_linear.kimi_linear_lm_program(
+        hp, bias_rate=0.03, bias_max_step=0.03, **kw)
 
 
 def _shapes(batch):
@@ -178,14 +202,16 @@ PROGRAMS = {"gpt2": lambda: _lm(gpt2.gpt2_lm_program, G),
             "lfm2": lambda: _lm(lfm2.lfm2_lm_program, L),
             "trinity": lambda: _lm(_trinity_program, T),
             "kanana2": lambda: _lm(kanana2.kanana2_lm_program, K),
+            "kimi_linear": lambda: _lm(_kimi_program, M),
             "ouro": lambda: _lm(ouro.ouro_lm_program, U),
             "transformer": _transformer,
             "resnet": _resnet}
 
 # name -> (sha1 of the normalised text, Mosaic calls in it) at ec9cdf7
 # (`lfm2`: at PR 39; `trinity`: at PR 40; `kanana2`: at PR 43; `transformer`,
-# `resnet`, `ouro`: at 6a7549d, PR 44's parent)
+# `resnet`, `ouro`: at 6a7549d, PR 44's parent; `kimi_linear`: at PR 45)
 BEFORE = {
+    "kimi_linear": ("dc0c89b9ad44d0f6a0461d8402db4259f57a38ec", 9),
     "transformer": ("e83306d8f28e59df41bc44b9fe7303322f29a58b", 0),
     "resnet": ("84575b13d140437bb64cb8461436105337774a6d", 0),
     "ouro": ("b1722471731c977327d882d12eb8e5106bcca444", 3),

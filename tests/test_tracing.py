@@ -795,3 +795,62 @@ def test_trinitys_attention_scopes_reach_the_lowered_steps_op_names():
             kind + ".attn_gate"]
     assert ("forward", "rotary_embed") in nested["attn_window"]
     assert not [t for _, t in nested["attn_full"] if "rotary" in t]
+
+
+def test_kimi_linears_kda_scopes_reach_the_lowered_steps_op_names():
+    """kda around a Kimi Delta Attention mixer with proj, conv, gate, core
+    and out inside it: the nested part of the optimized HLO's op names
+    carries each, forward and backward, under the op type the benchmark's
+    readers match first (`[a-z]+/kda_attention(_grad)?/<i>` then
+    `/kda.core/`); inside the op's lowering `intra` and `carry` follow; and
+    the lowering leaves how it chunked the length in attribution()."""
+    from paddle_tpu.models import gpt2, kimi_linear
+    from paddle_tpu.ops import kernel_tuning
+
+    class K(kimi_linear.KimiLinearConfig):
+        vocab_size, hidden_size, intermediate_size = 256, 64, 96
+        moe_intermediate_size, num_hidden_layers, kv_lora_rank = 32, 3, 32
+        linear_attn_config = {"kda_layers": [1, 2], "full_attn_layers": [3],
+                              "num_heads": 2, "head_dim": 16,
+                              "short_conv_kernel_size": 4}
+        num_attention_heads = num_key_value_heads = 2
+        qk_nope_head_dim, qk_rope_head_dim, v_head_dim = 16, 8, 16
+        num_experts, num_experts_per_token = 8, 2
+
+    kernel_tuning.reset_attribution()
+    main, startup, _, fetches = kimi_linear.kimi_linear_lm_program(
+        K, seq_len=40, lr=1e-3)
+    startup.random_seed = main.random_seed = 5
+    exe, scope = fluid.Executor(fluid.CPUPlace()), fluid.Scope()
+    with fluid.scope_guard(scope):
+        exe.run(startup)
+        exe.run(main, feed=gpt2.make_fake_lm_batch(2, 40, K, seed=1),
+                fetch_list=[fetches[0]])
+        (text,) = exe.compiled_hlo(main)
+    ops, nested, inside = main.global_block().ops, {}, set()
+    for op_name in re.findall(r'op_name="jit\(program_step\)/([^"]*)"', text):
+        found = SCOPE.findall(op_name)
+        want = ops[int(found[0][2])].attrs.get("op_namescope")
+        if want is None:
+            continue
+        (role, typ, _), (_, scopes, depth) = found[:2]
+        assert (scopes, int(depth)) == (want.replace("/", "."),
+                                        want.count("/") + 1), op_name
+        nested.setdefault(scopes, set()).add((role, typ))
+        if scopes == "kda.core":
+            inside.update(re.findall(r"[/(](intra|carry)[/)]", op_name))
+    assert {"kda.proj", "kda.conv", "kda.gate", "kda.core", "kda.out",
+            "mla.rope", "mla.core", "shared_expert"} <= set(nested)
+    assert nested["kda.core"] == {("forward", "kda_attention"),
+                                  ("backward", "kda_attention_grad")}
+    assert inside == {"intra", "carry"}
+    assert {("forward", "causal_conv"), ("backward", "causal_conv_grad"),
+            ("forward", "l2_normalize")} <= nested["kda.conv"]
+    assert {("forward", "softplus"), ("forward", "exp"),
+            ("forward", "sigmoid")} <= nested["kda.gate"]
+    assert ("forward", "rms_norm") in nested["kda.out"]
+    assert not [t for _, t in nested["mla.rope"] if "rotary" in t]
+    chunks = kernel_tuning.attribution()["kda_chunks"]
+    # two layers, each lowered forward and again inside its grad op
+    assert chunks["ops"] == 4
+    assert chunks["lengths"] == {40: [64, 1, 40, 64]}

@@ -24,7 +24,13 @@ adapter has `DEPARTURES`, `compare` and `bf16_unit` (PR 40: the window
 left out or off by one, rotary on the wrong kind of layer, the gate, the
 per-head norms, the norms on a branch's output, route_scale, route_norm
 and the embedding's scale; `--steps 120,132` is what its 32 warm-up
-steps and a 20 s window reach).
+steps and a 20 s window reach); `--cell kimi_linear_48b_a3b_train` (PR
+45: the decay a head's mean, left out, beta left out, the k k^T correction
+left out, q and k not L2-normalised, the convolution one step ahead, the
+output gate left out, rotary on the latent layer, routed_scaling_factor
+left out; its reference runs Kimi Delta Attention token by token on the
+host, about a minute a reference at 6,144 tokens; `--departures-at 120`
+runs the nine wrong ones at that step count alone).
 
 Prints one JSON line a step count (the adapter's own lines, with every
 reading, go to stderr).  PERF.md (PR 37) keeps what it read; what the
@@ -57,6 +63,10 @@ def main():
     ap.add_argument("--cell", default=CELL)
     ap.add_argument("--seed", type=int, default=7)
     ap.add_argument("--steps", default="98,110")
+    ap.add_argument("--departures-at", default=None,
+                    help="step counts (of --steps) at which the wrong "
+                         "references run too; the others read the exact "
+                         "and the all-bfloat16 one alone (default: all)")
     ap.add_argument("--rehearse", action="store_true",
                     help="the cell's rehearsal sizes on the CPU: proves the "
                          "plumbing, its readings mean nothing")
@@ -87,6 +97,8 @@ def main():
     sample = {k: v[:int(work["reference_rows"])] for k, v in ring[0].items()}
     place = fluid.CPUPlace() if args.rehearse else fluid.TPUPlace(0)
     exe, scope = fluid.Executor(place), fluid.Scope()
+    wrong_at = (None if args.departures_at is None
+                else [int(n) for n in args.departures_at.split(",") if n])
     ok, done = True, 0
     with fluid.scope_guard(scope):
         exe.run(built["startup"])
@@ -107,8 +119,10 @@ def main():
             params = [(p.name, scope.find_var(p.name))
                       for p in fwd["main"].global_block().all_parameters()]
             unit = adapter.bf16_unit(cfg, params, sample)
+            wrong = (adapter.DEPARTURES
+                     if wrong_at is None or steps in wrong_at else ())
             for dtype, departure in (
-                    [("float32", d) for d in (None,) + adapter.DEPARTURES]
+                    [("float32", d) for d in (None,) + wrong]
                     + [("bfloat16", None)]):
                 name = departure or ("exact" if dtype == "float32"
                                      else "all_" + dtype)
