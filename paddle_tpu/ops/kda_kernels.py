@@ -1,0 +1,337 @@
+"""Pallas TPU kernels for the inside of a `kda_attention` chunk
+(ops/kda_ops.py holds the op and the specification): everything a chunk of
+C = 64 tokens computes without the state it enters with (`intra`), and that
+computation transposed (`intra_bwd`).  Both are chunk-parallel: the grid
+walks batch, heads and blocks of `block` chunks of one head; q, k, v, g are
+read in place from [B, H, T, d] through the BlockSpecs' index maps, and a
+grid step holds its chunks in VMEM from the running sum of g to the
+results: the elementwise work on [block C, d] rows at once, every product
+batched over the block's chunks, so that their dependent chains interleave.
+
+The decayed products sum_c x_t[c] k_i[c] exp(G_t[c] - G_i[c]), i < t, are
+made level by level, m = 1, 2, .. 32: at level m the pairs whose positions
+first differ in bit m (t in the later, i in the earlier half of a block of
+2m tokens) go through the running sum where the later half starts (`ref`,
+the last row of the earlier half): (x_t exp(G_t - ref)) . (k_i exp(ref -
+G_i)), both factors <= 1 and their product the true value wherever that is
+not itself below the smallest float.  A level is ONE product of whole
+chunks whose operands are decayed by that level's references, of which the
+level's pairs are kept; the references come from G by sublane rolls.  No
+exponent is ever positive; t = i needs no decay.
+
+(I + A_kk)^-1 is made from the same levels: blocks of one token are their
+own inverse, and two inverted blocks T1, T2 with L below the diagonal
+between them make [[T1, 0], [-T2 L T1, T2]]: with `inv` block-diagonal and
+L the level's pairs of A_kk that is inv - inv L inv, two products a level
+at three bfloat16 passes (`_mm3`: float32 to some 2^-17, XLA's `HIGH`).
+
+The transposed inside makes the inside again, but for the inverse, which
+the backward's call of `intra` keeps for it, and transposes it by hand: with M = (I + A_kk)^-1 and R = beta [K exp(G) | V],
+dM = [dW | dU0] R^T, dR = M^T [dW | dU0], dA_kk = -M^T dM M^T below the
+diagonal; a level's transposed products are products with that level's
+decayed operands again; and the decay needs no pass of its own, because G
+enters a pair only through exp(G_t - G_i): dG = x (.) dx for the later
+operand of a pair and -k (.) dk for the earlier (the references cancel).
+
+Compiled on a TPU, interpreted elsewhere (`pallas_kernels._interpret`).
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+from . import pallas_kernels as _pk
+from .pallas_kernels import _mosaic_params, _note, _sds
+
+CHUNK = 64
+_F32 = jnp.float32
+_BF16 = jnp.bfloat16
+_LEVELS = (1, 2, 4, 8, 16, 32)
+
+
+def _bmm(a, b, dims, dtype):
+    """A product batched over the block's chunks, [n, ., .] x [n, ., .]
+    contracted over axes `dims` (a's, b's): operands in `dtype`, float32
+    accumulation (float32 operands at full precision)."""
+    return jax.lax.dot_general(
+        a.astype(dtype), b.astype(dtype),
+        (((dims[0],), (dims[1],)), ((0,), (0,))),
+        precision=(jax.lax.Precision.HIGHEST if dtype == _F32 else None),
+        preferred_element_type=_F32)
+
+
+def _split(x):
+    hi = x.astype(_BF16)
+    return hi, x - hi.astype(_F32)
+
+
+def _mm3(a, b, dims=(2, 1)):
+    """float32 a . b at three bfloat16 passes."""
+    (a_hi, a_lo), (b_hi, b_lo) = _split(a), _split(b)
+    return (_bmm(a_hi, b_hi, dims, _BF16)
+            + (_bmm(a_hi, b_lo, dims, _BF16) + _bmm(a_lo, b_hi, dims, _BF16)))
+
+
+def _tri_sum(x, upper):
+    """[n, C, d] float32 -> the running sum down a chunk's rows (`upper`:
+    from the row to the chunk's end), as products with a triangle of ones
+    (exact in bfloat16) of x in three bfloat16 parts: float32's sum."""
+    n, c, _ = x.shape
+    r = jax.lax.broadcasted_iota(jnp.int32, (n, c, c), 1)
+    s = jax.lax.broadcasted_iota(jnp.int32, (n, c, c), 2)
+    ones = jnp.where((s >= r) if upper else (s <= r), 1.0, 0.0).astype(_BF16)
+    hi, rest = _split(x)
+    mid, low = _split(rest)
+    return (_bmm(ones, hi, (2, 1), _BF16)
+            + (_bmm(ones, mid, (2, 1), _BF16) + _bmm(ones, low, (2, 1), _BF16)))
+
+
+def _roll(x, shift):
+    """jnp.roll down the rows: out[r] = x[r - shift]."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.roll(x, shift % x.shape[0], 0)
+
+
+def _pairs(n):
+    """The chunk's pairs (t, i) as [n, C, C] masks: t == i, i < t, and for
+    every level m those with i < t whose positions first differ in bit
+    m."""
+    t = jax.lax.broadcasted_iota(jnp.int32, (n, CHUNK, CHUNK), 1)
+    i = jax.lax.broadcasted_iota(jnp.int32, (n, CHUNK, CHUNK), 2)
+    differ = t ^ i
+    return t == i, t > i, [(t > i) & (differ >= m) & (differ < 2 * m)
+                           for m in _LEVELS]
+
+
+def _decays(gsum):
+    """gsum [rows, dk], the running sums of the block's chunks stacked:
+    -> (for every level (what its later rows are decayed by, exp(G_t -
+    ref); what its earlier rows are, exp(ref - G_i)), both [rows, dk] and
+    <= 1 on every row (rows that are not the level's are not used); G_C,
+    the chunk's last row, on every row of the chunk)."""
+    rows = gsum.shape[0]
+    pos = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+    out, last = [], gsum  # last[r] = G at the last row of r's block of m
+    for m in _LEVELS + (CHUNK,):
+        if m > 1:
+            last = jnp.where((pos & (m // 2)) == 0, _roll(last, -(m // 2)),
+                             last)
+        if m < CHUNK:
+            out.append((jnp.exp(jnp.minimum(gsum - _roll(last, m), 0.0)),
+                        jnp.exp(jnp.minimum(last - gsum, 0.0))))
+    return out, last
+
+
+def _column(row, eye):
+    """[n, 1, C] -> [n, C, 1] (and, with axes swapped, back) through the
+    diagonal: no transpose of a narrow array."""
+    return jnp.sum(jnp.where(eye, row, 0.0), axis=2, keepdims=True)
+
+
+def _row(column, eye):
+    return jnp.sum(jnp.where(eye, column, 0.0), axis=1, keepdims=True)
+
+
+def _inverse(a_kk, eye, levels):
+    """(I + A_kk)^-1 [n, C, C] for A_kk strictly lower triangular."""
+    inv = jnp.where(eye, 1.0, 0.0) - jnp.where(levels[0], a_kk, 0.0)
+    for pairs in levels[1:]:
+        inv = inv - _mm3(_mm3(inv, jnp.where(pairs, a_kk, 0.0)), inv)
+    return inv
+
+
+def _chunk_row(x):
+    """[n, C, d] whose rows are alike within a chunk -> [n, 1, d]."""
+    return jnp.max(x, axis=1, keepdims=True)
+
+
+def _flat(x):
+    return x.reshape(x.shape[0] * x.shape[1], x.shape[2])
+
+
+def _intra_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref,
+                  w_ref, u0_ref, a_ref, qg_ref, kd_ref, gamma_ref,
+                  *solve_ref, scale):
+    dtype = q_ref.dtype
+    n, c = w_ref.shape[0], CHUNK
+    shape = (n, c, q_ref.shape[-1])
+    gsum = _flat(_tri_sum(g_ref[...].astype(_F32).reshape(shape), False))
+    qf = q_ref[...].astype(_F32) * scale
+    kf = k_ref[...].astype(_F32)
+    eye, _, levels = _pairs(n)
+    decays, last = _decays(gsum)
+    a_qk = jnp.where(eye, jnp.sum(qf * kf, -1, keepdims=True).reshape(
+        n, c, 1), 0.0)
+    l_kk = jnp.zeros((n, c, c), _F32)
+    for pairs, (later, earlier) in zip(levels, decays):
+        here = jnp.concatenate([(qf * later).reshape(shape),
+                                (kf * later).reshape(shape)], 1)
+        below = _bmm(here, (kf * earlier).reshape(shape), (2, 2), dtype)
+        a_qk = jnp.where(pairs, below[:, :c], a_qk)
+        l_kk = jnp.where(pairs, below[:, c:], l_kk)
+    beta = _column(beta_ref[...], eye)
+    solve = _inverse(beta * l_kk, eye, levels)
+    into = jnp.exp(gsum)
+    w_ref[...] = _bmm(solve, beta * (kf * into).reshape(shape), (2, 1),
+                      dtype).astype(dtype)
+    u0_ref[...] = _bmm(
+        solve, beta * v_ref[...].astype(_F32).reshape(n, c, v_ref.shape[-1]),
+        (2, 1), dtype)
+    a_ref[...] = a_qk.astype(dtype)
+    qg_ref[...] = (qf * into).reshape(shape).astype(dtype)
+    kd_ref[...] = (kf * jnp.exp(last - gsum)).reshape(shape).astype(dtype)
+    gamma_ref[...] = _chunk_row(jnp.exp(last).reshape(shape))
+    for ref in solve_ref:  # the backward's call: kept for `intra_bwd`
+        ref[...] = solve
+
+
+def _intra_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, solve_ref,
+                      dw_ref, du0_ref, da_ref, dqg_ref, dkd_ref, dgamma_ref,
+                      dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, *, scale):
+    dtype = q_ref.dtype
+    n, c = dw_ref.shape[0], CHUNK
+    shape = (n, c, q_ref.shape[-1])
+    gsum = _flat(_tri_sum(g_ref[...].astype(_F32).reshape(shape), False))
+    qf = q_ref[...].astype(_F32) * scale
+    kf = k_ref[...].astype(_F32)
+    vf = v_ref[...].astype(_F32)
+    eye, lower, levels = _pairs(n)
+    decays, last = _decays(gsum)
+    # the inside again, as far as the transposition reads it (the inverse
+    # is kernel 1's: ten dependent three-pass products, a third of this
+    # kernel's time when it made them again)
+    l_kk = jnp.zeros((n, c, c), _F32)
+    for pairs, (later, earlier) in zip(levels, decays):
+        l_kk = jnp.where(pairs, _bmm(
+            (kf * later).reshape(shape), (kf * earlier).reshape(shape),
+            (2, 2), dtype), l_kk)
+    beta3 = _column(beta_ref[...], eye)
+    beta = _flat(beta3)
+    solve = solve_ref[...]
+    into = jnp.exp(gsum)
+    out = jnp.exp(last - gsum)
+    k_into = kf * into
+    # [W | U0] = solve . beta [K exp(G) | V]
+    dw, du0 = dw_ref[...], du0_ref[...]
+    d_solve = (_bmm(dw, (beta * k_into).reshape(shape), (2, 2), dtype)
+               + _bmm(du0, (beta * vf).reshape(du0.shape), (2, 2), dtype))
+    d_rk = _flat(_bmm(solve, dw, (1, 1), dtype))
+    d_rv = _flat(_bmm(solve, du0, (1, 1), dtype))
+    d_akk = jnp.where(
+        lower, -_mm3(_mm3(solve, d_solve, (1, 1)), solve, (2, 2)), 0.0)
+    d_lkk = beta3 * d_akk
+    d_aqk = da_ref[...].astype(_F32)
+    dbeta = (jnp.sum(d_akk * l_kk, 2, keepdims=True)
+             + jnp.sum(d_rk * k_into, -1, keepdims=True).reshape(n, c, 1)
+             + jnp.sum(d_rv * vf, -1, keepdims=True).reshape(n, c, 1))
+    # the decayed products, level by level: as the later operand of a
+    # pair (dq_l, dk_l) and as the earlier one (dk_e)
+    dq_l = jnp.zeros_like(qf)
+    dk_l = jnp.zeros_like(kf)
+    dk_e = jnp.zeros_like(kf)
+    for pairs, (later, earlier) in zip(levels, decays):
+        d_below = jnp.concatenate([jnp.where(pairs, d_aqk, 0.0),
+                                   jnp.where(pairs, d_lkk, 0.0)], 1)
+        here = jnp.concatenate([(qf * later).reshape(shape),
+                                (kf * later).reshape(shape)], 1)
+        d_here = _bmm(d_below, (kf * earlier).reshape(shape), (2, 1), dtype)
+        dq_l = dq_l + _flat(d_here[:, :c]) * later
+        dk_l = dk_l + _flat(d_here[:, c:]) * later
+        dk_e = dk_e + _flat(_bmm(d_below, here, (1, 1), dtype)) * earlier
+    on_diagonal = _flat(jnp.sum(jnp.where(eye, d_aqk, 0.0), 2,
+                                keepdims=True))
+    d_qg = dqg_ref[...].astype(_F32).reshape(qf.shape)
+    d_kd = dkd_ref[...].astype(_F32).reshape(kf.shape)
+    dq_ref[...] = (scale * (dq_l + on_diagonal * kf + d_qg * into)).astype(
+        dq_ref.dtype)
+    dk_ref[...] = (dk_l + dk_e + on_diagonal * qf + beta * into * d_rk
+                   + d_kd * out).astype(dk_ref.dtype)
+    dv_ref[...] = (beta * d_rv).astype(dv_ref.dtype)
+    leaving = d_kd * kf * out  # through exp(G_C - G)
+    d_gsum = (qf * dq_l + kf * (dk_l - dk_e) + (d_qg * qf + beta * kf * d_rk)
+              * into - leaving)
+    d_last = (jnp.sum(leaving.reshape(shape), 1, keepdims=True)
+              + dgamma_ref[...] * _chunk_row(jnp.exp(last).reshape(shape)))
+    dg_ref[...] = _flat(_tri_sum(d_gsum.reshape(shape), True) + d_last)
+    dbeta_ref[...] = _row(dbeta, eye)
+
+
+def _specs(b, h, t, block):
+    """(grid, the BlockSpec of a [B, H, T, d] array's block of chunks, of a
+    [N, B, H, ., d] array's, of beta's [B, H, N, 1, C])."""
+    from jax.experimental import pallas as pl
+
+    def tokens(d):
+        return pl.BlockSpec((None, None, block * CHUNK, d),
+                            lambda i, j, l: (i, j, l, 0))
+
+    def parts(rows, d):
+        return pl.BlockSpec((block, None, None, rows, d),
+                            lambda i, j, l: (l, i, j, 0, 0))
+
+    return ((b, h, t // (block * CHUNK)), tokens, parts,
+            pl.BlockSpec((None, None, block, 1, CHUNK),
+                         lambda i, j, l: (i, j, l, 0, 0)))
+
+
+def intra(q, k, v, g, beta, scale, block, keep_solve=False):
+    """q, k, g [B, H, T, dk], v [B, H, T, dv], beta [B, H, T], T a multiple
+    of `block` chunks -> (W, U0, A_qk, Q exp(G), K exp(G_C - G), exp(G_C)),
+    chunks leading ([N, B, H, C, .]; exp(G_C) [N, B, H, dk]): the operands
+    of the carry's products in q's dtype, U0 and the chunk's whole decay
+    float32; with `keep_solve` (I + A_kk)^-1 [N, B, H, C, C] float32 after
+    them, for `intra_bwd`."""
+    from jax.experimental import pallas as pl
+
+    b, h, t, dk = q.shape
+    dv, c, chunks = v.shape[-1], CHUNK, t // CHUNK
+    grid, tokens, parts, beta_spec = _specs(b, h, t, block)
+    _note("kda_intra")
+    out = pl.pallas_call(
+        functools.partial(_intra_kernel, scale=scale),
+        grid=grid,
+        in_specs=[tokens(dk), tokens(dk), tokens(dv), tokens(dk), beta_spec],
+        out_specs=[parts(c, dk), parts(c, dv), parts(c, c), parts(c, dk),
+                   parts(c, dk), parts(1, dk)] + [parts(c, c)] * keep_solve,
+        out_shape=[_sds((chunks, b, h, c, dk), q.dtype, q),
+                   _sds((chunks, b, h, c, dv), _F32, q),
+                   _sds((chunks, b, h, c, c), q.dtype, q),
+                   _sds((chunks, b, h, c, dk), q.dtype, q),
+                   _sds((chunks, b, h, c, dk), q.dtype, q),
+                   _sds((chunks, b, h, 1, dk), _F32, q)]
+        + [_sds((chunks, b, h, c, c), _F32, q)] * keep_solve,
+        interpret=_pk._interpret(),
+        compiler_params=_mosaic_params(),
+    )(q, k, v, g, beta.astype(_F32).reshape(b, h, chunks, 1, c))
+    return (tuple(out[:5]) + (out[5].reshape(chunks, b, h, dk),)
+            + tuple(out[6:]))
+
+
+def intra_bwd(q, k, v, g, beta, solve, d_parts, scale, block):
+    """`intra` transposed: its inputs, the inverse it kept and the six
+    results' gradients (as `intra` lays them out) -> the gradients of q,
+    k, v (in their dtypes), g and beta (float32)."""
+    from jax.experimental import pallas as pl
+
+    b, h, t, dk = q.shape
+    dv, c, chunks = v.shape[-1], CHUNK, t // CHUNK
+    dw, du0, da, dqg, dkd, dgamma = d_parts
+    grid, tokens, parts, beta_spec = _specs(b, h, t, block)
+    _note("kda_intra_bwd")
+    dq, dk_, dv_, dg, dbeta = pl.pallas_call(
+        functools.partial(_intra_bwd_kernel, scale=scale),
+        grid=grid,
+        in_specs=[tokens(dk), tokens(dk), tokens(dv), tokens(dk), beta_spec,
+                  parts(c, c), parts(c, dk), parts(c, dv), parts(c, c),
+                  parts(c, dk), parts(c, dk), parts(1, dk)],
+        out_specs=[tokens(dk), tokens(dk), tokens(dv), tokens(dk), beta_spec],
+        out_shape=[_sds(q.shape, q.dtype, q), _sds(k.shape, k.dtype, q),
+                   _sds(v.shape, v.dtype, q), _sds(g.shape, _F32, q),
+                   _sds((b, h, chunks, 1, c), _F32, q)],
+        interpret=_pk._interpret(),
+        compiler_params=_mosaic_params(),
+    )(q, k, v, g, beta.astype(_F32).reshape(b, h, chunks, 1, c), solve,
+      dw, du0, da, dqg, dkd, dgamma.astype(_F32).reshape(chunks, b, h, 1, dk))
+    return dq, dk_, dv_, dg, dbeta.reshape(b, h, t)

@@ -30,7 +30,7 @@ _moe_live_chunks = {"ops": 0, "chunk_rows": {}}  # chunk_rows: N*k -> rows a chu
 _attention_band_grid = {"ops": 0, "steps": {}}  # "TxWxBQxBK" -> [walked, computed]
 # kda_attention lowerings (a grad op lowers its forward again), with the
 # chunking each length got
-_kda_chunks = {"ops": 0, "lengths": {}}  # T -> [chunk, chunks a group, T, padded T]
+_kda_chunks = {"ops": 0, "lengths": {}}  # T -> [chunk, chunks a grid step, T, padded T]
 
 
 def note_kernel(family, n=1):
@@ -81,13 +81,13 @@ def note_band_grid(t, window, block_q, block_k, walked, computed):
             t, window, block_q, block_k)] = [int(walked), int(computed)]
 
 
-def note_kda_chunks(t, padded_t, chunk, group):
+def note_kda_chunks(t, padded_t, chunk, block):
     """Count a trace-time lowering of a `kda_attention`, and keep by length
-    the chunk it ran at, the chunks whose inside it computes at once and
+    the chunk it ran at, the chunks a grid step of its kernels holds and
     the length it padded to."""
     with _lock:
         _kda_chunks["ops"] += 1
-        _kda_chunks["lengths"][int(t)] = [int(chunk), int(group), int(t),
+        _kda_chunks["lengths"][int(t)] = [int(chunk), int(block), int(t),
                                           int(padded_t)]
 
 
@@ -97,7 +97,7 @@ def attribution():
     type, the moe_ffn lowerings that took the live-chunk path with the
     rows of a chunk by buffer size, the windowed flash lowerings with their
     forward grid steps walked and computed by shape, the kda_attention
-    lowerings with [chunk, chunks a group, T, padded T] by length."""
+    lowerings with [chunk, chunks a grid step, T, padded T] by length."""
     with _lock:
         return {
             "pallas_hits": dict(_kernel_hits),

@@ -1,9 +1,12 @@
-"""kda_attention: Kimi Delta Attention's chunkwise lowering (ops/kda_ops.py)
-against the token-by-token recurrence it stands for, written here in a
-lax.scan over T: the result and every input's gradient, at beta = 0 (pure
+"""kda_attention: Kimi Delta Attention's chunkwise lowering (ops/kda_ops.py,
+its chunk inside the two Pallas kernels of ops/kda_kernels.py, interpreted
+here) against the token-by-token recurrence it stands for, written here in
+a lax.scan over T: the result and every input's gradient, at beta = 0 (pure
 decay), g = 0 (the plain delta rule), log-decays down to -5 a token a
-channel (exp(+320) over a chunk if it were ever taken), lengths that pad
-(1, 63, 65, 200) and that do not (64), one group of chunks and several;
+channel (exp(+320) over a chunk if it were ever taken) and so slow that a
+chunk hands half its state on, lengths that pad (1, 63, 65, 200, 600) and
+that do not (64), one chunk a grid step and several,
+at the narrow heads of most cases and at the cell's (dk = dv = 128);
 through a Program with its grad op, under the AMP pass, its infer rule,
 its line in program_flops and what it leaves in attribution(); and
 causal_conv, the ungated depthwise convolution beside short_conv, against
@@ -45,15 +48,15 @@ def recurrence(q, k, v, g, beta, scale=SCALE):
     return jnp.moveaxis(o, 0, 2)
 
 
-def _data(t, kind="mixed"):
+def _data(t, kind="mixed", dk=DK, dv=DV):
     """q and k on the unit sphere (as the model's L2 norm leaves them), v
     normal, beta in (0, 1), g by `kind`; `mix` weights the result so that
     the loss is no constant."""
     rng = np.random.RandomState(7 + t)
-    q, k = (rng.randn(B, H, t, DK).astype("float32") for _ in range(2))
+    q, k = (rng.randn(B, H, t, dk).astype("float32") for _ in range(2))
     q /= np.linalg.norm(q, axis=-1, keepdims=True)
     k /= np.linalg.norm(k, axis=-1, keepdims=True)
-    g = -rng.uniform(0.001, 1.6, (B, H, t, DK)).astype("float32")
+    g = -rng.uniform(0.001, 1.6, (B, H, t, dk)).astype("float32")
     beta = rng.uniform(0.05, 0.95, (B, H, t)).astype("float32")
     if kind == "pure_decay":
         beta = np.zeros_like(beta)
@@ -63,20 +66,24 @@ def _data(t, kind="mixed"):
         g = np.where(rng.rand(*g.shape) < 0.5, -5.0, g).astype("float32")
     elif kind == "all_fast":
         g = np.full_like(g, -5.0)
-    return {"Q": q, "K": k, "V": rng.randn(B, H, t, DV).astype("float32"),
+    elif kind == "slow":  # exp(G_C) ~ 0.5: the states reach far, and the
+        g = g / 80.0      # chunk's whole decay has a gradient that counts
+    return {"Q": q, "K": k, "V": rng.randn(B, H, t, dv).astype("float32"),
             "G": g, "Beta": beta,
-            "mix": rng.uniform(0.5, 1.5, (B, H, t, DV)).astype("float32")}
+            "mix": rng.uniform(0.5, 1.5, (B, H, t, dv)).astype("float32")}
 
 
 @functools.lru_cache(maxsize=None)
-def _both(t, kind):
+def _both(t, kind, width=DK):
     """((result, gradients by input) of the op's lowering, the same of the
-    recurrence)."""
-    w = _data(t, kind)
+    recurrence); `width`: dk, and dv where it is not the narrow DK."""
+    w = _data(t, kind, width, DV if width == DK else width)
     args = [jnp.asarray(w[n]) for n in INPUTS]
+    scale = width ** -0.5
     out = []
     with jax.default_matmul_precision("highest"):
-        for f in (lambda *a: kda_ops.kda_chunked(*a, SCALE), recurrence):
+        for f in (lambda *a: kda_ops.kda_chunked(*a, scale),
+                  lambda *a: recurrence(*a, scale)):
             o, pull = jax.jit(lambda *a: jax.vjp(f, *a))(*args)
             out.append((np.asarray(o), dict(zip(INPUTS, map(
                 np.asarray, jax.jit(pull)(jnp.asarray(w["mix"])))))))
@@ -84,28 +91,34 @@ def _both(t, kind):
 
 
 # every length with mixed decays; each special decay where a chunk is
-# whole, where it pads and over several chunks
-CASES = ([(t, "mixed") for t in (1, 63, 64, 65, 200)]
-         + [(65, "pure_decay"), (200, "pure_decay"), (64, "no_decay"),
-            (200, "no_decay"), (65, "fast"), (200, "fast"),
-            (63, "all_fast"), (200, "all_fast")])
+# whole, where it pads and over several chunks (T = 200 is four chunks in
+# one grid step, T = 600 ten, padded to two steps of eight); at the cell's
+# head shape, dk = dv = 128, a length that pads and one that forgets in a
+# token
+CASES = ([(t, "mixed", DK) for t in (1, 63, 64, 65, 200, 600)]
+         + [(65, "pure_decay", DK), (200, "pure_decay", DK),
+            (64, "no_decay", DK), (200, "no_decay", DK), (65, "fast", DK),
+            (200, "fast", DK), (63, "all_fast", DK), (200, "all_fast", DK),
+            (200, "slow", DK), (130, "mixed", 128), (130, "fast", 128)])
 
 
-@pytest.mark.parametrize("t, kind", CASES)
-def test_the_chunkwise_result_is_the_recurrences(t, kind):
-    (got, _), (want, _) = _both(t, kind)
-    assert got.shape == want.shape == (B, H, t, DV)
+@pytest.mark.parametrize("t, kind, width", CASES)
+def test_the_chunkwise_result_is_the_recurrences(t, kind, width):
+    (got, _), (want, _) = _both(t, kind, width)
+    assert got.shape == want.shape == (B, H, t, DV if width == DK else width)
     assert np.isfinite(got).all()
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
 
 
 @pytest.mark.parametrize("wrt", INPUTS)
-@pytest.mark.parametrize("t, kind", CASES)
-def test_every_gradient_is_jax_grad_of_the_recurrence(t, kind, wrt):
-    """The op's own backward (the inside made again, two walks over the
-    groups) against autodiff of the recurrence: 1e-4 of the gradient's
-    largest element (measured: 7e-6 or less)."""
-    (_, got), (_, want) = _both(t, kind)
+@pytest.mark.parametrize("t, kind, width", CASES)
+def test_every_gradient_is_jax_grad_of_the_recurrence(t, kind, width, wrt):
+    """The op's own backward (kernel 1 again for the carry's operands, the
+    carry forward for the entering states and backwards, then the
+    transposed inside by hand in kernel 2) against autodiff of the
+    recurrence: 1e-4 of the gradient's largest element (measured: 1e-5 or
+    less)."""
+    (_, got), (_, want) = _both(t, kind, width)
     g, w = got[wrt], want[wrt]
     assert g.shape == w.shape and np.isfinite(g).all()
     assert np.abs(g - w).max() <= 1e-4 * max(np.abs(w).max(), 1e-3), wrt
@@ -154,15 +167,14 @@ def test_output_at_t_does_not_see_inputs_after_t():
     assert np.abs(a[:, :, cut + 1] - b[:, :, cut + 1]).max() > 0.1
 
 
-@pytest.mark.parametrize("group", [1, 2])
-def test_several_groups_of_chunks_are_one_group(monkeypatch, group):
-    """T = 200 is four chunks: one group at GROUP 16, two at GROUP 2, four
-    at GROUP 1; the outer walk hands the state on and the backward's two
-    walks find it again: the same result and gradients (to rounding: the
-    products are the same, batched otherwise)."""
+@pytest.mark.parametrize("block", [1, 2])
+def test_several_chunks_a_grid_step_are_one_a_step(monkeypatch, block):
+    """T = 200 is four chunks: one grid step a head at BLOCK 8, two at
+    BLOCK 2, four at BLOCK 1: the same result and gradients (to rounding:
+    the products are the same, batched otherwise)."""
     (want, want_grads), _ = _both(200, "mixed")
-    monkeypatch.setattr(kda_ops, "GROUP", group)
-    assert kda_ops._groups(4) == group
+    monkeypatch.setattr(kda_ops, "BLOCK", block)
+    assert kda_ops._block(200) == block
     w = _data(200, "mixed")
     args = [jnp.asarray(w[n]) for n in INPUTS]
     with jax.default_matmul_precision("highest"):
@@ -175,19 +187,47 @@ def test_several_groups_of_chunks_are_one_group(monkeypatch, group):
         np.testing.assert_allclose(g, want_grads[n], rtol=1e-4, atol=1e-5)
 
 
-def test_groups_divide_the_chunks():
-    assert [kda_ops._groups(n) for n in (1, 4, 16, 17, 18, 64, 96, 128)] == [
-        1, 4, 16, 1, 9, 16, 16, 16]
-    assert (kda_ops.CHUNK, kda_ops.GROUP) == (64, 16)
+def test_a_length_pads_to_whole_grid_steps():
+    """Up to BLOCK chunks are one grid step of whole chunks; beyond, the
+    length pads to whole steps of BLOCK chunks (no divisor is looked for:
+    97 chunks are 13 steps of 8, not 97 of one)."""
+    assert (kda_ops.CHUNK, kda_ops.BLOCK) == (64, 8)
+    lengths = (1, 64, 65, 200, 512, 513, 600, 6144, 6208)
+    assert [kda_ops._block(t) for t in lengths] == [1, 1, 2, 4, 8, 8, 8, 8, 8]
+    assert [kda_ops._padded(t) for t in lengths] == [
+        64, 64, 128, 256, 512, 1024, 1024, 6144, 6656]
+
+
+def test_lowered_for_a_tpu_the_inside_is_three_mosaic_calls(monkeypatch):
+    """Compiled where interpreted here: forward + backward of the op at the
+    cell's head shape lower to kernel 1, kernel 1 again and kernel 2, and
+    no flag chose them."""
+    from paddle_tpu.ops import pallas_kernels
+
+    monkeypatch.setattr(pallas_kernels, "_interpret", lambda: False)
+    jax.clear_caches()
+    x = jax.ShapeDtypeStruct((1, 2, 1024, 128), jnp.bfloat16)
+    g = jax.ShapeDtypeStruct((1, 2, 1024, 128), jnp.float32)
+    beta = jax.ShapeDtypeStruct((1, 2, 1024), jnp.float32)
+    text = jax.jit(jax.value_and_grad(
+        lambda *a: kda_ops.kda_chunked(*a, 128 ** -0.5).astype(
+            jnp.float32).sum(), argnums=range(5))).trace(
+                x, x, x, g, beta).lower(lowering_platforms=("tpu",)).as_text()
+    jax.clear_caches()
+    assert text.count("tpu_custom_call") == 3
+
+
+def _half(t, kind):
+    w = _data(t, kind)
+    args = [jnp.asarray(w[n]) for n in INPUTS]
+    return [a.astype(jnp.bfloat16) for a in args[:3]] + args[3:], w["mix"]
 
 
 def test_bf16_operands_float32_state():
     """bf16 q, k, v with float32 g and beta: a bf16 result, within bf16
     rounding of the float32 recurrence on the same (rounded) inputs, and
     float32 gradients for g and beta."""
-    w = _data(130)
-    args = [jnp.asarray(w[n]) for n in INPUTS]
-    half = [a.astype(jnp.bfloat16) for a in args[:3]] + args[3:]
+    half, _ = _half(130, "mixed")
     got = kda_ops.kda_chunked(*half, SCALE)
     assert got.dtype == jnp.bfloat16
     want = recurrence(*[a.astype(jnp.float32) for a in half])
@@ -195,7 +235,41 @@ def test_bf16_operands_float32_state():
     grads = jax.grad(lambda *a: kda_ops.kda_chunked(*a, SCALE).astype(
         jnp.float32).sum(), argnums=range(5))(*half)
     assert [str(g.dtype) for g in grads] == ["bfloat16"] * 3 + ["float32"] * 2
-    assert all(np.isfinite(np.asarray(g, "float32")).all() for g in grads)
+
+
+@functools.lru_cache(maxsize=None)
+def _half_grads():
+    half, mix = _half(200, "slow")
+    return [jax.grad(lambda *a: (f(*a).astype(jnp.float32) * mix).sum(),
+                     argnums=range(5))(*x)
+            for f, x in ((lambda *a: kda_ops.kda_chunked(*a, SCALE), half),
+                         (recurrence, [a.astype(jnp.float32) for a in half]))]
+
+
+@pytest.mark.parametrize("wrt", INPUTS)
+def test_bf16_operands_every_gradient_is_the_recurrences(wrt):
+    """Slow decays over four chunks, so that the entering states and the
+    gradient through a chunk's whole decay count: every gradient within 2%
+    of the largest element of the float32 recurrence's on the same
+    (rounded) inputs (measured: 0.5-0.7%, the products' bf16 operands)."""
+    got, want = (np.asarray(g[INPUTS.index(wrt)], "float32")
+                 for g in _half_grads())
+    assert np.isfinite(got).all()
+    assert np.abs(got - want).max() <= 0.02 * np.abs(want).max()
+
+
+def test_the_backward_keeps_the_entering_states_float32():
+    """The state every chunk entered with is summed elementwise against
+    the carried gradient (the gradient through exp(G_C)): under bf16
+    operands the backward stacks it float32, as it carries it; only the
+    products narrow it (rounding it where it is stacked moves dg by less
+    than the products' own rounding, so no tolerance would say)."""
+    half, _ = _half(200, "slow")
+    jaxpr = jax.make_jaxpr(jax.grad(lambda *a: kda_ops.kda_chunked(
+        *a, SCALE).astype(jnp.float32).sum(), argnums=range(5)))(*half)
+    states = [v.aval for eqn in jaxpr.eqns if eqn.primitive.name == "scan"
+              for v in eqn.outvars if v.aval.shape == (4, B, H, DK, DV)]
+    assert states and all(a.dtype == jnp.float32 for a in states)
 
 
 # --- through a Program ------------------------------------------------------
@@ -245,6 +319,10 @@ def test_attribution_says_how_each_length_was_chunked():
     # the forward op and the grad op's lowering of it
     assert found["ops"] == 2
     assert found["lengths"] == {65: [64, 2, 65, 128]}
+    # kernel 1: the forward op, and the grad op twice (its forward, traced
+    # and then dead, and its backward); kernel 2: the grad op
+    hits = kernel_tuning.attribution()["pallas_hits"]
+    assert (hits["kda_intra"], hits["kda_intra_bwd"]) == (3, 1)
 
 
 def test_amp_pass_narrows_q_k_v_and_keeps_the_decay_and_beta_float32():

@@ -69,7 +69,17 @@ chunkwise `kda_attention` op, three `causal_conv` ops a layer, and
 of the experts held), its digest taken from PR 45's tree by this file's
 `_digest`.  The twelve digests above did not move, `kanana2`'s above all:
 `latent_attention`'s new switch builds the ops it built, in their order,
-where rotary stays on."""
+where rotary stays on.
+
+PR 46 made `kda_attention`'s chunk inside two Pallas kernels
+(ops/kda_kernels.py: `intra`, and `intra_bwd`, the inside transposed by
+hand), so `kimi_linear`'s step changed on purpose: its digest below is
+taken from PR 46's final tree (the backward stacks the entering states
+float32, as it carries them) by this file's `_digest`, and its Mosaic
+calls went from 9 to 15 (three a KDA layer: `intra` in the forward, `intra` again and
+`intra_bwd` in the grad op; the tiny program's two KDA layers engage both
+kernels at heads of 128, one chunk-block of 8 a head).  The other twelve
+digests and counts did not move: no other program holds the op."""
 
 import base64
 import hashlib
@@ -209,9 +219,9 @@ PROGRAMS = {"gpt2": lambda: _lm(gpt2.gpt2_lm_program, G),
 
 # name -> (sha1 of the normalised text, Mosaic calls in it) at ec9cdf7
 # (`lfm2`: at PR 39; `trinity`: at PR 40; `kanana2`: at PR 43; `transformer`,
-# `resnet`, `ouro`: at 6a7549d, PR 44's parent; `kimi_linear`: at PR 45)
+# `resnet`, `ouro`: at 6a7549d, PR 44's parent; `kimi_linear`: at PR 46)
 BEFORE = {
-    "kimi_linear": ("dc0c89b9ad44d0f6a0461d8402db4259f57a38ec", 9),
+    "kimi_linear": ("b1186584b9717d9847564035b6096993865060d7", 15),
     "transformer": ("e83306d8f28e59df41bc44b9fe7303322f29a58b", 0),
     "resnet": ("84575b13d140437bb64cb8461436105337774a6d", 0),
     "ouro": ("b1722471731c977327d882d12eb8e5106bcca444", 3),

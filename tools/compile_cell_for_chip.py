@@ -2,7 +2,9 @@
 DESCRIBED (not attached) TPU v5e on this host, and print the TPU
 compiler's memory counts: what the chip's compiler would refuse costs no
 chip time (on-chip-measurement guide, section 2).  Nothing runs: no time,
-no result.  One-chip cells only.
+no result.  One-chip cells only.  Pallas kernels are compiled as Mosaic
+calls, as on the chip (until PR 46 they were interpreted here, and the
+counts read 0.05 GiB lower on `kimi_linear_48b_a3b_train`).
 
     JAX_PLATFORMS=cpu python tools/compile_cell_for_chip.py \
         --workload kanana2_30b_a3b_train [--seq-len 6144]
@@ -35,6 +37,12 @@ def main():
 
     import paddle_tpu as fluid
     from paddle_tpu.core.trace import build_traced_function
+    from paddle_tpu.ops import pallas_kernels
+
+    # the Pallas kernels as the chip compiles them: this host's backend is
+    # the CPU, where they would be interpreted, and the count would be of a
+    # program that never runs (PERF.md section 6, PR 46)
+    pallas_kernels._interpret = lambda: False
 
     # benchmark/run.py as a module: the registry is read as it reads it
     run_spec = importlib.util.spec_from_file_location(

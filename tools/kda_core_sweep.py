@@ -8,11 +8,12 @@ or the transposed backward) and its longest device ops, and holds the
 result against the token-by-token recurrence in float32 at a shorter
 length.  Run on a TPU:
 
-    python3 tools/kda_core_sweep.py [--seq-len 6144] [--group 16]
+    python3 tools/kda_core_sweep.py [--seq-len 6144] [--block 8]
 
-(`--rehearse`: tiny sizes on the CPU, proves the plumbing.)  `--group`
-runs the op at another number of chunks a group (`kda_ops.GROUP`).  Prints
-one JSON line; PERF.md (PR 45) keeps what it read.
+(`--rehearse`: tiny sizes on the CPU, proves the plumbing.)  `--block`
+runs the op's kernels at another number of chunks a grid step
+(`kda_ops.BLOCK`).  Prints one JSON line; PERF.md (PRs 45, 46) keeps what
+it read.
 """
 
 import argparse
@@ -94,7 +95,7 @@ def _device_ops(trace_dir):
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--seq-len", type=int, default=6144)
-    ap.add_argument("--group", type=int, default=None)
+    ap.add_argument("--block", type=int, default=None)
     ap.add_argument("--seed", type=int, default=45)
     ap.add_argument("--reps", type=int, default=5)
     ap.add_argument("--rehearse", action="store_true")
@@ -109,8 +110,8 @@ def main():
     if not args.rehearse and jax.devices()[0].platform != "tpu":
         raise SystemExit("kda_core_sweep: needs a TPU, jax found %s"
                          % jax.devices())
-    if args.group:
-        kda_ops.GROUP = args.group
+    if args.block:
+        kda_ops.BLOCK = args.block
     b, h, t, d = (1, 2, 200, 16) if args.rehearse else (1, 32, args.seq_len,
                                                         128)
     scale = d ** -0.5
@@ -123,7 +124,7 @@ def main():
         lambda *a: (kda_ops.kda_chunked(*a, scale).astype(jnp.float32)
                     * mix.astype(jnp.float32)).sum(), argnums=range(5)))
     out = {"shape": [b, h, t, d], "chunk": kda_ops.CHUNK,
-           "group": kda_ops._groups(kda_ops._padded(t) // kda_ops.CHUNK),
+           "block": kda_ops._block(t),
            "device": jax.devices()[0].device_kind,
            "forward_ms": _timed(fwd, ins, args.reps),
            "forward_backward_ms": _timed(both, ins, args.reps)}
