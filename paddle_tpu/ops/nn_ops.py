@@ -561,17 +561,35 @@ def _mm_act(z, act):
     raise ValueError("matmul epilogue: unsupported activation %r" % (act,))
 
 
+# The activations whose derivative reads the pre-activation itself (relu's,
+# tanh's and sigmoid's are read off the op's output, an identity's needs
+# nothing): the step needs both the pre-activation, for the backward, and the
+# output, for the next op.  Applied behind the reshape to [..., N] the
+# compiler keeps the pre-activation alone and computes the activation again
+# inside every matmul that consumes it; applied to the [M, N] product it
+# fuses bias and activation into the matmul that made it and writes both
+# (GPT-2 345M on a v5e: PERF.md section 6, PR 47).
+FC_PRODUCT_EPILOGUE_ACTS = ("gelu", "swish")
+
+
 @register("fc")
 def _fc(ctx, ins, attrs):
     """Fused fully-connected (fc_op of fc_fuse_pass.cc): mul + bias-add +
-    activation in one op: one MXU matmul with an XLA-fused epilogue."""
+    activation in one op: one MXU matmul with an XLA-fused epilogue, on
+    the [M, N] product under FC_PRODUCT_EPILOGUE_ACTS (the same values: the
+    reshape moves behind two elementwise ops)."""
     x, w = ins["Input"][0], ins["W"][0]
     k = int(attrs.get("in_num_col_dims", 1))
     x2 = x.reshape((int(np.prod(x.shape[:k])), -1))
     act = attrs.get("activation_type", "") or ""
     bias = ins["Bias"][0].reshape(-1) if ins.get("Bias") else None
     out = x2 @ w
-    out = out.reshape(tuple(x.shape[:k]) + (w.shape[-1],))
+    shape = tuple(x.shape[:k]) + (w.shape[-1],)
+    if act in FC_PRODUCT_EPILOGUE_ACTS:
+        if bias is not None:
+            out = out + bias.reshape(1, -1)
+        return {"Out": [_mm_act(out, act).reshape(shape)]}
+    out = out.reshape(shape)
     if bias is not None:
         out = out + bias.reshape((1,) * k + (-1,))
     return {"Out": [_mm_act(out, act)]}

@@ -79,7 +79,15 @@ float32, as it carries them) by this file's `_digest`, and its Mosaic
 calls went from 9 to 15 (three a KDA layer: `intra` in the forward, `intra` again and
 `intra_bwd` in the grad op; the tiny program's two KDA layers engage both
 kernels at heads of 128, one chunk-block of 8 a head).  The other twelve
-digests and counts did not move: no other program holds the op."""
+digests and counts did not move: no other program holds the op.
+
+PR 47 moved `fc`'s bias and activation in front of its reshape under gelu
+and swish (ops/nn_ops.FC_PRODUCT_EPILOGUE_ACTS: the epilogue on the [M, N]
+product), so `gpt2`'s step changed on purpose (one gelu `fc` a layer): its
+digest below is taken from PR 47's tree by this file's `_digest`; its
+Mosaic calls stayed 3.  The other twelve digests and counts did not move:
+`transformer`'s `fc` ops carry relu or no activation, `resnet`'s none, and
+no other pinned program holds an `fc` under gelu or swish."""
 
 import base64
 import hashlib
@@ -219,7 +227,8 @@ PROGRAMS = {"gpt2": lambda: _lm(gpt2.gpt2_lm_program, G),
 
 # name -> (sha1 of the normalised text, Mosaic calls in it) at ec9cdf7
 # (`lfm2`: at PR 39; `trinity`: at PR 40; `kanana2`: at PR 43; `transformer`,
-# `resnet`, `ouro`: at 6a7549d, PR 44's parent; `kimi_linear`: at PR 46)
+# `resnet`, `ouro`: at 6a7549d, PR 44's parent; `kimi_linear`: at PR 46;
+# `gpt2`: at PR 47)
 BEFORE = {
     "kimi_linear": ("b1186584b9717d9847564035b6096993865060d7", 15),
     "transformer": ("e83306d8f28e59df41bc44b9fe7303322f29a58b", 0),
@@ -227,7 +236,7 @@ BEFORE = {
     "ouro": ("b1722471731c977327d882d12eb8e5106bcca444", 3),
     "kanana2": ("442939de9116ac8230c03beb34b17b378ccc0476", 9),
     "trinity": ("12416b47e9d02155b186ce8f38c8ee118bdb5539", 12),
-    "gpt2": ("df7ec28481d2c6f9e45f238963591867ce97786d", 3),
+    "gpt2": ("e5c95bdfc499c3d1c08aedb34fcd729310f95fa4", 3),
     "olmoe": ("10b230dd86c559798a6caed82c488d59280933fd", 9),
     "lfm2": ("1991430ba2fa7bbe38f4bdb0225a591ed0238837", 9),
     "two_kernel_backward": ("3f2ec33fc054ccb7413c50a5286bea4b68cd0550", 3),
