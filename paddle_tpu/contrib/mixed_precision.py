@@ -32,7 +32,9 @@ _BF16_OPS = ("mul", "matmul", "conv2d", "depthwise_conv2d",
              # Kimi Delta Attention: q, k, v are the operands of its
              # products; the decay, beta and everything the lowering
              # derives from them stay f32 (below and in ops/kda_ops.py)
-             "kda_attention")
+             "kda_attention",
+             # Gated DeltaNet's core, alike: one decay a head
+             "gated_delta_attention")
 
 # input slots that must stay float32 even when the op is rewritten
 # (additive -1e9 padding masks lose nothing in bf16, but keeping them f32
@@ -45,7 +47,8 @@ _KEEP_F32_SLOTS = {"fused_attention": ("Bias",),
                    # the experts' dtype itself
                    "moe_ffn": ("X", "RouterW", "ExpertBias"),
                    # the log-decay is summed over a chunk and exponentiated
-                   "kda_attention": ("G", "Beta")}
+                   "kda_attention": ("G", "Beta"),
+                   "gated_delta_attention": ("G", "Beta")}
 
 # output slots that are not activations (counts, f32 statistics): they
 # keep their declared dtype and get no cast-back

@@ -38,6 +38,7 @@ __all__ = [
     "short_conv",
     "causal_conv",
     "kda_attention",
+    "gated_delta_attention",
     "moe_ffn",
     "group_norm",
     "instance_norm",
@@ -532,14 +533,20 @@ def layer_norm(
     return helper.append_activation(out)
 
 
-def rms_norm(input, epsilon=1e-5, param_attr=None, name=None):
+def rms_norm(input, epsilon=1e-5, param_attr=None, name=None,
+             unit_offset=False):
     """x * rsqrt(mean(x^2) + epsilon) * w over the last axis, w a [d]
-    parameter initialised to 1 (no mean subtraction, no bias)."""
+    parameter initialised to 1 (no mean subtraction, no bias).
+    `unit_offset`: the gain is 1 + w and w is initialised to 0 (the
+    published Qwen3NextRMSNorm, Gemma's): a `scale` op adds the 1 to the
+    parameter, the `rms_norm` op is the same."""
     helper = LayerHelper("rms_norm", **locals())
     dtype = helper.input_dtype()
     w = helper.create_parameter(
         attr=helper.param_attr, shape=[int(input.shape[-1])], dtype=dtype,
-        default_initializer=Constant(1.0))
+        default_initializer=Constant(0.0 if unit_offset else 1.0))
+    if unit_offset:
+        w = scale(w, scale=1.0, bias=1.0)
     out = helper.create_variable_for_type_inference(dtype)
     helper.append_op("rms_norm", inputs={"X": [input], "Scale": [w]},
                      outputs={"Y": [out]}, attrs={"epsilon": epsilon})
@@ -592,7 +599,9 @@ def kda_attention(q, k, v, g, beta, scale=None, name=None):
     <= 0), corrects by the delta rule with step `beta` [batch, heads, T]
     and reads with q times `scale` (dk^-0.5 where None).  The result is
     [batch, heads, T, dv] in v's dtype.  No initial state and no state
-    handed back: the causal training path."""
+    handed back: the causal training path.  `g` is a decay of every key
+    channel; one number a head a token (and key heads shared by several
+    value heads) is `gated_delta_attention`'s."""
     helper = LayerHelper("kda_attention", **locals())
     out = helper.create_variable_for_type_inference(v.dtype)
     # Out is V's shape and dtype, said here: appended straight to the block
@@ -600,6 +609,28 @@ def kda_attention(q, k, v, g, beta, scale=None, name=None):
     # build a program
     helper.main_program.current_block().append_op(
         "kda_attention",
+        inputs={"Q": [q], "K": [k], "V": [v], "G": [g], "Beta": [beta]},
+        outputs={"Out": [out]},
+        attrs={"scale": None if scale is None else float(scale)})
+    out.shape = tuple(v.shape)
+    return out
+
+
+def gated_delta_attention(q, k, v, g, beta, scale=None, name=None):
+    """Gated DeltaNet's core (the `gated_delta_attention` op,
+    ops/kda_ops.py): the delta rule with ONE decay a head.  `q`, `k`
+    [batch, key heads, T, dk]; `v` [batch, value heads, T, dv]; `g` (the
+    log-decay, <= 0) and `beta` (the rule's step) [batch, value heads, T]
+    float32.  The key heads divide the value heads and value head j reads
+    key head j // (value heads / key heads) (Qwen3-Next: 16 read by 32);
+    q is multiplied by `scale` (dk^-0.5 where None).  The result is
+    [batch, value heads, T, dv] in v's dtype.  No initial state and no
+    state handed back: the causal training path.  A decay of every key
+    CHANNEL is `kda_attention`'s."""
+    helper = LayerHelper("gated_delta_attention", **locals())
+    out = helper.create_variable_for_type_inference(v.dtype)
+    helper.main_program.current_block().append_op(
+        "gated_delta_attention",
         inputs={"Q": [q], "K": [k], "V": [v], "G": [g], "Beta": [beta]},
         outputs={"Out": [out]},
         attrs={"scale": None if scale is None else float(scale)})

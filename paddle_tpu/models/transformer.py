@@ -116,7 +116,7 @@ def multi_head_attention(
     is_test=False, cache=None, fused=False, kpad_bias=None, causal=False,
     n_kv_head=None, rotary=False, qk_norm=False, qk_norm_eps=1e-5,
     rotary_base=10000.0, param_attr=None, head_dim=None, window=0,
-    out_gate=False, scopes=False,
+    out_gate=False, scopes=False, rotary_dim=None, norm_unit_offset=False,
 ):
     """All heads in one qkv projection + batched matmuls (MXU-shaped).
     attn_bias: [B, 1 or H, Tq, Tk] additive mask (−1e9 at masked slots).
@@ -151,9 +151,20 @@ def multi_head_attention(
 
     q, k and v are of ONE head width, `head_dim` (d_model / n_head where
     none is given: Trinity-Mini's 32 heads of 128 over a hidden size of
-    2048 project to 4096 and back), and rotary turns the whole head; the
-    flash kernel takes it at 64 or 128.  Scores of another width than the
-    values, and a decoupled rotary part, are `latent_attention`'s.
+    2048 project to 4096 and back); the flash kernel takes it at 64, 128
+    or 256 (Qwen3-Next's).  Rotary turns the whole head, or, with
+    `rotary_dim` < head_dim, the head's first `rotary_dim` lanes alone
+    (`partial_rotary_factor`: Qwen3-Next turns 64 of 256, rotate-half
+    pairing (i, i + rotary_dim / 2) inside them, the frequencies those of a
+    head `rotary_dim` wide) and leaves the others as projected: a split,
+    `rotary_embed` on the first part and a concatenation, under the name
+    scope `rope` where `scopes` is set; without `rotary_dim`, or at
+    head_dim, the layer is built as before, op for op.  Scores of another
+    width than the values, and a decoupled rotary part of which the key
+    has one for all heads, are `latent_attention`'s.
+
+    norm_unit_offset=True: the per-head QK-norm's gain is 1 + w with w
+    initialised to zero (`layers.rms_norm(unit_offset=True)`).
 
     window > 0 (the fused causal training path alone): sliding-window
     attention, key j visible to query i iff 0 <= i - j < window; the
@@ -225,7 +236,8 @@ def multi_head_attention(
         b, t = x.shape[0], x.shape[1]
         x = layers.reshape(x, [b, t, heads, dh])
         if norm_attr is not None:  # per head: one [dh] weight for all
-            x = layers.rms_norm(x, epsilon=qk_norm_eps, param_attr=norm_attr)
+            x = layers.rms_norm(x, epsilon=qk_norm_eps, param_attr=norm_attr,
+                                unit_offset=norm_unit_offset)
         return layers.transpose(x, [0, 2, 1, 3])  # [B, heads, T, Dh]
 
     def repeat_kv(x):
@@ -240,6 +252,27 @@ def multi_head_attention(
         return layers.reshape(x, [b, n_head, t, dh])
 
     per_head = qk_norm == "head"
+    if norm_unit_offset and not per_head:
+        raise ValueError("norm_unit_offset is the per-head QK-norm's "
+                         "(qk_norm='head')")
+    rotary_dim = int(rotary_dim or dh)
+    if not 0 < rotary_dim <= dh or rotary_dim % 2:
+        raise ValueError("rotary_dim %d is not an even part of head_dim %d"
+                         % (rotary_dim, dh))
+    if rotary_dim < dh and cache is not None:
+        raise ValueError("rotary on a part of the head is the training "
+                         "path's: the cache paths rotate the whole head")
+
+    def turn(x, rpos):
+        if rotary_dim == dh:
+            return layers.rotary_embed(x, pos=rpos, base=rotary_base)
+        with scoped("rope"):
+            turned, kept = layers.split(x, [rotary_dim, dh - rotary_dim],
+                                        dim=-1)
+            return layers.concat(
+                [layers.rotary_embed(turned, pos=rpos, base=rotary_base),
+                 kept], axis=-1)
+
     q = split_heads(q, n_head, pa("mha_q_norm.w") if per_head else None)
     k = split_heads(k, n_kv, pa("mha_k_norm.w") if per_head else None)
     v = split_heads(v, n_kv)
@@ -261,8 +294,7 @@ def multi_head_attention(
             if rpos is None:
                 raise KeyError(
                     "cached rotary attention needs pos/pos_vec/pos_mat")
-        q = layers.rotary_embed(q, pos=rpos, base=rotary_base)
-        k = layers.rotary_embed(k, pos=rpos, base=rotary_base)
+        q, k = turn(q, rpos), turn(k, rpos)
     if cache is not None:
         if attn_bias is not None or kpad_bias is not None:
             raise ValueError(

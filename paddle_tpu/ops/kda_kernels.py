@@ -335,3 +335,205 @@ def intra_bwd(q, k, v, g, beta, solve, d_parts, scale, block):
     )(q, k, v, g, beta.astype(_F32).reshape(b, h, chunks, 1, c), solve,
       dw, du0, da, dqg, dkd, dgamma.astype(_F32).reshape(chunks, b, h, 1, dk))
     return dq, dk_, dv_, dg, dbeta.reshape(b, h, t)
+
+
+# ---------------------------------------------------------------------------
+# the family's second member: ONE decay a head (`gated_delta_attention`)
+# ---------------------------------------------------------------------------
+# With a scalar log-decay the chunk's D(t, i) = exp(G_t - G_i) is a [C, C]
+# matrix OUTSIDE the contraction: Q K^T and K K^T are one product each
+# (here one product of [Q; K] against K), multiplied by D afterwards; the
+# exponent is the difference itself, <= 0 wherever the pair is kept, so no
+# level and no reference is needed for the decay.  The inverse is the
+# per-channel kernel's (`_inverse`, by the same levels).  g and beta come
+# in as rows [.., 1, C] (their chunk on the lanes), and a row turns into a
+# column through the diagonal (`_column` / `_row`).  Key heads are shared:
+# value head j reads q and k of head j // (Hv / Hk) through the BlockSpecs'
+# index maps, nothing is repeated in HBM.
+
+
+def _lanes(x):
+    """[B, H, T] -> [B, H, N, 1, C] float32: a chunk's numbers on the
+    lanes."""
+    return x.astype(_F32).reshape(x.shape[:2] + (-1, 1, CHUNK))
+
+
+def _gdn_decay(g_row, eye, lower):
+    """g_row [n, 1, C] -> (G as a column [n, C, 1], D [n, C, C] = exp(G_t -
+    G_i) for i <= t (1 on the diagonal, <= 1 below; above it the clamped
+    exponent's 1, never kept), G_C [n, 1, 1])."""
+    upto = jnp.sum(jnp.where(lower | eye, g_row, 0.0), axis=2, keepdims=True)
+    diff = upto - _row(upto, eye)
+    # the chunk's last row by a masked sum: Mosaic refuses a one-row
+    # sublane slice
+    last = jnp.sum(jnp.where(
+        jax.lax.broadcasted_iota(jnp.int32, upto.shape, 1) == CHUNK - 1,
+        upto, 0.0), axis=1, keepdims=True)
+    return upto, jnp.exp(jnp.minimum(diff, 0.0)), last
+
+
+def _gdn_inside(q_ref, k_ref, g_ref, scale, n):
+    """What both kernels make first: masks, q (scaled) and k [n, C, dk]
+    float32, the decay's pieces, and the two decayed products."""
+    dtype = q_ref.dtype
+    shape = (n, CHUNK, q_ref.shape[-1])
+    eye, lower, levels = _pairs(n)
+    qf = q_ref[...].astype(_F32).reshape(shape) * scale
+    kf = k_ref[...].astype(_F32).reshape(shape)
+    gsum, decay, last = _gdn_decay(g_ref[...], eye, lower)
+    both = _bmm(jnp.concatenate([qf, kf], 1), kf, (2, 2), dtype)
+    a_qk = jnp.where(lower | eye, both[:, :CHUNK] * decay, 0.0)
+    l_kk = jnp.where(lower, both[:, CHUNK:] * decay, 0.0)
+    return (eye, lower, levels), qf, kf, (gsum, decay, last), a_qk, l_kk
+
+
+def _gdn_intra_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref,
+                      w_ref, u0_ref, a_ref, qg_ref, kd_ref, gamma_ref,
+                      *solve_ref, scale):
+    dtype = q_ref.dtype
+    n, c = w_ref.shape[0], CHUNK
+    (eye, _, levels), qf, kf, (gsum, _, last), a_qk, l_kk = _gdn_inside(
+        q_ref, k_ref, g_ref, scale, n)
+    beta = _column(beta_ref[...], eye)
+    solve = _inverse(beta * l_kk, eye, levels)
+    into = jnp.exp(gsum)
+    w_ref[...] = _bmm(solve, beta * into * kf, (2, 1), dtype).astype(dtype)
+    u0_ref[...] = _bmm(
+        solve, beta * v_ref[...].astype(_F32).reshape(n, c, v_ref.shape[-1]),
+        (2, 1), dtype)
+    a_ref[...] = a_qk.astype(dtype)
+    qg_ref[...] = (qf * into).astype(dtype)
+    kd_ref[...] = (kf * jnp.exp(last - gsum)).astype(dtype)
+    gamma_ref[...] = jnp.broadcast_to(jnp.exp(last), gamma_ref.shape)
+    for ref in solve_ref:  # the backward's call: kept for the transpose
+        ref[...] = solve
+
+
+def _gdn_intra_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, solve_ref,
+                          dw_ref, du0_ref, da_ref, dqg_ref, dkd_ref,
+                          dgamma_ref, dq_ref, dk_ref, dv_ref, dg_ref,
+                          dbeta_ref, *, scale):
+    dtype = q_ref.dtype
+    n, c = dw_ref.shape[0], CHUNK
+    ((eye, lower, _), qf, kf, (gsum, decay, last), a_qk,
+     l_kk) = _gdn_inside(q_ref, k_ref, g_ref, scale, n)
+    vf = v_ref[...].astype(_F32).reshape(n, c, v_ref.shape[-1])
+    beta = _column(beta_ref[...], eye)
+    solve = solve_ref[...]
+    into = jnp.exp(gsum)
+    out = jnp.exp(last - gsum)
+    k_into = kf * into
+    # [W | U0] = solve . beta [K exp(G) | V]
+    dw, du0 = dw_ref[...], du0_ref[...]
+    d_solve = (_bmm(dw, beta * k_into, (2, 2), dtype)
+               + _bmm(du0, beta * vf, (2, 2), dtype))
+    d_rk = _bmm(solve, dw, (1, 1), dtype)
+    d_rv = _bmm(solve, du0, (1, 1), dtype)
+    d_akk = jnp.where(
+        lower, -_mm3(_mm3(solve, d_solve, (1, 1)), solve, (2, 2)), 0.0)
+    d_lkk = beta * d_akk
+    d_aqk = jnp.where(lower | eye, da_ref[...].astype(_F32), 0.0)
+    dbeta = (jnp.sum(d_akk * l_kk, 2, keepdims=True)
+             + jnp.sum(d_rk * k_into, -1, keepdims=True)
+             + jnp.sum(d_rv * vf, -1, keepdims=True))
+    # the two decayed products: [dQK; dKK] against k for the later operand
+    # of a pair, transposed against [q; k] for the earlier one
+    d_both = jnp.concatenate([d_aqk * decay, d_lkk * decay], 1)
+    d_later = _bmm(d_both, kf, (2, 1), dtype)
+    d_earlier = _bmm(d_both, jnp.concatenate([qf, kf], 1), (1, 1), dtype)
+    d_qg = dqg_ref[...].astype(_F32)
+    d_kd = dkd_ref[...].astype(_F32)
+    dq_ref[...] = (scale * (d_later[:, :c] + d_qg * into)).reshape(
+        dq_ref.shape).astype(dq_ref.dtype)
+    dk_ref[...] = (d_later[:, c:] + d_earlier + beta * into * d_rk
+                   + d_kd * out).reshape(dk_ref.shape).astype(dk_ref.dtype)
+    dv_ref[...] = (beta * d_rv).reshape(dv_ref.shape).astype(dv_ref.dtype)
+    # G enters a pair only through exp(G_t - G_i): + for the later token,
+    # - for the earlier, of the pair's gradient times its decayed value
+    pairs = d_lkk * l_kk + d_aqk * a_qk
+    leaving = jnp.sum(d_kd * kf * out, -1, keepdims=True)  # exp(G_C - G)
+    d_gsum = (jnp.sum((d_qg * qf + beta * kf * d_rk) * into, -1,
+                      keepdims=True) - leaving
+              + jnp.sum(pairs, 2, keepdims=True)
+              - _column(jnp.sum(pairs, 1, keepdims=True), eye))
+    d_last = (jnp.sum(leaving, 1, keepdims=True)
+              + jnp.max(dgamma_ref[...], 2, keepdims=True) * jnp.exp(last))
+    # the running sum transposed: from the row to the chunk's end
+    dg_ref[...] = jnp.sum(jnp.where(lower | eye, d_gsum, 0.0), axis=1,
+                          keepdims=True) + d_last
+    dbeta_ref[...] = _row(dbeta, eye)
+
+
+def _gdn_specs(b, h, t, block, rep):
+    """As `_specs`, with q and k read from key head j // rep and a row
+    [B, H, N, 1, C] spec for g as for beta."""
+    from jax.experimental import pallas as pl
+
+    grid, tokens, parts, rows = _specs(b, h, t, block)
+
+    def keys(d):
+        return pl.BlockSpec((None, None, block * CHUNK, d),
+                            lambda i, j, l: (i, j // rep, l, 0))
+
+    return grid, keys, tokens, parts, rows
+
+
+def gdn_intra(q, k, v, g, beta, scale, block, keep_solve=False):
+    """q, k [B, Hk, T, dk], v [B, Hv, T, dv], g, beta [B, Hv, T], T a
+    multiple of `block` chunks, Hk dividing Hv -> `intra`'s six results for
+    the Hv value heads, the chunk's whole decay [N, B, Hv, 1]."""
+    from jax.experimental import pallas as pl
+
+    b, hv, t, dv = v.shape
+    dk, c, chunks = q.shape[-1], CHUNK, t // CHUNK
+    grid, keys, tokens, parts, rows = _gdn_specs(b, hv, t, block,
+                                                 hv // q.shape[1])
+    _note("gdn_intra")
+    out = pl.pallas_call(
+        functools.partial(_gdn_intra_kernel, scale=scale),
+        grid=grid,
+        in_specs=[keys(dk), keys(dk), tokens(dv), rows, rows],
+        out_specs=[parts(c, dk), parts(c, dv), parts(c, c), parts(c, dk),
+                   parts(c, dk), parts(1, c)] + [parts(c, c)] * keep_solve,
+        out_shape=[_sds((chunks, b, hv, c, dk), q.dtype, q),
+                   _sds((chunks, b, hv, c, dv), _F32, q),
+                   _sds((chunks, b, hv, c, c), q.dtype, q),
+                   _sds((chunks, b, hv, c, dk), q.dtype, q),
+                   _sds((chunks, b, hv, c, dk), q.dtype, q),
+                   _sds((chunks, b, hv, 1, c), _F32, q)]
+        + [_sds((chunks, b, hv, c, c), _F32, q)] * keep_solve,
+        interpret=_pk._interpret(),
+        compiler_params=_mosaic_params(),
+    )(q, k, v, _lanes(g), _lanes(beta))
+    return tuple(out[:5]) + (out[5][..., 0, :1],) + tuple(out[6:])
+
+
+def gdn_intra_bwd(q, k, v, g, beta, solve, d_parts, scale, block):
+    """`gdn_intra` transposed -> the gradients of q and k PER VALUE HEAD
+    ([B, Hv, T, dk]: the caller sums a key head's readers), of v, and of g
+    and beta (float32, [B, Hv, T])."""
+    from jax.experimental import pallas as pl
+
+    b, hv, t, dv = v.shape
+    dk, c, chunks = q.shape[-1], CHUNK, t // CHUNK
+    dw, du0, da, dqg, dkd, dgamma = d_parts
+    grid, keys, tokens, parts, rows = _gdn_specs(b, hv, t, block,
+                                                 hv // q.shape[1])
+    _note("gdn_intra_bwd")
+    dq, dk_, dv_, dg, dbeta = pl.pallas_call(
+        functools.partial(_gdn_intra_bwd_kernel, scale=scale),
+        grid=grid,
+        in_specs=[keys(dk), keys(dk), tokens(dv), rows, rows,
+                  parts(c, c), parts(c, dk), parts(c, dv), parts(c, c),
+                  parts(c, dk), parts(c, dk), parts(1, c)],
+        out_specs=[tokens(dk), tokens(dk), tokens(dv), rows, rows],
+        out_shape=[_sds((b, hv, t, dk), q.dtype, q),
+                   _sds((b, hv, t, dk), k.dtype, q),
+                   _sds(v.shape, v.dtype, q),
+                   _sds((b, hv, chunks, 1, c), _F32, q),
+                   _sds((b, hv, chunks, 1, c), _F32, q)],
+        interpret=_pk._interpret(),
+        compiler_params=_mosaic_params(),
+    )(q, k, v, _lanes(g), _lanes(beta), solve, dw, du0, da, dqg, dkd,
+      jnp.broadcast_to(dgamma.astype(_F32)[..., None], (chunks, b, hv, 1, c)))
+    return dq, dk_, dv_, dg.reshape(b, hv, t), dbeta.reshape(b, hv, t)

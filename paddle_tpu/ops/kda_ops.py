@@ -1,4 +1,8 @@
-"""Kimi Delta Attention as a Program op: `kda_attention`.
+"""The gated delta rule as Program ops: `kda_attention` (Kimi Delta
+Attention, a decay of every key channel: this docstring) and, at the end
+of the module, `gated_delta_attention` (Gated DeltaNet, ONE decay a head
+and key heads shared by several value heads: `gdn_chunked`), which runs
+the same carry around an inside of its own.
 
 A gated delta-rule linear attention with a per-CHANNEL decay (Kimi Linear,
 moonshotai; the published `chunk_kda`).  Per head, with S_0 = 0 in
@@ -148,15 +152,18 @@ def _intra(ins, scale, keep_solve=False):
                                  keep_solve)
 
 
-def _state0(q, v):
-    return jnp.zeros(q.shape[:2] + (q.shape[-1], v.shape[-1]), _F32)
+def _state0(w, v):
+    """The state every head starts from, [B, H, dk, dv] float32 (`w`: any
+    of the carry's [N, B, H, C, dk] operands)."""
+    return jnp.zeros(w.shape[1:3] + (w.shape[-1], v.shape[-1]), _F32)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def kda_chunked(q, k, v, g, beta, scale):
-    """q, k, g [B, H, T, dk], v [B, H, T, dv], beta [B, H, T] -> o
-    [B, H, T, dv] in v's dtype."""
-    t, dtype = q.shape[2], q.dtype
+def _carry_forward(parts, v, t, dtype):
+    """The scan over the chunks that carries S: `parts` (an inside's six
+    results, chunks leading) -> o [B, H, T, dv] in v's dtype.  Both
+    members of the family run it: the chunk's whole decay `gamma` is
+    [B, H, dk] a chunk under a per-channel decay, [B, H, 1] under one a
+    head."""
 
     def step(s, xs):
         w, u0, a_qk, qg, kd, gamma = xs
@@ -165,24 +172,16 @@ def kda_chunked(q, k, v, g, beta, scale):
              + _mm("...ti,...iv->...tv", a_qk, u, dtype))
         return _next_state(s, u, kd, gamma, dtype), o.astype(v.dtype)
 
-    parts = _intra(tuple(_whole_chunks(x, t) for x in (q, k, v, g, beta)),
-                   scale)
     with jax.named_scope("carry"):
-        _, o = jax.lax.scan(step, _state0(q, v), parts)
+        _, o = jax.lax.scan(step, _state0(parts[0], v), parts)
     o = jnp.moveaxis(o, 0, 2)
     return o.reshape(o.shape[:2] + (-1, o.shape[-1]))[:, :, :t]
 
 
-def _kda_fwd(q, k, v, g, beta, scale):
-    return kda_chunked(q, k, v, g, beta, scale), (q, k, v, g, beta)
-
-
-def _kda_bwd(scale, res, do):
-    # the barrier ties the recomputation to the gradient: without it the
-    # compiler may find the forward's identical work and keep ITS results
-    # alive from the forward to here
-    q, k, v, g, beta, do = jax.lax.optimization_barrier(res + (do,))
-    t, dtype = q.shape[2], q.dtype
+def _carry_backward(parts, v, do, dtype):
+    """The carry transposed: `parts` as above and the result's gradient
+    [B, H, T, dv] -> the six parts' gradients (the last, the chunk's whole
+    decay's, [N, B, H, dk]: a decay of one number a head sums it)."""
 
     def entering(s, xs):
         w, u0, _, _, kd, gamma = xs
@@ -202,11 +201,8 @@ def _kda_bwd(scale, res, do):
               - _mm("...tc,...tv->...cv", w, du, dtype))
         return ds, out
 
-    ins = tuple(_whole_chunks(x, t) for x in (q, k, v, g, beta))
-    parts = _intra(ins, scale, keep_solve=True)
-    parts, solve = parts[:6], parts[6]
-    s0 = _state0(q, v)
-    d_o = _chunks_leading(do, t)
+    s0 = _state0(parts[0], v)
+    d_o = _chunks_leading(do, do.shape[2])
     with jax.named_scope("carry"):
         # forward for the state every chunk entered with and what it
         # wrote, backwards with the state's gradient as the carry; what
@@ -215,11 +211,37 @@ def _kda_bwd(scale, res, do):
         _, (du, d_kd, d_gamma) = jax.lax.scan(
             backward, jnp.zeros_like(s0), (parts, states, u, d_o),
             reverse=True)
-        d_parts = (
+        return (
             (-_mm("...tv,...cv->...tc", du, states, dtype)).astype(dtype),
             du, _mm("...tv,...iv->...ti", d_o, u, dtype).astype(dtype),
             _mm("...tv,...cv->...tc", d_o, states, dtype).astype(dtype),
             d_kd, d_gamma)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def kda_chunked(q, k, v, g, beta, scale):
+    """q, k, g [B, H, T, dk], v [B, H, T, dv], beta [B, H, T] -> o
+    [B, H, T, dv] in v's dtype."""
+    t = q.shape[2]
+    parts = _intra(tuple(_whole_chunks(x, t) for x in (q, k, v, g, beta)),
+                   scale)
+    return _carry_forward(parts, v, t, q.dtype)
+
+
+def _kda_fwd(q, k, v, g, beta, scale):
+    return kda_chunked(q, k, v, g, beta, scale), (q, k, v, g, beta)
+
+
+def _kda_bwd(scale, res, do):
+    # the barrier ties the recomputation to the gradient: without it the
+    # compiler may find the forward's identical work and keep ITS results
+    # alive from the forward to here
+    q, k, v, g, beta, do = jax.lax.optimization_barrier(res + (do,))
+    t, dtype = q.shape[2], q.dtype
+    ins = tuple(_whole_chunks(x, t) for x in (q, k, v, g, beta))
+    parts = _intra(ins, scale, keep_solve=True)
+    parts, solve = parts[:6], parts[6]
+    d_parts = _carry_backward(parts, v, do, dtype)
     with jax.named_scope("intra"):
         grads = kda_kernels.intra_bwd(*ins, solve, d_parts, scale,
                                       _block(ins[0].shape[2]))
@@ -243,12 +265,95 @@ def _kda_attention(ctx, ins, attrs):
     return {"Out": [kda_chunked(q, k, v, g, beta, scale)]}
 
 
+# ---------------------------------------------------------------------------
+# the family's second member: ONE decay a head (Gated DeltaNet)
+# ---------------------------------------------------------------------------
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def gdn_chunked(q, k, v, g, beta, scale):
+    """The gated delta rule with a scalar decay a head (Gated DeltaNet,
+    Yang et al. 2024, arXiv:2412.06464; Qwen3-Next's linear layers): q, k
+    [B, Hk, T, dk], v [B, Hv, T, dv], g (the log-decay, <= 0) and beta
+    [B, Hv, T] float32 -> o [B, Hv, T, dv] in v's dtype.  Value head j
+    reads key head j // (Hv / Hk).
+
+        S_t = (I - beta_t k_t k_t^T) exp(g_t) S_{t-1} + beta_t k_t v_t^T
+        o_t = S_t^T q_t scale
+
+    The carry is KDA's, code and all (`_carry_forward` /
+    `_carry_backward`, the chunk's whole decay one number a head); the
+    chunk's inside is not: D(t, i) = exp(G_t - G_i) is a [C, C] matrix
+    that multiplies Q K^T and K K^T after ONE product (kda_kernels.
+    gdn_intra), exp only ever of a difference <= 0, no level and no
+    reference, and the decay is never broadcast to the channels."""
+    t = v.shape[2]
+    parts = _gdn_intra(tuple(_whole_chunks(x, t) for x in (q, k, v, g, beta)),
+                       scale)
+    return _carry_forward(parts, v, t, q.dtype)
+
+
+def _gdn_intra(ins, scale, keep_solve=False):
+    with jax.named_scope("intra"):
+        return kda_kernels.gdn_intra(*ins, scale, _block(ins[2].shape[2]),
+                                     keep_solve)
+
+
+def _gdn_fwd(q, k, v, g, beta, scale):
+    return gdn_chunked(q, k, v, g, beta, scale), (q, k, v, g, beta)
+
+
+def _gdn_bwd(scale, res, do):
+    # as `_kda_bwd`: nothing but the inputs is kept, and the barrier ties
+    # the recomputation to the gradient
+    q, k, v, g, beta, do = jax.lax.optimization_barrier(res + (do,))
+    t, dtype = v.shape[2], q.dtype
+    ins = tuple(_whole_chunks(x, t) for x in (q, k, v, g, beta))
+    parts = _gdn_intra(ins, scale, keep_solve=True)
+    parts, solve = parts[:6], parts[6]
+    d_parts = _carry_backward(parts, v, do, dtype)
+    d_parts = d_parts[:5] + (d_parts[5].sum(-1, keepdims=True),)
+    with jax.named_scope("intra"):
+        dq, dk, dv, dg, dbeta = kda_kernels.gdn_intra_bwd(
+            *ins, solve, d_parts, scale, _block(ins[2].shape[2]))
+
+        def readers(d):  # a key head's gradient: the sum over its readers
+            b, hk = q.shape[:2]
+            return d.reshape(b, hk, -1, *d.shape[2:]).astype(_F32).sum(2)
+
+        grads = (readers(dq), readers(dk), dv, dg, dbeta)
+    return tuple(d[:, :, :t].astype(x.dtype) for d, x in zip(grads, res))
+
+
+gdn_chunked.defvjp(_gdn_fwd, _gdn_bwd)
+
+
+@register("gated_delta_attention")
+def _gated_delta_attention(ctx, ins, attrs):
+    """Q, K [B, Hk, T, dk], V [B, Hv, T, dv], G, Beta [B, Hv, T] -> Out
+    [B, Hv, T, dv] in V's dtype; Hk divides Hv and value head j reads key
+    head j // (Hv / Hk).  `scale` multiplies q (dk^-0.5 where not given).
+    See `gdn_chunked`."""
+    q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
+    g, beta = ins["G"][0], ins["Beta"][0]
+    scale = attrs.get("scale")
+    scale = float(q.shape[-1]) ** -0.5 if scale is None else float(scale)
+    kernel_tuning.note_kda_chunks(
+        v.shape[2], _padded(v.shape[2]), CHUNK, _block(v.shape[2]),
+        decay="head")
+    return {"Out": [gdn_chunked(q, k, v, g, beta, scale)]}
+
+
 from ..analysis.infer import (  # noqa: E402
     InferError,
     VarInfo,
     register_infer,
     slot_info as _vi,
 )
+
+
+def _same(a, b):
+    """Two declared shapes agree (a negative extent agrees with any)."""
+    return len(a) == len(b) and all(
+        x == y or x < 0 or y < 0 for x, y in zip(a, b))
 
 
 @register_infer("kda_attention", req_ins=("Q", "K", "V", "G", "Beta"),
@@ -258,20 +363,41 @@ def _kda_attention_infer(op, ins):
     g, beta = _vi(ins, "G"), _vi(ins, "Beta")
     if any(x is None or x.shape is None for x in (q, k, v, g, beta)):
         return {}
-
-    def same(a, b):
-        return len(a) == len(b) and all(
-            x == y or x < 0 or y < 0 for x, y in zip(a, b))
-
-    if len(q.shape) != 4 or not same(q.shape, k.shape) \
-            or not same(q.shape, g.shape):
+    if len(q.shape) != 4 or not _same(q.shape, k.shape) \
+            or not _same(q.shape, g.shape):
         raise InferError("kda_attention wants Q, K and G [B, H, T, dk] "
                          "alike, got Q%s K%s G%s"
                          % (q.shape, k.shape, g.shape))
-    if len(v.shape) != 4 or not same(q.shape[:3], v.shape[:3]):
+    if len(v.shape) != 4 or not _same(q.shape[:3], v.shape[:3]):
         raise InferError("kda_attention V%s is not [B, H, T, dv] beside Q%s"
                          % (v.shape, q.shape))
-    if not same(q.shape[:3], beta.shape):
+    if not _same(q.shape[:3], beta.shape):
         raise InferError("kda_attention Beta%s is not Q%s's [B, H, T]"
                          % (beta.shape, q.shape))
+    return {"Out": [VarInfo(v.shape, v.dtype)]}
+
+
+@register_infer("gated_delta_attention",
+                req_ins=("Q", "K", "V", "G", "Beta"), req_outs=("Out",))
+def _gated_delta_attention_infer(op, ins):
+    q, k, v = _vi(ins, "Q"), _vi(ins, "K"), _vi(ins, "V")
+    g, beta = _vi(ins, "G"), _vi(ins, "Beta")
+    if any(x is None or x.shape is None for x in (q, k, v, g, beta)):
+        return {}
+    if len(q.shape) != 4 or not _same(q.shape, k.shape):
+        raise InferError("gated_delta_attention wants Q and K [B, Hk, T, dk] "
+                         "alike, got Q%s K%s" % (q.shape, k.shape))
+    if len(v.shape) != 4 or not _same(
+            (q.shape[0], q.shape[2]), (v.shape[0], v.shape[2])):
+        raise InferError("gated_delta_attention V%s is not [B, Hv, T, dv] "
+                         "beside Q%s" % (v.shape, q.shape))
+    if q.shape[1] > 0 and v.shape[1] > 0 and v.shape[1] % q.shape[1]:
+        raise InferError("gated_delta_attention: Q's %d key heads do not "
+                         "divide V's %d value heads"
+                         % (q.shape[1], v.shape[1]))
+    for name, x in (("G", g), ("Beta", beta)):
+        if not _same(v.shape[:3], x.shape):
+            raise InferError("gated_delta_attention %s%s is not V%s's "
+                             "[B, Hv, T]: one number a head a token"
+                             % (name, x.shape, v.shape))
     return {"Out": [VarInfo(v.shape, v.dtype)]}

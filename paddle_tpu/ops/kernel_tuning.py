@@ -31,6 +31,9 @@ _attention_band_grid = {"ops": 0, "steps": {}}  # "TxWxBQxBK" -> [walked, comput
 # kda_attention lowerings (a grad op lowers its forward again), with the
 # chunking each length got
 _kda_chunks = {"ops": 0, "lengths": {}}  # T -> [chunk, chunks a grid step, T, padded T]
+# gated_delta_attention lowerings, alike: the family's member whose decay
+# is one number a head
+_gdn_chunks = {"ops": 0, "lengths": {}}
 
 
 def note_kernel(family, n=1):
@@ -81,14 +84,16 @@ def note_band_grid(t, window, block_q, block_k, walked, computed):
             t, window, block_q, block_k)] = [int(walked), int(computed)]
 
 
-def note_kda_chunks(t, padded_t, chunk, block):
-    """Count a trace-time lowering of a `kda_attention`, and keep by length
+def note_kda_chunks(t, padded_t, chunk, block, decay="channel"):
+    """Count a trace-time lowering of a delta-rule op, and keep by length
     the chunk it ran at, the chunks a grid step of its kernels holds and
-    the length it padded to."""
+    the length it padded to.  `decay`: "channel" (`kda_attention`) or
+    "head" (`gated_delta_attention`): which record it lands in."""
+    record = {"channel": _kda_chunks, "head": _gdn_chunks}[decay]
     with _lock:
-        _kda_chunks["ops"] += 1
-        _kda_chunks["lengths"][int(t)] = [int(chunk), int(block), int(t),
-                                          int(padded_t)]
+        record["ops"] += 1
+        record["lengths"][int(t)] = [int(chunk), int(block), int(t),
+                                     int(padded_t)]
 
 
 def attribution():
@@ -97,7 +102,9 @@ def attribution():
     type, the moe_ffn lowerings that took the live-chunk path with the
     rows of a chunk by buffer size, the windowed flash lowerings with their
     forward grid steps walked and computed by shape, the kda_attention
-    lowerings with [chunk, chunks a grid step, T, padded T] by length."""
+    lowerings with [chunk, chunks a grid step, T, padded T] by length and
+    the gated_delta_attention lowerings alike (`gdn_chunks`, "decay":
+    "head")."""
     with _lock:
         return {
             "pallas_hits": dict(_kernel_hits),
@@ -115,6 +122,10 @@ def attribution():
                 "ops": _kda_chunks["ops"],
                 "lengths": {k: list(v) for k, v in
                             _kda_chunks["lengths"].items()}},
+            "gdn_chunks": {
+                "ops": _gdn_chunks["ops"], "decay": "head",
+                "lengths": {k: list(v) for k, v in
+                            _gdn_chunks["lengths"].items()}},
         }
 
 
@@ -127,3 +138,4 @@ def reset_attribution():
         _moe_live_chunks.update(ops=0, chunk_rows={})
         _attention_band_grid.update(ops=0, steps={})
         _kda_chunks.update(ops=0, lengths={})
+        _gdn_chunks.update(ops=0, lengths={})

@@ -1030,7 +1030,9 @@ def _fused_attention(ctx, ins, attrs):
         from .spmd_epilogue import mesh_ctx, spmd_flash_attention
 
         note_kernel("attention")
-        if dv != d:  # which widths the kernel engaged with, when not one
+        # which widths the kernel engaged with, where they are not one
+        # width of at most 128 (latent attention's 192 over 128, 256)
+        if dv != d or d > 128:
             note_kernel("attention_qk%d_v%d" % (d, dv))
         mc, blk = mesh_ctx(), _flash_block(t)
         if window:  # the grid a head's forward walks against its band
@@ -1071,7 +1073,7 @@ def _flash_block(t):
 # 128, or latent attention's 192-wide scores (128 without position + 64
 # rotary) over 128-wide values, the score tile ONE 192-wide contraction
 # (tools/mla_kernel_sweep.py on a v5e; the table is in CHANGES.md, PR 37).
-_FLASH_WIDTHS = ((64, 64), (128, 128), (192, 128))
+_FLASH_WIDTHS = ((64, 64), (128, 128), (192, 128), (256, 256))
 
 
 def _flash_engages(ctx, tq, tk, d, dv=None):

@@ -610,6 +610,19 @@ _FUSED_BWD_DQ_BYTES = 4 * 2 ** 20  # [T, d] f32: T <= 8192 at d = 128
 # at T = 6144, 23.2 against 29.7 at 8192; narrower heads keep the limit
 # they were swept under)
 _FUSED_BWD_DQ_BYTES_WIDE = 6 * 2 ** 20
+# Qwen3-Next's 256-wide heads: T <= 8192 again (tools/mla_kernel_sweep.py
+# --width 256 --heads 16 on a v5e, PR 48: one kernel 7.95 against two 10.44
+# ms at T = 6144, 13.51 against 17.73 at 8192, forward + backward)
+_FUSED_BWD_DQ_BYTES_256 = 8 * 2 ** 20
+
+
+def _fused_bwd_dq_limit(d):
+    """The largest [T, d] float32 dq scratch the one-kernel backward is
+    given at head width d: each width keeps the limit it was swept
+    under."""
+    return (_FUSED_BWD_DQ_BYTES if d <= 128
+            else _FUSED_BWD_DQ_BYTES_WIDE if d <= 192
+            else _FUSED_BWD_DQ_BYTES_256)
 
 
 def _flash_bwd_fused_kernel(*refs, block_q, block_k, nq, nk, causal, scale,
@@ -769,8 +782,7 @@ def _flash_fwd_call(q, k, v, kbias, seg, *, causal, scale, block_q, block_k,
 def _flash_bwd_call(q, k, v, kbias, seg, o, lse, do, *, causal, scale,
                     block_q, block_k, window, interpret):
     T, d = q.shape[1:]
-    if T == k.shape[1] and T * d * 4 <= (
-            _FUSED_BWD_DQ_BYTES if d <= 128 else _FUSED_BWD_DQ_BYTES_WIDE):
+    if T == k.shape[1] and T * d * 4 <= _fused_bwd_dq_limit(d):
         return _flash_bwd_fused(q, k, v, kbias, seg, o, lse, do, causal,
                                 scale, block_q, block_k, window, interpret)
     kb = kbias if kbias is not None else jnp.zeros(k.shape[:2], jnp.float32)

@@ -123,7 +123,7 @@ def program_flops(program, batch_hint=1):
             if not x or not k:
                 continue
             total += factor * (2.0 * k[1] + 2.0) * _prod(x[:-1]) * k[0]
-        elif t == "kda_attention":
+        elif t in ("kda_attention", "gated_delta_attention"):
             # the chunkwise form at C = 64, the triangular solve's C^3
             # left out: a token a head three [C, dk] and two [C, dv]
             # products against the chunk (A_kk, A_qk and the solve's W;
@@ -134,7 +134,9 @@ def program_flops(program, batch_hint=1):
             if not q or not v or len(q) != 4:
                 continue
             from ..ops.kda_ops import CHUNK
-            b, h, tq, dk = q
+            # a VALUE head a token (gated_delta_attention's key heads are
+            # fewer: no credit for a product two value heads could share)
+            (b, _, tq, dk), h = q, v[1]
             total += factor * b * h * tq * (
                 2.0 * CHUNK * (3 * dk + 2 * v[-1]) + 6.0 * dk * v[-1])
         elif t == "matmul":

@@ -87,7 +87,20 @@ product), so `gpt2`'s step changed on purpose (one gelu `fc` a layer): its
 digest below is taken from PR 47's tree by this file's `_digest`; its
 Mosaic calls stayed 3.  The other twelve digests and counts did not move:
 `transformer`'s `fc` ops carry relu or no activation, `resnet`'s none, and
-no other pinned program holds an `fc` under gelu or swish."""
+no other pinned program holds an `fc` under gelu or swish.
+
+PR 48 added `qwen3_next` (a tiny Qwen3-Next: three Gated DeltaNet layers
+around the chunkwise `gated_delta_attention` op, one key head read by two
+value heads of 128, ONE `causal_conv` a layer; a gated attention layer at
+the flash kernel's new (256, 256) with rotary on 64 of its lanes and the
+1 + w gains; a share of softmax-routed experts held), its digest taken from
+PR 48's tree by this file's `_digest`: 18 Mosaic calls (three a GDN layer:
+`gdn_intra` in the forward, again and `gdn_intra_bwd` in the grad op; the
+nine every share-holding program with one flash core has).  The thirteen digests and counts above did not move,
+`kimi_linear`'s above all: the carry its op shares with the new one
+(`kda_ops._carry_forward` / `_carry_backward`) traces to the text it traced
+to, and `multi_head_attention`'s `rotary_dim` and `norm_unit_offset` at
+their defaults build `trinity`'s and `lfm2`'s layers op for op."""
 
 import base64
 import hashlib
@@ -100,7 +113,7 @@ import pytest
 import paddle_tpu as fluid
 from paddle_tpu.core.trace import build_traced_function
 from paddle_tpu.models import (gpt2, kanana2, kimi_linear, lfm2, olmoe, ouro,
-                               resnet, transformer, trinity)
+                               qwen3_next, resnet, transformer, trinity)
 from paddle_tpu.ops import pallas_kernels as pk
 
 SEQ = 512
@@ -152,6 +165,15 @@ class M(kimi_linear.KimiLinearConfig):
                           "short_conv_kernel_size": 4}
     num_attention_heads = num_key_value_heads = 2
     num_experts, num_experts_per_token = 8, 2
+    num_local_experts, expert_offset = 2, 2
+
+
+class Q(qwen3_next.Qwen3NextConfig):
+    vocab_size, hidden_size, num_hidden_layers = 512, 128, 4
+    linear_num_key_heads, linear_num_value_heads = 1, 2
+    num_attention_heads, num_key_value_heads = 2, 1
+    moe_intermediate_size = shared_expert_intermediate_size = 128
+    num_experts, num_experts_per_tok = 8, 2
     num_local_experts, expert_offset = 2, 2
 
 
@@ -221,6 +243,7 @@ PROGRAMS = {"gpt2": lambda: _lm(gpt2.gpt2_lm_program, G),
             "trinity": lambda: _lm(_trinity_program, T),
             "kanana2": lambda: _lm(kanana2.kanana2_lm_program, K),
             "kimi_linear": lambda: _lm(_kimi_program, M),
+            "qwen3_next": lambda: _lm(qwen3_next.qwen3_next_lm_program, Q),
             "ouro": lambda: _lm(ouro.ouro_lm_program, U),
             "transformer": _transformer,
             "resnet": _resnet}
@@ -228,8 +251,9 @@ PROGRAMS = {"gpt2": lambda: _lm(gpt2.gpt2_lm_program, G),
 # name -> (sha1 of the normalised text, Mosaic calls in it) at ec9cdf7
 # (`lfm2`: at PR 39; `trinity`: at PR 40; `kanana2`: at PR 43; `transformer`,
 # `resnet`, `ouro`: at 6a7549d, PR 44's parent; `kimi_linear`: at PR 46;
-# `gpt2`: at PR 47)
+# `gpt2`: at PR 47; `qwen3_next`: at PR 48)
 BEFORE = {
+    "qwen3_next": ("a6d462189341a711f761e33d94502ac783953a30", 18),
     "kimi_linear": ("b1186584b9717d9847564035b6096993865060d7", 15),
     "transformer": ("e83306d8f28e59df41bc44b9fe7303322f29a58b", 0),
     "resnet": ("84575b13d140437bb64cb8461436105337774a6d", 0),

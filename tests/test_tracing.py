@@ -854,3 +854,69 @@ def test_kimi_linears_kda_scopes_reach_the_lowered_steps_op_names():
     # two layers, each lowered forward and again inside its grad op
     assert chunks["ops"] == 4
     assert chunks["lengths"] == {40: [64, 1, 40, 64]}
+
+
+def test_qwen3_nexts_gdn_and_rope_scopes_reach_the_lowered_steps_op_names():
+    """gdn around a Gated DeltaNet mixer with proj, conv, gate, core and
+    out inside it, attn_full around the gated attention with core,
+    attn_gate and rope (the split, the rotation of the head's first lanes
+    and the concatenation): the nested part of the optimized HLO's op names
+    carries each, forward and backward, under the op type the benchmark's
+    readers match first (`[a-z]+/gated_delta_attention(_grad)?/<i>` then
+    `/gdn.core/`); inside the op's lowering `intra` and `carry` follow; and
+    the lowering leaves how it chunked the length, and which decay it ran,
+    in attribution()."""
+    from paddle_tpu.models import gpt2, qwen3_next
+    from paddle_tpu.ops import kernel_tuning
+
+    class Q(qwen3_next.Qwen3NextConfig):
+        vocab_size, hidden_size, num_hidden_layers = 256, 64, 4
+        linear_num_key_heads, linear_num_value_heads = 2, 4
+        linear_key_head_dim = linear_value_head_dim = 16
+        num_attention_heads, num_key_value_heads, head_dim = 4, 2, 32
+        moe_intermediate_size = shared_expert_intermediate_size = 32
+        num_experts, num_experts_per_tok = 8, 2
+
+    kernel_tuning.reset_attribution()
+    main, startup, _, fetches = qwen3_next.qwen3_next_lm_program(
+        Q, seq_len=40, lr=1e-3)
+    startup.random_seed = main.random_seed = 5
+    exe, scope = fluid.Executor(fluid.CPUPlace()), fluid.Scope()
+    with fluid.scope_guard(scope):
+        exe.run(startup)
+        exe.run(main, feed=gpt2.make_fake_lm_batch(2, 40, Q, seed=1),
+                fetch_list=[fetches[0]])
+        (text,) = exe.compiled_hlo(main)
+    ops, nested, inside = main.global_block().ops, {}, set()
+    for op_name in re.findall(r'op_name="jit\(program_step\)/([^"]*)"', text):
+        found = SCOPE.findall(op_name)
+        want = ops[int(found[0][2])].attrs.get("op_namescope")
+        if want is None:
+            continue
+        (role, typ, _), (_, scopes, depth) = found[:2]
+        assert (scopes, int(depth)) == (want.replace("/", "."),
+                                        want.count("/") + 1), op_name
+        nested.setdefault(scopes, set()).add((role, typ))
+        if scopes == "gdn.core":
+            inside.update(re.findall(r"[/(](intra|carry)[/)]", op_name))
+    assert {"gdn.proj", "gdn.conv", "gdn.gate", "gdn.core", "gdn.out",
+            "attn_full.core", "attn_full.rope", "attn_full.attn_gate",
+            "shared_expert"} <= set(nested)
+    assert nested["gdn.core"] == {
+        ("forward", "gated_delta_attention"),
+        ("backward", "gated_delta_attention_grad")}
+    assert inside == {"intra", "carry"}
+    assert {("forward", "causal_conv"), ("backward", "causal_conv_grad"),
+            ("forward", "l2_normalize")} <= nested["gdn.conv"]
+    assert {("forward", "softplus"), ("forward", "exp"),
+            ("forward", "sigmoid")} <= nested["gdn.gate"]
+    assert {("forward", "rms_norm"), ("forward", "swish")} <= nested[
+        "gdn.out"]
+    assert ("forward", "rotary_embed") in nested["attn_full.rope"]
+    assert {t for _, t in nested["attn_full.core"]} == {
+        "fused_attention", "fused_attention_grad"}
+    found = kernel_tuning.attribution()
+    # three layers, each lowered forward and again inside its grad op
+    assert found["gdn_chunks"] == {"ops": 6, "decay": "head",
+                                   "lengths": {40: [64, 1, 40, 64]}}
+    assert found["kda_chunks"]["ops"] == 0

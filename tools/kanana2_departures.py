@@ -30,7 +30,14 @@ left out, q and k not L2-normalised, the convolution one step ahead, the
 output gate left out, rotary on the latent layer, routed_scaling_factor
 left out; its reference runs Kimi Delta Attention token by token on the
 host, about a minute a reference at 6,144 tokens; `--departures-at 120`
-runs the nine wrong ones at that step count alone).
+runs the nine wrong ones at that step count alone); `--cell
+qwen3_next_80b_a3b_train` (PR 48: the decay left out, beta left out, the
+k k^T correction left out, q and k not L2-normalised, value head j reading
+key head j mod 16, the convolution one step ahead, the GDN gate a sigmoid,
+the gains w in place of 1 + w, rotary over all 256 lanes, the attention's
+output gate left out, the shared expert's gate left out, the top-10
+weights not renormalised; its reference runs Gated DeltaNet token by token
+on the host, about a minute a reference at 8,192 tokens).
 
 Prints one JSON line a step count (the adapter's own lines, with every
 reading, go to stderr).  PERF.md (PR 37) keeps what it read; what the

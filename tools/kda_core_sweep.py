@@ -12,7 +12,11 @@ length.  Run on a TPU:
 
 (`--rehearse`: tiny sizes on the CPU, proves the plumbing.)  `--block`
 runs the op's kernels at another number of chunks a grid step
-(`kda_ops.BLOCK`).  Prints one JSON line; PERF.md (PRs 45, 46) keeps what
+(`kda_ops.BLOCK`).  `--decay head` times the family's other member,
+`gated_delta_attention` (`kda_ops.gdn_chunked`), at the
+qwen3_next_80b_a3b_train cell's shape: 16 key heads under 32 value heads,
+ONE log-decay a head a token (`--seq-len 8192` is the cell's).  Prints
+one JSON line; PERF.md (PRs 45, 46, 48) keeps what
 it read.
 """
 
@@ -99,6 +103,8 @@ def main():
     ap.add_argument("--seed", type=int, default=45)
     ap.add_argument("--reps", type=int, default=5)
     ap.add_argument("--rehearse", action="store_true")
+    ap.add_argument("--decay", choices=("channel", "head"),
+                    default="channel")
     args = ap.parse_args()
 
     import jax
@@ -116,14 +122,20 @@ def main():
                                                         128)
     scale = d ** -0.5
     ins = _inputs(jnp, np, b, h, t, d, args.seed)
+    chunked = kda_ops.kda_chunked
+    if args.decay == "head":  # half the key heads, one decay a head
+        chunked = kda_ops.gdn_chunked
+        ins = [ins[0][:, :h // 2], ins[1][:, :h // 2], ins[2],
+               ins[3][..., 0], ins[4]]
     mix = jnp.asarray(np.random.default_rng(1).standard_normal(
         (b, h, t, d)), jnp.bfloat16)
 
-    fwd = jax.jit(lambda *a: kda_ops.kda_chunked(*a, scale))
+    fwd = jax.jit(lambda *a: chunked(*a, scale))
     both = jax.jit(jax.value_and_grad(
-        lambda *a: (kda_ops.kda_chunked(*a, scale).astype(jnp.float32)
+        lambda *a: (chunked(*a, scale).astype(jnp.float32)
                     * mix.astype(jnp.float32)).sum(), argnums=range(5)))
-    out = {"shape": [b, h, t, d], "chunk": kda_ops.CHUNK,
+    out = {"shape": [b, h, t, d], "decay": args.decay,
+           "chunk": kda_ops.CHUNK,
            "block": kda_ops._block(t),
            "device": jax.devices()[0].device_kind,
            "forward_ms": _timed(fwd, ins, args.reps),
@@ -132,9 +144,12 @@ def main():
     # against the recurrence, in float32 on the same (bfloat16-rounded)
     # inputs, at a length the scan finishes in seconds
     short = [x[:, :, :min(t, 1024)] for x in ins]
-    want = jax.jit(lambda *a: _recurrence(jax, jnp, *a, scale))(*short)
-    got = jax.jit(lambda *a: kda_ops.kda_chunked(*a, scale))(*short)
-    exact = jax.jit(lambda *a: kda_ops.kda_chunked(*a, scale))(
+    want = jax.jit(lambda *a: _recurrence(jax, jnp, *a, scale))(*(
+        short if args.decay == "channel" else
+        [jnp.repeat(short[0], 2, 1), jnp.repeat(short[1], 2, 1), short[2],
+         jnp.broadcast_to(short[3][..., None], short[2].shape), short[4]]))
+    got = jax.jit(lambda *a: chunked(*a, scale))(*short)
+    exact = jax.jit(lambda *a: chunked(*a, scale))(
         *[x.astype(jnp.float32) for x in short])
     scale_of = float(jnp.abs(want).max())
     out["max_abs_error_over_max"] = {

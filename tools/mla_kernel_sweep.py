@@ -23,6 +23,12 @@ The forms, each the SAME kernel bodies (ops/pallas_kernels.py):
 and, first, the kernel against the dense lowering at T = 1024 on the chip
 (largest absolute difference of the result and of the three gradients).
 Prints one JSON line a (T, form); a form is judged in its cell in the end.
+
+`--width 256 --heads 16` (PR 48) sweeps ONE head width for scores and
+values, Qwen3-Next's gated attention: the one-kernel backward (its [T, d]
+float32 dq scratch admitted whatever `_fused_bwd_dq_limit` says)
+against the two-kernel one, at the block the lowering takes and at 512, at
+T = 6144 and 8192.
 """
 
 import argparse
@@ -41,7 +47,14 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default="chiprun_out/mla_kernel_sweep.json")
     ap.add_argument("--iters", type=int, default=20)
+    ap.add_argument("--width", type=int, default=None,
+                    help="one head width for q, k and v (256: Qwen3-Next's)"
+                         " in place of latent attention's 192 over 128")
+    ap.add_argument("--heads", type=int, default=HEADS)
     args = ap.parse_args()
+    square = args.width is not None
+    heads = args.heads
+    d_qk, d_v = (args.width, args.width) if square else (D_QK, D_V)
 
     import jax
     import jax.numpy as jnp
@@ -51,19 +64,19 @@ def main():
     dev = jax.devices()[0]
     if dev.platform != "tpu":
         raise SystemExit("mla_kernel_sweep: needs a TPU, jax found %s" % dev)
-    scale = D_QK ** -0.5
+    scale = d_qk ** -0.5
 
     def operands(t):
         keys = jax.random.split(jax.random.PRNGKey(0), 3)
         return tuple(
-            jax.random.normal(kk, (HEADS, t, w), jnp.float32).astype(
+            jax.random.normal(kk, (heads, t, w), jnp.float32).astype(
                 jnp.bfloat16)
-            for kk, w in zip(keys, (D_QK, D_QK, D_V)))
+            for kk, w in zip(keys, (d_qk, d_qk, d_v)))
 
     def grads(fn):
         return jax.jit(jax.value_and_grad(
             lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32)
-                                    * jnp.cos(jnp.arange(D_V))),
+                                    * jnp.cos(jnp.arange(d_v))),
             argnums=(0, 1, 2)))
 
     def timed(fn, ops):
@@ -86,7 +99,7 @@ def main():
             jnp.pad(q, pad), jnp.pad(k, pad), v, None, True, scale, block,
             block)
 
-    dot_nt = pk._dot_nt
+    dot_nt, dq_limit = pk._dot_nt, pk._fused_bwd_dq_limit
 
     def split_dot_nt(a, b):
         if a.shape[-1] != D_QK:
@@ -110,12 +123,17 @@ def main():
 
     rows = []
     fused_limit = pk._FUSED_BWD_DQ_BYTES_WIDE
-    for t in LENGTHS:
+    for t in (LENGTHS[1:] if square else LENGTHS):
         ops = operands(t)
         block = nn_ops._flash_block(t)
         # what one core must do forward + backward over the causal half
-        flops = 3.0 * 2.0 * HEADS * t * t / 2.0 * (D_QK + D_V)
-        forms = [("one192", kernel(block), {}),
+        flops = 3.0 * 2.0 * heads * t * t / 2.0 * (d_qk + d_v)
+        one = {"_fused_bwd_dq_limit": lambda d: 2 ** 40}
+        two = {"_fused_bwd_dq_limit": lambda d: 0}
+        forms = [("one_kernel_bwd", kernel(block), one),
+                 ("one_kernel_bwd_b512", kernel(512), one),
+                 ("two_kernel_bwd", kernel(block), two),
+                 ("two_kernel_bwd_b512", kernel(512), two)] if square else [("one192", kernel(block), {}),
                  ("one192_b512", kernel(512), {}),
                  ("split128_64", kernel(block), {"_dot_nt": split_dot_nt}),
                  ("pad256", padded(block), {}),
@@ -123,7 +141,7 @@ def main():
                   {"_FUSED_BWD_DQ_BYTES_WIDE": 0}),
                  ("two_kernel_bwd_b512", kernel(512),
                   {"_FUSED_BWD_DQ_BYTES_WIDE": 0})]
-        if t == 4096:
+        if t == 4096 and not square:
             forms.append(("dense", lambda q, k, v: pk._dense_attention(
                 q, k, v, True, scale), {}))
         for name, fn, patch in forms:
@@ -137,9 +155,10 @@ def main():
                       flush=True)
             finally:
                 pk._dot_nt, pk._FUSED_BWD_DQ_BYTES_WIDE = dot_nt, fused_limit
+                pk._fused_bwd_dq_limit = dq_limit
             row = {"t": t, "form": name, "block": block,
                    "one_kernel_bwd": bool(
-                       t * D_QK * 4 <= pk._FUSED_BWD_DQ_BYTES_WIDE
+                       (square or t * d_qk * 4 <= fused_limit)
                        and "two_kernel" not in name),
                    "ms": None if ms is None else round(ms, 4),
                    "tflops_causal_half": None if ms is None else round(
@@ -149,6 +168,7 @@ def main():
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
     with open(args.out, "w") as f:
         json.dump({"device": dev.device_kind, "iters": args.iters,
+                   "heads": heads, "widths": [d_qk, d_v],
                    "check_T1024": check, "rows": rows}, f, indent=1)
 
 
