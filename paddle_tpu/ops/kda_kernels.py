@@ -1,7 +1,11 @@
-"""Pallas TPU kernels for the inside of a `kda_attention` chunk
-(ops/kda_ops.py holds the op and the specification): everything a chunk of
-C = 64 tokens computes without the state it enters with (`intra`), and that
-computation transposed (`intra_bwd`).  Both are chunk-parallel: the grid
+"""Pallas TPU kernels for `kda_attention` and `gated_delta_attention`
+(ops/kda_ops.py holds the ops and the specification).  The inside of a
+chunk: everything a chunk of C = 64 tokens computes without the state it
+enters with (`intra`; `gdn_intra` under ONE decay a head), and that
+computation transposed (`intra_bwd`, `gdn_intra_bwd`).  The carry, at the
+end of the module: what reads the state, with the state held in VMEM across
+a head's chunks (`carry`, `carry_bwd`: both members' one).  The inside's
+kernels are chunk-parallel: the grid
 walks batch, heads and blocks of `block` chunks of one head; q, k, v, g are
 read in place from [B, H, T, d] through the BlockSpecs' index maps, and a
 grid step holds its chunks in VMEM from the running sum of g to the
@@ -537,3 +541,210 @@ def gdn_intra_bwd(q, k, v, g, beta, solve, d_parts, scale, block):
     )(q, k, v, _lanes(g), _lanes(beta), solve, dw, du0, da, dqg, dkd,
       jnp.broadcast_to(dgamma.astype(_F32)[..., None], (chunks, b, hv, 1, c)))
     return dq, dk_, dv_, dg.reshape(b, hv, t), dbeta.reshape(b, hv, t)
+
+
+# ---------------------------------------------------------------------------
+# the carry: the state across a head's chunks, both members
+# ---------------------------------------------------------------------------
+# What is left of a chunk once `intra` / `gdn_intra` has run reads the
+# state S the chunk enters with: U = U0 - W S, O = (Q exp(G)) S + A_qk U,
+# S' = gamma S + (K exp(G_C - G))^T U.  The grid is (groups of `heads`
+# heads, chunks), the chunk axis last and sequential (`_carry_params`
+# says so to the compiler), and S lives in a VMEM scratch from a head's
+# first chunk to its last: float32, and TRANSPOSED, [dv, dk], so that the
+# chunk's whole decay (a row [1, dk] under a per-channel decay, [1, 1]
+# under one a head: the kernels broadcast what they are given) scales its
+# lanes and the decay's gradient, sum(S . dS) over dv, is a sum down the
+# sublanes: no narrow array is ever turned.  A grid step holds `heads`
+# heads: their chains of dependent 64-row products interleave, as the
+# chunks of a block do in `intra`.  The pipeline fetches the next chunk's
+# operands under this chunk's products.  Leading axes are merged
+# ([N, B, H, ..] -> [N, B H, ..]: no copy), the parts are read where
+# `intra` wrote them and O is written in place in [B H, T, dv].
+#
+# The backward walks twice: `carry(keep_states=True)` forward, writing the
+# state every chunk ENTERED with ([N, B H, dv, dk] float32: its gradient
+# through the chunk's whole decay sums s . ds elementwise) and U (a
+# product's operand, in the operands' dtype), then `carry_bwd` from the
+# last chunk to the first (the index maps read chunk N - 1 - n) with dS in
+# the scratch.  A visit makes everything the chunk owes, the three products
+# that do not wait for dS among them (dW, dA_qk, d(Q exp(G)): S and U are
+# in VMEM already, so a state is read once).
+
+
+def _carry_params():
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"),
+        vmem_limit_bytes=_pk._VMEM_LIMIT_BYTES)
+
+
+def _carry_kernel(*refs, keep_states):
+    from jax.experimental import pallas as pl
+
+    state = refs[-1]  # S^T [heads, dv, dk] float32
+    if keep_states:  # the backward's first walk reads what U is made of
+        w_ref, u0_ref, kd_ref, gamma_ref, s_ref, u_ref = refs[:-1]
+    else:
+        w_ref, u0_ref, a_ref, qg_ref, kd_ref, gamma_ref, o_ref = refs[:-1]
+    dtype = w_ref.dtype
+
+    @pl.when(pl.program_id(1) == 0)
+    def _first_chunk():
+        state[...] = jnp.zeros_like(state)
+
+    s = state[...]
+    s_op = s.astype(dtype)  # as a product's operand
+    if keep_states:
+        s_ref[...] = s
+        u = (u0_ref[...] - _bmm(w_ref[...], s_op, (2, 2), dtype)).astype(
+            dtype)
+        u_ref[...] = u
+    else:
+        # [W; Q exp(G)] S: one product of 128 rows
+        reads = _bmm(jnp.concatenate([w_ref[...], qg_ref[...]], 1), s_op,
+                     (2, 2), dtype)
+        u = (u0_ref[...] - reads[:, :CHUNK]).astype(dtype)
+        o_ref[...] = (reads[:, CHUNK:] + _bmm(a_ref[...], u, (2, 1), dtype)
+                      ).astype(o_ref.dtype)
+    state[...] = gamma_ref[...] * s + _bmm(u, kd_ref[...], (1, 1), dtype)
+
+
+def _carry_bwd_kernel(w_ref, a_ref, qg_ref, kd_ref, gamma_ref, s_ref, u_ref,
+                      do_ref, dw_ref, du_ref, da_ref, dqg_ref, dkd_ref,
+                      dgamma_ref, d_state):
+    from jax.experimental import pallas as pl
+
+    dtype = w_ref.dtype
+
+    @pl.when(pl.program_id(1) == 0)
+    def _last_chunk():
+        d_state[...] = jnp.zeros_like(d_state)
+
+    ds = d_state[...]  # dS^T [heads, dv, dk] float32
+    ds_op = ds.astype(dtype)  # as a product's operand
+    s, u, d_o = s_ref[...], u_ref[...], do_ref[...]
+    du = (_bmm(a_ref[...], d_o, (1, 1), dtype)
+          + _bmm(kd_ref[...], ds_op, (2, 2), dtype)).astype(dtype)
+    du_ref[...] = du
+    dkd_ref[...] = _bmm(u, ds_op, (2, 1), dtype).astype(dtype)
+    dgamma_ref[...] = jnp.sum(s * ds, axis=1, keepdims=True)
+    # [-dU; dO] S^T -> [dW; d(Q exp(G))]: one product of 128 rows
+    reads = _bmm(jnp.concatenate([-du, d_o], 1), s, (2, 1), dtype)
+    dw_ref[...] = reads[:, :CHUNK].astype(dtype)
+    dqg_ref[...] = reads[:, CHUNK:].astype(dtype)
+    da_ref[...] = _bmm(d_o, u, (2, 2), dtype).astype(dtype)
+    d_state[...] = (_bmm(d_o, qg_ref[...], (1, 1), dtype)
+                    + gamma_ref[...] * ds
+                    - _bmm(du, w_ref[...], (1, 1), dtype))
+
+
+def _carry_specs(n, bh, heads, reverse=False):
+    """(grid, the BlockSpec of a [N, B H, rows, d] array's chunk of `heads`
+    heads, of a [B H, T, d] array's); `reverse`: from the last chunk to the
+    first."""
+    from jax.experimental import pallas as pl
+
+    def at(l):
+        return n - 1 - l if reverse else l
+
+    def parts(rows, d):
+        return pl.BlockSpec((None, heads, rows, d),
+                            lambda i, l: (at(l), i, 0, 0))
+
+    def tokens(d):
+        return pl.BlockSpec((heads, CHUNK, d), lambda i, l: (i, at(l), 0))
+
+    return (bh // heads, n), parts, tokens
+
+
+def _heads_merged(x):
+    """[N, B, H, rows, d] -> [N, B H, rows, d]."""
+    return x.reshape(x.shape[:1] + (-1,) + x.shape[3:])
+
+
+def _carry_operands(parts):
+    """The six parts with their heads merged; the chunk's whole decay
+    [N, B, H, dk or 1] as rows [N, B H, 1, dk or 1]."""
+    return ([_heads_merged(x) for x in parts[:5]]
+            + [_heads_merged(parts[5][:, :, :, None])])
+
+
+def carry(parts, out_dtype, heads, keep_states=False):
+    """An inside's six results, chunks leading ([N, B, H, C, .]; the chunk's
+    whole decay [N, B, H, dk] under a per-channel decay, [N, B, H, 1] under
+    one a head), `heads` dividing B H -> O [B, H, N C, dv] in `out_dtype`;
+    with `keep_states`, for `carry_bwd`: (the state every chunk entered
+    with, transposed, [N, B H, dv, dk] float32; U [N, B H, C, dv] in the
+    operands' dtype)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    w = parts[0]
+    n, b, h, c, dk = w.shape
+    dv, bh = parts[1].shape[-1], b * h
+    ins = _carry_operands(parts)
+    grid, chunk, tokens = _carry_specs(n, bh, heads)
+    _note("kda_carry")
+    in_specs = [chunk(c, dk), chunk(c, dv), chunk(c, c), chunk(c, dk),
+                chunk(c, dk), chunk(1, ins[5].shape[-1])]
+    if keep_states:
+        ins, in_specs = (x[:2] + x[4:] for x in (ins, in_specs))
+        out_specs = [chunk(dv, dk), chunk(c, dv)]
+        out_shape = [_sds((n, bh, dv, dk), _F32, w),
+                     _sds((n, bh, c, dv), w.dtype, w)]
+    else:
+        out_specs = tokens(dv)
+        out_shape = _sds((bh, n * c, dv), out_dtype, w)
+    out = pl.pallas_call(
+        functools.partial(_carry_kernel, keep_states=keep_states),
+        grid=grid,
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((heads, dv, dk), _F32)],
+        interpret=_pk._interpret(),
+        compiler_params=_carry_params(),
+    )(*ins)
+    return out if keep_states else out.reshape(b, h, n * c, dv)
+
+
+def carry_bwd(parts, states, u, do, heads):
+    """`carry` transposed: the parts, what `carry(keep_states=True)` kept
+    and the result's gradient [B, H, N C, dv] -> the six parts' gradients,
+    chunks leading as `intra_bwd` / `gdn_intra_bwd` take them (the last,
+    the chunk's whole decay's, [N, B, H, dk]: a decay of one number a head
+    sums it)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    w = parts[0]
+    n, b, h, c, dk = w.shape
+    dv, bh, dtype = parts[1].shape[-1], b * h, w.dtype
+    ins = _carry_operands(parts)
+    grid, chunk, tokens = _carry_specs(n, bh, heads, reverse=True)
+    _note("kda_carry_bwd")
+    out = pl.pallas_call(
+        _carry_bwd_kernel,
+        grid=grid,
+        in_specs=[chunk(c, dk), chunk(c, c), chunk(c, dk), chunk(c, dk),
+                  chunk(1, ins[5].shape[-1]), chunk(dv, dk), chunk(c, dv),
+                  tokens(dv)],
+        out_specs=[chunk(c, dk), chunk(c, dv), chunk(c, c), chunk(c, dk),
+                   chunk(c, dk), chunk(1, dk)],
+        out_shape=[_sds((n, bh, c, dk), dtype, w),
+                   _sds((n, bh, c, dv), dtype, w),
+                   _sds((n, bh, c, c), dtype, w),
+                   _sds((n, bh, c, dk), dtype, w),
+                   _sds((n, bh, c, dk), dtype, w),
+                   _sds((n, bh, 1, dk), _F32, w)],
+        scratch_shapes=[pltpu.VMEM((heads, dv, dk), _F32)],
+        # a visit reads and writes the same chunk: W, A_qk, Q exp(G),
+        # K exp(G_C - G) and U give their memory to their gradients
+        input_output_aliases={0: 0, 1: 2, 2: 3, 3: 4, 6: 1},
+        interpret=_pk._interpret(),
+        compiler_params=_carry_params(),
+    )(ins[0], *ins[2:], states, u, do.astype(dtype).reshape(bh, n * c, dv))
+    return (tuple(x.reshape((n, b, h) + x.shape[2:]) for x in out[:5])
+            + (out[5].reshape(n, b, h, dk),))

@@ -2,7 +2,7 @@
 Attention, a decay of every key channel: this docstring) and, at the end
 of the module, `gated_delta_attention` (Gated DeltaNet, ONE decay a head
 and key heads shared by several value heads: `gdn_chunked`), which runs
-the same carry around an inside of its own.
+the same carry (the same two kernels) around an inside of its own.
 
 A gated delta-rule linear attention with a per-CHANNEL decay (Kimi Linear,
 moonshotai; the published `chunk_kda`).  Per head, with S_0 = 0 in
@@ -30,8 +30,13 @@ delta rule's step.  ONE lowering, the chunkwise form:
   chooses), chunk-parallel over the whole length, that reads q, k, v, g
   where they lie and holds a grid step's BLOCK chunks of one head in VMEM
   from the running sum to the six results, written chunks leading as the
-  carry walks them.  `carry` is a `lax.scan` over the chunks that carries
-  S [B, H, dk, dv] in float32 (three products a chunk).
+  carry walks them.  `carry` is a Pallas kernel too (kda_kernels.carry,
+  since PR 50; a `lax.scan` before): its grid walks groups of CARRY_HEADS
+  heads and, last and in order, a head's chunks, with S in a VMEM scratch
+  from the first chunk to the last, float32 (and transposed, [dv, dk]: the
+  chunk's whole decay scales its lanes); a visit makes U, O and S' (three
+  products: [W; Q exp(G)] S is one of 128 rows) and writes O's tile
+  straight into [B, H, T, dv].  The state never goes to HBM.
 
 The decay never appears as exp(+cumsum).  D(t, i) is needed inside a
 product over c, and exp(G_t) exp(-G_i) overflows where a channel forgets
@@ -49,11 +54,16 @@ The backward is the op's own (`jax.custom_vjp`): nothing but the op's
 inputs is kept from the forward (behind an optimization barrier with the
 result's gradient, so that the compiler cannot merge the recomputation
 with the forward's work and keep that alive instead).  It makes the
-carry's operands again (kernel 1), runs the carry forward for the state
-every chunk entered with and what it wrote, walks the chunks backwards
-with the state's gradient as the carry (the products that do not wait for
-that gradient are made for all chunks at once, outside the scan), and
-hands the six operands' gradients to the transposed inside: a second
+carry's operands again (kernel 1), walks the chunks forward for the state
+every chunk entered with (stacked float32, [N, B H, dv, dk]: its gradient
+through the chunk's whole decay sums s . ds elementwise) and what it
+wrote (U, a product's operand, in the operands' dtype):
+`carry(keep_states=True)`; walks them backwards with the state's gradient
+in the scratch (kda_kernels.carry_bwd: a visit reads the chunk's parts,
+its entering state, its U and its tile of the result's gradient where
+they lie and makes everything the chunk owes, the three products that do
+not wait for the carried gradient among them, so a state is read once),
+and hands the six operands' gradients to the transposed inside: a second
 kernel that makes a chunk's decays and level products once more in VMEM
 (the inverse it reads: the backward's call of kernel 1 keeps it) and
 transposes the inside by hand (ops/kda_kernels.py says how).  So a step
@@ -69,8 +79,9 @@ by the inverse: XLA's own triangular solve took 60% of the op's time on a
 v5e (PERF.md section 6, PR 45).
 
 Precision: g, beta, the running sums, every exp, the inverse (three
-bfloat16 passes a product: float32 to some 2^-17) and the carried state
-are float32 whatever the trunk; the operands of the other products are in
+bfloat16 passes a product: float32 to some 2^-17), the carried state, its
+gradient and the stacked entering states are float32 whatever the trunk;
+the operands of the other products are in
 Q's dtype (bfloat16 under the AMP pass) with float32 accumulation.  T that
 is no multiple of 64 is padded on the right inside the op (k = 0, beta =
 0, g = 0: the state passes through unchanged), and a length of more than
@@ -98,22 +109,21 @@ CHUNK = kda_kernels.CHUNK  # the published kernel's chunk
 # temporaries the jax.numpy inside bounded, went with them: the whole
 # length's operands are one call's results
 BLOCK = 8
+# heads that a grid step of the carry's kernels holds (kda_kernels.carry /
+# carry_bwd): a head's chunks are a chain of dependent 64-row products, and
+# the heads of a step are the independent chains that fill the waits
+# between them.  On a v5e the op alone, forward + backward (device ms of
+# the forward walk / of the backward's two walks; tools/kda_core_sweep.py
+# --heads-per-step, PERF.md section 6, PR 50): kda_attention at 1 x 32 x
+# 6,144 x 128: 9.11 (0.51 / 1.80) at 4, 8.80 (0.38 / 1.59) at 8, 8.73
+# (0.34 / 1.58) at 16, 8.71 (0.34 / 1.57) at 32; gated_delta_attention at
+# 8,192 tokens: 9.31 (0.74 / 2.42), 8.80 (0.56 / 2.12), 8.77 (0.53 /
+# 2.10), 8.76 (0.53 / 2.09).  16: as fast as 32 to the hundredth, half
+# its blocks in VMEM (the reverse walk holds ~8 MB at 16), and two groups
+# of the cells' 32 heads for a chip with two cores.  A head count it does
+# not divide runs at its largest divisor up to it (`_carry_heads`)
+CARRY_HEADS = 16
 _F32 = jnp.float32
-
-
-def _mm(eq, a, b, dtype):
-    """einsum with operands in `dtype`, accumulated in float32."""
-    return jnp.einsum(eq, a.astype(dtype), b.astype(dtype),
-                      preferred_element_type=_F32)
-
-
-def _new_values(w, u0, s, dtype):
-    """U = U0 - W S: what the chunk's tokens write, given the state."""
-    return u0 - _mm("...tc,...cv->...tv", w, s, dtype)
-
-
-def _next_state(s, u, kd, gamma, dtype):
-    return gamma[..., None] * s + _mm("...tc,...tv->...cv", kd, u, dtype)
 
 
 def _block(t):
@@ -137,13 +147,6 @@ def _whole_chunks(x, t):
                    + [(0, 0)] * (x.ndim - 3))
 
 
-def _chunks_leading(x, t):
-    """[B, H, T, d] -> [N, B, H, C, d], as the carry's scans walk it."""
-    x = _whole_chunks(x, t)
-    return jnp.moveaxis(
-        x.reshape(x.shape[:2] + (-1, CHUNK) + x.shape[3:]), 2, 0)
-
-
 def _intra(ins, scale, keep_solve=False):
     """Everything the chunks compute without the state, chunks leading;
     `ins`: the five inputs in whole chunks."""
@@ -152,70 +155,37 @@ def _intra(ins, scale, keep_solve=False):
                                  keep_solve)
 
 
-def _state0(w, v):
-    """The state every head starts from, [B, H, dk, dv] float32 (`w`: any
-    of the carry's [N, B, H, C, dk] operands)."""
-    return jnp.zeros(w.shape[1:3] + (w.shape[-1], v.shape[-1]), _F32)
+def _carry_heads(heads):
+    """Heads a grid step of the carry's kernels holds: the largest divisor
+    of the B H heads there are that is no more than CARRY_HEADS."""
+    return max(d for d in range(1, min(CARRY_HEADS, heads) + 1)
+               if heads % d == 0)
 
 
-def _carry_forward(parts, v, t, dtype):
-    """The scan over the chunks that carries S: `parts` (an inside's six
-    results, chunks leading) -> o [B, H, T, dv] in v's dtype.  Both
-    members of the family run it: the chunk's whole decay `gamma` is
-    [B, H, dk] a chunk under a per-channel decay, [B, H, 1] under one a
+def _carry_forward(parts, v, t):
+    """The walk over the chunks that carries S (kda_kernels.carry):
+    `parts` (an inside's six results, chunks leading) -> o [B, H, T, dv] in
+    v's dtype.  Both members of the family run it: the chunk's whole decay
+    is [B, H, dk] a chunk under a per-channel decay, [B, H, 1] under one a
     head."""
-
-    def step(s, xs):
-        w, u0, a_qk, qg, kd, gamma = xs
-        u = _new_values(w, u0, s, dtype)
-        o = (_mm("...tc,...cv->...tv", qg, s, dtype)
-             + _mm("...ti,...iv->...tv", a_qk, u, dtype))
-        return _next_state(s, u, kd, gamma, dtype), o.astype(v.dtype)
-
     with jax.named_scope("carry"):
-        _, o = jax.lax.scan(step, _state0(parts[0], v), parts)
-    o = jnp.moveaxis(o, 0, 2)
-    return o.reshape(o.shape[:2] + (-1, o.shape[-1]))[:, :, :t]
+        o = kda_kernels.carry(parts, v.dtype,
+                              _carry_heads(v.shape[0] * v.shape[1]))
+    return o[:, :, :t]
 
 
-def _carry_backward(parts, v, do, dtype):
+def _carry_backward(parts, v, do):
     """The carry transposed: `parts` as above and the result's gradient
     [B, H, T, dv] -> the six parts' gradients (the last, the chunk's whole
-    decay's, [N, B, H, dk]: a decay of one number a head sums it)."""
-
-    def entering(s, xs):
-        w, u0, _, _, kd, gamma = xs
-        u = _new_values(w, u0, s, dtype)
-        # the state stays float32 (the chunk's whole decay's gradient
-        # sums it against the carried gradient); u is a product's operand
-        return _next_state(s, u, kd, gamma, dtype), (s, u.astype(dtype))
-
-    def backward(ds, xs):
-        (w, _, a_qk, qg, kd, gamma), s, u, d_o = xs
-        du = (_mm("...ti,...tv->...iv", a_qk, d_o, dtype)
-              + _mm("...tc,...cv->...tv", kd, ds, dtype)).astype(dtype)
-        out = (du, _mm("...tv,...cv->...tc", u, ds, dtype).astype(dtype),
-               (s * ds).sum(-1))
-        ds = (_mm("...tc,...tv->...cv", qg, d_o, dtype)
-              + gamma[..., None] * ds
-              - _mm("...tc,...tv->...cv", w, du, dtype))
-        return ds, out
-
-    s0 = _state0(parts[0], v)
-    d_o = _chunks_leading(do, do.shape[2])
+    decay's, [N, B, H, dk]: a decay of one number a head sums it).  Two
+    walks: forward for the state every chunk entered with (float32) and
+    what it wrote, backwards with the state's gradient as the carry."""
+    heads = _carry_heads(v.shape[0] * v.shape[1])
     with jax.named_scope("carry"):
-        # forward for the state every chunk entered with and what it
-        # wrote, backwards with the state's gradient as the carry; what
-        # does not wait for that gradient is one product over all chunks
-        _, (states, u) = jax.lax.scan(entering, s0, parts)
-        _, (du, d_kd, d_gamma) = jax.lax.scan(
-            backward, jnp.zeros_like(s0), (parts, states, u, d_o),
-            reverse=True)
-        return (
-            (-_mm("...tv,...cv->...tc", du, states, dtype)).astype(dtype),
-            du, _mm("...tv,...iv->...ti", d_o, u, dtype).astype(dtype),
-            _mm("...tv,...cv->...tc", d_o, states, dtype).astype(dtype),
-            d_kd, d_gamma)
+        states, u = kda_kernels.carry(parts, v.dtype, heads,
+                                      keep_states=True)
+        return kda_kernels.carry_bwd(
+            parts, states, u, _whole_chunks(do, do.shape[2]), heads)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
@@ -225,7 +195,7 @@ def kda_chunked(q, k, v, g, beta, scale):
     t = q.shape[2]
     parts = _intra(tuple(_whole_chunks(x, t) for x in (q, k, v, g, beta)),
                    scale)
-    return _carry_forward(parts, v, t, q.dtype)
+    return _carry_forward(parts, v, t)
 
 
 def _kda_fwd(q, k, v, g, beta, scale):
@@ -237,11 +207,11 @@ def _kda_bwd(scale, res, do):
     # compiler may find the forward's identical work and keep ITS results
     # alive from the forward to here
     q, k, v, g, beta, do = jax.lax.optimization_barrier(res + (do,))
-    t, dtype = q.shape[2], q.dtype
+    t = q.shape[2]
     ins = tuple(_whole_chunks(x, t) for x in (q, k, v, g, beta))
     parts = _intra(ins, scale, keep_solve=True)
     parts, solve = parts[:6], parts[6]
-    d_parts = _carry_backward(parts, v, do, dtype)
+    d_parts = _carry_backward(parts, v, do)
     with jax.named_scope("intra"):
         grads = kda_kernels.intra_bwd(*ins, solve, d_parts, scale,
                                       _block(ins[0].shape[2]))
@@ -261,7 +231,8 @@ def _kda_attention(ctx, ins, attrs):
     scale = attrs.get("scale")
     scale = float(q.shape[-1]) ** -0.5 if scale is None else float(scale)
     kernel_tuning.note_kda_chunks(
-        q.shape[2], _padded(q.shape[2]), CHUNK, _block(q.shape[2]))
+        q.shape[2], _padded(q.shape[2]), CHUNK, _block(q.shape[2]),
+        _carry_heads(v.shape[0] * v.shape[1]))
     return {"Out": [kda_chunked(q, k, v, g, beta, scale)]}
 
 
@@ -288,7 +259,7 @@ def gdn_chunked(q, k, v, g, beta, scale):
     t = v.shape[2]
     parts = _gdn_intra(tuple(_whole_chunks(x, t) for x in (q, k, v, g, beta)),
                        scale)
-    return _carry_forward(parts, v, t, q.dtype)
+    return _carry_forward(parts, v, t)
 
 
 def _gdn_intra(ins, scale, keep_solve=False):
@@ -305,11 +276,11 @@ def _gdn_bwd(scale, res, do):
     # as `_kda_bwd`: nothing but the inputs is kept, and the barrier ties
     # the recomputation to the gradient
     q, k, v, g, beta, do = jax.lax.optimization_barrier(res + (do,))
-    t, dtype = v.shape[2], q.dtype
+    t = v.shape[2]
     ins = tuple(_whole_chunks(x, t) for x in (q, k, v, g, beta))
     parts = _gdn_intra(ins, scale, keep_solve=True)
     parts, solve = parts[:6], parts[6]
-    d_parts = _carry_backward(parts, v, do, dtype)
+    d_parts = _carry_backward(parts, v, do)
     d_parts = d_parts[:5] + (d_parts[5].sum(-1, keepdims=True),)
     with jax.named_scope("intra"):
         dq, dk, dv, dg, dbeta = kda_kernels.gdn_intra_bwd(
@@ -338,7 +309,7 @@ def _gated_delta_attention(ctx, ins, attrs):
     scale = float(q.shape[-1]) ** -0.5 if scale is None else float(scale)
     kernel_tuning.note_kda_chunks(
         v.shape[2], _padded(v.shape[2]), CHUNK, _block(v.shape[2]),
-        decay="head")
+        _carry_heads(v.shape[0] * v.shape[1]), decay="head")
     return {"Out": [gdn_chunked(q, k, v, g, beta, scale)]}
 
 

@@ -30,7 +30,8 @@ _moe_live_chunks = {"ops": 0, "chunk_rows": {}}  # chunk_rows: N*k -> rows a chu
 _attention_band_grid = {"ops": 0, "steps": {}}  # "TxWxBQxBK" -> [walked, computed]
 # kda_attention lowerings (a grad op lowers its forward again), with the
 # chunking each length got
-_kda_chunks = {"ops": 0, "lengths": {}}  # T -> [chunk, chunks a grid step, T, padded T]
+# T -> [chunk, chunks a grid step, T, padded T, heads a carry step]
+_kda_chunks = {"ops": 0, "lengths": {}}
 # gated_delta_attention lowerings, alike: the family's member whose decay
 # is one number a head
 _gdn_chunks = {"ops": 0, "lengths": {}}
@@ -84,16 +85,18 @@ def note_band_grid(t, window, block_q, block_k, walked, computed):
             t, window, block_q, block_k)] = [int(walked), int(computed)]
 
 
-def note_kda_chunks(t, padded_t, chunk, block, decay="channel"):
+def note_kda_chunks(t, padded_t, chunk, block, carry_heads,
+                    decay="channel"):
     """Count a trace-time lowering of a delta-rule op, and keep by length
-    the chunk it ran at, the chunks a grid step of its kernels holds and
-    the length it padded to.  `decay`: "channel" (`kda_attention`) or
-    "head" (`gated_delta_attention`): which record it lands in."""
+    the chunk it ran at, the chunks a grid step of its inside's kernels
+    holds, the length it padded to and the heads a grid step of its
+    carry's kernels holds.  `decay`: "channel" (`kda_attention`) or "head"
+    (`gated_delta_attention`): which record it lands in."""
     record = {"channel": _kda_chunks, "head": _gdn_chunks}[decay]
     with _lock:
         record["ops"] += 1
         record["lengths"][int(t)] = [int(chunk), int(block), int(t),
-                                     int(padded_t)]
+                                     int(padded_t), int(carry_heads)]
 
 
 def attribution():
@@ -102,9 +105,9 @@ def attribution():
     type, the moe_ffn lowerings that took the live-chunk path with the
     rows of a chunk by buffer size, the windowed flash lowerings with their
     forward grid steps walked and computed by shape, the kda_attention
-    lowerings with [chunk, chunks a grid step, T, padded T] by length and
-    the gated_delta_attention lowerings alike (`gdn_chunks`, "decay":
-    "head")."""
+    lowerings with [chunk, chunks a grid step, T, padded T, heads a carry
+    step] by length and the gated_delta_attention lowerings alike
+    (`gdn_chunks`, "decay": "head")."""
     with _lock:
         return {
             "pallas_hits": dict(_kernel_hits),

@@ -11,7 +11,11 @@ heads and not, lengths that pad (1, 63, 65, 200, 600) and that do not
 and the cell's (dk = dv = 128); against `kda_attention` fed the same decay
 on every channel (the two members of the family agree); through a Program
 with its grad op, under the AMP pass, its infer rule, its line in
-program_flops and what it leaves in attribution()."""
+program_flops and what it leaves in attribution(); and the carry's kernels
+(kda_kernels.carry / carry_bwd, KDA's code) fed the parts of this member's
+own inside, against the docstring's equations as a plain lax.scan
+(tests/delta_rule_carry.py; at random parts under either decay:
+tests/test_kda_op.py)."""
 
 import functools
 
@@ -23,7 +27,9 @@ import pytest
 import paddle_tpu as fluid
 from paddle_tpu import analysis, framework, layers, unique_name
 from paddle_tpu.analysis.infer import InferError, VarInfo, get_infer_rule
-from paddle_tpu.ops import kda_ops, kernel_tuning
+from paddle_tpu.ops import kda_kernels, kda_ops, kernel_tuning
+
+import delta_rule_carry as plain
 
 B, HK, HV, DK, DV = 2, 2, 4, 16, 8
 SCALE = DK ** -0.5
@@ -254,21 +260,25 @@ def _lowered_for_tpu(f, *avals):
         jax.clear_caches()
 
 
-def test_lowered_for_a_tpu_the_inside_is_three_mosaic_calls():
+def test_lowered_for_a_tpu_the_op_is_six_mosaic_calls():
     """Compiled where interpreted here: forward + backward of the op at the
     cell's head shape (16 key heads under 32 value heads would be the
-    same program: 2 under 4 here) lower to kernel 1, kernel 1 again and
-    kernel 2, and no flag chose them; the decay reaches the kernels as it
-    came, a number a head a token, its chunks on the lanes ([B, Hv, N, 1,
-    C]), never broadcast to the channels."""
+    same program: 2 under 4 here) lower to kernel 1 and the carry, then
+    kernel 1 again, the carry's two backward walks and kernel 2, and no
+    flag chose them; the decay reaches the inside's kernels as it came, a
+    number a head a token, its chunks on the lanes ([B, Hv, N, 1, C]), and
+    the carry's as one number a head a chunk ([N, B Hv, 1, 1]), never
+    broadcast to the channels."""
     qk = jax.ShapeDtypeStruct((1, 2, 1024, 128), jnp.bfloat16)
     v = jax.ShapeDtypeStruct((1, 4, 1024, 128), jnp.bfloat16)
     row = jax.ShapeDtypeStruct((1, 4, 1024), jnp.float32)
     text = _lowered_for_tpu(jax.value_and_grad(
         lambda *a: kda_ops.gdn_chunked(*a, 128 ** -0.5).astype(
             jnp.float32).sum(), argnums=range(5)), qk, qk, v, row, row)
-    assert text.count("tpu_custom_call") == 3
+    assert text.count("tpu_custom_call") == 6
+    assert "stablehlo.while" not in text
     assert "tensor<1x4x16x1x64xf32>" in text
+    assert "tensor<16x4x1x1xf32>" in text
 
 
 def _half(t, kind):
@@ -310,6 +320,56 @@ def test_bf16_operands_every_gradient_is_the_recurrences(wrt):
                  for g in _half_grads())
     assert np.isfinite(got).all()
     assert np.abs(got - want).max() <= 0.02 * np.abs(want).max()
+
+
+# --- the carry's kernels under one decay a head -----------------------------
+# (against the plain scan at random parts, both decays: tests/test_kda_op.py)
+def test_the_carry_of_a_head_that_forgets_in_a_token_is_finite_and_unflushed():
+    """g = -5 a token a head: the parts kernel 1 hands over have a chunk's
+    whole decay of exp(-320) = 0.  The carry's result is the scan's,
+    finite, the states it enters later chunks with are what the last
+    tokens wrote, not the zero of a flushed state, and the gradients are
+    finite."""
+    w = _data(200, "all_fast")
+    ins = tuple(kda_ops._whole_chunks(jnp.asarray(w[n]), 200)
+                for n in INPUTS)
+    parts = kda_ops._gdn_intra(ins, SCALE)
+    assert float(jnp.abs(parts[5][:3]).max()) == 0.0  # the whole chunks
+    got = kda_ops._carry_forward(parts, ins[2], 256)
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, plain.scan_carry(parts), rtol=1e-4,
+                               atol=1e-6)
+    states, _ = kda_kernels.carry(parts, jnp.float32, B * HV, True)
+    want = plain.scan_carry(parts, states=True)
+    np.testing.assert_allclose(
+        jnp.swapaxes(states, -1, -2).reshape(want.shape), want, rtol=1e-4,
+        atol=1e-7)
+    assert float(jnp.abs(states[1:]).max()) > 1e-3
+    assert all(np.isfinite(g).all() for g in plain.kernel_grads(
+        parts, plain.mix(parts)))
+
+
+def test_the_carried_state_its_gradient_and_the_stacked_states_are_float32():
+    """Under bf16 operands: each of the carry's three calls (the forward's
+    walk, the backward's first walk, its reverse walk) holds its scratch
+    float32, the backward's first walk stacks the entering states float32
+    and U in the operands' dtype, and no scan is left in the op."""
+    half, _ = _half(200, "slow")
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(lambda *a: kda_ops.gdn_chunked(
+        *a, SCALE).astype(jnp.float32).sum(), argnums=range(5)))(*half)
+    calls = plain.carry_calls(jaxpr)
+    assert [len(c.outvars) for c in calls] == [1, 2, 6]
+    for call in calls:
+        (scratch,) = plain.scratch_avals(call)
+        assert (scratch.shape, scratch.dtype) == ((B * HV, DV, DK),
+                                                  jnp.float32)
+    states, u = (v.aval for v in calls[1].outvars)
+    assert (states.shape, states.dtype) == ((4, B * HV, DV, DK), jnp.float32)
+    assert (u.shape, u.dtype) == ((4, B * HV, 64, DV), jnp.bfloat16)
+    # the decay: one float32 number a head a chunk
+    assert ((4, B * HV, 1, 1), jnp.float32) in [
+        (v.aval.shape, v.aval.dtype) for v in calls[2].invars]
+    assert not [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "scan"]
 
 
 # --- through a Program ------------------------------------------------------
@@ -359,13 +419,18 @@ def test_attribution_says_how_each_length_was_chunked_and_which_decay():
     found = kernel_tuning.attribution()
     # the forward op and the grad op's lowering of it; KDA's record is its
     # own and stays empty
-    assert found["gdn_chunks"] == {"ops": 2, "decay": "head",
-                                   "lengths": {65: [64, 2, 65, 128]}}
+    # ..., the heads a grid step of the carry's kernels holds (all B Hv)
+    assert found["gdn_chunks"] == {
+        "ops": 2, "decay": "head",
+        "lengths": {65: [64, 2, 65, 128, B * HV]}}
     assert found["kda_chunks"] == {"ops": 0, "lengths": {}}
     # kernel 1: the forward op, and the grad op twice (its forward, traced
-    # and then dead, and its backward); kernel 2: the grad op
+    # and then dead, and its backward); kernel 2: the grad op; the carry,
+    # the family's one, walks forward wherever kernel 1 ran and backwards
+    # in the grad op
     hits = found["pallas_hits"]
     assert (hits["gdn_intra"], hits["gdn_intra_bwd"]) == (3, 1)
+    assert (hits["kda_carry"], hits["kda_carry_bwd"]) == (3, 1)
     assert "kda_intra" not in hits
 
 

@@ -8,7 +8,11 @@ chunk hands half its state on, lengths that pad (1, 63, 65, 200, 600) and
 that do not (64), one chunk a grid step and several,
 at the narrow heads of most cases and at the cell's (dk = dv = 128);
 through a Program with its grad op, under the AMP pass, its infer rule,
-its line in program_flops and what it leaves in attribution(); and
+its line in program_flops and what it leaves in attribution(); the
+carry's kernels (kda_kernels.carry / carry_bwd, the state in a VMEM scratch
+across a head's chunks) against the docstring's equations as a plain
+lax.scan (tests/delta_rule_carry.py), under a per-channel decay and under
+one a head (`gated_delta_attention`'s: the carry is the family's one); and
 causal_conv, the ungated depthwise convolution beside short_conv, against
 four shifted products."""
 
@@ -23,9 +27,11 @@ import paddle_tpu as fluid
 from paddle_tpu import analysis, framework, layers, unique_name
 from paddle_tpu.analysis.infer import InferError, VarInfo, get_infer_rule
 from paddle_tpu.initializer import NumpyArrayInitializer
-from paddle_tpu.ops import kda_ops, kernel_tuning
+from paddle_tpu.ops import kda_kernels, kda_ops, kernel_tuning
 from paddle_tpu.ops.nn_ops import causal_conv
 from paddle_tpu.param_attr import ParamAttr
+
+import delta_rule_carry as plain
 
 B, H, DK, DV = 2, 2, 16, 8
 SCALE = DK ** -0.5
@@ -198,10 +204,11 @@ def test_a_length_pads_to_whole_grid_steps():
         64, 64, 128, 256, 512, 1024, 1024, 6144, 6656]
 
 
-def test_lowered_for_a_tpu_the_inside_is_three_mosaic_calls(monkeypatch):
+def test_lowered_for_a_tpu_the_op_is_six_mosaic_calls(monkeypatch):
     """Compiled where interpreted here: forward + backward of the op at the
-    cell's head shape lower to kernel 1, kernel 1 again and kernel 2, and
-    no flag chose them."""
+    cell's head shape lower to kernel 1 and the carry, then kernel 1 again,
+    the carry's two backward walks and kernel 2, and no flag chose them;
+    no loop is left beside them."""
     from paddle_tpu.ops import pallas_kernels
 
     monkeypatch.setattr(pallas_kernels, "_interpret", lambda: False)
@@ -214,7 +221,8 @@ def test_lowered_for_a_tpu_the_inside_is_three_mosaic_calls(monkeypatch):
             jnp.float32).sum(), argnums=range(5))).trace(
                 x, x, x, g, beta).lower(lowering_platforms=("tpu",)).as_text()
     jax.clear_caches()
-    assert text.count("tpu_custom_call") == 3
+    assert text.count("tpu_custom_call") == 6
+    assert "stablehlo.while" not in text
 
 
 def _half(t, kind):
@@ -261,15 +269,143 @@ def test_bf16_operands_every_gradient_is_the_recurrences(wrt):
 def test_the_backward_keeps_the_entering_states_float32():
     """The state every chunk entered with is summed elementwise against
     the carried gradient (the gradient through exp(G_C)): under bf16
-    operands the backward stacks it float32, as it carries it; only the
-    products narrow it (rounding it where it is stacked moves dg by less
-    than the products' own rounding, so no tolerance would say)."""
+    operands the carry's kernels hold the state and its gradient float32
+    in their scratch, and the backward's first walk stacks the entering
+    states float32 as it carries them; only the products narrow them
+    (rounding them where they are stacked moves dg by less than the
+    products' own rounding, so no tolerance would say).  U, a product's
+    operand, is stacked in the operands' dtype."""
     half, _ = _half(200, "slow")
-    jaxpr = jax.make_jaxpr(jax.grad(lambda *a: kda_ops.kda_chunked(
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(lambda *a: kda_ops.kda_chunked(
         *a, SCALE).astype(jnp.float32).sum(), argnums=range(5)))(*half)
-    states = [v.aval for eqn in jaxpr.eqns if eqn.primitive.name == "scan"
-              for v in eqn.outvars if v.aval.shape == (4, B, H, DK, DV)]
-    assert states and all(a.dtype == jnp.float32 for a in states)
+    calls = plain.carry_calls(jaxpr)
+    # the forward's walk, the backward's first walk, its reverse walk
+    assert [len(c.outvars) for c in calls] == [1, 2, 6]
+    for call in calls:
+        (scratch,) = plain.scratch_avals(call)
+        assert (scratch.shape, scratch.dtype) == ((B * H, DV, DK),
+                                                  jnp.float32)
+    _, first, reverse = calls
+    states, u = (v.aval for v in first.outvars)
+    assert (states.shape, states.dtype) == ((4, B * H, DV, DK), jnp.float32)
+    assert (u.shape, u.dtype) == ((4, B * H, 64, DV), jnp.bfloat16)
+    assert states.shape in [v.aval.shape for v in reverse.invars]
+    assert not [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "scan"]
+
+
+# --- the carry's kernels against a plain scan -------------------------------
+DECAYS = ("channel", "head")  # the chunk's whole decay [.., dk] or [.., 1]
+# chunks, batch, heads, CARRY_HEADS (None: as the module has it)
+CARRY_CASES = [(3, 1, 2, None), (1, 2, 2, None), (2, 2, 3, 4), (2, 1, 7, 4)]
+CARRY_IDS = ["three_chunks", "one_chunk", "six_heads_three_a_step",
+             "seven_heads_one_a_step"]
+
+
+@pytest.mark.parametrize("decay", DECAYS)
+@pytest.mark.parametrize("n, b, h, heads", CARRY_CASES, ids=CARRY_IDS)
+def test_the_carry_kernel_is_the_plain_scan(monkeypatch, n, b, h, heads,
+                                            decay):
+    """U = U0 - W S, O = (Q exp(G)) S + A_qk U, S' = gamma S + (K exp(G_C -
+    G))^T U with the state in the kernel's scratch, against the same
+    equations in a lax.scan, the chunk's whole decay a number a channel or
+    ONE a head (the kernel broadcasts what it is given); a head count that
+    CARRY_HEADS does not divide runs at its largest divisor (6 heads at 4:
+    3; 7 at 4: one a step)."""
+    if heads:
+        monkeypatch.setattr(kda_ops, "CARRY_HEADS", heads)
+        assert kda_ops._carry_heads(b * h) == {6: 3, 7: 1}[b * h]
+    parts = plain.random_parts(n, b, h, decay, dk=DK, dv=DV)
+    got = kda_ops._carry_forward(parts, plain.mix(parts), n * 64)
+    assert got.shape == (b, h, n * 64, DV)
+    np.testing.assert_allclose(got, plain.scan_carry(parts), rtol=1e-4,
+                               atol=1e-5)
+
+
+@functools.lru_cache(maxsize=None)
+def _carry_grads(n, b, h, heads, decay):
+    parts = plain.random_parts(n, b, h, decay, dk=DK, dv=DV)
+    held, kda_ops.CARRY_HEADS = kda_ops.CARRY_HEADS, heads or \
+        kda_ops.CARRY_HEADS
+    try:
+        return (plain.kernel_grads(parts, plain.mix(parts)),
+                plain.scan_grads(parts, plain.mix(parts)))
+    finally:
+        kda_ops.CARRY_HEADS = held
+
+
+@pytest.mark.parametrize("part", plain.PARTS)
+@pytest.mark.parametrize("decay", DECAYS)
+@pytest.mark.parametrize("n, b, h, heads", CARRY_CASES, ids=CARRY_IDS)
+def test_every_parts_gradient_through_the_carry_is_jax_grad_of_the_scan(
+        n, b, h, heads, decay, part):
+    """The backward's two walks (the entering states and U forward, then
+    the state's gradient from the last chunk to the first, the three
+    products that do not wait for it made in the same visit) against
+    jax.grad of the plain scan: all six parts; one decay a head sums the
+    kernel's [.., dk] over the channels, as `_gdn_bwd` does."""
+    got, want = (g[plain.PARTS.index(part)] for g in _carry_grads(
+        n, b, h, heads, decay))
+    assert got.shape == want.shape
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("decay", DECAYS)
+def test_the_carry_at_a_length_that_pads_reads_and_writes_t_tokens(decay):
+    """101 tokens are two chunks a grid step (`_padded`: 128): the result
+    is cut to 101 tokens, and its gradient comes 101 tokens long and pads
+    with zeros, which is what the scan gets."""
+    t = 101
+    assert kda_ops._padded(t) == 128
+    parts = plain.random_parts(2, B, H, decay, dk=DK, dv=DV, seed=1)
+    mix = plain.mix(parts, t)
+    got = kda_ops._carry_forward(parts, mix, t)
+    assert got.shape == (B, H, t, DV)
+    np.testing.assert_allclose(got, plain.scan_carry(parts)[:, :, :t],
+                               rtol=1e-4, atol=1e-5)
+    whole = jnp.pad(mix, [(0, 0), (0, 0), (0, 128 - t), (0, 0)])
+    for g, w in zip(plain.kernel_grads(parts, mix),
+                    plain.scan_grads(parts, whole)):
+        np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-5)
+
+
+def test_the_carry_of_a_head_that_forgets_in_a_token_is_finite_and_unflushed():
+    """g = -5 a token a channel: the parts kernel 1 hands over have a
+    chunk's whole decay of exp(-320) = 0 and K exp(G_C - G) that is 0 but
+    for the chunk's last tokens.  The carry's result is the scan's, finite,
+    and the states it enters later chunks with are what the last tokens
+    wrote, not the zero of a flushed state; the gradients are finite."""
+    w = _data(200, "all_fast")
+    ins = tuple(kda_ops._whole_chunks(jnp.asarray(w[n]), 200)
+                for n in INPUTS)
+    parts = kda_ops._intra(ins, SCALE)
+    assert float(jnp.abs(parts[5][:3]).max()) == 0.0  # the whole chunks
+    got = kda_ops._carry_forward(parts, ins[2], 256)
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, plain.scan_carry(parts), rtol=1e-4,
+                               atol=1e-6)
+    states, _ = kda_kernels.carry(parts, jnp.float32, B * H, True)
+    want = plain.scan_carry(parts, states=True)
+    np.testing.assert_allclose(
+        jnp.swapaxes(states, -1, -2).reshape(want.shape), want, rtol=1e-4,
+        atol=1e-7)
+    assert float(jnp.abs(states[1:]).max()) > 1e-3
+    assert all(np.isfinite(g).all() for g in plain.kernel_grads(
+        parts, plain.mix(parts)))
+
+
+def test_bf16_parts_carry_a_float32_state_through_the_kernel():
+    """bf16 operands: the kernel's result is the scan's with the same
+    operands narrowed and the state float32 (within the rounding of U,
+    which the kernel narrows once for both of its products), in the
+    result's dtype."""
+    parts = plain.random_parts(4, B, H, "channel", jnp.bfloat16, DK, DV,
+                               seed=2)
+    got = kda_ops._carry_forward(
+        parts, jax.ShapeDtypeStruct((B, H, 256, DV), jnp.bfloat16), 256)
+    assert got.dtype == jnp.bfloat16
+    want = plain.scan_carry(parts, jnp.bfloat16)
+    assert np.abs(np.asarray(got, "float32") - want).max() \
+        <= 0.02 * np.abs(want).max()
 
 
 # --- through a Program ------------------------------------------------------
@@ -318,11 +454,14 @@ def test_attribution_says_how_each_length_was_chunked():
     found = kernel_tuning.attribution()["kda_chunks"]
     # the forward op and the grad op's lowering of it
     assert found["ops"] == 2
-    assert found["lengths"] == {65: [64, 2, 65, 128]}
+    # ..., the heads a grid step of the carry's kernels holds (all B H = 4)
+    assert found["lengths"] == {65: [64, 2, 65, 128, 4]}
     # kernel 1: the forward op, and the grad op twice (its forward, traced
-    # and then dead, and its backward); kernel 2: the grad op
+    # and then dead, and its backward); kernel 2: the grad op; the carry
+    # walks forward wherever kernel 1 ran, and backwards in the grad op
     hits = kernel_tuning.attribution()["pallas_hits"]
     assert (hits["kda_intra"], hits["kda_intra_bwd"]) == (3, 1)
+    assert (hits["kda_carry"], hits["kda_carry_bwd"]) == (3, 1)
 
 
 def test_amp_pass_narrows_q_k_v_and_keeps_the_decay_and_beta_float32():

@@ -100,7 +100,17 @@ nine every share-holding program with one flash core has).  The thirteen digests
 `kimi_linear`'s above all: the carry its op shares with the new one
 (`kda_ops._carry_forward` / `_carry_backward`) traces to the text it traced
 to, and `multi_head_attention`'s `rotary_dim` and `norm_unit_offset` at
-their defaults build `trinity`'s and `lfm2`'s layers op for op."""
+their defaults build `trinity`'s and `lfm2`'s layers op for op.
+
+PR 50 made that carry Pallas kernels (ops/kda_kernels.py: `carry`, and
+`carry_bwd`; the state in a VMEM scratch across a head's chunks, where
+three `lax.scan`s and three hoisted products ran), so `kimi_linear`'s and
+`qwen3_next`'s steps changed on purpose: their digests below are taken from
+PR 50's tree by this file's `_digest`, and their Mosaic calls went from 15
+to 21 and from 18 to 27 (three more a delta-rule layer: `carry` in the
+forward, `carry` keeping the entering states and `carry_bwd` in the grad
+op).  The other twelve digests and counts did not move: no other program
+holds either op."""
 
 import base64
 import hashlib
@@ -251,10 +261,11 @@ PROGRAMS = {"gpt2": lambda: _lm(gpt2.gpt2_lm_program, G),
 # name -> (sha1 of the normalised text, Mosaic calls in it) at ec9cdf7
 # (`lfm2`: at PR 39; `trinity`: at PR 40; `kanana2`: at PR 43; `transformer`,
 # `resnet`, `ouro`: at 6a7549d, PR 44's parent; `kimi_linear`: at PR 46;
-# `gpt2`: at PR 47; `qwen3_next`: at PR 48)
+# `gpt2`: at PR 47; `qwen3_next`: added at PR 48; both delta-rule programs:
+# at PR 50)
 BEFORE = {
-    "qwen3_next": ("a6d462189341a711f761e33d94502ac783953a30", 18),
-    "kimi_linear": ("b1186584b9717d9847564035b6096993865060d7", 15),
+    "qwen3_next": ("e9af49f0b341f789850613e3f1b168b838d4cc8d", 27),
+    "kimi_linear": ("d704e6170f02704b71e679efd22d6817ef0fd123", 21),
     "transformer": ("e83306d8f28e59df41bc44b9fe7303322f29a58b", 0),
     "resnet": ("84575b13d140437bb64cb8461436105337774a6d", 0),
     "ouro": ("b1722471731c977327d882d12eb8e5106bcca444", 3),

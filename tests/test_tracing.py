@@ -853,7 +853,13 @@ def test_kimi_linears_kda_scopes_reach_the_lowered_steps_op_names():
     chunks = kernel_tuning.attribution()["kda_chunks"]
     # two layers, each lowered forward and again inside its grad op
     assert chunks["ops"] == 4
-    assert chunks["lengths"] == {40: [64, 1, 40, 64]}
+    # ..., and the carry's kernels hold all B H = 4 heads a grid step
+    assert chunks["lengths"] == {40: [64, 1, 40, 64, 4]}
+    # a layer a direction: the carry walks forward in the forward op and
+    # (traced, then dead) in the grad op's own forward, and the grad op's
+    # backward walks forward once more for the entering states, then back
+    hits = kernel_tuning.attribution()["pallas_hits"]
+    assert (hits["kda_carry"], hits["kda_carry_bwd"]) == (6, 2)
 
 
 def test_qwen3_nexts_gdn_and_rope_scopes_reach_the_lowered_steps_op_names():
@@ -917,6 +923,11 @@ def test_qwen3_nexts_gdn_and_rope_scopes_reach_the_lowered_steps_op_names():
         "fused_attention", "fused_attention_grad"}
     found = kernel_tuning.attribution()
     # three layers, each lowered forward and again inside its grad op
+    # (the last: the B Hv = 8 heads a grid step of the carry's kernels)
     assert found["gdn_chunks"] == {"ops": 6, "decay": "head",
-                                   "lengths": {40: [64, 1, 40, 64]}}
+                                   "lengths": {40: [64, 1, 40, 64, 8]}}
+    # the carry is the family's one: three layers' walks count under KDA's
+    # names, forward (op, grad op's dead forward, entering states) and back
+    assert (found["pallas_hits"]["kda_carry"],
+            found["pallas_hits"]["kda_carry_bwd"]) == (9, 3)
     assert found["kda_chunks"]["ops"] == 0

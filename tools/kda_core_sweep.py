@@ -9,14 +9,18 @@ result against the token-by-token recurrence in float32 at a shorter
 length.  Run on a TPU:
 
     python3 tools/kda_core_sweep.py [--seq-len 6144] [--block 8]
+        [--heads-per-step 8]
 
 (`--rehearse`: tiny sizes on the CPU, proves the plumbing.)  `--block`
-runs the op's kernels at another number of chunks a grid step
-(`kda_ops.BLOCK`).  `--decay head` times the family's other member,
+runs the inside's kernels at another number of chunks a grid step
+(`kda_ops.BLOCK`), `--heads-per-step` the carry's at another number of
+heads a grid step (`kda_ops.CARRY_HEADS`; several, separated by commas,
+are timed and traced in turn, one JSON line each).  `--decay head` times
+the family's other member,
 `gated_delta_attention` (`kda_ops.gdn_chunked`), at the
 qwen3_next_80b_a3b_train cell's shape: 16 key heads under 32 value heads,
 ONE log-decay a head a token (`--seq-len 8192` is the cell's).  Prints
-one JSON line; PERF.md (PRs 45, 46, 48) keeps what
+one JSON line; PERF.md (PRs 45, 46, 48, 50) keeps what
 it read.
 """
 
@@ -100,6 +104,8 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--seq-len", type=int, default=6144)
     ap.add_argument("--block", type=int, default=None)
+    ap.add_argument("--heads-per-step", default=None,
+                    help="kda_ops.CARRY_HEADS, or several: 4,8,16,32")
     ap.add_argument("--seed", type=int, default=45)
     ap.add_argument("--reps", type=int, default=5)
     ap.add_argument("--rehearse", action="store_true")
@@ -130,61 +136,67 @@ def main():
     mix = jnp.asarray(np.random.default_rng(1).standard_normal(
         (b, h, t, d)), jnp.bfloat16)
 
-    fwd = jax.jit(lambda *a: chunked(*a, scale))
-    both = jax.jit(jax.value_and_grad(
-        lambda *a: (chunked(*a, scale).astype(jnp.float32)
-                    * mix.astype(jnp.float32)).sum(), argnums=range(5)))
-    out = {"shape": [b, h, t, d], "decay": args.decay,
-           "chunk": kda_ops.CHUNK,
-           "block": kda_ops._block(t),
-           "device": jax.devices()[0].device_kind,
-           "forward_ms": _timed(fwd, ins, args.reps),
-           "forward_backward_ms": _timed(both, ins, args.reps)}
+    for heads in (args.heads_per_step or "").split(","):
+        if heads:
+            kda_ops.CARRY_HEADS = int(heads)
+        fwd = jax.jit(lambda *a: chunked(*a, scale))
+        both = jax.jit(jax.value_and_grad(
+            lambda *a: (chunked(*a, scale).astype(jnp.float32)
+                        * mix.astype(jnp.float32)).sum(), argnums=range(5)))
+        out = {"shape": [b, h, t, d], "decay": args.decay,
+               "chunk": kda_ops.CHUNK,
+               "block": kda_ops._block(t),
+               "heads_per_step": kda_ops._carry_heads(b * h),
+               "device": jax.devices()[0].device_kind,
+               "forward_ms": _timed(fwd, ins, args.reps),
+               "forward_backward_ms": _timed(both, ins, args.reps)}
 
-    # against the recurrence, in float32 on the same (bfloat16-rounded)
-    # inputs, at a length the scan finishes in seconds
-    short = [x[:, :, :min(t, 1024)] for x in ins]
-    want = jax.jit(lambda *a: _recurrence(jax, jnp, *a, scale))(*(
-        short if args.decay == "channel" else
-        [jnp.repeat(short[0], 2, 1), jnp.repeat(short[1], 2, 1), short[2],
-         jnp.broadcast_to(short[3][..., None], short[2].shape), short[4]]))
-    got = jax.jit(lambda *a: chunked(*a, scale))(*short)
-    exact = jax.jit(lambda *a: chunked(*a, scale))(
-        *[x.astype(jnp.float32) for x in short])
-    scale_of = float(jnp.abs(want).max())
-    out["max_abs_error_over_max"] = {
-        "bf16_operands": float(jnp.abs(got.astype(jnp.float32)
-                                       - want).max()) / scale_of,
-        "f32_operands": float(jnp.abs(exact - want).max()) / scale_of}
+        # against the recurrence, in float32 on the same (bfloat16-rounded)
+        # inputs, at a length the scan finishes in seconds
+        short = [x[:, :, :min(t, 1024)] for x in ins]
+        want = jax.jit(lambda *a: _recurrence(jax, jnp, *a, scale))(*(
+            short if args.decay == "channel" else
+            [jnp.repeat(short[0], 2, 1), jnp.repeat(short[1], 2, 1), short[2],
+             jnp.broadcast_to(short[3][..., None], short[2].shape), short[4]]))
+        got = jax.jit(lambda *a: chunked(*a, scale))(*short)
+        exact = jax.jit(lambda *a: chunked(*a, scale))(
+            *[x.astype(jnp.float32) for x in short])
+        scale_of = float(jnp.abs(want).max())
+        out["max_abs_error_over_max"] = {
+            "bf16_operands": float(jnp.abs(got.astype(jnp.float32)
+                                           - want).max()) / scale_of,
+            "f32_operands": float(jnp.abs(exact - want).max()) / scale_of}
 
-    # one traced call: device time by named scope and by device op
-    placed = {}
-    for m in re.finditer(r"(%[\w.\-]+) = [^\n]*op_name=\"([^\"]*)\"",
-                         both.lower(*ins).compile().as_text()):
-        placed[m.group(1).lstrip("%")] = m.group(2)
-    with tempfile.TemporaryDirectory() as tmp:
-        jax.profiler.start_trace(tmp)
-        jax.block_until_ready(both(*ins))
-        jax.profiler.stop_trace()
-        ops = _device_ops(tmp)
-    scopes, by_scope = collections.Counter(), collections.defaultdict(list)
-    for name, ms in ops.items():
-        short = name.lstrip("%").split(" ")[0]
-        where = placed.get(short, "")
-        if re.match(r"while[.\d]*$", short):
-            continue  # a loop's own event spans its body's: counted there
-        part = ("intra" if re.search(r"[/(]intra[/)]", where)
-                else "carry" if re.search(r"[/(]carry[/)]", where)
-                else "other")
-        part = ("backward " if "transpose(" in where else "") + part
-        scopes[part] += ms
-        by_scope[part].append([round(ms, 3), short, where[-60:]])
-    out["traced_ms_by_scope"] = dict(scopes)
-    out["traced_ms"] = sum(scopes.values())
-    out["longest_device_ops_ms"] = {
-        part: sorted(found, reverse=True)[:14]
-        for part, found in by_scope.items()}
-    print(json.dumps(out))
+        # one traced call: device time by named scope and by device op
+        placed = {}
+        for m in re.finditer(r"(%[\w.\-]+) = [^\n]*op_name=\"([^\"]*)\"",
+                             both.lower(*ins).compile().as_text()):
+            placed[m.group(1).lstrip("%")] = m.group(2)
+        with tempfile.TemporaryDirectory() as tmp:
+            jax.profiler.start_trace(tmp)
+            jax.block_until_ready(both(*ins))
+            jax.profiler.stop_trace()
+            ops = _device_ops(tmp)
+        scopes, by_scope = collections.Counter(), collections.defaultdict(list)
+        for name, ms in ops.items():
+            short = name.lstrip("%").split(" ")[0]
+            where = placed.get(short, "")
+            if re.match(r"while[.\d]*$", short):
+                continue  # a loop's own event spans its body's: counted there
+            part = ("intra" if re.search(r"[/(]intra[/)]", where)
+                    else "carry" if re.search(r"[/(]carry[/)]", where)
+                    else "other")
+            part = ("backward " if "transpose(" in where else "") + part
+            scopes[part] += ms
+            by_scope[part].append([round(ms, 3), short, where[-60:]])
+        out["traced_ms_by_scope"] = dict(scopes)
+        out["traced_ms"] = sum(scopes.values())
+        out["longest_device_ops_ms"] = {
+            part: sorted(found, reverse=True)[:14]
+            for part, found in by_scope.items()}
+        print(json.dumps(out), flush=True)
+
+
     return 0
 
 
