@@ -58,7 +58,7 @@ def current_microbatch_rows():
 class OpDef:
     def __init__(
         self, type, lower, no_grad_inputs=None, needs_rng=False,
-        side_effect=False, handles_selected_rows=False,
+        side_effect=False, handles_selected_rows=False, stat_outputs=None,
     ):
         self.type = type
         self.lower = lower  # fn(ctx, ins: {slot: [arrays]}, attrs) -> {slot: [arrays]}
@@ -71,18 +71,24 @@ class OpDef:
         # the reference kernels specialized on the SELECTED_ROWS var type);
         # all other ops get inputs densified by the tracer
         self.handles_selected_rows = handles_selected_rows
+        # output slots that hold a step statistic: a persistable the op
+        # writes anew every step for whoever reads the scope (moe_ffn's
+        # TokensPerExpert).  The Executor keeps the last values of those a
+        # step does not read back (trace.TracedFunction.stat_names,
+        # Executor.step_stats)
+        self.stat_outputs = tuple(stat_outputs or ())
 
 
 OPS = {}
 
 
 def register(type_, no_grad_inputs=None, needs_rng=False, side_effect=False,
-             handles_selected_rows=False):
+             handles_selected_rows=False, stat_outputs=None):
     """Decorator: register a lowering rule for op `type_`."""
 
     def deco(fn):
         OPS[type_] = OpDef(type_, fn, no_grad_inputs, needs_rng, side_effect,
-                           handles_selected_rows)
+                           handles_selected_rows, stat_outputs)
         return fn
 
     return deco

@@ -32,13 +32,37 @@ class _TraceContextError(RuntimeError):
 
 
 class TracedFunction:
-    def __init__(self, fn, feed_names, ro_names, rw_names, fetch_names, updated):
+    def __init__(self, fn, feed_names, ro_names, rw_names, fetch_names, updated,
+                 stat_names=()):
         self.fn = fn
         self.feed_names = feed_names
         self.ro_names = ro_names
         self.rw_names = rw_names
         self.fetch_names = fetch_names
         self.updated = updated
+        # the step statistics whose history the Executor keeps
+        # (step_stat_names): a tuple, empty for most programs
+        self.stat_names = stat_names
+
+
+def step_stat_names(block, keep, updated, rw_names):
+    """The step statistics of a traced block that are a FRESH output of
+    every step: the persistable variables in a slot some kept op's
+    registration declares (OpDef.stat_outputs) that the step writes
+    (`updated`) and does not read first (not in `rw_names`).  Such an
+    output is never donated, so the array of an earlier step stays
+    readable for as long as someone holds it: Executor._commit does.  A
+    statistic the step reads before it writes (an accumulator) is donated
+    to the next step and is left out: its earlier arrays are deleted."""
+    fresh = set(updated) - set(rw_names)
+    names = []
+    for op, kept in zip(block.ops, keep):
+        if not kept or op.type not in OPS:
+            continue
+        for slot in OPS[op.type].stat_outputs:
+            names.extend(n for n in op.outputs.get(slot, ())
+                         if n in fresh and n not in names)
+    return tuple(names)
 
 
 def dce_mask(program, block_idx, fetch_names):
@@ -419,7 +443,9 @@ def build_traced_function(program, block_idx, feed_names, fetch_names, scope,
         new_state = {n: densify_maybe(env[n]) for n in updated if n in env}
         return fetches, new_state
 
-    return TracedFunction(program_step, list(feed_names), ro_names, rw_names, fetch_names, updated)
+    return TracedFunction(program_step, list(feed_names), ro_names, rw_names,
+                          fetch_names, updated,
+                          step_stat_names(block, keep, updated, rw_names))
 
 
 class CompiledBlock:

@@ -7,7 +7,9 @@ Feed dict entries become function arguments; fetch vars become outputs; no
 feed/fetch ops or feed-variable side channel are needed.
 """
 
+import collections
 import time
+import weakref
 
 import numpy as np
 import jax
@@ -23,6 +25,9 @@ __all__ = ["Executor", "global_scope", "scope_guard"]
 
 global_scope = scope_mod.global_scope
 scope_guard = scope_mod.scope_guard
+
+# how many steps of a step statistic an Executor keeps (step_stats)
+STAT_WINDOW = 256
 
 def as_numpy(value):
     """Fetch result -> numpy (executor.py:66 analog)."""
@@ -73,6 +78,9 @@ class Executor:
         # executable, feed spec, state classification).  See run().
         self._run_cache = {}
         self._host_feed_ms = 0.0  # cumulative feed-upload wall time
+        # program -> {statistic: deque of (step number, the step's own
+        # output array)}, the last STAT_WINDOW steps.  See step_stats().
+        self._stat_rings = weakref.WeakKeyDictionary()
 
     @property
     def host_feed_ms(self):
@@ -140,11 +148,51 @@ class Executor:
             with compiling:
                 return jitted(*args)
 
-    def _commit(self, scope, new_state):
-        """state_commit: the step's updated state back into the scope."""
+    def _commit(self, scope, new_state, program=None, stat_names=()):
+        """state_commit: the step's updated state back into the scope,
+        and the step statistics of its executable (`stat_names`:
+        TracedFunction.stat_names, empty for most programs) onto their
+        rings.  A statistic is a fresh output of the step, neither donated
+        nor an alias of state, so keeping it is one append: no transfer,
+        no wait, no device op."""
         with RecordEvent("state_commit"):
             for n, v in new_state.items():
                 scope.set(n, v)
+            if stat_names:
+                rings = self._stat_rings.get(program)
+                if rings is None:
+                    rings = self._stat_rings[program] = {}
+                step = self._step - 1  # the number this step's key folded
+                for n in stat_names:
+                    ring = rings.get(n)
+                    if ring is None:
+                        ring = rings[n] = collections.deque(
+                            maxlen=STAT_WINDOW)
+                    ring.append((step, new_state[n]))
+
+    def step_stats(self, program=None):
+        """The history of `program`'s step statistics: {variable name:
+        (steps [n] int64, values [n, ...])}, oldest first, as host numpy.
+        A step statistic is a persistable that an op writes anew every
+        step into a slot its registration declares (`stat_outputs`:
+        moe_ffn's TokensPerExpert); the scope holds the last step's, this
+        the last STAT_WINDOW steps' that ran through run() on this
+        executor, each under the step number its `executor.run` span
+        carries (the number the step's rng key folded; other programs'
+        runs take numbers in between).  Reading stacks a variable's
+        arrays on the device and transfers once; it clears nothing and
+        changes nothing a later step computes.  {} for a program without
+        such a statistic.  A statistic that the step reads before it
+        writes (an accumulator) is donated to the next step and keeps no
+        history, nor does a run_loop window or a pipeline stage's state."""
+        if program is None:
+            program = framework.default_main_program()
+        out = {}
+        for name, ring in self._stat_rings.get(program, {}).items():
+            entries = list(ring)
+            out[name] = (np.array([s for s, _ in entries], np.int64),
+                         np.asarray(jnp.stack([v for _, v in entries])))
+        return out
 
     def _fetched(self, fetches, return_numpy, to_numpy=as_numpy):
         """The run's result; fetch_to_host only where the caller asked
@@ -291,7 +339,9 @@ class Executor:
                 path, runner, how = "fast", self._run_fast, entry
             else:
                 path, runner, how = "slow", self._run_slow, fast_key
-        with RecordEvent("executor.run", path=path):
+        # `step`: the number this run's rng key folds, which its step
+        # statistics are kept under (step_stats)
+        with RecordEvent("executor.run", path=path, step=self._step):
             return runner(program, feed, fetch_names, scope, return_numpy,
                           how)
 
@@ -447,7 +497,7 @@ class Executor:
             jax.block_until_ready(fetches if fetches else list(new_state.values()))
             print("[benchmark] run %.3f ms" % ((time.time() - t0) * 1e3))
 
-        self._commit(scope, new_state)
+        self._commit(scope, new_state, program, compiled.traced.stat_names)
 
         if get_flag("check_nan_inf"):
             # FLAGS_check_nan_inf contract (operator.cc:688): raise on any
@@ -630,7 +680,7 @@ class Executor:
             avals[0] = call_avals(args)
         fetches, new_state = self._dispatch(
             jitted, args, compiling=compiling)
-        self._commit(scope, new_state)
+        self._commit(scope, new_state, program, traced.stat_names)
         return self._fetched(fetches, return_numpy)
 
     def _run_pipeline(self, program, feed, fetch_names, scope, return_numpy,
@@ -928,7 +978,7 @@ class Executor:
         fetches, new_state = self._dispatch(
             jitted, (feed_arrays, ro_state, rw_state, key),
             compiling=compiling)
-        self._commit(scope, new_state)
+        self._commit(scope, new_state, program, traced.stat_names)
         # P() out_specs are fully replicated: np.asarray reads the local
         # shard even in multi-process runs
         return self._fetched(fetches, return_numpy, to_numpy=np.asarray)
@@ -1103,6 +1153,7 @@ class Executor:
             self._loop_cache.clear()
         if getattr(self, "_spmd_cache", None):
             self._spmd_cache.clear()
+        self._stat_rings.clear()
         self._closed = True
 
     # infer_* helpers used by contrib Trainer/Inferencer

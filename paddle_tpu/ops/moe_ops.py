@@ -536,7 +536,8 @@ def route_sigmoid(x2, router_w, bias, top_k, norm_topk_prob, norm_eps=1e-6):
             jnp.zeros((2,), jnp.float32))
 
 
-@register("moe_ffn", no_grad_inputs=("ExpertBias",))
+@register("moe_ffn", no_grad_inputs=("ExpertBias",),
+          stat_outputs=("TokensPerExpert",))
 def _moe_ffn(ctx, ins, attrs):
     """Y = sum over a token's top-k experts of p_e * down_e(silu(gate_e x)
     * up_e x).  Inputs: X [..., d], RouterW [d, E], GateUpW [E_held, d, 2f]

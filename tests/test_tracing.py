@@ -256,6 +256,18 @@ def test_every_run_emits_one_nested_set_of_spans(traced):
         assert outer[3].get("path") == want, outer
 
 
+def test_a_run_span_carries_the_number_its_steps_rng_key_folds(traced):
+    """`step` on every executor.run span, whichever path ran it (the
+    "fast" runs take the slow path at the new batch size): the startup
+    program took 0 and the compile outside the trace 1.  The number is the
+    one Executor.step_stats keeps a step's statistics under."""
+    calls = _calls(traced.spans)
+    assert [int(outer[3]["step"]) for outer, _ in calls] == [2, 3, 4, 5, 6]
+    if traced.path == "fast":
+        assert [outer[3]["path"] for outer, _ in calls] == [
+            "fast", "fast", "fast", "slow", "fast"]
+
+
 def test_the_run_paths_emit_the_same_names(traced):
     spans = traced.spans
     names = {s[2] for s in spans}
