@@ -1,7 +1,8 @@
 """Trace-time attribution counters of the op lowerings (`note_*` /
 `attribution()`): per-family pallas-hit counts, hand-written VJPs, random
 draws by generator, uneven weight constraints, `moe_ffn`'s live chunks, the
-windowed flash kernels' band grids and `kda_attention`'s chunking
+windowed flash kernels' band grids, the causal flash kernels' tiles by class
+and `kda_attention`'s chunking
 (chip_smoke.py and the benchmark's
 readers read them), so an MFU regression can be pinned to "kernel X
 stopped dispatching" instead of guessed at.  Counts tick at TRACE time
@@ -28,6 +29,11 @@ _moe_live_chunks = {"ops": 0, "chunk_rows": {}}  # chunk_rows: N*k -> rows a chu
 # windowed fused_attention lowerings that took the flash kernel, with the
 # forward grid steps a head walks and the tiles its band computes
 _attention_band_grid = {"ops": 0, "steps": {}}  # "TxWxBQxBK" -> [walked, computed]
+# causal fused_attention lowerings that took the flash kernel, with what a
+# head's forward and backward bodies compute of each shape
+# "TxWxBQxBKxD" -> {"ops", "visible", "fwd_pairs", "bwd_pairs",
+#                   "fwd_bodies", "bwd_bodies", "tiles": {class: tiles}}
+_attention_tile_classes = {"ops": 0, "shapes": {}}
 # kda_attention lowerings (a grad op lowers its forward again), with the
 # chunking each length got
 # T -> [chunk, chunks a grid step, T, padded T, heads a carry step]
@@ -85,6 +91,20 @@ def note_band_grid(t, window, block_q, block_k, walked, computed):
             t, window, block_q, block_k)] = [int(walked), int(computed)]
 
 
+def note_tile_classes(t, window, block_q, block_k, d, stats):
+    """Count a trace-time lowering of a causal `fused_attention` to the
+    flash kernel, and keep by shape what pallas_kernels.tile_class_stats
+    says of it (the score pairs visible, the pairs a head's forward and
+    backward bodies compute, the copies of the tile's computation a body
+    holds, the tiles by class) with the lowerings of that shape."""
+    key = "%dx%dx%dx%dx%d" % (t, window, block_q, block_k, d)
+    with _lock:
+        _attention_tile_classes["ops"] += 1
+        ops = _attention_tile_classes["shapes"].get(key, {}).get("ops", 0)
+        _attention_tile_classes["shapes"][key] = dict(
+            stats, tiles=dict(stats["tiles"]), ops=ops + 1)
+
+
 def note_kda_chunks(t, padded_t, chunk, block, carry_heads,
                     decay="channel"):
     """Count a trace-time lowering of a delta-rule op, and keep by length
@@ -104,7 +124,9 @@ def attribution():
     in-program random draws by generator, uneven weight constraints by op
     type, the moe_ffn lowerings that took the live-chunk path with the
     rows of a chunk by buffer size, the windowed flash lowerings with their
-    forward grid steps walked and computed by shape, the kda_attention
+    forward grid steps walked and computed by shape, the causal flash
+    lowerings with the pairs their bodies compute and the tiles of each
+    class by shape (`attention_tile_classes`), the kda_attention
     lowerings with [chunk, chunks a grid step, T, padded T, heads a carry
     step] by length and the gated_delta_attention lowerings alike
     (`gdn_chunks`, "decay": "head")."""
@@ -121,6 +143,10 @@ def attribution():
                 "ops": _attention_band_grid["ops"],
                 "steps": {k: list(v) for k, v in
                           _attention_band_grid["steps"].items()}},
+            "attention_tile_classes": {
+                "ops": _attention_tile_classes["ops"],
+                "shapes": {k: dict(v, tiles=dict(v["tiles"])) for k, v in
+                           _attention_tile_classes["shapes"].items()}},
             "kda_chunks": {
                 "ops": _kda_chunks["ops"],
                 "lengths": {k: list(v) for k, v in
@@ -140,5 +166,6 @@ def reset_attribution():
         _uneven_constraints.clear()
         _moe_live_chunks.update(ops=0, chunk_rows={})
         _attention_band_grid.update(ops=0, steps={})
+        _attention_tile_classes.update(ops=0, shapes={})
         _kda_chunks.update(ops=0, lengths={})
         _gdn_chunks.update(ops=0, lengths={})

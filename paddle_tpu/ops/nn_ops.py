@@ -1025,8 +1025,10 @@ def _fused_attention(ctx, ins, attrs):
     # step is placed on a TPU and the shape is one the chip sweep found
     # it ahead at, dense XLA everywhere else.  No flag is read here.
     if _flash_engages(ctx, t, tk, d, dv):
-        from .kernel_tuning import note_band_grid, note_kernel
-        from .pallas_kernels import band_grid_steps, flash_attention
+        from .kernel_tuning import (note_band_grid, note_kernel,
+                                    note_tile_classes)
+        from .pallas_kernels import (band_grid_steps, flash_attention,
+                                     tile_class_stats)
         from .spmd_epilogue import mesh_ctx, spmd_flash_attention
 
         note_kernel("attention")
@@ -1038,6 +1040,9 @@ def _fused_attention(ctx, ins, attrs):
         if window:  # the grid a head's forward walks against its band
             note_band_grid(t, window, blk, blk,
                            *band_grid_steps(t, blk, blk, window))
+        if causal:  # the pairs its bodies compute against the visible
+            note_tile_classes(t, window, blk, blk, d,
+                              tile_class_stats(t, d, blk, blk, window))
         if mc is None:
             out = flash_attention(qf, kf, vf, kbias, causal, float(scale),
                                   blk, blk, window, seg)

@@ -12,6 +12,8 @@ path chooses `flash_attention` from platform and shape
 """
 
 import functools
+import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -101,7 +103,7 @@ def _unpack_flash_refs(refs, has_qoff, has_seg):
 
 def _flash_fwd_kernel(*refs, block_q, block_k, nk,
                       causal, scale, window=0, has_qoff=False,
-                      has_seg=False, band=0):
+                      has_seg=False, band=0, tiles=None):
     from jax.experimental import pallas as pl
 
     qo, q_ref, k_ref, v_ref, kb_ref, sq_ref, sk_ref, refs = \
@@ -122,25 +124,37 @@ def _flash_fwd_kernel(*refs, block_q, block_k, nk,
     if band:
         run = run & live
 
-    @pl.when(run)
-    def _compute():
+    def _compute(keep, rows=_ALL, cols=_ALL):
+        """Rows `rows` of the q block against rows `cols` of the k block
+        (slices of the tile; the whole tile by default), the scores masked
+        by `keep` where one is given."""
         # operands reach the MXU in their own dtype (bf16 under AMP),
         # accumulation and the softmax state are f32
-        q, k, v = q_ref[0], k_ref[0], v_ref[0]  # [bq, d], [bk, d] x2
+        q, k, v = q_ref[0, rows], k_ref[0, cols], v_ref[0, cols]
         s = _dot_nt(q, k) * scale  # [bq, bk]
-        s = s + kb_ref[0].astype(jnp.float32)  # [1, bk] broadcast
+        s = s + kb_ref[0, :, cols].astype(jnp.float32)  # [1, bk] broadcast
         if has_seg:  # packing: keep within-segment scores only
             s = jnp.where(
-                sq_ref[0].reshape(-1, 1) == sk_ref[0], s, NEG_INF)
-        s = keep_fn(s)
-        m_prev = m_ref[:]
+                sq_ref[0, :, rows].reshape(-1, 1) == sk_ref[0, :, cols],
+                s, NEG_INF)
+        if keep is not None:
+            s = keep(s)
+        m_prev = m_ref[rows]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + jnp.dot(
+        l_ref[rows] = l_ref[rows] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[rows] = acc_ref[rows] * alpha + jnp.dot(
             p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-        m_ref[:] = m_new
+        m_ref[rows] = m_new
+
+    if tiles is None:  # a tile's place is not known here: every one masked
+        pl.when(run)(lambda: _compute(keep_fn))
+    else:
+        # a cut tile in strips of q rows, each against the k rows it can
+        # see: a row's softmax state is rescaled once, as on a whole tile
+        _tile_bodies(tiles, run, qi, ki, block_q, block_k, window, keep_fn,
+                     _compute, strips_of="q")
 
     @pl.when(step == (band or nk) - 1)
     def _write():
@@ -173,6 +187,201 @@ def _band(qi, ki, qo, block_q, block_k, causal, window, transposed=False):
         return jnp.where(keep, s, NEG_INF)
 
     return run, keep_fn
+
+
+# A tile by where it lies.  With a causal mask, blocks known when the
+# kernel is traced and no traced q offset, a tile that _band lets run is one
+# of four classes: wholly visible (under the diagonal and inside the band:
+# computed without a mask, no iotas, compare or select), cut by the diagonal
+# alone, cut by the band's lower edge alone, or cut by both (a window
+# narrower than a block).  A tile cut by one edge is computed over its
+# visible part, in `parts` strips of block / parts rows, each against the
+# rows of the other side it can see and masked on the one square the cut
+# crosses: (parts + 1) / (2 parts) of the tile's pairs, the others' exact
+# zeros left out.  Strips need square blocks (the diagonal then cuts tile
+# qi == ki corner to corner) and, on the band's edge, a window that is a
+# multiple of the block (the edge then cuts tile qi - ki == window / block
+# corner to corner); a tile cut otherwise, or by both, is the whole masked
+# tile it was.  A kernel body holds one copy of the tile's computation a
+# class that occurs and `parts` a class in strips: 1 + 4 a cut edge at most,
+# and what a model traces once a kernel is that body (a copy costs ~0.05 s
+# of a first step on a chip's host, PERF.md section 6, PR 53).
+_ALL = slice(None)
+_MAX_PARTS = 4
+
+
+class _Tiles(NamedTuple):
+    """How a kernel body computes each class of tile: `whole`, `both`: the
+    class occurs; `diag`, `edge`: 0 the class does not occur, 1 the whole
+    masked tile, more: that many strips."""
+    whole: bool
+    diag: int
+    edge: int
+    both: bool
+
+    @property
+    def bodies(self):
+        """Copies of the tile's computation in a kernel body."""
+        masked = self.both or self.diag == 1 or self.edge == 1
+        return (int(self.whole) + int(masked)
+                + sum(p for p in (self.diag, self.edge) if p > 1))
+
+
+def _tile_counts(t, block_q, block_k, window):
+    """Tiles of a head by class, {"whole", "diag", "edge", "both"}: causal
+    self-attention at length t, under `window` where it is not 0."""
+    qi = np.arange(t // block_q)[:, None]
+    ki = np.arange(t // block_k)[None, :]
+    q_lo, k_lo = qi * block_q, ki * block_k
+    q_hi, k_hi = q_lo + block_q - 1, k_lo + block_k - 1
+    run = k_lo <= q_hi
+    inside = np.ones_like(run)
+    if window:
+        run = run & (q_lo - k_hi < window)
+        inside = q_hi - k_lo < window
+    under = k_hi <= q_lo
+    return {"whole": int(np.sum(run & under & inside)),
+            "diag": int(np.sum(run & ~under & inside)),
+            "edge": int(np.sum(run & under & ~inside)),
+            "both": int(np.sum(run & ~under & ~inside))}
+
+
+def _strip_parts(block):
+    """Strips a cut tile of square `block` blocks is taken in: their width
+    block / parts a multiple of 128 (lse and delta are sliced by lanes),
+    _MAX_PARTS at most; 1, the whole masked tile, under 256."""
+    return max(1, min(_MAX_PARTS, block // 128))
+
+
+def _fwd_strip_parts(t, block):
+    """Strips the FORWARD takes a cut tile in: 2 where the sequence holds
+    several blocks, the whole masked tile where one block holds it.  A strip
+    of the forward ends in a row maximum and sum that the next matmul waits
+    for, so more strips expose more of that chain: on a v5e
+    (tools/attention_sweep.py --tile-classes both --parts 1x4,2x4,4x2, PR
+    53; forward alone against every tile masked, 1 / 2 / 4 strips) T 4096
+    d 128 0.997 / 0.926 / 0.961, T 6144 192 over 128 0.986 / 0.919 / 0.933,
+    T 8192 d 64 0.994 / 0.959 / 0.976, d 128 0.993 / 0.954 / 0.974, its 2048
+    band 0.995 / 0.929 / 0.946, d 256 0.990 / 0.935 / 0.936; GPT-2's one
+    1024-tile a head (d 64) 0.996 / 1.082 / 1.066: strips cost it 7 %.  The
+    backward rebuilds its tiles from the saved lse, has no such chain, and
+    takes _strip_parts' four everywhere (0.72–0.94 against 0.82–0.95 in
+    two)."""
+    return min(2, _strip_parts(block)) if t > block else 1
+
+
+def _tile_plan(t, block_q, block_k, window, parts):
+    """The _Tiles of a causal kernel at length t whose cut tiles are taken
+    in `parts` strips where strips apply."""
+    n = _tile_counts(t, block_q, block_k, window)
+    square = block_q == block_k
+
+    def how(count, strips):
+        return 0 if not count else parts if strips and parts > 1 else 1
+
+    return _Tiles(whole=n["whole"] > 0,
+                  diag=how(n["diag"], square),
+                  edge=how(n["edge"], square and window % block_q == 0),
+                  both=n["both"] > 0)
+
+
+def _plan_pairs(tiles, n, block_q, block_k):
+    """Score pairs a head's kernel computes under plan `tiles` over tile
+    counts `n`: a whole or masked tile all of its pairs, a tile in p strips
+    (p + 1) / (2 p) of them."""
+    def share(p):
+        return (p + 1) / (2.0 * p) if p > 1 else 1.0
+
+    return int(block_q * block_k * (
+        n["whole"] + n["both"] + n["diag"] * share(tiles.diag)
+        + n["edge"] * share(tiles.edge)))
+
+
+def tile_class_stats(t, d, block_q, block_k, window):
+    """What the training path's kernels compute of a causal head at length
+    t, width d, for the lowering's attribution: the score pairs visible
+    (causal: t (t + 1) / 2; under a window each query's last `window`), the
+    pairs the forward's and the backward's bodies compute, the copies of
+    the tile's computation each body holds and the tiles by class.  The
+    two-kernel backward (a sequence past the one kernel's dq scratch)
+    computes every tile _band lets run twice, masked."""
+    block_q, block_k = min(block_q, t), min(block_k, t)
+    n = _tile_counts(t, block_q, block_k, window)
+    fwd = _tile_plan(t, block_q, block_k, window,
+                     _fwd_strip_parts(t, block_q))
+    if _fused_bwd_applies(t, t, d):
+        bwd = _tile_plan(t, block_q, block_k, window, _strip_parts(block_q))
+        bwd_pairs, bwd_bodies = _plan_pairs(bwd, n, block_q, block_k), \
+            bwd.bodies
+    else:
+        bwd_pairs, bwd_bodies = 2 * block_q * block_k * sum(n.values()), 1
+    seen = np.minimum(np.arange(t) + 1, window or t)
+    return {"visible": int(np.sum(seen)),
+            "fwd_pairs": _plan_pairs(fwd, n, block_q, block_k),
+            "bwd_pairs": bwd_pairs, "fwd_bodies": fwd.bodies,
+            "bwd_bodies": bwd_bodies, "tiles": n}
+
+
+def _strip_keep(at, width, edge, transposed):
+    """Masks the [width, width] square at lane `at` of a strip's scores,
+    where the cut crosses it: in the square's own coordinates the diagonal
+    keeps k <= q, the band's edge q < k (q along axis `transposed`)."""
+    def keep(s):
+        sq = s[:, at:at + width]
+        q = jax.lax.broadcasted_iota(jnp.int32, sq.shape, int(transposed))
+        k = jax.lax.broadcasted_iota(jnp.int32, sq.shape,
+                                     1 - int(transposed))
+        sq = jnp.where((q < k) if edge else (k <= q), sq, NEG_INF)
+        pieces = ([s[:, :at]] if at else []) + [sq] + (
+            [s[:, at + width:]] if at + width < s.shape[1] else [])
+        return jnp.concatenate(pieces, axis=1) if len(pieces) > 1 else sq
+
+    return keep
+
+
+def _tile_bodies(tiles, run, qi, ki, block_q, block_k, window, keep_fn,
+                 compute, strips_of, transposed=False):
+    """Emits, under pl.when on the tile's class, the bodies plan `tiles`
+    asks for.  compute(keep, q rows, k rows) computes a part of the tile,
+    its scores masked by `keep` where that is not None.  A strip is
+    block / parts rows of side `strips_of` ("q" or "k") against the rows of
+    the other side it can see; the scores' minor axis is that other side."""
+    from jax.experimental import pallas as pl
+
+    under = ki * block_k + block_k - 1 <= qi * block_q
+    inside = window and (qi + 1) * block_q - 1 - ki * block_k < window
+
+    def cls(diag_cuts, edge_cuts):
+        c = ~under if diag_cuts else under
+        return c & (~inside if edge_cuts else inside) if window else c
+
+    masked = []  # the classes that keep the whole masked tile
+
+    def strips(parts, edge):
+        w = block_q // parts
+        # the other side's visible rows start at the tile's start (and the
+        # cut crosses their last square) or end at its end (their first)
+        low = edge == (strips_of == "k")
+        for i in range(parts):
+            own = slice(i * w, (i + 1) * w)
+            other = slice(0, (i + 1) * w) if low else slice(i * w, block_q)
+            keep = _strip_keep(i * w if low else 0, w, edge, transposed)
+            compute(keep, *((own, other) if strips_of == "q"
+                            else (other, own)))
+
+    if tiles.whole:
+        pl.when(run & cls(False, False))(lambda: compute(None))
+    for parts, edge in ((tiles.diag, False), (tiles.edge, True)):
+        if parts > 1:
+            pl.when(run & cls(not edge, edge))(
+                functools.partial(strips, parts, edge))
+        elif parts:
+            masked.append(cls(not edge, edge))
+    if tiles.both:
+        masked.append(cls(True, True))
+    if masked:
+        pl.when(run & functools.reduce(lambda a, b: a | b, masked))(
+            lambda: compute(keep_fn))
 
 
 # The band as a grid.  A windowed kernel without a traced q offset knows
@@ -262,7 +471,7 @@ def _flash_blocks(Tq, Tk, block_q, block_k, causal):
 
 
 def _flash_fwd(q, k, v, kbias, causal, scale, block_q, block_k, window=0,
-               qoff=None, seg=None, interpret=None):
+               qoff=None, seg=None, interpret=None, by_class=False):
     """q: [BH, Tq, d], k: [BH, Tk, d], v: [BH, Tk, dv] (the result is
     [BH, Tq, dv]; dv is d everywhere but under latent attention, whose
     scores are 192 wide over 128-wide values), kbias: [BH, Tk] additive
@@ -274,7 +483,9 @@ def _flash_fwd(q, k, v, kbias, causal, scale, block_q, block_k, window=0,
     passes its chunk offset so causal/window masks apply in global
     positions.  seg: optional [BH, T] int32 segment ids (sequence
     packing; requires Tq == Tk) — rides as two more [BH, 1, X] rank-1
-    operands, compared per score tile.  Returns (o, lse)."""
+    operands, compared per score tile.  by_class (the training path's
+    entry; causal self-attention without an offset): a tile is computed by
+    where it lies (_tile_plan).  Returns (o, lse)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -290,11 +501,15 @@ def _flash_fwd(q, k, v, kbias, causal, scale, block_q, block_k, window=0,
     band = _band_grid(T, Tk, block_q, block_k,
                       causal and qoff is None, int(window))
     kblock = _band_inner(band, block_q, block_k, int(window), nk)
+    tiles = None
+    if by_class and causal and qoff is None:
+        tiles = _tile_plan(T, block_q, block_k, int(window),
+                           _fwd_strip_parts(T, block_q))
     kernel = functools.partial(
         _flash_fwd_kernel, block_q=block_q, block_k=block_k, nk=nk,
         causal=causal, scale=scale, window=int(window),
         has_qoff=qoff is not None,
-        has_seg=seg is not None, band=band,
+        has_seg=seg is not None, band=band, tiles=tiles,
     )
     # 2D [BH, X] operands ride as [BH, 1, X] so every block keeps a
     # Mosaic-legal last-two-dims shape ((1, blk): second-minor equals the
@@ -625,8 +840,13 @@ def _fused_bwd_dq_limit(d):
             else _FUSED_BWD_DQ_BYTES_256)
 
 
+def _fused_bwd_applies(tq, tk, d):
+    """Self-attention whose [T, d] float32 dq fits the scratch."""
+    return tq == tk and tq * d * 4 <= _fused_bwd_dq_limit(d)
+
+
 def _flash_bwd_fused_kernel(*refs, block_q, block_k, nq, nk, causal, scale,
-                            window, has_kb, has_seg, band=0):
+                            window, has_kb, has_seg, band=0, tiles=None):
     from jax.experimental import pallas as pl
 
     refs = list(refs)
@@ -661,28 +881,45 @@ def _flash_bwd_fused_kernel(*refs, block_q, block_k, nq, nk, causal, scale,
     if band:
         run = run & live
 
-    @pl.when(run)
-    def _compute():
-        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
+    def _compute(keep, qs=_ALL, ks=_ALL):
+        """Rows `ks` of the k block against rows `qs` of the q block
+        (slices of the tile; the whole tile by default), the transposed
+        scores masked by `keep` where one is given."""
+        q, k, v, do = q_ref[0, qs], k_ref[0, ks], v_ref[0, ks], do_ref[0, qs]
         st = _dot_nt(k, q) * scale  # [bk, bq]
         if has_kb:
-            st = st + kb_ref[0].astype(jnp.float32).reshape(-1, 1)
+            st = st + kb_ref[0, :, ks].astype(jnp.float32).reshape(-1, 1)
         if has_seg:
-            st = jnp.where(sk_ref[0].reshape(-1, 1) == sq_ref[0], st, NEG_INF)
-        pt = jnp.exp(keep_fn(st) - lse_ref[0])  # lse, delta: [1, bq]
-        dv_acc[:] = dv_acc[:] + jnp.dot(
+            st = jnp.where(
+                sk_ref[0, :, ks].reshape(-1, 1) == sq_ref[0, :, qs],
+                st, NEG_INF)
+        if keep is not None:
+            st = keep(st)
+        pt = jnp.exp(st - lse_ref[0, :, qs])  # lse, delta: [1, bq]
+        dv_acc[ks] = dv_acc[ks] + jnp.dot(
             pt.astype(do.dtype), do, preferred_element_type=jnp.float32)
-        dst = pt * (_dot_nt(v, do) - delta_ref[0])
+        dst = pt * (_dot_nt(v, do) - delta_ref[0, :, qs])
         if has_kb:
-            dkb_acc[:] = dkb_acc[:] + jnp.sum(
+            dkb_acc[:, ks] = dkb_acc[:, ks] + jnp.sum(
                 dst, axis=1, keepdims=True).reshape(1, -1)
         dsc = dst.astype(q.dtype)
-        dk_acc[:] = dk_acc[:] + scale * jnp.dot(
+        dk_acc[ks] = dk_acc[ks] + scale * jnp.dot(
             dsc, q, preferred_element_type=jnp.float32)
-        rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+        q0 = qs.indices(block_q)[0]  # these q rows' place in dq's scratch
+        first = qi * block_q + q0 if q0 else qi * block_q
+        rows = pl.ds(pl.multiple_of(first, math.gcd(block_q, q0)),
+                     q.shape[0])
         dq_acc[rows, :] = dq_acc[rows, :] + scale * jax.lax.dot_general(
             dsc, k, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)  # [bq, d]
+
+    if tiles is None:  # non-causal: every tile whole, and none masked
+        pl.when(run)(lambda: _compute(keep_fn))
+    else:
+        # a cut tile in strips of k rows, each against the q rows that see
+        # it: dk and dv take one update a k row, as on a whole tile
+        _tile_bodies(tiles, run, qi, ki, block_q, block_k, window, keep_fn,
+                     _compute, strips_of="k", transposed=True)
 
     @pl.when(step == (band or nq) - 1)
     def _write():
@@ -711,6 +948,8 @@ def _flash_bwd_fused(q, k, v, kbias, seg, o, lse, do, causal, scale, block_q,
                       transposed=True)
     qblock = _band_inner(band, block_q, block_k, int(window), nq,
                          transposed=True)
+    tiles = (_tile_plan(T, block_q, block_k, int(window),
+                        _strip_parts(block_q)) if causal else None)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
 
     def spec(shape, index):
@@ -747,7 +986,8 @@ def _flash_bwd_fused(q, k, v, kbias, seg, o, lse, do, causal, scale, block_q,
         functools.partial(
             _flash_bwd_fused_kernel, block_q=block_q, block_k=block_k,
             nq=nq, nk=nk, causal=causal, scale=scale, window=int(window),
-            has_kb=kbias is not None, has_seg=seg is not None, band=band),
+            has_kb=kbias is not None, has_seg=seg is not None, band=band,
+            tiles=tiles),
         grid=(BH, nk, band or nq),
         in_specs=in_specs,
         out_specs=out_specs,
@@ -775,14 +1015,14 @@ def _flash_fwd_call(q, k, v, kbias, seg, *, causal, scale, block_q, block_k,
                     window, interpret):
     kb = kbias if kbias is not None else jnp.zeros(k.shape[:2], jnp.float32)
     return _flash_fwd(q, k, v, kb, causal, scale, block_q, block_k, window,
-                      seg=seg, interpret=interpret)
+                      seg=seg, interpret=interpret, by_class=True)
 
 
 @functools.partial(jax.jit, static_argnames=_FLASH_STATICS)
 def _flash_bwd_call(q, k, v, kbias, seg, o, lse, do, *, causal, scale,
                     block_q, block_k, window, interpret):
     T, d = q.shape[1:]
-    if T == k.shape[1] and T * d * 4 <= _fused_bwd_dq_limit(d):
+    if _fused_bwd_applies(T, k.shape[1], d):
         return _flash_bwd_fused(q, k, v, kbias, seg, o, lse, do, causal,
                                 scale, block_q, block_k, window, interpret)
     kb = kbias if kbias is not None else jnp.zeros(k.shape[:2], jnp.float32)

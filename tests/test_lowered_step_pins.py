@@ -110,9 +110,28 @@ PR 50's tree by this file's `_digest`, and their Mosaic calls went from 15
 to 21 and from 18 to 27 (three more a delta-rule layer: `carry` in the
 forward, `carry` keeping the entering states and `carry_bwd` in the grad
 op).  The other twelve digests and counts did not move: no other program
-holds either op."""
+holds either op.
+
+PR 53 made the causal flash kernels of the training path compute a tile by
+where it lies (`pallas_kernels._tile_plan`: a tile wholly visible without a
+mask, a tile the diagonal or the band's edge cuts in strips over its visible
+part), so every causal payload changed on purpose: the digests of the eight
+programs that hold a causal flash core (`gpt2`, `olmoe`, `lfm2`, `trinity`,
+`kanana2`, `kimi_linear`, `qwen3_next`, `ouro`) and of the four causal cores
+(`two_kernel_backward`: its forward alone, the dq and dk/dv kernels are what
+they were) are re-taken from PR 53's tree by this file's `_digest`.  No
+Mosaic count moved (a kernel's body grew, no kernel was added), and
+`transformer` and `resnet` (no flash kernel) did not move.  Three cores the
+change must NOT reach were added, their digests taken from PR 53's PARENT
+commit ba67ef1 by this file's `_digest`: `non_causal_2048` (a mask-free
+`flash_attention`: forward and one-kernel backward), `piece_at_an_offset`
+(`flash_attention_piece` under a window at a traced q offset: forward, dq,
+dk/dv) and `piece_diagonal_chunk` (the ring's causal chunk, no offset): a
+call that cannot know a tile's class when traced, and the ring's entry,
+lower to what they lowered to, to the instruction."""
 
 import base64
+import functools
 import hashlib
 import re
 
@@ -262,27 +281,47 @@ PROGRAMS = {"gpt2": lambda: _lm(gpt2.gpt2_lm_program, G),
 # (`lfm2`: at PR 39; `trinity`: at PR 40; `kanana2`: at PR 43; `transformer`,
 # `resnet`, `ouro`: at 6a7549d, PR 44's parent; `kimi_linear`: at PR 46;
 # `gpt2`: at PR 47; `qwen3_next`: added at PR 48; both delta-rule programs:
-# at PR 50)
+# at PR 50; every program and core with a causal flash kernel: at PR 53; the
+# three UNTOUCHED cores: at ba67ef1, PR 53's parent)
 BEFORE = {
-    "qwen3_next": ("e9af49f0b341f789850613e3f1b168b838d4cc8d", 27),
-    "kimi_linear": ("d704e6170f02704b71e679efd22d6817ef0fd123", 21),
+    "qwen3_next": ("066aa16bdf09bc9d5c356dda010c95375aae93b6", 27),
+    "kimi_linear": ("0b6cea6313d097ed575295ef51fd8b563347239c", 21),
     "transformer": ("e83306d8f28e59df41bc44b9fe7303322f29a58b", 0),
     "resnet": ("84575b13d140437bb64cb8461436105337774a6d", 0),
-    "ouro": ("b1722471731c977327d882d12eb8e5106bcca444", 3),
-    "kanana2": ("442939de9116ac8230c03beb34b17b378ccc0476", 9),
-    "trinity": ("12416b47e9d02155b186ce8f38c8ee118bdb5539", 12),
-    "gpt2": ("e5c95bdfc499c3d1c08aedb34fcd729310f95fa4", 3),
-    "olmoe": ("10b230dd86c559798a6caed82c488d59280933fd", 9),
-    "lfm2": ("1991430ba2fa7bbe38f4bdb0225a591ed0238837", 9),
-    "two_kernel_backward": ("3f2ec33fc054ccb7413c50a5286bea4b68cd0550", 3),
-    "full_causal_8192": ("1d2658a220612308dabdfadc170a714c52702bcb", 2),
-    "window_covers_8192": ("f33f5c775539a38a0bbcc60e17df72e7754d8a78", 2),
-    "window_2048_of_8192": ("7969f8a89b4c29447cefb9f8dc35fd4424fd33ec", 2),
+    "ouro": ("0057fcbecadc1719a1de27cb3b94bb41ac569650", 3),
+    "kanana2": ("f4a9b6486a2b6513c06bb50fec5aeb98be334a10", 9),
+    "trinity": ("393382caa36c2983f2c696aab8920cbea225d4e7", 12),
+    "gpt2": ("bfdcbc6f62d3aaf4418dc9bf22e0aaf9dc8a8a4a", 3),
+    "olmoe": ("8fc96fbebcde6d156165e9b26443399f6b36494f", 9),
+    "lfm2": ("25c451b8998c9bd5e6e4808f9b598d69a27d779a", 9),
+    "two_kernel_backward": ("6f367b397e82d43ecfa48e0704dc18729431a92e", 3),
+    "full_causal_8192": ("f284ffd3954ccf8c5126a296a953a8f757415e38", 2),
+    "window_covers_8192": ("2d62811d7d6a9788008f38ce1ebbccc6f736718c", 2),
+    "window_2048_of_8192": ("55fb7bfd281ba57dbb1e66197fc71be9aa664089", 2),
+    "non_causal_2048": ("9b7ca3bb75c85e16b56930b0829a721b43a0acac", 2),
+    "piece_at_an_offset": ("1d162f089780ed64698a5fda1537085bfc2cb3c6", 3),
+    "piece_diagonal_chunk": ("8d11042a97d13bfc58008ff7a66b546217520674", 3),
 }
 # name -> (T, window) of an attention core alone, forward + backward
 CORES = {"two_kernel_backward": (16384, 0), "full_causal_8192": (8192, 0),
          "window_covers_8192": (8192, 8192),
          "window_2048_of_8192": (8192, 2048)}
+
+
+def _untouched(q, k, v, qoff, name):
+    """The calls PR 53's tile classes must not reach, at T = 2048, heads of
+    128: a scalar to differentiate."""
+    if name == "non_causal_2048":
+        return jnp.sum(pk.flash_attention(
+            q, k, v, None, False, 128 ** -0.5, 1024, 1024).astype(
+                jnp.float32))
+    o, lse = pk.flash_attention_piece(
+        q, k, v, True, 128 ** -0.5, 128, 128,
+        *((512, qoff) if name == "piece_at_an_offset" else ()))
+    return jnp.sum(o.astype(jnp.float32)) + jnp.sum(lse)
+
+
+UNTOUCHED = ("non_causal_2048", "piece_at_an_offset", "piece_diagonal_chunk")
 
 
 def _module_without_locations(payload):
@@ -328,6 +367,14 @@ def _lowered_step(main, startup, loss, feeds):
             lowering_platforms=("tpu",))
 
 
+def _untouched_text(name):
+    x = jax.ShapeDtypeStruct((2, 2048, 128), jnp.bfloat16)
+    qoff = jax.ShapeDtypeStruct((1,), jnp.int32)
+    return jax.jit(jax.grad(
+        functools.partial(_untouched, name=name), argnums=(0, 1, 2))).trace(
+            x, x, x, qoff).lower(lowering_platforms=("tpu",)).as_text()
+
+
 def _core_text(t, window):
     """An attention core alone, forward + backward, heads of 128 in blocks
     of 1024.  T = 16384 outgrows the one-kernel backward's dq scratch:
@@ -345,6 +392,7 @@ def test_the_lowered_step_is_what_it_was_before_pr_37(monkeypatch, name):
     monkeypatch.setattr(pk, "_interpret", lambda: False)
     jax.clear_caches()  # an interpreted trace of these shapes would hide
     text = (_core_text(*CORES[name]) if name in CORES
+            else _untouched_text(name) if name in UNTOUCHED
             else _lowered_step(*PROGRAMS[name]()).as_text())
     assert _digest(text) == BEFORE[name]
     jax.clear_caches()  # and these would hide from a later interpreted one

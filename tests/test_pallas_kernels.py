@@ -351,6 +351,205 @@ def test_without_a_window_the_traced_kernels_are_the_full_grid(monkeypatch,
     jax.clear_caches()
 
 
+# (name, window as a function of (T, block)): the ways a causal band lies
+# over the tiles
+BANDS = {
+    "causal": lambda t, blk: 0,
+    "window_inside": lambda t, blk: (2 * blk if t > 2 * blk else blk
+                                     if t > blk else t - 3),
+    "window_inside_off_block": lambda t, blk: blk + blk // 2 - 3,
+    "window_covers": lambda t, blk: t + 5,
+    "window_narrower_than_a_block": lambda t, blk: blk // 2 + 3,
+}
+
+
+@pytest.mark.parametrize("extras", ["plain", "kbias_and_segments"])
+@pytest.mark.parametrize("blk,blocks,d,dv", [
+    (128, 1, 64, 64), (256, 1, 64, 64), (256, 3, 64, 64), (512, 2, 64, 64),
+    (128, 1, 192, 128), (256, 1, 192, 128), (256, 3, 192, 128)])
+@pytest.mark.parametrize("band", sorted(BANDS))
+def test_a_tile_by_where_it_lies_is_the_whole_masked_tile(
+        monkeypatch, band, blk, blocks, d, dv, extras):
+    """The training path's forward and one-kernel backward, a tile computed
+    by its class (whole without a mask, cut tiles in strips over their
+    visible part: pallas_kernels._tile_plan), against every tile computed
+    whole under the mask from positions (the plan held at none), both
+    interpreted: o, lse, dk, dv (and the key bias's gradient) to float32
+    rounding of the sums' order (a strip's exact zeros are left out of a
+    row's sum and of a matmul's contraction), dq alike."""
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    t = blk * blocks
+    window = BANDS[band](t, blk)
+    rng = np.random.RandomState(blk + blocks + d)
+    q, k, do = (jnp.asarray(rng.randn(1, t, n).astype("float32"))
+                for n in (d, d, dv))
+    v = jnp.asarray(rng.randn(1, t, dv).astype("float32"))
+    kbias = seg = None
+    if extras != "plain":
+        kb = np.zeros((1, t), "float32")
+        kb[:, 5:9] = -1e9  # padded keys inside the first strip
+        kb[:, t - 70:t - 30] = -3.0
+        kbias = jnp.asarray(kb)
+        seg = jnp.asarray((np.arange(t) >= t // 3 + 5).astype(
+            "int32")[None, :])
+    scale = d ** -0.5
+
+    def both_passes():
+        kb = kbias if kbias is not None else jnp.zeros((1, t), jnp.float32)
+        o, lse = pk._flash_fwd(q, k, v, kb, True, scale, blk, blk, window,
+                               seg=seg, interpret=True, by_class=True)
+        grads = pk._flash_bwd_fused(q, k, v, kbias, seg, o, lse, do, True,
+                                    scale, blk, blk, window, True)
+        return (o, lse) + tuple(g for g in grads if g is not None)
+
+    plan = pk._tile_plan(t, blk, blk, window, pk._strip_parts(blk))
+    assert plan.bodies <= (9 if window else 5)
+    if blk >= 256 and band in ("causal", "window_covers"):
+        assert plan.diag == blk // 128 and not plan.edge and not plan.both
+    if band == "window_narrower_than_a_block":
+        assert plan.both and plan.diag == 0 and plan.edge <= 1
+    if band == "window_inside" and blocks > 1 and blk >= 256:
+        assert plan.diag == plan.edge == min(4, blk // 128)
+        assert plan.whole == (blocks > 2)
+    if band == "window_inside_off_block" and blocks > 1 and blk >= 256:
+        assert plan.diag > 1 and plan.edge == 1  # no corner-to-corner cut
+    by_class = both_passes()
+    monkeypatch.setattr(pk, "_tile_plan", lambda *a, **kw: None)
+    masked = both_passes()
+    assert len(by_class) == len(masked) == (5 if kbias is None else 6)
+    for a, b in zip(by_class, masked):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-5,
+                                   atol=2e-5)
+    # and the masked computation is the dense one
+    ref = _dense_attention(q, k, v, True, scale, kbias, window=window,
+                           seg=seg)
+    np.testing.assert_allclose(np.asarray(by_class[0]), np.asarray(ref),
+                               rtol=2e-4, atol=2e-4)
+
+
+def _dots(jaxpr):
+    """dot_general equations under `jaxpr`, every branch included."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == "dot_general"
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += _dots(sub)
+    return n
+
+
+# name -> (T, d, d_v, window, block, copies of the tile's computation the
+# forward's and the backward's body may hold); the cells' cores first
+BODIES = {
+    "gpt2": (1024, 64, 64, 0, 1024, 1, 4),
+    "ouro_olmoe": (4096, 128, 128, 0, 1024, 3, 5),
+    "kanana2_kimi": (6144, 192, 128, 0, 1024, 3, 5),
+    "lfm2": (8192, 64, 64, 0, 1024, 3, 5),
+    "qwen3_next": (8192, 256, 256, 0, 1024, 3, 5),
+    "trinity_window": (8192, 128, 128, 2048, 1024, 5, 9),
+    "window_covers": (8192, 128, 128, 8192, 1024, 3, 5),
+    "window_off_block": (8192, 128, 128, 2500, 1024, 4, 6),
+    "window_narrower_than_a_block": (8192, 128, 128, 1000, 1024, 1, 1),
+    "blocks_of_128": (512, 64, 64, 0, 128, 2, 2),
+}
+
+
+@pytest.mark.parametrize("name", sorted(BODIES))
+def test_a_kernel_body_holds_no_more_copies_than_its_cut_edges_allow(name):
+    """What a model's first step pays once a kernel is the size of ONE
+    traced body (~0.05 s a copy of the tile's computation, PERF.md section
+    6): 1 + 4 copies a cut edge at most (causal 5, windowed 9; the parent
+    held 1), counted as dot_generals of the traced kernel (two a copy in
+    the forward, five in the one-kernel backward); the forward takes a cut
+    tile in two strips, so 1 + 2 an edge, and GPT-2's one tile a head
+    whole (pallas_kernels._fwd_strip_parts).  What tile_class_stats tells
+    the benchmark is what was traced."""
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    t, d, dv, window, blk, fwd_copies, bwd_copies = BODIES[name]
+    x = jax.ShapeDtypeStruct((2, t, d), jnp.bfloat16)
+    xv = jax.ShapeDtypeStruct((2, t, dv), jnp.bfloat16)
+    row = jax.ShapeDtypeStruct((2, t), jnp.float32)
+    fwd, = _pallas_eqns(jax.make_jaxpr(
+        lambda q, k, v: pk._flash_fwd(
+            q, k, v, jnp.zeros((2, t), jnp.float32), True, d ** -0.5, blk,
+            blk, window, interpret=True, by_class=True))(x, x, xv).jaxpr)
+    bwd, = _pallas_eqns(jax.make_jaxpr(
+        lambda q, k, v, o, lse, do: pk._flash_bwd_fused(
+            q, k, v, None, None, o, lse, do, True, d ** -0.5, blk, blk,
+            window, True))(x, x, xv, xv, row, xv).jaxpr)
+    budget = 9 if 0 < window < t else 5
+    assert fwd_copies <= budget and bwd_copies <= budget
+    assert _dots(fwd.params["jaxpr"]) == 2 * fwd_copies
+    assert _dots(bwd.params["jaxpr"]) == 5 * bwd_copies
+    said = pk.tile_class_stats(t, d, blk, blk, window)
+    assert (said["fwd_bodies"], said["bwd_bodies"]) == (fwd_copies,
+                                                        bwd_copies)
+
+
+def test_a_traced_offset_and_a_mask_free_call_keep_the_one_masked_body():
+    """flash_attention_piece (a traced q offset, or none: the ring's
+    diagonal chunk) and a non-causal flash_attention cannot or need not
+    know a tile's class: one copy of the tile's computation a kernel, as
+    before, in the two-kernel backward too."""
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    t, d, blk = 1024, 64, 256
+    x = jax.ShapeDtypeStruct((2, t, d), jnp.float32)
+    qoff = jax.ShapeDtypeStruct((1,), jnp.int32)
+
+    def piece(q, k, v, qoff):
+        o, lse = pk.flash_attention_piece(q, k, v, True, None, blk, blk, 0,
+                                          qoff)
+        return jnp.sum(o) + jnp.sum(lse)
+
+    def plain(q, k, v, qoff):
+        return jnp.sum(pk.flash_attention(q, k, v, None, False, None, blk,
+                                          blk))
+
+    def diagonal_chunk(q, k, v, qoff):
+        return jnp.sum(pk.flash_attention_piece(q, k, v, True, None, blk,
+                                                blk)[0])
+
+    for fn, dots in ((piece, [2, 3, 4]), (diagonal_chunk, [2, 3, 4]),
+                     (plain, [2, 5])):
+        kernels = _pallas_eqns(jax.make_jaxpr(jax.grad(
+            fn, argnums=(0, 1, 2)))(x, x, x, qoff).jaxpr)
+        assert [_dots(e.params["jaxpr"]) for e in kernels] == dots
+
+
+def test_tile_class_stats_at_the_cells_shapes():
+    """What the lowering records for the benchmark: the visible pairs
+    (causal T (T + 1) / 2, a band its own count), the pairs the bodies
+    compute (a whole or masked tile all of its pairs, a tile in four strips
+    10 / 16) and the tiles by class."""
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    said = pk.tile_class_stats(8192, 128, 1024, 1024, 2048)
+    assert said["tiles"] == {"whole": 7, "diag": 8, "edge": 6, "both": 0}
+    assert said["visible"] == 14681088  # 2048 x 8192 - 2048 x 2047 / 2
+    assert said["bwd_pairs"] == 1024 * 1024 * (7 + 14 * 10 // 16) \
+        + 1024 * 1024 * 12 // 16
+    said = pk.tile_class_stats(8192, 128, 1024, 1024, 0)
+    assert said["tiles"] == {"whole": 28, "diag": 8, "edge": 0, "both": 0}
+    assert said["visible"] == 8192 * 8193 // 2
+    assert said["bwd_pairs"] == 1024 * 1024 * 33
+    assert said["fwd_pairs"] == 1024 * 1024 * 34  # two strips: 3 / 4
+    said = pk.tile_class_stats(1024, 64, 1024, 1024, 0)
+    assert said["tiles"] == {"whole": 0, "diag": 1, "edge": 0, "both": 0}
+    assert said["fwd_pairs"] == 1024 * 1024  # one tile a head: whole
+    assert said["bwd_pairs"] / said["visible"] == pytest.approx(1.2488,
+                                                                abs=1e-4)
+    # past the one kernel's dq scratch: two masked kernels a tile
+    said = pk.tile_class_stats(16384, 128, 1024, 1024, 0)
+    assert said["bwd_bodies"] == 1
+    assert said["bwd_pairs"] == 2 * 1024 * 1024 * 136
+    # a window narrower than a block cuts every tile it reaches twice
+    said = pk.tile_class_stats(8192, 128, 1024, 1024, 1000)
+    assert said["tiles"] == {"whole": 0, "diag": 0, "edge": 7, "both": 8}
+    assert said["fwd_pairs"] == said["bwd_pairs"] == 1024 * 1024 * 15
+
+
 def test_fused_attention_layer_window():
     """The window attr flows through the op and layer (dense path here;
     the pallas path shares the masks by the kernel test above)."""

@@ -21,6 +21,19 @@ tiles it computes, and from the two grids the cost of one skipped step;
 the full causal triangle (window 0) at 1024 beside them.
 
     python3 tools/attention_sweep.py --window 2048 [--out chiprun_out/window_sweep.json]
+
+With --tile-classes it times the training path's kernels at the flash
+cells' shapes (TILE_SHAPES) with a tile computed by where it lies (PR 53:
+`on`, what the program does) against every tile masked whole (`off`:
+pallas_kernels._tile_plan held at "no plan" for that row, here in the tool:
+the program has no such switch), or `both` and their ratio: ms forward and
+backward, the pairs computed over the visible ones, the copies of the tile's
+computation a kernel body holds, and the warm trace + lower seconds of the
+forward + backward (what a model's first step pays once a kernel).
+--parts 1x4,4x4 times further strip counts (forward x backward), held in the
+tool alike.
+
+    python3 tools/attention_sweep.py --tile-classes both [--out chiprun_out/tile_sweep.json]
 """
 
 import argparse
@@ -41,6 +54,19 @@ SHAPES = [
     ("T512", 128, 512, 64, True, False),
 ]
 BLOCKS = (128, 256, 512, 1024)
+# (cells, B*H, T, width of Q and K, width of V, window) of --tile-classes:
+# the nine flash cells' attention cores, in blocks of nn_ops._flash_block(T)
+TILE_SHAPES = [
+    ("gpt2_345m_train", 64, 1024, 64, 64, 0),
+    ("ouro_2b6_train", 16, 4096, 128, 128, 0),
+    ("olmoe_1b7b_train", 32, 4096, 128, 128, 0),
+    ("kanana2_30b_a3b_train+kimi_linear_48b_a3b_train", 32, 6144, 192, 128,
+     0),
+    ("lfm2_8b_a1b_train", 64, 8192, 64, 64, 0),
+    ("trinity_mini_train.full", 32, 8192, 128, 128, 0),
+    ("trinity_mini_train.window", 32, 8192, 128, 128, 2048),
+    ("qwen3_next_80b_a3b_train", 16, 8192, 256, 256, 0),
+]
 
 
 def main():
@@ -52,18 +78,32 @@ def main():
     ap.add_argument("--bh", type=int, default=32)
     ap.add_argument("--t", type=int, default=8192)
     ap.add_argument("--d", type=int, default=128)
+    ap.add_argument("--tile-classes", choices=("on", "off", "both"),
+                    default=None,
+                    help="time the kernels with a tile computed by where it "
+                    "lies (on), every tile masked whole (off), or both")
+    ap.add_argument("--parts", default="",
+                    help="with --tile-classes: further strip counts to time,"
+                    " forward x backward, as 1x4,4x4")
+    ap.add_argument("--repeats", type=int, default=3)
+    ap.add_argument("--shapes", default="",
+                    help="with --tile-classes: only the shapes whose name "
+                    "holds one of these comma-separated words")
     ap.add_argument("--rehearse", action="store_true",
-                    help="the window sweep's plumbing on the CPU, kernels "
-                    "interpreted: the times mean nothing")
+                    help="the window or tile sweep's plumbing on the CPU, "
+                    "kernels interpreted at small sizes: the times mean "
+                    "nothing")
     args = ap.parse_args()
 
     import jax
     import jax.numpy as jnp
+    import numpy as np
 
     from paddle_tpu.ops import pallas_kernels as pk
 
     dev = jax.devices()[0]
-    if dev.platform != "tpu" and not (args.rehearse and args.window):
+    if dev.platform != "tpu" and not (
+            args.rehearse and (args.window or args.tile_classes)):
         raise SystemExit("attention_sweep: needs a TPU, jax found %s" % dev)
 
     def timed(fn, operands, backward=True):
@@ -128,6 +168,106 @@ def main():
             rows.append(cost)
             print(json.dumps(cost), flush=True)
         return rows
+
+    def tile_sweep():
+        """One JSON line a (shape, variant), and with `both` one a shape
+        comparing them."""
+        from paddle_tpu.ops import nn_ops
+
+        plan, fwd_parts, bwd_parts = (pk._tile_plan, pk._fwd_strip_parts,
+                                      pk._strip_parts)
+        variants = [v for v in ("off", "on")
+                    if args.tile_classes in (v, "both")]
+        variants += [tuple(int(n) for n in p.split("x"))
+                     for p in args.parts.split(",") if p]
+        words = [w for w in args.shapes.split(",") if w]
+        rows = []
+        for name, bh, t, d, dv, w in TILE_SHAPES:
+            if words and not any(word in name for word in words):
+                continue
+            if args.rehearse:  # two blocks of 256 a side, interpreted
+                bh, t, w = 1, 512, w and 256
+            blk = 256 if args.rehearse else nn_ops._flash_block(t)
+            keys = jax.random.split(jax.random.PRNGKey(0), 3)
+            q, k, v = (jax.random.normal(kk, (bh, t, n), jnp.float32).astype(
+                jnp.bfloat16) for kk, n in zip(keys, (d, d, dv)))
+            scale = d ** -0.5
+
+            def fn(q, k, v, kb):
+                return pk.flash_attention(q, k, v, kb, True, scale, blk, blk,
+                                          w)
+
+            grad = jax.grad(lambda q, k, v: jnp.sum(
+                fn(q, k, v, None).astype(jnp.float32)), argnums=(0, 1, 2))
+            # every tile _band lets run computed whole, and the pairs seen
+            whole = blk * blk * sum(pk._tile_counts(t, blk, blk, w).values())
+            seen = int(np.sum(np.minimum(np.arange(t) + 1, w or t)))
+            said = {}
+            for variant in variants:
+                pk._tile_plan = plan if variant != "off" else (
+                    lambda *a, **kw: None)
+                pk._fwd_strip_parts, pk._strip_parts = (
+                    (fwd_parts, bwd_parts) if isinstance(variant, str) else
+                    (lambda t, block, n=variant[0]: min(n, block // 128),
+                     lambda block, n=variant[1]: min(n, block // 128)))
+                setup = []
+                for _ in range(2):  # the second: imports and caches warm
+                    jax.clear_caches()  # the kernels' entries are jitted
+                    t0 = time.perf_counter()
+                    jax.jit(grad).trace(q, k, v).lower()
+                    setup.append(time.perf_counter() - t0)
+                jax.clear_caches()
+                fwd = min(timed(fn, (q, k, v, None), backward=False)
+                          for _ in range(args.repeats))
+                both = min(timed(fn, (q, k, v, None))
+                           for _ in range(args.repeats))
+                o = fn(q, k, v, None)
+                stats = (pk.tile_class_stats(t, d, blk, blk, w)
+                         if variant != "off" else None)
+                row = {
+                    "shape": name, "bh": bh, "t": t, "d": d, "dv": dv,
+                    "window": w, "block": blk,
+                    "tile_classes": variant if isinstance(variant, str)
+                    else "%dx%d" % variant,
+                    "fwd_ms": round(fwd, 4), "bwd_ms": round(both - fwd, 4),
+                    "fwd_bwd_ms": round(both, 4),
+                    "pairs_over_visible_fwd_bwd": [
+                        round((stats[p] if stats else whole) / seen, 4)
+                        for p in ("fwd_pairs", "bwd_pairs")],
+                    "bodies_fwd_bwd": [stats["fwd_bodies"],
+                                       stats["bwd_bodies"]] if stats
+                    else [1, 1],
+                    "trace_lower_s": round(setup[1], 3)}
+                said[row["tile_classes"]] = (row, o, grad(q, k, v))
+                rows.append(row)
+                print(json.dumps(row), flush=True)
+            if "off" in said and len(said) > 1:
+                (off, o0, g0) = said.pop("off")
+                for label, (on, o1, g1) in said.items():
+                    cmp = {"shape": name, "tile_classes": label + "/off",
+                           "max_abs_diff_o_dq_dk_dv": [
+                               float(jnp.max(jnp.abs(a.astype(jnp.float32)
+                                                     - b.astype(jnp.float32))))
+                               for a, b in zip((o1,) + g1, (o0,) + g0)],
+                           "trace_lower_s_more": round(
+                               on["trace_lower_s"] - off["trace_lower_s"], 3)}
+                    for p in ("fwd_ms", "bwd_ms", "fwd_bwd_ms"):
+                        cmp[p + "_ratio"] = round(on[p] / off[p], 4)
+                    rows.append(cmp)
+                    print(json.dumps(cmp), flush=True)
+        pk._tile_plan, pk._fwd_strip_parts, pk._strip_parts = (
+            plan, fwd_parts, bwd_parts)
+        return rows
+
+    if args.tile_classes:
+        rows = tile_sweep()
+        if args.out == ap.get_default("out"):
+            args.out = "chiprun_out/tile_sweep.json"
+        os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
+        with open(args.out, "w") as f:
+            json.dump({"device": dev.device_kind, "iters": args.iters,
+                       "rows": rows}, f, indent=1)
+        return
 
     if args.window:
         rows = window_sweep()
