@@ -96,7 +96,8 @@ def note_tile_classes(t, window, block_q, block_k, d, stats):
     flash kernel, and keep by shape what pallas_kernels.tile_class_stats
     says of it (the score pairs visible, the pairs a head's forward and
     backward bodies compute, the copies of the tile's computation a body
-    holds, the tiles by class) with the lowerings of that shape."""
+    holds, the tiles by class, the inner blocks a head's forward and
+    backward walks copy in) with the lowerings of that shape."""
     key = "%dx%dx%dx%dx%d" % (t, window, block_q, block_k, d)
     with _lock:
         _attention_tile_classes["ops"] += 1
@@ -125,8 +126,9 @@ def attribution():
     type, the moe_ffn lowerings that took the live-chunk path with the
     rows of a chunk by buffer size, the windowed flash lowerings with their
     forward grid steps walked and computed by shape, the causal flash
-    lowerings with the pairs their bodies compute and the tiles of each
-    class by shape (`attention_tile_classes`), the kda_attention
+    lowerings with the pairs their bodies compute, the tiles of each
+    class and the blocks their walks copy in by shape
+    (`attention_tile_classes`), the kda_attention
     lowerings with [chunk, chunks a grid step, T, padded T, heads a carry
     step] by length and the gated_delta_attention lowerings alike
     (`gdn_chunks`, "decay": "head")."""

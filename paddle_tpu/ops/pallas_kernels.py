@@ -302,24 +302,31 @@ def tile_class_stats(t, d, block_q, block_k, window):
     t, width d, for the lowering's attribution: the score pairs visible
     (causal: t (t + 1) / 2; under a window each query's last `window`), the
     pairs the forward's and the backward's bodies compute, the copies of
-    the tile's computation each body holds and the tiles by class.  The
-    two-kernel backward (a sequence past the one kernel's dq scratch)
-    computes every tile _band lets run twice, masked."""
+    the tile's computation each body holds, the tiles by class, and the
+    inner blocks each pass copies in (_walk_fetches: the tiles _band lets
+    run where every block copied in is computed on, fewer where a block held
+    over a row's end serves two tiles).  The two-kernel backward (a
+    sequence past the one kernel's dq scratch) computes every tile _band
+    lets run twice, masked, and copies in for both of its walks."""
     block_q, block_k = min(block_q, t), min(block_k, t)
     n = _tile_counts(t, block_q, block_k, window)
     fwd = _tile_plan(t, block_q, block_k, window,
                      _fwd_strip_parts(t, block_q))
+    fwd_fetches = _walk_fetches(t, block_q, block_k, window)
+    bwd_fetches = _walk_fetches(t, block_q, block_k, window, True)
     if _fused_bwd_applies(t, t, d):
         bwd = _tile_plan(t, block_q, block_k, window, _strip_parts(block_q))
         bwd_pairs, bwd_bodies = _plan_pairs(bwd, n, block_q, block_k), \
             bwd.bodies
-    else:
+    else:  # the dq kernel walks as the forward does, the dk/dv kernel q
         bwd_pairs, bwd_bodies = 2 * block_q * block_k * sum(n.values()), 1
+        bwd_fetches += fwd_fetches
     seen = np.minimum(np.arange(t) + 1, window or t)
     return {"visible": int(np.sum(seen)),
             "fwd_pairs": _plan_pairs(fwd, n, block_q, block_k),
             "bwd_pairs": bwd_pairs, "fwd_bodies": fwd.bodies,
-            "bwd_bodies": bwd_bodies, "tiles": n}
+            "bwd_bodies": bwd_bodies, "tiles": n,
+            "fwd_fetches": fwd_fetches, "bwd_fetches": bwd_fetches}
 
 
 def _strip_keep(at, width, edge, transposed):
@@ -392,13 +399,17 @@ def _tile_bodies(tiles, run, qi, ki, block_q, block_k, window, keep_fn,
 # walk k block j's q blocks (`transposed`).  A walk shorter than the
 # widest one (the sequence's start, or its end transposed) repeats its
 # last block, which the pipeline does not fetch again and `live` keeps
-# from computing twice.
+# from computing twice.  The triangle (no window, or one that covers the
+# sequence) keeps the full grid, and its index maps name at a step _band
+# skips the block the head's next live step reads (_band_inner): a block
+# named twice in a row is not copied again, so the triangle copies in the
+# blocks it computes on and no other.
 def _band_span(o, block_q, block_k, window, n_inner, transposed=False,
                traced=True):
-    """(first, last) inner block that _band runs (qo = 0, causal, window)
-    for outer block `o`: k blocks of q block o, or with `transposed` q
-    blocks of k block o.  `o` is a traced int32 scalar, or with
-    traced=False a numpy array or int."""
+    """(first, last) inner block that _band runs (qo = 0, causal; under
+    `window` where it is not 0) for outer block `o`: k blocks of q block o,
+    or with `transposed` q blocks of k block o.  `o` is a traced int32
+    scalar, or with traced=False a numpy array or int."""
     if traced:
         mx, mn = jnp.maximum, jnp.minimum
 
@@ -409,27 +420,59 @@ def _band_span(o, block_q, block_k, window, n_inner, transposed=False,
     if transposed:
         first = div(o * block_k, block_q)
         last = mn(div(o * block_k + (block_k + window - 2), block_q),
-                  n_inner - 1)
+                  n_inner - 1) if window else n_inner - 1
     else:
-        first = div(mx(o * block_q - (window - 1), 0), block_k)
+        first = div(mx(o * block_q - (window - 1), 0),
+                    block_k) if window else 0
         last = mn(div(o * block_q + (block_q - 1), block_k), n_inner - 1)
     return first, last
 
 
-def _band_step(o, step, block_q, block_k, window, n_inner, transposed=False):
+def _band_step(o, step, block_q, block_k, window, n_inner, transposed=False,
+               traced=True):
     """Step `step` of outer block o's walk: (inner block, live)."""
     first, last = _band_span(o, block_q, block_k, window, n_inner,
-                             transposed)
-    return jnp.minimum(first + step, last), first + step <= last
+                             transposed, traced)
+    mn = jnp.minimum if traced else np.minimum
+    return mn(first + step, last), first + step <= last
 
 
-def _band_inner(band, block_q, block_k, window, n_inner, transposed=False):
-    """An index map's inner block of grid step (outer block, step): the
-    step itself on the full grid (band == 0)."""
-    if not band:
+def _band_inner(band, block_q, block_k, window, n_inner, transposed=False,
+                causal=False, traced=True):
+    """An index map's inner block of grid step (outer block, step).  On the
+    band grid (band > 0) the band's step.  On the full grid of a `causal`
+    kernel (no traced offset) a step _band runs names its own block, and a
+    step it skips the block the head's next live step reads: the first
+    live block of this row before it, the first of the next row after the
+    last (forward: block 0 is copied in under the diagonal tile and held to
+    the next row's start; backward: the diagonal's q block under the row
+    before).  The step itself where the call is not causal, carries a
+    traced offset, or one block holds the inner axis."""
+    if band:
+        return lambda o, step: _band_step(o, step, block_q, block_k, window,
+                                          n_inner, transposed, traced)[0]
+    if not causal or n_inner == 1:
         return lambda o, step: step
-    return lambda o, step: _band_step(o, step, block_q, block_k, window,
-                                      n_inner, transposed)[0]
+    t = n_inner * (block_q if transposed else block_k)
+    n_outer = t // (block_k if transposed else block_q)
+    window = window if window < t else 0
+    mx, mn, where = ((jnp.maximum, jnp.minimum, jnp.where) if traced
+                     else (np.maximum, np.minimum, np.where))
+
+    def span(o):
+        return _band_span(o, block_q, block_k, window, n_inner, transposed,
+                          traced)
+
+    def inner(o, step):
+        first, last = span(o)
+        if not window:  # the triangle: a row starts at block 0, a column
+            # (transposed) ends at the last block
+            return mx(step, first) if transposed else where(
+                step <= last, step, 0)
+        return where(step <= last, mx(step, first),
+                     span(mn(o + 1, n_outer - 1))[0])
+
+    return inner
 
 
 def _band_grid(tq, tk, block_q, block_k, causal, window, transposed=False):
@@ -457,6 +500,25 @@ def band_grid_steps(t, block_q, block_k, window):
                              traced=False)
     width = _band_grid(t, t, block_q, block_k, True, window)
     return nq * (width or nk), int(np.sum(last - first + 1))
+
+
+def _walk_fetches(t, block_q, block_k, window, transposed=False):
+    """Blocks a head's walk of a causal kernel's grid copies in on its
+    inner side (k, v and the key rows; `transposed`: q, do and the query
+    rows): the pipeline copies a block in where the index maps name another
+    than at the step before, so the times the name changes, and the head's
+    first.  The maps are the kernel's own (_band_inner), run on the
+    host."""
+    n_outer, n_inner = t // block_q, t // block_k
+    if transposed:
+        n_outer, n_inner = n_inner, n_outer
+    band = _band_grid(t, t, block_q, block_k, True, window, transposed)
+    inner = _band_inner(band, block_q, block_k, window, n_inner, transposed,
+                        causal=True, traced=False)
+    o, step = np.meshgrid(np.arange(n_outer), np.arange(band or n_inner),
+                          indexing="ij")
+    named = np.broadcast_to(inner(o, step), o.shape).reshape(-1)
+    return 1 + int(np.sum(named[1:] != named[:-1]))
 
 
 def _flash_blocks(Tq, Tk, block_q, block_k, causal):
@@ -500,7 +562,8 @@ def _flash_fwd(q, k, v, kbias, causal, scale, block_q, block_k, window=0,
     nq, nk = T // block_q, Tk // block_k
     band = _band_grid(T, Tk, block_q, block_k,
                       causal and qoff is None, int(window))
-    kblock = _band_inner(band, block_q, block_k, int(window), nk)
+    kblock = _band_inner(band, block_q, block_k, int(window), nk,
+                         causal=causal and qoff is None)
     tiles = None
     if by_class and causal and qoff is None:
         tiles = _tile_plan(T, block_q, block_k, int(window),
@@ -696,9 +759,10 @@ def _flash_bwd(q, k, v, kbias, o, lse, do, causal, scale, block_q, block_k,
     band_k = _band_grid(T, Tk, block_q, block_k, static, int(window))
     band_q = _band_grid(T, Tk, block_q, block_k, static, int(window),
                         transposed=True)
-    kblock = _band_inner(band_k, block_q, block_k, int(window), nk)
+    kblock = _band_inner(band_k, block_q, block_k, int(window), nk,
+                         causal=static)
     qblock = _band_inner(band_q, block_q, block_k, int(window), nq,
-                         transposed=True)
+                         transposed=True, causal=static)
 
     q_spec_q = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0),
                             memory_space=pltpu.VMEM)
@@ -947,7 +1011,7 @@ def _flash_bwd_fused(q, k, v, kbias, seg, o, lse, do, causal, scale, block_q,
     band = _band_grid(T, T, block_q, block_k, causal, int(window),
                       transposed=True)
     qblock = _band_inner(band, block_q, block_k, int(window), nq,
-                         transposed=True)
+                         transposed=True, causal=causal)
     tiles = (_tile_plan(T, block_q, block_k, int(window),
                         _strip_parts(block_q)) if causal else None)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)

@@ -40,10 +40,11 @@ def test_band_grid_attribution_and_reset():
     assert kt.attribution()["attention_band_grid"] == {"ops": 0, "steps": {}}
 
 
-def _stats(visible, fwd, bwd, bodies=(5, 5), tiles=None):
+def _stats(visible, fwd, bwd, bodies=(5, 5), tiles=None, fetches=(9, 9)):
     return {"visible": visible, "fwd_pairs": fwd, "bwd_pairs": bwd,
             "fwd_bodies": bodies[0], "bwd_bodies": bodies[1],
-            "tiles": tiles or {"whole": 6, "diag": 4, "edge": 0, "both": 0}}
+            "tiles": tiles or {"whole": 6, "diag": 4, "edge": 0, "both": 0},
+            "fwd_fetches": fetches[0], "bwd_fetches": fetches[1]}
 
 
 def test_tile_class_attribution_and_reset():
@@ -97,7 +98,10 @@ def test_the_lowering_records_the_tile_classes_of_a_causal_flash_op_only():
     assert band == dict(pk.tile_class_stats(8192, 128, 1024, 1024, 2048),
                         ops=4)
     assert band["tiles"] == {"whole": 7, "diag": 8, "edge": 6, "both": 0}
-    assert got["shapes"]["8192x0x1024x1024x128"]["ops"] == 1
+    assert (band["fwd_fetches"], band["bwd_fetches"]) == (20, 20)
+    full = got["shapes"]["8192x0x1024x1024x128"]
+    assert (full["ops"], full["fwd_fetches"], full["bwd_fetches"]) == (
+        1, 35, 35)  # 36 tiles, one of them on a block held from the row before
     kt.reset_attribution()
     jax.eval_shape(lambda q, k, v: op(tpu, q, k, v, causal=False), x, x, x)
     jax.eval_shape(lambda q, k, v: op(LowerCtx(platform="cpu"), q, k, v,
@@ -125,7 +129,8 @@ def test_attention_pairs_computed_over_visible_is_in_the_benchmark_by_name(
         spec = json.load(f)
     name = "attention_pairs_computed_over_visible"
     entry, = [m for m in spec["per_layer"] if m["name"] == name]
-    assert spec["per_layer"][-1] is entry  # appended, nothing moved
+    # appended by PR 53, nothing moved; PR 56 appended one after it
+    assert spec["per_layer"].index(entry) == len(spec["per_layer"]) - 2
     dense = {"tfm_base_train", "tfm_base_train_s64", "resnet50_train"}
     assert set(entry["workloads"]) == {
         c["name"] for c in spec["workloads"]} - dense
@@ -160,3 +165,92 @@ def test_attention_pairs_computed_over_visible_is_in_the_benchmark_by_name(
         524800, tile, 655360))  # a forward whole, a backward in four strips
     assert reader.read(ctx) == pytest.approx((2 + 1.25) / 2 * 1048576
                                              / 1049600, rel=1e-9)
+
+
+# T x window x d -> (tiles a head, forward fetches, backward fetches) of the
+# flash cells' cores in 1024-blocks
+CELL_FETCHES = {
+    (1024, 0, 64): (1, 1, 1),  # GPT-2: one block a sequence
+    (4096, 0, 128): (10, 9, 9),  # Ouro, OLMoE
+    (6144, 0, 192): (21, 20, 20),  # kanana-2, Kimi Linear
+    (8192, 0, 64): (36, 35, 35),  # LFM2
+    (8192, 0, 128): (36, 35, 35),  # Trinity-Mini's full layer
+    (8192, 2048, 128): (21, 20, 20),  # its window layers, on the band grid
+    (8192, 0, 256): (36, 35, 35),  # Qwen3-Next
+}
+
+
+@pytest.mark.parametrize("t,window,d", sorted(CELL_FETCHES))
+def test_the_attribution_carries_the_fetches_at_the_cells_shapes(t, window,
+                                                                 d):
+    """What tile_class_stats says of a cell's core goes into the record
+    whole, the two counts of PR 56 with the rest: the inner blocks a head's
+    forward and backward walks copy in, each no more than the tiles the
+    mask lets run (every block copied in is computed on)."""
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    said = pk.tile_class_stats(t, d, 1024, 1024, window)
+    kt.note_tile_classes(t, window, 1024, 1024, d, said)
+    got, = kt.attribution()["attention_tile_classes"]["shapes"].values()
+    assert got == dict(said, ops=1)
+    assert (sum(got["tiles"].values()), got["fwd_fetches"],
+            got["bwd_fetches"]) == CELL_FETCHES[t, window, d]
+
+
+def test_attention_block_fetches_over_tiles_is_in_the_benchmark_by_name(
+        monkeypatch):
+    """BENCHMARK.json carries `attention_block_fetches_over_tiles` last,
+    for the nine cells of `attention_pairs_computed_over_visible`; its
+    layer_metrics file names a reader that imports and answers None on a
+    program that records no fetches (the parent commit's record holds the
+    tiles without them) or no flash lowering, and otherwise forward +
+    backward fetches over twice the tiles, weighted by lowerings: 0.95 on
+    kanana-2's five latent layers, 1.71 had every grid step named its own
+    block, 0.96 on Trinity-Mini's four window layers and one full one."""
+    import importlib.util
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        spec = json.load(f)
+    name = "attention_block_fetches_over_tiles"
+    entry = spec["per_layer"][-1]  # appended, nothing moved
+    pairs, = [m for m in spec["per_layer"]
+              if m["name"] == "attention_pairs_computed_over_visible"]
+    assert entry == dict(pairs, name=name)
+    with open(os.path.join(root, "benchmark", "layer_metrics",
+                           name + ".json")) as f:
+        how = json.load(f)
+    path = os.path.join(root, "benchmark", "readers", how["reader"] + ".py")
+    mod_spec = importlib.util.spec_from_file_location("block_fetch_stat",
+                                                      path)
+    reader = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(reader)
+    ctx = {"log": lambda msg: None}
+    assert reader.read(ctx, **how.get("args", {})) is None
+    parent = _stats(6144 * 6145 // 2, 0, 0)
+    del parent["fwd_fetches"], parent["bwd_fetches"]
+    kt.note_tile_classes(6144, 0, 1024, 1024, 192, parent)
+    assert reader.read(ctx) is None  # a program from before the counter
+    kt.reset_attribution()
+    tiles = {"whole": 15, "diag": 6, "edge": 0, "both": 0}
+    for _ in range(10):  # five latent layers, forward op and grad op
+        kt.note_tile_classes(6144, 0, 1024, 1024, 192, _stats(
+            1, 1, 1, tiles=tiles, fetches=(20, 20)))
+    assert reader.read(ctx) == pytest.approx(40 / 42.0)
+    kt.reset_attribution()
+    kt.note_tile_classes(6144, 0, 1024, 1024, 192, _stats(
+        1, 1, 1, tiles=tiles, fetches=(36, 36)))
+    assert reader.read(ctx) == pytest.approx(36 / 21.0)
+    kt.reset_attribution()
+    for _ in range(8):
+        kt.note_tile_classes(8192, 2048, 1024, 1024, 128, _stats(
+            1, 1, 1, tiles={"whole": 7, "diag": 8, "edge": 6, "both": 0},
+            fetches=(20, 20)))
+    for _ in range(2):
+        kt.note_tile_classes(8192, 0, 1024, 1024, 128, _stats(
+            1, 1, 1, tiles={"whole": 28, "diag": 8, "edge": 0, "both": 0},
+            fetches=(35, 35)))
+    assert reader.read(ctx) == pytest.approx(
+        (8 * 40 + 2 * 70) / (2.0 * (8 * 21 + 2 * 36)))

@@ -128,7 +128,27 @@ commit ba67ef1 by this file's `_digest`: `non_causal_2048` (a mask-free
 (`flash_attention_piece` under a window at a traced q offset: forward, dq,
 dk/dv) and `piece_diagonal_chunk` (the ring's causal chunk, no offset): a
 call that cannot know a tile's class when traced, and the ring's entry,
-lower to what they lowered to, to the instruction."""
+lower to what they lowered to, to the instruction.
+
+PR 56 made the index maps of a causal kernel on the FULL grid name, at a
+step the mask skips, the block the head's next live step reads
+(`pallas_kernels._band_inner`: a block named twice in a row is not copied in
+again), wherever the kernel knows the steps when it is traced (causal, no
+traced q offset) and its inner axis has more than one step.  So the four
+cores whose causal kernels walk several blocks changed on purpose, in their
+index maps alone, and their digests are re-taken from PR 56's tree by this
+file's `_digest`: `full_causal_8192`, `window_covers_8192` (T = 8192),
+`two_kernel_backward` (T = 16384: forward, dq and dk/dv kernels) and
+`piece_diagonal_chunk` (T = 2048 in blocks of 128: the ring's causal chunk
+carries no offset, so it is such a call; its one masked body is what it
+was).  The other thirteen digests and every Mosaic count did NOT move, and
+must not: `window_2048_of_8192` walks the band's grid, whose maps are PR
+41's; `non_causal_2048` has no mask and `piece_at_an_offset` a traced
+offset; `transformer` and `resnet` hold no flash kernel; and the eight tiny
+programs with a causal flash core (`gpt2`, `olmoe`, `lfm2`, `trinity`,
+`kanana2`, `kimi_linear`, `qwen3_next`, `ouro`) run T = 512 in ONE block,
+where the map stays the grid's own step, as the two GPT-2 cells do at
+T = 1024."""
 
 import base64
 import functools
@@ -282,7 +302,9 @@ PROGRAMS = {"gpt2": lambda: _lm(gpt2.gpt2_lm_program, G),
 # `resnet`, `ouro`: at 6a7549d, PR 44's parent; `kimi_linear`: at PR 46;
 # `gpt2`: at PR 47; `qwen3_next`: added at PR 48; both delta-rule programs:
 # at PR 50; every program and core with a causal flash kernel: at PR 53; the
-# three UNTOUCHED cores: at ba67ef1, PR 53's parent)
+# three UNTOUCHED cores: at ba67ef1, PR 53's parent; the four cores whose
+# causal kernels walk several blocks of the full grid, `piece_diagonal_chunk`
+# among them: at PR 56)
 BEFORE = {
     "qwen3_next": ("066aa16bdf09bc9d5c356dda010c95375aae93b6", 27),
     "kimi_linear": ("0b6cea6313d097ed575295ef51fd8b563347239c", 21),
@@ -294,13 +316,13 @@ BEFORE = {
     "gpt2": ("bfdcbc6f62d3aaf4418dc9bf22e0aaf9dc8a8a4a", 3),
     "olmoe": ("8fc96fbebcde6d156165e9b26443399f6b36494f", 9),
     "lfm2": ("25c451b8998c9bd5e6e4808f9b598d69a27d779a", 9),
-    "two_kernel_backward": ("6f367b397e82d43ecfa48e0704dc18729431a92e", 3),
-    "full_causal_8192": ("f284ffd3954ccf8c5126a296a953a8f757415e38", 2),
-    "window_covers_8192": ("2d62811d7d6a9788008f38ce1ebbccc6f736718c", 2),
+    "two_kernel_backward": ("11298a99a31b05beda8f898f4d78ff6e1a7258d4", 3),
+    "full_causal_8192": ("5b5142af7fe40d858d5b144ad6aa1e2ff0328207", 2),
+    "window_covers_8192": ("06a0539acce2ff8f9fe3049ed5f52419b7a27c44", 2),
     "window_2048_of_8192": ("55fb7bfd281ba57dbb1e66197fc71be9aa664089", 2),
     "non_causal_2048": ("9b7ca3bb75c85e16b56930b0829a721b43a0acac", 2),
     "piece_at_an_offset": ("1d162f089780ed64698a5fda1537085bfc2cb3c6", 3),
-    "piece_diagonal_chunk": ("8d11042a97d13bfc58008ff7a66b546217520674", 3),
+    "piece_diagonal_chunk": ("ad6d3cada983238a935695eaf4e5082e997c1f53", 3),
 }
 # name -> (T, window) of an attention core alone, forward + backward
 CORES = {"two_kernel_backward": (16384, 0), "full_causal_8192": (8192, 0),
@@ -310,7 +332,8 @@ CORES = {"two_kernel_backward": (16384, 0), "full_causal_8192": (8192, 0),
 
 def _untouched(q, k, v, qoff, name):
     """The calls PR 53's tile classes must not reach, at T = 2048, heads of
-    128: a scalar to differentiate."""
+    128: a scalar to differentiate.  (PR 56's index maps reach the third,
+    the ring's chunk without an offset, and neither of the others.)"""
     if name == "non_causal_2048":
         return jnp.sum(pk.flash_attention(
             q, k, v, None, False, 128 ** -0.5, 1024, 1024).astype(

@@ -308,12 +308,17 @@ def _pallas_eqns(jaxpr):
 @pytest.mark.parametrize("backward", ["one_kernel", "two_kernels"])
 def test_without_a_window_the_traced_kernels_are_the_full_grid(monkeypatch,
                                                                backward):
-    """window == 0 (and a window that covers the sequence) trace what they
-    traced before the band grid: the (BH, nq, nk) / (BH, nk, nq) grids,
-    index maps that hand a grid index on without arithmetic, and kernel
-    bodies with no division or minimum; a window inside the sequence
-    traces the band's width and maps that compute.  (The Mosaic modules
-    themselves are pinned in tests/test_lowered_step_pins.py.)"""
+    """window == 0 (and a window that covers the sequence) trace the
+    (BH, nq, nk) / (BH, nk, nq) grids and kernel bodies with no division or
+    minimum, as before the band grid.  Their index maps compute on the
+    INNER block's operands alone (PR 56: k, v and the key rows where k is
+    innermost; q, do, lse and delta where q is: a step the mask skips names
+    the block of the next live one) and hand a grid index on for every
+    other operand and every result; a non-causal call, and a causal one
+    whose inner axis is one step, hand the step on everywhere.  A window
+    inside the sequence traces the band's width and maps that compute.
+    (The Mosaic modules themselves are pinned in
+    tests/test_lowered_step_pins.py.)"""
     from paddle_tpu.ops import pallas_kernels as pk
 
     if backward == "two_kernels":
@@ -322,32 +327,50 @@ def test_without_a_window_the_traced_kernels_are_the_full_grid(monkeypatch,
     bh, t, d, blk = 2, 64, 8, 8
     x = jnp.zeros((bh, t, d), jnp.float32)
 
-    def calls(window):
+    def calls(window, causal=True, blk=blk):
         jaxpr = jax.make_jaxpr(jax.grad(
             lambda q, k, v: jnp.sum(flash_attention(
-                q, k, v, None, True, 1.0, blk, blk, window)),
+                q, k, v, None, causal, 1.0, blk, blk, window)),
             argnums=(0, 1, 2)))(x, x, x)
         return _pallas_eqns(jaxpr.jaxpr)
 
     def arithmetic(eqn):
-        """(index-map equations, has the body a div or a min)"""
-        maps = sum(len(bm.index_map_jaxpr.jaxpr.eqns)
-                   for bm in eqn.params["grid_mapping"].block_mappings)
+        """(operands whose index map computes, results whose map does, has
+        the body a div or a min)"""
+        maps = {bm.origin: len(bm.index_map_jaxpr.jaxpr.eqns)
+                for bm in eqn.params["grid_mapping"].block_mappings}
         body = {e.primitive.name for e in eqn.params["jaxpr"].eqns}
-        return maps, bool(body & {"div", "min"})
+        return (sorted(o for o, n in maps.items() if n and "args" in o),
+                sorted(o for o, n in maps.items() if n and "args" not in o),
+                bool(body & {"div", "min"}))
 
+    # q, k, v, key bias (the forward and the two-kernel backward carry a
+    # zero one), do, lse, delta
+    k_side = ["args[1]", "args[2]", "args[3]"]
+    forward = (k_side, [], False)
+    backward_kernels = {
+        "one_kernel": [(["args[0]", "args[3]", "args[4]", "args[5]"], [],
+                        False)],
+        "two_kernels": [(k_side, [], False),
+                        (["args[0]", "args[4]", "args[5]", "args[6]"], [],
+                         False)]}[backward]
     n = t // blk
     for window in (0, t, t + 5):
         got = calls(window)
         assert len(got) == (2 if backward == "one_kernel" else 3)
         for eqn in got:
             assert eqn.params["grid_mapping"].grid == (bh, n, n)
-            assert arithmetic(eqn) == (0, False)
+        assert [arithmetic(eqn) for eqn in got] == [forward] \
+            + backward_kernels
+    for got in (calls(0, causal=False), calls(0, blk=t)):
+        assert len(got) == (2 if backward == "one_kernel" else 3)
+        for eqn in got:
+            assert arithmetic(eqn) == ([], [], False)
     got = calls(2 * blk)  # 3 blocks a walk: its own, two of the window
     for eqn in got:
         assert eqn.params["grid_mapping"].grid == (bh, n, 3)
-        maps, body = arithmetic(eqn)
-        assert maps > 0 and body
+        operands, results, body = arithmetic(eqn)
+        assert operands and not results and body
     jax.clear_caches()
 
 

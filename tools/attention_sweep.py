@@ -34,6 +34,25 @@ forward + backward (what a model's first step pays once a kernel).
 tool alike.
 
     python3 tools/attention_sweep.py --tile-classes both [--out chiprun_out/tile_sweep.json]
+
+With --dead-fetch it times the same kernels at the same shapes with the
+full grid's index maps naming, at a step the causal mask skips, the block
+the head's next live step reads (PR 56: `next`, what the program does)
+against the plain step (`step`: every skipped step copies its block in;
+pallas_kernels._band_inner held at its old form for that row, here in the
+tool: the program has no such switch) and against the clamp to the row's
+live range (`clamp`: as few copies, one a row exposed), or `both` (all
+three): ms forward and backward, the grid steps a head walks, the tiles it
+computes and the blocks it copies in each pass, and against `step` the ms
+each other map saves and the us that is a skipped head-step.  The window
+shape walks its band's grid (PR 41), where the maps play no part, so it is
+timed on that grid once and on the FULL grid under each map (_band_grid
+held at 0, as --window does): against the band grid, whose skipped steps
+are gone, that is the us a skipped head-step costs under `step` and what it
+STILL costs under `next` (the price of an empty grid step).  --rehearse
+walks the same code on the CPU and prints the counts, no time.
+
+    python3 tools/attention_sweep.py --dead-fetch both [--out chiprun_out/dead_fetch_sweep.json]
 """
 
 import argparse
@@ -82,12 +101,18 @@ def main():
                     default=None,
                     help="time the kernels with a tile computed by where it "
                     "lies (on), every tile masked whole (off), or both")
+    ap.add_argument("--dead-fetch", choices=("next", "step", "clamp", "both"),
+                    default=None,
+                    help="time the kernels with a skipped step's index maps "
+                    "naming the next live block (next), the step (step), "
+                    "the row's clamp (clamp), or all three (both)")
     ap.add_argument("--parts", default="",
                     help="with --tile-classes: further strip counts to time,"
                     " forward x backward, as 1x4,4x4")
     ap.add_argument("--repeats", type=int, default=3)
     ap.add_argument("--shapes", default="",
-                    help="with --tile-classes: only the shapes whose name "
+                    help="with --tile-classes or --dead-fetch: only the "
+                    "shapes whose name "
                     "holds one of these comma-separated words")
     ap.add_argument("--rehearse", action="store_true",
                     help="the window or tile sweep's plumbing on the CPU, "
@@ -103,7 +128,8 @@ def main():
 
     dev = jax.devices()[0]
     if dev.platform != "tpu" and not (
-            args.rehearse and (args.window or args.tile_classes)):
+            args.rehearse and (args.window or args.tile_classes
+                               or args.dead_fetch)):
         raise SystemExit("attention_sweep: needs a TPU, jax found %s" % dev)
 
     def timed(fn, operands, backward=True):
@@ -259,25 +285,135 @@ def main():
             plan, fwd_parts, bwd_parts)
         return rows
 
-    if args.tile_classes:
-        rows = tile_sweep()
-        if args.out == ap.get_default("out"):
-            args.out = "chiprun_out/tile_sweep.json"
-        os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
-        with open(args.out, "w") as f:
-            json.dump({"device": dev.device_kind, "iters": args.iters,
-                       "rows": rows}, f, indent=1)
-        return
+    def dead_fetch_sweep():
+        """One JSON line a (shape, map), and with `both` one a shape: the
+        ms each map saves against `step` and the us of a skipped
+        head-step."""
+        from paddle_tpu.ops import nn_ops
 
-    if args.window:
-        rows = window_sweep()
-        if args.out == ap.get_default("out"):
-            args.out = "chiprun_out/window_sweep.json"
-        os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
-        with open(args.out, "w") as f:
+        band_inner = pk._band_inner
+
+        def held(kind):
+            """_band_inner with the full grid's maps held at `kind`."""
+            def inner(band, block_q, block_k, window, n_inner,
+                      transposed=False, causal=False, traced=True):
+                if band or not causal or n_inner == 1 or kind == "next":
+                    return band_inner(band, block_q, block_k, window,
+                                      n_inner, transposed, causal, traced)
+                if kind == "step":
+                    return lambda o, step: step
+                mx, mn = ((jnp.maximum, jnp.minimum) if traced
+                          else (np.maximum, np.minimum))
+
+                def clamp(o, step):
+                    first, last = pk._band_span(o, block_q, block_k, window,
+                                                n_inner, transposed, traced)
+                    return mn(mx(step, first), last)
+
+                return clamp
+
+            return inner
+
+        kinds = [k for k in ("step", "clamp", "next")
+                 if args.dead_fetch in (k, "both")]
+        band_grid = pk._band_grid
+        words = [w for w in args.shapes.split(",") if w]
+        rows = []
+        for name, bh, t, d, dv, w in TILE_SHAPES:
+            if words and not any(word in name for word in words):
+                continue
+            blk = nn_ops._flash_block(t)
+            if args.rehearse:  # four blocks of 128 a side (one of 512 where
+                # one block holds the cell's sequence), interpreted
+                bh, t, w, blk = 1, 512, w and 256, 512 if blk == t else 128
+            keys = jax.random.split(jax.random.PRNGKey(0), 3)
+            q, k, v = (jax.random.normal(kk, (bh, t, n), jnp.float32).astype(
+                jnp.bfloat16) for kk, n in zip(keys, (d, d, dv)))
+            scale = d ** -0.5
+
+            def fn(q, k, v, kb):
+                return pk.flash_attention(q, k, v, kb, True, scale, blk, blk,
+                                          w)
+
+            grad = jax.grad(lambda q, k, v: jnp.sum(
+                fn(q, k, v, None).astype(jnp.float32)), argnums=(0, 1, 2))
+            n = t // blk
+            # a window's band is off the grid (PR 41: the maps play no part
+            # there), so it is also timed on the full grid under each map:
+            # what a skipped step costs against one the grid does not have
+            variants = [(kind, False) for kind in kinds]
+            if w:
+                variants = [("band", False)] + [(kind, True)
+                                                for kind in kinds]
+            said = {}
+            for kind, full in variants:
+                pk._band_inner = held("next" if kind == "band" else kind)
+                pk._band_grid = (lambda *a, **kw: 0) if full else band_grid
+                jax.clear_caches()  # the kernels' entries are jitted
+                stats = pk.tile_class_stats(t, d, blk, blk, w)
+                row = {"shape": name, "bh": bh, "t": t, "d": d, "dv": dv,
+                       "window": w, "block": blk, "maps": kind,
+                       "steps": n * (pk._band_grid(t, t, blk, blk, True, w)
+                                     or n),
+                       "tiles": sum(stats["tiles"].values()),
+                       "fwd_fetches": stats["fwd_fetches"],
+                       "bwd_fetches": stats["bwd_fetches"]}
+                if not args.rehearse:
+                    fwd = min(timed(fn, (q, k, v, None), backward=False)
+                              for _ in range(args.repeats))
+                    both = min(timed(fn, (q, k, v, None))
+                               for _ in range(args.repeats))
+                    row.update(fwd_ms=round(fwd, 4),
+                               bwd_ms=round(both - fwd, 4),
+                               fwd_bwd_ms=round(both, 4))
+                said[kind] = (row, fn(q, k, v, None), grad(q, k, v))
+                rows.append(row)
+                print(json.dumps(row), flush=True)
+            base = "band" if w else "step"
+            if base in said and len(said) > 1:
+                off, o0, g0 = said.pop(base)
+                for kind, (on, o1, g1) in said.items():
+                    # the steps the band grid does not have, or on one grid
+                    # the steps the mask skips
+                    dead = abs(on["steps"] - off["steps"]) or (
+                        off["steps"] - off["tiles"])
+                    cmp = {"shape": name, "maps": kind + "/" + base,
+                           "dead_steps_a_head": dead,
+                           "bit_equal_o_dq_dk_dv": [
+                               bool(jnp.all(a == b))
+                               for a, b in zip((o1,) + g1, (o0,) + g0)]}
+                    for p in ("fwd", "bwd") if "fwd_ms" in on and dead else ():
+                        # against `step`: what the map took off a skipped
+                        # step; against the band grid: what one still costs
+                        less = off[p + "_ms"] - on[p + "_ms"]
+                        cmp[p + "_ms_less"] = round(less, 4)
+                        cmp[p + "_dead_step_us_a_head"] = round(
+                            abs(less) / dead / bh * 1e3, 4)
+                    rows.append(cmp)
+                    print(json.dumps(cmp), flush=True)
+        pk._band_grid = band_grid
+        pk._band_inner = band_inner
+        jax.clear_caches()
+        return rows
+
+    def save(rows, name):
+        """Writes the sweep's rows to --out, or to its own file
+        chiprun_out/<name>.json where --out was not given (a rehearsal's
+        to <name>.rehearsal.json: it must not replace a chip's)."""
+        out = (args.out if args.out != ap.get_default("out")
+               else "chiprun_out/%s.json" % (
+                   name + ".rehearsal" * args.rehearse))
+        os.makedirs(os.path.dirname(out) or ".", exist_ok=True)
+        with open(out, "w") as f:
             json.dump({"device": dev.device_kind, "iters": args.iters,
                        "rows": rows}, f, indent=1)
-        return
+
+    if args.dead_fetch:
+        return save(dead_fetch_sweep(), "dead_fetch_sweep")
+    if args.tile_classes:
+        return save(tile_sweep(), "tile_sweep")
+    if args.window:
+        return save(window_sweep(), "window_sweep")
 
     rows = []
     for name, bh, t, d, causal, bias in SHAPES:
@@ -308,10 +444,7 @@ def main():
         row["best"] = min(ok, key=ok.get) if ok else None
         print(json.dumps(row), flush=True)
         rows.append(row)
-    os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
-    with open(args.out, "w") as f:
-        json.dump({"device": dev.device_kind, "iters": args.iters,
-                   "rows": rows}, f, indent=1)
+    save(rows, "attention_sweep")
 
 
 if __name__ == "__main__":
