@@ -34,7 +34,11 @@ _BF16_OPS = ("mul", "matmul", "conv2d", "depthwise_conv2d",
              # derives from them stay f32 (below and in ops/kda_ops.py)
              "kda_attention",
              # Gated DeltaNet's core, alike: one decay a head
-             "gated_delta_attention")
+             "gated_delta_attention",
+             # the Mamba-2 selective scan: x, B and C are the operands of
+             # its products; the step, the decay rate, the skip and what
+             # the lowering derives from them stay f32 (ops/mamba2_ops.py)
+             "mamba2_scan")
 
 # input slots that must stay float32 even when the op is rewritten
 # (additive -1e9 padding masks lose nothing in bf16, but keeping them f32
@@ -48,7 +52,9 @@ _KEEP_F32_SLOTS = {"fused_attention": ("Bias",),
                    "moe_ffn": ("X", "RouterW", "ExpertBias"),
                    # the log-decay is summed over a chunk and exponentiated
                    "kda_attention": ("G", "Beta"),
-                   "gated_delta_attention": ("G", "Beta")}
+                   "gated_delta_attention": ("G", "Beta"),
+                   # dt A is summed over a chunk and exponentiated
+                   "mamba2_scan": ("Dt", "A", "D")}
 
 # output slots that are not activations (counts, f32 statistics): they
 # keep their declared dtype and get no cast-back

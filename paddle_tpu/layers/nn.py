@@ -39,6 +39,7 @@ __all__ = [
     "causal_conv",
     "kda_attention",
     "gated_delta_attention",
+    "mamba2_scan",
     "moe_ffn",
     "group_norm",
     "instance_norm",
@@ -534,16 +535,20 @@ def layer_norm(
 
 
 def rms_norm(input, epsilon=1e-5, param_attr=None, name=None,
-             unit_offset=False):
+             unit_offset=False, gain_axes=1):
     """x * rsqrt(mean(x^2) + epsilon) * w over the last axis, w a [d]
     parameter initialised to 1 (no mean subtraction, no bias).
     `unit_offset`: the gain is 1 + w and w is initialised to 0 (the
     published Qwen3NextRMSNorm, Gemma's): a `scale` op adds the 1 to the
-    parameter, the `rms_norm` op is the same."""
+    parameter, the `rms_norm` op is the same.  `gain_axes` 2: the gain
+    holds a number for every element of the last TWO axes ([groups, d]:
+    Mamba-2's norm over groups of d_inner / n_groups channels, each group
+    with gains of its own); the statistic stays the last axis's."""
     helper = LayerHelper("rms_norm", **locals())
     dtype = helper.input_dtype()
     w = helper.create_parameter(
-        attr=helper.param_attr, shape=[int(input.shape[-1])], dtype=dtype,
+        attr=helper.param_attr,
+        shape=[int(n) for n in input.shape[-int(gain_axes):]], dtype=dtype,
         default_initializer=Constant(0.0 if unit_offset else 1.0))
     if unit_offset:
         w = scale(w, scale=1.0, bias=1.0)
@@ -573,11 +578,14 @@ def short_conv(input, kernel_size=3, param_attr=None, name=None):
     return out
 
 
-def causal_conv(input, kernel_size=4, act=None, param_attr=None, name=None):
+def causal_conv(input, kernel_size=4, act=None, param_attr=None, name=None,
+                bias_attr=None):
     """Depthwise causal convolution over the T axis of `input` [..., T, d]
     (the `causal_conv` op): one [kernel_size] filter a channel, zeros left
-    of t = 0, no bias, no gate; `act` "silu" applies SiLU to the result
-    inside the op.  Kimi Linear's q, k and v pass through one each."""
+    of t = 0, no gate; `act` "silu" applies SiLU to the result inside the
+    op.  Kimi Linear's q, k and v pass through one each.  `bias_attr`: a
+    [d] bias (zero at initialisation) added before the activation (Mamba-2's
+    `use_conv_bias`); None builds the op without one."""
     if act not in (None, "silu"):
         raise ValueError("causal_conv act %r is neither silu nor None"
                          % (act,))
@@ -586,9 +594,14 @@ def causal_conv(input, kernel_size=4, act=None, param_attr=None, name=None):
     filt = helper.create_parameter(
         attr=helper.param_attr, shape=[int(input.shape[-1]), int(kernel_size)],
         dtype=dtype)
+    inputs = {"X": [input], "Filter": [filt]}
+    if bias_attr is not None:
+        inputs["Bias"] = [helper.create_parameter(
+            attr=bias_attr, shape=[int(input.shape[-1])], dtype=dtype,
+            is_bias=True)]
     out = helper.create_variable_for_type_inference(dtype)
-    helper.append_op("causal_conv", inputs={"X": [input], "Filter": [filt]},
-                     outputs={"Out": [out]}, attrs={"act": act or ""})
+    helper.append_op("causal_conv", inputs=inputs, outputs={"Out": [out]},
+                     attrs={"act": act or ""})
     return out
 
 
@@ -638,15 +651,42 @@ def gated_delta_attention(q, k, v, g, beta, scale=None, name=None):
     return out
 
 
+def mamba2_scan(x, dt, a, b, c, d, name=None):
+    """The Mamba-2 selective scan (the `mamba2_scan` op, ops/mamba2_ops.py):
+    `x` [batch, heads, T, P]; `dt` [batch, heads, T] float32, the step of
+    every token (after its softplus); `a` [heads] float32, one negative
+    decay rate a head; `b`, `c` [batch, groups, T, N], the groups dividing
+    the heads (head j reads group j // (heads / groups)); `d` [heads]
+    float32, the skip.  Per head S_t = exp(dt_t a) S_{t-1} + dt_t x_t b_t^T
+    and y_t = S_t c_t + d x_t from a zero state; the result is [batch,
+    heads, T, P] in x's dtype.  No initial state and no state handed back:
+    the causal training path."""
+    helper = LayerHelper("mamba2_scan", **locals())
+    out = helper.create_variable_for_type_inference(x.dtype)
+    # Out is X's shape and dtype, said here: appended straight to the block
+    # like kda_attention, the chunked lowering is never evaluated to build
+    # a program
+    helper.main_program.current_block().append_op(
+        "mamba2_scan",
+        inputs={"X": [x], "Dt": [dt], "A": [a], "B": [b], "C": [c],
+                "D": [d]},
+        outputs={"Out": [out]}, attrs={})
+    out.shape = tuple(x.shape)
+    return out
+
+
 def moe_ffn(input, num_experts, expert_size, top_k, norm_topk_prob=False,
             router_attr=None, gate_up_attr=None, down_attr=None,
             stat_name="moe_tokens_per_expert", name=None, router="softmax",
             expert_bias_attr=None, num_local_experts=None, expert_offset=0,
-            routed_scaling_factor=1.0, norm_topk_eps=1e-6):
+            routed_scaling_factor=1.0, norm_topk_eps=1e-6,
+            expert_act="swiglu"):
     """Token-choice mixture of SwiGLU experts over the last axis of
     `input` (the `moe_ffn` op: top-k, dropless).  The experts' weights are
     stacked: gate and up side by side in one [E, d, 2 * expert_size]
-    parameter, down in [E, expert_size, d].
+    parameter, down in [E, expert_size, d].  `expert_act` "relu2" makes
+    every expert the ungated down(relu(up x)^2) (Nemotron-H's): the first
+    parameter is then the up weight alone, [E, d, expert_size].
 
     `router` is "softmax" (OLMoE's) or "sigmoid": scores sigmoid(logits),
     weights renormalised over the chosen with `norm_topk_eps` added to
@@ -668,6 +708,9 @@ def moe_ffn(input, num_experts, expert_size, top_k, norm_topk_prob=False,
     named `stat_name`_<n>: a program that shares a scope with the training
     program (an evaluation pass) gives its own so as not to overwrite the
     training step's."""
+    if expert_act not in ("swiglu", "relu2"):
+        raise ValueError("moe_ffn expert_act %r is neither swiglu nor relu2"
+                         % (expert_act,))
     helper = LayerHelper("moe_ffn", **locals())
     dtype = helper.input_dtype()
     d = int(input.shape[-1])
@@ -684,7 +727,9 @@ def moe_ffn(input, num_experts, expert_size, top_k, norm_topk_prob=False,
         inputs["ExpertBias"] = [helper.create_parameter(
             attr=expert_bias_attr, shape=[num_experts], dtype="float32")]
     inputs["GateUpW"] = [helper.create_parameter(
-        attr=gate_up_attr, shape=[held, d, 2 * expert_size], dtype=dtype)]
+        attr=gate_up_attr,
+        shape=[held, d, expert_size * (1 if expert_act == "relu2" else 2)],
+        dtype=dtype)]
     inputs["DownW"] = [helper.create_parameter(
         attr=down_attr, shape=[held, expert_size, d], dtype=dtype)]
     counts = helper.create_global_variable(
@@ -699,13 +744,15 @@ def moe_ffn(input, num_experts, expert_size, top_k, norm_topk_prob=False,
     # an unknown batch that runs at a million sequences, whose rows times
     # top_k no int32 index reaches, and every layer's build would trace a
     # share's chunk loops at that size.
+    attrs = {"top_k": int(top_k), "norm_topk_prob": bool(norm_topk_prob),
+             "router": router, "expert_offset": int(expert_offset),
+             "routed_scaling_factor": float(routed_scaling_factor),
+             "norm_topk_eps": float(norm_topk_eps)}
+    if expert_act != "swiglu":  # a SwiGLU op carries what it carried
+        attrs["expert_act"] = expert_act
     helper.main_program.current_block().append_op(
         "moe_ffn", inputs,
-        {"Y": [out], "TokensPerExpert": [counts], "AuxLoss": [aux]},
-        {"top_k": int(top_k), "norm_topk_prob": bool(norm_topk_prob),
-         "router": router, "expert_offset": int(expert_offset),
-         "routed_scaling_factor": float(routed_scaling_factor),
-         "norm_topk_eps": float(norm_topk_eps)})
+        {"Y": [out], "TokensPerExpert": [counts], "AuxLoss": [aux]}, attrs)
     out.shape, aux.shape = tuple(input.shape), (2,)
     return out, aux, counts
 

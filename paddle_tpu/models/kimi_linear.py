@@ -127,15 +127,20 @@ class _LogUniform(Initializer):
 
 class _InverseSoftplusOfLogUniform(Initializer):
     """softplus^-1(dt) = log(exp(dt) - 1) of dt = exp(uniform(log low,
-    log high)): dt_bias."""
+    log high)), or of max(dt, floor) where a floor is given (Mamba-2's
+    `time_step_floor`): dt_bias."""
 
-    def __init__(self, low, high):
+    def __init__(self, low, high, floor=None):
         self.draw = Uniform(math.log(low), math.log(high))
+        self.floor = floor
 
     def __call__(self, var, block):
         self.draw(var, block)
         same = {"inputs": {"X": [var]}, "outputs": {"Out": [var]}}
         block.append_op("exp", **same)
+        if self.floor is not None:
+            block.append_op("clip", attrs={"min": float(self.floor),
+                                           "max": 3.4e38}, **same)
         block.append_op("exp", **same)
         block.append_op("scale", attrs={"scale": 1.0, "bias": -1.0}, **same)
         return block.append_op("log", **same)

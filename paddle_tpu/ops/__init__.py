@@ -12,6 +12,7 @@ from . import tensor_ops  # noqa: F401
 from . import nn_ops  # noqa: F401
 from . import moe_ops  # noqa: F401
 from . import kda_ops  # noqa: F401
+from . import mamba2_ops  # noqa: F401
 from . import optimizer_ops  # noqa: F401
 from . import loss_ops  # noqa: F401
 from . import image_ops  # noqa: F401

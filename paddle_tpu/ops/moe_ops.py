@@ -1,6 +1,7 @@
 """Routed experts as a Program op: `moe_ffn`.
 
-A token-choice mixture of SwiGLU experts (softmax or sigmoid router,
+A token-choice mixture of experts (SwiGLU, or the ungated
+down(relu(up x)^2) under `expert_act` "relu2"; softmax or sigmoid router,
 top-k, no capacity: dropless under any imbalance) lowered with static
 shapes: the N*k (token, expert) assignments are sorted by expert, the
 tokens gathered into one [N*k, d] array, and the experts run as two
@@ -22,7 +23,9 @@ whose body is traced once, and never touch the rest.  C comes from the
 shape alone: the largest multiple of the kernels' 256-row tile up to
 `_CHUNK_ROWS` that divides N*k, the whole buffer where there is none.  An
 op that holds every expert has nothing to skip and keeps the whole-size
-lowering.  What the experts held elsewhere would add is left out, forward
+lowering.  An expert width the kernels' 128-lane tile does not divide is
+padded with zero columns inside the op where the kernels would otherwise
+engage (`_kernel_widths`).  What the experts held elsewhere would add is left out, forward
 and backward.  Nothing stands in for the other chips or their exchange.
 
 The lowering opens `route`, `dispatch`, `experts` and `combine` under the
@@ -130,6 +133,30 @@ def _product_grads(kernel, lhs, rhs, group_sizes, g):
     return jax.lax.optimization_barrier(grads)
 
 
+def _kernel_widths(ctx, rows, w_gu, w_down, halves):
+    """The experts' weights as the kernels take them: where the Pallas
+    grouped matmul would engage but for an expert width that is no multiple
+    of its 128-lane tile (Nemotron-H's 1856 = 14.5 x 128), the width padded
+    with zero columns of the first weight (of each of its `halves`: gate
+    and up) and zero rows of the second, which add nothing to any result
+    or gradient (the body of a zero column is zero); `ragged_dot`, the
+    other lowering, computes every group over the whole row buffer on a
+    TPU (PERF.md section 6, PR 57).  A width the tile divides, and every
+    placement where the kernel does not engage anyway, passes through."""
+    f = w_down.shape[1]
+    pad = -f % 128
+    lhs = jax.ShapeDtypeStruct((rows, w_gu.shape[1]), w_gu.dtype)
+    if not pad or not _megablox_fits(
+            ctx, lhs, jax.ShapeDtypeStruct(
+                (w_gu.shape[0], w_gu.shape[1], 128), w_gu.dtype)):
+        return w_gu, w_down
+    e, d = w_gu.shape[:2]
+    w_gu = jnp.pad(w_gu.reshape(e, d, halves, f),
+                   ((0, 0), (0, 0), (0, 0), (0, pad))).reshape(
+                       e, d, halves * (f + pad))
+    return w_gu, jnp.pad(w_down, ((0, 0), (0, pad), (0, 0)))
+
+
 def _takes_kernel(ctx, lhs, rhs):
     """_megablox_fits, counted at trace time by what it answered."""
     fits = _megablox_fits(ctx, lhs, rhs)
@@ -200,12 +227,12 @@ _from_expert_order.defvjp(_feo_fwd, _feo_bwd)
 
 
 def _whole(ctx, x2, router_w, bias, w_gu, w_down, *, k, sigmoid, offset,
-           norm, eps, scaling):
+           norm, eps, scaling, act="swiglu"):
     """(Y [N, d], tokens per expert [E], aux [2]) of x2 [N, d] where the op
     holds every expert: all N*k rows are live."""
     (top_p, aux), (top_e, counts) = _routed(x2, router_w, bias, k, sigmoid,
                                             norm, eps, scaling)
-    cdt, f = w_gu.dtype, w_down.shape[1]
+    cdt = w_gu.dtype
     with jax.named_scope("dispatch"):
         order, inv, group_sizes = _expert_order(top_e, counts, offset,
                                                 w_gu.shape[0])
@@ -214,9 +241,7 @@ def _whole(ctx, x2, router_w, bias, w_gu, w_down, *, k, sigmoid, offset,
         row_p = _to_expert_order(top_p.reshape(-1, 1), order, inv, 1)
     with jax.named_scope("experts"):
         gu = grouped_matmul(ctx, rows, w_gu, group_sizes)
-        act = (jax.nn.silu(gu[:, :f].astype(jnp.float32))
-               * gu[:, f:].astype(jnp.float32)).astype(cdt)
-        out = grouped_matmul(ctx, act, w_down, group_sizes)
+        out = grouped_matmul(ctx, _EXPERT_ACTS[act](gu), w_down, group_sizes)
     with jax.named_scope("combine"):
         out = (out.astype(jnp.float32) * row_p).astype(cdt)
         return _from_expert_order(out, tok, inv, k), counts, aux
@@ -308,22 +333,37 @@ def _swiglu(gu):
             * gu[:, f:].astype(jnp.float32)).astype(gu.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("chunk",))
-def _swiglu_live(gu, n_live, *, chunk):
-    """silu(gate) * up in f32 over the live rows of gu [N*k, 2f]."""
-    def body(start, live, act):
-        return _put(act, jnp.where(live, _swiglu(_rows(gu, start, chunk)), 0),
+def _relu2(up):
+    """relu(up)^2 in f32: the ungated expert's body (Nemotron-H's
+    `mlp_hidden_act` relu2), up [rows, f] -> [rows, f]."""
+    return jnp.square(jax.nn.relu(up.astype(jnp.float32))).astype(up.dtype)
+
+
+# what stands between an expert's two matmuls, by the op's `expert_act`:
+# the first weight is [E_held, d, 2f] (gate | up) under swiglu, [E_held, d,
+# f] under relu2
+_EXPERT_ACTS = {"swiglu": _swiglu, "relu2": _relu2}
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "act"))
+def _swiglu_live(gu, n_live, *, chunk, act="swiglu"):
+    """The experts' body (silu(gate) * up of gu [N*k, 2f], or `act`'s) in
+    f32 over the live rows."""
+    body_of = _EXPERT_ACTS[act]
+
+    def body(start, live, out):
+        return _put(out, jnp.where(live, body_of(_rows(gu, start, chunk)), 0),
                     start)
 
+    width = gu.shape[1] // 2 if act == "swiglu" else gu.shape[1]
     return _live_chunks(
-        n_live, chunk, jnp.zeros((gu.shape[0], gu.shape[1] // 2), gu.dtype),
-        body)
+        n_live, chunk, jnp.zeros((gu.shape[0], width), gu.dtype), body)
 
 
-@functools.partial(jax.jit, static_argnames=("chunk",))
-def _swiglu_live_bwd(gu, g, n_live, *, chunk):
+@functools.partial(jax.jit, static_argnames=("chunk", "act"))
+def _swiglu_live_bwd(gu, g, n_live, *, chunk, act="swiglu"):
     def body(start, live, d_gu):
-        _, vjp = jax.vjp(_swiglu, _rows(gu, start, chunk))
+        _, vjp = jax.vjp(_EXPERT_ACTS[act], _rows(gu, start, chunk))
         (d,) = vjp(_rows(g, start, chunk))
         return _put(d_gu, jnp.where(live, d, 0), start)
 
@@ -403,12 +443,12 @@ def _expert_order(top_e, counts, offset, held):
 # what is made again to the incoming gradient, or the compiler would merge
 # it with the forward's and keep that.
 _SHARE_STATICS = ("k", "sigmoid", "offset", "norm", "eps", "scaling",
-                  "chunk", "kernels")
+                  "chunk", "kernels", "act")
 
 
 @functools.partial(jax.jit, static_argnames=_SHARE_STATICS)
 def _share_fwd(x2, router_w, bias, w_gu, w_down, *, k, sigmoid, offset, norm,
-               eps, scaling, chunk, kernels):
+               eps, scaling, chunk, kernels, act="swiglu"):
     ((top_p, aux), route_vjp, (top_e, counts)) = jax.vjp(
         lambda x2, router_w: _routed(x2, router_w, bias, k, sigmoid, norm,
                                      eps, scaling),
@@ -421,16 +461,17 @@ def _share_fwd(x2, router_w, bias, w_gu, w_down, *, k, sigmoid, offset, norm,
         rows = _gather_live(x, order // k, n_live, chunk=chunk)
     with jax.named_scope("experts"):
         gu = _product(kernels[0], rows, w_gu, group_sizes)
-        act = _swiglu_live(gu, n_live, chunk=chunk)
-        out = _product(kernels[1], act, w_down, group_sizes)
+        out = _product(kernels[1],
+                       _swiglu_live(gu, n_live, chunk=chunk, act=act),
+                       w_down, group_sizes)
     with jax.named_scope("combine"):
         y = _weigh_to_tokens(out, top_p, inv, n_live, k=k)
     return (y, counts, aux), (route_vjp, x, w_gu, w_down, top_p, order, inv,
                               group_sizes, n_live, gu, out)
 
 
-@functools.partial(jax.jit, static_argnames=("k", "chunk", "kernels"))
-def _share_bwd(res, g_y, g_aux, *, k, chunk, kernels):
+@functools.partial(jax.jit, static_argnames=("k", "chunk", "kernels", "act"))
+def _share_bwd(res, g_y, g_aux, *, k, chunk, kernels, act="swiglu"):
     (route_vjp, x, w_gu, w_down, top_p, order, inv, group_sizes, n_live, gu,
      out) = res
     with jax.named_scope("combine"):
@@ -438,10 +479,10 @@ def _share_bwd(res, g_y, g_aux, *, k, chunk, kernels):
                                           n_live, k=k, chunk=chunk)
     with jax.named_scope("experts"):
         gu, d_out = jax.lax.optimization_barrier((gu, d_out))
-        act = _swiglu_live(gu, n_live, chunk=chunk)
-        d_act, d_w_down = _product_grads(kernels[1], act, w_down,
-                                         group_sizes, d_out)
-        d_gu = _swiglu_live_bwd(gu, d_act, n_live, chunk=chunk)
+        d_act, d_w_down = _product_grads(
+            kernels[1], _swiglu_live(gu, n_live, chunk=chunk, act=act),
+            w_down, group_sizes, d_out)
+        d_gu = _swiglu_live_bwd(gu, d_act, n_live, chunk=chunk, act=act)
     with jax.named_scope("dispatch"):
         x, d_gu = jax.lax.optimization_barrier((x, d_gu))
         rows = _gather_live(x, order // k, n_live, chunk=chunk)
@@ -469,7 +510,7 @@ def _share_layer_bwd(said, res, g):
     said = dict(said)
     d_x2, d_router_w, d_w_gu, d_w_down = _share_bwd(
         res, g[0], g[2], k=said["k"], chunk=said["chunk"],
-        kernels=said["kernels"])
+        kernels=said["kernels"], act=said["act"])
     return d_x2, d_router_w, None, d_w_gu, d_w_down
 
 
@@ -544,7 +585,9 @@ def _moe_ffn(ctx, ins, attrs):
     (gate in [..., :f], up in [..., f:]: one grouped matmul reads the
     gathered rows once), DownW [E_held, f, d], optionally ExpertBias [E]
     (sigmoid router: added to the scores for the selection alone).
-    Attributes: top_k, norm_topk_prob, router "softmax" (default) or
+    Attributes: expert_act "swiglu" (default) or "relu2": the ungated
+    expert down_e(relu(up_e x)^2), GateUpW then the up weight alone,
+    [E_held, d, f]; top_k, norm_topk_prob, router "softmax" (default) or
     "sigmoid", norm_topk_eps (1e-6: what the sigmoid router adds to the
     chosen scores' sum before it divides), routed_scaling_factor (1: the
     chosen experts' weights are multiplied by it after the
@@ -571,10 +614,16 @@ def _moe_ffn(ctx, ins, attrs):
         norm=bool(attrs.get("norm_topk_prob", False)),
         eps=float(attrs.get("norm_topk_eps", 1e-6)),
         scaling=float(attrs.get("routed_scaling_factor", 1.0)))
+    act = attrs.get("expert_act") or "swiglu"
+    if act not in _EXPERT_ACTS:
+        raise ValueError("moe_ffn expert_act %r is neither swiglu nor relu2"
+                         % (act,))
     x2 = x.reshape(-1, x.shape[-1])
+    w_gu, w_down = _kernel_widths(ctx, x2.shape[0] * said["k"], w_gu, w_down,
+                                  2 if act == "swiglu" else 1)
     if held == n_experts:
         y, counts, aux = _whole(ctx, x2, router_w, bias, w_gu, w_down,
-                                **said)
+                                act=act, **said)
     else:
         m, f = x2.shape[0] * said["k"], w_down.shape[1]
         chunk = _chunk_rows(m)
@@ -584,7 +633,8 @@ def _moe_ffn(ctx, ins, attrs):
                    _takes_kernel(ctx, rows((m, f)), w_down))
         y, counts, aux = _share_layer(
             x2, router_w, bias, w_gu, w_down,
-            tuple(dict(said, chunk=chunk, kernels=kernels).items()))
+            tuple(dict(said, chunk=chunk, kernels=kernels,
+                       act=act).items()))
     return {"Y": [y.reshape(x.shape)], "TokensPerExpert": [counts],
             "AuxLoss": [aux]}
 
@@ -642,12 +692,14 @@ def _moe_ffn_infer(op, ins):
     if all(known):
         n_experts, f = wr.shape[-1], wd.shape[1]
         d, held = wr.shape[0], wd.shape[0]
-        if (tuple(wgu.shape) != (held, d, 2 * f)
+        relu2 = op.attrs.get("expert_act") == "relu2"
+        if (tuple(wgu.shape) != (held, d, f if relu2 else 2 * f)
                 or tuple(wd.shape) != (held, f, d)):
             raise InferError(
                 "moe_ffn expert weights disagree: RouterW%s GateUpW%s "
-                "DownW%s (want [d, E], [E_held, d, 2f], [E_held, f, d])"
-                % (wr.shape, wgu.shape, wd.shape))
+                "DownW%s (want [d, E], [E_held, d, %s], [E_held, f, d])"
+                % (wr.shape, wgu.shape, wd.shape,
+                   "f] under relu2" if relu2 else "2f"))
         offset = int(op.attrs.get("expert_offset", 0))
         if offset < 0 or offset + held > n_experts:
             raise InferError(
@@ -665,6 +717,9 @@ def _moe_ffn_infer(op, ins):
                 and tuple(bias.shape) != (n_experts,)):
             raise InferError("moe_ffn ExpertBias%s is not [%d]"
                              % (bias.shape, n_experts))
+    if (op.attrs.get("expert_act") or "swiglu") not in ("swiglu", "relu2"):
+        raise InferError("moe_ffn expert_act %r is neither swiglu nor relu2"
+                         % (op.attrs.get("expert_act"),))
     if op.attrs.get("router", "softmax") not in ("softmax", "sigmoid"):
         raise InferError("moe_ffn router %r is neither softmax nor sigmoid"
                          % (op.attrs.get("router"),))

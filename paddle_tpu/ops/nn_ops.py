@@ -283,7 +283,8 @@ def _layer_norm(ctx, ins, attrs):
 
 @register("rms_norm")
 def _rms_norm(ctx, ins, attrs):
-    """x * rsqrt(mean(x^2) + eps) * w over the last axis.  The statistic
+    """x * rsqrt(mean(x^2) + eps) * w over the last axis (w [d], or over
+    X's last few axes: a gain a group).  The statistic
     and the normalisation run in f32 whatever X's dtype and Y leaves in
     X's dtype, so the op is dtype-transparent for the AMP trunk pass like
     layer_norm."""
@@ -381,36 +382,43 @@ def _short_conv(ctx, ins, attrs):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def causal_conv(x, filt, silu):
+def causal_conv(x, filt, silu, bias=None):
     """Depthwise causal convolution over the T axis (second to last) of x
     [..., T, d], one filter a channel, filt [d, L]: c_t = sum_j filt[:, j]
-    x_{t-(L-1)+j}, zeros left of t = 0; SiLU on the result where `silu`.
+    x_{t-(L-1)+j}, zeros left of t = 0, plus `bias` [d] where given
+    (Mamba-2's); SiLU on the result where `silu`.
     The UNGATED form beside gated_short_conv (Kimi Linear's q, k and v):
     L multiply-adds in f32, result in x's dtype, one pass over x."""
     c = _filtered([_f32(w) for w in _windows(x, filt.shape[1])], _f32(filt))
+    if bias is not None:
+        c = c + _f32(bias)
     return (jax.nn.silu(c) if silu else c).astype(x.dtype)
 
 
-def _cc_fwd(x, filt, silu):
-    return causal_conv(x, filt, silu), (x, filt)
+def _cc_fwd(x, filt, silu, bias=None):
+    return causal_conv(x, filt, silu, bias), (x, filt, bias)
 
 
 def _cc_bwd(silu, res, g):
     """Written out as gated_short_conv's: c is made again from x (cheaper
     than kept), dc = g silu'(c) is read at L offsets ahead for dx as the
-    forward reads x behind, and against x's windows for the filter."""
-    x, filt = res
+    forward reads x behind, and against x's windows for the filter; the
+    bias's gradient is dc summed over the rows."""
+    x, filt, bias = res
     taps, k = filt.shape[1], _f32(filt)
     xs = [_f32(w) for w in _windows(x, taps)]
     dc = _f32(g)
     if silu:
         c = _filtered(xs, k)
+        if bias is not None:
+            c = c + _f32(bias)
         sig = jax.nn.sigmoid(c)
         dc = dc * sig * (1.0 + c * (1.0 - sig))
     dx = _filtered(_windows(dc, taps, ahead=True), k)
     rows = tuple(range(x.ndim - 1))
     d_filt = jnp.stack([(dc * w).sum(rows) for w in xs], -1)
-    return dx.astype(x.dtype), d_filt.astype(filt.dtype)
+    return (dx.astype(x.dtype), d_filt.astype(filt.dtype),
+            None if bias is None else dc.sum(rows).astype(bias.dtype))
 
 
 causal_conv.defvjp(_cc_fwd, _cc_bwd)
@@ -418,17 +426,18 @@ causal_conv.defvjp(_cc_fwd, _cc_bwd)
 
 @register("causal_conv")
 def _causal_conv(ctx, ins, attrs):
-    """X [..., T, d], Filter [d, L] -> Out [..., T, d]: a depthwise causal
-    convolution of L taps with no gate, `act` "silu" or none on its
-    result.  f32 arithmetic whatever the dtype, Out in X's dtype
+    """X [..., T, d], Filter [d, L], optionally Bias [d] -> Out [..., T,
+    d]: a depthwise causal convolution of L taps with no gate, `act`
+    "silu" or none on its result.  f32 arithmetic whatever the dtype, Out in X's dtype
     (dtype-transparent for the AMP trunk pass like short_conv); the
     gradient is causal_conv's own VJP."""
     act = attrs.get("act") or ""
     if act not in ("", "silu"):
         raise ValueError("causal_conv act %r is neither silu nor none"
                          % (act,))
+    bias = ins["Bias"][0] if ins.get("Bias") else None
     return {"Out": [causal_conv(ins["X"][0], ins["Filter"][0],
-                                act == "silu")]}
+                                act == "silu", bias)]}
 
 
 @register("group_norm")
@@ -1544,8 +1553,11 @@ def _rms_infer(op, ins):
     x, w = _vi(ins, "X"), _vi(ins, "Scale")
     if x is None:
         return {}
+    # the gain covers the last axis, or the last few (a gain a group)
     if (x.shape is not None and w is not None and w.shape is not None
-            and x.shape[-1] >= 0 and tuple(w.shape) != (x.shape[-1],)):
+            and x.shape[-1] >= 0 and not (
+                0 < len(w.shape) < len(x.shape)
+                and tuple(w.shape) == tuple(x.shape[-len(w.shape):]))):
         raise InferError(
             "rms_norm Scale%s does not match X%s's last axis"
             % (w.shape, x.shape))
@@ -1585,6 +1597,11 @@ def _causal_conv_infer(op, ins):
                  or (x.shape[-1] >= 0 and x.shape[-1] != k.shape[0]))):
         raise InferError("causal_conv Filter%s does not match X%s (want "
                          "[d, L] against [..., T, d])" % (k.shape, x.shape))
+    bias = _vi(ins, "Bias")
+    if (bias is not None and bias.shape is not None and x.shape[-1] >= 0
+            and tuple(bias.shape) != (x.shape[-1],)):
+        raise InferError("causal_conv Bias%s is not [%d]: one number a "
+                         "channel" % (bias.shape, x.shape[-1]))
     return {"Out": [VarInfo(x.shape, x.dtype)]}
 
 

@@ -114,8 +114,10 @@ def program_flops(program, batch_hint=1):
             # (a chip's share holds wd[0] of the router's wr[-1] experts
             # and expects that share of the routed rows)
             routed = rows * int(attrs["top_k"]) * wd[0] / float(wr[-1])
+            # an ungated expert (relu2) is two [d, f] matmuls, not three
+            mats = 2 if attrs.get("expert_act") == "relu2" else 3
             total += factor * 2.0 * (rows * d * wr[-1]
-                                     + routed * 3 * d * wd[1])
+                                     + routed * mats * d * wd[1])
         elif t == "short_conv":
             # no matmul: B * u, L multiply-adds and the C gate a value
             x = _shape(blk, op.inputs.get("BCX", [""])[0], batch_hint)
@@ -139,6 +141,18 @@ def program_flops(program, batch_hint=1):
             (b, _, tq, dk), h = q, v[1]
             total += factor * b * h * tq * (
                 2.0 * CHUNK * (3 * dk + 2 * v[-1]) + 6.0 * dk * v[-1])
+        elif t == "mamba2_scan":
+            # the chunkwise form at Q = 128: a token a group C B^T against
+            # its chunk (2 Q N), a head the masked product (2 Q P) and the
+            # state read and written (4 N P); the exponentials left out
+            x = _shape(blk, op.inputs.get("X", [""])[0], batch_hint)
+            bm = _shape(blk, op.inputs.get("B", [""])[0], batch_hint)
+            if not x or not bm or len(x) != 4:
+                continue
+            from ..ops.mamba2_ops import CHUNK as q
+            (b, h, tq, p), (g, n) = x, (bm[1], bm[-1])
+            total += factor * b * tq * (
+                g * 2.0 * q * n + h * (2.0 * q * p + 4.0 * n * p))
         elif t == "matmul":
             x = _shape(blk, op.inputs.get("X", [""])[0], batch_hint)
             y = _shape(blk, op.inputs.get("Y", [""])[0], batch_hint)

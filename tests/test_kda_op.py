@@ -654,3 +654,75 @@ def test_causal_conv_refuses_what_it_does_not_compute():
     with pytest.raises(InferError, match="causal_conv"):
         rule(Op, {"X": [VarInfo((4, 32, D), "float32")],
                   "Filter": [VarInfo((D + 1, 4), "float32")]})
+
+
+# --- causal_conv with a bias (Mamba-2's `use_conv_bias`, PR 57) --------------
+@functools.lru_cache(maxsize=None)
+def _conv_bias_run(act):
+    w = _conv_data(4, 3)
+    bias = np.random.RandomState(9).randn(D).astype("float32")
+    main, startup = fluid.Program(), fluid.Program()
+    with framework.program_guard(main, startup), unique_name.guard():
+        x = layers.data("x", shape=list(w["x"].shape),
+                        append_batch_size=False)
+        x.stop_gradient = False
+        mix = layers.data("mix", shape=list(w["mix"].shape),
+                          append_batch_size=False)
+        y = layers.causal_conv(
+            x, 4, act=act,
+            param_attr=ParamAttr(
+                name="filt", initializer=NumpyArrayInitializer(w["filt"])),
+            bias_attr=ParamAttr(
+                name="bias", initializer=NumpyArrayInitializer(bias)))
+        loss = layers.reduce_sum(layers.elementwise_mul(y, mix))
+        fluid.backward.append_backward(loss)
+    exe = fluid.Executor(fluid.CPUPlace())
+    with fluid.scope_guard(fluid.Scope()):
+        exe.run(startup)
+        out = exe.run(main, feed={"x": w["x"], "mix": w["mix"]},
+                      fetch_list=[y] + [main._grad_names[n]
+                                        for n in ("x", "filt", "bias")])
+    errors = [d for d in analysis.verify_program(main, fetches=[loss])
+              if d.is_error]
+
+    def plain(a, k, b):
+        c = shifted_products(a, k, False) + b
+        return jax.nn.silu(c) if act == "silu" else c
+
+    args = (jnp.asarray(w["x"]), jnp.asarray(w["filt"]), jnp.asarray(bias))
+    grads = jax.grad(lambda *a: (plain(*a) * w["mix"]).sum(),
+                     argnums=(0, 1, 2))(*args)
+    return out, errors, (plain(*args),) + grads, main
+
+
+@pytest.mark.parametrize("act", ["silu", None])
+def test_causal_conv_adds_its_bias_before_the_activation(act):
+    """Result and the three gradients (x, the filter, the bias) against the
+    shifted products plus the bias, written out; the bias is a [d]
+    parameter, zero where no initializer says otherwise, and an op built
+    without `bias_attr` has no `Bias` slot (the accepted cells' op)."""
+    got, errors, want, main = _conv_bias_run(act)
+    assert not errors
+    for g, w in zip(got, want):
+        assert g.shape == w.shape
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-5)
+    (op,) = [o for o in main.global_block().ops if o.type == "causal_conv"]
+    assert tuple(main.global_block().var(op.inputs["Bias"][0]).shape) == (D,)
+    plain_main = _conv_run(4, 3, act)[3].block.program
+    (plain_op,) = [o for o in plain_main.global_block().ops
+                   if o.type == "causal_conv"]
+    assert "Bias" not in plain_op.inputs
+
+
+def test_causal_conv_infer_rule_checks_the_bias():
+    rule = get_infer_rule("causal_conv").fn
+
+    class Op:
+        attrs = {"act": "silu"}
+
+    ins = {"X": [VarInfo((2, 10, 8), "bfloat16")],
+           "Filter": [VarInfo((8, 4), "float32")]}
+    assert rule(Op, dict(ins, Bias=[VarInfo((8,), "float32")]))[
+        "Out"][0].shape == (2, 10, 8)
+    with pytest.raises(InferError, match=r"causal_conv Bias\(4,\) is not"):
+        rule(Op, dict(ins, Bias=[VarInfo((4,), "float32")]))

@@ -129,8 +129,11 @@ def test_attention_pairs_computed_over_visible_is_in_the_benchmark_by_name(
         spec = json.load(f)
     name = "attention_pairs_computed_over_visible"
     entry, = [m for m in spec["per_layer"] if m["name"] == name]
-    # appended by PR 53, nothing moved; PR 56 appended one after it
-    assert spec["per_layer"].index(entry) == len(spec["per_layer"]) - 2
+    # appended by PR 53, nothing moved; PR 56 appended one right after it
+    # (found by name: a later PR's entries come after the two)
+    names = [m["name"] for m in spec["per_layer"]]
+    assert names.index("attention_block_fetches_over_tiles") == names.index(
+        name) + 1
     dense = {"tfm_base_train", "tfm_base_train_s64", "resnet50_train"}
     assert set(entry["workloads"]) == {
         c["name"] for c in spec["workloads"]} - dense
@@ -215,9 +218,11 @@ def test_attention_block_fetches_over_tiles_is_in_the_benchmark_by_name(
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         spec = json.load(f)
     name = "attention_block_fetches_over_tiles"
-    entry = spec["per_layer"][-1]  # appended, nothing moved
-    pairs, = [m for m in spec["per_layer"]
-              if m["name"] == "attention_pairs_computed_over_visible"]
+    # appended right behind PR 53's entry, nothing moved (by name: a later
+    # PR's entries come after it)
+    (at, pairs), = [(i, m) for i, m in enumerate(spec["per_layer"])
+                    if m["name"] == "attention_pairs_computed_over_visible"]
+    entry = spec["per_layer"][at + 1]
     assert entry == dict(pairs, name=name)
     with open(os.path.join(root, "benchmark", "layer_metrics",
                            name + ".json")) as f:

@@ -148,7 +148,24 @@ offset; `transformer` and `resnet` hold no flash kernel; and the eight tiny
 programs with a causal flash core (`gpt2`, `olmoe`, `lfm2`, `trinity`,
 `kanana2`, `kimi_linear`, `qwen3_next`, `ouro`) run T = 512 in ONE block,
 where the map stays the grid's own step, as the two GPT-2 cells do at
-T = 1024."""
+T = 1024.
+
+PR 57 added `nemotron_h` (a tiny Nemotron-3-Nano: the nine-layer pattern
+MEMEM*EME, four Mamba-2 mixers around the chunkwise `mamba2_scan` op at
+eight heads of 64 over two groups at state 128, their `causal_conv` with a
+bias, a rotary-free attention layer at heads of 128, a share of `relu2`
+experts held beside a relu2 shared one, one `expert_bias_update` an expert
+layer), its digest taken from PR 57's tree by this file's `_digest`: 18
+Mosaic calls (three a Mamba-2 layer, twelve: the chunk scan in the forward,
+the walk that keeps the entering states and the reverse walk in the grad
+op; the three of a flash core; and three grouped-matmul kernels, which the
+share's module-level jitted functions hold once for the four expert layers
+that call them).
+The seventeen digests and counts above did NOT move: `moe_ffn` without
+`expert_act` lowers through the SwiGLU body it lowered through (the op's
+jitted loops keep their names and take the body as one more static
+argument), `causal_conv` without a `Bias` to the text it lowered to, and
+`rms_norm` with a gain of one axis likewise."""
 
 import base64
 import functools
@@ -161,8 +178,9 @@ import pytest
 
 import paddle_tpu as fluid
 from paddle_tpu.core.trace import build_traced_function
-from paddle_tpu.models import (gpt2, kanana2, kimi_linear, lfm2, olmoe, ouro,
-                               qwen3_next, resnet, transformer, trinity)
+from paddle_tpu.models import (gpt2, kanana2, kimi_linear, lfm2, nemotron_h,
+                               olmoe, ouro, qwen3_next, resnet, transformer,
+                               trinity)
 from paddle_tpu.ops import pallas_kernels as pk
 
 SEQ = 512
@@ -223,6 +241,16 @@ class Q(qwen3_next.Qwen3NextConfig):
     num_attention_heads, num_key_value_heads = 2, 1
     moe_intermediate_size = shared_expert_intermediate_size = 128
     num_experts, num_experts_per_tok = 8, 2
+    num_local_experts, expert_offset = 2, 2
+
+
+class N(nemotron_h.NemotronHConfig):
+    vocab_size, hidden_size, num_hidden_layers = 512, 128, 9
+    hybrid_override_pattern = "MEMEM*EME"
+    mamba_num_heads, mamba_head_dim, n_groups, ssm_state_size = 8, 64, 2, 128
+    num_attention_heads, num_key_value_heads, head_dim = 2, 1, 128
+    moe_intermediate_size, moe_shared_expert_intermediate_size = 128, 256
+    n_routed_experts, num_experts_per_tok = 8, 2
     num_local_experts, expert_offset = 2, 2
 
 
@@ -294,6 +322,7 @@ PROGRAMS = {"gpt2": lambda: _lm(gpt2.gpt2_lm_program, G),
             "kimi_linear": lambda: _lm(_kimi_program, M),
             "qwen3_next": lambda: _lm(qwen3_next.qwen3_next_lm_program, Q),
             "ouro": lambda: _lm(ouro.ouro_lm_program, U),
+            "nemotron_h": lambda: _lm(nemotron_h.nemotron_h_lm_program, N),
             "transformer": _transformer,
             "resnet": _resnet}
 
@@ -304,8 +333,9 @@ PROGRAMS = {"gpt2": lambda: _lm(gpt2.gpt2_lm_program, G),
 # at PR 50; every program and core with a causal flash kernel: at PR 53; the
 # three UNTOUCHED cores: at ba67ef1, PR 53's parent; the four cores whose
 # causal kernels walk several blocks of the full grid, `piece_diagonal_chunk`
-# among them: at PR 56)
+# among them: at PR 56; `nemotron_h`: added at PR 57)
 BEFORE = {
+    "nemotron_h": ("e3fb7a83c14d14a8acce54e62588e6cc395bb2a5", 18),
     "qwen3_next": ("066aa16bdf09bc9d5c356dda010c95375aae93b6", 27),
     "kimi_linear": ("0b6cea6313d097ed575295ef51fd8b563347239c", 21),
     "transformer": ("e83306d8f28e59df41bc44b9fe7303322f29a58b", 0),

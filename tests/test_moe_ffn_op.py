@@ -942,3 +942,314 @@ def test_a_two_layer_share_program_traces_each_chunk_loop_once(monkeypatch):
     assert found["ops"] - before == 2 * layers_
     assert found["chunk_rows"][rows] == 16
     jax.clear_caches()
+
+
+# --- the ungated expert: down(relu(up x)^2) (`expert_act` "relu2", PR 57) ---
+def _relu2_weights():
+    w = _weights("balanced")
+    rng = np.random.RandomState(57)
+    return dict(w, up=(rng.randn(E, D, F) * 0.3).astype("float32"))
+
+
+def _relu2_loop(x, router_w, up, down, offset, scaling=2.5):
+    """The plain statement: s = sigmoid(x W_r); the top-k of s + b; weights
+    scaling s / (sum + 1e-20); a loop over the held experts."""
+    s = jax.nn.sigmoid(x @ router_w)
+    _, top_e = jax.lax.top_k(s + jnp.asarray(_bias()), K)
+    top_p = jnp.take_along_axis(s, top_e, -1)
+    top_p = scaling * top_p / (top_p.sum(-1, keepdims=True) + 1e-20)
+    y = jnp.zeros_like(x)
+    for local in range(up.shape[0]):
+        chosen = top_e == offset + local
+        weight = jnp.where(chosen, top_p, 0.0).sum(-1, keepdims=True)
+        y = y + weight * (jnp.square(jax.nn.relu(x @ up[local]))
+                          @ down[local])
+    return y
+
+
+def _relu2_op(x, router_w, up, down, offset):
+    from paddle_tpu.core.registry import LowerCtx
+    from paddle_tpu.ops import moe_ops
+
+    return moe_ops._moe_ffn(
+        LowerCtx(platform="cpu"),
+        {"X": [x], "RouterW": [router_w], "GateUpW": [up], "DownW": [down],
+         "ExpertBias": [jnp.asarray(_bias())]},
+        {"top_k": K, "router": "sigmoid", "norm_topk_prob": True,
+         "norm_topk_eps": 1e-20, "routed_scaling_factor": 2.5,
+         "expert_offset": offset, "expert_act": "relu2"})
+
+
+RELU2 = {"whole": (0, E, None), "share": (4, 2, None),
+         "share_in_chunks": (3, 3, 16), "share_at_the_start": (0, 3, 8)}
+
+
+@functools.lru_cache(maxsize=None)
+def _relu2_both(case):
+    from paddle_tpu.ops import moe_ops
+
+    offset, held, chunk = RELU2[case]
+    w = _relu2_weights()
+    sl = slice(offset, offset + held)
+    args = [jnp.asarray(v) for v in (w["x"], w["router"], w["up"][sl],
+                                     w["down"][sl])]
+    mix = jnp.asarray(w["mix"])
+    before = moe_ops._chunk_rows
+    if chunk:
+        moe_ops._chunk_rows = lambda m: chunk
+    try:
+        with jax.default_matmul_precision("highest"):
+            got = jax.value_and_grad(
+                lambda *a: (_relu2_op(*a, offset)["Y"][0] * mix).sum(),
+                argnums=(0, 1, 2, 3))(*args)
+            y = _relu2_op(*args, offset)["Y"][0]
+            want = jax.value_and_grad(
+                lambda *a: (_relu2_loop(*a, offset) * mix).sum(),
+                argnums=(0, 1, 2, 3))(*args)
+            want_y = _relu2_loop(*args, offset)
+    finally:
+        moe_ops._chunk_rows = before
+    return (y, got), (want_y, want)
+
+
+@pytest.mark.parametrize("case", list(RELU2))
+def test_relu2_experts_match_the_plain_loop(case):
+    (y, _), (want_y, _) = _relu2_both(case)
+    assert np.abs(np.asarray(want_y)).max() > 0.1
+    np.testing.assert_allclose(y, want_y, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("wrt", range(4), ids=["x", "router", "up", "down"])
+@pytest.mark.parametrize("case", list(RELU2))
+def test_relu2_experts_every_gradient_matches_the_plain_loop(case, wrt):
+    (_, (_, got)), (_, (_, want)) = _relu2_both(case)
+    assert got[wrt].shape == want[wrt].shape
+    np.testing.assert_allclose(got[wrt], want[wrt], rtol=1e-5, atol=1e-5)
+
+
+def test_relu2_is_not_a_swiglu_of_half_the_width():
+    """The same [E, d, f] weight read as (gate | up) halves is another
+    function: the attribute names the body, the shape does not."""
+    from paddle_tpu.core.registry import LowerCtx
+    from paddle_tpu.ops import moe_ops
+
+    w = _relu2_weights()
+    ins = {"X": [jnp.asarray(w["x"])], "RouterW": [jnp.asarray(w["router"])],
+           "GateUpW": [jnp.asarray(w["up"])],
+           "DownW": [jnp.asarray(w["down"])]}
+    relu2 = moe_ops._moe_ffn(LowerCtx(platform="cpu"), ins,
+                             {"top_k": K, "expert_act": "relu2"})["Y"][0]
+    swiglu = moe_ops._moe_ffn(
+        LowerCtx(platform="cpu"),
+        dict(ins, DownW=[jnp.asarray(w["down"][:, :F // 2])]),
+        {"top_k": K})["Y"][0]
+    assert np.abs(np.asarray(relu2) - np.asarray(swiglu)).max() > 0.01
+    with pytest.raises(ValueError, match="expert_act 'gelu' is neither"):
+        moe_ops._moe_ffn(LowerCtx(platform="cpu"), ins,
+                         {"top_k": K, "expert_act": "gelu"})
+
+
+def test_the_layer_names_the_body_and_a_swiglu_op_carries_what_it_carried():
+    from paddle_tpu import framework, unique_name
+
+    main, startup = fluid.Program(), fluid.Program()
+    with framework.program_guard(main, startup), unique_name.guard():
+        x = layers.data("x", shape=[N, D], append_batch_size=False)
+        layers.moe_ffn(x, E, F, K)
+        layers.moe_ffn(x, E, F, K, num_local_experts=2, expert_offset=2,
+                       expert_act="relu2")
+        with pytest.raises(ValueError, match="expert_act 'gelu'"):
+            layers.moe_ffn(x, E, F, K, expert_act="gelu")
+    block = main.global_block()
+    swiglu, relu2 = [op for op in block.ops if op.type == "moe_ffn"]
+    assert "expert_act" not in swiglu.attrs
+    assert relu2.attrs["expert_act"] == "relu2"
+    assert tuple(block.var(swiglu.inputs["GateUpW"][0]).shape) == (E, D, 2 * F)
+    assert tuple(block.var(relu2.inputs["GateUpW"][0]).shape) == (2, D, F)
+    assert not [d for d in analysis.verify_program(main) if d.is_error]
+
+
+def test_infer_rule_reads_the_up_weight_by_the_body():
+    out = _infer((N, D), (D, E), (2, D, F), (2, F, D), expert_act="relu2",
+                 expert_offset=2)
+    assert out["Y"][0].shape == (N, D)
+    with pytest.raises(InferError, match=r"f\] under relu2"):
+        _infer((N, D), (D, E), (E, D, 2 * F), (E, F, D), expert_act="relu2")
+    with pytest.raises(InferError, match="2f"):
+        _infer((N, D), (D, E), (E, D, F), (E, F, D))
+    with pytest.raises(InferError, match="expert_act 'gelu' is neither"):
+        _infer((N, D), (D, E), (E, D, 2 * F), (E, F, D), expert_act="gelu")
+
+
+def test_program_flops_counts_two_matmuls_an_ungated_expert():
+    from paddle_tpu import framework, unique_name
+    from paddle_tpu.utils.flops import program_flops
+
+    def flops(**kw):
+        main, startup = fluid.Program(), fluid.Program()
+        with framework.program_guard(main, startup), unique_name.guard():
+            x = layers.data("x", shape=[N, D], append_batch_size=False)
+            layers.moe_ffn(x, E, F, K, **kw)
+        return program_flops(main)
+
+    assert flops() == 2.0 * (N * D * E + N * K * 3 * D * F)
+    assert flops(expert_act="relu2") == 2.0 * (N * D * E + N * K * 2 * D * F)
+
+
+# one test that ties the share to the model: Nemotron-3-Nano's expert layer
+SHARES = 16
+
+
+def test_sixteen_shares_and_the_shared_expert_once_are_the_uncut_layer():
+    """Sixteen chips hold one expert each of one Nemotron-H expert layer
+    (`models/nemotron_h._experts`: sigmoid router over 16 with a selection
+    bias, top-4, scaled 2.5, relu2 experts beside a relu2 shared one).
+    Each routes over all sixteen, computes its own expert's part and the
+    WHOLE shared expert; the sixteen routed parts plus the shared expert
+    counted ONCE are what the uncut reference gives for the layer, and
+    every chip saw the same routing decisions."""
+    from paddle_tpu import framework, unique_name
+    from paddle_tpu.models import nemotron_h, nemotron_h_reference
+
+    d, f, fs, k = 32, 16, 24, 4
+    rng = np.random.RandomState(7)
+    w = {"x": rng.randn(2, 24, d).astype("float32"),
+         "router": (rng.randn(d, SHARES) * 0.3).astype("float32"),
+         "bias": (rng.randn(SHARES) * 0.3).astype("float32"),
+         "up": (rng.randn(SHARES, d, f) * 0.3).astype("float32"),
+         "down": (rng.randn(SHARES, f, d) * 0.3).astype("float32"),
+         "shared": [(rng.randn(d, fs) * 0.3).astype("float32"),
+                    (rng.randn(fs, d) * 0.3).astype("float32")]}
+
+    def share(offset):
+        hp = type("Share", (nemotron_h.NemotronHConfig,), dict(
+            hidden_size=d, moe_intermediate_size=f,
+            moe_shared_expert_intermediate_size=fs, n_routed_experts=SHARES,
+            num_experts_per_tok=k, num_local_experts=1,
+            expert_offset=offset, num_hidden_layers=1))
+        main, startup = fluid.Program(), fluid.Program()
+        with framework.program_guard(main, startup), unique_name.guard():
+            x = layers.data("x", shape=list(w["x"].shape),
+                            append_batch_size=False)
+            y = nemotron_h._experts(x, hp, is_test=False)
+        block = main.global_block()
+        (moe,) = [op for op in block.ops if op.type == "moe_ffn"]
+        init = dict(zip(
+            [moe.inputs[s][0] for s in ("RouterW", "ExpertBias", "GateUpW",
+                                        "DownW")],
+            [w["router"], w["bias"], w["up"][offset:offset + 1],
+             w["down"][offset:offset + 1]]))
+        init.update(zip([p.name for p in block.all_parameters()
+                         if p.name.startswith("shared_ffn")], w["shared"]))
+        exe, scope = fluid.Executor(fluid.CPUPlace()), fluid.Scope()
+        with fluid.scope_guard(scope):
+            exe.run(startup)
+            for name, value in init.items():
+                assert tuple(np.asarray(scope.find_var(name)).shape) == (
+                    value.shape), name
+                scope.set(name, jnp.asarray(value))
+            return exe.run(main, feed={"x": w["x"]}, fetch_list=[
+                y, moe.outputs["Y"][0], moe.outputs["TokensPerExpert"][0]])
+
+    cfg = {"num_experts_per_tok": k, "norm_topk_prob": True,
+           "routed_scaling_factor": 2.5, "expert_offset": 0}
+    args = [jnp.asarray(w[n]) for n in ("x", "router", "bias", "up", "down")]
+    with jax.default_matmul_precision("highest"):
+        routed, top_e = nemotron_h_reference.routed(cfg, *args)
+        shared = nemotron_h_reference.relu2_mlp(
+            args[0], *map(jnp.asarray, w["shared"]))
+    want_counts = np.bincount(np.asarray(top_e).reshape(-1),
+                              minlength=SHARES)
+    parts = [share(offset) for offset in range(SHARES)]
+    for both, part, counts in parts:
+        np.testing.assert_array_equal(counts, want_counts)
+        np.testing.assert_allclose(both - part, shared, rtol=1e-4, atol=1e-4)
+    assert sum(np.abs(part).max() > 0 for _, part, _ in parts) >= 12
+    np.testing.assert_allclose(sum(p for _, p, _ in parts) + shared,
+                               routed + shared, rtol=1e-5, atol=5e-5)
+
+
+# --- an expert width the kernels' tile does not divide (PR 57) --------------
+def test_a_width_off_the_kernels_tile_is_padded_with_columns_that_add_nothing():
+    """Nemotron-H's experts are 1856 = 14.5 x 128 wide.  Where the Pallas
+    grouped matmul would engage but for that, the op pads the up weight's
+    columns and the down weight's rows with zeros to the next multiple of
+    128: result and every gradient are the unpadded op's (relu(0)^2 = 0;
+    a SwiGLU's halves are padded each: silu(0) * 0 = 0), and a width the
+    tile divides, or a placement off the chip, passes through."""
+    from paddle_tpu.core.registry import LowerCtx
+    from paddle_tpu.ops import moe_ops
+
+    on_chip, here = LowerCtx(platform="tpu"), LowerCtx(platform="cpu")
+    n, d, f, e, k = 128, 128, 96, 8, 2  # N k = 256 rows: the kernels' tile
+    rng = np.random.RandomState(3)
+    x = jnp.asarray(rng.randn(n, d), jnp.float32)
+    wr = jnp.asarray(rng.randn(d, e) * 0.3, jnp.float32)
+    for act, halves in (("relu2", 1), ("swiglu", 2)):
+        up = jnp.asarray(rng.randn(2, d, halves * f) * 0.3, jnp.float32)
+        down = jnp.asarray(rng.randn(2, f, d) * 0.3, jnp.float32)
+        wide_up, wide_down = moe_ops._kernel_widths(on_chip, n * k, up, down,
+                                                    halves)
+        assert wide_up.shape == (2, d, halves * 128)
+        assert wide_down.shape == (2, 128, d)
+        for w in moe_ops._kernel_widths(here, n * k, up, down, halves):
+            assert w is up or w is down  # off the chip: as given
+        fits = moe_ops._kernel_widths(on_chip, n * k, wide_up, wide_down,
+                                      halves)
+        assert fits[0] is wide_up and fits[1] is wide_down
+        # rows the kernels' tile does not divide: ragged_dot either way
+        assert moe_ops._kernel_widths(on_chip, n * k + 2, up, down,
+                                      halves)[0] is up
+
+        def loss(x, wr, wgu, wd):
+            out = moe_ops._moe_ffn(
+                here, {"X": [x], "RouterW": [wr], "GateUpW": [wgu],
+                       "DownW": [wd]},
+                {"top_k": k, "router": "sigmoid", "norm_topk_prob": True,
+                 "expert_offset": 2, "expert_act": act})
+            return (out["Y"][0] * jnp.cos(jnp.arange(d))).sum()
+
+        with jax.default_matmul_precision("highest"):
+            want = jax.value_and_grad(loss, argnums=(0, 1, 2, 3))(
+                x, wr, up, down)
+            got = jax.value_and_grad(
+                lambda x, wr, a, b: loss(x, wr, *moe_ops._kernel_widths(
+                    on_chip, n * k, a, b, halves)), argnums=(0, 1, 2, 3))(
+                        x, wr, up, down)
+        assert abs(float(want[0])) > 1.0
+        np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
+        for g, w in zip(got[1], want[1]):
+            assert g.shape == w.shape
+            np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-5)
+
+
+def test_nemotrons_share_cross_lowers_for_the_tpu_with_six_kernels_a_layer():
+    """The relu2 share at Nemotron-3-Nano's widths (d 2688, f 1856, 8 of
+    128 held, top-6), lowered for the TPU on this host: the six Mosaic
+    calls a layer every share has (without the padding the two products
+    and their four transposes are `ragged_dot`s, none), and the body's two
+    loops are the shared functions under their old names."""
+    from paddle_tpu.core.registry import LowerCtx
+    from paddle_tpu.ops import moe_ops
+
+    f, e, held, k, n, d = 1856, 128, 8, 6, 1024, 2688
+    on_chip = LowerCtx(platform="tpu")
+
+    def loss(x, wr, wgu, wd):
+        out = moe_ops._moe_ffn(
+            on_chip, {"X": [x], "RouterW": [wr], "GateUpW": [wgu],
+                      "DownW": [wd]},
+            {"top_k": k, "router": "sigmoid", "norm_topk_prob": True,
+             "expert_offset": 0, "expert_act": "relu2"})
+        return out["Y"][0].astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).trace(
+        jax.ShapeDtypeStruct((n, d), jnp.float32),
+        jax.ShapeDtypeStruct((d, e), jnp.float32),
+        jax.ShapeDtypeStruct((held, d, f), jnp.bfloat16),
+        jax.ShapeDtypeStruct((held, f, d), jnp.bfloat16),
+    ).lower(lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") == 6
+    assert "ragged_dot" not in text
+    for name, functions in (("_swiglu_live", 2), ("_swiglu_live_bwd", 1)):
+        assert len(_functions(text, name)) == functions

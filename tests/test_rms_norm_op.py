@@ -1,6 +1,7 @@
 """rms_norm: x * rsqrt(mean(x^2) + eps) * w over the last axis, statistics
 in f32 whatever X's dtype, output in X's dtype; its infer rule; its grad."""
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -92,3 +93,61 @@ def test_layer_creates_a_unit_scale_and_the_program_verifies():
     (got,) = exe.run(main, feed={"x": xv}, fetch_list=[y])
     np.testing.assert_allclose(got, _rms(xv, np.ones(32), 1e-5), rtol=1e-5,
                                atol=1e-6)
+
+
+def test_a_gain_a_group_covers_the_last_two_axes():
+    """`gain_axes=2` (Mamba-2's norm over groups of d_inner / n_groups
+    channels, each group with gains of its own): the parameter is
+    [groups, d], the statistic stays the last axis's, result and both
+    gradients are the formula's; the infer rule takes a Scale that matches
+    X's last axes and refuses one that does not."""
+    import jax
+
+    from paddle_tpu import framework, unique_name
+    from paddle_tpu.initializer import NumpyArrayInitializer
+    from paddle_tpu.param_attr import ParamAttr
+
+    rng = np.random.RandomState(3)
+    xv = rng.randn(2, 5, 4, 16).astype("float32")
+    gain = rng.uniform(0.5, 1.5, (4, 16)).astype("float32")
+    mixv = rng.uniform(0.5, 1.5, xv.shape).astype("float32")
+    main, startup = fluid.Program(), fluid.Program()
+    with framework.program_guard(main, startup), unique_name.guard():
+        x = layers.data("x", shape=list(xv.shape), append_batch_size=False)
+        x.stop_gradient = False
+        mix = layers.data("mix", shape=list(xv.shape),
+                          append_batch_size=False)
+        y = layers.rms_norm(x, 1e-5, gain_axes=2, param_attr=ParamAttr(
+            name="gain", initializer=NumpyArrayInitializer(gain)))
+        loss = layers.reduce_sum(layers.elementwise_mul(y, mix))
+        fluid.backward.append_backward(loss)
+    assert tuple(main.global_block().var("gain").shape) == (4, 16)
+    assert not [d for d in analysis.verify_program(main, fetches=[loss])
+                if d.is_error]
+    exe = fluid.Executor(fluid.CPUPlace())
+    with fluid.scope_guard(fluid.Scope()):
+        exe.run(startup)
+        got = exe.run(main, feed={"x": xv, "mix": mixv}, fetch_list=[
+            y, main._grad_names["x"], main._grad_names["gain"]])
+
+    def plain(a, w):
+        return a * jax.lax.rsqrt(jnp.mean(a * a, -1, keepdims=True)
+                                 + 1e-5) * w
+
+    want = (plain(xv, gain),) + jax.grad(
+        lambda a, w: (plain(a, w) * mixv).sum(), argnums=(0, 1))(
+            jnp.asarray(xv), jnp.asarray(gain))
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-5)
+    rule = get_infer_rule("rms_norm")
+
+    class Op:
+        attrs = {"epsilon": 1e-5}
+
+    x_info = [VarInfo((-1, 5, 4, 16), "bfloat16")]
+    assert rule.fn(Op, {"X": x_info, "Scale": [VarInfo(
+        (4, 16), "float32")]})["Y"][0].shape == (-1, 5, 4, 16)
+    for wrong in ((8, 16), (5, 4, 16, 1), (2, 5, 4, 16)):
+        with pytest.raises(InferError, match="does not match"):
+            rule.fn(Op, {"X": [VarInfo((2, 5, 4, 16), "float32")],
+                         "Scale": [VarInfo(wrong, "float32")]})

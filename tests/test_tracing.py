@@ -943,3 +943,60 @@ def test_qwen3_nexts_gdn_and_rope_scopes_reach_the_lowered_steps_op_names():
     assert (found["pallas_hits"]["kda_carry"],
             found["pallas_hits"]["kda_carry_bwd"]) == (9, 3)
     assert found["kda_chunks"]["ops"] == 0
+
+
+def test_nemotron_hs_mamba2_scopes_reach_the_lowered_steps_op_names():
+    """mamba2 around a Mamba-2 mixer with in_proj, conv, core, norm and
+    out_proj inside it: the nested part of the optimized HLO's op names
+    carries each, forward and backward, under the op type the benchmark's
+    readers match first (`[a-z]+/mamba2_scan(_grad)?/<i>` then
+    `/mamba2.core/`); inside the op's lowering `chunk_scan` (the forward's
+    kernel and the reverse walk) and `states` (the backward's first walk)
+    tell the kernels apart; and every engagement counts under
+    attribution()["pallas_hits"]["ssd"]."""
+    from paddle_tpu.models import gpt2, nemotron_h
+    from paddle_tpu.ops import kernel_tuning
+
+    class N(nemotron_h.NemotronHConfig):
+        vocab_size, hidden_size, num_hidden_layers = 256, 64, 4
+        hybrid_override_pattern = "ME*M"
+        mamba_num_heads, mamba_head_dim, n_groups, ssm_state_size = 4, 16, 2, 16
+        num_attention_heads, num_key_value_heads, head_dim = 4, 2, 32
+        moe_intermediate_size, moe_shared_expert_intermediate_size = 32, 64
+        n_routed_experts, num_experts_per_tok = 8, 2
+
+    kernel_tuning.reset_attribution()
+    main, startup, _, fetches = nemotron_h.nemotron_h_lm_program(
+        N, seq_len=40, lr=1e-3)
+    startup.random_seed = main.random_seed = 5
+    exe, scope = fluid.Executor(fluid.CPUPlace()), fluid.Scope()
+    with fluid.scope_guard(scope):
+        exe.run(startup)
+        exe.run(main, feed=gpt2.make_fake_lm_batch(2, 40, N, seed=1),
+                fetch_list=[fetches[0]])
+        (text,) = exe.compiled_hlo(main)
+    ops, nested, inside = main.global_block().ops, {}, set()
+    for op_name in re.findall(r'op_name="jit\(program_step\)/([^"]*)"', text):
+        found = SCOPE.findall(op_name)
+        want = ops[int(found[0][2])].attrs.get("op_namescope")
+        if want is None:
+            continue
+        (role, typ, _), (_, scopes, depth) = found[:2]
+        assert (scopes, int(depth)) == (want.replace("/", "."),
+                                        want.count("/") + 1), op_name
+        nested.setdefault(scopes, set()).add((role, typ))
+        if scopes == "mamba2.core" and typ.startswith("mamba2_scan"):
+            inside.update(re.findall(r"[/(](chunk_scan|states)[/)]", op_name))
+    assert {"mamba2.in_proj", "mamba2.conv", "mamba2.core", "mamba2.norm",
+            "mamba2.out_proj", "attn_full.core", "shared_expert"} <= set(
+                nested)
+    assert {("forward", "mamba2_scan"),
+            ("backward", "mamba2_scan_grad")} <= nested["mamba2.core"]
+    assert inside == {"chunk_scan", "states"}
+    assert {("forward", "causal_conv"),
+            ("backward", "causal_conv_grad")} <= nested["mamba2.conv"]
+    assert ("forward", "rms_norm") in nested["mamba2.norm"]
+    assert "attn_full.rope" not in nested  # position-free attention
+    # two layers: the forward op's scan, and the grad op's three (its own
+    # forward, traced and then dead, the states' walk and the reverse walk)
+    assert kernel_tuning.attribution()["pallas_hits"]["ssd"] == 8
