@@ -14,6 +14,8 @@ needed (bf16 has float32's exponent range).
     opt.minimize(loss)      # grads flow through the casts
 """
 
+import collections
+
 from .. import framework
 
 _BF16_OPS = ("mul", "matmul", "conv2d", "depthwise_conv2d",
@@ -60,6 +62,9 @@ _KEEP_F32_SLOTS = {"fused_attention": ("Bias",),
 # keep their declared dtype and get no cast-back
 _KEEP_OUT_SLOTS = {"moe_ffn": ("TokensPerExpert", "AuxLoss")}
 
+# the dtype-transparent ops that compute nothing
+_MOVE_OPS = ("split", "concat", "expand")
+
 # dtype-transparent trunk ops: (data input slots, flippable output slots).
 # When every data input of one of these is available in half precision,
 # the op itself runs in half — its lowering preserves the input dtype
@@ -90,6 +95,14 @@ _TRANSPARENT_OPS = {
     # (stats stay f32 internally, like layer_norm); Scale/Bias params
     # and the Mean/Variance state outputs keep f32
     "fused_residual_ln": (("X", "Y"), ("Sum", "Y")),
+    # the ops that only MOVE values: rounding to half commutes with a
+    # slice, a concatenation and a tiling, so cast(op(castback(x))) is
+    # op(x) value for value, forward and in the gradient (expand's sums
+    # its copies in f32 and rounds once, tensor_ops._tile_copies) — the
+    # op runs in the dtype its data arrives in and no rounding moves.
+    # `concat` flips only when EVERY input is half-sourced (the generic
+    # rule below, as a same-shape elementwise_add's)
+    **{t: (("X",), ("Out",)) for t in _MOVE_OPS},
 }
 
 
@@ -224,12 +237,13 @@ def propagate_half_through_trunk(program, dtype="bfloat16"):
     untouched.  Unused cast-backs are dropped by trace-time DCE, and the
     downstream f32->half re-casts collapse in collapse_redundant_casts —
     net effect: the conv/BN/relu/add/pool trunk runs half end-to-end.
-    Returns the number of flipped ops."""
+    Returns the number of flipped ops, and leaves them by op type on the
+    Program (`_amp_half_flipped`: type -> count, summed over calls)."""
     tag = _tag_for(dtype)
     block = program.global_block()
     castback_src = {}  # f32 name -> half name, current definitions only
     new_ops = []
-    flipped = 0
+    flipped = collections.Counter()  # op type -> ops flipped
     bias_cast_cache = {}  # f32 bias name -> half name
 
     def half_bias(name):
@@ -302,7 +316,7 @@ def propagate_half_through_trunk(program, dtype="bfloat16"):
                 if s in op.inputs:
                     op.inputs[s] = [halves.get(n, n) for n in op.inputs[s]]
             new_ops.append(op)
-            flipped += 1
+            flipped[op.type] += 1
             for s in out_slots:
                 for i, n in enumerate(list(op.outputs.get(s, []))):
                     raw, cb = _emit_raw_and_castback(block, n, dtype, tag)
@@ -335,8 +349,11 @@ def propagate_half_through_trunk(program, dtype="bfloat16"):
         new_ops.append(op)
     if flipped:
         block.ops = new_ops
+        program._amp_half_flipped = dict(
+            flipped + collections.Counter(
+                getattr(program, "_amp_half_flipped", {})))
         program._bump_version()
-    return flipped
+    return sum(flipped.values())
 
 
 def collapse_redundant_casts(program, dtype="bfloat16"):

@@ -7,6 +7,8 @@ trace RNG key via ``ctx.rng`` — the functional replacement for the
 reference's per-op seed + global generator.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -232,11 +234,35 @@ def _strided_slice(ctx, ins, attrs):
     return {"Out": [x[tuple(idx)]]}
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
+def _tile_copies(x, times, shape):
+    """jnp.tile of x of `shape`, whose gradient sums the copies in f32 (or
+    wider) and rounds once: at half precision what the f32 op followed by
+    a cast gives, so that `expand` only moves values under the AMP trunk
+    pass; at f32 the sum jnp.tile's own gradient makes."""
+    return jnp.tile(x, times)
+
+
+def _tile_copies_fwd(x, times, shape):
+    return jnp.tile(x, times), None
+
+
+def _tile_copies_bwd(times, shape, _, g):
+    wide = jnp.promote_types(g.dtype, jnp.float32)
+    summed = jax.linear_transpose(
+        lambda v: jnp.tile(v, times),
+        jax.ShapeDtypeStruct(shape, wide))(g.astype(wide))[0]
+    return (summed.astype(g.dtype),)
+
+
+_tile_copies.defvjp(_tile_copies_fwd, _tile_copies_bwd)
+
+
 @register("expand")
 def _expand(ctx, ins, attrs):
     x = ins["X"][0]
-    times = attrs["expand_times"]
-    return {"Out": [jnp.tile(x, times)]}
+    times = tuple(int(t) for t in attrs["expand_times"])
+    return {"Out": [_tile_copies(x, times, tuple(x.shape))]}
 
 
 @register("expand_as")

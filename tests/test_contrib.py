@@ -295,6 +295,206 @@ def test_amp_trunk_keeps_bf16_through_bn_relu_pool():
     np.testing.assert_allclose(amp, f32, rtol=0.2, atol=0.05)
 
 
+# --- the ops that only move values (split, concat, expand) ---------------------
+def _split_net():
+    """One input, three outputs: one feeds a transparent op, one a
+    computing op, one a bfloat16-list op."""
+    x = layers.data("x", shape=[6, 8])
+    a, b, c = layers.split(
+        layers.fc(x, 24, num_flatten_dims=2, bias_attr=False), [8, 8, 8],
+        dim=-1)
+    a = layers.reshape(a, [-1, 6, 2, 4])
+    b = layers.swish(b)
+    c = layers.fc(c, 8, num_flatten_dims=2, bias_attr=False)
+    return layers.mean(layers.reshape(a, [-1, 6, 8]) * b + c)
+
+
+def _concat_net(second_is_half):
+    x = layers.data("x", shape=[6, 8])
+    a = layers.fc(x, 8, num_flatten_dims=2, bias_attr=False)
+    b = (layers.fc(x, 4, num_flatten_dims=2, bias_attr=False)
+         if second_is_half else layers.scale(x, scale=0.5))
+    y = layers.fc(layers.concat([a, b], axis=-1), 8, num_flatten_dims=2,
+                  bias_attr=False)
+    return layers.mean(y)
+
+
+def _expand_net():
+    x = layers.data("x", shape=[6, 8])
+    a = layers.reshape(layers.fc(x, 8, num_flatten_dims=2, bias_attr=False),
+                       [-1, 6, 1, 8])
+    y = layers.fc(layers.reshape(layers.expand(a, [1, 1, 3, 1]),
+                                 [-1, 6, 24]), 8, num_flatten_dims=2,
+                  bias_attr=False)
+    return layers.mean(y)
+
+
+def _mamba2_shaped_net():
+    """fc -> split -> causal_conv -> split -> reshape / transpose ->
+    mamba2_scan, gated by the float32 z: the shape of
+    models/nemotron_h._mamba2 at toy widths."""
+    from paddle_tpu.initializer import Constant
+
+    heads, p, g, n, t = 2, 4, 1, 4, 8
+    inner = heads * p
+
+    def lead(y, count, width):
+        return layers.transpose(layers.reshape(y, [-1, t, count, width]),
+                                [0, 2, 1, 3])
+
+    def number_a_head(name, value):
+        return layers.create_parameter(
+            [heads], "float32", attr=fluid.ParamAttr(
+                name=name, initializer=Constant(value)))
+
+    x = layers.data("x", shape=[t, 8])
+    z, xbc, dt = layers.split(
+        layers.fc(x, 2 * inner + 2 * g * n + heads, num_flatten_dims=2,
+                  bias_attr=False), [inner, inner + 2 * g * n, heads], dim=-1)
+    xbc = layers.causal_conv(xbc, 4, act="silu")
+    xs, bm, cm = layers.split(xbc, [inner, g * n, g * n], dim=-1)
+    dt = layers.softplus(layers.elementwise_add(
+        layers.cast(layers.transpose(dt, [0, 2, 1]), "float32"),
+        number_a_head("dt_bias", 0.5), axis=1))
+    y = layers.mamba2_scan(
+        lead(xs, heads, p), dt,
+        layers.scale(layers.exp(number_a_head("a_log", 0.1)), scale=-1.0),
+        lead(bm, g, n), lead(cm, g, n), number_a_head("skip", 1.0))
+    y = layers.elementwise_mul(
+        layers.reshape(layers.transpose(y, [0, 2, 1, 3]), [-1, t, inner]),
+        layers.swish(z))
+    return layers.mean(layers.fc(y, 8, num_flatten_dims=2, bias_attr=False))
+
+
+_ROUNDINGS_KEPT = "--xla_allow_excess_precision=false"
+_MOVE_NETS = {
+    "split": (_split_net, {"split": 1}),
+    "concat": (lambda: _concat_net(True), {"concat": 1}),
+    "concat_with_a_float32_input": (lambda: _concat_net(False), {}),
+    "expand": (_expand_net, {"expand": 1}),
+    "mamba2_block": (_mamba2_shaped_net, {"split": 2}),
+}
+
+
+def _amp_program(net, hold_out, monkeypatch):
+    """The net under rewrite_bf16 with its backward, its loss and every
+    parameter gradient on one batch; `hold_out`: the three move ops taken
+    out of the pass's table first (what the pass did before it knew them)."""
+    from paddle_tpu.contrib import mixed_precision as mp
+
+    main, startup = fluid.Program(), fluid.Program()
+    with monkeypatch.context() as patch, \
+            fluid.framework.program_guard(main, startup), \
+            fluid.unique_name.guard():
+        if hold_out:
+            patch.setattr(mp, "_TRANSPARENT_OPS", {
+                k: v for k, v in mp._TRANSPARENT_OPS.items()
+                if k not in mp._MOVE_OPS})
+        startup.random_seed = 5
+        loss = net()
+        mp.rewrite_bf16(main)
+        fluid.backward.append_backward(loss)
+    params = sorted(p.name for p in main.global_block().all_parameters())
+    x = np.random.RandomState(7).randn(3, *main.global_block().var(
+        "x").shape[1:]).astype("float32")
+    with fluid.scope_guard(fluid.Scope()):
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        out = exe.run(main, feed={"x": x}, fetch_list=[loss] + [
+            main._grad_names[n] for n in params])
+    return main, dict(zip(["loss"] + params, out))
+
+
+@pytest.mark.parametrize("case", sorted(_MOVE_NETS))
+def test_amp_move_ops_run_in_the_dtype_their_data_arrives_in(
+        case, monkeypatch):
+    """split, concat and expand read `@RAW_BF16` names where every input
+    is the cast-back of a half tensor, and write half vars of their own; a
+    concat with one float32 input stays float32; the pass leaves the count
+    by op type on the Program."""
+    from paddle_tpu import analysis
+    from paddle_tpu.contrib import mixed_precision as mp
+
+    net, want = _MOVE_NETS[case]
+    main, _ = _amp_program(net, False, monkeypatch)
+    block = main.global_block()
+    flipped = getattr(main, "_amp_half_flipped", {})
+    assert {t: flipped.get(t, 0) for t in mp._MOVE_OPS} == {
+        t: want.get(t, 0) for t in mp._MOVE_OPS}
+    for op in block.ops:
+        if op.type not in mp._MOVE_OPS:
+            continue
+        names = op.inputs["X"] + op.outputs["Out"]
+        if want:
+            assert all(n.endswith("@RAW_BF16") for n in names), names
+            assert {str(block.var(n).dtype) for n in names} == {"bfloat16"}
+        else:
+            assert not any("@RAW_BF16" in n for n in names), names
+            assert {str(block.var(n).dtype) for n in names} == {"float32"}
+    assert not [d for d in analysis.verify_program(main) if d.is_error]
+    if case == "split":
+        # the transparent consumer flips, the bfloat16-list consumer reads
+        # the half output with no cast between, the computing consumer
+        # reads the float32 cast-back
+        (split,) = [op for op in block.ops if op.type == "split"]
+        a, b, c = split.outputs["Out"]
+        readers = {n: sorted(
+            op.type for op in block.ops
+            if op.attrs.get("op_role", "forward") == "forward"
+            and n in op.input_arg_names()) for n in (a, b, c)}
+        assert readers[a] == ["cast", "reshape2"]
+        assert readers[b] == ["cast"]
+        assert readers[c] == ["cast", "mul"]
+        (swish,) = [op for op in block.ops if op.type == "swish"]
+        assert str(block.var(swish.inputs["X"][0]).dtype) == "float32"
+
+
+@pytest.mark.parametrize("case", sorted(_MOVE_NETS))
+def test_amp_move_ops_change_no_value(case, monkeypatch):
+    """Rounding to bfloat16 commutes with a slice, a concatenation and a
+    tiling: the loss and every parameter gradient are EQUAL, array for
+    array, to those of the same Program rewritten with the three ops held
+    out of the table."""
+    net, _ = _MOVE_NETS[case]
+    _, got = _amp_program(net, False, monkeypatch)
+    held, want = _amp_program(net, True, monkeypatch)
+    assert not getattr(held, "_amp_half_flipped", {}).keys() & {
+        "split", "concat", "expand"}
+    assert sorted(got) == sorted(want) and len(got) > 1
+    # with every rounding kept (the test below) the two Programs fuse into
+    # other float32 reductions: the last bit of a float32 sum may differ,
+    # 2^12 times under one bfloat16 ulp
+    rtol = 2.0 ** -20 if _ROUNDINGS_KEPT in os.environ.get(
+        "XLA_FLAGS", "") else 0.0
+    for name in want:
+        assert np.asarray(got[name]).dtype == np.float32, name
+        assert np.any(np.asarray(want[name]) != 0), name
+        np.testing.assert_allclose(np.asarray(got[name]),
+                                   np.asarray(want[name]), rtol=rtol, atol=0,
+                                   err_msg=name)
+
+
+def test_amp_move_ops_change_no_value_with_every_rounding_kept():
+    """XLA may drop a float32 -> bfloat16 -> float32 pair
+    (`xla_allow_excess_precision`, on by default: on this host the
+    cast-back of a matmul's bfloat16 result then reads the float32
+    accumulator), on BOTH sides of the comparison above.  In a process of
+    its own with every rounding kept, the equality is the Program's."""
+    import subprocess
+    import sys
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu", XLA_FLAGS=(
+        os.environ.get("XLA_FLAGS", "") + " " + _ROUNDINGS_KEPT))
+    out = subprocess.run(
+        [sys.executable, "-m", "pytest", os.path.abspath(__file__), "-q",
+         "-p", "no:cacheprovider", "-k",
+         "test_amp_move_ops_change_no_value and not rounding_kept"],
+        env=env, cwd=os.path.dirname(os.path.dirname(os.path.abspath(
+            __file__))), capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-2000:]
+    assert "%d passed" % len(_MOVE_NETS) in out.stdout, out.stdout[-500:]
+
+
 def test_amp_trunk_keeps_bf16_through_transformer_chain():
     """The transformer-block chain (mul -> broadcast bias add -> reshape2
     -> transpose2 -> dropout -> layer_norm -> residual add) stays bf16:

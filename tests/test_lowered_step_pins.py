@@ -165,7 +165,28 @@ The seventeen digests and counts above did NOT move: `moe_ffn` without
 `expert_act` lowers through the SwiGLU body it lowered through (the op's
 jitted loops keep their names and take the body as one more static
 argument), `causal_conv` without a `Bias` to the text it lowered to, and
-`rms_norm` with a gain of one axis likewise."""
+`rms_norm` with a gain of one axis likewise.
+
+PR 58 let the AMP pass carry bfloat16 through the ops that only move values
+(contrib/mixed_precision._MOVE_OPS: `split`, `concat`, `expand` run in the
+dtype their data arrives in; `expand` sums its gradient's copies in float32
+and rounds once whatever its dtype, ops/tensor_ops._tile_copies), so the six
+programs whose built Program holds such an op behind a bfloat16 cast-back
+changed on purpose, in dtypes and `convert`s alone, and their digests are
+re-taken from PR 58's tree by this file's `_digest`: `nemotron_h` (both
+splits of every Mamba-2 mixer, the grouped keys' and values' `expand`),
+`qwen3_next` (the split after every GDN convolution, the splits of the
+partly rotated query and key, the grouped values' `expand`), `kanana2`
+(latent attention's three splits a layer; its `concat`s keep a float32
+`rotary_embed` input and stay), `kimi_linear` (its latent layer's two
+splits, the rotary-free key's `expand` and `concat`), `trinity` and `lfm2`
+(the `expand` of the grouped keys and values that no `rotary_embed` stands
+before).  No Mosaic count moved.  The other twelve digests did
+NOT move, and that is the proof that the steps of the cells they stand
+for are the parent's: in `gpt2`, `olmoe`, `ouro`, `transformer` and
+`resnet` no `split`, `concat` or `expand` reads the cast-back of a
+bfloat16 value, and a float32 `expand` (`kanana2`'s rotated key among
+them) lowers to the text `jnp.tile` and its gradient lowered to."""
 
 import base64
 import functools
@@ -333,19 +354,20 @@ PROGRAMS = {"gpt2": lambda: _lm(gpt2.gpt2_lm_program, G),
 # at PR 50; every program and core with a causal flash kernel: at PR 53; the
 # three UNTOUCHED cores: at ba67ef1, PR 53's parent; the four cores whose
 # causal kernels walk several blocks of the full grid, `piece_diagonal_chunk`
-# among them: at PR 56; `nemotron_h`: added at PR 57)
+# among them: at PR 56; `nemotron_h`: added at PR 57; the six programs whose
+# AMP rewrite flips a `split`, `concat` or `expand`: at PR 58)
 BEFORE = {
-    "nemotron_h": ("e3fb7a83c14d14a8acce54e62588e6cc395bb2a5", 18),
-    "qwen3_next": ("066aa16bdf09bc9d5c356dda010c95375aae93b6", 27),
-    "kimi_linear": ("0b6cea6313d097ed575295ef51fd8b563347239c", 21),
+    "nemotron_h": ("424a80a8933a0ec7ee89c4ece3eeca9006e18e92", 18),
+    "qwen3_next": ("3d4b8d2d56c5035594075b0508a3614e286234ce", 27),
+    "kimi_linear": ("3e0377967224298932fcd5be83fe7ce7f59a2b5b", 21),
     "transformer": ("e83306d8f28e59df41bc44b9fe7303322f29a58b", 0),
     "resnet": ("84575b13d140437bb64cb8461436105337774a6d", 0),
     "ouro": ("0057fcbecadc1719a1de27cb3b94bb41ac569650", 3),
-    "kanana2": ("f4a9b6486a2b6513c06bb50fec5aeb98be334a10", 9),
-    "trinity": ("393382caa36c2983f2c696aab8920cbea225d4e7", 12),
+    "kanana2": ("58a5bd2363cca5fb23fea690fbcbdda362a0ee97", 9),
+    "trinity": ("1376e7973e2fe1f118d8b4c0310391977cccd060", 12),
     "gpt2": ("bfdcbc6f62d3aaf4418dc9bf22e0aaf9dc8a8a4a", 3),
     "olmoe": ("8fc96fbebcde6d156165e9b26443399f6b36494f", 9),
-    "lfm2": ("25c451b8998c9bd5e6e4808f9b598d69a27d779a", 9),
+    "lfm2": ("3def3cb90e0a1051d6b1d7f46e50389e659a824d", 9),
     "two_kernel_backward": ("11298a99a31b05beda8f898f4d78ff6e1a7258d4", 3),
     "full_causal_8192": ("5b5142af7fe40d858d5b144ad6aa1e2ff0328207", 2),
     "window_covers_8192": ("06a0539acce2ff8f9fe3049ed5f52419b7a27c44", 2),
