@@ -18,15 +18,13 @@ option combination unchanged on a {dp, mp} mesh.
 import numpy as np
 
 from .. import layers, unique_name
-from ..initializer import Normal
-from ..param_attr import ParamAttr
+from . import transformer as tfm
+from .decoder import fc, lm_train_program, weight, xent_cost
 
 __all__ = [
     "GPT2Config",
     "gpt2_lm",
     "gpt2_lm_program",
-    "lm_train_program",
-    "xent_cost",
     "gpt2_logits_program",
     "greedy_generate",
     "greedy_generate_cached",
@@ -65,19 +63,11 @@ class GPT2Config:
     partition_family = "gpt2"
 
 
-def _pa(base, std=0.02):
-    return ParamAttr(
-        name=unique_name.generate(base), initializer=Normal(0.0, std)
-    )
-
-
 def _attn(x, hp, is_test, cache=None):
     """Causal self-attention via the shared transformer block (same graph,
     same mha_* param names, one fused-path implementation to maintain).
     With `cache`, x is the single current token and causality comes from
     the cache's <=pos mask instead of the causal flag."""
-    from . import transformer as tfm
-
     return tfm.multi_head_attention(
         x, x, x, None, hp.d_model, hp.n_head, dropout_rate=0.0,
         is_test=is_test, fused=True, causal=cache is None, cache=cache,
@@ -101,19 +91,15 @@ def _block(x, hp, is_test, cache=None):
         hid = int(4 * hp.d_model * 2 // 3)
         mult = int(getattr(hp, "ffn_multiple_of", 1) or 1)
         hid = ((hid + mult - 1) // mult) * mult
-        gate = layers.fc(ln, size=hid, num_flatten_dims=2,
-                         act="swish", bias_attr=False,
-                         param_attr=_pa("ffn_gate.w"))
-        up = layers.fc(ln, size=hid, num_flatten_dims=2, bias_attr=False,
-                       param_attr=_pa("ffn_up.w"))
-        h = layers.elementwise_mul(gate, up)
+        h = layers.elementwise_mul(fc(ln, hid, "ffn_gate.w", act="swish"),
+                                   fc(ln, hid, "ffn_up.w"))
     else:
         h = layers.fc(
             ln, size=4 * hp.d_model, num_flatten_dims=2, act="gelu",
-            param_attr=_pa("ffn_in.w"), bias_attr=_pa("ffn_in.b"),
+            param_attr=weight("ffn_in.w"), bias_attr=weight("ffn_in.b"),
         )
     h = layers.fc(h, size=hp.d_model, num_flatten_dims=2,
-                  param_attr=_pa("ffn_out.w"))
+                  param_attr=weight("ffn_out.w"))
     if hp.dropout and not is_test:
         h = layers.dropout(h, hp.dropout, is_test=is_test)
     return layers.elementwise_add(x, h)
@@ -128,13 +114,12 @@ def _tied_logits(x, hp, emb_name):
 
         w = framework.default_main_program().global_block().var(emb_name)
         return layers.matmul(x, w, transpose_y=True)
-    return layers.fc(x, size=hp.vocab_size, num_flatten_dims=2,
-                     bias_attr=False, param_attr=_pa("softmax_out.w"))
+    return fc(x, hp.vocab_size, "softmax_out.w")
 
 
 def gpt2_lm(ids, hp=GPT2Config, is_test=False):
     """[B, T] token ids -> [B, T, vocab] next-token logits."""
-    emb_attr = _pa("emb.w")
+    emb_attr = weight("emb.w")
     tok = layers.embedding(
         ids, size=[hp.vocab_size, hp.d_model], param_attr=emb_attr
     )
@@ -143,7 +128,7 @@ def gpt2_lm(ids, hp=GPT2Config, is_test=False):
     else:
         pos_table = layers.create_parameter(
             shape=[hp.n_ctx, hp.d_model], dtype="float32",
-            attr=_pa("pos_emb.w", 0.01)
+            attr=weight("pos_emb.w", 0.01)
         )
         T = ids.shape[1]
         pos = layers.slice(pos_table, axes=[0], starts=[0], ends=[T])
@@ -178,73 +163,6 @@ def gpt2_lm_program(hp=GPT2Config, seq_len=128, lr=3e-4, is_test=False,
                              None),
         seq_len, lr, is_test, use_bf16, mesh,
         getattr(hp, "partition_family", "gpt2"))
-
-
-def xent_cost(logits, labels):
-    """[B, T, vocab] logits and [B, T] labels -> the [B, T, 1]
-    cross-entropy of every token: what a trunk with one set of logits
-    returns as its cost."""
-    return layers.softmax_with_cross_entropy(
-        logits, layers.unsqueeze(labels, [2])
-    )
-
-
-def lm_train_program(trunk, seq_len, lr, is_test, use_bf16, mesh,
-                     partition_family):
-    """The causal-LM train-program plumbing every decoder-only builder
-    shares (gpt2_lm_program, olmoe.olmoe_lm_program): feeds, the weighted
-    token cross-entropy, the fuse passes, AMP, remat, Adam and the mesh
-    stamp.  `trunk(ids, labels)` builds the model and returns ([B, T, 1]
-    cost of every token, extra) where extra is a scalar var added to the
-    loss (a mixture's router losses) or None; a trunk with one set of
-    logits ends in `xent_cost(logits, labels)`, ouro's in its expected
-    loss over the exit steps."""
-    import paddle_tpu as fluid
-
-    main = fluid.Program()
-    startup = fluid.Program()
-    with fluid.program_guard(main, startup), unique_name.guard():
-        ids = layers.data("ids", shape=[seq_len], dtype="int64")
-        lbl = layers.data("labels", shape=[seq_len], dtype="int64")
-        w = layers.data("loss_weight", shape=[seq_len], dtype="float32")
-
-        cost, extra = trunk(ids, lbl)
-        cost = layers.elementwise_mul(cost, layers.unsqueeze(w, [2]))
-        tokens = layers.reduce_sum(w)
-        # epsilon guard: an all-pad batch yields loss 0, never 0/0 NaN
-        loss = layers.elementwise_div(
-            layers.reduce_sum(cost), layers.clip(tokens, 1e-5, 1e30)
-        )
-        if extra is not None:
-            loss = layers.elementwise_add(loss, extra)
-
-        # logits-free fused cross-entropy (fused_linear_xent lowers to
-        # linear_xent_tiled: the [B, T, V] f32 logits exist a vocabulary
-        # tile at a time) + the fc / fused_swiglu / fused_residual_ln
-        # ops for the FFN/residual-LN chains (one dense lowering each,
-        # their epilogues fused by XLA) — both BEFORE minimize so grads
-        # differentiate through the fused ops
-        from ..transpiler.pass_registry import apply_pass
-
-        apply_pass(main, "linear_xent_fuse_pass")
-        apply_pass(main, "matmul_epilogue_fuse_pass")
-        if use_bf16:
-            apply_pass(main, "bf16_amp_pass")
-        # HBM-budgeted remat (FLAGS_hbm_budget_bytes; no-op when unset);
-        # the flag is a per-device budget, so a mesh scales it
-        from ..transpiler.remat import maybe_remat
-
-        maybe_remat(main, loss, is_test, mesh=mesh)
-        if not is_test:
-            fluid.optimizer.Adam(learning_rate=lr).minimize(loss)
-
-    if mesh is not None:
-        from ..parallel.partition_rules import (annotate_spmd,
-                                                train_partition_rules_for)
-
-        annotate_spmd(main, mesh,
-                      train_partition_rules_for(partition_family))
-    return main, startup, ["ids", "labels", "loss_weight"], [loss, tokens]
 
 
 def make_fake_lm_batch(batch_size, seq_len, hp=GPT2Config, seed=0):
@@ -318,7 +236,7 @@ def gpt2_decode_step_program(hp=GPT2Config, batch=1, t_max=None, width=1,
         if width > 1:
             pos_vec = layers.data("pos_vec", shape=[width], dtype="int64",
                                   append_batch_size=False)
-        emb_attr = _pa("emb.w")
+        emb_attr = weight("emb.w")
         tok = layers.embedding(
             ids, size=[hp.vocab_size, hp.d_model], param_attr=emb_attr
         )  # [B, W, D] (W == 1 squeezes in the lookup)
@@ -328,7 +246,7 @@ def gpt2_decode_step_program(hp=GPT2Config, batch=1, t_max=None, width=1,
         else:
             pos_table = layers.create_parameter(
                 shape=[hp.n_ctx, hp.d_model], dtype="float32",
-                attr=_pa("pos_emb.w", 0.01),
+                attr=weight("pos_emb.w", 0.01),
             )
             if width == 1:
                 pos_row = layers.reshape(layers.gather(pos_table, pos),
@@ -425,7 +343,7 @@ def gpt2_ragged_step_program(hp=GPT2Config, batch=4, t_max=None, width=8,
                                  append_batch_size=False)
         pos_mat = layers.data("pos_mat", shape=[batch, width],
                               dtype="int64", append_batch_size=False)
-        emb_attr = _pa("emb.w")
+        emb_attr = weight("emb.w")
         tok = layers.embedding(
             ids, size=[hp.vocab_size, hp.d_model], param_attr=emb_attr
         )
@@ -435,7 +353,7 @@ def gpt2_ragged_step_program(hp=GPT2Config, batch=4, t_max=None, width=8,
         else:
             pos_table = layers.create_parameter(
                 shape=[hp.n_ctx, hp.d_model], dtype="float32",
-                attr=_pa("pos_emb.w", 0.01),
+                attr=weight("pos_emb.w", 0.01),
             )
             pos_emb = layers.gather(pos_table, pos_mat)  # [B, W, D]
             x = layers.elementwise_add(tok, pos_emb)

@@ -25,25 +25,22 @@ No bias anywhere.
            MLP of n_shared_experts x moe_intermediate_size (the published
            code builds them so), computed alike on every chip.
 
-The train-program plumbing is `gpt2.lm_train_program`;
+The train-program plumbing is `decoder.lm_train_program`;
 `kanana2_reference.py` is the plain float32 statement of the same
 equations.
 """
 
-from .. import framework, layers
-from ..layer_helper import LayerHelper
+from .. import layers
 from . import transformer as tfm
-from .gpt2 import _pa, lm_train_program, xent_cost
-from .lfm2 import balance_expert_biases
+from .decoder import (EXPERT_BIAS_STD, beside_shared, fc, lm_train_program,
+                      norm_or_weight, routed_experts, swiglu_mlp, weight,
+                      xent_cost)
 
 __all__ = ["Kanana2Config", "kanana2_lm", "kanana2_lm_program"]
 
 # e_score_correction_bias is a buffer in the published modeling code, zero
 # at initialisation, and the training rule that moves it is not in the
-# config.  Here it is seeded non-zero, so that selection (score + bias)
-# and weights (score alone) differ from the first step, and balanced after
-# every training step, as lfm2's expert_bias (models/lfm2.py).
-_EXPERT_BIAS_STD = 0.1
+# config: seeded and balanced as `decoder.EXPERT_BIAS_STD` says.
 # what the family adds to the chosen scores' sum before it divides
 _NORM_TOPK_EPS = 1e-20
 # what a forward-only program leaves in the scope: every token's
@@ -91,11 +88,6 @@ class Kanana2Config:
     partition_family = "gpt2"
 
 
-def _weight(base):
-    """normal(0, 0.02) for a matrix, ones for a norm's gain."""
-    return tfm._pa(base) if "norm" in base else _pa(base)
-
-
 def _check(hp):
     """What the builder would have to guess, it refuses."""
     if hp.n_group != 1 or hp.topk_group != 1:
@@ -127,52 +119,38 @@ def _check(hp):
             % (hp.num_key_value_heads, hp.num_attention_heads))
 
 
-def _swiglu_mlp(h, width, d, prefix):
-    """The three `fc` ops the fuse pass turns into `fused_swiglu`."""
-    gate = layers.fc(h, size=width, num_flatten_dims=2, act="swish",
-                     bias_attr=False, param_attr=_pa(prefix + "_gate.w"))
-    up = layers.fc(h, size=width, num_flatten_dims=2, bias_attr=False,
-                   param_attr=_pa(prefix + "_up.w"))
-    return layers.fc(layers.elementwise_mul(gate, up), size=d,
-                     num_flatten_dims=2, bias_attr=False,
-                     param_attr=_pa(prefix + "_out.w"))
-
-
 def _experts(h, hp, is_test):
-    routed, _, _ = layers.moe_ffn(
-        h, hp.n_routed_experts, hp.moe_intermediate_size,
+    routed, _ = routed_experts(
+        h, is_test, hp.n_routed_experts, hp.moe_intermediate_size,
         hp.num_experts_per_tok, norm_topk_prob=hp.norm_topk_prob,
         router="sigmoid",
-        expert_bias_attr=_pa("moe_e_score_correction_bias.b",
-                             std=_EXPERT_BIAS_STD),
+        expert_bias_attr=weight("moe_e_score_correction_bias.b",
+                                EXPERT_BIAS_STD),
         num_local_experts=hp.num_local_experts,
         expert_offset=hp.expert_offset,
         routed_scaling_factor=hp.routed_scaling_factor,
-        norm_topk_eps=_NORM_TOPK_EPS,
-        router_attr=_pa("moe_router.w"), gate_up_attr=_pa("moe_gate_up.w"),
-        down_attr=_pa("moe_down.w"),
-        stat_name=("moe_tokens_per_expert_eval" if is_test
-                   else "moe_tokens_per_expert"))
-    if not hp.n_shared_experts:
-        return routed
-    with framework.name_scope("shared_expert"):
-        shared = _swiglu_mlp(
-            h, hp.n_shared_experts * hp.moe_intermediate_size,
-            hp.hidden_size, "shared_ffn")
-        return layers.elementwise_add(shared, routed)
+        norm_topk_eps=_NORM_TOPK_EPS)
+
+    def shared(h):
+        return swiglu_mlp(h, hp.n_shared_experts * hp.moe_intermediate_size,
+                          hp.hidden_size, "shared_ffn")
+
+    return beside_shared(h, routed, shared if hp.n_shared_experts else None)
 
 
 def _block(x, hp, i, is_test):
     h = layers.rms_norm(x, hp.rms_norm_eps,
-                        param_attr=tfm._pa("attn_norm.w"))
+                        param_attr=tfm.named("attn_norm.w"))
     a = tfm.latent_attention(
         h, hp.num_attention_heads, hp.kv_lora_rank, hp.qk_nope_head_dim,
         hp.qk_rope_head_dim, hp.v_head_dim, norm_eps=hp.rms_norm_eps,
         rotary_base=float(hp.rope_theta),
-        rotary_interleaved=bool(hp.rope_interleave), param_attr=_weight)
+        rotary_interleaved=bool(hp.rope_interleave),
+        param_attr=norm_or_weight)
     x = layers.elementwise_add(x, a)
-    h = layers.rms_norm(x, hp.rms_norm_eps, param_attr=tfm._pa("ffn_norm.w"))
-    m = (_swiglu_mlp(h, hp.intermediate_size, hp.hidden_size, "ffn")
+    h = layers.rms_norm(x, hp.rms_norm_eps,
+                        param_attr=tfm.named("ffn_norm.w"))
+    m = (swiglu_mlp(h, hp.intermediate_size, hp.hidden_size, "ffn")
          if i < hp.first_k_dense_replace else _experts(h, hp, is_test))
     return layers.elementwise_add(x, m)
 
@@ -184,30 +162,12 @@ def kanana2_lm(ids, hp=Kanana2Config, is_test=False):
     if hp.tie_word_embeddings:
         raise NotImplementedError("the published head is untied")
     x = layers.embedding(ids, size=[hp.vocab_size, hp.hidden_size],
-                         param_attr=_pa("emb.w"))
+                         param_attr=weight("emb.w"))
     for i in range(hp.num_hidden_layers):
         x = _block(x, hp, i, is_test)
     x = layers.rms_norm(x, hp.rms_norm_eps,
-                        param_attr=tfm._pa("final_norm.w"))
-    return layers.fc(x, size=hp.vocab_size, num_flatten_dims=2,
-                     bias_attr=False, param_attr=_pa("softmax_out.w"))
-
-
-def leave_eval_rows(cost, name, seq_len):
-    """Every token's cost, [B, T, 1], left in the scope as the persistable
-    [B, T] float32 `name` (what a forward-only program hands an evaluation
-    that pairs rows with a reference's)."""
-    rows = LayerHelper(name).create_global_variable(
-        name=name, persistable=True, dtype="float32", shape=[-1, seq_len])
-    rows.stop_gradient = True
-    layers.assign(layers.reshape(cost, [-1, seq_len]), output=rows)
-
-
-def _token_cost(ids, labels, hp, seq_len, is_test):
-    cost = xent_cost(kanana2_lm(ids, hp, is_test), labels)  # [B, T, 1]
-    if is_test:
-        leave_eval_rows(cost, EVAL_ROWS, seq_len)
-    return cost
+                        param_attr=tfm.named("final_norm.w"))
+    return fc(x, hp.vocab_size, "softmax_out.w")
 
 
 def kanana2_lm_program(hp=Kanana2Config, seq_len=4096, lr=4e-4,
@@ -216,10 +176,8 @@ def kanana2_lm_program(hp=Kanana2Config, seq_len=4096, lr=4e-4,
     returns them; a training step ends with the selection biases'
     balancing step, as lfm2_lm_program's; an `is_test` program leaves
     every token's cost in the scope under EVAL_ROWS."""
-    main, startup, feeds, fetches = lm_train_program(
-        lambda ids, labels: (_token_cost(ids, labels, hp, seq_len, is_test),
+    return lm_train_program(
+        lambda ids, labels: (xent_cost(kanana2_lm(ids, hp, is_test), labels),
                              None),
-        seq_len, lr, is_test, use_bf16, mesh, hp.partition_family)
-    if not is_test:
-        balance_expert_biases(main)
-    return main, startup, feeds, fetches
+        seq_len, lr, is_test, use_bf16, mesh, hp.partition_family,
+        eval_rows=EVAL_ROWS)

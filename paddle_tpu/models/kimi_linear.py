@@ -36,35 +36,26 @@ head.  No bias on any projection, no position encoding anywhere.
         `num_shared_experts` x `moe_intermediate_size` wide, under
         `shared_expert`, computed alike on every chip.
 
-The train-program plumbing is `gpt2.lm_train_program`;
+The train-program plumbing is `decoder.lm_train_program`;
 `kimi_linear_reference.py` is the plain float32 statement of the same
 equations, with KDA as the token-by-token recurrence.
 """
 
-import math
-
 from .. import framework, layers
-from ..initializer import Initializer, Uniform
 from ..param_attr import ParamAttr
 from . import transformer as tfm
-from .gpt2 import _pa, lm_train_program, xent_cost
-from .kanana2 import _swiglu_mlp, _weight, leave_eval_rows
-from .lfm2 import balance_expert_biases
+from .decoder import (A_RANGE, DT_RANGE, EXPERT_BIAS_STD, L2_EPS,
+                      InverseSoftplusOfLogUniform, LogUniform, beside_shared,
+                      fc, lm_train_program, norm_or_weight, routed_experts,
+                      swiglu_mlp, weight, xent_cost)
 
 __all__ = ["KimiLinearConfig", "kimi_linear_lm", "kimi_linear_lm_program"]
 
 # e_score_correction_bias is a buffer in the published modeling code, zero
-# at initialisation; the rule that moves it in training is the trainer's.
-# Seeded non-zero and balanced after every training step, as kanana2's.
-_EXPERT_BIAS_STD = 0.1
+# at initialisation; the rule that moves it in training is the trainer's:
+# seeded and balanced as `decoder.EXPERT_BIAS_STD` says.
 # what the published gate adds to the chosen scores' sum before it divides
 _NORM_TOPK_EPS = 1e-20
-# the published l2norm's epsilon (inside the square root, per head)
-_L2_EPS = 1e-6
-# the published initialisation of the decay: A = uniform(1, 16) a head,
-# dt = exp(uniform(log 0.001, log 0.1)) a channel, dt_bias = softplus^-1(dt)
-_A_RANGE = (1.0, 16.0)
-_DT_RANGE = (0.001, 0.1)
 # what a forward-only program leaves in the scope: every token's
 # cross-entropy, [B, T] float32 (an evaluation pairs it with a reference's)
 EVAL_ROWS = "kimi_linear_eval_rows"
@@ -111,39 +102,6 @@ class KimiLinearConfig:
     num_local_experts = None
     expert_offset = 0
     partition_family = "gpt2"
-
-
-class _LogUniform(Initializer):
-    """log of a uniform(low, high) draw: A_log."""
-
-    def __init__(self, low, high):
-        self.draw = Uniform(low, high)
-
-    def __call__(self, var, block):
-        self.draw(var, block)
-        return block.append_op("log", inputs={"X": [var]},
-                               outputs={"Out": [var]})
-
-
-class _InverseSoftplusOfLogUniform(Initializer):
-    """softplus^-1(dt) = log(exp(dt) - 1) of dt = exp(uniform(log low,
-    log high)), or of max(dt, floor) where a floor is given (Mamba-2's
-    `time_step_floor`): dt_bias."""
-
-    def __init__(self, low, high, floor=None):
-        self.draw = Uniform(math.log(low), math.log(high))
-        self.floor = floor
-
-    def __call__(self, var, block):
-        self.draw(var, block)
-        same = {"inputs": {"X": [var]}, "outputs": {"Out": [var]}}
-        block.append_op("exp", **same)
-        if self.floor is not None:
-            block.append_op("clip", attrs={"min": float(self.floor),
-                                           "max": 3.4e38}, **same)
-        block.append_op("exp", **same)
-        block.append_op("scale", attrs={"scale": 1.0, "bias": -1.0}, **same)
-        return block.append_op("log", **same)
 
 
 def mixer_of(hp, i):
@@ -196,11 +154,6 @@ def _check(hp):
         raise NotImplementedError("the published head is untied")
 
 
-def _fc(x, size, base, bias_attr=False):
-    return layers.fc(x, size=size, num_flatten_dims=2, bias_attr=bias_attr,
-                     param_attr=_pa(base))
-
-
 def _kda(h, hp):
     """h [B, T, d] -> [B, T, d]: one Kimi Delta Attention mixer."""
     la = hp.linear_attn_config
@@ -216,19 +169,19 @@ def _kda(h, hp):
 
     with framework.name_scope("kda"):
         with framework.name_scope("proj"):
-            q, k, v = (_fc(h, width, "kda_%s.w" % name) for name in "qkv")
-            decay = _fc(_fc(h, dh, "kda_f_a.w"), width, "kda_f_b.w",
-                        ParamAttr(
-                            name=framework.unique_name.generate("kda_dt.b"),
-                            initializer=_InverseSoftplusOfLogUniform(
-                                *_DT_RANGE)))
-            gate = _fc(_fc(h, dh, "kda_g_a.w"), width, "kda_g_b.w")
-            beta = _fc(h, n, "kda_b.w")
+            q, k, v = (fc(h, width, "kda_%s.w" % name) for name in "qkv")
+            decay = fc(fc(h, dh, "kda_f_a.w"), width, "kda_f_b.w",
+                       bias_attr=ParamAttr(
+                           name=framework.unique_name.generate("kda_dt.b"),
+                           initializer=InverseSoftplusOfLogUniform(*DT_RANGE)))
+            gate = fc(fc(h, dh, "kda_g_a.w"), width, "kda_g_b.w")
+            beta = fc(h, n, "kda_b.w")
         with framework.name_scope("conv"):
             q, k, v = (heads(layers.causal_conv(
-                y, taps, act="silu", param_attr=_pa("kda_%s_conv.w" % name)))
+                y, taps, act="silu",
+                param_attr=weight("kda_%s_conv.w" % name)))
                 for y, name in ((q, "q"), (k, "k"), (v, "v")))
-            q, k = (layers.l2_normalize(y, axis=-1, epsilon=_L2_EPS)
+            q, k = (layers.l2_normalize(y, axis=-1, epsilon=L2_EPS)
                     for y in (q, k))
             q, k, v = lead(q), lead(k), lead(v)
         with framework.name_scope("gate"):
@@ -236,7 +189,7 @@ def _kda(h, hp):
                 [n, 1], "float32",
                 attr=ParamAttr(
                     name=framework.unique_name.generate("kda_A_log.w"),
-                    initializer=_LogUniform(*_A_RANGE)))
+                    initializer=LogUniform(*A_RANGE)))
             g = lead(layers.elementwise_mul(
                 heads(layers.softplus(decay)),
                 layers.scale(layers.exp(a_log), scale=-1.0), axis=2))
@@ -246,10 +199,10 @@ def _kda(h, hp):
         with framework.name_scope("out"):
             o = layers.rms_norm(layers.transpose(o, [0, 2, 1, 3]),
                                 hp.rms_norm_eps,
-                                param_attr=tfm._pa("kda_o_norm.w"))
+                                param_attr=tfm.named("kda_o_norm.w"))
             o = layers.elementwise_mul(o, layers.sigmoid(heads(gate)))
-            return _fc(layers.reshape(o, [b, t, width]), hp.hidden_size,
-                       "kda_o.w")
+            return fc(layers.reshape(o, [b, t, width]), hp.hidden_size,
+                      "kda_o.w")
 
 
 def _mla(h, hp):
@@ -257,40 +210,36 @@ def _mla(h, hp):
         h, hp.num_attention_heads, hp.kv_lora_rank, hp.qk_nope_head_dim,
         hp.qk_rope_head_dim, hp.v_head_dim, norm_eps=hp.rms_norm_eps,
         rotary_base=float(hp.rope_theta), rotary_interleaved=True,
-        param_attr=_weight, rotary=not hp.mla_use_nope)
+        param_attr=norm_or_weight, rotary=not hp.mla_use_nope)
 
 
 def _experts(h, hp, is_test):
-    routed, _, _ = layers.moe_ffn(
-        h, hp.num_experts, hp.moe_intermediate_size,
+    routed, _ = routed_experts(
+        h, is_test, hp.num_experts, hp.moe_intermediate_size,
         hp.num_experts_per_token, norm_topk_prob=hp.moe_renormalize,
         router="sigmoid",
-        expert_bias_attr=_pa("moe_e_score_correction_bias.b",
-                             std=_EXPERT_BIAS_STD),
+        expert_bias_attr=weight("moe_e_score_correction_bias.b",
+                                EXPERT_BIAS_STD),
         num_local_experts=hp.num_local_experts,
         expert_offset=hp.expert_offset,
         routed_scaling_factor=hp.routed_scaling_factor,
-        norm_topk_eps=_NORM_TOPK_EPS,
-        router_attr=_pa("moe_router.w"), gate_up_attr=_pa("moe_gate_up.w"),
-        down_attr=_pa("moe_down.w"),
-        stat_name=("moe_tokens_per_expert_eval" if is_test
-                   else "moe_tokens_per_expert"))
-    if not hp.num_shared_experts:
-        return routed
-    with framework.name_scope("shared_expert"):
-        shared = _swiglu_mlp(
-            h, hp.num_shared_experts * hp.moe_intermediate_size,
-            hp.hidden_size, "shared_ffn")
-        return layers.elementwise_add(shared, routed)
+        norm_topk_eps=_NORM_TOPK_EPS)
+
+    def shared(h):
+        return swiglu_mlp(h, hp.num_shared_experts * hp.moe_intermediate_size,
+                          hp.hidden_size, "shared_ffn")
+
+    return beside_shared(h, routed, shared if hp.num_shared_experts else None)
 
 
 def _block(x, hp, i, is_test):
     h = layers.rms_norm(x, hp.rms_norm_eps,
-                        param_attr=tfm._pa("attn_norm.w"))
+                        param_attr=tfm.named("attn_norm.w"))
     a = _kda(h, hp) if mixer_of(hp, i) == "kda" else _mla(h, hp)
     x = layers.elementwise_add(x, a)
-    h = layers.rms_norm(x, hp.rms_norm_eps, param_attr=tfm._pa("ffn_norm.w"))
-    m = (_swiglu_mlp(h, hp.intermediate_size, hp.hidden_size, "ffn")
+    h = layers.rms_norm(x, hp.rms_norm_eps,
+                        param_attr=tfm.named("ffn_norm.w"))
+    m = (swiglu_mlp(h, hp.intermediate_size, hp.hidden_size, "ffn")
          if i < hp.first_k_dense_replace else _experts(h, hp, is_test))
     return layers.elementwise_add(x, m)
 
@@ -300,20 +249,12 @@ def kimi_linear_lm(ids, hp=KimiLinearConfig, is_test=False):
     its own matrix (`tie_word_embeddings` false)."""
     _check(hp)
     x = layers.embedding(ids, size=[hp.vocab_size, hp.hidden_size],
-                         param_attr=_pa("emb.w"))
+                         param_attr=weight("emb.w"))
     for i in range(hp.num_hidden_layers):
         x = _block(x, hp, i, is_test)
     x = layers.rms_norm(x, hp.rms_norm_eps,
-                        param_attr=tfm._pa("final_norm.w"))
-    return layers.fc(x, size=hp.vocab_size, num_flatten_dims=2,
-                     bias_attr=False, param_attr=_pa("softmax_out.w"))
-
-
-def _token_cost(ids, labels, hp, seq_len, is_test):
-    cost = xent_cost(kimi_linear_lm(ids, hp, is_test), labels)  # [B, T, 1]
-    if is_test:
-        leave_eval_rows(cost, EVAL_ROWS, seq_len)
-    return cost
+                        param_attr=tfm.named("final_norm.w"))
+    return fc(x, hp.vocab_size, "softmax_out.w")
 
 
 def kimi_linear_lm_program(hp=KimiLinearConfig, seq_len=8192, lr=5e-6,
@@ -325,10 +266,8 @@ def kimi_linear_lm_program(hp=KimiLinearConfig, seq_len=8192, lr=5e-6,
     the `expert_bias_update` op's `rate` and `max_step` where given); an
     `is_test` program leaves every token's cost in the scope under
     EVAL_ROWS."""
-    main, startup, feeds, fetches = lm_train_program(
-        lambda ids, labels: (_token_cost(ids, labels, hp, seq_len, is_test),
-                             None),
-        seq_len, lr, is_test, use_bf16, mesh, hp.partition_family)
-    if not is_test:
-        balance_expert_biases(main, bias_rate, bias_max_step)
-    return main, startup, feeds, fetches
+    return lm_train_program(
+        lambda ids, labels: (
+            xent_cost(kimi_linear_lm(ids, hp, is_test), labels), None),
+        seq_len, lr, is_test, use_bf16, mesh, hp.partition_family,
+        eval_rows=EVAL_ROWS, bias_rate=bias_rate, bias_max_step=bias_max_step)

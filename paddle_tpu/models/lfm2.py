@@ -23,22 +23,20 @@ the embedding, transposed.  No bias anywhere.
                   `num_local_experts` / `expert_offset` build one chip's
                   share of every expert layer (the router keeps its width).
 
-The train-program plumbing is `gpt2.lm_train_program`;
+The train-program plumbing is `decoder.lm_train_program`;
 `lfm2_reference.py` is the plain float32 statement of the same equations.
 """
 
 from .. import framework, layers
 from . import transformer as tfm
-from .gpt2 import _pa, lm_train_program, xent_cost
+from .decoder import (EXPERT_BIAS_STD, fc, lm_train_program, routed_experts,
+                      swiglu_mlp, weight, xent_cost)
 
 __all__ = ["LFM2MoEConfig", "lfm2_lm", "lfm2_lm_program"]
 
 # expert_bias is a buffer in the published modeling code, which has no
-# training rule; the family trains it as an adaptive routing bias.  Seeded
-# non-zero, so that selection (score + bias) and weights (score alone)
-# differ from the first step, and after every training step moved against
-# each expert's share of the load (`expert_bias_update`).
-_EXPERT_BIAS_STD = 0.1
+# training rule; the family trains it as an adaptive routing bias
+# (`decoder.EXPERT_BIAS_STD`, `decoder.balance_expert_biases`).
 
 
 class LFM2MoEConfig:
@@ -73,14 +71,12 @@ class LFM2MoEConfig:
 
 def _conv_operator(h, hp):
     d = hp.hidden_size
-    bcx = layers.fc(h, size=3 * d, num_flatten_dims=2, bias_attr=False,
-                    param_attr=_pa("conv_in.w"))
+    bcx = fc(h, 3 * d, "conv_in.w")
     # PyTorch's Conv1d default scale for L taps a channel
     y = layers.short_conv(
         bcx, hp.conv_L_cache,
-        param_attr=_pa("conv_filter.w", std=hp.conv_L_cache ** -0.5))
-    return layers.fc(y, size=d, num_flatten_dims=2, bias_attr=False,
-                     param_attr=_pa("conv_out.w"))
+        param_attr=weight("conv_filter.w", std=hp.conv_L_cache ** -0.5))
+    return fc(y, d, "conv_out.w")
 
 
 def _attention_operator(h, hp, is_test):
@@ -92,36 +88,23 @@ def _attention_operator(h, hp, is_test):
         qk_norm_eps=hp.norm_eps)
 
 
-def _dense_mlp(h, hp):
-    f = hp.intermediate_size
-    gate = layers.fc(h, size=f, num_flatten_dims=2, act="swish",
-                     bias_attr=False, param_attr=_pa("ffn_gate.w"))
-    up = layers.fc(h, size=f, num_flatten_dims=2, bias_attr=False,
-                   param_attr=_pa("ffn_up.w"))
-    return layers.fc(layers.elementwise_mul(gate, up), size=hp.hidden_size,
-                     num_flatten_dims=2, bias_attr=False,
-                     param_attr=_pa("ffn_out.w"))
-
-
 def _experts(h, hp, is_test):
-    bias = (_pa("moe_expert_bias.b", std=_EXPERT_BIAS_STD)
+    bias = (weight("moe_expert_bias.b", EXPERT_BIAS_STD)
             if hp.use_expert_bias else None)
-    y, _, _ = layers.moe_ffn(
-        h, hp.num_experts, hp.moe_intermediate_size, hp.num_experts_per_tok,
-        norm_topk_prob=hp.norm_topk_prob, router="sigmoid",
-        expert_bias_attr=bias, num_local_experts=hp.num_local_experts,
+    routed, _ = routed_experts(
+        h, is_test, hp.num_experts, hp.moe_intermediate_size,
+        hp.num_experts_per_tok, norm_topk_prob=hp.norm_topk_prob,
+        router="sigmoid", expert_bias_attr=bias,
+        num_local_experts=hp.num_local_experts,
         expert_offset=hp.expert_offset,
-        routed_scaling_factor=hp.routed_scaling_factor,
-        router_attr=_pa("moe_router.w"), gate_up_attr=_pa("moe_gate_up.w"),
-        down_attr=_pa("moe_down.w"),
-        stat_name=("moe_tokens_per_expert_eval" if is_test
-                   else "moe_tokens_per_expert"))
-    return y
+        routed_scaling_factor=hp.routed_scaling_factor)
+    return routed
 
 
 def _block(x, hp, i, is_test):
     kind = hp.layer_types[i]
-    h = layers.rms_norm(x, hp.norm_eps, param_attr=tfm._pa("operator_norm.w"))
+    h = layers.rms_norm(x, hp.norm_eps,
+                        param_attr=tfm.named("operator_norm.w"))
     if kind == "conv":
         a = _conv_operator(h, hp)
     elif kind == "full_attention":
@@ -130,9 +113,9 @@ def _block(x, hp, i, is_test):
         raise ValueError("layer_types[%d] is %r: neither conv nor "
                          "full_attention" % (i, kind))
     x = layers.elementwise_add(x, a)
-    h = layers.rms_norm(x, hp.norm_eps, param_attr=tfm._pa("ffn_norm.w"))
-    m = (_dense_mlp(h, hp) if i < hp.num_dense_layers
-         else _experts(h, hp, is_test))
+    h = layers.rms_norm(x, hp.norm_eps, param_attr=tfm.named("ffn_norm.w"))
+    m = (swiglu_mlp(h, hp.intermediate_size, hp.hidden_size, "ffn")
+         if i < hp.num_dense_layers else _experts(h, hp, is_test))
     return layers.elementwise_add(x, m)
 
 
@@ -143,44 +126,22 @@ def lfm2_lm(ids, hp=LFM2MoEConfig, is_test=False):
         raise ValueError("layer_types names %d layers, num_hidden_layers "
                          "is %d" % (len(hp.layer_types),
                                     hp.num_hidden_layers))
-    emb_attr = _pa("emb.w")
+    emb_attr = weight("emb.w")
     x = layers.embedding(ids, size=[hp.vocab_size, hp.hidden_size],
                          param_attr=emb_attr)
     for i in range(hp.num_hidden_layers):
         x = _block(x, hp, i, is_test)
-    x = layers.rms_norm(x, hp.norm_eps, param_attr=tfm._pa("final_norm.w"))
+    x = layers.rms_norm(x, hp.norm_eps, param_attr=tfm.named("final_norm.w"))
     emb = framework.default_main_program().global_block().var(emb_attr.name)
     return layers.matmul(x, emb, transpose_y=True)
-
-
-def balance_expert_biases(main, rate=None, max_step=None):
-    """After the optimizer, one `expert_bias_update` per mixture layer:
-    the layer's selection bias follows the step's own counts.  `rate` and
-    `max_step` become the op's attributes where given (a fine-tuning
-    schedule's smaller, bounded step); left out, the op is the one every
-    program before had, attribute for attribute."""
-    attrs = {k: float(v) for k, v in (("rate", rate), ("max_step", max_step))
-             if v is not None}
-    block = main.global_block()
-    with main._op_role_guard("optimize"):
-        for op in list(block.ops):
-            if op.type == "moe_ffn" and op.inputs.get("ExpertBias"):
-                bias = op.inputs["ExpertBias"]
-                block.append_op(
-                    "expert_bias_update",
-                    inputs={"ExpertBias": bias,
-                            "TokensPerExpert": op.outputs["TokensPerExpert"]},
-                    outputs={"ExpertBiasOut": bias}, attrs=attrs)
 
 
 def lfm2_lm_program(hp=LFM2MoEConfig, seq_len=8192, lr=4e-4, is_test=False,
                     use_bf16=False, mesh=None):
     """(main, startup, feeds, [loss, token_count]) as gpt2_lm_program
-    returns them."""
-    main, startup, feeds, fetches = lm_train_program(
+    returns them; a training step ends with the selection biases'
+    balancing step where the experts select with one."""
+    return lm_train_program(
         lambda ids, labels: (xent_cost(lfm2_lm(ids, hp, is_test), labels),
                              None),
         seq_len, lr, is_test, use_bf16, mesh, hp.partition_family)
-    if hp.use_expert_bias and not is_test:
-        balance_expert_biases(main)
-    return main, startup, feeds, fetches

@@ -54,7 +54,7 @@ the shared expert's down) scaled by 1 / sqrt(`num_hidden_layers`)
 = softplus^-1 of max(exp(uniform(log `time_step_min`, log
 `time_step_max`)), `time_step_floor`), D ones, the convolution's bias zero.
 
-The train-program plumbing is `gpt2.lm_train_program`;
+The train-program plumbing is `decoder.lm_train_program`;
 `nemotron_h_reference.py` is the plain float32 statement of the same
 equations, with the scan as the token-by-token recurrence.
 """
@@ -65,22 +65,15 @@ from .. import framework, layers
 from ..initializer import Constant, Uniform
 from ..param_attr import ParamAttr
 from . import transformer as tfm
-from .gpt2 import _pa, lm_train_program, xent_cost
-from .kanana2 import leave_eval_rows
-from .kimi_linear import (
-    _A_RANGE,
-    _InverseSoftplusOfLogUniform,
-    _LogUniform,
-)
-from .lfm2 import balance_expert_biases
+from .decoder import (A_RANGE, EXPERT_BIAS_STD, InverseSoftplusOfLogUniform,
+                      LogUniform, beside_shared, fc, lm_train_program,
+                      routed_experts, weight, xent_cost)
 
 __all__ = ["NemotronHConfig", "nemotron_h_lm", "nemotron_h_lm_program"]
 
 # e_score_correction_bias is a buffer without gradient in the published
-# modeling code, zero at initialisation; seeded non-zero here so that
-# selection (score + bias) and weights (score alone) differ from the first
-# step, and balanced after every training step, as trinity's
-_EXPERT_BIAS_STD = 0.1
+# modeling code, zero at initialisation: seeded and balanced as
+# `decoder.EXPERT_BIAS_STD` says.
 # what the published router adds to the chosen scores' sum before it divides
 _ROUTE_NORM_EPS = 1e-20
 # what a forward-only program leaves in the scope: every token's
@@ -181,18 +174,14 @@ def _check(hp):
 
 
 def _norm(x, hp, base):
-    return layers.rms_norm(x, hp.layer_norm_epsilon, param_attr=tfm._pa(base))
+    return layers.rms_norm(x, hp.layer_norm_epsilon,
+                           param_attr=tfm.named(base))
 
 
 def _out_std(hp):
     """Of a projection that writes to the residual."""
     return 0.02 / (math.sqrt(hp.num_hidden_layers)
                    if hp.rescale_prenorm_residual else 1.0)
-
-
-def _fc(x, size, base, std=0.02, act=None):
-    return layers.fc(x, size=size, num_flatten_dims=2, bias_attr=False,
-                     act=act, param_attr=_pa(base, std))
 
 
 def _number_a_head(base, heads, initializer):
@@ -215,7 +204,7 @@ def _mamba2(h, hp):
     with framework.name_scope("mamba2"):
         with framework.name_scope("in_proj"):
             z, xbc, dt = layers.split(
-                _fc(h, 2 * inner + 2 * g * n + heads, "mamba_in.w"),
+                fc(h, 2 * inner + 2 * g * n + heads, "mamba_in.w"),
                 [inner, inner + 2 * g * n, heads], dim=-1)
         with framework.name_scope("conv"):
             reach = float(hp.conv_kernel) ** -0.5
@@ -231,10 +220,10 @@ def _mamba2(h, hp):
             x, bm, cm = lead(x, heads, p), lead(bm, g, n), lead(cm, g, n)
         with framework.name_scope("core"):
             dt_bias = _number_a_head(
-                "mamba_dt.b", heads, _InverseSoftplusOfLogUniform(
+                "mamba_dt.b", heads, InverseSoftplusOfLogUniform(
                     hp.time_step_min, hp.time_step_max, hp.time_step_floor))
             a_log = _number_a_head("mamba_A_log.w", heads,
-                                   _LogUniform(*_A_RANGE))
+                                   LogUniform(*A_RANGE))
             skip = _number_a_head("mamba_D.w", heads, Constant(1.0))
             # in the op's layout, [B, heads, T]; dt_bias joins the
             # projection after a cast to float32 that says so (as the
@@ -252,48 +241,44 @@ def _mamba2(h, hp):
                                [b, t, inner]), layers.swish(z))
             y = layers.rms_norm(
                 layers.reshape(y, [b, t, g, inner // g]),
-                hp.layer_norm_epsilon, param_attr=tfm._pa("mamba_norm.w"),
+                hp.layer_norm_epsilon, param_attr=tfm.named("mamba_norm.w"),
                 gain_axes=2)
         with framework.name_scope("out_proj"):
-            return _fc(layers.reshape(y, [b, t, inner]), hp.hidden_size,
-                       "mamba_out.w", _out_std(hp))
+            return fc(layers.reshape(y, [b, t, inner]), hp.hidden_size,
+                      "mamba_out.w", _out_std(hp))
 
 
 def _attention(h, hp, is_test):
-    def weight(base):
-        return _pa(base, _out_std(hp) if base == "mha_o.w" else 0.02)
+    def attr(base):
+        return weight(base, _out_std(hp) if base == "mha_o.w" else 0.02)
 
     with framework.name_scope("attn_full"):
         return tfm.multi_head_attention(
             h, h, h, None, hp.hidden_size, hp.num_attention_heads,
             is_test=is_test, fused=True, causal=True,
             n_kv_head=hp.num_key_value_heads, rotary=False,
-            param_attr=weight, head_dim=hp.head_dim, scopes=True)
+            param_attr=attr, head_dim=hp.head_dim, scopes=True)
 
 
 def _experts(h, hp, is_test):
-    routed, _, _ = layers.moe_ffn(
-        h, hp.n_routed_experts, hp.moe_intermediate_size,
-        hp.num_experts_per_tok, norm_topk_prob=hp.norm_topk_prob,
-        router="sigmoid",
-        expert_bias_attr=_pa("moe_expert_bias.b", std=_EXPERT_BIAS_STD),
+    routed, _ = routed_experts(
+        h, is_test, hp.n_routed_experts, hp.moe_intermediate_size,
+        hp.num_experts_per_tok, gate_up="moe_up.w", down_std=_out_std(hp),
+        norm_topk_prob=hp.norm_topk_prob, router="sigmoid",
+        expert_bias_attr=weight("moe_expert_bias.b", EXPERT_BIAS_STD),
         num_local_experts=hp.num_local_experts,
         expert_offset=hp.expert_offset,
         routed_scaling_factor=hp.routed_scaling_factor,
-        norm_topk_eps=_ROUTE_NORM_EPS, expert_act="relu2",
-        router_attr=_pa("moe_router.w"), gate_up_attr=_pa("moe_up.w"),
-        down_attr=_pa("moe_down.w", _out_std(hp)),
-        stat_name=("moe_tokens_per_expert_eval" if is_test
-                   else "moe_tokens_per_expert"))
-    if not hp.n_shared_experts:
-        return routed
-    with framework.name_scope("shared_expert"):
-        up = _fc(h, hp.n_shared_experts
-                 * hp.moe_shared_expert_intermediate_size, "shared_ffn_up.w",
-                 act="relu")
-        shared = _fc(layers.square(up), hp.hidden_size, "shared_ffn_out.w",
-                     _out_std(hp))
-        return layers.elementwise_add(shared, routed)
+        norm_topk_eps=_ROUTE_NORM_EPS, expert_act="relu2")
+
+    def shared(h):
+        up = fc(h, hp.n_shared_experts
+                * hp.moe_shared_expert_intermediate_size, "shared_ffn_up.w",
+                act="relu")
+        return fc(layers.square(up), hp.hidden_size, "shared_ffn_out.w",
+                  _out_std(hp))
+
+    return beside_shared(h, routed, shared if hp.n_shared_experts else None)
 
 
 def _block(x, hp, kind, is_test):
@@ -309,19 +294,10 @@ def nemotron_h_lm(ids, hp=NemotronHConfig, is_test=False):
     its own matrix (`tie_word_embeddings` false)."""
     _check(hp)
     x = layers.embedding(ids, size=[hp.vocab_size, hp.hidden_size],
-                         param_attr=_pa("emb.w"))
+                         param_attr=weight("emb.w"))
     for kind in kinds_of(hp):
         x = _block(x, hp, kind, is_test)
-    return layers.fc(_norm(x, hp, "final_norm.w"), size=hp.vocab_size,
-                     num_flatten_dims=2, bias_attr=False,
-                     param_attr=_pa("softmax_out.w"))
-
-
-def _token_cost(ids, labels, hp, seq_len, is_test):
-    cost = xent_cost(nemotron_h_lm(ids, hp, is_test), labels)  # [B, T, 1]
-    if is_test:
-        leave_eval_rows(cost, EVAL_ROWS, seq_len)
-    return cost
+    return fc(_norm(x, hp, "final_norm.w"), hp.vocab_size, "softmax_out.w")
 
 
 def nemotron_h_lm_program(hp=NemotronHConfig, seq_len=8192, lr=5e-6,
@@ -333,10 +309,8 @@ def nemotron_h_lm_program(hp=NemotronHConfig, seq_len=8192, lr=5e-6,
     `max_step`, a fine-tuning schedule's as trinity's cell runs it); an
     `is_test` program leaves every token's cost in the scope under
     EVAL_ROWS."""
-    main, startup, feeds, fetches = lm_train_program(
-        lambda ids, labels: (_token_cost(ids, labels, hp, seq_len, is_test),
-                             None),
-        seq_len, lr, is_test, use_bf16, mesh, hp.partition_family)
-    if not is_test:
-        balance_expert_biases(main, bias_rate, bias_max_step)
-    return main, startup, feeds, fetches
+    return lm_train_program(
+        lambda ids, labels: (
+            xent_cost(nemotron_h_lm(ids, hp, is_test), labels), None),
+        seq_len, lr, is_test, use_bf16, mesh, hp.partition_family,
+        eval_rows=EVAL_ROWS, bias_rate=bias_rate, bias_max_step=bias_max_step)

@@ -7,17 +7,17 @@ Attention is the shared `transformer.multi_head_attention` (causal, fused,
 RoPE, QK-norm: an rms_norm over the whole q and the whole k projection
 before the head split); the experts are one `moe_ffn` op per layer
 (softmax router, top-k, dropless); the train-program plumbing is
-`gpt2.lm_train_program`.  Loss = token cross-entropy + router_aux_loss_coef
-* load-balance + router_z_loss_coef * z, the two router losses summed over
-the layers.  `olmoe_reference.py` is the plain float32 statement of the
-same equations.
+`decoder.lm_train_program`.  Loss = token cross-entropy +
+router_aux_loss_coef * load-balance + router_z_loss_coef * z, the two
+router losses summed over the layers.  `olmoe_reference.py` is the plain
+float32 statement of the same equations.
 """
 
 import numpy as np
 
 from .. import layers
 from . import transformer as tfm
-from .gpt2 import _pa, lm_train_program, xent_cost
+from .decoder import fc, lm_train_program, routed_experts, weight, xent_cost
 
 __all__ = ["OLMoEConfig", "olmoe_lm", "olmoe_lm_program"]
 
@@ -47,20 +47,18 @@ class OLMoEConfig:
 
 def _block(x, hp, is_test):
     d = hp.hidden_size
-    h = layers.rms_norm(x, hp.rms_norm_eps, param_attr=tfm._pa("attn_norm.w"))
+    h = layers.rms_norm(x, hp.rms_norm_eps,
+                        param_attr=tfm.named("attn_norm.w"))
     a = tfm.multi_head_attention(
         h, h, h, None, d, hp.num_attention_heads, is_test=is_test,
         fused=True, causal=True, n_kv_head=hp.num_key_value_heads,
         rotary=True, rotary_base=float(hp.rope_theta), qk_norm=True,
         qk_norm_eps=hp.rms_norm_eps)
     x = layers.elementwise_add(x, a)
-    h = layers.rms_norm(x, hp.rms_norm_eps, param_attr=tfm._pa("ffn_norm.w"))
-    m, aux, _ = layers.moe_ffn(
-        h, hp.num_experts, hp.intermediate_size, hp.num_experts_per_tok,
-        norm_topk_prob=hp.norm_topk_prob, router_attr=_pa("moe_router.w"),
-        gate_up_attr=_pa("moe_gate_up.w"), down_attr=_pa("moe_down.w"),
-        stat_name=("moe_tokens_per_expert_eval" if is_test
-                   else "moe_tokens_per_expert"))
+    h = layers.rms_norm(x, hp.rms_norm_eps, param_attr=tfm.named("ffn_norm.w"))
+    m, aux = routed_experts(
+        h, is_test, hp.num_experts, hp.intermediate_size,
+        hp.num_experts_per_tok, norm_topk_prob=hp.norm_topk_prob)
     return layers.elementwise_add(x, m), aux
 
 
@@ -68,7 +66,7 @@ def olmoe_lm(ids, hp=OLMoEConfig, is_test=False):
     """[B, T] token ids -> ([B, T, vocab] next-token logits, the weighted
     router losses summed over the layers as a [1] var)."""
     x = layers.embedding(ids, size=[hp.vocab_size, hp.hidden_size],
-                         param_attr=_pa("emb.w"))
+                         param_attr=weight("emb.w"))
     coef = layers.assign(np.array(
         [hp.router_aux_loss_coef, hp.router_z_loss_coef], "float32"))
     coef.stop_gradient = True
@@ -78,10 +76,9 @@ def olmoe_lm(ids, hp=OLMoEConfig, is_test=False):
         aux = layers.reduce_sum(layers.elementwise_mul(aux, coef))
         router_loss = (aux if router_loss is None
                        else layers.elementwise_add(router_loss, aux))
-    x = layers.rms_norm(x, hp.rms_norm_eps, param_attr=tfm._pa("final_norm.w"))
-    logits = layers.fc(x, size=hp.vocab_size, num_flatten_dims=2,
-                       bias_attr=False, param_attr=_pa("softmax_out.w"))
-    return logits, router_loss
+    x = layers.rms_norm(x, hp.rms_norm_eps,
+                        param_attr=tfm.named("final_norm.w"))
+    return fc(x, hp.vocab_size, "softmax_out.w"), router_loss
 
 
 def olmoe_lm_program(hp=OLMoEConfig, seq_len=4096, lr=4e-4, is_test=False,

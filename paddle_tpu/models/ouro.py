@@ -36,7 +36,7 @@ its own (`..._eval`) and, in `ouro_eval_rows` [B, 2 total_ut_steps,
 T], every token's cost after each loop step and the logarithm of its exit
 distribution: which step a token would leave at, and at what loss.
 
-The train-program plumbing is `gpt2.lm_train_program`;
+The train-program plumbing is `decoder.lm_train_program`;
 `ouro_reference.py` is the plain float32 statement of the same equations.
 """
 
@@ -45,7 +45,7 @@ from ..initializer import Constant, Normal
 from ..layer_helper import LayerHelper
 from ..param_attr import ParamAttr
 from . import transformer as tfm
-from .gpt2 import lm_train_program
+from .decoder import lm_train_program, swiglu_mlp
 
 __all__ = ["OuroConfig", "ouro_lm", "ouro_token_cost", "ouro_lm_program"]
 
@@ -97,13 +97,7 @@ def _layer(x, hp, i, is_test):
         rotary_base=float(hp.rope_theta), param_attr=weight)
     x = layers.elementwise_add(x, norm(a, "attn_post_norm.w"))
     h = norm(x, "ffn_norm.w")
-    gate = layers.fc(h, size=f, num_flatten_dims=2, act="swish",
-                     bias_attr=False, param_attr=weight("ffn_gate.w"))
-    up = layers.fc(h, size=f, num_flatten_dims=2, bias_attr=False,
-                   param_attr=weight("ffn_up.w"))
-    m = layers.fc(layers.elementwise_mul(gate, up), size=d,
-                  num_flatten_dims=2, bias_attr=False,
-                  param_attr=weight("ffn_out.w"))
+    m = swiglu_mlp(h, f, d, "ffn", weight)
     return layers.elementwise_add(x, norm(m, "ffn_post_norm.w"))
 
 

@@ -42,7 +42,7 @@ projection.
 
 The published module list also holds a multi-token prediction head in the
 checkpoint's description; `Qwen3NextForCausalLM` builds none and none is
-built here.  The train-program plumbing is `gpt2.lm_train_program`;
+built here.  The train-program plumbing is `decoder.lm_train_program`;
 `qwen3_next_reference.py` is the plain float32 statement of the same
 equations, with Gated DeltaNet as the token-by-token recurrence.
 """
@@ -50,15 +50,10 @@ equations, with Gated DeltaNet as the token-by-token recurrence.
 from .. import framework, layers
 from ..param_attr import ParamAttr
 from . import transformer as tfm
-from .gpt2 import _pa, lm_train_program, xent_cost
-from .kanana2 import _swiglu_mlp, _weight, leave_eval_rows
-from .kimi_linear import (
-    _A_RANGE,
-    _DT_RANGE,
-    _L2_EPS,
-    _InverseSoftplusOfLogUniform,
-    _LogUniform,
-)
+from .decoder import (A_RANGE, DT_RANGE, L2_EPS, InverseSoftplusOfLogUniform,
+                      LogUniform, beside_shared, fc, lm_train_program,
+                      norm_or_weight, routed_experts, swiglu_mlp, weight,
+                      xent_cost)
 
 __all__ = ["Qwen3NextConfig", "qwen3_next_lm", "qwen3_next_lm_program"]
 
@@ -136,13 +131,8 @@ def _check(hp):
 
 def _norm(x, hp, base):
     """The model's RMSNorm: the gain 1 + w, w zero at initialisation."""
-    return layers.rms_norm(x, hp.rms_norm_eps, param_attr=tfm._pa(base),
+    return layers.rms_norm(x, hp.rms_norm_eps, param_attr=tfm.named(base),
                            unit_offset=True)
-
-
-def _fc(x, size, base):
-    return layers.fc(x, size=size, num_flatten_dims=2, bias_attr=False,
-                     param_attr=_pa(base))
 
 
 def _gdn(h, hp):
@@ -157,22 +147,22 @@ def _gdn(h, hp):
 
     with framework.name_scope("gdn"):
         with framework.name_scope("proj"):
-            qkv = _fc(h, 2 * hk * dk + hv * dv, "gdn_qkv.w")
-            z = _fc(h, hv * dv, "gdn_z.w")
-            beta = _fc(h, hv, "gdn_b.w")
-            a = _fc(h, hv, "gdn_a.w")  # dt_bias joins it under `gate`
+            qkv = fc(h, 2 * hk * dk + hv * dv, "gdn_qkv.w")
+            z = fc(h, hv * dv, "gdn_z.w")
+            beta = fc(h, hv, "gdn_b.w")
+            a = fc(h, hv, "gdn_a.w")  # dt_bias joins it under `gate`
             dt_bias = layers.create_parameter(
                 [hv], "float32",
                 attr=ParamAttr(
                     name=framework.unique_name.generate("gdn_dt.b"),
-                    initializer=_InverseSoftplusOfLogUniform(*_DT_RANGE)))
+                    initializer=InverseSoftplusOfLogUniform(*DT_RANGE)))
         with framework.name_scope("conv"):
             qkv = layers.causal_conv(
                 qkv, int(hp.linear_conv_kernel_dim), act="silu",
-                param_attr=_pa("gdn_conv.w"))
+                param_attr=weight("gdn_conv.w"))
             q, k, v = layers.split(qkv, [hk * dk, hk * dk, hv * dv], dim=-1)
             q, k = (layers.l2_normalize(
-                layers.reshape(y, [b, t, hk, dk]), axis=-1, epsilon=_L2_EPS)
+                layers.reshape(y, [b, t, hk, dk]), axis=-1, epsilon=L2_EPS)
                 for y in (q, k))
             q, k = (layers.transpose(y, [0, 2, 1, 3]) for y in (q, k))
             v = lead(v, hv, dv)
@@ -181,7 +171,7 @@ def _gdn(h, hp):
                 [hv], "float32",
                 attr=ParamAttr(
                     name=framework.unique_name.generate("gdn_A_log.w"),
-                    initializer=_LogUniform(*_A_RANGE)))
+                    initializer=LogUniform(*A_RANGE)))
             # in the op's layout, [B, heads, T]; dt_bias is added to the
             # projection HERE, after a cast to float32 that says so: as
             # the projection's own bias, or added to its bfloat16 result,
@@ -202,11 +192,11 @@ def _gdn(h, hp):
         with framework.name_scope("out"):
             o = layers.rms_norm(layers.transpose(o, [0, 2, 1, 3]),
                                 hp.rms_norm_eps,
-                                param_attr=tfm._pa("gdn_o_norm.w"))
+                                param_attr=tfm.named("gdn_o_norm.w"))
             o = layers.elementwise_mul(
                 o, layers.swish(layers.reshape(z, [b, t, hv, dv])))
-            return _fc(layers.reshape(o, [b, t, hv * dv]), hp.hidden_size,
-                       "gdn_o.w")
+            return fc(layers.reshape(o, [b, t, hv * dv]), hp.hidden_size,
+                      "gdn_o.w")
 
 
 def _attention(h, hp, is_test):
@@ -216,28 +206,26 @@ def _attention(h, hp, is_test):
             is_test=is_test, fused=True, causal=True,
             n_kv_head=hp.num_key_value_heads, rotary=True,
             rotary_base=float(hp.rope_theta), qk_norm="head",
-            qk_norm_eps=hp.rms_norm_eps, param_attr=_weight,
+            qk_norm_eps=hp.rms_norm_eps, param_attr=norm_or_weight,
             head_dim=hp.head_dim, out_gate=True, scopes=True,
             rotary_dim=int(hp.head_dim * hp.partial_rotary_factor),
             norm_unit_offset=True)
 
 
 def _experts(h, hp, is_test):
-    routed, _, _ = layers.moe_ffn(
-        h, hp.num_experts, hp.moe_intermediate_size, hp.num_experts_per_tok,
-        norm_topk_prob=hp.norm_topk_prob, router="softmax",
-        num_local_experts=hp.num_local_experts,
-        expert_offset=hp.expert_offset,
-        router_attr=_pa("moe_router.w"), gate_up_attr=_pa("moe_gate_up.w"),
-        down_attr=_pa("moe_down.w"),
-        stat_name=("moe_tokens_per_expert_eval" if is_test
-                   else "moe_tokens_per_expert"))
-    with framework.name_scope("shared_expert"):
-        shared = _swiglu_mlp(h, hp.shared_expert_intermediate_size,
-                             hp.hidden_size, "shared_ffn")
-        shared = layers.elementwise_mul(
-            shared, layers.sigmoid(_fc(h, 1, "shared_expert_gate.w")))
-        return layers.elementwise_add(shared, routed)
+    routed, _ = routed_experts(
+        h, is_test, hp.num_experts, hp.moe_intermediate_size,
+        hp.num_experts_per_tok, norm_topk_prob=hp.norm_topk_prob,
+        router="softmax", num_local_experts=hp.num_local_experts,
+        expert_offset=hp.expert_offset)
+
+    def shared(h):
+        return layers.elementwise_mul(
+            swiglu_mlp(h, hp.shared_expert_intermediate_size, hp.hidden_size,
+                       "shared_ffn"),
+            layers.sigmoid(fc(h, 1, "shared_expert_gate.w")))
+
+    return beside_shared(h, routed, shared)
 
 
 def _block(x, hp, i, is_test):
@@ -254,19 +242,10 @@ def qwen3_next_lm(ids, hp=Qwen3NextConfig, is_test=False):
     its own matrix (`tie_word_embeddings` false)."""
     _check(hp)
     x = layers.embedding(ids, size=[hp.vocab_size, hp.hidden_size],
-                         param_attr=_pa("emb.w"))
+                         param_attr=weight("emb.w"))
     for i in range(hp.num_hidden_layers):
         x = _block(x, hp, i, is_test)
-    return layers.fc(_norm(x, hp, "final_norm.w"), size=hp.vocab_size,
-                     num_flatten_dims=2, bias_attr=False,
-                     param_attr=_pa("softmax_out.w"))
-
-
-def _token_cost(ids, labels, hp, seq_len, is_test):
-    cost = xent_cost(qwen3_next_lm(ids, hp, is_test), labels)  # [B, T, 1]
-    if is_test:
-        leave_eval_rows(cost, EVAL_ROWS, seq_len)
-    return cost
+    return fc(_norm(x, hp, "final_norm.w"), hp.vocab_size, "softmax_out.w")
 
 
 def qwen3_next_lm_program(hp=Qwen3NextConfig, seq_len=8192, lr=5e-6,
@@ -276,6 +255,7 @@ def qwen3_next_lm_program(hp=Qwen3NextConfig, seq_len=8192, lr=5e-6,
     scope under EVAL_ROWS.  The router has no selection bias and the step
     no balancing op (the published model has neither)."""
     return lm_train_program(
-        lambda ids, labels: (_token_cost(ids, labels, hp, seq_len, is_test),
-                             None),
-        seq_len, lr, is_test, use_bf16, mesh, hp.partition_family)
+        lambda ids, labels: (
+            xent_cost(qwen3_next_lm(ids, hp, is_test), labels), None),
+        seq_len, lr, is_test, use_bf16, mesh, hp.partition_family,
+        eval_rows=EVAL_ROWS)

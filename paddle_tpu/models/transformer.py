@@ -18,7 +18,7 @@ from ..initializer import Normal
 from ..param_attr import ParamAttr
 
 
-def _pa(base):
+def named(base):
     """Named ParamAttr so parallel.transformer_tp_rules can target these
     weights by regex (the GSPMD analog of the transpiler's param slicing)."""
     return ParamAttr(name=unique_name.generate(base))
@@ -197,7 +197,7 @@ def multi_head_attention(
     ("mha_q.w", ...); a builder that runs a layer several times over one
     set of weights gives each use the same names.  The default numbers
     every call's weights anew."""
-    pa = param_attr or _pa
+    pa = param_attr or named
     dh = int(head_dim or d_model // n_head)
     n_kv = n_kv_head or n_head
     if n_head % n_kv:
@@ -471,7 +471,7 @@ def latent_attention(
     projections from x and the latent's norm), `up` (the expansion of the
     latent), `rope`, `core` (the fused_attention op) and `out`, so the
     lowered HLO carries them.  param_attr as `multi_head_attention`'s."""
-    pa = param_attr or _pa
+    pa = param_attr or named
     d_model = int(x.shape[-1])
     b, t = x.shape[0], x.shape[1]
     d_qk = qk_nope_head_dim + qk_rope_head_dim
@@ -526,11 +526,12 @@ def latent_attention(
 
 def positionwise_ffn(x, d_inner, d_model, dropout_rate=0.0, is_test=False):
     hidden = layers.fc(x, size=d_inner, num_flatten_dims=2, act="relu",
-                       param_attr=_pa("ffn_in.w"), bias_attr=_pa("ffn_in.b"))
+                       param_attr=named("ffn_in.w"),
+                       bias_attr=named("ffn_in.b"))
     if dropout_rate:
         hidden = layers.dropout(hidden, dropout_rate, is_test=is_test)
     return layers.fc(hidden, size=d_model, num_flatten_dims=2,
-                     param_attr=_pa("ffn_out.w"))
+                     param_attr=named("ffn_out.w"))
 
 
 def pre_post_process(prev, out, dropout_rate=0.0, is_test=False):
@@ -635,7 +636,7 @@ def transformer(
             )
 
     logits = layers.fc(y, size=hp.trg_vocab_size, num_flatten_dims=2,
-                       bias_attr=False, param_attr=_pa("softmax_out.w"))
+                       bias_attr=False, param_attr=named("softmax_out.w"))
     return logits
 
 
@@ -997,7 +998,8 @@ def transformer_decode_programs(hp=ModelHyperParams, batch=1, src_len=64,
                 y = decoder_layer(y, enc_ref, None, None, hp, is_test=True,
                                   cross_kpad=kpad_ref, cache=cache)
             logits = layers.fc(y, size=hp.trg_vocab_size, num_flatten_dims=2,
-                               bias_attr=False, param_attr=_pa("softmax_out.w"))
+                               bias_attr=False,
+                               param_attr=named("softmax_out.w"))
             if width == 1:
                 logits = layers.reshape(logits,
                                         shape=[batch, hp.trg_vocab_size])

@@ -34,25 +34,22 @@ a final rms; an untied head; the embedding scaled by sqrt(hidden_size)
          `moe_intermediate_size` wide, under `shared_expert`, computed
          alike on every chip.
 
-The train-program plumbing is `gpt2.lm_train_program`;
+The train-program plumbing is `decoder.lm_train_program`;
 `trinity_reference.py` is the plain float32 statement of the same
 equations.
 """
 
 from .. import framework, layers
 from . import transformer as tfm
-from .gpt2 import _pa, lm_train_program, xent_cost
-from .kanana2 import _swiglu_mlp, _weight, leave_eval_rows
-from .lfm2 import balance_expert_biases
+from .decoder import (EXPERT_BIAS_STD, beside_shared, fc, lm_train_program,
+                      norm_or_weight, routed_experts, swiglu_mlp, weight,
+                      xent_cost)
 
 __all__ = ["TrinityConfig", "trinity_lm", "trinity_lm_program"]
 
 # expert_bias is a parameter without gradient in the published modeling
 # code, zero at initialisation; the rule that moves it in training is the
-# trainer's.  Here it is seeded non-zero, so that selection (score + bias)
-# and weights (score alone) differ from the first step, and balanced after
-# every training step, as lfm2's and kanana2's.
-_EXPERT_BIAS_STD = 0.1
+# trainer's: seeded and balanced as `decoder.EXPERT_BIAS_STD` says.
 # what the published router adds to the chosen scores' sum before it divides
 _ROUTE_NORM_EPS = 1e-20
 # what a forward-only program leaves in the scope: every token's
@@ -129,42 +126,38 @@ def _attention(h, hp, kind, is_test):
             is_test=is_test, fused=True, causal=True,
             n_kv_head=hp.num_key_value_heads, rotary=sliding,
             rotary_base=float(hp.rope_theta), qk_norm="head",
-            qk_norm_eps=hp.rms_norm_eps, param_attr=_weight,
+            qk_norm_eps=hp.rms_norm_eps, param_attr=norm_or_weight,
             head_dim=hp.head_dim,
             window=int(hp.sliding_window) if sliding else 0,
             out_gate=True, scopes=True)
 
 
 def _experts(h, hp, is_test):
-    routed, _, _ = layers.moe_ffn(
-        h, hp.num_experts, hp.moe_intermediate_size, hp.num_experts_per_tok,
-        norm_topk_prob=hp.route_norm, router="sigmoid",
-        expert_bias_attr=_pa("moe_expert_bias.b", std=_EXPERT_BIAS_STD),
+    routed, _ = routed_experts(
+        h, is_test, hp.num_experts, hp.moe_intermediate_size,
+        hp.num_experts_per_tok, norm_topk_prob=hp.route_norm,
+        router="sigmoid",
+        expert_bias_attr=weight("moe_expert_bias.b", EXPERT_BIAS_STD),
         num_local_experts=hp.num_local_experts,
         expert_offset=hp.expert_offset,
         routed_scaling_factor=hp.route_scale,
-        norm_topk_eps=_ROUTE_NORM_EPS,
-        router_attr=_pa("moe_router.w"), gate_up_attr=_pa("moe_gate_up.w"),
-        down_attr=_pa("moe_down.w"),
-        stat_name=("moe_tokens_per_expert_eval" if is_test
-                   else "moe_tokens_per_expert"))
-    if not hp.num_shared_experts:
-        return routed
-    with framework.name_scope("shared_expert"):
-        shared = _swiglu_mlp(
-            h, hp.num_shared_experts * hp.moe_intermediate_size,
-            hp.hidden_size, "shared_ffn")
-        return layers.elementwise_add(shared, routed)
+        norm_topk_eps=_ROUTE_NORM_EPS)
+
+    def shared(h):
+        return swiglu_mlp(h, hp.num_shared_experts * hp.moe_intermediate_size,
+                          hp.hidden_size, "shared_ffn")
+
+    return beside_shared(h, routed, shared if hp.num_shared_experts else None)
 
 
 def _block(x, hp, i, is_test):
     def norm(y, base):
-        return layers.rms_norm(y, hp.rms_norm_eps, param_attr=tfm._pa(base))
+        return layers.rms_norm(y, hp.rms_norm_eps, param_attr=tfm.named(base))
 
     a = _attention(norm(x, "input_norm.w"), hp, hp.layer_types[i], is_test)
     x = layers.elementwise_add(x, norm(a, "post_attn_norm.w"))
     h = norm(x, "pre_mlp_norm.w")
-    m = (_swiglu_mlp(h, hp.intermediate_size, hp.hidden_size, "ffn")
+    m = (swiglu_mlp(h, hp.intermediate_size, hp.hidden_size, "ffn")
          if i < hp.num_dense_layers else _experts(h, hp, is_test))
     return layers.elementwise_add(x, norm(m, "post_mlp_norm.w"))
 
@@ -174,22 +167,14 @@ def trinity_lm(ids, hp=TrinityConfig, is_test=False):
     its own matrix (`tie_word_embeddings` false)."""
     _check(hp)
     x = layers.embedding(ids, size=[hp.vocab_size, hp.hidden_size],
-                         param_attr=_pa("emb.w"))
+                         param_attr=weight("emb.w"))
     if hp.mup_enabled:
         x = layers.scale(x, scale=float(hp.hidden_size) ** 0.5)
     for i in range(hp.num_hidden_layers):
         x = _block(x, hp, i, is_test)
     x = layers.rms_norm(x, hp.rms_norm_eps,
-                        param_attr=tfm._pa("final_norm.w"))
-    return layers.fc(x, size=hp.vocab_size, num_flatten_dims=2,
-                     bias_attr=False, param_attr=_pa("softmax_out.w"))
-
-
-def _token_cost(ids, labels, hp, seq_len, is_test):
-    cost = xent_cost(trinity_lm(ids, hp, is_test), labels)  # [B, T, 1]
-    if is_test:
-        leave_eval_rows(cost, EVAL_ROWS, seq_len)
-    return cost
+                        param_attr=tfm.named("final_norm.w"))
+    return fc(x, hp.vocab_size, "softmax_out.w")
 
 
 def trinity_lm_program(hp=TrinityConfig, seq_len=4096, lr=4e-4,
@@ -201,10 +186,8 @@ def trinity_lm_program(hp=TrinityConfig, seq_len=4096, lr=4e-4,
     the `expert_bias_update` op's `rate` and `max_step` where given, a
     fine-tuning schedule's); an `is_test` program leaves every token's
     cost in the scope under EVAL_ROWS."""
-    main, startup, feeds, fetches = lm_train_program(
-        lambda ids, labels: (_token_cost(ids, labels, hp, seq_len, is_test),
+    return lm_train_program(
+        lambda ids, labels: (xent_cost(trinity_lm(ids, hp, is_test), labels),
                              None),
-        seq_len, lr, is_test, use_bf16, mesh, hp.partition_family)
-    if not is_test:
-        balance_expert_biases(main, bias_rate, bias_max_step)
-    return main, startup, feeds, fetches
+        seq_len, lr, is_test, use_bf16, mesh, hp.partition_family,
+        eval_rows=EVAL_ROWS, bias_rate=bias_rate, bias_max_step=bias_max_step)

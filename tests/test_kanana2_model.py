@@ -25,6 +25,8 @@ from paddle_tpu.models import gpt2, kanana2, kanana2_reference as ref
 from paddle_tpu.ops import moe_ops
 from paddle_tpu.param_attr import ParamAttr
 
+from expert_share import share_through_the_executor
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -443,38 +445,6 @@ def _layer_weights():
                        (rng.randn(fs, d) * 0.2).astype("float32")]}
 
 
-def _share_through_the_executor(w, offset, held):
-    """One expert layer of the builder (`kanana2._experts`: the routed
-    experts [offset, offset + held) and the shared expert) as a Program of
-    its own; -> (routed + shared, routed alone, counts)."""
-    hp = type("Share", (HP,), {"num_local_experts": held,
-                               "expert_offset": offset})
-    main, startup = fluid.Program(), fluid.Program()
-    with framework.program_guard(main, startup), unique_name.guard():
-        x = layers.data("x", shape=list(w["x"].shape),
-                        append_batch_size=False)
-        y = kanana2._experts(x, hp, is_test=False)
-    block = main.global_block()
-    (moe,) = [op for op in block.ops if op.type == "moe_ffn"]
-    init = dict(zip(
-        [moe.inputs[s][0] for s in ("RouterW", "ExpertBias", "GateUpW",
-                                    "DownW")],
-        [w["router"], w["bias"], w["gate_up"][offset:offset + held],
-         w["down"][offset:offset + held]]))
-    shared = [p.name for p in block.all_parameters()
-              if p.name.startswith("shared_ffn")]
-    init.update(zip(shared, w["shared"]))
-    exe, scope = fluid.Executor(fluid.CPUPlace()), fluid.Scope()
-    with fluid.scope_guard(scope):
-        exe.run(startup)
-        for name, value in init.items():
-            assert tuple(np.asarray(scope.find_var(name)).shape) == (
-                value.shape), name
-            scope.set(name, jnp.asarray(value))
-        return exe.run(main, feed={"x": w["x"]}, fetch_list=[
-            y, moe.outputs["Y"][0], moe.outputs["TokensPerExpert"][0]])
-
-
 def test_the_shares_routed_parts_and_the_shared_expert_once_are_the_layer():
     """Four chips hold experts 0-1, 2-3, 4-5, 6-7 of one layer.  Each
     routes over all eight, computes its own experts' part and the WHOLE
@@ -490,7 +460,7 @@ def test_the_shares_routed_parts_and_the_shared_expert_once_are_the_layer():
         routed, top_e = ref.routed(cfg, *args)
         shared = ref.swiglu_mlp(args[0], *map(jnp.asarray, w["shared"]))
     want_counts = np.bincount(np.asarray(top_e).reshape(-1), minlength=8)
-    parts = [_share_through_the_executor(w, offset, 2)
+    parts = [share_through_the_executor(kanana2._experts, HP, w, offset, 2)
              for offset in (0, 2, 4, 6)]
     for both, part, counts in parts:
         np.testing.assert_array_equal(counts, want_counts)

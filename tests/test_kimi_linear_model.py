@@ -27,6 +27,8 @@ import paddle_tpu as fluid
 from paddle_tpu import analysis, framework, layers, unique_name
 from paddle_tpu.models import gpt2, kimi_linear, kimi_linear_reference as ref
 
+from expert_share import share_through_the_executor
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -526,38 +528,6 @@ def _layer_weights():
                        (rng.randn(fs, d) * 0.2).astype("float32")]}
 
 
-def _share_through_the_executor(w, offset, held):
-    """One expert layer of the builder (`kimi_linear._experts`: the routed
-    experts [offset, offset + held) and the shared expert) as a Program of
-    its own; -> (routed + shared, routed alone, counts)."""
-    hp = type("Share", (Wide,), {"num_local_experts": held,
-                                 "expert_offset": offset})
-    main, startup = fluid.Program(), fluid.Program()
-    with framework.program_guard(main, startup), unique_name.guard():
-        x = layers.data("x", shape=list(w["x"].shape),
-                        append_batch_size=False)
-        y = kimi_linear._experts(x, hp, is_test=False)
-    block = main.global_block()
-    (moe,) = [op for op in block.ops if op.type == "moe_ffn"]
-    init = dict(zip(
-        [moe.inputs[s][0] for s in ("RouterW", "ExpertBias", "GateUpW",
-                                    "DownW")],
-        [w["router"], w["bias"], w["gate_up"][offset:offset + held],
-         w["down"][offset:offset + held]]))
-    shared = [p.name for p in block.all_parameters()
-              if p.name.startswith("shared_ffn")]
-    init.update(zip(shared, w["shared"]))
-    exe, scope = fluid.Executor(fluid.CPUPlace()), fluid.Scope()
-    with fluid.scope_guard(scope):
-        exe.run(startup)
-        for name, value in init.items():
-            assert tuple(np.asarray(scope.find_var(name)).shape) == (
-                value.shape), name
-            scope.set(name, jnp.asarray(value))
-        return exe.run(main, feed={"x": w["x"]}, fetch_list=[
-            y, moe.outputs["Y"][0], moe.outputs["TokensPerExpert"][0]])
-
-
 def test_the_thirty_two_shares_and_the_shared_expert_once_are_the_layer():
     """Thirty-two chips hold one expert each of one layer.  Each routes
     over all thirty-two, computes its own expert's part and the WHOLE
@@ -575,7 +545,8 @@ def test_the_thirty_two_shares_and_the_shared_expert_once_are_the_layer():
         shared = ref.swiglu_mlp(args[0], *map(jnp.asarray, w["shared"]))
     want_counts = np.bincount(np.asarray(top_e).reshape(-1),
                               minlength=SHARES)
-    parts = [_share_through_the_executor(w, offset, 1)
+    parts = [share_through_the_executor(kimi_linear._experts, Wide, w,
+                                        offset, 1)
              for offset in range(SHARES)]
     for both, part, counts in parts:
         np.testing.assert_array_equal(counts, want_counts)

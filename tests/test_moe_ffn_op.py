@@ -1108,7 +1108,7 @@ def test_sixteen_shares_and_the_shared_expert_once_are_the_uncut_layer():
     WHOLE shared expert; the sixteen routed parts plus the shared expert
     counted ONCE are what the uncut reference gives for the layer, and
     every chip saw the same routing decisions."""
-    from paddle_tpu import framework, unique_name
+    from expert_share import share_through_the_executor
     from paddle_tpu.models import nemotron_h, nemotron_h_reference
 
     d, f, fs, k = 32, 16, 24, 4
@@ -1116,51 +1116,27 @@ def test_sixteen_shares_and_the_shared_expert_once_are_the_uncut_layer():
     w = {"x": rng.randn(2, 24, d).astype("float32"),
          "router": (rng.randn(d, SHARES) * 0.3).astype("float32"),
          "bias": (rng.randn(SHARES) * 0.3).astype("float32"),
-         "up": (rng.randn(SHARES, d, f) * 0.3).astype("float32"),
+         "gate_up": (rng.randn(SHARES, d, f) * 0.3).astype("float32"),
          "down": (rng.randn(SHARES, f, d) * 0.3).astype("float32"),
          "shared": [(rng.randn(d, fs) * 0.3).astype("float32"),
                     (rng.randn(fs, d) * 0.3).astype("float32")]}
 
-    def share(offset):
-        hp = type("Share", (nemotron_h.NemotronHConfig,), dict(
-            hidden_size=d, moe_intermediate_size=f,
-            moe_shared_expert_intermediate_size=fs, n_routed_experts=SHARES,
-            num_experts_per_tok=k, num_local_experts=1,
-            expert_offset=offset, num_hidden_layers=1))
-        main, startup = fluid.Program(), fluid.Program()
-        with framework.program_guard(main, startup), unique_name.guard():
-            x = layers.data("x", shape=list(w["x"].shape),
-                            append_batch_size=False)
-            y = nemotron_h._experts(x, hp, is_test=False)
-        block = main.global_block()
-        (moe,) = [op for op in block.ops if op.type == "moe_ffn"]
-        init = dict(zip(
-            [moe.inputs[s][0] for s in ("RouterW", "ExpertBias", "GateUpW",
-                                        "DownW")],
-            [w["router"], w["bias"], w["up"][offset:offset + 1],
-             w["down"][offset:offset + 1]]))
-        init.update(zip([p.name for p in block.all_parameters()
-                         if p.name.startswith("shared_ffn")], w["shared"]))
-        exe, scope = fluid.Executor(fluid.CPUPlace()), fluid.Scope()
-        with fluid.scope_guard(scope):
-            exe.run(startup)
-            for name, value in init.items():
-                assert tuple(np.asarray(scope.find_var(name)).shape) == (
-                    value.shape), name
-                scope.set(name, jnp.asarray(value))
-            return exe.run(main, feed={"x": w["x"]}, fetch_list=[
-                y, moe.outputs["Y"][0], moe.outputs["TokensPerExpert"][0]])
-
+    hp = type("Layer", (nemotron_h.NemotronHConfig,), dict(
+        hidden_size=d, moe_intermediate_size=f,
+        moe_shared_expert_intermediate_size=fs, n_routed_experts=SHARES,
+        num_experts_per_tok=k, num_hidden_layers=1))
     cfg = {"num_experts_per_tok": k, "norm_topk_prob": True,
            "routed_scaling_factor": 2.5, "expert_offset": 0}
-    args = [jnp.asarray(w[n]) for n in ("x", "router", "bias", "up", "down")]
+    args = [jnp.asarray(w[n])
+            for n in ("x", "router", "bias", "gate_up", "down")]
     with jax.default_matmul_precision("highest"):
         routed, top_e = nemotron_h_reference.routed(cfg, *args)
         shared = nemotron_h_reference.relu2_mlp(
             args[0], *map(jnp.asarray, w["shared"]))
     want_counts = np.bincount(np.asarray(top_e).reshape(-1),
                               minlength=SHARES)
-    parts = [share(offset) for offset in range(SHARES)]
+    parts = [share_through_the_executor(nemotron_h._experts, hp, w, offset, 1)
+             for offset in range(SHARES)]
     for both, part, counts in parts:
         np.testing.assert_array_equal(counts, want_counts)
         np.testing.assert_allclose(both - part, shared, rtol=1e-4, atol=1e-4)

@@ -133,4 +133,5 @@ def test_gpt2_builder_still_builds_the_same_program():
     assert types.count("fused_linear_xent") == 1
     assert types.count("fused_attention") == 1
     assert "moe_ffn" not in types and "rms_norm" not in types
+    assert "expert_bias_update" not in types  # nothing selects with a bias
     assert types.count("adam") == len(main.global_block().all_parameters())

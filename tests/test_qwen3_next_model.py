@@ -27,6 +27,8 @@ import paddle_tpu as fluid
 from paddle_tpu import analysis, framework, layers, unique_name
 from paddle_tpu.models import gpt2, qwen3_next, qwen3_next_reference as ref
 
+from expert_share import share_through_the_executor
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -604,38 +606,6 @@ def _layer_weights():
                        (rng.randn(d, 1) * 0.5).astype("float32")]}
 
 
-def _share_through_the_executor(w, offset, held):
-    """One expert layer of the builder (`qwen3_next._experts`: the routed
-    experts [offset, offset + held) and the gated shared expert) as a
-    Program of its own; -> (routed + shared, routed alone, counts)."""
-    hp = type("Share", (Wide,), {"num_local_experts": held,
-                                 "expert_offset": offset})
-    main, startup = fluid.Program(), fluid.Program()
-    with framework.program_guard(main, startup), unique_name.guard():
-        x = layers.data("x", shape=list(w["x"].shape),
-                        append_batch_size=False)
-        y = qwen3_next._experts(x, hp, is_test=False)
-    block = main.global_block()
-    (moe,) = [op for op in block.ops if op.type == "moe_ffn"]
-    init = dict(zip(
-        [moe.inputs[s][0] for s in ("RouterW", "GateUpW", "DownW")],
-        [w["router"], w["gate_up"][offset:offset + held],
-         w["down"][offset:offset + held]]))
-    shared = [p.name for p in block.all_parameters()
-              if p.name.startswith("shared_")]
-    assert [n.rsplit("_", 1)[0] for n in shared] == MOE[3:]
-    init.update(zip(shared, w["shared"]))
-    exe, scope = fluid.Executor(fluid.CPUPlace()), fluid.Scope()
-    with fluid.scope_guard(scope):
-        exe.run(startup)
-        for name, value in init.items():
-            assert tuple(np.asarray(scope.find_var(name)).shape) == (
-                value.shape), name
-            scope.set(name, jnp.asarray(value))
-        return exe.run(main, feed={"x": w["x"]}, fetch_list=[
-            y, moe.outputs["Y"][0], moe.outputs["TokensPerExpert"][0]])
-
-
 def test_the_sixteen_shares_and_the_gated_shared_expert_once_are_the_layer():
     """Sixteen chips hold two experts each of one layer.  Each routes
     (softmax, top-10, renormalised) over all thirty-two, computes its own
@@ -656,7 +626,9 @@ def test_the_sixteen_shares_and_the_gated_shared_expert_once_are_the_layer():
     assert np.abs(np.asarray(shared - ungated)).max() > 0.1  # the gate counts
     want_counts = np.bincount(np.asarray(top_e).reshape(-1),
                               minlength=2 * SHARES)
-    parts = [_share_through_the_executor(w, 2 * i, 2) for i in range(SHARES)]
+    parts = [share_through_the_executor(qwen3_next._experts, Wide, w, 2 * i,
+                                        2, shared_bases=MOE[3:])
+             for i in range(SHARES)]
     for both, part, counts in parts:
         np.testing.assert_array_equal(counts, want_counts)
         np.testing.assert_allclose(both - part, shared, rtol=1e-4, atol=1e-4)
