@@ -17,9 +17,12 @@ reads (``ro_state``) are not donated and stay valid across steps.
 """
 
 import contextlib
+import functools
+import time
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Format, Layout
 
 from ..profiler import phase
 from .registry import OPS, LowerCtx, get_op, lower_grad_op
@@ -448,24 +451,259 @@ def build_traced_function(program, block_idx, feed_names, fetch_names, scope,
                           step_stat_names(block, keep, updated, rw_names))
 
 
+def state_format(name, sharding):
+    """The format a step is compiled to take a donated read-write array
+    in, and to hand back the result that aliases it: the sharding it has,
+    the layout left to the compiler.  A value that is no array has no
+    sharding (None) and gets no format.  One rule for every array of
+    every program: what differs between programs is what the compiler
+    answers for their shapes."""
+    return None if sharding is None else Format(Layout.AUTO, sharding)
+
+
+def jit_step(traced, shardings, feed_shardings=None, key_sharding=None,
+             **options):
+    """The jit of a traced step, the one every run path that compiles
+    `traced.fn` for the device goes through (the flat path at a block's
+    first call, Executor._jit_spmd_step, tools/compile_cell_for_chip.py):
+    the read-write state donated and taken in `state_format`, and the
+    result that aliases each array handed back in the same.  So the state
+    LIVES in the layout the compiled step reads it in, where a default
+    layout makes the compiler transpose it in and out of every step (a
+    float32 [8, 2688, 1856] expert stack on a TPU: 1856 is 14.5 tiles of
+    128 lanes, the default puts 2688 minor, and every consumer wants 1856
+    minor).  Feeds, read-only state (other programs' executables share
+    it), the rng key, fetches (they go to the host) and fresh outputs keep
+    the default layout.
+
+    A layout left to the compiler is known only once it has compiled, so
+    the result is never called: `.lower(*avals).compile()` it
+    (CompiledBlock does), lay the arrays out in `input_formats`, call the
+    executable.  The jitted function returns (the read-write state's new
+    values, fetches, every other updated variable).  Two dicts, because a
+    format is given per result and `traced.fn` alone knows which updated
+    names its trace produces; the read-write state first, because JAX
+    pairs a donated argument with the first result of its shape and
+    dtype, in order, and refuses a pair of which one side's layout is the
+    compiler's and the other's is not: first, every array meets its own
+    new value.  (A variable whose new value has another shape or dtype
+    than the array it replaces cannot take its buffer: the array goes
+    back as it came, and the new value goes with the fresh outputs.)
+
+    `shardings`: {name: the sharding the array has} over
+    `traced.rw_names`, and over the read-only and fresh state too where a
+    mesh places them, as it gives `feed_shardings` and `key_sharding`
+    (a name left out, or None: as the argument comes); `options`: further
+    jax.jit arguments."""
+    formats = {n: state_format(n, shardings[n]) for n in traced.rw_names}
+    fresh = {n: shardings[n] for n in traced.updated
+             if n not in formats and n in shardings}
+
+    def aval(x):
+        return getattr(x, "shape", None), getattr(x, "dtype", None)
+
+    @functools.wraps(traced.fn)  # the module and every op_name keep its name
+    def program_step(feeds, ro_state, rw_state, rng_key):
+        fetches, new_state = traced.fn(feeds, ro_state, rw_state, rng_key)
+        kept = {n: new_state.pop(n) if aval(new_state[n]) == aval(old) else old
+                for n, old in rw_state.items()}
+        return kept, fetches, new_state
+
+    return jax.jit(
+        program_step,
+        in_shardings=(feed_shardings,
+                      {n: shardings.get(n) for n in traced.ro_names},
+                      formats, key_sharding),
+        out_shardings=(formats, None, fresh or None),
+        donate_argnums=(2,), **options)
+
+
 class CompiledBlock:
-    """One XLA executable for (program version, block, signature)."""
+    """One XLA executable for (program version, block, signature),
+    compiled ahead of its first call (jit_step says why): that call
+    lowers and compiles at the arguments' abstract signature, lays the
+    scope's read-write arrays out once in the formats the compiler chose
+    (a device_put of each array whose format differs; none for the
+    rest), and runs; from then on the step's results come back in those
+    formats and go in again unchanged.
 
-    def __init__(self, traced, jitted, feed_sig):
+    What the block compiled for is a signature as a jit's is, formats
+    included.  Where JAX refuses a later call's arguments (it checks
+    before it runs or donates anything), the block looks at what
+    arrived: read-write arrays in another format (a checkpoint loaded
+    into the scope) are laid out again; anything else that differs
+    (another program's executable left a parameter this one reads in
+    another layout, a shape changed) compiles again for what arrives, as
+    a jit would for a new signature.
+
+    An array is as good as its label (`relabelled` says what can go wrong
+    with one and what the block does about it)."""
+
+    def __init__(self, traced, feed_sig, jitted=None):
         self.traced = traced
-        self.jitted = jitted
         self.feed_sig = feed_sig
+        # jit_step's result; None until the first call's arrays say where
+        # the read-write state is placed
+        self.jitted = jitted
+        # the jax.stages.Compiled that runs, and how many were made
+        self.executable = None
+        self.compiles = 0
         # the trace_compile phase of the miss that made this block; the
-        # first call resumes it (ExecutionCache.miss)
+        # first call resumes it (ExecutionCache.miss), a later compile
+        # opens `again()`'s
         self.compiling = None
-        # abstract signature of the first call, so Executor.compiled_hlo
-        # can AOT-lower the same executable later
+        self.again = None
+        # abstract signature the executable was compiled at
         self.avals = None
+        # {name: format} of the read-write results this executable hands
+        # out under another format than it wrote them in (None: not looked
+        # at yet; {} for an executable compiled in this process)
+        self.mislabelled = None
 
-    def __call__(self, feeds, ro_state, rw_state, rng_key):
-        if self.avals is None:
-            self.avals = call_avals((feeds, ro_state, rw_state, rng_key))
-        return self.jitted(feeds, ro_state, rw_state, rng_key)
+    def __call__(self, feeds, ro_state, rw_state, rng_key, scope):
+        args = (feeds, ro_state, rw_state, rng_key)
+        if self.executable is None:
+            self._compile(args)
+            self._lay_out(rw_state, scope)
+        try:
+            kept, fetches, fresh = self.executable(*args)
+        except (TypeError, ValueError):
+            if not self._fit(args, scope):
+                raise
+            kept, fetches, fresh = self.executable(*args)
+        if self.mislabelled is None:  # this executable's first results
+            self.mislabelled = {
+                n: written for n, written in self._written().items()
+                if getattr(kept[n], "format", written) != written}
+        if self.mislabelled:
+            kept.update(relabelled({n: kept[n] for n in self.mislabelled},
+                                   self.mislabelled))
+        kept.update(fresh)
+        return fetches, kept
+
+    def _compile(self, args):
+        if self.jitted is None:
+            self.jitted = jit_step(
+                self.traced, {n: getattr(a, "sharding", None)
+                              for n, a in args[2].items()})
+        self.avals = call_avals(args)
+        self.executable = self.jitted.lower(*self.avals).compile()
+        self.compiles += 1
+        self.mislabelled = None
+
+    def _written(self):
+        """{name: format} the executable writes its read-write results in;
+        {} for an executable that reports no layouts (one with host
+        effects: JAX then compares none either)."""
+        try:
+            return self.executable.output_formats[0]
+        except AssertionError:
+            return {}
+
+    def _lay_out(self, rw_state, scope):
+        """Put every read-write array that is not in the format the
+        executable takes it in into that format, in `rw_state` and in the
+        scope (so that the array it replaces is freed before the step
+        needs the room), and count them in the block's trace_compile
+        record: `state_relayouts`, `state_relayout_s`."""
+        t0 = time.perf_counter()
+        # an argument the step never reads is pruned from the executable
+        # and has no layout there: any array will do
+        formats = {n: f for n, f in
+                   self.executable.input_formats[0][2].items()
+                   if getattr(f, "layout", f) is not None}
+        moved = {}
+        for n, f in formats.items():
+            if getattr(rw_state[n], "format", f) != f:
+                # one at a time, and waited for: the array it replaces is
+                # let go of before the next copy asks for room
+                moved[n] = jax.block_until_ready(
+                    jax.device_put(rw_state[n], f))
+                rw_state[n] = moved[n]
+                scope.set(n, moved[n])
+        for n, a in relabelled({n: a for n, a in moved.items()
+                                if a.format != formats[n]}, formats).items():
+            moved[n] = rw_state[n] = a
+            scope.set(n, a)
+        record = self.compiling.record["args"]
+        record["state_relayouts"] = (record.get("state_relayouts", 0)
+                                     + len(moved))
+        record["state_relayout_s"] = (record.get("state_relayout_s", 0.0)
+                                      + time.perf_counter() - t0)
+        return moved
+
+    def _fit(self, args, scope):
+        """After JAX refused `args`: make executable and arguments agree.
+        True where something was done about it."""
+        compiled_at = self.avals
+        if call_avals(args) != compiled_at:
+            with self.again() as compiling:
+                pass  # counted as a miss is, under a record of its own
+            self.compiling = compiling
+            with compiling:
+                self._compile(args)
+        return bool(self._lay_out(args[2], scope)
+                    or self.avals is not compiled_at)
+
+
+# {(name, shape, dtype, format), ...} -> the executable that relabels them
+_RELABELLERS = {}
+
+
+def relabelled(arrays, formats):
+    """`arrays` ({name: array}) under the labels `formats[name]`: the SAME
+    buffers, handed out as arrays that say the layout they are in.
+
+    Why that is ever needed (JAX 0.9.0): an array that comes out of an
+    executable READ FROM THE PERSISTENT COMPILATION CACHE says it is in
+    the default layout, whatever layout the executable wrote it in.  (The
+    runtime takes its results' layouts from the module's layout modes,
+    and a deserialized executable has no module; the Python side's
+    `output_formats` is right.)  The buffer is right and the label is
+    wrong, and everything that takes the array at its word goes wrong
+    with it: JAX refuses it to the executable that made it, and a jit
+    that reads it reads garbage.  This repo's benchmark runs every
+    process but the first warm, so what the compiler's layouts buy would
+    be lost exactly where it is measured.
+
+    The cure costs no copy: an identity over the arrays, each argument
+    taken and handed back in its true format and donated (so aliased: the
+    program moves nothing), compiled IN THIS PROCESS with the persistent
+    cache out of the way (its own results would come mislabelled from
+    there), and run on the runtime's executable directly, because JAX's
+    own call would refuse the arguments for their labels.  {} in, {} out:
+    where every label is right, nothing here runs."""
+    if not arrays:
+        return {}
+    from jax.experimental.compilation_cache import compilation_cache
+
+    names = sorted(arrays)
+    key = tuple((n, arrays[n].shape, str(arrays[n].dtype), formats[n])
+                for n in names)
+    if key not in _RELABELLERS:
+        true = {n: formats[n] for n in names}
+        cached = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            _RELABELLERS[key] = jax.jit(
+                lambda state: state, in_shardings=(true,),
+                out_shardings=true, donate_argnums=(0,)).lower(
+                    {n: jax.ShapeDtypeStruct(
+                        arrays[n].shape, arrays[n].dtype,
+                        sharding=formats[n].sharding) for n in names}
+                ).compile().runtime_executable()
+        finally:
+            jax.config.update("jax_enable_compilation_cache", cached)
+            compilation_cache.reset_cache()
+    shards = _RELABELLERS[key].execute_sharded(
+        [arrays[n] for n in names]).disassemble_into_single_device_arrays()
+    # an array on one device is its one shard; over a mesh, the shards
+    # are put together again under the sharding they came in
+    return {n: of_one[0] if len(arrays[n].sharding.device_set) == 1
+            else jax.make_array_from_single_device_arrays(
+                arrays[n].shape, formats[n].sharding, of_one)
+            for n, of_one in zip(names, shards)}
 
 
 def sig_text(feed_sig):
@@ -477,15 +715,29 @@ def sig_text(feed_sig):
 
 
 def call_avals(args):
-    """Abstract twin of a call's arguments.  An uncommitted array (the
-    per-step rng key) keeps no sharding: pinning it would change the
-    lowered module, and with it the compilation-cache key, so the AOT
-    compile would miss the entry the jit call just wrote."""
-    return jax.tree_util.tree_map(
-        lambda x: jax.ShapeDtypeStruct(
-            x.shape, x.dtype,
-            sharding=x.sharding if getattr(x, "committed", True) else None),
-        args)
+    """Abstract twin of a step's arguments (feeds, ro_state, rw_state, rng
+    key): what CompiledBlock lowers at, and the signature it compiled
+    for.  A read-only array keeps its whole format: a parameter that
+    another program's executable laid out is read in the layout it
+    arrives in, as a jit with no layout given reads it.  A read-write
+    array keeps its sharding alone: its layout is jit_step's to leave to
+    the compiler.  An uncommitted array (the per-step rng key) keeps no
+    sharding: pinning it would change the lowered module, and with it the
+    compilation-cache key."""
+    def twin(placement):
+        def aval(x):
+            return jax.ShapeDtypeStruct(
+                x.shape, x.dtype, weak_type=getattr(x, "weak_type", False),
+                sharding=(getattr(x, placement)
+                          if getattr(x, "committed", True) else None))
+        return aval
+
+    feeds, ro_state, rw_state, rng_key = args
+    tree_map = jax.tree_util.tree_map
+    return (tree_map(twin("sharding"), feeds),
+            tree_map(twin("format"), ro_state),
+            tree_map(twin("sharding"), rw_state),
+            twin("sharding")(rng_key))
 
 
 class ExecutionCache:
@@ -499,7 +751,7 @@ class ExecutionCache:
         # occupancy churn must change feed VALUES only, never keys
         self.compile_count = 0
 
-    def get(self, program, block_idx, feed_sig, fetch_names, scope, donate=True,
+    def get(self, program, block_idx, feed_sig, fetch_names, scope,
             platform=None):
         key = (
             id(program),
@@ -517,10 +769,9 @@ class ExecutionCache:
             traced = build_traced_function(
                 program, block_idx, feed_names, fetch_names, scope,
                 platform=platform)
-            jitted = jax.jit(traced.fn,
-                             donate_argnums=(2,) if donate else ())
-            compiled = CompiledBlock(traced, jitted, feed_sig)
+            compiled = CompiledBlock(traced, feed_sig)
         compiled.compiling = compiling
+        compiled.again = lambda: self.miss(program, feed_sig, "flat")
         self._cache[key] = compiled
         return compiled
 

@@ -17,7 +17,7 @@ import jax.numpy as jnp
 
 from . import framework
 from .core import scope as scope_mod
-from .core.trace import ExecutionCache, call_avals
+from .core.trace import CompiledBlock, ExecutionCache, jit_step
 from .places import CPUPlace, default_place
 from .profiler import RecordEvent
 
@@ -51,19 +51,20 @@ def _dtype_kind(dt):
 class CompiledStep:
     """One executable an Executor has run for a program
     (Executor.compiled_steps): the run path that owns it ("flat" for
-    _run_fast / _run_slow, "spmd"), the feeds of its first call as
-    {name: (shape, dtype)}, its fetch names, and `hlo()`, its optimized
-    HLO text, AOT-lowered again at that first call's signature."""
+    _run_fast / _run_slow, "spmd"), the feeds it was compiled at as
+    {name: (shape, dtype)}, its fetch names, and `hlo()`, the optimized
+    HLO text of the executable that runs (core/trace.CompiledBlock keeps
+    it: nothing is lowered again)."""
 
-    def __init__(self, path, traced, jitted, avals):
+    def __init__(self, path, block):
         self.path = path
         self.feeds = {n: (tuple(a.shape), str(a.dtype))
-                      for n, a in avals[0].items()}
-        self.fetches = list(traced.fetch_names)
-        self._jitted, self._avals = jitted, avals
+                      for n, a in block.avals[0].items()}
+        self.fetches = list(block.traced.fetch_names)
+        self._jitted, self._executable = block.jitted, block.executable
 
     def hlo(self):
-        return self._jitted.lower(*self._avals).compile().as_text()
+        return self._executable.as_text()
 
 
 class Executor:
@@ -489,8 +490,9 @@ class Executor:
         timed = get_flag("benchmark")
         t0 = time.time() if timed else None
         fetches, new_state = self._dispatch(
-            compiled, (feed_arrays, ro_state, rw_state, key),
-            compiling=compiled.compiling if compiled.avals is None else None)
+            compiled, (feed_arrays, ro_state, rw_state, key, scope),
+            compiling=(compiled.compiling if compiled.executable is None
+                       else None))
         if timed:
             # FLAGS_benchmark contract: per-run timing log with a device
             # barrier so the number is real
@@ -537,31 +539,22 @@ class Executor:
     @staticmethod
     def _jit_spmd_step(traced, mesh, feed_shardings, state_shardings):
         """(jitted step, its compile options): `traced.fn` jitted over
-        `mesh` with the feeds' and the state's shardings in and out, rw
-        state donated, and the compile options the mesh calls for
+        `mesh` (core/trace.jit_step: rw state donated, its layouts the
+        compiler's) with the feeds' and the state's shardings in and out
+        and the compile options the mesh calls for
         (parallel.mesh.mesh_compile_options: asynchronous collectives on a
         mesh of TPUs, none on any other).  The run path's one jit site:
-        compiled_steps hands the readers this same object, so
-        compiled_hlo re-lowers with the same options, and a test compiles
-        it for a described topology."""
+        the CompiledBlock that runs compiles this same object, and a test
+        compiles it for a described topology."""
         from jax.sharding import NamedSharding, PartitionSpec
 
         from .parallel.mesh import mesh_compile_options
 
         options = mesh_compile_options(mesh)
-        sh = state_shardings
-        jitted = jax.jit(
-            traced.fn,
-            in_shardings=(
-                feed_shardings,
-                {n: sh[n] for n in traced.ro_names},
-                {n: sh[n] for n in traced.rw_names},
-                NamedSharding(mesh, PartitionSpec()),
-            ),
-            out_shardings=(None, {n: sh[n] for n in traced.updated}),
-            donate_argnums=(2,),
-            compiler_options=options,
-        )
+        jitted = jit_step(
+            traced, state_shardings, feed_shardings=feed_shardings,
+            key_sharding=NamedSharding(mesh, PartitionSpec()),
+            compiler_options=options)
         return jitted, options
 
     def _run_spmd(self, program, feed, fetch_names, scope, return_numpy,
@@ -638,7 +631,7 @@ class Executor:
             cache = self._spmd_cache = {}
         key_id = (id(program), program._version, feed_sig,
                   tuple(fetch_names), id(scope))
-        entry, compiling = cache.get(key_id), None
+        entry = cache.get(key_id)
         if entry is None:
             from .core.trace import build_traced_function
 
@@ -657,10 +650,11 @@ class Executor:
                     traced, mesh,
                     {n: a.sharding for n, a in feed_arrays.items()}, sh)
                 compiling.record["args"]["compiler_options"] = sorted(options)
-            # avals[0] records the first call's abstract args so
-            # compiled_hlo can AOT-lower the same signature later
-            entry = cache[key_id] = (traced, jitted, sh, [None])
-        traced, jitted, sh, avals = entry
+            block = CompiledBlock(traced, feed_sig, jitted)
+            block.compiling = compiling
+            block.again = lambda: self._cache.miss(program, feed_sig, "spmd")
+            entry = cache[key_id] = (block, sh)
+        block, sh = entry
 
         def commit(n):
             v = scope.find_var(n)
@@ -671,15 +665,14 @@ class Executor:
             scope.set(n, arr)
             return arr
 
+        traced = block.traced
         ro_state, rw_state = self._gather(commit, traced.ro_names,
                                           traced.rw_names)
         key = jax.device_put(
             self._rng_key(program, mesh.devices.flat[0].platform), repl)
-        args = (feed_arrays, ro_state, rw_state, key)
-        if avals[0] is None:
-            avals[0] = call_avals(args)
         fetches, new_state = self._dispatch(
-            jitted, args, compiling=compiling)
+            block, (feed_arrays, ro_state, rw_state, key, scope),
+            compiling=block.compiling if block.executable is None else None)
         self._commit(scope, new_state, program, traced.stat_names)
         return self._fetched(fetches, return_numpy)
 
@@ -778,13 +771,13 @@ class Executor:
         (names, shapes, dtypes) and fetch names of the run that made it —
         what a reader needs to run the same step again and hit the same
         executable — and its optimized HLO on demand."""
-        steps = [CompiledStep("flat", cb.traced, cb.jitted, cb.avals)
+        steps = [CompiledStep("flat", cb)
                  for cb in self._cache.blocks_for(program)
-                 if cb.avals is not None]
-        for key, (traced, jitted, _sh, avals) in (
+                 if cb.executable is not None]
+        for key, (block, _sh) in (
                 getattr(self, "_spmd_cache", None) or {}).items():
-            if key[0] == id(program) and avals[0] is not None:
-                steps.append(CompiledStep("spmd", traced, jitted, avals[0]))
+            if key[0] == id(program) and block.executable is not None:
+                steps.append(CompiledStep("spmd", block))
         return steps
 
     def compiled_hlo(self, program):
@@ -793,9 +786,8 @@ class Executor:
         executes: custom calls that survived, collectives the
         partitioner emitted, and in every instruction's `op_name` the
         `<op_role>/<op type>/<index>` scope of the Fluid op it came from
-        (core/trace.py).  Costs a re-trace plus a compile (a
-        persistent-cache read where the step took long enough to be
-        written)."""
+        (core/trace.py).  The text of the executables that run: nothing
+        is traced or compiled again."""
         return [step.hlo() for step in self.compiled_steps(program)]
 
     def spmd_comm_stats(self, program):

@@ -136,9 +136,9 @@ def test_no_hidden_recompile_across_steps():
         for _ in range(3):
             exe.run(main, feed={"x": xv, "y": yv}, fetch_list=[loss])
     for compiled in exe._cache._cache.values():
-        assert compiled.jitted._cache_size() == 1, (
+        assert compiled.compiles == 1, (
             "hidden recompile: one ExecutionCache entry compiled %d times"
-            % compiled.jitted._cache_size())
+            % compiled.compiles)
 
 
 def test_run_loop_matches_sequential_runs():

@@ -300,15 +300,16 @@ def test_tied_table_step_under_dp2_mp2_mesh(vocab, monkeypatch):
         return
     assert _spec_of(sc, "emb.w_0") == ("mp", None)
     assert placed == {} and rules.uneven_log == []
-    (_traced, jitted, _sh, avals), = exe._spmd_cache.values()
-    with_mechanism = jitted.trace(*avals[0]).lower().as_text()
+    (block, _sh), = exe._spmd_cache.values()
+    jitted, avals = block.jitted, block.avals
+    with_mechanism = jitted.trace(*avals).lower().as_text()
     for mod in (math_ops, tensor_ops):
         monkeypatch.setattr(mod, "rule_sharded_weight",
                             lambda ctx, op_type, slot, w: w)
     monkeypatch.setattr(spmd_epilogue, "grad_in_param_storage",
                         lambda op, ins: ins)
     jax.clear_caches()
-    assert jitted.trace(*avals[0]).lower().as_text() == with_mechanism
+    assert jitted.trace(*avals).lower().as_text() == with_mechanism
 
 
 @needs_four_devices
@@ -556,10 +557,10 @@ def test_cpu_mesh_step_compiles_with_no_option_and_matches_one_device():
 @needs_four_devices
 def test_run_path_and_compiled_hlo_compile_with_the_same_options(
         monkeypatch):
-    """Whatever mesh_compile_options answers reaches BOTH the executable
-    that runs and the one compiled_hlo re-lowers for the readers (an
-    option the CPU compiler takes stands in for the TPU's), and the
-    trace_compile record names it."""
+    """Whatever mesh_compile_options answers reaches the executable that
+    runs, which is the one compiled_hlo reads for the readers (an option
+    the CPU compiler takes stands in for the TPU's), and the trace_compile
+    record names it."""
     from paddle_tpu import profiler
     from paddle_tpu.parallel import mesh as mesh_mod
 
@@ -568,8 +569,8 @@ def test_run_path_and_compiled_hlo_compile_with_the_same_options(
 
     def jit(fn, **kw):
         jitted = real_jit(fn, **kw)
-        if "in_shardings" in kw:  # the mesh step, not a helper jit
-            lowered_with.append((jitted, kw.get("compiler_options")))
+        if "compiler_options" in kw:  # the mesh step, no other jit
+            lowered_with.append((jitted, kw["compiler_options"]))
         return jitted
 
     monkeypatch.setattr(jax, "jit", jit)
@@ -830,3 +831,166 @@ def test_an_engaged_fc_compiles_for_the_described_chip_with_a_fused_epilogue(
               "engaged %d bytes, rule off %d bytes (%+d)"
               % (temp, temp_off, temp - temp_off))
     assert temp - temp_off <= 2 * (4096 * 4096 * 2) * 1.1
+
+
+def _expert_layer_step(topo):
+    """One Nemotron-3-Nano expert layer at the published widths the layout
+    turns on (hidden 2688, `moe_intermediate_size` 1856 = 14.5 tiles of 128
+    lanes; two experts held, T = 512), its train step compiled for one chip
+    of the described v5e:2x2 through the run paths' own jit
+    (core/trace.jit_step), over a scope of shapes."""
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from paddle_tpu.core.trace import build_traced_function, jit_step
+    from paddle_tpu.models import nemotron_h
+
+    class OneExpertLayer(nemotron_h.NemotronHConfig):
+        vocab_size, hidden_size, num_hidden_layers = 512, 2688, 1
+        hybrid_override_pattern = "E"
+        moe_intermediate_size, moe_shared_expert_intermediate_size = 1856, 256
+        n_routed_experts, num_experts_per_tok = 8, 2
+        num_local_experts, expert_offset = 2, 2
+
+    _fresh()
+    chip = SingleDeviceSharding(topo.devices[0])
+    with fluid.unique_name.guard():
+        main, startup, _, fetches = nemotron_h.nemotron_h_lm_program(
+            OneExpertLayer, seq_len=512, lr=1e-3, use_bf16=True)
+    scope = scope_mod.Scope()
+    for block in (main.global_block(), startup.global_block()):
+        for name, var in block.vars.items():
+            if var.persistable and all(int(d) >= 0 for d in var.shape):
+                scope.set(name, jax.ShapeDtypeStruct(
+                    tuple(int(d) for d in var.shape),
+                    jnp.dtype(str(var.dtype)), sharding=chip))
+    feeds = {n: jax.ShapeDtypeStruct((2, 512), dt, sharding=chip)
+             for n, dt in (("ids", jnp.int32), ("labels", jnp.int32),
+                           ("loss_weight", jnp.float32))}
+    traced = build_traced_function(
+        main, 0, tuple(sorted(feeds)), [fetches[0].name], scope,
+        platform="tpu")
+    key = jax.eval_shape(lambda: jax.random.key(1, impl="rbg"))
+    return jit_step(traced, {n: chip for n in traced.rw_names}).lower(
+        feeds, {n: scope.find_var(n) for n in traced.ro_names},
+        {n: scope.find_var(n) for n in traced.rw_names},
+        jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=chip)).compile()
+
+
+def test_the_donated_state_is_compiled_in_the_layout_the_step_reads(
+        v5e_2x2, monkeypatch):
+    """ISSUE 60, compile only (nothing runs).  The device's default layout
+    of a float32 [E, 2688, 1856] array puts 2688 minor, and every consumer
+    of the up-projection experts and of their two Adam moments wants 1856
+    minor.  Compiled as the run paths compile it, the step takes and
+    returns the three arrays 1856-minor, aliased, and its entry computation
+    copies no read-write matrix.  The control, with the rule switched off
+    HERE (the program has no such option): the arrays come 2688-minor and
+    the entry computation transposes them with `copy`s."""
+    from paddle_tpu.core import trace
+    from paddle_tpu.ops import pallas_kernels
+
+    names = ("moe_up.w_0", "moe_up.w_0_moment1_0", "moe_up.w_0_moment2_0")
+
+    def read(compiled):
+        text = compiled.as_text()
+        entry = text[text.index("\nENTRY "):]
+        params = dict(
+            (name, (layout, int(number))) for name, layout, number in
+            re.findall(r"%rw_state__(\w+?)__\.\d+ = f32\[2,2688,1856\]"
+                       r"(\{[0-9,]+)[^ ]* parameter\((\d+)\)", entry))
+        copied = re.findall(
+            r"= \w+\[\d+,[0-9,]+\]\S* copy\(%rw_state__(\w+?)__\.\d+\)",
+            entry)
+        aliased = set(int(n) for n in re.findall(
+            r"\}: \((\d+), \{\}, (?:may|must)-alias\)",
+            text[:text.index("\n")]))
+        return params, copied, aliased
+
+    monkeypatch.setattr(pallas_kernels, "_interpret", lambda: False)
+    with _no_compilation_cache():
+        jax.clear_caches()  # an interpreted trace of these shapes would hide
+        compiled = _expert_layer_step(v5e_2x2)
+        params, copied, aliased = read(compiled)
+        monkeypatch.setattr(trace, "state_format",
+                            lambda name, sharding: None)
+        as_it_was, copied_before, _ = read(_expert_layer_step(v5e_2x2))
+    jax.clear_caches()
+    dotted = [n.replace(".", "_") for n in names]
+    assert {n: params[n][0] for n in dotted} == dict.fromkeys(
+        dotted, "{2,1,0")
+    assert copied == []
+    assert {params[n][1] for n in dotted} <= aliased
+    formats = compiled.input_formats[0][2]
+    outs = compiled.output_formats[0]
+    for n in names:
+        assert formats[n].layout.major_to_minor == (0, 1, 2)
+        assert outs[n] == formats[n]
+    assert {n: as_it_was[n][0] for n in dotted} == dict.fromkeys(
+        dotted, "{1,2,0")
+    assert set(copied_before) & set(dotted)
+
+
+@needs_four_devices
+def test_a_mesh_step_keeps_its_state_in_the_layout_it_was_compiled_for(
+        monkeypatch, tmp_path):
+    """The mesh path's blocks are the flat path's (core/trace.CompiledBlock
+    over _jit_spmd_step's jit).  With every rank-2 Adam moment put
+    column-major HERE (the CPU's compiler asks for nothing; the program
+    has no such option) three dp2 x mp2 steps end on the losses of the run
+    that asks for no format, to the bit: compiled in this process, and
+    read back from the persistent cache, whose results JAX hands out
+    mislabelled (trace.relabelled puts the labels right, shard by
+    shard)."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.experimental.layout import Format, Layout
+
+    from paddle_tpu import profiler
+    from paddle_tpu.core import trace
+
+    mesh = make_mesh({"dp": 2, "mp": 2}, jax.devices()[:4])
+    _fresh()
+    main, startup, _, _ = gpt2.gpt2_lm_program(TinyHP, seq_len=8, lr=3e-3,
+                                               mesh=mesh)
+    rank = {n: len(v.shape) for blk in (main.global_block(),
+                                        startup.global_block())
+            for n, v in blk.vars.items() if v.persistable}
+
+    def column_major_moments(name, sharding):
+        order = tuple(range(rank[name]))
+        turned = rank[name] == 2 and "moment" in name
+        return Format(Layout(order[::-1] if turned else order, ()), sharding)
+
+    def train(state_format):
+        jax.clear_caches()
+        monkeypatch.setattr(trace, "state_format", state_format)
+        losses, scope, main, exe = _train(mesh, steps=3)
+        (block, _sh), = exe._spmd_cache.values()
+        record = [r["args"] for r in profiler.phases()
+                  if r["name"] == "trace_compile"
+                  and r["args"].get("program") == id(main)][-1]
+        turned = [n for n in block.traced.rw_names
+                  if scope.find_var(n).format.layout.major_to_minor == (1, 0)]
+        return losses, record["state_relayouts"], len(turned), block
+
+    plain, none, _, _ = train(lambda name, sharding: None)
+    configured = {n: getattr(jax.config, n) for n in (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes")}
+    for n, v in zip(configured, (str(tmp_path), 0.0, 0)):
+        jax.config.update(n, v)
+    compilation_cache.reset_cache()
+    try:
+        cold, moved, turned, block = train(column_major_moments)
+        warm, moved_again, turned_again, block_again = train(
+            column_major_moments)
+    finally:
+        for n, v in configured.items():
+            jax.config.update(n, v)
+        compilation_cache.reset_cache()
+        jax.clear_caches()
+    assert none == 0 and moved == moved_again == turned == turned_again > 0
+    assert block.mislabelled == {}
+    assert len(block_again.mislabelled) in (0, moved)
+    assert cold == plain and warm == plain

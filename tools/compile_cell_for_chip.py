@@ -4,7 +4,13 @@ compiler's memory counts: what the chip's compiler would refuse costs no
 chip time (on-chip-measurement guide, section 2).  Nothing runs: no time,
 no result.  One-chip cells only.  Pallas kernels are compiled as Mosaic
 calls, as on the chip (until PR 46 they were interpreted here, and the
-counts read 0.05 GiB lower on `kimi_linear_48b_a3b_train`).
+counts read 0.05 GiB lower on `kimi_linear_48b_a3b_train`).  The step is
+jitted by the Executor's own helper (core/trace.jit_step: the read-write
+state's layouts are the compiler's), so the counts and `--hlo` are of the
+step that runs; `state_relayouts` names the arrays the compiler takes in
+another layout than the device's default, and the last line sums the
+`copy` instructions of the entry computation (a transposing copy of a
+parameter is the state's layout not suiting the step).
 
     JAX_PLATFORMS=cpu python tools/compile_cell_for_chip.py \
         --workload kanana2_30b_a3b_train [--seq-len 6144]
@@ -14,11 +20,30 @@ The process that describes the topology holds libtpu until it exits."""
 import argparse
 import importlib.util
 import os
+import re
 import sys
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+
+_ITEM = {"f32": 4, "s32": 4, "u32": 4, "bf16": 2, "f16": 2, "s8": 1, "u8": 1,
+         "pred": 1, "f64": 8, "s64": 8, "u64": 8}
+
+
+def entry_copies(text):
+    """Bytes each `copy` instruction of the ENTRY computation writes."""
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    sizes = []
+    for dtype, dims in re.findall(
+            r"= (\w+)\[([0-9,]*)\][^ ]* copy\(", entry):
+        n = _ITEM.get(dtype, 4)
+        for d in dims.split(","):
+            n *= int(d) if d else 1
+        sizes.append(n)
+    return sizes
 
 
 def main():
@@ -36,7 +61,7 @@ def main():
     from jax.sharding import SingleDeviceSharding
 
     import paddle_tpu as fluid
-    from paddle_tpu.core.trace import build_traced_function
+    from paddle_tpu.core.trace import build_traced_function, jit_step
     from paddle_tpu.ops import pallas_kernels
 
     # the Pallas kernels as the chip compiles them: this host's backend is
@@ -83,26 +108,43 @@ def main():
         return jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=chip)
 
     key = jax.eval_shape(lambda: jax.random.key(1, impl="rbg"))
-    compiled = jax.jit(traced.fn, donate_argnums=(2,)).lower(
+    rw = {n: shaped(n) for n in traced.rw_names}
+    compiled = jit_step(traced, {n: chip for n in rw}).lower(
         {n: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip)
          for n, a in batch.items()},
-        {n: shaped(n) for n in traced.ro_names},
-        {n: shaped(n) for n in traced.rw_names},
+        {n: shaped(n) for n in traced.ro_names}, rw,
         jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=chip),
     ).compile()
+    taken = compiled.input_formats[0][2]
+    default = jax.jit(lambda state: state).lower(rw).compile(
+        ).input_formats[0][0]
+    relaid = sorted(n for n in rw if taken[n].layout != default[n].layout)
     m = compiled.memory_analysis()
     gib = 2.0 ** 30
-    print("cell %s seq_len %d: arguments %.2f GiB, temporaries %.2f GiB, "
+    print("cell %s seq_len %s: arguments %.2f GiB, temporaries %.2f GiB, "
           "output %.2f GiB, aliased %.2f GiB, generated code %.2f GiB: "
           "arguments + temporaries + output - aliased = %.2f GiB"
-          % (cell["name"], int(work["seq_len"]),
+          % (cell["name"], work.get("seq_len", "-"),
              m.argument_size_in_bytes / gib, m.temp_size_in_bytes / gib,
              m.output_size_in_bytes / gib, m.alias_size_in_bytes / gib,
              m.generated_code_size_in_bytes / gib,
              (m.argument_size_in_bytes + m.temp_size_in_bytes
               + m.output_size_in_bytes - m.alias_size_in_bytes) / gib))
+    print("state_relayouts: %d of %d read-write arrays" % (len(relaid),
+                                                           len(rw)))
+    by_kind = {}
+    for n in relaid:
+        by_kind.setdefault((str(rw[n].dtype), tuple(rw[n].shape),
+                            taken[n].layout.major_to_minor), []).append(n)
+    for (dtype, shape, order), names in sorted(by_kind.items()):
+        print("  %d x %s%s -> major to minor %s: %s%s" % (
+            len(names), dtype, list(shape), list(order),
+            ", ".join(names[:3]), ", ..." if len(names) > 3 else ""))
     text = compiled.as_text()
     print("tpu_custom_call instructions: %d" % text.count("tpu_custom_call"))
+    copies = entry_copies(text)
+    print("entry computation: %d copy instructions, %.2f GB written"
+          % (len(copies), sum(copies) / 1e9))
     if args.hlo:
         with open(args.hlo, "w") as f:
             f.write(text)
