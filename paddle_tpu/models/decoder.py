@@ -8,7 +8,10 @@ the same in every one of them, so that a model file imports this module and
   feed-forward  `swiglu_mlp`; `routed_experts`, the one `layers.moe_ffn`
                 call under `models/`, and `beside_shared`, a shared branch
                 added to it.  A model's own `_experts(h, hp, is_test)` maps
-                its published config keys onto them.
+                its published config keys onto them; `deepseek_v3_block`
+                (with its `_check` and `_experts`) is the whole block of
+                the published `deepseek_v3` modeling code, which more than
+                one model stacks.
   recurrences   `LogUniform`, `InverseSoftplusOfLogUniform` and the ranges
                 the delta-rule and state-space mixers draw their decay from.
   the program   `xent_cost` and `lm_train_program`: feeds, the weighted
@@ -32,8 +35,10 @@ from . import transformer as tfm
 __all__ = [
     "EXPERT_BIAS_STD", "L2_EPS", "A_RANGE", "DT_RANGE", "weight",
     "norm_or_weight", "fc", "swiglu_mlp", "routed_experts", "beside_shared",
-    "LogUniform", "InverseSoftplusOfLogUniform", "xent_cost",
-    "leave_eval_rows", "balance_expert_biases", "lm_train_program",
+    "NORM_TOPK_EPS", "deepseek_v3_check", "deepseek_v3_experts",
+    "deepseek_v3_block", "LogUniform", "InverseSoftplusOfLogUniform",
+    "xent_cost", "leave_eval_rows", "balance_expert_biases",
+    "lm_train_program",
 ]
 
 # A selection bias (LFM2's expert_bias, the DeepSeek-V3 family's
@@ -107,6 +112,86 @@ def beside_shared(h, routed, shared):
         return routed
     with framework.name_scope("shared_expert"):
         return layers.elementwise_add(shared(h), routed)
+
+
+# --------------------------------------------------------------------------
+# the block of the published `deepseek_v3` modeling code, under the keys of
+# its config.json: what kanana-2 and JoyAI-LLM-Flash stack (Kimi-Linear's
+# block mixes two kinds of mixer under other keys and stays in its file)
+# --------------------------------------------------------------------------
+# what the family adds to the chosen scores' sum before it divides
+NORM_TOPK_EPS = 1e-20
+
+
+def deepseek_v3_check(hp):
+    """What the builder would have to guess, it refuses."""
+    if hp.n_group != 1 or hp.topk_group != 1:
+        raise NotImplementedError(
+            "n_group %r / topk_group %r: the router here chooses among all "
+            "experts at once (one group, where the group limit is the "
+            "identity)" % (hp.n_group, hp.topk_group))
+    if hp.scoring_func != "sigmoid" or hp.topk_method != "noaux_tc":
+        raise NotImplementedError(
+            "scoring_func %r / topk_method %r: the router here is sigmoid "
+            "scores with a selection bias (noaux_tc)"
+            % (hp.scoring_func, hp.topk_method))
+    if hp.rope_scaling is not None:
+        raise NotImplementedError(
+            "rope_scaling %r: rotary_embed has no scaled frequencies and "
+            "the softmax scale no mscale" % (hp.rope_scaling,))
+    if hp.moe_layer_freq != 1:
+        raise NotImplementedError(
+            "moe_layer_freq %r: every layer after the leading dense ones "
+            "is an expert layer here" % (hp.moe_layer_freq,))
+    if hp.num_key_value_heads != hp.num_attention_heads:
+        raise ValueError(
+            "num_key_value_heads %d is not num_attention_heads %d: latent "
+            "attention expands a key and a value for every head"
+            % (hp.num_key_value_heads, hp.num_attention_heads))
+    if hp.tie_word_embeddings:
+        raise NotImplementedError("the published head is untied")
+
+
+def deepseek_v3_experts(h, hp, is_test):
+    """Shared(h) + Routed(h): one `moe_ffn` with the `noaux_tc` router
+    beside the `n_shared_experts` shared experts as ONE SwiGLU MLP."""
+    routed, _ = routed_experts(
+        h, is_test, hp.n_routed_experts, hp.moe_intermediate_size,
+        hp.num_experts_per_tok, norm_topk_prob=hp.norm_topk_prob,
+        router="sigmoid",
+        expert_bias_attr=weight("moe_e_score_correction_bias.b",
+                                EXPERT_BIAS_STD),
+        num_local_experts=hp.num_local_experts,
+        expert_offset=hp.expert_offset,
+        routed_scaling_factor=hp.routed_scaling_factor,
+        norm_topk_eps=NORM_TOPK_EPS)
+
+    def shared(h):
+        return swiglu_mlp(h, hp.n_shared_experts * hp.moe_intermediate_size,
+                          hp.hidden_size, "shared_ffn")
+
+    return beside_shared(h, routed, shared if hp.n_shared_experts else None)
+
+
+def deepseek_v3_block(x, hp, i, is_test):
+    """x += MLA(rms(x)); x += F_i(rms(x)), F_i the dense SwiGLU MLP in the
+    first `first_k_dense_replace` layers and the experts after them; a
+    `q_lora_rank` puts a latent under the query."""
+    h = layers.rms_norm(x, hp.rms_norm_eps,
+                        param_attr=tfm.named("attn_norm.w"))
+    a = tfm.latent_attention(
+        h, hp.num_attention_heads, hp.kv_lora_rank, hp.qk_nope_head_dim,
+        hp.qk_rope_head_dim, hp.v_head_dim, norm_eps=hp.rms_norm_eps,
+        rotary_base=float(hp.rope_theta),
+        rotary_interleaved=bool(hp.rope_interleave),
+        param_attr=norm_or_weight, q_lora_rank=hp.q_lora_rank)
+    x = layers.elementwise_add(x, a)
+    h = layers.rms_norm(x, hp.rms_norm_eps,
+                        param_attr=tfm.named("ffn_norm.w"))
+    m = (swiglu_mlp(h, hp.intermediate_size, hp.hidden_size, "ffn")
+         if i < hp.first_k_dense_replace
+         else deepseek_v3_experts(h, hp, is_test))
+    return layers.elementwise_add(x, m)
 
 
 class LogUniform(Initializer):

@@ -25,25 +25,24 @@ No bias anywhere.
            MLP of n_shared_experts x moe_intermediate_size (the published
            code builds them so), computed alike on every chip.
 
-The train-program plumbing is `decoder.lm_train_program`;
-`kanana2_reference.py` is the plain float32 statement of the same
-equations.
+The block is `decoder.deepseek_v3_block` (the family's published modeling
+code; `models/joyai_flash.py` builds its trunk and its prediction module
+from the same function), the train-program plumbing
+`decoder.lm_train_program`; `kanana2_reference.py` is the plain float32
+statement of the same equations.
 """
 
 from .. import layers
 from . import transformer as tfm
-from .decoder import (EXPERT_BIAS_STD, beside_shared, fc, lm_train_program,
-                      norm_or_weight, routed_experts, swiglu_mlp, weight,
-                      xent_cost)
+from .decoder import (deepseek_v3_block, deepseek_v3_check, fc,
+                      lm_train_program, weight, xent_cost)
 
 __all__ = ["Kanana2Config", "kanana2_lm", "kanana2_lm_program"]
 
-# e_score_correction_bias is a buffer in the published modeling code, zero
+# (e_score_correction_bias is a buffer in the published modeling code, zero
 # at initialisation, and the training rule that moves it is not in the
-# config: seeded and balanced as `decoder.EXPERT_BIAS_STD` says.
-# what the family adds to the chosen scores' sum before it divides
-_NORM_TOPK_EPS = 1e-20
-# what a forward-only program leaves in the scope: every token's
+# config: seeded and balanced as `decoder.EXPERT_BIAS_STD` says.)
+# What a forward-only program leaves in the scope: every token's
 # cross-entropy, [B, T] float32 (an evaluation pairs it with a reference's)
 EVAL_ROWS = "kanana2_eval_rows"
 
@@ -88,83 +87,14 @@ class Kanana2Config:
     partition_family = "gpt2"
 
 
-def _check(hp):
-    """What the builder would have to guess, it refuses."""
-    if hp.n_group != 1 or hp.topk_group != 1:
-        raise NotImplementedError(
-            "n_group %r / topk_group %r: the router here chooses among all "
-            "experts at once (one group, where the group limit is the "
-            "identity)" % (hp.n_group, hp.topk_group))
-    if hp.scoring_func != "sigmoid" or hp.topk_method != "noaux_tc":
-        raise NotImplementedError(
-            "scoring_func %r / topk_method %r: the router here is sigmoid "
-            "scores with a selection bias (noaux_tc)"
-            % (hp.scoring_func, hp.topk_method))
-    if hp.q_lora_rank is not None:
-        raise NotImplementedError(
-            "q_lora_rank %r: latent_attention projects the query straight "
-            "from the hidden state" % (hp.q_lora_rank,))
-    if hp.rope_scaling is not None:
-        raise NotImplementedError(
-            "rope_scaling %r: rotary_embed has no scaled frequencies and "
-            "the softmax scale no mscale" % (hp.rope_scaling,))
-    if hp.moe_layer_freq != 1:
-        raise NotImplementedError(
-            "moe_layer_freq %r: every layer after the leading dense ones "
-            "is an expert layer here" % (hp.moe_layer_freq,))
-    if hp.num_key_value_heads != hp.num_attention_heads:
-        raise ValueError(
-            "num_key_value_heads %d is not num_attention_heads %d: latent "
-            "attention expands a key and a value for every head"
-            % (hp.num_key_value_heads, hp.num_attention_heads))
-
-
-def _experts(h, hp, is_test):
-    routed, _ = routed_experts(
-        h, is_test, hp.n_routed_experts, hp.moe_intermediate_size,
-        hp.num_experts_per_tok, norm_topk_prob=hp.norm_topk_prob,
-        router="sigmoid",
-        expert_bias_attr=weight("moe_e_score_correction_bias.b",
-                                EXPERT_BIAS_STD),
-        num_local_experts=hp.num_local_experts,
-        expert_offset=hp.expert_offset,
-        routed_scaling_factor=hp.routed_scaling_factor,
-        norm_topk_eps=_NORM_TOPK_EPS)
-
-    def shared(h):
-        return swiglu_mlp(h, hp.n_shared_experts * hp.moe_intermediate_size,
-                          hp.hidden_size, "shared_ffn")
-
-    return beside_shared(h, routed, shared if hp.n_shared_experts else None)
-
-
-def _block(x, hp, i, is_test):
-    h = layers.rms_norm(x, hp.rms_norm_eps,
-                        param_attr=tfm.named("attn_norm.w"))
-    a = tfm.latent_attention(
-        h, hp.num_attention_heads, hp.kv_lora_rank, hp.qk_nope_head_dim,
-        hp.qk_rope_head_dim, hp.v_head_dim, norm_eps=hp.rms_norm_eps,
-        rotary_base=float(hp.rope_theta),
-        rotary_interleaved=bool(hp.rope_interleave),
-        param_attr=norm_or_weight)
-    x = layers.elementwise_add(x, a)
-    h = layers.rms_norm(x, hp.rms_norm_eps,
-                        param_attr=tfm.named("ffn_norm.w"))
-    m = (swiglu_mlp(h, hp.intermediate_size, hp.hidden_size, "ffn")
-         if i < hp.first_k_dense_replace else _experts(h, hp, is_test))
-    return layers.elementwise_add(x, m)
-
-
 def kanana2_lm(ids, hp=Kanana2Config, is_test=False):
     """[B, T] token ids -> [B, T, vocab] next-token logits; the head is
     its own matrix (`tie_word_embeddings` false)."""
-    _check(hp)
-    if hp.tie_word_embeddings:
-        raise NotImplementedError("the published head is untied")
+    deepseek_v3_check(hp)
     x = layers.embedding(ids, size=[hp.vocab_size, hp.hidden_size],
                          param_attr=weight("emb.w"))
     for i in range(hp.num_hidden_layers):
-        x = _block(x, hp, i, is_test)
+        x = deepseek_v3_block(x, hp, i, is_test)
     x = layers.rms_norm(x, hp.rms_norm_eps,
                         param_attr=tfm.named("final_norm.w"))
     return fc(x, hp.vocab_size, "softmax_out.w")

@@ -9,7 +9,8 @@ on the published (2i, 2i+1) pairs, gradients are jax.grad.
     for layer i:  x += MLA(rms(x)); x += F_i(rms(x))
     logits = rms(x) @ W_head
 
-  MLA   q = h W_q -> [H, nope + rope]; [c, k_rot] = h W_kva -> [r], [rope];
+  MLA   q = h W_q -> [H, nope + rope], or under a `q_lora_rank` q =
+        rms(h W_qa; own gain) W_qb; [c, k_rot] = h W_kva -> [r], [rope];
         [k_nope, v] = rms(c; own gain) W_kvb -> [H, nope], [H, dv];
         RoPE on q's rotary part and on k_rot (ONE for all heads), pairs
         (2i, 2i+1), angle t theta^(-2i / rope);
@@ -38,8 +39,9 @@ Departures from the published model, each on purpose:
   share.
 
 `params` is the list of weights in creation order: embedding [V, d]; per
-layer attn_norm [d], W_q [d, H (nope + rope)], W_kva [d, r + rope],
-kv_a_norm [r], W_kvb [r, H (nope + dv)], W_o [H dv, d], ffn_norm [d]; then
+layer attn_norm [d], W_q [d, H (nope + rope)] (under a `q_lora_rank` the
+three W_qa [d, r_q], q_a_norm [r_q], W_qb [r_q, H (nope + rope)] in its
+place), W_kva [d, r + rope], kv_a_norm [r], W_kvb [r, H (nope + dv)], W_o [H dv, d], ffn_norm [d]; then
 for a dense layer w1 (gate) [d, f], w3 (up) [d, f], w2 [f, d], for an
 expert layer router [d, E], bias [E], gate_up [E_held, d, 2 f_e], down
 [E_held, f_e, d], shared w1 [d, n_s f_e], w3, w2 [n_s f_e, d]; final_norm
@@ -67,12 +69,18 @@ def rope_pairs(x, theta):
 
 
 def latent_attention(cfg, x, wq, wkva, kv_norm, wkvb, wo):
+    """`wq` is the list of the query's parameters: [W_q], or [W_qa,
+    q_a_norm, W_qb] under a query latent."""
     b, t, _ = x.shape
     h, r = cfg["num_attention_heads"], cfg["kv_lora_rank"]
     nope, rot, dv = (cfg["qk_nope_head_dim"], cfg["qk_rope_head_dim"],
                      cfg["v_head_dim"])
     theta = float(cfg["rope_theta"])
-    q = (x @ wq).reshape(b, t, h, nope + rot).transpose(0, 2, 1, 3)
+    if len(wq) == 3:
+        q = rms_norm(x @ wq[0], wq[1], cfg["rms_norm_eps"]) @ wq[2]
+    else:
+        q = x @ wq[0]
+    q = q.reshape(b, t, h, nope + rot).transpose(0, 2, 1, 3)
     latent = x @ wkva
     c, k_rot = latent[..., :r], latent[..., r:]
     kv = (rms_norm(c, kv_norm, cfg["rms_norm_eps"]) @ wkvb).reshape(
@@ -114,30 +122,47 @@ def routed(cfg, x, router, bias, gate_up, down):
     return y.reshape(x.shape), top_e
 
 
-def forward(cfg, params, ids):
-    """-> ([B, T, V] logits, [per expert layer chosen experts])."""
+def block(cfg, x, i, take):
+    """Layer i of the family's stack -> (x, the chosen experts [N, k] of an
+    expert layer or None); `take(n)` hands out the next n parameters."""
     eps = cfg["rms_norm_eps"]
+    h = rms_norm(x, *take(1), eps)
+    x = x + latent_attention(
+        cfg, h, take(3 if cfg.get("q_lora_rank") else 1), *take(4))
+    h = rms_norm(x, *take(1), eps)
+    if i < cfg["first_k_dense_replace"]:
+        return x + swiglu_mlp(h, *take(3)), None
+    y, top_e = routed(cfg, h, *take(4))
+    if cfg["n_shared_experts"]:
+        y = y + swiglu_mlp(h, *take(3))
+    return x + y, top_e
+
+
+def taker(params):
+    """-> (take(n): the next n parameters, done(): raises unless every one
+    was taken)."""
     it = iter(params)
 
     def take(n):
         return [next(it) for _ in range(n)]
 
-    x, chosen = next(it)[ids], []
+    def done():
+        if next(it, None) is not None:
+            raise ValueError("reference did not consume every parameter")
+
+    return take, done
+
+
+def forward(cfg, params, ids):
+    """-> ([B, T, V] logits, [per expert layer chosen experts])."""
+    take, done = taker(params)
+    x, chosen = take(1)[0][ids], []
     for i in range(cfg["num_hidden_layers"]):
-        h = rms_norm(x, next(it), eps)
-        x = x + latent_attention(cfg, h, *take(5))
-        h = rms_norm(x, next(it), eps)
-        if i < cfg["first_k_dense_replace"]:
-            x = x + swiglu_mlp(h, *take(3))
-        else:
-            y, top_e = routed(cfg, h, *take(4))
-            if cfg["n_shared_experts"]:
-                y = y + swiglu_mlp(h, *take(3))
-            x = x + y
+        x, top_e = block(cfg, x, i, take)
+        if top_e is not None:
             chosen.append(top_e)
-    logits = rms_norm(x, next(it), eps) @ next(it)
-    if next(it, None) is not None:
-        raise ValueError("reference did not consume every parameter")
+    logits = rms_norm(x, *take(1), cfg["rms_norm_eps"]) @ take(1)[0]
+    done()
     return logits, chosen
 
 

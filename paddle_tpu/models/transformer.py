@@ -438,7 +438,7 @@ def multi_head_attention(
 def latent_attention(
     x, n_head, kv_lora_rank, qk_nope_head_dim, qk_rope_head_dim, v_head_dim,
     norm_eps=1e-6, rotary_base=10000.0, rotary_interleaved=True,
-    param_attr=None, rotary=True,
+    param_attr=None, rotary=True, q_lora_rank=None,
 ):
     """Multi-head latent attention (MLA, DeepSeek-V2/V3), the causal
     training path, beside `multi_head_attention`: keys and values are not
@@ -459,17 +459,21 @@ def latent_attention(
     The scores are nope + rope wide and the values v_head_dim: one
     `fused_attention` op takes both (the flash kernel at (192, 128) on the
     chip, ops/nn_ops._flash_engages; dense XLA elsewhere).  No bias, no
-    query latent (the configurations that have one publish `q_lora_rank`;
-    none is built here), no dropout, no cache: serving keeps the latent
-    and the rotary key instead of K and V, which is another path.
+    dropout, no cache: serving keeps the latent and the rotary key instead
+    of K and V, which is another path.
+    `q_lora_rank` (the configurations that publish one) puts a latent
+    under the query as well: q = rms(h W_q_a; own gain) W_q_b, with W_q_a
+    [d, q_lora_rank] and W_q_b [q_lora_rank, H (nope + rope)] in W_q's
+    place; everything after q is the same.  None builds no such op.
     `rotary=False` (Kimi Linear's `mla_use_nope`) is the same layer without
     any position encoding: q's `rope`-wide part and the shared key part go
     into the scores as projected, the key part still ONE for all heads,
     repeated and concatenated; q is then never split.
 
-    Built under `name_scope("mla")` with inner scopes `down` (the
-    projections from x and the latent's norm), `up` (the expansion of the
-    latent), `rope`, `core` (the fused_attention op) and `out`, so the
+    Built under `name_scope("mla")` with inner scopes `q_latent` (where
+    there is a query latent: its two projections and its norm), `down`
+    (the projections from x and the latent's norm), `up` (the expansion of
+    the latent), `rope`, `core` (the fused_attention op) and `out`, so the
     lowered HLO carries them.  param_attr as `multi_head_attention`'s."""
     pa = param_attr or named
     d_model = int(x.shape[-1])
@@ -481,9 +485,18 @@ def latent_attention(
             layers.reshape(y, [b, t, n_head, width]), [0, 2, 1, 3])
 
     with framework.name_scope("mla"):
+        if q_lora_rank is not None:
+            with framework.name_scope("q_latent"):
+                c_q = layers.fc(x, size=q_lora_rank, num_flatten_dims=2,
+                                bias_attr=False, param_attr=pa("mla_q_a.w"))
+                c_q = layers.rms_norm(c_q, epsilon=norm_eps,
+                                      param_attr=pa("mla_q_a_norm.w"))
+                q = layers.fc(c_q, size=n_head * d_qk, num_flatten_dims=2,
+                              bias_attr=False, param_attr=pa("mla_q_b.w"))
         with framework.name_scope("down"):
-            q = layers.fc(x, size=n_head * d_qk, num_flatten_dims=2,
-                          bias_attr=False, param_attr=pa("mla_q.w"))
+            if q_lora_rank is None:
+                q = layers.fc(x, size=n_head * d_qk, num_flatten_dims=2,
+                              bias_attr=False, param_attr=pa("mla_q.w"))
             latent = layers.fc(x, size=kv_lora_rank + qk_rope_head_dim,
                                num_flatten_dims=2, bias_attr=False,
                                param_attr=pa("mla_kv_a.w"))

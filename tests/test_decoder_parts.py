@@ -14,7 +14,7 @@ from paddle_tpu.models import decoder
 
 MODELS = pathlib.Path(decoder.__file__).parent
 BUILDERS = {"gpt2", "olmoe", "lfm2", "ouro", "kanana2", "trinity",
-            "kimi_linear", "qwen3_next", "nemotron_h"}
+            "kimi_linear", "qwen3_next", "nemotron_h", "joyai_flash"}
 SEQ, D, VOCAB = 8, 16, 32
 
 

@@ -21,7 +21,8 @@ import pytest
 import paddle_tpu as fluid
 from paddle_tpu import analysis, framework, layers, unique_name
 from paddle_tpu.initializer import NumpyArrayInitializer
-from paddle_tpu.models import gpt2, kanana2, kanana2_reference as ref
+from paddle_tpu.models import (decoder, gpt2, kanana2,
+                               kanana2_reference as ref)
 from paddle_tpu.ops import moe_ops
 from paddle_tpu.param_attr import ParamAttr
 
@@ -275,7 +276,6 @@ def test_a_training_step_counts_three_forwards():
                                           NotImplementedError),
     ("scoring_func", "softmax", NotImplementedError),
     ("topk_method", "greedy", NotImplementedError),
-    ("q_lora_rank", 16, NotImplementedError),
     ("rope_scaling", {"type": "yarn"}, NotImplementedError),
     ("moe_layer_freq", 2, NotImplementedError),
     ("num_key_value_heads", 1, ValueError),
@@ -284,6 +284,41 @@ def test_what_the_builder_would_have_to_guess_it_refuses(key, value, error):
     hp = type("Guess", (HP,), {key: value})
     with pytest.raises(error, match=key.split("_")[0]):
         kanana2.kanana2_lm_program(hp, seq_len=SEQ)
+
+
+def test_with_a_query_latent_the_program_is_the_reference_with_one():
+    """`q_lora_rank` was refused until PR 61: the block now builds
+    `latent_attention(q_lora_rank=)` and the reference takes the three
+    parameters in W_q's place: loss and every gradient, float32."""
+    hp = type("QueryLatent", (HP,), {"q_lora_rank": 24})
+    cfg = dict(CFG, q_lora_rank=24)
+    main, startup, _, fetches = kanana2.kanana2_lm_program(hp, seq_len=SEQ)
+    startup.random_seed = main.random_seed = 5
+    batch = gpt2.make_fake_lm_batch(BATCH, SEQ, hp, seed=1)
+    exe, scope = fluid.Executor(fluid.CPUPlace()), fluid.Scope()
+    with fluid.scope_guard(scope):
+        exe.run(startup)
+        every = main.global_block().all_parameters()
+        names = [p.name.rsplit("_", 1)[0] for p in every]
+        assert "mla_q.w" not in names
+        assert names[1:5] == ["attn_norm.w", "mla_q_a.w", "mla_q_a_norm.w",
+                              "mla_q_b.w"]
+        values = [np.asarray(scope.find_var(p.name)) for p in every]
+        with jax.default_matmul_precision("highest"):
+            want_loss, want = jax.jit(jax.value_and_grad(
+                lambda p: ref.loss(cfg, p, batch)))(
+                    [jnp.asarray(v) for v in values])
+        trained = [p for p in every if p.trainable]
+        out = exe.run(main, feed=batch, fetch_list=[fetches[0]] + [
+            main._grad_names[p.name] for p in trained])
+    got = float(np.asarray(out[0]).reshape(-1)[0])
+    assert abs(got - float(want_loss)) <= 1e-5 * abs(float(want_loss))
+    by_name = dict(zip([p.name for p in every], want))
+    for p, g in zip(trained, out[1:]):
+        g, w = np.asarray(g), np.asarray(by_name[p.name])
+        assert np.abs(g - w).max() <= 1e-4 * np.abs(w).max(), p.name
+    scopes = {op.attrs.get("op_namescope") for op in main.global_block().ops}
+    assert "mla/q_latent" in scopes
 
 
 # --- the departures ---------------------------------------------------------
@@ -460,7 +495,8 @@ def test_the_shares_routed_parts_and_the_shared_expert_once_are_the_layer():
         routed, top_e = ref.routed(cfg, *args)
         shared = ref.swiglu_mlp(args[0], *map(jnp.asarray, w["shared"]))
     want_counts = np.bincount(np.asarray(top_e).reshape(-1), minlength=8)
-    parts = [share_through_the_executor(kanana2._experts, HP, w, offset, 2)
+    parts = [share_through_the_executor(decoder.deepseek_v3_experts, HP, w,
+                                        offset, 2)
              for offset in (0, 2, 4, 6)]
     for both, part, counts in parts:
         np.testing.assert_array_equal(counts, want_counts)

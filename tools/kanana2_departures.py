@@ -37,7 +37,12 @@ key head j mod 16, the convolution one step ahead, the GDN gate a sigmoid,
 the gains w in place of 1 + w, rotary over all 256 lanes, the attention's
 output gate left out, the shared expert's gate left out, the top-10
 weights not renormalised; its reference runs Gated DeltaNet token by token
-on the host, about a minute a reference at 8,192 tokens).
+on the host, about a minute a reference at 8,192 tokens); `--workload
+joyai_flash_48b_a3b_train` (PR 61; `--workload` is `--cell`: the module
+left out of the loss, reading the normed trunk state, scored against
+targets that are not shifted, its combine's halves swapped, lambda 1.0, the
+query latent's norm left out, and kanana-2's kv_a_layernorm, routed scale
+and shared expert left out; `--steps 130,142`).
 
 Prints one JSON line a step count (the adapter's own lines, with every
 reading, go to stderr).  PERF.md (PR 37) keeps what it read; what the
@@ -67,7 +72,7 @@ def _run_py():
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--cell", default=CELL)
+    ap.add_argument("--cell", "--workload", dest="cell", default=CELL)
     ap.add_argument("--seed", type=int, default=7)
     ap.add_argument("--steps", default="98,110")
     ap.add_argument("--departures-at", default=None,
