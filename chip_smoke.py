@@ -234,6 +234,32 @@ def kernel_cases(rehearse):
         "GPT-2 345M causal self-attention, T %d, as fused_attention "
         "lowers it by default" % AT)
 
+    # Transformer-base's decoder self-attention (T 256, 8 heads of 64,
+    # causal with the key-padding bias), as the op lowers it under the
+    # blockwise kernel's lengths: the one-tile form, several heads a step
+    SB, SH, ST = S(8, 1), S(8, 2), S(256, 128)
+
+    def short_op(q, k, v, bias):
+        return nn_ops._fused_attention(
+            LowerCtx(platform="tpu"),
+            {"Q": [q], "K": [k], "V": [v], "Bias": [bias]},
+            {"causal": True})["Out"][0]
+
+    def short_dense(q, k, v, bias):
+        flat = [a.reshape(SB * SH, ST, AD) for a in (q, k, v)]
+        kb = jnp.broadcast_to(bias[:, None, :], (SB, SH, ST)).reshape(
+            SB * SH, ST)
+        return pk._dense_attention(*flat, True, AD ** -0.5, kb).reshape(
+            q.shape)
+
+    cases["short_attention"] = (
+        with_grads(short_op, 3), with_grads(short_dense, 3),
+        lambda: tuple(arr(i, (SB, SH, ST, AD), bf16) for i in range(3)) + (
+            jnp.where(jnp.arange(ST)[None, :] < ST - 9, 0.0, -1e9).astype(f32)
+            * jnp.ones((SB, 1), f32),),
+        "Transformer-base decoder self-attention, T %d, as fused_attention "
+        "lowers it by default" % ST)
+
     PB, PT = S(32, 2), S(256, 16)
 
     def piece_dense(q, k, v, qoff):

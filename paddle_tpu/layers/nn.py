@@ -1670,8 +1670,9 @@ def log_loss(input, label, epsilon=1e-4, name=None):
 def fused_attention(q, k, v, causal=False, scale=None, bias=None,
                     window=0, segment_ids=None, qstart=None, name=None):
     """Fused scaled-dot-product attention over [batch, heads, T, d]
-    (the blockwise flash kernel where the placed platform and the shape
-    choose it: ops/nn_ops._flash_engages).  V may be of another width
+    (the blockwise flash kernel, or its one-tile form under 512 positions,
+    where the placed platform and the shape choose it:
+    ops/nn_ops._flash_engages, _short_engages).  V may be of another width
     than Q and K ([batch, heads, Tk, d_v]: latent attention scores 192
     wide over 128-wide values); the result is [batch, heads, Tq, d_v].
     bias: optional

@@ -964,7 +964,8 @@ def _fused_attention(ctx, ins, attrs):
     """Fused scaled-dot-product attention (the cuDNN-fused-kernel slot of
     the reference, TPU-style).  Q/K/V: [batch, heads, T, d].  Training
     path (no QStart): the blockwise kernel where platform and shape say
-    so (_flash_engages), dense XLA otherwise.  QStart paths (chunked and
+    so (_flash_engages), its one-tile form under that kernel's lengths
+    (_short_engages), dense XLA otherwise.  QStart paths (chunked and
     ragged decode): dense XLA (_qstart_attention)."""
     from .pallas_kernels import _dense_attention
 
@@ -1032,8 +1033,16 @@ def _fused_attention(ctx, ins, attrs):
     # The training path (no QStart).  One algorithm, engaged by what the
     # lowering can see: the blockwise kernel where _flash_engages says the
     # step is placed on a TPU and the shape is one the chip sweep found
-    # it ahead at, dense XLA everywhere else.  No flag is read here.
-    if _flash_engages(ctx, t, tk, d, dv):
+    # it ahead at, its one-tile form at the short lengths of
+    # _short_engages, dense XLA everywhere else.  No flag is read here.
+    if _short_engages(ctx, t, tk, d, dv, window, seg is not None):
+        from .kernel_tuning import note_kernel
+        from .pallas_kernels import short_attention
+
+        note_kernel("attention")
+        note_kernel("attention_short")
+        out = short_attention(qf, kf, vf, kbias, causal, float(scale))
+    elif _flash_engages(ctx, t, tk, d, dv):
         from .kernel_tuning import (note_band_grid, note_kernel,
                                     note_tile_classes)
         from .pallas_kernels import (band_grid_steps, flash_attention,
@@ -1070,7 +1079,8 @@ def _fused_attention(ctx, ins, attrs):
 # one sweep on a v5e over the transformer cells' attention shapes
 # (tools/attention_sweep.py; the table is in CHANGES.md, PR 29), not a
 # tuning-cache entry consulted at trace time.  Forward + backward alone,
-# bf16, kernel against dense: T = 256 3.64 against 2.85 ms (dense stays),
+# bf16, kernel against dense: T = 256 3.64 against 2.85 ms (not this
+# kernel's: its one-tile form takes the lengths under 512, _short_engages),
 # T = 512 0.77 against 1.11, T = 1024 0.95 against 2.66, T = 4096 4.0
 # against 29.5; at every length the largest square block was fastest.
 _FLASH_MIN_T = 512
@@ -1090,17 +1100,49 @@ def _flash_block(t):
 _FLASH_WIDTHS = ((64, 64), (128, 128), (192, 128), (256, 256))
 
 
+def _placed_on_tpu(ctx):
+    """The platform is the placed device's (LowerCtx.platform, which the
+    Executor states), the process's default backend only where a caller
+    did not say."""
+    return (getattr(ctx, "platform", None) or jax.default_backend()) == "tpu"
+
+
 def _flash_engages(ctx, tq, tk, d, dv=None):
     """Self-attention on a TPU-placed step, T a multiple of 128 at or
     above _FLASH_MIN_T, (Q/K width, V width) one of _FLASH_WIDTHS: 64 or
     128 for both, or 192 over 128 (V's width is Q's where a caller gives
-    none).  The platform is the placed device's (LowerCtx.platform, which
-    the Executor states), the process's default backend only where a
-    caller did not say."""
-    platform = getattr(ctx, "platform", None) or jax.default_backend()
-    return (platform == "tpu" and tq == tk and tq % 128 == 0
+    none)."""
+    return (_placed_on_tpu(ctx) and tq == tk and tq % 128 == 0
             and tq >= _FLASH_MIN_T
             and (d, d if dv is None else dv) in _FLASH_WIDTHS)
+
+
+# Under _FLASH_MIN_T the whole sequence is one tile and a grid step holds
+# several heads (pallas_kernels.short_attention).  It engages at the (T, head
+# width) pairs one sweep on a v5e ran it at and found it ahead of both the
+# dense lowering and the blockwise kernel, and at no other: the sweep's table
+# has a row for each (tools/attention_sweep.py --short; CHANGES.md, PR 62).
+# Forward + backward alone, bf16, one-tile against dense against the
+# blockwise kernel at one block of T: (64, 64) 1.92 / 3.17 / 5.89 ms,
+# (128, 64) 0.56 / 1.30 / 3.84, (256, 64) 1.06 / 2.85 / 3.54, (384, 64)
+# 1.11 / 4.04 / 3.22, (256, 128) 1.21 / 1.31 / 1.26.  128-wide heads at the
+# other lengths are NOT its: (64, 128) 1.10 / 0.62 / 2.23 and (128, 128)
+# 1.10 / 0.62 / 1.42 (dense ahead: its 128-lane arrays have no padding to
+# copy), (384, 128) 1.30 / 2.20 / 1.14.  The sequence is the kernels' lane
+# dim: whole 128-lane tiles, or 64, two heads side by side.
+_SHORT_SHAPES = ((64, 64), (128, 64), (256, 64), (384, 64), (256, 128))
+
+
+def _short_engages(ctx, tq, tk, d, dv=None, window=0, seg=False):
+    """Self-attention on a TPU-placed step at one of _SHORT_SHAPES' (T, head
+    width) pairs, V as wide as Q, no window, no segment ids, no live mesh
+    (spmd_flash_attention is the blockwise kernel's; no cell shards such a
+    shape)."""
+    from .spmd_epilogue import mesh_ctx
+
+    return (_placed_on_tpu(ctx) and tq == tk and dv in (None, d)
+            and (tq, d) in _SHORT_SHAPES and not window and not seg
+            and mesh_ctx() is None)
 
 
 @register("sequence_conv")

@@ -8,7 +8,9 @@ hierarchy: attention blocked over q and k with an online softmax, so the
 Kernels run compiled on TPU and in interpreter mode elsewhere, so the same
 code path is unit-testable on the CPU mesh.  `fused_attention`'s training
 path chooses `flash_attention` from platform and shape
-(nn_ops._flash_engages); `flash_attention_piece` is parallel/ring.py's.
+(nn_ops._flash_engages), and under that kernel's lengths its one-tile form
+`short_attention` (nn_ops._short_engages); `flash_attention_piece` is
+parallel/ring.py's.
 """
 
 import functools
@@ -24,6 +26,7 @@ import numpy as np
 __all__ = [
     "flash_attention",
     "flash_attention_piece",
+    "short_attention",
 ]
 
 NEG_INF = -1e30
@@ -1199,3 +1202,294 @@ def _piece_vjp_bwd(causal, scale, block_q, block_k, window, res, cts):
 
 
 flash_attention_piece.defvjp(_piece_vjp_fwd, _piece_vjp_bwd)
+
+
+# ---------------------------------------------------------------------------
+# short-sequence attention: the whole sequence one tile, several heads a
+# grid step
+#
+# Under the blockwise kernel's reach (nn_ops._FLASH_MIN_T) a head's scores
+# are ONE tile, and a grid that walks a head a step pays a step's fixed cost
+# for ~0.1 us of MXU work (tools/attention_sweep.py, PR 29: 3.64 ms against
+# dense's 2.85 at T = 256).  Here a grid step holds G heads: the scores of
+# all G one batched product, a plain softmax over the whole row, no
+# accumulation across steps, no scratch, no tile classes; the backward is
+# one call that rebuilds the probabilities from q, k and the saved rows of
+# each query's max and 1 / sum.
+#
+# What bounds these shapes on a v5e is not the arithmetic but the copies: a
+# [BH, T, 64] bfloat16 array is stored in (16, 128) tiles, half of every
+# tile padding, and a kernel that only copied such blocks in and out took
+# 0.83 ms forward and 1.62 ms backward at BH 1024 x T 256 (PR 62,
+# CHANGES.md): dense's whole 2.87.  So the kernels take every operand
+# TRANSPOSED, [BH, d, T]: the sequence fills the lanes, the 64 head
+# channels are whole sublane tiles, no byte of a copy is padding, and XLA
+# reaches that layout from a projection's [B, T, H d] in one transposing
+# copy where [B, H, T, d] takes it two.  A sequence shorter than the 128
+# lanes shares them with its neighbours (_short_pack heads side by side,
+# [BH / pack, d, pack T]; a query sees the keys of its own).
+# The score tiles are [keys, queries], as the fused backward's above: max,
+# 1 / sum and delta are rows [1, queries] that broadcast along sublanes as
+# stored, the reductions run down sublanes, and o^T = v^T p^T, dq^T, dk^T,
+# dv^T come out of the MXU in the operands' own layout.
+# The precision contract is the flash kernels': operands reach the MXU in
+# their own dtype, scores, max and sum are f32 and the scores never leave
+# VMEM.
+# ---------------------------------------------------------------------------
+def _bdot(a, b, dims):
+    """[G, ., .] x [G, ., .] contracted over axes `dims` (a's, b's), one
+    product a head, f32 accumulation."""
+    return jax.lax.dot_general(a, b, (((dims[0],), (dims[1],)), ((0,), (0,))),
+                               preferred_element_type=jnp.float32)
+
+
+def _short_keep(n, seq, causal):
+    """The [1, n, n] mask of a [keys, queries] tile that holds n // seq
+    sequences of `seq` positions side by side: a query sees its own
+    sequence's keys, those at or before it under `causal`.  None where
+    every score is kept."""
+    if not causal and seq == n:
+        return None
+    ki = jax.lax.broadcasted_iota(jnp.int32, (1, n, n), 1)
+    qi = jax.lax.broadcasted_iota(jnp.int32, (1, n, n), 2)
+    keep = (qi >= ki) if causal else None  # within a sequence, as across
+    if seq != n:
+        same = (qi // seq) == (ki // seq)
+        keep = same if keep is None else keep & same
+    return keep
+
+
+def _short_scores(qt, kt, kb_ref, causal, scale, seq):
+    """The masked f32 scores [G, keys, queries] of q^T, k^T [G, d, n]."""
+    g, _, n = qt.shape
+    st = _bdot(kt, qt, (1, 1)) * scale
+    if kb_ref is not None:
+        st = st + kb_ref[...].reshape(g, n, 1)
+    keep = _short_keep(n, seq, causal)
+    if keep is not None:
+        st = jnp.where(keep, st, NEG_INF)
+    return st
+
+
+def _short_fwd_kernel(*refs, causal, scale, has_kb, seq):
+    qt_ref, kt_ref, vt_ref = refs[:3]
+    kb_ref = refs[3] if has_kb else None
+    ot_ref, stat_ref = refs[-2:]
+    vt = vt_ref[...]
+    st = _short_scores(qt_ref[...], kt_ref[...], kb_ref, causal, scale, seq)
+    m = jnp.max(st, axis=1, keepdims=True)  # [G, 1, queries]
+    pt = jnp.exp(st - m)
+    l = jnp.sum(pt, axis=1, keepdims=True)  # >= 1: the row's max is in it
+    # the max and 1 / sum as two rows, not their lse: beside a bias of -1e9
+    # float32 cannot hold m + log l, and a fully padded row's probabilities
+    # would come back n times too large in the backward
+    stat_ref[:, 0:1, :] = m
+    stat_ref[:, 1:2, :] = 1.0 / l
+    ot_ref[...] = (_bdot(vt, pt.astype(vt.dtype), (2, 1)) / l).astype(
+        ot_ref.dtype)
+
+
+def _short_bwd_kernel(*refs, causal, scale, has_kb, seq):
+    refs = list(refs)
+    qt_ref, kt_ref, vt_ref = refs[:3]
+    del refs[:3]
+    kb_ref = refs.pop(0) if has_kb else None
+    ot_ref, dot_ref, stat_ref, dqt_ref, dkt_ref, dvt_ref = refs[:6]
+    dkb_ref = refs[6] if has_kb else None
+    qt, kt, dot = qt_ref[...], kt_ref[...], dot_ref[...]
+    g, _, n = qt.shape
+    st = _short_scores(qt, kt, kb_ref, causal, scale, seq)
+    # max, 1 / sum, delta: [G, 1, queries]
+    pt = jnp.exp(st - stat_ref[:, 0:1, :]) * stat_ref[:, 1:2, :]
+    delta = jnp.sum(dot.astype(jnp.float32) * ot_ref[...].astype(jnp.float32),
+                    axis=1, keepdims=True)
+    dvt_ref[...] = _bdot(dot, pt.astype(dot.dtype), (2, 2)).astype(
+        dvt_ref.dtype)
+    dst = pt * (_bdot(vt_ref[...], dot, (1, 1)) - delta)
+    if has_kb:
+        dkb_ref[...] = jnp.sum(dst, axis=2).reshape(g, 1, n)
+    dsc = dst.astype(qt.dtype)
+    dkt_ref[...] = (scale * _bdot(qt, dsc, (2, 2))).astype(dkt_ref.dtype)
+    dqt_ref[...] = (scale * _bdot(kt, dsc, (2, 1))).astype(dqt_ref.dtype)
+
+
+# The tile plan, from the shapes alone: constants from one sweep on a v5e
+# (tools/attention_sweep.py --short; the table is in CHANGES.md, PR 62:
+# forward + backward of one layer alone, bf16, key bias, from q, k, v stored
+# [BH, T, d]).  heads: tiles a grid step holds, as many as keep the f32
+# tiles a step's backward holds live (scores, probabilities, dp, ds and two
+# narrowed copies: ~4.5 f32 tiles; the operand blocks are [d, n], a
+# fraction of one) inside the scoped VMEM every kernel here asks for
+# (_mosaic_params) with room for the compiler's own temporaries: BH 1024 x
+# T 256 x 64 reads 2.11 ms at one head a step, 1.14 at 4, 1.06 at 8 (what
+# the budget gives), 1.04 at 16, against dense's 2.85 and the blockwise
+# kernel's 3.54 at one 256-block; T 128 0.56 at 32 (dense 1.30); T 384 1.11
+# at 4 (dense 4.04, blockwise 3.22); 128-wide heads at T 256 1.21 at 8
+# (dense 1.31, blockwise 1.29).
+_SHORT_VMEM_BYTES = 20 * 2 ** 20
+
+
+def _short_pack(t):
+    """Heads a tile holds side by side: a sequence under the 128 lanes
+    shares them (BH 4096 x T 64 x 64: 1.92 ms at two heads a tile, 2.43 at
+    one, 2.37 at four, dense 3.17)."""
+    return max(1, 128 // t)
+
+
+class _ShortPlan(NamedTuple):
+    pack: int
+    heads: int
+
+
+def _short_plan(bh, t, d, dv, itemsize):
+    """A function of the shapes and the operands' dtype alone."""
+    pack = _short_pack(t)
+    while bh % pack:
+        pack -= 1
+    n = pack * t
+    lanes = -(-n // 128) * 128
+    a_tile = (18 * n * lanes  # 4.5 f32 [n, n] tiles
+              + 2 * itemsize * lanes * (5 * d + 4 * dv)  # the blocks, twice
+              + 2 * 4 * 8 * 4 * lanes)  # kb, max | 1 / sum, dkb rows
+    most = max(1, _SHORT_VMEM_BYTES // a_tile)
+    tiles = bh // pack
+    return _ShortPlan(pack, next(g for g in range(most, 0, -1)
+                                 if tiles % g == 0))
+
+
+def _short_t(x, pack):
+    """[BH, T, d] -> [BH / pack, d, pack T]: transposed, `pack` heads side
+    by side."""
+    bh, t, d = x.shape
+    return x.reshape(bh // pack, pack, t, d).transpose(0, 3, 1, 2).reshape(
+        bh // pack, d, pack * t)
+
+
+def _short_t_back(xt, pack):
+    """_short_t's inverse."""
+    tiles, d, n = xt.shape
+    return xt.reshape(tiles, d, pack, n // pack).transpose(0, 2, 3, 1).reshape(
+        tiles * pack, n // pack, d)
+
+
+def _short_operands(plan, qt, kt, vt, kb):
+    """((q / k, v, one-row and two-row block specs), the in_specs and the
+    operands both kernels start with: q^T, k^T, v^T and the bias rows where
+    there are any)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def spec(rows):
+        return pl.BlockSpec((plan.heads, rows, qt.shape[2]),
+                            lambda i: (i, 0, 0), memory_space=pltpu.VMEM)
+
+    qk_spec, v_spec, row_spec, stat_spec = (
+        spec(qt.shape[1]), spec(vt.shape[1]), spec(1), spec(2))
+    in_specs, args = [qk_spec, qk_spec, v_spec], [qt, kt, vt]
+    if kb is not None:
+        in_specs.append(row_spec)
+        args.append(kb)
+    return (qk_spec, v_spec, row_spec, stat_spec), in_specs, args
+
+
+# jitted at module level as the flash entries above: a model's attentions of
+# one shape and configuration trace each body once
+_SHORT_STATICS = ("causal", "scale", "plan", "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_SHORT_STATICS)
+def _short_fwd_call(q, k, v, kbias, *, causal, scale, plan, interpret):
+    """q, k: [BH, T, d], v: [BH, T, dv], kbias: [BH, T] f32 or None.
+    Returns (o, what the backward reads: q^T, k^T, v^T, the bias rows, o^T
+    and the [BH / pack, 2, pack T] max | 1 / sum rows, all as the kernels
+    take them)."""
+    from jax.experimental import pallas as pl
+
+    t = q.shape[1]
+    qt, kt, vt = (_short_t(x, plan.pack) for x in (q, k, v))
+    tiles, _, n = qt.shape
+    kb = None if kbias is None else kbias.reshape(tiles, 1, n)
+    (_, v_spec, row_spec, stat_spec), in_specs, args = _short_operands(
+        plan, qt, kt, vt, kb)
+    ot, stat = pl.pallas_call(
+        functools.partial(_short_fwd_kernel, causal=causal, scale=scale,
+                          has_kb=kb is not None, seq=t),
+        grid=(tiles // plan.heads,),
+        in_specs=in_specs,
+        out_specs=[v_spec, stat_spec],
+        out_shape=[_sds(vt.shape, q.dtype, q, k, v),
+                   _sds((tiles, 2, n), jnp.float32, q, k, v)],
+        compiler_params=_mosaic_params(),
+        interpret=interpret,
+    )(*args)
+    return _short_t_back(ot, plan.pack), (qt, kt, vt, kb, ot, stat)
+
+
+@functools.partial(jax.jit, static_argnames=_SHORT_STATICS)
+def _short_bwd_call(res, do, *, causal, scale, plan, interpret):
+    """(dq, dk, dv, dkbias [BH, T] f32 or None): one pallas_call."""
+    from jax.experimental import pallas as pl
+
+    qt, kt, vt, kb, ot, stat = res
+    t = do.shape[1]
+    dot = _short_t(do, plan.pack)
+    (qk_spec, v_spec, row_spec, stat_spec), in_specs, args = _short_operands(
+        plan, qt, kt, vt, kb)
+    in_specs += [v_spec, v_spec, stat_spec]
+    args += [ot, dot, stat]
+    out_specs = [qk_spec, qk_spec, v_spec]
+    out_shape = [_sds(qt.shape, qt.dtype, qt, kt, vt, do),
+                 _sds(kt.shape, kt.dtype, qt, kt, vt, do),
+                 _sds(vt.shape, vt.dtype, qt, kt, vt, do)]
+    if kb is not None:
+        out_specs.append(row_spec)
+        out_shape.append(_sds(kb.shape, jnp.float32, qt, kt, vt, do))
+    outs = pl.pallas_call(
+        functools.partial(_short_bwd_kernel, causal=causal, scale=scale,
+                          has_kb=kb is not None, seq=t),
+        grid=(qt.shape[0] // plan.heads,),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        compiler_params=_mosaic_params(),
+        interpret=interpret,
+    )(*args)
+    dq, dk, dv = (_short_t_back(x, plan.pack) for x in outs[:3])
+    return dq, dk, dv, (outs[3].reshape(-1, t) if kb is not None else None)
+
+
+def _short_statics(bh, t, d, dv, dtype, causal, scale):
+    if scale is None:
+        scale = 1.0 / (d ** 0.5)
+    return dict(causal=bool(causal), scale=float(scale),
+                plan=_short_plan(bh, t, d, dv, jnp.dtype(dtype).itemsize),
+                interpret=_interpret())
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def short_attention(q, k, v, kbias=None, causal=False, scale=None):
+    """flash_attention's one-tile form for a short self-attention, q, k:
+    [BH, T, d], v: [BH, T, dv] -> [BH, T, dv], kbias: optional [BH, T]
+    additive key bias: the whole sequence is one tile and a grid step holds
+    several heads (_short_plan), so a head pays no grid step of its own,
+    and the kernels read and write [BH, d, T], the sequence in the lanes.
+    The same arithmetic at the same precision as flash_attention (f32
+    scores and softmax statistics, MXU operands in their own dtype), no
+    [BH, T, T] array in HBM; the backward is one kernel and rebuilds the
+    probabilities from each query's saved max and 1 / sum."""
+    return _short_vjp_fwd(q, k, v, kbias, causal, scale)[0]
+
+
+def _short_vjp_fwd(q, k, v, kbias, causal, scale):
+    return _short_fwd_call(q, k, v, kbias, **_short_statics(
+        *q.shape, v.shape[2], q.dtype, causal, scale))
+
+
+def _short_vjp_bwd(causal, scale, res, do):
+    qt = res[0]  # [BH / pack, d, pack T]
+    return _short_bwd_call(res, do, **_short_statics(
+        do.shape[0], do.shape[1], qt.shape[1], do.shape[2], qt.dtype, causal,
+        scale))
+
+
+short_attention.defvjp(_short_vjp_fwd, _short_vjp_bwd)

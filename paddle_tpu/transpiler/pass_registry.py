@@ -307,8 +307,9 @@ class AttentionFusePass(Pass):
           -> softmax [-> dropout(is_test)]
           -> matmul(weights, V)
 
-    becomes ONE fused_attention op — the flash kernel where platform and
-    shape say so (nn_ops._flash_engages), fused XLA otherwise.
+    becomes ONE fused_attention op — the flash kernel or its one-tile form
+    where platform and shape say so (nn_ops._flash_engages,
+    _short_engages), fused XLA otherwise.
     Conservative conditions: single-consumer chain (the matcher
     guarantees it), Q rank-4 [B, H, Tq, Dh], bias with key axis only
     (shape [..., 1, Tk]), softmax over the default last axis,

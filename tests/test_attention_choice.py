@@ -150,17 +150,33 @@ def test_no_t_by_t_array_in_the_forward_or_backward_jaxpr():
     assert square(lambda q, k, v: _op(TPU, q, k, v)) == []
 
 
-@pytest.mark.parametrize("bh,t,d", [(4, 1024, 64), (2, 4096, 128)])
-def test_kernel_cross_lowers_for_the_tpu_on_this_host(monkeypatch, bh, t, d):
-    """At the cells' engaged head shapes, blocks as the lowering sets
-    them: the Pallas -> Mosaic lowering and its block-spec checks, which
-    interpret mode skips.  Two calls: one forward (the primal and the
-    VJP's forward are one), one fused backward."""
+@pytest.mark.parametrize("bh,t,d,bias,causal", [
+    (4, 1024, 64, False, True), (2, 4096, 128, False, True),
+    # the two Transformer-base cells' attentions (PR 62: the one-tile form)
+    (1024, 256, 64, True, True), (1024, 256, 64, True, False),
+    (1024, 256, 64, False, False), (4096, 64, 64, True, True),
+    (4096, 64, 64, True, False), (4096, 64, 64, False, True),
+    (672, 384, 64, True, True), (512, 256, 128, True, True)])
+def test_kernel_cross_lowers_for_the_tpu_on_this_host(monkeypatch, bh, t, d,
+                                                      bias, causal):
+    """At the cells' engaged head shapes, blocks and tile plans as the
+    lowering sets them: the Pallas -> Mosaic lowering and its block-spec
+    checks, which interpret mode skips.  Two calls: one forward (the
+    primal and the VJP's forward are one), one backward."""
     monkeypatch.setattr(pk, "_interpret", lambda: False)
     x = jax.ShapeDtypeStruct((1, bh, t, d), jnp.bfloat16)
+    kb = jax.ShapeDtypeStruct((1, t), jnp.float32)
+
+    def op(q, k, v, kb):
+        ins = {"Q": [q], "K": [k], "V": [v]}
+        if bias:
+            ins["Bias"] = [kb]
+        return nn_ops._fused_attention(TPU, ins, {"causal": causal})["Out"][0]
+
     lowered = jax.jit(jax.grad(
-        _loss(lambda q, k, v: _op(TPU, q, k, v)), argnums=(0, 1, 2))).trace(
-            x, x, x).lower(lowering_platforms=("tpu",))
+        lambda q, k, v, kb: _loss(lambda *a: op(*a, kb))(q, k, v),
+        argnums=(0, 1, 2))).trace(x, x, x, kb).lower(
+            lowering_platforms=("tpu",))
     assert lowered.as_text().count("tpu_custom_call") == 2
 
 
@@ -169,10 +185,10 @@ CHOICE = [
     ("gpt2_345m_train", "tpu", 1024, 1024, 64, True),
     ("gpt2_345m_train_dp2mp2", "tpu", 1024, 1024, 64, True),
     ("olmoe_1b7b_train", "tpu", 4096, 4096, 128, True),
-    ("tfm_base_train: T = 256 is under the threshold", "tpu", 256, 256, 64,
-     False),
-    ("tfm_base_train_s64: 64 is no multiple of 128", "tpu", 64, 64, 64,
-     False),
+    ("tfm_base_train: T = 256 is under the threshold (the one-tile form's)",
+     "tpu", 256, 256, 64, False),
+    ("tfm_base_train_s64: 64 is no multiple of 128 (the one-tile form's)",
+     "tpu", 64, 64, 64, False),
     # resnet50_train, the sixth cell, has no attention op
     ("cross-attention, Tq != Tk", "tpu", 1024, 2048, 64, False),
     ("a ragged length", "tpu", 1000, 1000, 64, False),
@@ -189,6 +205,119 @@ def test_choice_table(monkeypatch, what, platform, tq, tk, d, engages):
                         lambda: "cpu" if platform == "tpu" else "tpu")
     assert nn_ops._flash_engages(
         LowerCtx(platform=platform), tq, tk, d) is engages, what
+
+
+def _taken(ctx, t, d, tk=None, **extra):
+    """Which lowering the op takes, read off the counters its engagements
+    tick: the op itself is traced (abstractly), nothing is asked of the
+    rule's helpers."""
+    tk = tk or t
+    q = jax.ShapeDtypeStruct((2, 2, t, d), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((2, 2, tk, d), jnp.bfloat16)
+    ins = {"QStart": jax.ShapeDtypeStruct((1,), jnp.int32),
+           "SegmentIds": jax.ShapeDtypeStruct((2, t), jnp.int32),
+           "Bias": jax.ShapeDtypeStruct((2, tk), jnp.float32)}
+    ins = {slot: ins[slot] for slot in extra.pop("slots", ())}
+    hits = lambda: dict(kt.attribution()["pallas_hits"])  # noqa: E731
+    before = hits()
+    jax.eval_shape(
+        lambda q, k, v, ins: nn_ops._fused_attention(
+            ctx, dict({"Q": [q], "K": [k], "V": [v]},
+                      **{s: [a] for s, a in ins.items()}),
+            dict({"causal": True}, **extra))["Out"][0], q, kv, kv, ins)
+    more = {f: n - before.get(f, 0) for f, n in hits().items()
+            if n != before.get(f, 0)}
+    if not more:
+        return "dense"
+    assert more.pop("attention") == 1  # every kernel engagement counts here
+    if more == {"attention_short": 1}:  # and the one-tile form once more
+        return "one_tile"
+    assert "attention_short" not in more
+    return "blockwise"
+
+
+@pytest.mark.parametrize("d", [64, 128, 192])
+@pytest.mark.parametrize("t", [56, 64, 256, 384, 512, 1024])
+def test_three_way_choice_by_length_and_width(t, d):
+    """TPU-placed self-attention: the one-tile form under the blockwise
+    kernel's lengths at the (T, head width) pairs the chip sweep has a row
+    for and found it ahead at (64-wide heads at 64, two heads side by side,
+    and at 256 and 384; 128-wide heads at 256 alone: dense is ahead of it
+    at 64 and 128, the blockwise kernel at 384), the blockwise kernel from
+    512 on at either width; dense for everything else; a CPU-placed step is
+    dense whatever the shape."""
+    want = ("blockwise" if t >= 512 and d != 192
+            else "one_tile" if (t, d) in ((64, 64), (256, 64), (384, 64),
+                                          (256, 128)) else "dense")
+    assert _taken(TPU, t, d) == want
+    assert _taken(LowerCtx(platform="cpu"), t, d) == "dense"
+
+
+@pytest.mark.parametrize("what,kwargs,want", [
+    ("the key-padding bias rides the one-tile form", {"slots": ("Bias",)},
+     "one_tile"),
+    ("not causal", {"causal": False}, "one_tile"),
+    ("a window is the blockwise kernel's, from 512 on", {"window": 64},
+     "dense"),
+    ("segment ids are the blockwise kernel's", {"slots": ("SegmentIds",)},
+     "dense"),
+    ("QStart (cached decode) never enters the training path",
+     {"slots": ("QStart",)}, "dense"),
+    ("cross-attention over another length", {"tk": 128, "causal": False},
+     "dense"),
+], ids=lambda x: x.split(":")[0] if isinstance(x, str) else None)
+def test_what_keeps_a_short_attention_off_the_one_tile_form(what, kwargs,
+                                                            want):
+    assert _taken(TPU, 256, 64, **dict(kwargs)) == want, what
+
+
+@pytest.mark.skipif(len(jax.devices()) < 4, reason="needs 4 virtual devices")
+def test_under_a_live_mesh_a_short_attention_stays_dense():
+    """spmd_flash_attention is the blockwise kernel's: under a live mesh the
+    one-tile lengths keep the dense lowering, T = 512 the kernel in
+    shard_map as before."""
+    from paddle_tpu.parallel.mesh import make_mesh
+    from paddle_tpu.parallel.partition_rules import (
+        spmd_lowering, train_partition_rules_for)
+
+    mesh = make_mesh({"dp": 2, "mp": 2}, jax.devices()[:4])
+    with spmd_lowering(mesh, train_partition_rules_for("gpt2")):
+        assert _taken(TPU, 256, 64) == "dense"
+        assert _taken(TPU, 512, 64) == "blockwise"
+    assert _taken(TPU, 256, 64) == "one_tile"
+
+
+def test_one_tile_op_matches_dense_with_the_bias_forward_and_gradients():
+    """The op placed on a TPU at T = 128 (the kernel, interpreted here)
+    against the op placed on the CPU (dense), the key-padding bias [B, T]
+    broadcast over heads: the result and dq / dk / dv."""
+    q, k, v = _qkv(2, 4, 128, 64, seed=9)
+    bias = jnp.where(jnp.arange(128)[None, :] < jnp.array([[100], [128]]),
+                     0.0, -1e9).astype(jnp.float32)
+
+    def op(ctx):
+        return lambda q, k, v: nn_ops._fused_attention(
+            ctx, {"Q": [q], "K": [k], "V": [v], "Bias": [bias]},
+            {"causal": True})["Out"][0]
+
+    before = kt.attribution()["pallas_hits"].get("attention_short", 0)
+    got = jax.jit(op(TPU))(q, k, v)
+    assert kt.attribution()["pallas_hits"]["attention_short"] == before + 1
+    _close(got, op(LowerCtx(platform="cpu"))(q, k, v))
+    grads = jax.jit(jax.grad(_loss(op(TPU)), argnums=(0, 1, 2)))(q, k, v)
+    want = jax.jit(jax.grad(_loss(op(LowerCtx(platform="cpu"))),
+                            argnums=(0, 1, 2)))(q, k, v)
+    for g, r in zip(grads, want):
+        assert g.dtype == jnp.bfloat16 and g.shape == r.shape
+        _close(g, r)
+
+
+def test_no_t_by_t_array_with_the_one_tile_form_either():
+    t = 256
+    q, k, v = _qkv(1, 2, t, 64)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        _loss(lambda q, k, v: _op(TPU, q, k, v)), argnums=(0, 1, 2)))(q, k, v)
+    assert [s for s in _shapes_in(jaxpr) if s.count(t) >= 2] == []
 
 
 def test_blocks_divide_the_length():
@@ -228,17 +357,24 @@ def test_layer_states_its_output_without_evaluating_the_lowering(
     assert tuple(out.shape) == tuple(q.shape) and out.dtype == q.dtype
 
 
-def test_deep_program_traces_and_carries_each_kernel_once(monkeypatch):
+@pytest.mark.parametrize("t,entry,fwd_body,bwd_body,family", [
+    (1024, "_flash", "_flash_fwd_kernel", "_flash_bwd_fused_kernel",
+     "attention"),
+    (128, "_short", "_short_fwd_kernel", "_short_bwd_kernel",
+     "attention_short")], ids=["blockwise", "one_tile"])
+def test_deep_program_traces_and_carries_each_kernel_once(
+        monkeypatch, t, entry, fwd_body, bwd_body, family):
     """The host-cost pin: a 4-layer causal training step lowered for the
     TPU traces each kernel body once, and its StableHLO holds a Mosaic
     payload per distinct entry, not per layer — twelve call sites share
     three functions: the forward op's (jit's dead-code pass prunes its
-    unused lse output, so it is a jaxpr of its own), the grad op's
+    unused lse output, or the one-tile form's unused residuals, so it is a
+    jaxpr of its own), the grad op's
     re-traced forward and the backward.  (On the device the first two are
     one instruction: same operands, same payload.)"""
     from paddle_tpu.core.trace import build_traced_function
 
-    n_layer, heads, t, d = 4, 2, 1024, 64
+    n_layer, heads, d = 4, 2, 64
     counts = {"fwd": 0, "bwd": 0}
 
     def counted(name, body):
@@ -247,10 +383,8 @@ def test_deep_program_traces_and_carries_each_kernel_once(monkeypatch):
             return body(*a, **k)
         return wrapper
 
-    monkeypatch.setattr(pk, "_flash_fwd_kernel",
-                        counted("fwd", pk._flash_fwd_kernel))
-    monkeypatch.setattr(pk, "_flash_bwd_fused_kernel",
-                        counted("bwd", pk._flash_bwd_fused_kernel))
+    monkeypatch.setattr(pk, fwd_body, counted("fwd", getattr(pk, fwd_body)))
+    monkeypatch.setattr(pk, bwd_body, counted("bwd", getattr(pk, bwd_body)))
     monkeypatch.setattr(pk, "_interpret", lambda: False)
     jax.clear_caches()  # an earlier test's trace of this shape would hide
 
@@ -275,17 +409,17 @@ def test_deep_program_traces_and_carries_each_kernel_once(monkeypatch):
             {n: sds(scope.find_var(n)) for n in traced.ro_names},
             {n: sds(scope.find_var(n)) for n in traced.rw_names},
             sds(jax.random.PRNGKey(0)))
-    before = kt.attribution()["pallas_hits"].get("attention", 0)
+    before = kt.attribution()["pallas_hits"].get(family, 0)
     text = jax.jit(traced.fn).trace(*args).lower(
         lowering_platforms=("tpu",)).as_text()
 
     assert counts == {"fwd": 1, "bwd": 1}
     assert text.count("tpu_custom_call") == 3
-    assert text.count("call @_flash_fwd_call") == 2 * n_layer
-    assert text.count("call @_flash_bwd_call") == n_layer
-    assert text.count("func.func private @_flash") == 3
+    assert text.count("call @%s_fwd_call" % entry) == 2 * n_layer
+    assert text.count("call @%s_bwd_call" % entry) == n_layer
+    assert text.count("func.func private @%s" % entry) == 3
     # the counter still sees every engagement: forward and grad op a layer
-    assert (kt.attribution()["pallas_hits"]["attention"] - before
+    assert (kt.attribution()["pallas_hits"][family] - before
             == 2 * n_layer)
 
 

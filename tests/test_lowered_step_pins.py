@@ -186,7 +186,19 @@ NOT move, and that is the proof that the steps of the cells they stand
 for are the parent's: in `gpt2`, `olmoe`, `ouro`, `transformer` and
 `resnet` no `split`, `concat` or `expand` reads the cast-back of a
 bfloat16 value, and a float32 `expand` (`kanana2`'s rotated key among
-them) lowers to the text `jnp.tile` and its gradient lowered to."""
+them) lowers to the text `jnp.tile` and its gradient lowered to.
+
+PR 62 gave the short sequences a kernel of their own
+(`pallas_kernels.short_attention`, engaged under the blockwise kernel's
+lengths: `nn_ops._short_engages`), so `transformer` (4 x 64 + 64, two heads
+of 64) moved ON PURPOSE, its digest re-taken from PR 62's tree by this
+file's `_digest`: 0 Mosaic payloads became 6 (the forward op's body, the
+grad op's re-traced forward and the backward, for each of two
+configurations under the key bias: causal, the decoder's self-attention,
+and not, the encoder's and the cross-attention).  The other seventeen did
+NOT move: every
+attention they hold has T >= 1024 and takes the blockwise kernel through
+the branch it took, and that is the proof of it."""
 
 import base64
 import functools
@@ -315,9 +327,9 @@ def _lm(build, hp):
 
 
 def _transformer():
-    """Transformer-base's program shape at tiny widths, 4 x 64 + 64 (dense
-    attention, as the cells run it at 64 and 256): `fc` with bias and
-    activation, `fused_residual_ln`."""
+    """Transformer-base's program shape at tiny widths, 4 x 64 + 64 (the
+    one-tile attention kernel, as the cells run it at 64 and 256 since
+    PR 62): `fc` with bias and activation, `fused_residual_ln`."""
     main, startup, _, fetches = transformer.wmt_transformer_program(
         W, src_len=64, trg_len=64, use_bf16=True)
     return main, startup, fetches[0].name, _shapes(
@@ -355,12 +367,13 @@ PROGRAMS = {"gpt2": lambda: _lm(gpt2.gpt2_lm_program, G),
 # three UNTOUCHED cores: at ba67ef1, PR 53's parent; the four cores whose
 # causal kernels walk several blocks of the full grid, `piece_diagonal_chunk`
 # among them: at PR 56; `nemotron_h`: added at PR 57; the six programs whose
-# AMP rewrite flips a `split`, `concat` or `expand`: at PR 58)
+# AMP rewrite flips a `split`, `concat` or `expand`: at PR 58; `transformer`
+# again: at PR 62)
 BEFORE = {
     "nemotron_h": ("424a80a8933a0ec7ee89c4ece3eeca9006e18e92", 18),
     "qwen3_next": ("3d4b8d2d56c5035594075b0508a3614e286234ce", 27),
     "kimi_linear": ("3e0377967224298932fcd5be83fe7ce7f59a2b5b", 21),
-    "transformer": ("e83306d8f28e59df41bc44b9fe7303322f29a58b", 0),
+    "transformer": ("6b1f32555633e8e024cb7b0093967d49e7c0bb3e", 6),
     "resnet": ("84575b13d140437bb64cb8461436105337774a6d", 0),
     "ouro": ("0057fcbecadc1719a1de27cb3b94bb41ac569650", 3),
     "kanana2": ("58a5bd2363cca5fb23fea690fbcbdda362a0ee97", 9),

@@ -10,7 +10,8 @@ import pytest
 
 import paddle_tpu as fluid
 from paddle_tpu import layers
-from paddle_tpu.ops.pallas_kernels import _dense_attention, flash_attention
+from paddle_tpu.ops.pallas_kernels import (_dense_attention, flash_attention,
+                                           short_attention)
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -902,6 +903,74 @@ def test_fused_linear_xent_op_and_its_grad_match_the_unfused_chain(
     np.testing.assert_allclose(l0, l1, rtol=1e-5, atol=1e-6)
     for a, b in zip(w0, w1):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("mask", ["none", "kbias", "causal", "causal_kbias"])
+@pytest.mark.parametrize("t,d", [(64, 64), (256, 64), (128, 128)])
+@pytest.mark.parametrize("dtype,tol", [("float32", 2e-5), ("bfloat16", 2e-2)])
+def test_short_attention_matches_dense(dtype, tol, t, d, mask):
+    """The one-tile form (interpreted) against `_dense_attention`: forward,
+    and dq / dk / dv / dbias under `jax.grad`, in float32 and bfloat16, at
+    the two Transformer-base cells' head shapes (T = 64 packs two heads
+    into a tile's lanes) and at 128-wide heads.  The key bias pads each
+    row's tail and row 1 whole: a fully padded row comes out finite and as
+    dense gives it, forward and backward (the backward rebuilds its
+    probabilities from the saved max and 1 / sum, which float32 holds
+    beside a bias of -1e9 where their logsumexp would lose log n)."""
+    rng = np.random.RandomState(t + d)
+    bh = 4
+    q, k, v = (jnp.asarray(rng.randn(bh, t, d), dtype) for _ in range(3))
+    causal = mask.startswith("causal")
+    kbias = None
+    if mask.endswith("kbias"):
+        kb = np.where(np.arange(t)[None, :] < t - 5, 0.0, -1e9) + rng.randn(
+            bh, t)
+        kb[1] = -1e9
+        kbias = jnp.asarray(kb, jnp.float32)
+    scale = 1.0 / np.sqrt(d)
+    w = jnp.cos(jnp.arange(bh * t * d, dtype=jnp.float32)).reshape(bh, t, d)
+    argnums = (0, 1, 2, 3) if kbias is not None else (0, 1, 2)
+
+    def close(got, want):
+        got, want = (np.asarray(x, np.float32) for x in (got, want))
+        assert np.isfinite(got).all()
+        assert (np.max(np.abs(got - want))
+                <= tol * max(1.0, np.max(np.abs(want))))
+
+    def loss(fn):
+        return lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * w)
+
+    one_tile = lambda q, k, v, kb: short_attention(  # noqa: E731
+        q, k, v, kb, causal, scale)
+    dense = lambda q, k, v, kb: _dense_attention(  # noqa: E731
+        q, k, v, causal, scale, kb)
+    out = one_tile(q, k, v, kbias)
+    assert out.shape == (bh, t, d) and out.dtype == q.dtype
+    close(out, dense(q, k, v, kbias))
+    got = jax.grad(loss(one_tile), argnums)(q, k, v, kbias)
+    want = jax.grad(loss(dense), argnums)(q, k, v, kbias)
+    for g, r, like in zip(got, want, (q, k, v, kbias)):
+        assert g.shape == like.shape and g.dtype == like.dtype
+        close(g, r)
+        if kbias is not None:  # the fully padded row against its own scale
+            close(g[1], r[1])
+
+
+def test_short_attention_plan_is_a_function_of_the_shapes():
+    """Heads a grid step and heads a tile from (B*H, T, d, dtype) alone:
+    the two cells' plans, a width of 128, float32's, and a B*H the pack
+    does not divide."""
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    assert pk._short_plan(1024, 256, 64, 64, 2) == pk._ShortPlan(1, 8)
+    assert pk._short_plan(4096, 64, 64, 64, 2) == pk._ShortPlan(2, 32)
+    assert pk._short_plan(512, 256, 128, 128, 2) == pk._ShortPlan(1, 8)
+    for args in ((1024, 256, 64, 64, 2), (96, 384, 64, 64, 4),
+                 (7, 64, 64, 64, 2)):
+        plan = pk._short_plan(*args)
+        assert plan == pk._short_plan(*args)
+        assert args[0] % (plan.pack * plan.heads) == 0
+    assert pk._short_plan(7, 64, 64, 64, 2).pack == 1
 
 
 def test_every_kernel_lowers_for_tpu_without_a_chip(monkeypatch):

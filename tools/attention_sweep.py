@@ -8,8 +8,22 @@ transformer cells' attention shapes.  Run on a TPU:
 
 Prints one JSON line a (shape, lowering) and the best block pair a shape.
 A lowering is judged in its cell in the end (PERF.md, PR 24); this only
-orders the candidates.  T = 64 (tfm_base_train_s64) is no multiple of 128
-and cannot engage, so it is not here.
+orders the candidates.  The lengths under the blockwise kernel's reach are
+--short's.
+
+With --short it sweeps the one-tile kernel of the short sequences (PR 62,
+pallas_kernels.short_attention) at the two Transformer-base cells' shapes
+and the lengths between and beside them (SHORT_SHAPES), causal and not,
+with the key-padding bias: dense | the blockwise kernel at one block of T |
+the one-tile kernel at each heads-a-step G and each pack (heads side by
+side in a tile's lanes), forward alone and forward + backward.  G and the
+pack are held in the tool (pallas_kernels._short_plan patched for that row:
+the program has no such switch; it computes both from the shapes).  Each
+lowering is timed from q, k, v stored [BH, T, d]: the one-tile kernel's
+transposes to [BH, d, T] and back are in its time here, where a model's step
+folds them into the copies the projections' [B, T, H d] need anyway.
+
+    python3 tools/attention_sweep.py --short [--out chiprun_out/short_sweep.json]
 
 With --window W it sweeps the sliding-window kernel instead (PR 41), at
 trinity_mini_train's shape unless --bh / --t / --d say another: square
@@ -73,6 +87,18 @@ SHAPES = [
     ("T512", 128, 512, 64, True, False),
 ]
 BLOCKS = (128, 256, 512, 1024)
+# (what, B*H, T, head dim, causal or not) of --short, with a key bias
+SHORT_SHAPES = [
+    ("tfm_base_train", 1024, 256, 64, (True, False)),
+    ("tfm_base_train_s64", 4096, 64, 64, (True, False)),
+    ("T128", 2048, 128, 64, (True,)),
+    ("T384", 672, 384, 64, (True,)),
+    ("T256_d128", 512, 256, 128, (True,)),
+    ("T64_d128", 2048, 64, 128, (True,)),
+    ("T128_d128", 1024, 128, 128, (True,)),
+    ("T384_d128", 336, 384, 128, (True,)),
+]
+SHORT_HEADS = (1, 2, 4, 8, 16, 32, 64)
 # (cells, B*H, T, width of Q and K, width of V, window) of --tile-classes:
 # the nine flash cells' attention cores, in blocks of nn_ops._flash_block(T)
 TILE_SHAPES = [
@@ -109,6 +135,9 @@ def main():
     ap.add_argument("--parts", default="",
                     help="with --tile-classes: further strip counts to time,"
                     " forward x backward, as 1x4,4x4")
+    ap.add_argument("--short", action="store_true",
+                    help="sweep the one-tile kernel of the short sequences "
+                    "over heads a grid step and sequences a tile")
     ap.add_argument("--repeats", type=int, default=3)
     ap.add_argument("--shapes", default="",
                     help="with --tile-classes or --dead-fetch: only the "
@@ -129,7 +158,7 @@ def main():
     dev = jax.devices()[0]
     if dev.platform != "tpu" and not (
             args.rehearse and (args.window or args.tile_classes
-                               or args.dead_fetch)):
+                               or args.dead_fetch or args.short)):
         raise SystemExit("attention_sweep: needs a TPU, jax found %s" % dev)
 
     def timed(fn, operands, backward=True):
@@ -396,6 +425,81 @@ def main():
         jax.clear_caches()
         return rows
 
+    def short_sweep():
+        """One JSON line a (shape, causal): ms forward and forward +
+        backward of dense, of the blockwise kernel at one block of T, and of
+        the one-tile kernel by "G x pack"; the best of those."""
+        plan = pk._short_plan
+        words = [w for w in args.shapes.split(",") if w]
+        rows = []
+        for name, bh, t, d, causals in SHORT_SHAPES:
+            if words and not any(word in name for word in words):
+                continue
+            if args.rehearse:
+                bh = 8
+            keys = jax.random.split(jax.random.PRNGKey(0), 3)
+            q, k, v = (jax.random.normal(kk, (bh, t, d), jnp.float32).astype(
+                jnp.bfloat16) for kk in keys)
+            kb = (jnp.where(jnp.arange(t)[None, :] < t - 7, 0.0, -1e9).astype(
+                jnp.float32) * jnp.ones((bh, 1), jnp.float32))
+            scale = d ** -0.5
+            for causal in causals:
+                def both(fn):
+                    if args.rehearse:  # the plumbing: one call, no time
+                        jax.block_until_ready(jax.grad(lambda *a: jnp.sum(
+                            fn(*a).astype(jnp.float32)))(q, k, v, kb))
+                        return [None, None]
+                    return [round(min(timed(fn, (q, k, v, kb), backward=b)
+                                      for _ in range(args.repeats)), 4)
+                            for b in (False, True)]
+
+                def attempt(fn, what):
+                    try:
+                        return both(fn)
+                    except Exception as e:  # e.g. tiles over the VMEM limit
+                        print("%s %s refused: %s" % (name, what,
+                                                     str(e)[:300]), flush=True)
+                        return None
+
+                row = {"shape": name, "bh": bh, "t": t, "d": d,
+                       "causal": causal, "kbias": True,
+                       "dense_fwd_fwdbwd_ms": both(
+                           lambda q, k, v, kb: pk._dense_attention(
+                               q, k, v, causal, scale, kb)),
+                       "blockwise_fwd_fwdbwd_ms": attempt(
+                           lambda q, k, v, kb: pk.flash_attention(
+                               q, k, v, kb, causal, scale, t, t),
+                           "blockwise"),
+                       "one_tile_fwd_fwdbwd_ms": {}}
+                for p in (1, 2, 4):
+                    if p > 1 and p * t > 256:
+                        continue
+                    for g in SHORT_HEADS:
+                        # the f32 tiles a step keeps live, as _short_plan
+                        # counts them, inside the 32 MiB the kernels ask for
+                        if ((bh // p) % g or (args.rehearse and g > 2)
+                                or g * 18 * (p * t) * max(p * t, 128)
+                                > 26 * 2 ** 20):
+                            continue
+                        pk._short_plan = (
+                            lambda *a, g=g, p=p: pk._ShortPlan(p, g))
+                        ms = attempt(lambda q, k, v, kb: pk.short_attention(
+                            q, k, v, kb, causal, scale), "%dx%d" % (g, p))
+                        row["one_tile_fwd_fwdbwd_ms"]["%dx%d" % (g, p)] = ms
+                pk._short_plan = plan
+                ok = {c: ms[1] for c, ms in
+                      row["one_tile_fwd_fwdbwd_ms"].items()
+                      if ms and ms[1] is not None}
+                row["best"] = min(ok, key=ok.get) if ok else None
+                took = plan(bh, t, d, d, 2)
+                row["the_program_takes"] = "%dx%d" % (took.heads, took.pack)
+                rows.append(row)
+                print(json.dumps(row), flush=True)
+                # a row's executables go before the next row's come (the
+                # host of a one-chip machine ran out of memory over them)
+                jax.clear_caches()
+        return rows
+
     def save(rows, name):
         """Writes the sweep's rows to --out, or to its own file
         chiprun_out/<name>.json where --out was not given (a rehearsal's
@@ -408,6 +512,8 @@ def main():
             json.dump({"device": dev.device_kind, "iters": args.iters,
                        "rows": rows}, f, indent=1)
 
+    if args.short:
+        return save(short_sweep(), "short_sweep")
     if args.dead_fetch:
         return save(dead_fetch_sweep(), "dead_fetch_sweep")
     if args.tile_classes:
