@@ -236,21 +236,29 @@ def kernel_cases(rehearse):
 
     # Transformer-base's decoder self-attention (T 256, 8 heads of 64,
     # causal with the key-padding bias), as the op lowers it under the
-    # blockwise kernel's lengths: the one-tile form, several heads a step
-    SB, SH, ST = S(8, 1), S(8, 2), S(256, 128)
+    # blockwise kernel's lengths: the one-tile form, several heads a step,
+    # from [B, H, T, d] behind its own transposes, and (layout "bthd", what
+    # the Transformer builder's Program holds since PR 63) from the
+    # projections' [B, T, H, d] in place
+    SB, SH, ST = S(8, 1), S(8, 2), S(256, 64)
+    heads = (0, 2, 1, 3)
 
     def short_op(q, k, v, bias):
-        return nn_ops._fused_attention(
-            LowerCtx(platform="tpu"),
-            {"Q": [q], "K": [k], "V": [v], "Bias": [bias]},
-            {"causal": True})["Out"][0]
+        def op(layout, q, k, v):
+            return nn_ops._fused_attention(
+                LowerCtx(platform="tpu"),
+                {"Q": [q], "K": [k], "V": [v], "Bias": [bias]},
+                {"causal": True, "layout": layout})["Out"][0]
+        return op("bhtd", q, k, v), op(
+            "bthd", *(jnp.transpose(x, heads) for x in (q, k, v)))
 
     def short_dense(q, k, v, bias):
         flat = [a.reshape(SB * SH, ST, AD) for a in (q, k, v)]
         kb = jnp.broadcast_to(bias[:, None, :], (SB, SH, ST)).reshape(
             SB * SH, ST)
-        return pk._dense_attention(*flat, True, AD ** -0.5, kb).reshape(
+        out = pk._dense_attention(*flat, True, AD ** -0.5, kb).reshape(
             q.shape)
+        return out, jnp.transpose(out, heads)
 
     cases["short_attention"] = (
         with_grads(short_op, 3), with_grads(short_dense, 3),
@@ -258,7 +266,7 @@ def kernel_cases(rehearse):
             jnp.where(jnp.arange(ST)[None, :] < ST - 9, 0.0, -1e9).astype(f32)
             * jnp.ones((SB, 1), f32),),
         "Transformer-base decoder self-attention, T %d, as fused_attention "
-        "lowers it by default" % ST)
+        "lowers it by default in either layout" % ST)
 
     PB, PT = S(32, 2), S(256, 16)
 

@@ -1668,11 +1668,18 @@ def log_loss(input, label, epsilon=1e-4, name=None):
 
 
 def fused_attention(q, k, v, causal=False, scale=None, bias=None,
-                    window=0, segment_ids=None, qstart=None, name=None):
+                    window=0, segment_ids=None, qstart=None, name=None,
+                    layout="bhtd"):
     """Fused scaled-dot-product attention over [batch, heads, T, d]
     (the blockwise flash kernel, or its one-tile form under 512 positions,
     where the placed platform and the shape choose it:
-    ops/nn_ops._flash_engages, _short_engages).  V may be of another width
+    ops/nn_ops._flash_engages, _short_engages).  layout="bthd": q, k, v
+    are [batch, T, heads, d], a projection's [batch, T, heads * d]
+    reshaped, and the result is laid out alike; the op computes the same
+    thing, and on a TPU-placed step the one-tile form reads and writes
+    those arrays in place where ops/nn_ops._in_place_engages says so (the
+    transposes around a "bhtd" op cost two copies an operand there), every
+    other path transposes inside the lowering.  V may be of another width
     than Q and K ([batch, heads, Tk, d_v]: latent attention scores 192
     wide over 128-wide values); the result is [batch, heads, Tq, d_v].
     bias: optional
@@ -1700,6 +1707,9 @@ def fused_attention(q, k, v, causal=False, scale=None, bias=None,
     if qstart is not None and not causal:
         raise ValueError("fused_attention: qstart requires causal=True "
                          "(it defines the global causal cutoffs)")
+    if layout not in ("bhtd", "bthd"):
+        raise ValueError("fused_attention: layout is 'bhtd' or 'bthd', got "
+                         "%r" % (layout,))
     helper = LayerHelper("fused_attention", **locals())
     out = helper.create_variable_for_type_inference(q.dtype)
     inputs = {"Q": [q], "K": [k], "V": [v]}
@@ -1716,7 +1726,8 @@ def fused_attention(q, k, v, causal=False, scale=None, bias=None,
         "fused_attention",
         inputs=inputs,
         outputs={"Out": [out]},
-        attrs={"causal": causal, "scale": scale, "window": int(window)},
+        attrs=dict({"causal": causal, "scale": scale, "window": int(window)},
+                   **({"layout": layout} if layout != "bhtd" else {})),
     )
     out.shape = tuple(q.shape[:-1]) + (v.shape[-1],)
     return out

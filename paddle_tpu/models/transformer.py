@@ -706,6 +706,11 @@ def wmt_transformer_program(hp=ModelHyperParams, src_len=64, trg_len=64, learnin
         # (one dense lowering each, their epilogues fused by XLA)
         apply_pass(main, "linear_xent_fuse_pass")
         apply_pass(main, "matmul_epilogue_fuse_pass")
+        # and the heads' transposes around each fused attention into the
+        # op: it takes the projections' [B, T, H, d] as they are written
+        # (layout "bthd"), and the one-tile kernel reads them in place
+        # where nn_ops._in_place_engages says so
+        apply_pass(main, "attention_layout_fuse_pass")
 
         if use_bf16:
             # AMP rides the pass registry (bf16 MXU compute, f32 master

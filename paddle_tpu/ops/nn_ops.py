@@ -962,15 +962,31 @@ def _qstart_attention(q, k, v, qstart, scale, window):
 @register("fused_attention", no_grad_inputs=("QStart",))
 def _fused_attention(ctx, ins, attrs):
     """Fused scaled-dot-product attention (the cuDNN-fused-kernel slot of
-    the reference, TPU-style).  Q/K/V: [batch, heads, T, d].  Training
+    the reference, TPU-style).  Q/K/V: [batch, heads, T, d], or under
+    layout "bthd" [batch, T, heads, d] with Out alike (what the
+    projections write, reshaped: attention_layout_fuse_pass).  Training
     path (no QStart): the blockwise kernel where platform and shape say
     so (_flash_engages), its one-tile form under that kernel's lengths
-    (_short_engages), dense XLA otherwise.  QStart paths (chunked and
-    ragged decode): dense XLA (_qstart_attention)."""
+    (_short_engages; read in place where the layout is "bthd" and
+    _in_place_engages), dense XLA otherwise.  QStart paths (chunked and
+    ragged decode): dense XLA (_qstart_attention).  Every path but the
+    in-place one runs on [batch, heads, T, d]: a "bthd" op transposes
+    to it here and back, so the op means one thing in both layouts."""
     from .pallas_kernels import _dense_attention
 
     q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
     causal = bool(attrs.get("causal", False))
+    layout = attention_layout(attrs)
+    if layout == "bthd":
+        if _in_place_engages(ctx, ins, attrs):
+            return {"Out": [_in_place_attention(ins, causal,
+                                                attrs.get("scale"))]}
+        heads_first = dict(ins, **{slot: [jnp.transpose(ins[slot][0],
+                                                        (0, 2, 1, 3))]
+                                   for slot in ("Q", "K", "V")})
+        out = _fused_attention(ctx, heads_first,
+                               dict(attrs, layout="bhtd"))["Out"][0]
+        return {"Out": [jnp.transpose(out, (0, 2, 1, 3))]}
     window = int(attrs.get("window", 0) or 0)  # sliding-window (causal)
     if window < 0:
         raise ValueError("fused_attention: window must be >= 0")
@@ -1129,7 +1145,11 @@ def _flash_engages(ctx, tq, tk, d, dv=None):
 # other lengths are NOT its: (64, 128) 1.10 / 0.62 / 2.23 and (128, 128)
 # 1.10 / 0.62 / 1.42 (dense ahead: its 128-lane arrays have no padding to
 # copy), (384, 128) 1.30 / 2.20 / 1.14.  The sequence is the kernels' lane
-# dim: whole 128-lane tiles, or 64, two heads side by side.
+# dim: whole 128-lane tiles, or 64, two heads side by side.  Those rows
+# start from q, k, v stored [B H, T, d]; from the projections' [B, T, H d]
+# (64, 64) reads 2.16 and (256, 64) 1.69 with the copies counted, and a
+# Program that asks for layout "bthd" takes the kernel in place there
+# (_IN_PLACE_SHAPES below: 0.53 and 0.87).
 _SHORT_SHAPES = ((64, 64), (128, 64), (256, 64), (384, 64), (256, 128))
 
 
@@ -1143,6 +1163,63 @@ def _short_engages(ctx, tq, tk, d, dv=None, window=0, seg=False):
     return (_placed_on_tpu(ctx) and tq == tk and dv in (None, d)
             and (tq, d) in _SHORT_SHAPES and not window and not seg
             and mesh_ctx() is None)
+
+
+ATTENTION_LAYOUTS = ("bhtd", "bthd")
+
+
+def attention_layout(attrs):
+    """fused_attention's `layout`: "bhtd" (Q/K/V [B, H, T, d], the default
+    and every program's before PR 63) or "bthd" ([B, T, H, d])."""
+    layout = attrs.get("layout") or "bhtd"
+    if layout not in ATTENTION_LAYOUTS:
+        raise ValueError("fused_attention: layout is one of %s, got %r"
+                         % (ATTENTION_LAYOUTS, layout))
+    return layout
+
+
+# Where a "bthd" op takes the one-tile kernel IN PLACE (pallas_kernels.
+# short_attention(..., heads=H): blocks of the projections' [B, T, H d], no
+# copy of an operand): the (T, head width) pairs whose row of the chip sweep
+# from operands stored [B, T, H d] has it ahead (tools/attention_sweep.py
+# --short --in-place; the table is in CHANGES.md, PR 63).  Forward + backward
+# alone on a v5e, bf16, causal, key bias, eight heads of 64, in place
+# against the one-tile kernel above behind the copies XLA needs to reach its
+# layout against dense: (64, 64) at B 512 0.53 / 2.16 / 3.14 ms (two heads a
+# product; a 64-lane slice a head reads 1.75 where the pair reads 1.12, both
+# one sequence a loop body), (256, 64) at B 128 0.87 / 1.69 / 3.05.  A _SHORT_SHAPES pair that has no such row transposes in the
+# lowering and takes the kernel above, as before the layout existed.
+_IN_PLACE_SHAPES = ((64, 64), (256, 64))
+
+
+def _in_place_engages(ctx, ins, attrs):
+    """A "bthd" op whose [B, T, H, d] operands _short_engages would take,
+    at one of _IN_PLACE_SHAPES, no QStart."""
+    q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
+    return (not ins.get("QStart") and q.shape[2] == k.shape[2]
+            and (q.shape[1], q.shape[3]) in _IN_PLACE_SHAPES
+            and _short_engages(ctx, q.shape[1], k.shape[1], q.shape[3],
+                               v.shape[3], int(attrs.get("window", 0) or 0),
+                               bool(ins.get("SegmentIds"))))
+
+
+def _in_place_attention(ins, causal, scale):
+    from .kernel_tuning import note_kernel
+    from .pallas_kernels import short_attention
+
+    q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
+    b, t, h, d = q.shape
+    kbias = None
+    if ins.get("Bias"):  # [B, Tk] (or any shape squeezing to it), f32
+        kbias = ins["Bias"][0].reshape(b, t).astype(jnp.float32)
+    note_kernel("attention")
+    note_kernel("attention_short")
+    note_kernel("attention_short_in_place")
+    out = short_attention(
+        q.reshape(b, t, h * d), k.reshape(b, t, h * d),
+        v.reshape(b, t, h * v.shape[3]), kbias, causal,
+        float(scale or 1.0 / (d ** 0.5)), h)
+    return out.reshape(b, t, h, v.shape[3])
 
 
 @register("sequence_conv")
@@ -1694,19 +1771,24 @@ def _frln_infer(op, ins):
 @register_infer("fused_attention", req_ins=("Q", "K", "V"))
 def _fattn_infer(op, ins):
     q, k, v = _vi(ins, "Q"), _vi(ins, "K"), _vi(ins, "V")
+    try:
+        layout = attention_layout(op.attrs)
+    except ValueError as e:
+        raise InferError(str(e))
+    want = "[B, H, T, D]" if layout == "bhtd" else "[B, T, H, D]"
     for name, t in (("Q", q), ("K", k), ("V", v)):
         if t is not None and t.shape is not None and len(t.shape) != 4:
             raise InferError(
-                "fused_attention %s must be rank-4 [B, H, T, D], got %s"
-                % (name, t.shape))
+                "fused_attention %s must be rank-4 %s, got %s"
+                % (name, want, t.shape))
     if (q is not None and k is not None and q.shape is not None
             and k.shape is not None and q.shape[-1] >= 0
             and k.shape[-1] >= 0 and q.shape[-1] != k.shape[-1]):
         raise InferError(
             "fused_attention head-dim mismatch: Q%s vs K%s"
             % (q.shape, k.shape))
-    # [B, H, Tq, d_v]: V's width, which is Q's everywhere but under latent
-    # attention
+    # Q's shape at V's width ([B, H, Tq, d_v], or [B, Tq, H, d_v] under
+    # "bthd"): V's width is Q's everywhere but under latent attention
     shape = q.shape if q else None
     if shape is not None and v is not None and v.shape is not None:
         shape = tuple(shape[:-1]) + (v.shape[-1],)

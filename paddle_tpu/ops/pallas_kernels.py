@@ -1336,6 +1336,11 @@ def _short_pack(t):
     return max(1, 128 // t)
 
 
+def _divisor_up_to(n, most):
+    """The largest divisor of n that is at most `most` (1 at the least)."""
+    return next(x for x in range(max(1, min(most, n)), 0, -1) if n % x == 0)
+
+
 class _ShortPlan(NamedTuple):
     pack: int
     heads: int
@@ -1352,9 +1357,7 @@ def _short_plan(bh, t, d, dv, itemsize):
               + 2 * itemsize * lanes * (5 * d + 4 * dv)  # the blocks, twice
               + 2 * 4 * 8 * 4 * lanes)  # kb, max | 1 / sum, dkb rows
     most = max(1, _SHORT_VMEM_BYTES // a_tile)
-    tiles = bh // pack
-    return _ShortPlan(pack, next(g for g in range(most, 0, -1)
-                                 if tiles % g == 0))
+    return _ShortPlan(pack, _divisor_up_to(bh // pack, most))
 
 
 def _short_t(x, pack):
@@ -1466,26 +1469,296 @@ def _short_statics(bh, t, d, dv, dtype, causal, scale):
                 interpret=_interpret())
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def short_attention(q, k, v, kbias=None, causal=False, scale=None):
+# ---------------------------------------------------------------------------
+# the same one tile, read where the projections wrote it
+#
+# A projection's matmul writes [B, T, H d]: H d lanes, whole 128-lane tiles,
+# no padding.  The kernels above reach their [BH / pack, d, pack T] from it
+# in two transposing copies an operand at T = 64 (35.0 ms of a 175.6 ms step
+# of tfm_base_train_s64, PERF.md section 5, PR 62).  These index that array
+# as it is: a block is (seqs, T, H d) on the batch axis, and a head is a
+# static slice of the lanes.  Heads of 64 sit two to a 128-lane tile, and a
+# step takes such a GROUP of heads in one product with no lane moved: the
+# queries (and dO) are laid out block-diagonally ([group T, group d], a
+# head's rows keep its own d lanes and zeros elsewhere: _diag), so
+# k [T, group d] x that^T is [keys, group T queries], each head's scores
+# side by side in full 128 lanes, and the products that follow (dv, dk
+# against the same block-diagonal operand) land in the operands' own lanes.
+# Only the two products that contract over the KEYS of a [keys, queries]
+# tile (o and dq: the tile's major dim on both sides) come out [group T,
+# group d] with the heads' blocks on the diagonal; _undiag picks each head's
+# lanes from its own rows.
+# The tiles are [keys, queries] as above: max, 1 / sum and delta are rows,
+# the reductions run down sublanes.  delta is sum_k p dp, the softmax's own
+# Jacobian, in f32: the backward reads no o.
+# ---------------------------------------------------------------------------
+class _InPlacePlan(NamedTuple):
+    seqs: int  # sequences a grid step
+    group: int  # heads a product: their d lanes side by side
+    unroll: int  # sequences a loop body holds, batched
+
+
+# (sequence, group of heads) pairs a loop body holds, the sequences batched
+# in every product: each pair is a chain of products and reductions that
+# waits on itself, and the compiler fills one chain's waits with the others'
+# work.  From the sweep on a v5e (tools/attention_sweep.py --short
+# --in-place; CHANGES.md, PR 63; forward + backward of one layer from
+# [B, T, H d], bf16, causal, key bias): B 512 x T 64, 16 sequences a step: 4
+# pairs a body 1.123 ms, 8 0.728, 16 0.576, 32 0.528, 64 0.524 (PR 62's
+# kernel behind its copies 2.164, dense 3.144); B 128 x T 256, 4 sequences a
+# step: 4 pairs 0.942, 8 0.878, 16 0.871 (1.691, 3.049).  32 is also the
+# most tiles PR 62's kernel holds a step.  (The same bodies unrolled in
+# Python, one sequence a product, read 0.790 at 32 and cost a first step 5 s
+# of tracing and lowering.)
+_INPLACE_CHAINS = 32
+
+
+def _inplace_plan(b, t, h, d, dv, itemsize):
+    """A function of the shapes and the operands' dtype alone: as many heads
+    a product as fill 128 lanes (and divide H), as many sequences a step as
+    keep the backward's seven blocks, twice, and the live f32 tiles of one
+    group inside _SHORT_VMEM_BYTES (B 512 x T 64 x 8 heads of 64: 16; 2 to
+    32 a step read within 2 % of each other), as many of them a loop body as
+    make _INPLACE_CHAINS chains."""
+    group = next(g for g in range(max(1, 128 // max(d, dv)), 0, -1)
+                 if h % g == 0)
+    a_seq = 2 * itemsize * t * h * (4 * d + 3 * dv)
+    tiles = 6 * 4 * t * max(128, group * t)
+    most = max(1, (_SHORT_VMEM_BYTES - tiles) // a_seq)
+    seqs = _divisor_up_to(b, most)
+    return _InPlacePlan(seqs, group, _divisor_up_to(
+        seqs, _INPLACE_CHAINS // (h // group)))
+
+
+def _inplace_loop(plan, some_sequences):
+    """some_sequences(rows) for every plan.unroll sequences of the block:
+    one batched body (the compiler schedules its independent chains
+    against each other), a loop over the bodies."""
+    from jax.experimental import pallas as pl
+
+    def body(i, carry):
+        some_sequences(pl.ds(i * plan.unroll, plan.unroll))
+        return carry
+
+    jax.lax.fori_loop(0, plan.seqs // plan.unroll, body, 0)
+
+
+def _lane_head(shape, width):
+    """int32 of `shape`: which head of the group a lane belongs to."""
+    return jax.lax.broadcasted_iota(jnp.int32, shape, 2) // width
+
+
+def _diag(x, group, width):
+    """[S, T, group width] -> [S, group T, group width]: head g's rows keep
+    its own lanes, zeros elsewhere."""
+    if group == 1:
+        return x
+    head = _lane_head(x.shape, width)
+    return jnp.concatenate(
+        [jnp.where(head == g, x, jnp.zeros_like(x)) for g in range(group)],
+        axis=1)
+
+
+def _undiag(r, group, width):
+    """_diag's reading: [S, group T, group width] -> [S, T, group width],
+    head g's lanes from its own rows."""
+    if group == 1:
+        return r
+    t = r.shape[1] // group
+    head = _lane_head((r.shape[0], t, r.shape[2]), width)
+    out = r[:, :t]
+    for g in range(1, group):
+        out = jnp.where(head == g, r[:, g * t:(g + 1) * t], out)
+    return out
+
+
+def _inplace_scores(k, qd, kb, keep, scale):
+    """The masked f32 scores [S, keys, group T queries] of k [S, T, group d]
+    against the block-diagonal queries; kb the keys' bias as columns."""
+    st = _bdot(k, qd, (2, 2)) * scale
+    if kb is not None:
+        st = st + kb
+    if keep is not None:
+        st = jnp.where(keep, st, NEG_INF)
+    return st
+
+
+def _inplace_keep(t, group, causal):
+    """[1, keys, group T queries]: a query sees the keys at or before it."""
+    if not causal:
+        return None
+    ki = jax.lax.broadcasted_iota(jnp.int32, (1, t, group * t), 1)
+    qi = jax.lax.broadcasted_iota(jnp.int32, (1, t, group * t), 2) % t
+    return qi >= ki
+
+
+def _inplace_fwd_kernel(*refs, causal, scale, has_kb, plan, heads):
+    q_ref, k_ref, v_ref = refs[:3]
+    kb_ref = refs[3] if has_kb else None
+    o_ref, stat_ref = refs[-2:]
+    t, group = q_ref.shape[1], plan.group
+    d, dv = q_ref.shape[2] // heads, v_ref.shape[2] // heads
+    keep = _inplace_keep(t, group, causal)
+
+    def some_sequences(b):
+        kb = kb_ref[b].reshape(plan.unroll, t, 1) if has_kb else None
+        for j in range(heads // group):
+            qk = slice(j * group * d, (j + 1) * group * d)
+            vs = slice(j * group * dv, (j + 1) * group * dv)
+            v = v_ref[b, :, vs]
+            st = _inplace_scores(k_ref[b, :, qk],
+                                 _diag(q_ref[b, :, qk], group, d), kb, keep,
+                                 scale)
+            m = jnp.max(st, axis=1, keepdims=True)  # [S, 1, group T]
+            p = jnp.exp(st - m)
+            linv = 1.0 / jnp.sum(p, axis=1, keepdims=True)  # sum >= 1
+            # the max and 1 / sum as two rows, not their lse (see
+            # _short_fwd_kernel)
+            stat_ref[b, 2 * j:2 * j + 1, :] = m
+            stat_ref[b, 2 * j + 1:2 * j + 2, :] = linv
+            o_ref[b, :, vs] = _undiag(
+                _bdot((p * linv).astype(v.dtype), v, (1, 1)), group,
+                dv).astype(o_ref.dtype)
+
+    _inplace_loop(plan, some_sequences)
+
+
+def _inplace_bwd_kernel(*refs, causal, scale, has_kb, plan, heads):
+    refs = list(refs)
+    q_ref, k_ref, v_ref = refs[:3]
+    del refs[:3]
+    kb_ref = refs.pop(0) if has_kb else None
+    do_ref, stat_ref, dq_ref, dk_ref, dv_ref = refs[:5]
+    dkb_ref = refs[5] if has_kb else None
+    t, group = q_ref.shape[1], plan.group
+    d, dv = q_ref.shape[2] // heads, v_ref.shape[2] // heads
+    keep = _inplace_keep(t, group, causal)
+
+    def some_sequences(b):
+        kb = kb_ref[b].reshape(plan.unroll, t, 1) if has_kb else None
+        dst_sum = None
+        for j in range(heads // group):
+            qk = slice(j * group * d, (j + 1) * group * d)
+            vs = slice(j * group * dv, (j + 1) * group * dv)
+            k, v = k_ref[b, :, qk], v_ref[b, :, vs]
+            qd = _diag(q_ref[b, :, qk], group, d)
+            dod = _diag(do_ref[b, :, vs], group, dv)
+            st = _inplace_scores(k, qd, kb, keep, scale)
+            p = (jnp.exp(st - stat_ref[b, 2 * j:2 * j + 1, :])
+                 * stat_ref[b, 2 * j + 1:2 * j + 2, :])
+            dv_ref[b, :, vs] = _bdot(p.astype(v.dtype), dod, (2, 1)).astype(
+                dv_ref.dtype)
+            dp = _bdot(v, dod, (2, 2))
+            dst = p * (dp - jnp.sum(p * dp, axis=1, keepdims=True))
+            if has_kb:
+                dst_sum = dst if dst_sum is None else dst_sum + dst
+            dsc = dst.astype(k.dtype)
+            dk_ref[b, :, qk] = (scale * _bdot(dsc, qd, (2, 1))).astype(
+                dk_ref.dtype)
+            dq_ref[b, :, qk] = (scale * _undiag(
+                _bdot(dsc, k, (1, 1)), group, d)).astype(dq_ref.dtype)
+        if has_kb:
+            dkb_ref[b] = jnp.sum(dst_sum, axis=2).reshape(plan.unroll, 1, t)
+
+    _inplace_loop(plan, some_sequences)
+
+
+def _inplace_call(kernel, plan, interpret, args, outs):
+    """One pallas_call over the batch axis: every operand and every result
+    ([B, ., .] arrays; `outs` their ShapeDtypeStructs) in blocks of
+    plan.seqs sequences."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def spec(x):
+        return pl.BlockSpec((plan.seqs,) + tuple(x.shape[1:]),
+                            lambda i: (i, 0, 0), memory_space=pltpu.VMEM)
+
+    return pl.pallas_call(
+        kernel, grid=(args[0].shape[0] // plan.seqs,),
+        in_specs=[spec(x) for x in args], out_specs=[spec(x) for x in outs],
+        out_shape=outs, compiler_params=_mosaic_params(),
+        interpret=interpret)(*args)
+
+
+_INPLACE_STATICS = ("heads", "causal", "scale", "plan", "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_INPLACE_STATICS)
+def _inplace_fwd_call(q, k, v, kbias, *, heads, causal, scale, plan,
+                      interpret):
+    """q, k: [B, T, H d], v: [B, T, H dv], kbias: [B, T] f32 or None.
+    Returns (o [B, T, H dv], what the backward reads: q, k, v, the bias
+    rows [B, 1, T] and the [B, 2 H / group, group T] max | 1 / sum
+    rows)."""
+    b, t, _ = q.shape
+    kb = None if kbias is None else kbias.reshape(b, 1, t)
+    o, stat = _inplace_call(
+        functools.partial(_inplace_fwd_kernel, causal=causal, scale=scale,
+                          has_kb=kb is not None, plan=plan, heads=heads),
+        plan, interpret, [x for x in (q, k, v, kb) if x is not None],
+        [_sds(v.shape, q.dtype, q, k, v),
+         _sds((b, 2 * (heads // plan.group), plan.group * t), jnp.float32,
+              q, k, v)])
+    return o, (q, k, v, kb, stat)
+
+
+@functools.partial(jax.jit, static_argnames=_INPLACE_STATICS)
+def _inplace_bwd_call(res, do, *, heads, causal, scale, plan, interpret):
+    """(dq, dk, dv, dkbias [B, T] f32 or None): one pallas_call."""
+    q, k, v, kb, stat = res
+    grads = [q, k, v] + ([] if kb is None else [kb])
+    outs = _inplace_call(
+        functools.partial(_inplace_bwd_kernel, causal=causal, scale=scale,
+                          has_kb=kb is not None, plan=plan, heads=heads),
+        plan, interpret, grads + [do, stat],
+        [_sds(x.shape, x.dtype, q, k, v, do) for x in grads])
+    return outs[0], outs[1], outs[2], (
+        outs[3].reshape(q.shape[:2]) if kb is not None else None)
+
+
+def _inplace_statics(q, v, heads, causal, scale):
+    b, t, hd = q.shape
+    d, dv = hd // heads, v.shape[2] // heads
+    if scale is None:
+        scale = 1.0 / (d ** 0.5)
+    return dict(heads=int(heads), causal=bool(causal), scale=float(scale),
+                plan=_inplace_plan(b, t, heads, d, dv,
+                                   jnp.dtype(q.dtype).itemsize),
+                interpret=_interpret())
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def short_attention(q, k, v, kbias=None, causal=False, scale=None,
+                    heads=None):
     """flash_attention's one-tile form for a short self-attention, q, k:
     [BH, T, d], v: [BH, T, dv] -> [BH, T, dv], kbias: optional [BH, T]
     additive key bias: the whole sequence is one tile and a grid step holds
     several heads (_short_plan), so a head pays no grid step of its own,
     and the kernels read and write [BH, d, T], the sequence in the lanes.
+    With `heads` the operands are the projections' own arrays, q, k:
+    [B, T, H d], v: [B, T, H dv] -> [B, T, H dv], kbias: [B, T], and the
+    kernels read and write them in place (_inplace_plan): no copy of an
+    operand on either side.
     The same arithmetic at the same precision as flash_attention (f32
     scores and softmax statistics, MXU operands in their own dtype), no
     [BH, T, T] array in HBM; the backward is one kernel and rebuilds the
     probabilities from each query's saved max and 1 / sum."""
-    return _short_vjp_fwd(q, k, v, kbias, causal, scale)[0]
+    return _short_vjp_fwd(q, k, v, kbias, causal, scale, heads)[0]
 
 
-def _short_vjp_fwd(q, k, v, kbias, causal, scale):
+def _short_vjp_fwd(q, k, v, kbias, causal, scale, heads):
+    if heads is not None:
+        return _inplace_fwd_call(q, k, v, kbias, **_inplace_statics(
+            q, v, heads, causal, scale))
     return _short_fwd_call(q, k, v, kbias, **_short_statics(
         *q.shape, v.shape[2], q.dtype, causal, scale))
 
 
-def _short_vjp_bwd(causal, scale, res, do):
+def _short_vjp_bwd(causal, scale, heads, res, do):
+    if heads is not None:
+        return _inplace_bwd_call(res, do, **_inplace_statics(
+            res[0], res[2], heads, causal, scale))
     qt = res[0]  # [BH / pack, d, pack T]
     return _short_bwd_call(res, do, **_short_statics(
         do.shape[0], do.shape[1], qt.shape[1], do.shape[2], qt.dtype, causal,

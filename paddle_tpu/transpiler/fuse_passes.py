@@ -839,6 +839,74 @@ class LinearXentFusePass(Pass):
         return program
 
 
+_HEADS_AXIS = [0, 2, 1, 3]  # [B, T, H, d] <-> [B, H, T, d], its own inverse
+
+
+@register_pass("attention_layout_fuse_pass")
+class AttentionLayoutFusePass(Pass):
+    """transpose2 x 3 -> fused_attention -> transpose2, every transpose the
+    heads' ([0, 2, 1, 3])  =>  ONE fused_attention with layout "bthd" on the
+    untransposed values: Q, K, V [B, T, H, d] as the projections' reshape2
+    leaves them, Out [B, T, H, dv] as the output projection's reshape2 takes
+    it (both reshapes stay: they are bitcasts).  The op computes what the
+    chain computed (its lowering transposes inside wherever the kernel that
+    reads the projections' arrays in place does not engage:
+    nn_ops._in_place_engages); what goes is the Program's demand for
+    [B, H, T, d] arrays, which cost a TPU-placed step two copies an operand
+    where the kernel wants another layout.  Conservative: a training-path
+    op (no QStart, layout not yet set), each transposed value read by that
+    op alone in ANY block, the op's result read by its transpose alone,
+    protected fetches respected.  Run it before minimize: the backward is
+    then derived from the rewritten op."""
+
+    def apply(self, program, scope=None):
+        block = program.global_block()
+
+        def heads_transpose(op):
+            return (op is not None and op.type == "transpose2"
+                    and list(op.attrs.get("axis", [])) == _HEADS_AXIS)
+
+        producers = {name: op for op in block.ops
+                     for name in op.output_arg_names()}
+        n = 0
+        for attn in list(block.ops):
+            if (attn.type != "fused_attention" or attn.inputs.get("QStart")
+                    or attn.attrs.get("layout")):
+                continue
+            ins = {slot: producers.get(attn.inputs[slot][0])
+                   for slot in ("Q", "K", "V")}
+            out_name = attn.outputs["Out"][0]
+            after = _consumers_all_blocks(program, out_name)
+            if (not all(heads_transpose(op) for op in ins.values())
+                    or len(after) != 1 or not heads_transpose(after[0])
+                    or after[0] not in block.ops):
+                continue
+            if any(_consumers_all_blocks(program, attn.inputs[slot][0])
+                   != [attn] for slot in ins):
+                continue
+            before = list({id(op): op for op in ins.values()}.values())
+            chain = before + [attn, after[0]]
+            if not _chain_safe(program, chain):
+                continue
+            inputs = {slot: list(names)
+                      for slot, names in attn.inputs.items()}
+            for slot, op in ins.items():
+                inputs[slot] = list(op.inputs["X"])
+            fused = _mk_op(block, "fused_attention", inputs,
+                           {"Out": list(after[0].outputs["Out"])},
+                           dict(attn.attrs, layout="bthd"))
+            # at the attention's slot: every untransposed value is defined
+            # there, and the result's readers all come after its transpose
+            _fw.inherit_namescope(attn, fused)
+            block.ops.insert(block.ops.index(attn), fused)
+            for op in chain:
+                block.ops.remove(op)
+            program._bump_version()
+            n += 1
+        program._attention_layout_fused_count = n
+        return program
+
+
 @register_pass("matmul_epilogue_fuse_pass")
 def _matmul_epilogue_fuse(program, scope):
     """The training-program epilogue bundle (ROADMAP item 1): fc

@@ -171,12 +171,14 @@ def program_flops(program, batch_hint=1):
             k = _shape(blk, op.inputs.get("K", [""])[0], batch_hint)
             if not q or not k or len(q) != 4:
                 continue
+            # a grad op carries its forward's attrs under __fwd_attrs__
+            attrs = op.attrs.get("__fwd_attrs__", op.attrs)
+            if (attrs.get("layout") or "bhtd") == "bthd":  # [B, T, H, d]
+                q, k = ([s[0], s[2], s[1], s[3]] for s in (q, k))
             b, h, tq, d = q
             tk = k[2]
             v = _shape(blk, op.inputs.get("V", [""])[0], batch_hint)
             dv = v[-1] if v and len(v) == 4 else d  # latent: 192 over 128
-            # a grad op carries its forward's attrs under __fwd_attrs__
-            attrs = op.attrs.get("__fwd_attrs__", op.attrs)
             window = int(attrs.get("window", 0) or 0)
             if window:  # sliding window: compute scales with the band
                 tk = min(tk, window)

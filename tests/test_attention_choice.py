@@ -924,3 +924,209 @@ def test_window_grid_live_share_is_in_the_benchmark_by_name(monkeypatch):
     kt.note_band_grid(8192, 2048, 1024, 1024, 24, 21)
     assert reader.read(ctx) == 87.5
     kt.reset_attribution()
+
+
+# --------------------------------------------------------------------------
+# layout "bthd" (PR 63): the one-tile form reads [B, T, H, d] in place
+# --------------------------------------------------------------------------
+def _taken_bthd(ctx, t, d, tk=None, **extra):
+    """`_taken` for a "bthd" op on [2, T, 2, d]: "in_place" where the
+    in-place counter ticked beside the one-tile form's."""
+    tk = tk or t
+    q = jax.ShapeDtypeStruct((2, t, 2, d), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((2, tk, 2, d), jnp.bfloat16)
+    ins = {"QStart": jax.ShapeDtypeStruct((1,), jnp.int32),
+           "SegmentIds": jax.ShapeDtypeStruct((2, t), jnp.int32),
+           "Bias": jax.ShapeDtypeStruct((2, tk), jnp.float32)}
+    ins = {slot: ins[slot] for slot in extra.pop("slots", ())}
+    hits = lambda: dict(kt.attribution()["pallas_hits"])  # noqa: E731
+    before = hits()
+    out = jax.eval_shape(
+        lambda q, k, v, ins: nn_ops._fused_attention(
+            ctx, dict({"Q": [q], "K": [k], "V": [v]},
+                      **{s: [a] for s, a in ins.items()}),
+            dict({"causal": True, "layout": "bthd"}, **extra))["Out"][0],
+        q, kv, kv, ins)
+    assert out.shape == q.shape and out.dtype == q.dtype
+    more = {f: n - before.get(f, 0) for f, n in hits().items()
+            if n != before.get(f, 0)}
+    if not more:
+        return "dense"
+    assert more.pop("attention") == 1
+    if more == {"attention_short": 1, "attention_short_in_place": 1}:
+        return "in_place"
+    assert "attention_short_in_place" not in more
+    if more == {"attention_short": 1}:
+        return "one_tile"
+    assert "attention_short" not in more
+    return "blockwise"
+
+
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("t", [56, 64, 128, 256, 384, 512])
+def test_a_bthd_op_reads_in_place_where_the_sweep_has_it_ahead(t, d):
+    """TPU-placed, layout "bthd": in place at the two Transformer-base
+    cells' (T, head width), the pairs `tools/attention_sweep.py --short
+    --in-place` has a row for; at every other shape the op transposes in its
+    lowering and takes what the "bhtd" op takes (the one-tile form behind
+    its own transposes, the blockwise kernel, dense); dense on the CPU."""
+    want = ("in_place" if (t, d) in ((64, 64), (256, 64))
+            else _taken(TPU, t, d))
+    assert _taken_bthd(TPU, t, d) == want
+    assert _taken_bthd(LowerCtx(platform="cpu"), t, d) == "dense"
+
+
+@pytest.mark.parametrize("what,kwargs,want", [
+    ("the key-padding bias rides it", {"slots": ("Bias",)}, "in_place"),
+    ("not causal", {"causal": False}, "in_place"),
+    ("a window", {"window": 32}, "dense"),
+    ("segment ids", {"slots": ("SegmentIds",)}, "dense"),
+    ("QStart (cached decode)", {"slots": ("QStart",)}, "dense"),
+    ("cross-attention over another length", {"tk": 128, "causal": False},
+     "dense"),
+], ids=lambda x: x if isinstance(x, str) else None)
+def test_what_keeps_a_bthd_op_off_the_in_place_form(what, kwargs, want):
+    assert _taken_bthd(TPU, 64, 64, **dict(kwargs)) == want, what
+
+
+@pytest.mark.skipif(len(jax.devices()) < 4, reason="needs 4 virtual devices")
+def test_under_a_live_mesh_a_bthd_op_transposes_into_the_mesh_paths():
+    from paddle_tpu.parallel.mesh import make_mesh
+    from paddle_tpu.parallel.partition_rules import (
+        spmd_lowering, train_partition_rules_for)
+
+    mesh = make_mesh({"dp": 2, "mp": 2}, jax.devices()[:4])
+    with spmd_lowering(mesh, train_partition_rules_for("gpt2")):
+        assert _taken_bthd(TPU, 64, 64) == "dense"
+        assert _taken_bthd(TPU, 512, 64) == "blockwise"
+    assert _taken_bthd(TPU, 64, 64) == "in_place"
+
+
+@pytest.mark.parametrize("t,causal", [(64, True), (64, False), (256, True)])
+def test_in_place_op_matches_the_dense_bhtd_op_forward_and_gradients(
+        t, causal):
+    """A "bthd" op placed on a TPU (the in-place kernel, interpreted here)
+    against the "bhtd" op placed on the CPU (dense) on the transposed
+    operands, the key-padding bias [B, T] shared by the heads: the result
+    and dq / dk / dv, bfloat16."""
+    heads = (0, 2, 1, 3)
+    q, k, v = (jnp.transpose(x, heads) for x in _qkv(2, 4, t, 64, seed=t))
+    bias = jnp.where(jnp.arange(t)[None, :] < jnp.array([[t - 9], [t]]),
+                     0.0, -1e9).astype(jnp.float32)
+
+    def in_place(q, k, v):
+        return nn_ops._fused_attention(
+            TPU, {"Q": [q], "K": [k], "V": [v], "Bias": [bias]},
+            {"causal": causal, "layout": "bthd"})["Out"][0]
+
+    def dense(q, k, v):
+        ins = {s: [jnp.transpose(x, heads)]
+               for s, x in (("Q", q), ("K", k), ("V", v))}
+        return jnp.transpose(nn_ops._fused_attention(
+            LowerCtx(platform="cpu"), dict(ins, Bias=[bias]),
+            {"causal": causal})["Out"][0], heads)
+
+    before = kt.attribution()["pallas_hits"].get(
+        "attention_short_in_place", 0)
+    got = jax.jit(in_place)(q, k, v)
+    assert (kt.attribution()["pallas_hits"]["attention_short_in_place"]
+            == before + 1)
+    assert got.shape == (2, t, 4, 64) and got.dtype == jnp.bfloat16
+    _close(got, dense(q, k, v))
+    grads = jax.jit(jax.grad(_loss(in_place), argnums=(0, 1, 2)))(q, k, v)
+    want = jax.jit(jax.grad(_loss(dense), argnums=(0, 1, 2)))(q, k, v)
+    for g, r in zip(grads, want):
+        assert g.dtype == jnp.bfloat16 and g.shape == r.shape
+        _close(g, r)
+
+
+@pytest.mark.parametrize("b,t,h,d,bias,causal", [
+    (512, 64, 8, 64, True, True), (512, 64, 8, 64, True, False),
+    (512, 64, 8, 64, False, True), (128, 256, 8, 64, True, True),
+    (128, 256, 8, 64, True, False)])
+def test_in_place_kernel_cross_lowers_for_the_tpu_with_no_copy_around_it(
+        monkeypatch, b, t, h, d, bias, causal):
+    """At the two Transformer-base cells' attention shapes, from the
+    projections' [B, T, H d] reshaped as the Program reshapes it and back:
+    two Mosaic calls (forward, backward) and NOT ONE transpose in the
+    lowered text, where the "bhtd" op between its four `transpose2` holds
+    eight and the one-tile form's own on top."""
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    x = jax.ShapeDtypeStruct((b, t, h * d), jnp.bfloat16)
+    kb = jax.ShapeDtypeStruct((b, t), jnp.float32)
+
+    def op(q, k, v, kb):
+        ins = {s: [a.reshape(b, t, h, d)]
+               for s, a in (("Q", q), ("K", k), ("V", v))}
+        if bias:
+            ins["Bias"] = [kb]
+        return nn_ops._fused_attention(
+            TPU, ins, {"causal": causal, "layout": "bthd"})["Out"][
+                0].reshape(b, t, h * d)
+
+    text = jax.jit(jax.grad(
+        lambda q, k, v, kb: _loss(lambda *a: op(*a, kb))(q, k, v),
+        argnums=(0, 1, 2))).trace(x, x, x, kb).lower(
+            lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "stablehlo.transpose" not in text
+
+
+def test_deep_bthd_program_traces_and_carries_the_in_place_kernel_once(
+        monkeypatch):
+    """The host-cost pin of the in-place form: a 4-layer training step
+    whose attentions the layout pass folded traces each kernel body once
+    and holds three Mosaic payloads for twelve call sites, as the one-tile
+    form it replaces does (no second set beside it), and no transpose of the
+    heads."""
+    from paddle_tpu.core.trace import build_traced_function
+    from paddle_tpu.transpiler.pass_registry import apply_pass
+
+    n_layer, heads, d, t = 4, 2, 64, 64
+    counts = {"fwd": 0, "bwd": 0}
+
+    def counted(name, body):
+        def wrapper(*a, **k):
+            counts[name] += 1
+            return body(*a, **k)
+        return wrapper
+
+    for name, body in (("fwd", "_inplace_fwd_kernel"),
+                       ("bwd", "_inplace_bwd_kernel")):
+        monkeypatch.setattr(pk, body, counted(name, getattr(pk, body)))
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    jax.clear_caches()
+
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        x = layers.data("x", shape=[t, heads * d], dtype="float32")
+        h = x
+        for _ in range(n_layer):
+            qkv = [layers.transpose(layers.reshape(
+                layers.fc(h, heads * d, num_flatten_dims=2),
+                [-1, t, heads, d]), [0, 2, 1, 3]) for _ in range(3)]
+            a = layers.fused_attention(*qkv, causal=True)
+            h = h + layers.reshape(layers.transpose(a, [0, 2, 1, 3]),
+                                   [-1, t, heads * d])
+        loss = layers.mean(h)
+        apply_pass(main, "attention_layout_fuse_pass")
+        assert main._attention_layout_fused_count == n_layer
+        fluid.optimizer.SGD(0.1).minimize(loss)
+    scope = fluid.Scope()
+    with fluid.scope_guard(scope):
+        fluid.Executor(fluid.CPUPlace()).run(startup)
+    traced = build_traced_function(main, 0, ("x",), [loss.name], scope,
+                                   platform="tpu")
+    sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)  # noqa: E731
+    args = ({"x": jax.ShapeDtypeStruct((2, t, heads * d), jnp.float32)},
+            {n: sds(scope.find_var(n)) for n in traced.ro_names},
+            {n: sds(scope.find_var(n)) for n in traced.rw_names},
+            sds(jax.random.PRNGKey(0)))
+    text = jax.jit(traced.fn).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert counts == {"fwd": 1, "bwd": 1}
+    assert text.count("tpu_custom_call") == 3
+    assert text.count("call @_inplace_fwd_call") == 2 * n_layer
+    assert text.count("call @_inplace_bwd_call") == n_layer
+    assert "call @_short_" not in text
+    assert "dims = [0, 2, 1, 3]" not in text  # the heads' transposes

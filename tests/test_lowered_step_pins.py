@@ -198,7 +198,19 @@ configurations under the key bias: causal, the decoder's self-attention,
 and not, the encoder's and the cross-attention).  The other seventeen did
 NOT move: every
 attention they hold has T >= 1024 and takes the blockwise kernel through
-the branch it took, and that is the proof of it."""
+the branch it took, and that is the proof of it.
+
+PR 63 folded the heads' transposes around each `fused_attention` of the
+Transformer builder's Program into the op (`attention_layout_fuse_pass`,
+layout "bthd") and gave the one-tile kernel a form that reads the
+projections' [B, T, H d] in place, so `transformer` moved ON PURPOSE again,
+its digest re-taken from PR 63's tree by this file's `_digest`: the same 6
+Mosaic payloads (now `_inplace_fwd_call` / `_inplace_bwd_call`'s, none of
+`_short_*_call`'s left beside them) and not one [0, 2, 1, 3] transpose in
+the text, where the step held 48.  The other seventeen did NOT move: no
+other builder applies the pass, a `fused_attention` op without the
+attribute lowers through the branch it took, and `layers.fused_attention`
+writes no `layout` attribute at its default."""
 
 import base64
 import functools
@@ -329,7 +341,8 @@ def _lm(build, hp):
 def _transformer():
     """Transformer-base's program shape at tiny widths, 4 x 64 + 64 (the
     one-tile attention kernel, as the cells run it at 64 and 256 since
-    PR 62): `fc` with bias and activation, `fused_residual_ln`."""
+    PR 62, in place on the projections' [B, T, H d] since PR 63): `fc` with
+    bias and activation, `fused_residual_ln`."""
     main, startup, _, fetches = transformer.wmt_transformer_program(
         W, src_len=64, trg_len=64, use_bf16=True)
     return main, startup, fetches[0].name, _shapes(
@@ -368,12 +381,12 @@ PROGRAMS = {"gpt2": lambda: _lm(gpt2.gpt2_lm_program, G),
 # causal kernels walk several blocks of the full grid, `piece_diagonal_chunk`
 # among them: at PR 56; `nemotron_h`: added at PR 57; the six programs whose
 # AMP rewrite flips a `split`, `concat` or `expand`: at PR 58; `transformer`
-# again: at PR 62)
+# again: at PR 62, and at PR 63)
 BEFORE = {
     "nemotron_h": ("424a80a8933a0ec7ee89c4ece3eeca9006e18e92", 18),
     "qwen3_next": ("3d4b8d2d56c5035594075b0508a3614e286234ce", 27),
     "kimi_linear": ("3e0377967224298932fcd5be83fe7ce7f59a2b5b", 21),
-    "transformer": ("6b1f32555633e8e024cb7b0093967d49e7c0bb3e", 6),
+    "transformer": ("de649f8715dba25ff5b558116416dbc9d80910c5", 6),
     "resnet": ("84575b13d140437bb64cb8461436105337774a6d", 0),
     "ouro": ("0057fcbecadc1719a1de27cb3b94bb41ac569650", 3),
     "kanana2": ("58a5bd2363cca5fb23fea690fbcbdda362a0ee97", 9),

@@ -973,6 +973,139 @@ def test_short_attention_plan_is_a_function_of_the_shapes():
     assert pk._short_plan(7, 64, 64, 64, 2).pack == 1
 
 
+def _heads_first(x, h):
+    b, t, _ = x.shape
+    return x.reshape(b, t, h, -1).transpose(0, 2, 1, 3).reshape(b * h, t, -1)
+
+
+@pytest.mark.parametrize("mask", ["none", "kbias", "causal", "causal_kbias"])
+@pytest.mark.parametrize("t,h,d", [(64, 8, 64), (256, 8, 64), (64, 2, 64)])
+@pytest.mark.parametrize("dtype,tol", [("float32", 2e-5), ("bfloat16", 2e-2)])
+def test_short_attention_in_place_matches_dense(dtype, tol, t, h, d, mask):
+    """The one-tile form reading the projections' [B, T, H d] in place
+    (`heads=H`, interpreted) against `_dense_attention` on the transposed
+    operands: forward, and dq / dk / dv / dbias under `jax.grad`, in float32
+    and bfloat16, at the two Transformer-base cells' attention shapes (eight
+    heads of 64, two to a product) and at one pair of heads alone.  The
+    batch of 3 is odd: a grid step holds one sequence or all three, never
+    the largest count the VMEM budget allows.  The key bias [B, T] pads each
+    row's tail and row 1 whole: a fully padded row comes out finite and as
+    dense gives it, forward and backward."""
+    rng = np.random.RandomState(t + d + h)
+    b = 3
+    q, k, v = (jnp.asarray(rng.randn(b, t, h * d), dtype) for _ in range(3))
+    causal = mask.startswith("causal")
+    kbias = None
+    if mask.endswith("kbias"):
+        kb = np.where(np.arange(t)[None, :] < t - 5, 0.0, -1e9) + rng.randn(
+            b, t)
+        kb[1] = -1e9
+        kbias = jnp.asarray(kb, jnp.float32)
+    scale = 1.0 / np.sqrt(d)
+    w = jnp.cos(jnp.arange(b * t * h * d, dtype=jnp.float32)).reshape(
+        b, t, h * d)
+    argnums = (0, 1, 2, 3) if kbias is not None else (0, 1, 2)
+
+    def close(got, want):
+        got, want = (np.asarray(x, np.float32) for x in (got, want))
+        assert np.isfinite(got).all()
+        assert (np.max(np.abs(got - want))
+                <= tol * max(1.0, np.max(np.abs(want))))
+
+    def loss(fn):
+        return lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * w)
+
+    def dense(q, k, v, kb):
+        rows = None if kb is None else jnp.broadcast_to(
+            kb[:, None], (b, h, t)).reshape(b * h, t)
+        o = _dense_attention(*(_heads_first(x, h) for x in (q, k, v)),
+                             causal, scale, rows)
+        return o.reshape(b, h, t, d).transpose(0, 2, 1, 3).reshape(
+            b, t, h * d)
+
+    in_place = lambda q, k, v, kb: short_attention(  # noqa: E731
+        q, k, v, kb, causal, scale, h)
+    out = in_place(q, k, v, kbias)
+    assert out.shape == (b, t, h * d) and out.dtype == q.dtype
+    close(out, dense(q, k, v, kbias))
+    got = jax.grad(loss(in_place), argnums)(q, k, v, kbias)
+    want = jax.grad(loss(dense), argnums)(q, k, v, kbias)
+    for g, r, like in zip(got, want, (q, k, v, kbias)):
+        assert g.shape == like.shape and g.dtype == like.dtype
+        close(g, r)
+        if kbias is not None:  # the fully padded row against its own scale
+            close(g[1], r[1])
+
+
+@pytest.mark.parametrize("h,d,dv,group", [(3, 64, 64, 1), (2, 128, 128, 1),
+                                          (4, 32, 32, 4), (2, 64, 32, 2)])
+def test_short_attention_in_place_groups_heads_by_what_fills_the_lanes(
+        h, d, dv, group):
+    """Heads a product: as many as fill 128 lanes and divide H (an odd H of
+    64-wide heads goes one by one, a 64-lane slice each; 128-wide heads one
+    a tile; four heads of 32), V of another width than Q and K; each against
+    dense, forward and dq / dk / dv, causal under a key bias."""
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    b, t = 2, 64
+    assert pk._inplace_plan(b, t, h, d, dv, 4).group == group
+    rng = np.random.RandomState(h + d)
+    q, k = (jnp.asarray(rng.randn(b, t, h * d), "float32") for _ in range(2))
+    v = jnp.asarray(rng.randn(b, t, h * dv), "float32")
+    kb = jnp.asarray(np.where(np.arange(t)[None, :] < t - 9, 0.0, -1e9)
+                     + rng.randn(b, t), jnp.float32)
+    scale = d ** -0.5
+    w = jnp.cos(jnp.arange(b * t * h * dv, dtype=jnp.float32)).reshape(
+        b, t, h * dv)
+
+    def dense(q, k, v):
+        rows = jnp.broadcast_to(kb[:, None], (b, h, t)).reshape(b * h, t)
+        o = _dense_attention(*(_heads_first(x, h) for x in (q, k, v)), True,
+                             scale, rows)
+        return o.reshape(b, h, t, dv).transpose(0, 2, 1, 3).reshape(
+            b, t, h * dv)
+
+    in_place = lambda q, k, v: short_attention(  # noqa: E731
+        q, k, v, kb, True, scale, h)
+    got = jax.value_and_grad(lambda *a: jnp.sum(in_place(*a) * w),
+                             (0, 1, 2))(q, k, v)
+    want = jax.value_and_grad(lambda *a: jnp.sum(dense(*a) * w),
+                              (0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
+    for g, r in zip(got[1], want[1]):
+        np.testing.assert_allclose(g, r, atol=2e-5 * max(
+            1.0, float(jnp.max(jnp.abs(r)))))
+
+
+def test_short_attention_in_place_plan_is_a_function_of_the_shapes():
+    """Sequences a grid step, heads a product and sequences a loop body from
+    (B, T, H, d, dv, dtype) alone: the two cells' plans, float32's, 128-wide
+    heads, a batch the largest count does not divide (the budget allows 21
+    here: 512 takes 16, 510 = 2 x 3 x 5 x 17 takes 17, a prime 509 one) and
+    more groups of heads than a body's 32 chains."""
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    assert pk._inplace_plan(512, 64, 8, 64, 64, 2) == pk._InPlacePlan(
+        16, 2, 8)
+    assert pk._inplace_plan(128, 256, 8, 64, 64, 2) == pk._InPlacePlan(
+        4, 2, 4)
+    assert pk._inplace_plan(512, 64, 8, 64, 64, 4) == pk._InPlacePlan(8, 2, 8)
+    assert pk._inplace_plan(512, 64, 4, 128, 128, 2) == pk._InPlacePlan(
+        16, 1, 8)
+    assert pk._inplace_plan(510, 64, 8, 64, 64, 2) == pk._InPlacePlan(
+        17, 2, 1)
+    assert pk._inplace_plan(7, 64, 8, 64, 64, 2) == pk._InPlacePlan(7, 2, 7)
+    assert pk._inplace_plan(2, 64, 72, 64, 64, 2) == pk._InPlacePlan(2, 2, 1)
+    for args in ((512, 64, 8, 64, 64, 2), (96, 256, 6, 64, 64, 4),
+                 (509, 64, 3, 64, 64, 2)):
+        plan = pk._inplace_plan(*args)
+        assert plan == pk._inplace_plan(*args)
+        assert args[0] % plan.seqs == 0 and args[2] % plan.group == 0
+        assert plan.seqs % plan.unroll == 0
+        assert plan.unroll * args[2] // plan.group <= 32
+    assert pk._inplace_plan(509, 64, 3, 64, 64, 2) == pk._InPlacePlan(1, 1, 1)
+
+
 def test_every_kernel_lowers_for_tpu_without_a_chip(monkeypatch):
     """Cross-lower each chip_smoke kernel case (the repo's model shapes,
     forward and backward) for the TPU platform on this host.  That runs

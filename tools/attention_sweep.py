@@ -25,6 +25,20 @@ folds them into the copies the projections' [B, T, H d] need anyway.
 
     python3 tools/attention_sweep.py --short [--out chiprun_out/short_sweep.json]
 
+With --short --in-place the operands are stored as the projections write
+them, [B, T, H d] (IN_PLACE_SHAPES: the two Transformer-base cells', eight
+heads of 64), and every lowering is timed from there and back to there:
+dense and PR 62's one-tile kernel behind the transposes to [B H, T, d] and
+back (the copies XLA needs to reach their layouts are in their time), and
+the in-place form (PR 63, short_attention(..., heads=H)) by "S x G": S
+sequences a grid step, G heads a product (1: a static 64-lane slice a head;
+2: two heads' scores side by side, the queries laid out block-diagonally, no
+lane moved), and at the program's own S x G with 2 to 16 sequences held
+as one loop body ("unroll").  S, G and the unroll are held in the tool
+(pallas_kernels._inplace_plan patched for that row).
+
+    python3 tools/attention_sweep.py --short --in-place [--out chiprun_out/in_place_sweep.json]
+
 With --window W it sweeps the sliding-window kernel instead (PR 41), at
 trinity_mini_train's shape unless --bh / --t / --d say another: square
 blocks of 1024 / 512 / 256, each on the band grid the kernel takes and on
@@ -99,6 +113,12 @@ SHORT_SHAPES = [
     ("T384_d128", 336, 384, 128, (True,)),
 ]
 SHORT_HEADS = (1, 2, 4, 8, 16, 32, 64)
+# (what, B, T, heads, head dim, causal or not) of --short --in-place
+IN_PLACE_SHAPES = [
+    ("tfm_base_train_s64", 512, 64, 8, 64, (True, False)),
+    ("tfm_base_train", 128, 256, 8, 64, (True, False)),
+]
+IN_PLACE_SEQS = (2, 4, 8, 16, 32)
 # (cells, B*H, T, width of Q and K, width of V, window) of --tile-classes:
 # the nine flash cells' attention cores, in blocks of nn_ops._flash_block(T)
 TILE_SHAPES = [
@@ -138,6 +158,10 @@ def main():
     ap.add_argument("--short", action="store_true",
                     help="sweep the one-tile kernel of the short sequences "
                     "over heads a grid step and sequences a tile")
+    ap.add_argument("--in-place", action="store_true",
+                    help="with --short: from operands stored [B, T, H d], "
+                    "the in-place form against dense and PR 62's form "
+                    "behind their transposes")
     ap.add_argument("--repeats", type=int, default=3)
     ap.add_argument("--shapes", default="",
                     help="with --tile-classes or --dead-fetch: only the "
@@ -171,6 +195,24 @@ def main():
             out = f(*operands)
         jax.block_until_ready(out)
         return (time.perf_counter() - t) / args.iters * 1e3
+
+    def fwd_fwdbwd(fn, operands):
+        """[ms forward, ms forward + backward], the least of --repeats; a
+        rehearsal makes one call and times nothing."""
+        if args.rehearse:
+            jax.block_until_ready(jax.grad(lambda *a: jnp.sum(
+                fn(*a).astype(jnp.float32)))(*operands))
+            return [None, None]
+        return [round(min(timed(fn, operands, backward=b)
+                          for _ in range(args.repeats)), 4)
+                for b in (False, True)]
+
+    def attempt(fn, operands, what):
+        try:
+            return fwd_fwdbwd(fn, operands)
+        except Exception as e:  # e.g. tiles over the VMEM limit
+            print("%s refused: %s" % (what, str(e)[:300]), flush=True)
+            return None
 
     def window_sweep():
         """One JSON line a (block, grid): ms forward and forward + backward,
@@ -444,32 +486,15 @@ def main():
                 jnp.float32) * jnp.ones((bh, 1), jnp.float32))
             scale = d ** -0.5
             for causal in causals:
-                def both(fn):
-                    if args.rehearse:  # the plumbing: one call, no time
-                        jax.block_until_ready(jax.grad(lambda *a: jnp.sum(
-                            fn(*a).astype(jnp.float32)))(q, k, v, kb))
-                        return [None, None]
-                    return [round(min(timed(fn, (q, k, v, kb), backward=b)
-                                      for _ in range(args.repeats)), 4)
-                            for b in (False, True)]
-
-                def attempt(fn, what):
-                    try:
-                        return both(fn)
-                    except Exception as e:  # e.g. tiles over the VMEM limit
-                        print("%s %s refused: %s" % (name, what,
-                                                     str(e)[:300]), flush=True)
-                        return None
-
                 row = {"shape": name, "bh": bh, "t": t, "d": d,
                        "causal": causal, "kbias": True,
-                       "dense_fwd_fwdbwd_ms": both(
+                       "dense_fwd_fwdbwd_ms": fwd_fwdbwd(
                            lambda q, k, v, kb: pk._dense_attention(
-                               q, k, v, causal, scale, kb)),
+                               q, k, v, causal, scale, kb), (q, k, v, kb)),
                        "blockwise_fwd_fwdbwd_ms": attempt(
                            lambda q, k, v, kb: pk.flash_attention(
                                q, k, v, kb, causal, scale, t, t),
-                           "blockwise"),
+                           (q, k, v, kb), name + " blockwise"),
                        "one_tile_fwd_fwdbwd_ms": {}}
                 for p in (1, 2, 4):
                     if p > 1 and p * t > 256:
@@ -484,7 +509,8 @@ def main():
                         pk._short_plan = (
                             lambda *a, g=g, p=p: pk._ShortPlan(p, g))
                         ms = attempt(lambda q, k, v, kb: pk.short_attention(
-                            q, k, v, kb, causal, scale), "%dx%d" % (g, p))
+                            q, k, v, kb, causal, scale), (q, k, v, kb),
+                            "%s %dx%d" % (name, g, p))
                         row["one_tile_fwd_fwdbwd_ms"]["%dx%d" % (g, p)] = ms
                 pk._short_plan = plan
                 ok = {c: ms[1] for c, ms in
@@ -500,6 +526,76 @@ def main():
                 jax.clear_caches()
         return rows
 
+    def in_place_sweep():
+        """One JSON line a (shape, causal): ms forward and forward +
+        backward, from q, k, v stored [B, T, H d] to o stored alike, of
+        dense and of PR 62's one-tile kernel behind their transposes, and of
+        the in-place form by "S x G"; the best of those."""
+        plan = pk._inplace_plan
+        words = [w for w in args.shapes.split(",") if w]
+        rows = []
+        for name, b, t, h, d, causals in IN_PLACE_SHAPES:
+            if words and not any(word in name for word in words):
+                continue
+            if args.rehearse:
+                b = 4
+            keys = jax.random.split(jax.random.PRNGKey(0), 3)
+            q, k, v = (jax.random.normal(
+                kk, (b, t, h * d), jnp.float32).astype(jnp.bfloat16)
+                for kk in keys)
+            kb = (jnp.where(jnp.arange(t)[None, :] < t - 7, 0.0, -1e9).astype(
+                jnp.float32) * jnp.ones((b, 1), jnp.float32))
+            scale = d ** -0.5
+
+            def heads_first(fn):
+                """fn over [B H, T, d] and a [B H, T] bias, from and to
+                [B, T, H d]: what a "bthd" op does where the in-place form
+                does not engage."""
+                def run(q, k, v, kb):
+                    flat = [x.reshape(b, t, h, d).transpose(
+                        0, 2, 1, 3).reshape(b * h, t, d) for x in (q, k, v)]
+                    rows_ = jnp.broadcast_to(
+                        kb[:, None, :], (b, h, t)).reshape(b * h, t)
+                    return fn(*flat, rows_).reshape(b, h, t, d).transpose(
+                        0, 2, 1, 3).reshape(b, t, h * d)
+                return run
+
+            for causal in causals:
+                took = plan(b, t, h, d, d, 2)
+                row = {"shape": name, "b": b, "t": t, "h": h, "d": d,
+                       "causal": causal, "kbias": True, "stored": "bthd",
+                       "dense_fwd_fwdbwd_ms": fwd_fwdbwd(heads_first(
+                           lambda q, k, v, kb: pk._dense_attention(
+                               q, k, v, causal, scale, kb)), (q, k, v, kb)),
+                       "one_tile_with_copies_fwd_fwdbwd_ms": attempt(
+                           heads_first(lambda q, k, v, kb: pk.short_attention(
+                               q, k, v, kb, causal, scale)), (q, k, v, kb),
+                           name + " one_tile"),
+                       "in_place_fwd_fwdbwd_ms": {}}
+                for g, s, u in [(g, s, 1) for g in (1, 2)
+                                for s in IN_PLACE_SEQS] + [
+                                    (took.group, took.seqs, u)
+                                    for u in (2, 4, 8, 16)]:
+                    if b % s or s % u or (args.rehearse and s > 4):
+                        continue
+                    pk._inplace_plan = (
+                        lambda *a, s=s, g=g, u=u: pk._InPlacePlan(s, g, u))
+                    what = "%dx%d" % (s, g) + (" unroll %d" % u) * (u > 1)
+                    row["in_place_fwd_fwdbwd_ms"][what] = attempt(
+                        lambda q, k, v, kb: pk.short_attention(
+                            q, k, v, kb, causal, scale, h), (q, k, v, kb),
+                        "%s %s" % (name, what))
+                pk._inplace_plan = plan
+                ok = {c: ms[1] for c, ms in
+                      row["in_place_fwd_fwdbwd_ms"].items()
+                      if ms and ms[1] is not None}
+                row["best"] = min(ok, key=ok.get) if ok else None
+                row["the_program_takes"] = "%dx%d unroll %d" % took
+                rows.append(row)
+                print(json.dumps(row), flush=True)
+                jax.clear_caches()
+        return rows
+
     def save(rows, name):
         """Writes the sweep's rows to --out, or to its own file
         chiprun_out/<name>.json where --out was not given (a rehearsal's
@@ -512,6 +608,8 @@ def main():
             json.dump({"device": dev.device_kind, "iters": args.iters,
                        "rows": rows}, f, indent=1)
 
+    if args.short and args.in_place:
+        return save(in_place_sweep(), "in_place_sweep")
     if args.short:
         return save(short_sweep(), "short_sweep")
     if args.dead_fetch:
