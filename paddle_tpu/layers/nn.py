@@ -6,6 +6,8 @@ same graph-building contract as the reference (nn.py:174 fc, :283 embedding,
 MXU-friendly XLA ops.
 """
 
+import math
+
 import numpy as np
 
 from .. import framework, unique_name
@@ -1751,7 +1753,14 @@ def slot_cache_write(cache, new, pos, width, name=None):
     return out
 
 
-def rotary_embed(x, pos=None, base=10000.0, interleaved=False, name=None):
+# the keys of a published `rope_parameters` group that `rotary_embed`'s
+# `scaling` reads; any other key, or rope_type, is refused, not guessed
+_YARN_KEYS = {"rope_type", "factor", "original_max_position_embeddings",
+              "beta_fast", "beta_slow", "attention_factor"}
+
+
+def rotary_embed(x, pos=None, base=10000.0, interleaved=False, scaling=None,
+                 name=None):
     """Rotary position embedding over per-head projections [B, H, T, Dh]
     (rotate-half).  pos: optional int positions [T] — the KV-cached
     decode path passes the current position so cached keys are stored
@@ -1764,7 +1773,16 @@ def rotary_embed(x, pos=None, base=10000.0, interleaved=False, name=None):
     undone by a product with a constant Dh x Dh permutation matrix, bit
     for bit what strided slices give: a float32 input is multiplied at
     precision HIGHEST (the default would round it to bfloat16 on a TPU's
-    MXU), and a NaN or infinity in x reaches its whole row of Dh."""
+    MXU), and a NaN or infinity in x reaches its whole row of Dh.
+
+    scaling: a published `rope_parameters` group, {"rope_type": "yarn",
+    "factor", "original_max_position_embeddings", "beta_fast", "beta_slow",
+    "attention_factor"} (the last 0.1 ln(factor) + 1 where left out):
+    YaRN's static inverse frequencies over Dh (`ops/nn_ops.
+    _rotary_inv_freq`) and a factor on cos and sin.  The op then carries
+    `yarn_*` and `attention_factor` attributes; without `scaling` it is
+    the op every program has.  No `pos` with it: the cached decode paths
+    have no scaled frequencies yet."""
     helper = LayerHelper("rotary_embed", **locals())
     out = helper.create_variable_for_type_inference(x.dtype)
     inputs = {"X": [x]}
@@ -1773,6 +1791,29 @@ def rotary_embed(x, pos=None, base=10000.0, interleaved=False, name=None):
     attrs = {"base": base}
     if interleaved:  # the default leaves the op as every program has it
         attrs["interleaved"] = True
+    if scaling is not None:
+        if scaling.get("rope_type") != "yarn":
+            raise NotImplementedError(
+                "rotary_embed: rope_type %r; scaled frequencies are yarn's"
+                % (scaling.get("rope_type"),))
+        if set(scaling) - _YARN_KEYS:
+            raise NotImplementedError(
+                "rotary_embed: yarn scaling has no %s"
+                % sorted(set(scaling) - _YARN_KEYS))
+        if pos is not None:
+            raise NotImplementedError(
+                "rotary_embed: scaled frequencies are the training path's; "
+                "the cached decode paths (pos) have none yet")
+        factor = float(scaling["factor"])
+        amplitude = scaling.get("attention_factor")
+        attrs.update(
+            yarn_factor=factor,
+            yarn_original_max_position=float(
+                scaling["original_max_position_embeddings"]),
+            yarn_beta_fast=float(scaling.get("beta_fast", 32)),
+            yarn_beta_slow=float(scaling.get("beta_slow", 1)),
+            attention_factor=float(0.1 * math.log(factor) + 1.0
+                                   if amplitude is None else amplitude))
     helper.append_op("rotary_embed", inputs=inputs,
                      outputs={"Out": [out]}, attrs=attrs)
     return out

@@ -137,8 +137,9 @@ def deepseek_v3_check(hp):
             % (hp.scoring_func, hp.topk_method))
     if hp.rope_scaling is not None:
         raise NotImplementedError(
-            "rope_scaling %r: rotary_embed has no scaled frequencies and "
-            "the softmax scale no mscale" % (hp.rope_scaling,))
+            "rope_scaling %r: this block reads none (rotary_embed's scaled "
+            "frequencies are YaRN's, built from Laguna's `rope_parameters`; "
+            "the softmax scale has no mscale)" % (hp.rope_scaling,))
     if hp.moe_layer_freq != 1:
         raise NotImplementedError(
             "moe_layer_freq %r: every layer after the leading dense ones "

@@ -139,7 +139,8 @@ def _check(hp):
             "from the hidden state" % (hp.q_lora_rank,))
     if hp.rope_scaling is not None:
         raise NotImplementedError(
-            "rope_scaling %r: rotary_embed has no scaled frequencies"
+            "rope_scaling %r: Kimi-Linear publishes none and this builder reads "
+            "none (rotary_embed's scaled frequencies are YaRN's)"
             % (hp.rope_scaling,))
     if hp.moe_layer_freq != 1:
         raise NotImplementedError(

@@ -118,7 +118,8 @@ def _check(hp):
             "%d" % (hp.linear_num_key_heads, hp.linear_num_value_heads))
     if hp.rope_scaling is not None:
         raise NotImplementedError(
-            "rope_scaling %r: rotary_embed has no scaled frequencies"
+            "rope_scaling %r: Qwen3-Next publishes none and this builder reads "
+            "none (rotary_embed's scaled frequencies are YaRN's)"
             % (hp.rope_scaling,))
     if hp.use_sliding_window:
         raise NotImplementedError("the published attention layers are full")

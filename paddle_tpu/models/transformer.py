@@ -117,6 +117,7 @@ def multi_head_attention(
     n_kv_head=None, rotary=False, qk_norm=False, qk_norm_eps=1e-5,
     rotary_base=10000.0, param_attr=None, head_dim=None, window=0,
     out_gate=False, scopes=False, rotary_dim=None, norm_unit_offset=False,
+    rotary_scaling=None,
 ):
     """All heads in one qkv projection + batched matmuls (MXU-shaped).
     attn_bias: [B, 1 or H, Tq, Tk] additive mask (−1e9 at masked slots).
@@ -157,11 +158,17 @@ def multi_head_attention(
     (`partial_rotary_factor`: Qwen3-Next turns 64 of 256, rotate-half
     pairing (i, i + rotary_dim / 2) inside them, the frequencies those of a
     head `rotary_dim` wide) and leaves the others as projected: a split,
-    `rotary_embed` on the first part and a concatenation, under the name
-    scope `rope` where `scopes` is set; without `rotary_dim`, or at
-    head_dim, the layer is built as before, op for op.  Scores of another
+    `rotary_embed` on the first part and a concatenation; without
+    `rotary_dim`, or at head_dim, one `rotary_embed` over the head.  Either
+    is built under the name scope `rope` where `scopes` is set.  Scores of
+    another
     width than the values, and a decoupled rotary part of which the key
     has one for all heads, are `latent_attention`'s.
+
+    rotary_scaling: a published `rope_parameters` group with `rope_type`
+    "yarn" (`layers.rotary_embed`'s `scaling`: scaled inverse frequencies
+    over the `rotary_dim` lanes and a factor on cos and sin), on the
+    training path alone; the cache paths refuse it.
 
     norm_unit_offset=True: the per-head QK-norm's gain is 1 + w with w
     initialised to zero (`layers.rms_norm(unit_offset=True)`).
@@ -174,10 +181,15 @@ def multi_head_attention(
 
     out_gate=True multiplies the heads' output, [B, T, n_head * head_dim],
     by sigmoid(queries W_g) (its own projection, "mha_gate.w", of that
-    width) before the output projection.
+    width) before the output projection.  out_gate="head": one gate a head
+    and token, sigmoid(queries W_g) with W_g [d, n_head], broadcast over
+    head_dim; the projection is built under `attn_gate` with the sigmoid
+    and the product (Laguna's `gating`).  Under the AMP pass either gate's
+    sigmoid and product are float32.
 
     scopes=True builds the `fused_attention` op under the name scope
-    `core` and the gate's sigmoid and product under `attn_gate`, inside
+    `core`, the rotary turn under `rope` (over the whole head or a part of
+    it) and the gate's sigmoid and product under `attn_gate`, inside
     whatever scope the caller builds the layer under (trinity's
     `attn_window` / `attn_full`), so that the lowered step says which
     time is the core's; the default builds under none, as every program
@@ -219,8 +231,15 @@ def multi_head_attention(
                   param_attr=pa("mha_k.w"))
     v = layers.fc(values, size=n_kv * dh, num_flatten_dims=2, bias_attr=False,
                   param_attr=pa("mha_v.w"))
+    if out_gate not in (False, True, "head"):
+        raise ValueError("out_gate is False, True (a gate a lane) or 'head', "
+                         "got %r" % (out_gate,))
     gate = None
-    if out_gate:
+    if out_gate == "head":
+        with scoped("attn_gate"):
+            gate = layers.fc(queries, size=n_head, num_flatten_dims=2,
+                             bias_attr=False, param_attr=pa("mha_gate.w"))
+    elif out_gate:
         gate = layers.fc(queries, size=n_head * dh, num_flatten_dims=2,
                          bias_attr=False, param_attr=pa("mha_gate.w"))
     if qk_norm not in (False, True, "head"):
@@ -262,16 +281,21 @@ def multi_head_attention(
     if rotary_dim < dh and cache is not None:
         raise ValueError("rotary on a part of the head is the training "
                          "path's: the cache paths rotate the whole head")
+    if rotary_scaling is not None and cache is not None:
+        raise ValueError("rotary_scaling is the training path's: the cached "
+                         "decode paths have no scaled frequencies yet")
 
     def turn(x, rpos):
-        if rotary_dim == dh:
-            return layers.rotary_embed(x, pos=rpos, base=rotary_base)
+        def embed(y):
+            return layers.rotary_embed(y, pos=rpos, base=rotary_base,
+                                       scaling=rotary_scaling)
+
         with scoped("rope"):
+            if rotary_dim == dh:
+                return embed(x)
             turned, kept = layers.split(x, [rotary_dim, dh - rotary_dim],
                                         dim=-1)
-            return layers.concat(
-                [layers.rotary_embed(turned, pos=rpos, base=rotary_base),
-                 kept], axis=-1)
+            return layers.concat([embed(turned), kept], axis=-1)
 
     q = split_heads(q, n_head, pa("mha_q_norm.w") if per_head else None)
     k = split_heads(k, n_kv, pa("mha_k_norm.w") if per_head else None)
@@ -427,8 +451,16 @@ def multi_head_attention(
         ctx = layers.matmul(weights, v)  # [B, H, Tq, Dh]
     ctx = layers.transpose(ctx, [0, 2, 1, 3])
     b, t = ctx.shape[0], ctx.shape[1]
-    ctx = layers.reshape(ctx, [b, t, n_head * dh])
-    if gate is not None:
+    joined = [b, t, n_head * dh]
+    if gate is None:
+        ctx = layers.reshape(ctx, joined)
+    elif out_gate == "head":  # [B, T, H, Dh] x [B, T, H, 1], then joined
+        with scoped("attn_gate"):
+            ctx = layers.elementwise_mul(
+                ctx, layers.unsqueeze(layers.sigmoid(gate), [3]))
+        ctx = layers.reshape(ctx, joined)
+    else:  # a gate a lane meets the heads joined
+        ctx = layers.reshape(ctx, joined)
         with scoped("attn_gate"):
             ctx = layers.elementwise_mul(ctx, layers.sigmoid(gate))
     return layers.fc(ctx, size=d_model, num_flatten_dims=2, bias_attr=False,

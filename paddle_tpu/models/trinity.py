@@ -109,7 +109,9 @@ def _check(hp):
             "selection bias" % (hp.score_func,))
     if hp.rope_scaling is not None:
         raise NotImplementedError(
-            "rope_scaling %r: rotary_embed has no scaled frequencies"
+            "rope_scaling %r: Trinity-Mini publishes none and this builder "
+            "reads none (rotary_embed's scaled frequencies are YaRN's, "
+            "which models/laguna.py builds from `rope_parameters`)"
             % (hp.rope_scaling,))
     if hp.tie_word_embeddings:
         raise NotImplementedError("the published head is untied")

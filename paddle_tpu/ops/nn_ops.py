@@ -10,6 +10,7 @@ RNNs.
 """
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -1509,6 +1510,41 @@ def _deinterleave(x):
                       precision=jax.lax.Precision.HIGHEST)
 
 
+def yarn_correction_range(dim, base, original_max_position, beta_fast,
+                          beta_slow):
+    """(low, high): the rotary pairs of a head `dim` wide between which YaRN
+    ramps from the published frequencies to the interpolated ones: the pair
+    that turns `beta` times over `original_max_position` positions is
+    dim ln(original / (beta 2 pi)) / (2 ln base); low is the floor of
+    beta_fast's, high the ceiling of beta_slow's, both clipped to
+    [0, dim - 1] (the transformers convention the config keys are named
+    after)."""
+    def pair(turns):
+        return (dim * math.log(original_max_position / (turns * 2 * math.pi))
+                / (2 * math.log(base)))
+
+    return (max(math.floor(pair(beta_fast)), 0),
+            min(math.ceil(pair(beta_slow)), dim - 1))
+
+
+def _rotary_inv_freq(half, base, attrs):
+    """[half] float32 inverse frequencies of a head 2 * half wide: base^(-i
+    / half), or, where the op carries `yarn_factor`, YaRN's static blend of
+    them with the same divided by the factor: pair i keeps its frequency
+    below `low`, takes the interpolated one above `high`, and between them
+    r_i = (i - low) / (high - low) of the way."""
+    pos_freq = base ** (jnp.arange(half, dtype=jnp.float32) / half)
+    factor = attrs.get("yarn_factor")
+    if not factor:
+        return 1.0 / pos_freq
+    low, high = yarn_correction_range(
+        2 * half, base, float(attrs["yarn_original_max_position"]),
+        float(attrs["yarn_beta_fast"]), float(attrs["yarn_beta_slow"]))
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / max(high - low, 0.001), 0.0, 1.0)
+    return (1.0 - ramp) / pos_freq + ramp / (float(factor) * pos_freq)
+
+
 @register("rotary_embed", no_grad_inputs=("Pos",))
 def _rotary_embed(ctx, ins, attrs):
     """Rotary position embedding (RoPE, rotate-half convention) applied
@@ -1520,10 +1556,23 @@ def _rotary_embed(ctx, ins, attrs):
     the result is left in the rotate-half order.  The de-interleave is a
     product with a constant Dh x Dh permutation matrix (`_deinterleave`),
     bit for bit what two lane-strided slices give, on the MXU.
+    Attributes `yarn_factor`, `yarn_original_max_position`,
+    `yarn_beta_fast`, `yarn_beta_slow`: YaRN's scaled inverse frequencies
+    (`_rotary_inv_freq`), static, computed in float32 at trace time;
+    `attention_factor` multiplies cos and sin (so every rotated lane of q
+    and of k).  Positions arange(T) alone: the cached decode path's `Pos`
+    is refused with them.
     Beyond-reference (the reference era used learned/sinusoid absolute
     positions); standard in modern decoder LMs."""
     x = ins["X"][0]
     base = float(attrs.get("base", 10000.0))
+    amplitude = float(attrs.get("attention_factor", 1.0))
+    scaled = bool(attrs.get("yarn_factor")) or amplitude != 1.0
+    if scaled and ins.get("Pos"):
+        raise NotImplementedError(
+            "rotary_embed: scaled frequencies (yarn_factor / "
+            "attention_factor) are the training path's; the cached decode "
+            "path, which feeds Pos, has none yet")
     t = x.shape[2]
     if x.shape[-1] % 2:
         raise ValueError(
@@ -1532,7 +1581,10 @@ def _rotary_embed(ctx, ins, attrs):
     half = x.shape[-1] // 2
     if attrs.get("interleaved", False):
         x = _deinterleave(x)  # the result stays in that order
-    freq = base ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    if scaled:
+        freq = _rotary_inv_freq(half, base, attrs)
+    else:  # as every program before had it, to the instruction
+        freq = base ** (-jnp.arange(half, dtype=jnp.float32) / half)
     if ins.get("Pos") and ins["Pos"][0].ndim == 2:
         # PER-ROW positions [B, T] (ragged serving step: each pool slot
         # rotates by its own request's positions)
@@ -1548,8 +1600,12 @@ def _rotary_embed(ctx, ins, attrs):
     else:
         pos = jnp.arange(t, dtype=jnp.float32)
     ang = pos[:, None] * freq[None, :]  # [T, half]
-    sin = jnp.sin(ang)[None, None].astype(x.dtype)
-    cos = jnp.cos(ang)[None, None].astype(x.dtype)
+
+    def table(fn):  # [1, 1, T, half], times the attention factor
+        t = fn(ang) if amplitude == 1.0 else fn(ang) * amplitude
+        return t[None, None].astype(x.dtype)
+
+    sin, cos = table(jnp.sin), table(jnp.cos)
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
     return {"Out": [out]}

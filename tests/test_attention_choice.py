@@ -894,7 +894,7 @@ def test_window_grid_live_share_is_in_the_benchmark_by_name(monkeypatch):
     by position), its layer_metrics file names a reader that imports, and
     the reader answers None on a program that records no band grid (the
     parent commit's, or one whose windowed ops never took the kernel),
-    87.5 from Trinity-Mini's record."""
+    87.5 from Trinity-Mini's record and 91.7 from Laguna-XS.2's."""
     import importlib.util
     import json
 
@@ -903,7 +903,8 @@ def test_window_grid_live_share_is_in_the_benchmark_by_name(monkeypatch):
     entry = [m for m in spec["per_layer"]
              if m["name"] == "window_grid_live_share"]
     assert len(entry) == 1
-    assert entry[0]["workloads"] == ["trinity_mini_train"]
+    assert entry[0]["workloads"] == ["trinity_mini_train",
+                                     "laguna_xs2_33b_a3b_train"]
     assert (entry[0]["unit"], entry[0]["better"], entry[0]["moves"]) == (
         "%", "higher", "train_mfu")
     with open(os.path.join(ROOT, "benchmark", "layer_metrics",
@@ -923,6 +924,12 @@ def test_window_grid_live_share_is_in_the_benchmark_by_name(monkeypatch):
     monkeypatch.undo()
     kt.note_band_grid(8192, 2048, 1024, 1024, 24, 21)
     assert reader.read(ctx) == 87.5
+    kt.reset_attribution()
+    # Laguna-XS.2's (PR 65): a 512 window is half a 1024-block, a walk is two
+    # blocks wide and only the sequence's first block repeats one
+    assert pk.band_grid_steps(6144, 1024, 1024, 512) == (12, 11)
+    kt.note_band_grid(6144, 512, 1024, 1024, 12, 11)
+    assert reader.read(ctx) == pytest.approx(100.0 * 11 / 12)
     kt.reset_attribution()
 
 

@@ -309,7 +309,10 @@ def test_each_kind_of_layer_builds_its_own_mixer():
 
 def test_rotary_on_the_whole_head_builds_what_it_built():
     """`rotary_dim` None, or the head's width, adds no op: Trinity's and
-    LFM2's layers are built as before, op for op, under no `rope` scope."""
+    LFM2's layers are built as before, op for op; where `scopes` is set the
+    one `rotary_embed` of q and of k stands under `rope` (since PR 65: the
+    name alone), as a turn of a part of the head does with its split and
+    its concatenation."""
     from paddle_tpu.models import transformer as tfm
 
     def ops(**kwargs):
@@ -325,7 +328,7 @@ def test_rotary_on_the_whole_head_builds_what_it_built():
 
     plain = ops()
     assert ops(rotary_dim=32) == plain
-    assert not [s for _, s in plain if s and "rope" in s]
+    assert [t for t, s in plain if s == "rope"] == ["rotary_embed"] * 2
     assert "scale" not in [t for t, _ in plain]  # the gains are plain w
     part = ops(rotary_dim=8, norm_unit_offset=True)
     assert [t for t, s in part if s == "rope"] == [

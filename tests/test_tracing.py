@@ -811,7 +811,9 @@ def test_trinitys_attention_scopes_reach_the_lowered_steps_op_names():
         assert ("forward", "sigmoid") in nested[kind + ".attn_gate"]
         assert ("backward", "elementwise_mul_grad") in nested[
             kind + ".attn_gate"]
-    assert ("forward", "rotary_embed") in nested["attn_window"]
+    assert nested["attn_window.rope"] == {
+        ("forward", "rotary_embed"), ("backward", "rotary_embed_grad")}
+    assert "attn_full.rope" not in nested
     assert not [t for _, t in nested["attn_full"] if "rotary" in t]
 
 

@@ -236,7 +236,8 @@ def test_each_kind_of_layer_builds_its_own_attention():
     assert full.attrs["scale"] == 32 ** -0.5
     rotary = [op for op in block.ops if op.type == "rotary_embed"]
     assert len(rotary) == 2 * 4  # q and k of the four window layers
-    assert all(op.attrs["op_namescope"] == "attn_window" for op in rotary)
+    assert all(op.attrs["op_namescope"] == "attn_window/rope"
+               for op in rotary)
     assert {op.type for op in by_scope["attn_full/attn_gate"]} == {
         "sigmoid", "elementwise_mul", "sigmoid_grad", "elementwise_mul_grad"}
     assert "fused_swiglu" in {op.type for op in by_scope["shared_expert"]}
