@@ -1071,7 +1071,7 @@ def _fused_attention(ctx, ins, attrs):
         # width of at most 128 (latent attention's 192 over 128, 256)
         if dv != d or d > 128:
             note_kernel("attention_qk%d_v%d" % (d, dv))
-        mc, blk = mesh_ctx(), _flash_block(t)
+        mc, blk = mesh_ctx(), _flash_block(t, window)
         if window:  # the grid a head's forward walks against its band
             note_band_grid(t, window, blk, blk,
                            *band_grid_steps(t, blk, blk, window))
@@ -1099,15 +1099,37 @@ def _fused_attention(ctx, ins, attrs):
 # bf16, kernel against dense: T = 256 3.64 against 2.85 ms (not this
 # kernel's: its one-tile form takes the lengths under 512, _short_engages),
 # T = 512 0.77 against 1.11, T = 1024 0.95 against 2.66, T = 4096 4.0
-# against 29.5; at every length the largest square block was fastest.
+# against 29.5; at every length the largest square block was fastest: for
+# the triangle, and for a band of two blocks or more (PR 41's sweep).
+# A NARROWER band takes the block its window says (_flash_block), from
+# tools/attention_sweep.py --band-blocks on a v5e (CHANGES.md, PR 66):
+# Laguna-XS.2's window core, 64 heads x 6144 x 128, ms forward + backward
+# in blocks of 1024 / 512 / 256 / 128 by window:
+#   window  128:  8.98 /  6.73 /  7.45 /  8.53
+#   window  256:  8.97 /  6.73 /  7.60 / 12.29
+#   window  512:  8.98 /  6.58 / 10.70 / 19.47
+#   window 1024:  6.96 /  9.14 / 16.54 / 33.18
+#   window 2048: 10.06 / 13.60 / 26.86 / 58.09
+# A block the window is a multiple of puts the band's edge on a block
+# boundary (no tile cut by the diagonal AND the edge, both cuts in strips),
+# and under 512 a block costs more a step than its masked pairs save: 512
+# is ahead at windows 128 and 256 with every tile whole and masked.
 _FLASH_MIN_T = 512
 _FLASH_BLOCKS = (1024, 512, 256, 128)
+_FLASH_BAND_MIN_BLOCK = 512
 
 
-def _flash_block(t):
+def _flash_block(t, window=0):
     """The largest block that divides t (both sides of a tile take it);
-    t itself, one full-length block, for a t no block divides."""
-    return next((b for b in _FLASH_BLOCKS if t % b == 0), t)
+    t itself, one full-length block, for a t no block divides.  Under a
+    `window` narrower than t that a block divides, the largest block that
+    divides the window too or is no larger than _FLASH_BAND_MIN_BLOCK: the
+    band's edge on a block boundary, in blocks not under that floor."""
+    fits = [b for b in _FLASH_BLOCKS if t % b == 0]
+    if 0 < window < t and any(window % b == 0 for b in fits):
+        fits = [b for b in fits
+                if window % b == 0 or b <= _FLASH_BAND_MIN_BLOCK]
+    return fits[0] if fits else t
 
 
 # (width of Q and K, width of V) the kernel takes: one head width, 64 or

@@ -320,9 +320,37 @@ def test_no_t_by_t_array_with_the_one_tile_form_either():
     assert [s for s in _shapes_in(jaxpr) if s.count(t) >= 2] == []
 
 
-def test_blocks_divide_the_length():
-    assert [nn_ops._flash_block(t) for t in (512, 640, 1024, 1536, 4096, 16)
-            ] == [512, 128, 1024, 512, 1024, 16]
+@pytest.mark.parametrize("t, window, block", [
+    (512, 0, 512), (640, 0, 128), (1024, 0, 1024), (1536, 0, 512),
+    (4096, 0, 1024), (16, 0, 16),
+    (6144, 512, 512),  # Laguna-XS.2's: half a 1024-block, one of 512
+    (8192, 2048, 1024),  # Trinity-Mini's: two blocks, as before PR 66
+    (1024, 512, 512), (6144, 1536, 512), (6144, 3072, 1024),
+    (6144, 6144, 1024), (6144, 8192, 1024),  # covers the sequence
+    (6144, 1000, 1024), (640, 100, 128),  # no block divides the window
+    # the measured floor: 512-blocks ahead of 256 and 128 at these windows
+    (6144, 256, 512), (6144, 128, 512), (6144, 384, 512), (512, 256, 512),
+    (768, 256, 256), (640, 128, 128),  # where 512 does not divide T
+    (16, 8, 16),  # a length no block divides: one block
+], ids=lambda v: str(v))
+def test_blocks_divide_the_length_and_the_window(t, window, block):
+    """_flash_block(T, window): the largest block that divides T, and under
+    a window narrower than T the largest that divides the window too, not
+    under the floor the chip's sweep set (nn_ops._FLASH_BAND_MIN_BLOCK): the
+    band's edge lies on a block boundary and no tile is cut by the diagonal
+    and the edge at once, wherever a block from the floor up divides the
+    window."""
+    assert nn_ops._flash_block(t, window) == block
+    if not window:
+        assert nn_ops._flash_block(t) == block
+    band = window if window < t else 0  # what the kernels make of it
+    if window % block == 0:
+        assert pk._tile_counts(t, block, block, band)["both"] == 0
+        if band and block >= 256:  # strips on the band's edge
+            assert pk._tile_plan(t, block, block, band,
+                                 pk._strip_parts(block)).edge > 1
+    elif any(t % b == 0 and window % b == 0 for b in nn_ops._FLASH_BLOCKS):
+        assert block == nn_ops._FLASH_BAND_MIN_BLOCK  # the floor, no less
 
 
 def test_choice_falls_back_to_the_default_backend_and_skips_qstart(
@@ -889,12 +917,41 @@ def test_the_lowering_records_the_band_grid_of_a_windowed_op_only():
     assert kt.attribution()["attention_band_grid"] == {"ops": 0, "steps": {}}
 
 
+def test_the_counters_report_the_block_that_ran_under_lagunas_window():
+    """Laguna-XS.2's window layers (T 6144, window 512, heads of 128): the
+    lowering hands the kernels and both counters the block the rule answers
+    (PR 66: 512, where 1024 made every live tile a whole masked one): 24
+    forward steps a head, 23 live; 12 tiles on the diagonal and 11 on the
+    band's edge, none cut by both, 1.50 x the visible pairs forward and
+    1.25 x backward where 1024-blocks computed 3.83 x."""
+    x = jax.ShapeDtypeStruct((1, 2, 6144, 128), jnp.bfloat16)
+    kt.reset_attribution()
+    jax.eval_shape(lambda q, k, v: _op(TPU, q, k, v, window=512), x, x, x)
+    got = kt.attribution()
+    assert got["attention_band_grid"] == {
+        "ops": 1, "steps": {"6144x512x512x512": [24, 23]}}
+    (key, said), = got["attention_tile_classes"]["shapes"].items()
+    assert key == "6144x512x512x512x128"
+    assert said["tiles"] == {"whole": 0, "diag": 12, "edge": 11, "both": 0}
+    assert (said["fwd_bodies"], said["bwd_bodies"]) == (4, 8)
+    assert said["fwd_pairs"] / said["visible"] == pytest.approx(1.4999,
+                                                                abs=1e-4)
+    assert said["bwd_pairs"] / said["visible"] == pytest.approx(1.2499,
+                                                                abs=1e-4)
+    parent = pk.tile_class_stats(6144, 128, 1024, 1024, 512)
+    assert parent["tiles"] == {"whole": 0, "diag": 0, "edge": 5, "both": 6}
+    assert parent["fwd_pairs"] / parent["visible"] == pytest.approx(
+        3.8258, abs=1e-4)
+    kt.reset_attribution()
+
+
 def test_window_grid_live_share_is_in_the_benchmark_by_name(monkeypatch):
     """BENCHMARK.json carries `window_grid_live_share` (found by name, not
     by position), its layer_metrics file names a reader that imports, and
     the reader answers None on a program that records no band grid (the
     parent commit's, or one whose windowed ops never took the kernel),
-    87.5 from Trinity-Mini's record and 91.7 from Laguna-XS.2's."""
+    87.5 from Trinity-Mini's record and 95.8 from Laguna-XS.2's (91.7
+    before PR 66, in 1024-blocks)."""
     import importlib.util
     import json
 
@@ -925,11 +982,13 @@ def test_window_grid_live_share_is_in_the_benchmark_by_name(monkeypatch):
     kt.note_band_grid(8192, 2048, 1024, 1024, 24, 21)
     assert reader.read(ctx) == 87.5
     kt.reset_attribution()
-    # Laguna-XS.2's (PR 65): a 512 window is half a 1024-block, a walk is two
-    # blocks wide and only the sequence's first block repeats one
-    assert pk.band_grid_steps(6144, 1024, 1024, 512) == (12, 11)
-    kt.note_band_grid(6144, 512, 1024, 1024, 12, 11)
-    assert reader.read(ctx) == pytest.approx(100.0 * 11 / 12)
+    # Laguna-XS.2's: a 512 window in the 512-blocks the rule answers (PR 66;
+    # half a 1024-block before: 12 steps, 11 live): a walk is two blocks
+    # wide and only the sequence's first block repeats one
+    blk = nn_ops._flash_block(6144, 512)
+    assert (blk, pk.band_grid_steps(6144, blk, blk, 512)) == (512, (24, 23))
+    kt.note_band_grid(6144, 512, blk, blk, 24, 23)
+    assert reader.read(ctx) == pytest.approx(100.0 * 23 / 24)
     kt.reset_attribution()
 
 

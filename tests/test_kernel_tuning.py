@@ -171,7 +171,8 @@ def test_attention_pairs_computed_over_visible_is_in_the_benchmark_by_name(
 
 
 # T x window x d -> (tiles a head, forward fetches, backward fetches) of the
-# flash cells' cores in 1024-blocks
+# flash cells' cores in the blocks nn_ops._flash_block answers: 1024, and
+# 512 under Laguna-XS.2's 512 window (PR 66)
 CELL_FETCHES = {
     (1024, 0, 64): (1, 1, 1),  # GPT-2: one block a sequence
     (4096, 0, 128): (10, 9, 9),  # Ouro, OLMoE
@@ -180,6 +181,9 @@ CELL_FETCHES = {
     (8192, 0, 128): (36, 35, 35),  # Trinity-Mini's full layer
     (8192, 2048, 128): (21, 20, 20),  # its window layers, on the band grid
     (8192, 0, 256): (36, 35, 35),  # Qwen3-Next
+    (6144, 0, 128): (21, 20, 20),  # Laguna-XS.2's full layers
+    (6144, 512, 128): (23, 12, 12),  # its window layers: the diagonal
+    # tile's block is held over for the next row's edge tile
 }
 
 
@@ -190,10 +194,11 @@ def test_the_attribution_carries_the_fetches_at_the_cells_shapes(t, window,
     whole, the two counts of PR 56 with the rest: the inner blocks a head's
     forward and backward walks copy in, each no more than the tiles the
     mask lets run (every block copied in is computed on)."""
-    from paddle_tpu.ops import pallas_kernels as pk
+    from paddle_tpu.ops import nn_ops, pallas_kernels as pk
 
-    said = pk.tile_class_stats(t, d, 1024, 1024, window)
-    kt.note_tile_classes(t, window, 1024, 1024, d, said)
+    blk = nn_ops._flash_block(t, window)
+    said = pk.tile_class_stats(t, d, blk, blk, window)
+    kt.note_tile_classes(t, window, blk, blk, d, said)
     got, = kt.attribution()["attention_tile_classes"]["shapes"].values()
     assert got == dict(said, ops=1)
     assert (sum(got["tiles"].values()), got["fwd_fetches"],

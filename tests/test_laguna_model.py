@@ -783,27 +783,10 @@ def test_trinitys_refusal_says_what_is_supported():
 
 
 # --- the flash kernels under Laguna's window --------------------------------
-@pytest.mark.parametrize("window", [128, 256, 512],
-                         ids=["half_a_block", "one_block", "two_blocks"])
-def test_the_flash_kernels_are_right_under_a_window_of_half_one_and_two_blocks(
-        monkeypatch, window):
+def _windowed_op_against_dense(t, d, window):
     """The op as a chip takes it (the platform stated, the kernels
-    interpreted) at T = 1024 in 256-blocks, the ratios of Laguna's 512
-    window to the 1024-, 512- and 256-blocks a sequence may be cut in:
-    forward and the gradients of q, k and v against the dense lowering's
-    mask from positions.  Half a block cuts the diagonal tile on both
-    sides (class `both`) and leaves no multiple of the block on the
-    band's edge; one and two blocks cut the edge tile corner to corner."""
-    from paddle_tpu.ops import pallas_kernels as pk
-
-    monkeypatch.setattr(nn_ops, "_FLASH_BLOCKS", (256,))
-    jax.clear_caches()
-    t, d = 1024, 64
-    tiles = pk._tile_plan(t, 256, 256, window, pk._strip_parts(256))
-    assert tiles.both == (window < 256)
-    assert (tiles.edge > 1) == (window % 256 == 0)
-    assert pk._band_grid(t, t, 256, 256, True, window) == (
-        2 if window <= 256 else 3)
+    interpreted) against the dense lowering's mask from positions: forward
+    and the gradients of q, k and v."""
     keys = jax.random.split(jax.random.PRNGKey(window), 3)
     q, k, v = (jax.random.normal(key, (1, 3, t, d), jnp.float32)
                for key in keys)
@@ -830,3 +813,48 @@ def test_the_flash_kernels_are_right_under_a_window_of_half_one_and_two_blocks(
     for a, b in zip(got, want):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-3, atol=2e-4)
+
+
+@pytest.mark.parametrize("window", [128, 256, 512],
+                         ids=["half_a_block", "one_block", "two_blocks"])
+def test_the_flash_kernels_are_right_under_a_window_of_half_one_and_two_blocks(
+        monkeypatch, window):
+    """At T = 1024 in 256-blocks, the ratios of Laguna's 512 window to the
+    1024-, 512- and 256-blocks a sequence may be cut in.  Half a block cuts
+    the diagonal tile on both sides (class `both`) and leaves no multiple
+    of the block on the band's edge; one and two blocks cut the edge tile
+    corner to corner."""
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setattr(nn_ops, "_FLASH_BLOCKS", (256,))
+    jax.clear_caches()
+    t, d = 1024, 64
+    tiles = pk._tile_plan(t, 256, 256, window, pk._strip_parts(256))
+    assert tiles.both == (window < 256)
+    assert (tiles.edge > 1) == (window % 256 == 0)
+    assert pk._band_grid(t, t, 256, 256, True, window) == (
+        2 if window <= 256 else 3)
+    _windowed_op_against_dense(t, d, window)
+
+
+def test_the_block_the_rule_answers_under_lagunas_window_is_right():
+    """The REAL rule, no tuple patched (PR 66): at T = 1024, heads of 128,
+    Laguna's 512 window, nn_ops._flash_block answers 512, where it answered
+    1024 and one tile of class `both` held the whole sequence: two blocks a
+    side, the diagonal's tiles and the edge's in strips, the band as wide
+    as the grid."""
+    from paddle_tpu.ops import kernel_tuning as kt
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    t, d, window = 1024, 128, 512
+    assert nn_ops._flash_block(t, window) == 512
+    assert pk._tile_counts(t, 512, 512, window) == {
+        "whole": 0, "diag": 2, "edge": 1, "both": 0}
+    tiles = pk._tile_plan(t, 512, 512, window, pk._strip_parts(512))
+    assert (tiles.diag, tiles.edge, tiles.both) == (4, 4, False)
+    jax.clear_caches()
+    kt.reset_attribution()
+    _windowed_op_against_dense(t, d, window)
+    steps = kt.attribution()["attention_band_grid"]["steps"]
+    assert set(steps) == {"1024x512x512x512"}  # the block that ran
+    kt.reset_attribution()

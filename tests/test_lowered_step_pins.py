@@ -210,7 +210,24 @@ Mosaic payloads (now `_inplace_fwd_call` / `_inplace_bwd_call`'s, none of
 the text, where the step held 48.  The other seventeen did NOT move: no
 other builder applies the pass, a `fused_attention` op without the
 attribute lowers through the branch it took, and `layers.fused_attention`
-writes no `layout` attribute at its default."""
+writes no `layout` attribute at its default.
+
+PR 66 made the block the training path hands the flash kernels a function
+of the op's (T, window) (`nn_ops._flash_block(t, window)`: under a window
+narrower than T the largest block that divides the window too, not under
+512).  All eighteen digests and counts above stayed what they were, and
+that is the proof that the fourteen cells without Laguna-XS.2's window did
+not move: `trinity`'s tiny program runs a 256 window over T = 512, under
+the floor, in the one 512-block it ran in, as Trinity-Mini's cell keeps
+1024-blocks under its 2048 window (`window_2048_of_8192`).  `laguna` (a
+tiny Laguna-XS.2 at T = 1024: a full layer of one query head and a window
+layer of two over one KV head of 128, the gate a head, YaRN on half of the
+full layer's head, a 512 window, a share of the experts held) was added,
+its digest taken from PR 66's tree by this file's `_digest`: its window
+core runs two 512-blocks a side with the diagonal's and the edge's tiles
+in strips, and it is NOT what the parent's rule lowers (the window core in
+one 1024-block, one tile cut by both: `_flash_block` patched to drop the
+window, in a scratch script, gives another digest on this tree)."""
 
 import base64
 import functools
@@ -223,9 +240,9 @@ import pytest
 
 import paddle_tpu as fluid
 from paddle_tpu.core.trace import build_traced_function
-from paddle_tpu.models import (gpt2, kanana2, kimi_linear, lfm2, nemotron_h,
-                               olmoe, ouro, qwen3_next, resnet, transformer,
-                               trinity)
+from paddle_tpu.models import (gpt2, kanana2, kimi_linear, laguna, lfm2,
+                               nemotron_h, olmoe, ouro, qwen3_next, resnet,
+                               transformer, trinity)
 from paddle_tpu.ops import pallas_kernels as pk
 
 SEQ = 512
@@ -299,6 +316,18 @@ class N(nemotron_h.NemotronHConfig):
     num_local_experts, expert_offset = 2, 2
 
 
+class A(laguna.LagunaConfig):
+    vocab_size, hidden_size, intermediate_size = 512, 128, 128
+    moe_intermediate_size = shared_expert_intermediate_size = 128
+    num_hidden_layers, num_key_value_heads = 2, 1
+    layer_types = ["full_attention", "sliding_attention"]
+    mlp_layer_types = ["dense", "sparse"]
+    num_attention_heads_per_layer = [1, 2]
+    sliding_window = 512
+    num_experts, num_experts_per_tok = 8, 2
+    num_local_experts, expert_offset = 2, 2
+
+
 class U(ouro.OuroConfig):
     vocab_size, hidden_size, intermediate_size = 512, 128, 256
     num_hidden_layers, num_attention_heads, num_key_value_heads = 2, 2, 2
@@ -327,15 +356,15 @@ def _shapes(batch):
             for n, a in batch.items()}
 
 
-def _lm(build, hp):
+def _lm(build, hp, seq=SEQ):
     """(main, startup, loss name, feeds) of a causal LM builder's train
-    program at 2 x SEQ in bfloat16."""
-    main, startup, _, fetches = build(hp, seq_len=SEQ, lr=1e-3,
+    program at 2 x seq in bfloat16."""
+    main, startup, _, fetches = build(hp, seq_len=seq, lr=1e-3,
                                       use_bf16=True)
     return main, startup, fetches[0].name, {
-        "ids": jax.ShapeDtypeStruct((2, SEQ), jnp.int32),
-        "labels": jax.ShapeDtypeStruct((2, SEQ), jnp.int32),
-        "loss_weight": jax.ShapeDtypeStruct((2, SEQ), jnp.float32)}
+        "ids": jax.ShapeDtypeStruct((2, seq), jnp.int32),
+        "labels": jax.ShapeDtypeStruct((2, seq), jnp.int32),
+        "loss_weight": jax.ShapeDtypeStruct((2, seq), jnp.float32)}
 
 
 def _transformer():
@@ -369,6 +398,7 @@ PROGRAMS = {"gpt2": lambda: _lm(gpt2.gpt2_lm_program, G),
             "qwen3_next": lambda: _lm(qwen3_next.qwen3_next_lm_program, Q),
             "ouro": lambda: _lm(ouro.ouro_lm_program, U),
             "nemotron_h": lambda: _lm(nemotron_h.nemotron_h_lm_program, N),
+            "laguna": lambda: _lm(laguna.laguna_lm_program, A, 2 * SEQ),
             "transformer": _transformer,
             "resnet": _resnet}
 
@@ -381,7 +411,7 @@ PROGRAMS = {"gpt2": lambda: _lm(gpt2.gpt2_lm_program, G),
 # causal kernels walk several blocks of the full grid, `piece_diagonal_chunk`
 # among them: at PR 56; `nemotron_h`: added at PR 57; the six programs whose
 # AMP rewrite flips a `split`, `concat` or `expand`: at PR 58; `transformer`
-# again: at PR 62, and at PR 63)
+# again: at PR 62, and at PR 63; `laguna`: added at PR 66)
 BEFORE = {
     "nemotron_h": ("424a80a8933a0ec7ee89c4ece3eeca9006e18e92", 18),
     "qwen3_next": ("3d4b8d2d56c5035594075b0508a3614e286234ce", 27),
@@ -391,6 +421,7 @@ BEFORE = {
     "ouro": ("0057fcbecadc1719a1de27cb3b94bb41ac569650", 3),
     "kanana2": ("58a5bd2363cca5fb23fea690fbcbdda362a0ee97", 9),
     "trinity": ("1376e7973e2fe1f118d8b4c0310391977cccd060", 12),
+    "laguna": ("436e9f3354da5934a15bf1b697a53f5570de648d", 12),
     "gpt2": ("bfdcbc6f62d3aaf4418dc9bf22e0aaf9dc8a8a4a", 3),
     "olmoe": ("8fc96fbebcde6d156165e9b26443399f6b36494f", 9),
     "lfm2": ("3def3cb90e0a1051d6b1d7f46e50389e659a824d", 9),

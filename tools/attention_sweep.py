@@ -50,6 +50,18 @@ the full causal triangle (window 0) at 1024 beside them.
 
     python3 tools/attention_sweep.py --window 2048 [--out chiprun_out/window_sweep.json]
 
+With --band-blocks W1,W2,.. it times the band of each of those windows
+(--bh / --t / --d as above) on the grid the kernel takes, at square blocks
+of 1024 / 512 / 256 / 128: a window of several blocks, of ONE block and of
+HALF a block and less (PR 66: the rows behind nn_ops._flash_block's answer
+under a window, which --window's two blocks and more do not reach).  A row
+has ms forward and backward, the tiles by class, the pairs each pass
+computes over the visible ones and the bodies a kernel holds; a window's
+last line names its fastest block and the block the program takes.
+Laguna-XS.2's window core:
+
+    python3 tools/attention_sweep.py --band-blocks 128,256,512,1024,2048 --bh 64 --t 6144 [--out chiprun_out/band_block_sweep.json]
+
 With --tile-classes it times the training path's kernels at the flash
 cells' shapes (TILE_SHAPES) with a tile computed by where it lies (PR 53:
 `on`, what the program does) against every tile masked whole (`off`:
@@ -120,7 +132,8 @@ IN_PLACE_SHAPES = [
 ]
 IN_PLACE_SEQS = (2, 4, 8, 16, 32)
 # (cells, B*H, T, width of Q and K, width of V, window) of --tile-classes:
-# the nine flash cells' attention cores, in blocks of nn_ops._flash_block(T)
+# the ten flash cells' attention cores, in blocks of
+# nn_ops._flash_block(T, window)
 TILE_SHAPES = [
     ("gpt2_345m_train", 64, 1024, 64, 64, 0),
     ("ouro_2b6_train", 16, 4096, 128, 128, 0),
@@ -131,6 +144,8 @@ TILE_SHAPES = [
     ("trinity_mini_train.full", 32, 8192, 128, 128, 0),
     ("trinity_mini_train.window", 32, 8192, 128, 128, 2048),
     ("qwen3_next_80b_a3b_train", 16, 8192, 256, 256, 0),
+    ("laguna_xs2_33b_a3b_train.full", 48, 6144, 128, 128, 0),
+    ("laguna_xs2_33b_a3b_train.window", 64, 6144, 128, 128, 512),
 ]
 
 
@@ -140,6 +155,9 @@ def main():
     ap.add_argument("--iters", type=int, default=30)
     ap.add_argument("--window", type=int, default=0,
                     help="sweep the sliding-window kernel at this window")
+    ap.add_argument("--band-blocks", default="",
+                    help="time the band of each of these comma-separated "
+                    "windows at every square block")
     ap.add_argument("--bh", type=int, default=32)
     ap.add_argument("--t", type=int, default=8192)
     ap.add_argument("--d", type=int, default=128)
@@ -182,7 +200,8 @@ def main():
     dev = jax.devices()[0]
     if dev.platform != "tpu" and not (
             args.rehearse and (args.window or args.tile_classes
-                               or args.dead_fetch or args.short)):
+                               or args.dead_fetch or args.short
+                               or args.band_blocks)):
         raise SystemExit("attention_sweep: needs a TPU, jax found %s" % dev)
 
     def timed(fn, operands, backward=True):
@@ -266,6 +285,52 @@ def main():
             print(json.dumps(cost), flush=True)
         return rows
 
+    def band_block_sweep():
+        """One JSON line a (window, block), and one a window: its fastest
+        block forward + backward and the block the program takes."""
+        from paddle_tpu.ops import nn_ops
+
+        bh, t, d = args.bh, args.t, args.d
+        windows = [int(w) for w in args.band_blocks.split(",")]
+        if args.rehearse:  # one, two and four blocks a side, interpreted
+            bh, t, windows = 1, 512, [128, 256]
+        keys = jax.random.split(jax.random.PRNGKey(0), 3)
+        q, k, v = (jax.random.normal(kk, (bh, t, d), jnp.float32).astype(
+            jnp.bfloat16) for kk in keys)
+        scale = d ** -0.5
+        rows = []
+        for w in windows:
+            ms = {}
+            for blk in (b for b in BLOCKS[::-1] if t % b == 0):
+                jax.clear_caches()  # the kernels' entries are jitted
+
+                def fn(q, k, v, kb):
+                    return pk.flash_attention(q, k, v, kb, True, scale, blk,
+                                              blk, w)
+
+                stats = pk.tile_class_stats(t, d, blk, blk, w)
+                steps, tiles = pk.band_grid_steps(t, blk, blk, w)
+                fwd, both = fwd_fwdbwd(fn, (q, k, v, None))
+                ms[blk] = both
+                rows.append({
+                    "bh": bh, "t": t, "d": d, "window": w, "block": blk,
+                    "fwd_ms": fwd, "fwd_bwd_ms": both,
+                    "bwd_ms": both and round(both - fwd, 4),
+                    "fwd_steps": steps, "tiles": tiles,
+                    "tile_classes": stats["tiles"],
+                    "pairs_over_visible_fwd_bwd": [
+                        round(stats[p] / stats["visible"], 4)
+                        for p in ("fwd_pairs", "bwd_pairs")],
+                    "bodies_fwd_bwd": [stats["fwd_bodies"],
+                                       stats["bwd_bodies"]]})
+                print(json.dumps(rows[-1]), flush=True)
+            rows.append({"window": w, "t": t,
+                         "best": None if args.rehearse
+                         else min(ms, key=ms.get),
+                         "the_program_takes": nn_ops._flash_block(t, w)})
+            print(json.dumps(rows[-1]), flush=True)
+        return rows
+
     def tile_sweep():
         """One JSON line a (shape, variant), and with `both` one a shape
         comparing them."""
@@ -284,7 +349,7 @@ def main():
                 continue
             if args.rehearse:  # two blocks of 256 a side, interpreted
                 bh, t, w = 1, 512, w and 256
-            blk = 256 if args.rehearse else nn_ops._flash_block(t)
+            blk = 256 if args.rehearse else nn_ops._flash_block(t, w)
             keys = jax.random.split(jax.random.PRNGKey(0), 3)
             q, k, v = (jax.random.normal(kk, (bh, t, n), jnp.float32).astype(
                 jnp.bfloat16) for kk, n in zip(keys, (d, d, dv)))
@@ -393,7 +458,7 @@ def main():
         for name, bh, t, d, dv, w in TILE_SHAPES:
             if words and not any(word in name for word in words):
                 continue
-            blk = nn_ops._flash_block(t)
+            blk = nn_ops._flash_block(t, w)
             if args.rehearse:  # four blocks of 128 a side (one of 512 where
                 # one block holds the cell's sequence), interpreted
                 bh, t, w, blk = 1, 512, w and 256, 512 if blk == t else 128
@@ -616,6 +681,8 @@ def main():
         return save(dead_fetch_sweep(), "dead_fetch_sweep")
     if args.tile_classes:
         return save(tile_sweep(), "tile_sweep")
+    if args.band_blocks:
+        return save(band_block_sweep(), "band_block_sweep")
     if args.window:
         return save(window_sweep(), "window_sweep")
 

@@ -125,7 +125,7 @@ def main():
     fused_limit = pk._FUSED_BWD_DQ_BYTES_WIDE
     for t in (LENGTHS[1:] if square else LENGTHS):
         ops = operands(t)
-        block = nn_ops._flash_block(t)
+        block = nn_ops._flash_block(t, window=0)
         # what one core must do forward + backward over the causal half
         flops = 3.0 * 2.0 * heads * t * t / 2.0 * (d_qk + d_v)
         one = {"_fused_bwd_dq_limit": lambda d: 2 ** 40}
